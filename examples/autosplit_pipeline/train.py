@@ -50,6 +50,9 @@ def main():
     ap.add_argument("--zero-bubble", action="store_true")
     ap.add_argument("--steps", type=int, default=20)
     args = ap.parse_args()
+    from vescale_tpu.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     model = TangledLM()
     B, T = 8, 32

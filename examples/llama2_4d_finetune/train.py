@@ -35,6 +35,9 @@ def main():
 
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
+    from vescale_tpu.compile_cache import use_compile_cache
+
+    use_compile_cache()
     import jax.numpy as jnp
     import optax
 

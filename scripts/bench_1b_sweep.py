@@ -14,13 +14,16 @@ sys.path.insert(0, ".")
 def run(variant: str):
     import optax
 
+    from vescale_tpu.compile_cache import use_compile_cache
     from vescale_tpu.mesh import DeviceMesh
     from vescale_tpu.dmodule import parallelize_module
     from vescale_tpu.models.llama import Llama, LlamaConfig, llama_plan
     from vescale_tpu.models.nanogpt import cross_entropy_loss
     from vescale_tpu.parallel.optimizer import adamw_lowmem
+    from vescale_tpu.telemetry.calibrate import device_peak_flops
     from vescale_tpu.train import make_train_step
 
+    use_compile_cache()
     T = 4096
     base = dict(
         vocab_size=32000,
@@ -72,15 +75,15 @@ def run(variant: str):
 
     for _ in range(3):
         params, opt_state, loss = step(params, opt_state, batch)
-        float(loss)
+    jax.block_until_ready(loss)
     iters = 10
     t0 = time.perf_counter()
     for _ in range(iters):
         params, opt_state, loss = step(params, opt_state, batch)
-    float(loss)
+    jax.block_until_ready(loss)
     dt = (time.perf_counter() - t0) / iters
     flops_per_token = 6.0 * n_params + 12.0 * cfg.num_hidden_layers * T * cfg.hidden_size
-    mfu = flops_per_token * B * T / dt / 197e12
+    mfu = flops_per_token * B * T / dt / device_peak_flops(devices[0])
     print(
         f"{variant}: step={dt*1e3:.1f}ms  tok/s={B*T/dt:.0f}  MFU={mfu:.4f}",
         flush=True,

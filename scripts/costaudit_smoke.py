@@ -201,9 +201,12 @@ def serve_leg(failures, out_dir: str) -> None:
 def whatif_leg(failures) -> None:
     from vescale_tpu.telemetry import costaudit
 
+    import types
+
     ranked = costaudit.score_candidates(
         costaudit.mesh_candidates(8),
         params_bytes=1e9, activation_bytes=1e8, flops_per_step=1e12,
+        device=types.SimpleNamespace(device_kind="TPU v5 lite"),  # a chip the peak table lists
     )
     check(failures, len(ranked) >= 3, "whatif: >= 3 candidate layouts scored")
     costs = [r["predicted_step_us"] for r in ranked]

@@ -37,7 +37,6 @@ from vescale_tpu.telemetry.alerts import (
     ThresholdRule,
     TrendRule,
     ZScoreRule,
-    bench_rule_pack,
     burn_windows_from_env,
     fleet_rule_pack,
     serve_rule_pack,
@@ -605,17 +604,6 @@ def test_fleet_pack_burn_rule_needs_slo():
     assert "fleet-ttft-slo-burn" not in names
     rules = {r.name: r for r in fleet_rule_pack(slo_ttft_s=0.25)}
     assert rules["fleet-ttft-slo-burn"].slo == 0.25
-
-
-def test_bench_pack_fires_on_any_sample():
-    store = _store()
-    eng = AlertEngine(store=store)
-    eng.arm_pack("bench", bench_rule_pack())
-    assert eng.evaluate(now=T0) == []  # no series yet: quiet
-    store.registry.gauge("bench_tpu_record_age_days").set(3.0)
-    store.sample(now=T0 + 1, force=True)
-    (tr,) = eng.evaluate(now=T0 + 1)
-    assert tr["rule"] == "bench-tpu-stale" and tr["to"] == "firing"
 
 
 def test_burn_windows_env_parsing(monkeypatch):

@@ -638,3 +638,34 @@ def test_ndtimeline_runtime_wiring_fast():
         ckpt.save(td + "/ck", {"m": {"x": vt.distribute_tensor(np.arange(8, dtype=np.float32), mesh, [Shard(0)])}})
     names = {s.metric for s in mgr.flush()}
     assert {"train-step", "checkpoint-save", "checkpoint-commit"} <= names, names
+
+
+# ------------------------------------------------------------ compile cache
+@pytest.mark.parametrize(
+    "backend,env_dir,expect_set",
+    [
+        ("tpu", None, True),          # no variable: the one fixed path in the checkout
+        ("tpu", "/some/where", False),  # variable set: the directory is JAX's, none set in code
+        ("cpu", None, False),         # no accelerator: the cache stays off
+    ],
+)
+def test_use_compile_cache_places_the_cache_from_outside(monkeypatch, backend, env_dir, expect_set):
+    from vescale_tpu import compile_cache
+
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = compile_cache.use_compile_cache()
+        if expect_set:
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert got == os.path.join(repo, ".jax_cache") == compile_cache.DEFAULT_CACHE_DIR
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got is None
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -251,6 +251,35 @@ def test_roofline_counter_tracks_attach_to_perfetto(tmp_path):
 
 
 # ========================================================= what-if scorer
+# compute is priced from a published peak, so the scorer needs a device the
+# peak table lists (the CPU the tests run on is not one)
+_V5E = types.SimpleNamespace(device_kind="TPU v5 lite")
+
+
+def test_device_peaks_of_the_benchmark_chip():
+    from vescale_tpu.telemetry import calibrate
+
+    assert calibrate.device_peak_flops(_V5E) == 197e12
+    assert costaudit.device_mem_gbps(_V5E) == 819.0
+    assert calibrate.device_peaks(_V5E)["source"]
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v5", "tpu v5 lite", None])
+def test_device_peaks_raise_on_unknown_device_kind(kind):
+    """No default peak: a device the table does not list is an error for
+    every reader of it (the local CPU included)."""
+    from vescale_tpu.telemetry import calibrate
+
+    dev = types.SimpleNamespace(device_kind=kind, platform="tpu")
+    with pytest.raises(ValueError, match="no published peak"):
+        calibrate.device_peak_flops(dev)
+    with pytest.raises(ValueError, match="no published peak"):
+        costaudit.device_mem_gbps(dev)
+    with pytest.raises(ValueError, match="no published peak"):
+        costaudit.score_candidates([(8, 1, 1)], params_bytes=1e9, activation_bytes=1e8,
+                                   flops_per_step=1e12, device=dev)
+
+
 def test_mesh_candidates_enumerate_factorizations():
     cands = costaudit.mesh_candidates(8)
     assert (1, 8, 1) in cands and (8, 1, 1) in cands and (2, 2, 2) in cands
@@ -260,7 +289,7 @@ def test_mesh_candidates_enumerate_factorizations():
 def test_score_candidates_ranks_and_confidence_tiers():
     ranked = costaudit.score_candidates(
         costaudit.mesh_candidates(8),
-        params_bytes=1e9, activation_bytes=1e8, flops_per_step=1e12,
+        params_bytes=1e9, activation_bytes=1e8, flops_per_step=1e12, device=_V5E,
     )
     assert len(ranked) >= 3
     costs = [r["predicted_step_us"] for r in ranked]
@@ -277,7 +306,7 @@ def test_score_candidates_ranks_and_confidence_tiers():
         t.add_sample("all_reduce", 8, nb, 1e-3)
     dp8 = next(r for r in costaudit.score_candidates(
         [(8, 1, 1)], params_bytes=1e9, activation_bytes=1e8,
-        flops_per_step=1e12, table=t) if r["terms"])
+        flops_per_step=1e12, table=t, device=_V5E) if r["terms"])
     assert dp8["terms"][0]["source"] == "measured"
     assert dp8["confidence"] == pytest.approx(0.5)
 
@@ -289,7 +318,7 @@ def test_whatif_cli_ranks_meshes(tmp_path):
     t.save(str(tab))
     out = subprocess.run(
         [sys.executable, "-m", "vescale_tpu.analysis", "--json", "whatif",
-         "--devices", "8", "--table", str(tab)],
+         "--devices", "8", "--table", str(tab), "--device", _V5E.device_kind],
         capture_output=True, text=True, timeout=300,
         cwd=pathlib.Path(__file__).resolve().parent.parent,
     )

@@ -290,9 +290,32 @@ def test_flash_streaming_matches_dense(causal, rep):
         )
 
 
-def test_streaming_heuristic():
+@pytest.mark.parametrize(
+    "kernel,T,D,dtype,rep,streams",
+    [
+        # the bf16 main path keeps every kernel resident, MHA and GQA
+        ("fwd", 4096, 128, jnp.bfloat16, 1, False),
+        ("dq", 4096, 128, jnp.bfloat16, 1, False),
+        ("dkv", 4096, 128, jnp.bfloat16, 1, False),
+        ("dkv", 4096, 128, jnp.bfloat16, 4, False),
+        # dk/dv holds q, dO and the lane-padded lse/delta columns whole: it
+        # leaves the resident form long before fwd and dq do
+        ("dkv", 4096, 128, jnp.float32, 1, True),
+        ("dkv", 8192, 128, jnp.bfloat16, 1, True),
+        ("dq", 8192, 128, jnp.bfloat16, 1, False),
+        ("fwd", 8192, 128, jnp.bfloat16, 1, False),
+        # D 64 is padded to 128 lanes: no longer T than D 128 stays resident
+        ("fwd", 16384, 64, jnp.bfloat16, 1, True),
+        ("fwd", 16384, 128, jnp.bfloat16, 1, True),
+        # the long-context rung streams everything
+        ("fwd", 32768, 64, jnp.bfloat16, 2, True),
+        ("dq", 32768, 64, jnp.bfloat16, 2, True),
+        ("dkv", 32768, 64, jnp.bfloat16, 2, True),
+    ],
+)
+def test_streaming_choice_per_kernel(kernel, T, D, dtype, rep, streams):
+    """The resident/streaming choice counts what each resident kernel holds
+    in VMEM (tests/test_tpu_compile.py compiles both sides of it)."""
     from vescale_tpu.ops.flash_attention import _use_streaming
 
-    assert not _use_streaming(4096, 128, jnp.bfloat16)   # headline: resident
-    assert _use_streaming(32768, 64, jnp.bfloat16)       # longctx: streams
-    assert _use_streaming(16384, 128, jnp.bfloat16)
+    assert _use_streaming(kernel, T, D, dtype, 512, 512, rep) == streams

@@ -31,20 +31,6 @@ ULP_BOUND = 8.0
 from vescale_tpu.kernels import ulps_at_scale  # noqa: E402
 
 
-def ulps_elementwise(a, b) -> float:
-    """Max PER-ELEMENT fp32 ulp distance (strict: near-zero elements use
-    their own spacing) — the fused-adamw update bound."""
-    a32 = np.asarray(a, np.float32).ravel()
-    b32 = np.asarray(b, np.float32).ravel()
-    if ulps_at_scale(a32, b32) == float("inf"):
-        return float("inf")
-    fin = np.isfinite(a32) & np.isfinite(b32)
-    if not fin.any():
-        return 0.0
-    step = np.spacing(np.abs(b32[fin]).astype(np.float32))
-    return float(np.max(np.abs(a32[fin].astype(np.float64) - b32[fin]) / step))
-
-
 @pytest.fixture
 def kmode(monkeypatch):
     def set_mode(mode):
@@ -316,37 +302,36 @@ def test_serve_engine_decode_tokens_identical_off_vs_interpret(kmode):
 def test_fused_adamw_bitwise_under_jit(n, state_dtype):
     """Non-divisible block edges (1, 255, 257) and both state dtypes: the
     carried moments are BIT-IDENTICAL to the jitted XLA chain; the update
-    is within 4 elementwise ulps (XLA rewrites the trailing
-    divide/sqrt/divide chain context-dependently — docs/kernels.md
-    documents the bound)."""
-    from vescale_tpu.kernels.fused_adamw import fused_adamw_update
+    of each leg is within 4 elementwise ulps of the float64 evaluation of
+    its formula (``update_ulps_vs_float64`` — the two jitted legs are not
+    compared with each other: XLA:CPU contracts the first moment
+    differently in each fusion it copies it into, docs/kernels.md)."""
+    from vescale_tpu.kernels.fused_adamw import fused_adamw_update, update_ulps_vs_float64
 
     rng = np.random.default_rng(n)
     b1, b2, eps = 0.9, 0.999, 1e-8
     g = jnp.asarray(rng.normal(size=(n,)), jnp.float32)
     m = jnp.asarray(rng.normal(size=(n,)), jnp.float32).astype(state_dtype)
     v = jnp.abs(jnp.asarray(rng.normal(size=(n,)), jnp.float32)).astype(state_dtype)
+    c1 = jnp.asarray(1.0 - b1 ** 5, jnp.float32)
+    c2 = jnp.asarray(1.0 - b2 ** 5, jnp.float32)
 
-    def ref(g, m, v, count):
-        c1 = 1.0 - b1 ** count.astype(jnp.float32)
-        c2 = 1.0 - b2 ** count.astype(jnp.float32)
+    def ref(g, m, v, c1, c2):
         g32 = g.astype(jnp.float32)
         m32 = b1 * m.astype(jnp.float32) + (1.0 - b1) * g32
         v32 = b2 * v.astype(jnp.float32) + (1.0 - b2) * jnp.square(g32)
         u = ((m32 / c1) / (jnp.sqrt(v32 / c2) + eps)).astype(g.dtype)
         return u, m32.astype(state_dtype), v32.astype(state_dtype)
 
-    def ker(g, m, v, count):
-        c1 = 1.0 - b1 ** count.astype(jnp.float32)
-        c2 = 1.0 - b2 ** count.astype(jnp.float32)
+    def ker(g, m, v, c1, c2):
         return fused_adamw_update(g, m, v, c1, c2, b1=b1, b2=b2, eps=eps,
                                   state_dtype=state_dtype, interpret=True)
 
-    count = jnp.asarray(5, jnp.int32)
-    (uk, mk, vk), (ur, mr, vr) = jax.jit(ker)(g, m, v, count), jax.jit(ref)(g, m, v, count)
+    (uk, mk, vk), (ur, mr, vr) = jax.jit(ker)(g, m, v, c1, c2), jax.jit(ref)(g, m, v, c1, c2)
     assert np.array_equal(np.asarray(mk), np.asarray(mr))
     assert np.array_equal(np.asarray(vk), np.asarray(vr))
-    assert ulps_elementwise(uk, ur) <= 4.0
+    for u in (uk, ur):
+        assert update_ulps_vs_float64(u, g, m, v, c1, c2, b1=b1, b2=b2, eps=eps) <= 4.0
 
 
 def test_fused_adamw_nan_poison():

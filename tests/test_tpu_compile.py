@@ -1,0 +1,155 @@
+"""The Pallas kernels compile for the chip — checked here, without one.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+*described* v5e (``topologies.get_topology_desc``): each case lowers one
+kernel at the widths the main path runs it at, from ``ShapeDtypeStruct``s on
+one described device, and compiles it.  Nothing executes, so this says
+nothing about results or times; it catches what interpret mode cannot — a
+kernel the compiler refuses (scoped VMEM, tiling, HBM).  The cases call the
+kernel entry points below the platform dispatch (``_flash``,
+``paged_decode``, ``_fused_local``, ``fused_xent_parts``): code that asks
+``jax.devices()`` still sees the CPU here.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+bf16, f32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """Sharding on one described v5e device, with the persistent compile
+    cache off around the module (an entry written for a described chip
+    cannot be read back without one, and warns)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or another process holds it
+        pytest.skip(f"cannot describe a v5e topology: {str(e)[:200]}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the kernel is in the program
+    return compiled
+
+
+# ------------------------------------------------------------------ flash
+# (T, H, KV, D, dtype, backward): the main path (T 4096, MHA and GQA), the
+# per-shard heads of a two-way tp split (H 16), the long-context rung
+# (streaming kernels), and the lengths between them, which the compiler
+# refused before the resident/streaming choice counted what the resident
+# kernels hold in VMEM.
+FLASH_CASES = [
+    (4096, 32, 32, 128, bf16, True),
+    (4096, 32, 8, 128, bf16, True),
+    (4096, 32, 32, 128, f32, True),
+    (4096, 32, 8, 128, f32, True),
+    (4096, 16, 16, 128, bf16, True),
+    (2048, 16, 16, 128, bf16, True),
+    (2048, 32, 32, 128, bf16, False),
+    (6144, 32, 32, 128, bf16, True),
+    (6144, 32, 8, 128, bf16, True),
+    (8192, 32, 32, 128, bf16, True),
+    (10240, 32, 8, 128, bf16, True),
+    (12800, 32, 32, 128, bf16, False),
+    (16384, 12, 12, 64, bf16, False),
+    (16384, 12, 12, 64, bf16, True),
+    (32768, 16, 8, 64, bf16, True),
+]
+
+
+@pytest.mark.parametrize(
+    "T,H,KV,D,dtype,backward", FLASH_CASES,
+    ids=[f"T{t}-H{h}kv{kv}-D{d}-{jnp.dtype(dt).name}-{'bwd' if b else 'fwd'}"
+         for t, h, kv, d, dt, b in FLASH_CASES],
+)
+def test_flash_compiles(chip, T, H, KV, D, dtype, backward):
+    from vescale_tpu.ops.flash_attention import _flash
+
+    q = jax.ShapeDtypeStruct((1, T, H, D), dtype, sharding=chip)
+    kv = jax.ShapeDtypeStruct((1, T, KV, D), dtype, sharding=chip)
+
+    def fwd(q, k, v):
+        return _flash(q, k, v, D ** -0.5, True, 512, 512, False, "pallas")
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(f32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    _compile(fwd_bwd if backward else fwd, q, kv, kv)
+
+
+# ----------------------------------------------------------- paged decode
+# (slots, pages_per_slot, page, H, KV, hd, dtype)
+PAGED_CASES = [
+    (16, 128, 16, 32, 32, 128, bf16),
+    (16, 128, 16, 32, 8, 128, bf16),
+    (16, 128, 16, 32, 4, 128, bf16),
+    (16, 128, 16, 16, 16, 128, bf16),
+    (16, 64, 16, 12, 12, 64, f32),
+    (8, 16, 8, 32, 32, 128, f32),
+]
+
+
+@pytest.mark.parametrize(
+    "S,Pmax,page,H,KV,hd,dtype", PAGED_CASES,
+    ids=[f"S{s}-P{p}x{pg}-H{h}kv{kv}-hd{d}-{jnp.dtype(dt).name}"
+         for s, p, pg, h, kv, d, dt in PAGED_CASES],
+)
+def test_paged_decode_compiles(chip, S, Pmax, page, H, KV, hd, dtype):
+    from vescale_tpu.kernels.paged_attention import paged_decode
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    pool = sds((S * Pmax + 1, page, KV, hd), dtype)
+    _compile(
+        lambda q, k, v, table, lengths: paged_decode(
+            q, k, v, table, lengths, scale=hd ** -0.5, interpret=False),
+        sds((S, H, hd), dtype), pool, pool, sds((S, Pmax), jnp.int32), sds((S,), jnp.int32),
+    )
+
+
+# ------------------------------------------------------------ fused adamw
+@pytest.mark.parametrize("shape", [(4096, 14336), (4097,)], ids=["ffn-leaf", "ragged-tail"])
+def test_fused_adamw_compiles(chip, shape):
+    from vescale_tpu.kernels.fused_adamw import _fused_local
+
+    sds = lambda dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    _compile(
+        lambda g, m, v, coef: _fused_local(
+            g, m, v, coef, b1=0.9, b2=0.999, eps=1e-8, state_dtype=bf16, interpret=False),
+        sds(f32), sds(bf16), sds(bf16), jax.ShapeDtypeStruct((2,), f32, sharding=chip),
+    )
+
+
+# ------------------------------------------------------------- fused xent
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+@pytest.mark.parametrize("vocab", [32000, 128256])
+def test_fused_xent_compiles(chip, vocab, backward):
+    from vescale_tpu.kernels.cross_entropy import fused_xent_parts
+
+    rows = 4096
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    def loss(lg, idx, gmax):
+        se, pk, _ = fused_xent_parts(lg, idx, gmax, False)
+        return jnp.mean(gmax + jnp.log(se) - pk)
+
+    _compile(
+        jax.grad(loss) if backward else loss,
+        sds((rows, vocab), f32), sds((rows,), jnp.int32), sds((rows,), f32),
+    )

@@ -54,7 +54,7 @@ _REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 def _default_lint_paths() -> List[str]:
     paths = []
     for rel in ("vescale_tpu", "scripts", "examples", "tests", "bench.py",
-                "__graft_entry__.py"):
+                "chip_smoke.py", "__graft_entry__.py"):
         p = os.path.join(_REPO, rel)
         if os.path.exists(p):
             paths.append(p)
@@ -182,10 +182,9 @@ def cmd_whatif(args) -> int:
         num = len(jax.devices())
     device = None
     if args.device:
-        # a named generation ("v5p", "v6e", ...) instead of the local chip:
-        # a shim carrying just the two attrs device_peak_flops reads
-        device = type("_Dev", (), {"device_kind": args.device,
-                                   "platform": "tpu"})()
+        # a named chip instead of the local device: a shim carrying the one
+        # attr calibrate.device_peaks reads
+        device = type("_Dev", (), {"device_kind": args.device})()
     cands = costaudit.mesh_candidates(num)
     ranked = costaudit.score_candidates(
         cands,
@@ -250,8 +249,8 @@ def main(argv=None) -> int:
     p_wi.add_argument("--table", default=None, metavar="PATH",
                       help="calibration table JSON (default: active table)")
     p_wi.add_argument("--device", default=None,
-                      help='chip generation for the compute roofline '
-                      '(e.g. "v5p"; default: local device)')
+                      help='device_kind whose published peak prices compute '
+                      '(e.g. "TPU v5 lite"; default: local device)')
     p_wi.add_argument("--top", type=int, default=0,
                       help="print only the best N layouts")
     args = ap.parse_args(argv)

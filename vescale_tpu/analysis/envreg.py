@@ -428,20 +428,8 @@ register("VESCALE_COSTAUDIT_HARVEST", "bool", True,
 # --- bench harness ---------------------------------------------------
 register("VESCALE_BENCH", "str", None,
          "Which bench rung to run (e.g. `serve`, `redistribute`, `memtrack`, `watchdog`); unset = default MFU line.")
-register("VESCALE_BENCH_RUNG", "str", "1.3b",
-         "Model size rung for the 1B-sweep bench script.")
-register("VESCALE_BENCH_STEP_REPORT", "bool", None,
-         "Write a compile-time step report during bench runs; unset = on for CPU, off on TPU.")
-register("VESCALE_BENCH_NO_REGISTER", "bool", False,
-         "Skip BENCH_r*.json registration (set for child/sub-bench processes).")
-register("VESCALE_BENCH_BUDGET_S", "float", 1200.0,
-         "Wall-clock budget in seconds for the bench driver.")
-register("VESCALE_BENCH_CHILD", "bool", False,
-         "Marks a bench subprocess (internal; set by the bench driver).")
-register("VESCALE_BENCH_CPU_FALLBACK", "bool", False,
-         "Marks the orchestrator's last-resort CPU bench child (internal); the "
-         "child flags the stale TPU record through the alert engine "
-         "(bench-tpu-stale).")
+register("VESCALE_BENCH_STEP_REPORT", "bool", False,
+         "Add a compile-time step report to the bench MFU line (a second compile of the step program).")
 
 # --- AOT report scripts ----------------------------------------------
 register("VESCALE_AOT_MODEL", "str", "8b",
@@ -456,7 +444,5 @@ register("VESCALE_AOT_DEBUG", "bool", False,
          "Verbose AOT-report debugging output.")
 
 # --- entry / misc ----------------------------------------------------
-register("VESCALE_DRYRUN_VIRTUAL_CHILD", "bool", False,
-         "Marks a virtual-device dry-run subprocess (internal; set by __graft_entry__).")
 register("VESCALE_FP8_ON_TPU", "bool", False,
          "Allow the fp8 example on real TPU backends (off = CPU emulation only).")

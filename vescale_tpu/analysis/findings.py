@@ -6,9 +6,8 @@ planner's decline reasons — is a :class:`Finding` carrying a stable
 ``VSC###`` code, a severity, optional mesh-dim / op provenance, and (for
 data-movement findings) an estimated byte count priced by the collective
 cost model in ``collectives.py``.  Stable codes are the contract: the CLI
-greps them, tests assert them, docs/known_failures.md indexes by them, and
-``redistribute_plan`` reuses the VSC12x block as its structured decline
-reasons instead of free-form strings.
+greps them, tests assert them, and ``redistribute_plan`` reuses the VSC12x
+block as its structured decline reasons instead of free-form strings.
 
 Code blocks:
 

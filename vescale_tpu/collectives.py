@@ -25,43 +25,10 @@ from typing import Optional, Union
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .mesh import DeviceMesh
-
-try:  # jax>=0.4.35
-    from jax import shard_map as _shard_map_mod  # type: ignore
-
-    _raw_shard_map = (
-        _shard_map_mod.shard_map if hasattr(_shard_map_mod, "shard_map") else _shard_map_mod
-    )
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _raw_shard_map  # type: ignore
-
-# kwarg compat across the jax 0.4 -> 0.5+ rename: ``check_rep`` became
-# ``check_vma`` and ``axis_names`` was added.  Callers here use the NEW
-# spelling; translate (or drop) for older installed versions so the
-# per-shard transition kernels build everywhere.
-import inspect as _inspect
-
-_SM_PARAMS = frozenset(_inspect.signature(_raw_shard_map).parameters)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, axis_names=None, **kw):
-    if check_vma is not None:
-        if "check_vma" in _SM_PARAMS:
-            kw["check_vma"] = check_vma
-        elif "check_rep" in _SM_PARAMS:
-            kw["check_rep"] = check_vma
-    if axis_names is not None and "axis_names" in _SM_PARAMS:
-        # pre-rename jax (< 0.5) drops the partial-manual request: its
-        # ``auto=`` spelling exists but lowers partition-id collectives the
-        # SPMD partitioner rejects, so every axis goes manual inside the
-        # body there.  dmodule._constrain degrades its layout hints to
-        # no-ops in that regime (see _legacy_manual_axes) — same values,
-        # GSPMD just places the buffers without the explicit pins.
-        kw["axis_names"] = axis_names
-    return _raw_shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
 
 __all__ = [
     "mesh_all_reduce",

@@ -94,38 +94,6 @@ def _match(plan: Dict[str, Any], fqn: str) -> Tuple[Optional[str], Any]:
     return None, None
 
 
-def _abstract_mesh_ctx():
-    """The current abstract-mesh context, or None when there is none.
-
-    jax < 0.5 has no public ``jax.sharding.get_abstract_mesh`` (nor
-    ``AxisType``); there no abstract-mesh context can be entered, so the
-    concrete NamedSharding path below is always the right one."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        return None
-    ctx = get()
-    return ctx if getattr(ctx, "shape_tuple", None) else None
-
-
-def _legacy_manual_axes():
-    """Mesh axes bound as manual in the CURRENT trace on jax < 0.5.
-
-    Pre-rename jax has no abstract-mesh context and no reliable
-    partial-manual shard_map (collectives.shard_map drops ``axis_names``
-    there), so inside a shard_map body EVERY bound axis is manual.  The
-    legacy axis env is the only way to see that from here; empty outside
-    shard_map (and on jax >= 0.5, where _abstract_mesh_ctx answers
-    instead)."""
-    if getattr(jax.sharding, "get_abstract_mesh", None) is not None:
-        return frozenset()
-    try:
-        from jax._src.core import get_axis_env
-
-        return frozenset(get_axis_env().axis_sizes)
-    except (ImportError, AttributeError):  # pragma: no cover - other jaxes
-        return frozenset()
-
-
 def _constrain(x, placements, mesh: DeviceMesh):
     if placements is None or not isinstance(x, (jax.Array, jnp.ndarray)) or np.isscalar(x):
         return x
@@ -135,8 +103,8 @@ def _constrain(x, placements, mesh: DeviceMesh):
     # concrete NamedSharding would not match the context mesh — constrain
     # with the bare PartitionSpec so jax resolves it against the context,
     # dropping axes that are manual there (they're already local).
-    ctx = _abstract_mesh_ctx()
-    if ctx is not None:  # non-empty context mesh
+    ctx = jax.sharding.get_abstract_mesh()
+    if ctx.shape_tuple:  # non-empty context mesh
         manual = {
             n
             for n, t in zip(ctx.axis_names, ctx.axis_types)
@@ -151,12 +119,6 @@ def _constrain(x, placements, mesh: DeviceMesh):
             return None if entry in manual else entry
         spec = PartitionSpec(*(drop_manual(e) for e in spec))
         return jax.lax.with_sharding_constraint(x, spec)
-    # jax < 0.5 + inside shard_map: all bound axes are manual (no partial-
-    # manual there) and a concrete NamedSharding over them raises.  The
-    # constraint is a layout hint, never a semantics change — degrade to a
-    # no-op, the _constrain_auto precedent (pipe/spmd.py).
-    if _legacy_manual_axes():
-        return x
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh.jax_mesh, spec))
 
 

@@ -44,17 +44,16 @@ Contract points:
   * Telemetry: every dispatch decision increments
     ``kernel_dispatch_<name>_total`` (kernel path taken) or
     ``kernel_fallback_<name>_total`` (kernel requested but the XLA path
-    ran: off-TPU ``on``, pallas unavailable, unsupported shape), plus the
+    ran: off-TPU ``on``, unsupported shape), plus the
     ``kernel_dispatch_total`` / ``kernel_fallback_total`` aggregates.
     They ride the telemetry registry gate — a run that never calls
     ``telemetry.init()`` pays one dormant-branch check, nothing else —
     and render as the dashboard's ``kernels:`` block.
   * ``vescale-lint`` VSC206 bans direct ``pallas_call`` outside this
     package, so every kernel stays behind this contract.
-  * :func:`def_partition` is the jax-version compat shim for
-    ``custom_partitioning.def_partition`` shared by every custom-
-    partitioned op (kernel or XLA implementation — one partition rule per
-    op, not one per implementation).
+  * Every custom-partitioned op registers ONE partition rule covering its
+    kernel and XLA implementations (one rule per op, not one per
+    implementation).
 """
 
 from __future__ import annotations
@@ -67,8 +66,6 @@ __all__ = [
     "resolve",
     "record_dispatch",
     "record_fallback",
-    "def_partition",
-    "has_pallas",
     "on_tpu",
     "ulps_at_scale",
 ]
@@ -89,15 +86,6 @@ def mode() -> str:
     return m
 
 
-def has_pallas() -> bool:
-    try:  # pallas imports lazily-safe (TPU-only at compile time)
-        from jax.experimental import pallas as _pl  # noqa: F401
-
-        return True
-    except Exception:  # pragma: no cover
-        return False
-
-
 def on_tpu() -> bool:
     import jax
 
@@ -107,8 +95,8 @@ def on_tpu() -> bool:
 def resolve(name: str) -> Optional[bool]:
     """One-stop dispatch decision for kernel ``name``.
 
-    Returns ``None`` when the caller must take its XLA path (mode off, no
-    pallas, or ``on`` off-TPU), else the ``interpret=`` flag to pass to the
+    Returns ``None`` when the caller must take its XLA path (mode off, or
+    ``on`` off-TPU), else the ``interpret=`` flag to pass to the
     kernel (True under ``interpret`` mode, False for compiled-on-TPU).
     Counts the decision into the kernel telemetry (no-op while telemetry
     is dormant).  Call sites with their own late fallbacks (shape checks)
@@ -117,9 +105,6 @@ def resolve(name: str) -> Optional[bool]:
     """
     m = mode()
     if m == "off":
-        return None
-    if not has_pallas():
-        record_fallback(name)
         return None
     if m == "interpret":
         record_dispatch(name)
@@ -173,19 +158,3 @@ def ulps_at_scale(a, b) -> float:
         return 0.0
     step = float(np.spacing(np.float32(np.max(np.abs(b64[fin])) or 1.0)))
     return float(np.max(np.abs(a64[fin] - b64[fin])) / step)
-
-
-def def_partition(cp, **kwargs) -> None:
-    """``custom_partitioning.def_partition`` across jax versions: newer jax
-    grew ``sharding_rule`` (shardy) and ``need_replication_factors``; jax
-    0.4.x has neither.  Keyword args the installed signature doesn't accept
-    are dropped — the explicit ``partition``/``infer_sharding_from_operands``
-    callbacks (always passed) carry the same contract for GSPMD, so older
-    versions lose nothing but the shardy-path rule.  The same shim idea as
-    ``collectives.shard_map`` (check_vma/check_rep).  Shared by every
-    custom-partitioned op so the kernel and XLA implementations of one op
-    register ONE rule through one code path."""
-    import inspect as _inspect
-
-    params = frozenset(_inspect.signature(type(cp).def_partition).parameters)
-    cp.def_partition(**{k: v for k, v in kwargs.items() if k in params})

@@ -30,14 +30,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:  # pallas is TPU-only at runtime; import lazily-safe
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_xent_parts", "xent_blocks"]
 

@@ -19,17 +19,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-try:  # pallas is TPU-only at runtime; import lazily-safe
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
-    "_HAS_PALLAS",
     "_NEG_INF",
     "_use_streaming",
     "_flash_fwd_pallas",
@@ -83,16 +76,53 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, 
 
 # The resident kernels keep whole-(T, D) K/V (or Q/dO) blocks in VMEM —
 # fastest when they fit (one HBM fetch amortized over the whole inner loop).
-# Past this budget (scoped VMEM is ~16 MB; leave headroom for the compute
-# blocks) the streaming kernels walk the inner loop as a grid dimension with
-# fp32 scratch accumulators instead: VMEM O(block), HBM traffic O(T^2/block)
-# on the streamed side — the standard large-T flash trade.
-_VMEM_RESIDENT_BUDGET = 10 * 1024 * 1024
+# Where they do not fit the compiler's scoped-VMEM limit, the streaming
+# kernels walk the inner loop as a grid dimension with fp32 scratch
+# accumulators instead: VMEM O(block), HBM traffic O(T^2/block) on the
+# streamed side — the standard large-T flash trade.  The choice is made per
+# kernel (fwd, dq, dk/dv hold different blocks) from the bytes its resident
+# form holds in VMEM as the compiler lays them out.
+_VMEM_LIMIT_BYTES = 16 * 1024 * 1024  # Mosaic's scoped-VMEM limit for one kernel
 
 
-def _use_streaming(T: int, D: int, dtype) -> bool:
-    # two resident (T, D) arrays, double-buffered by the pipeline
-    return 4 * T * D * jnp.dtype(dtype).itemsize > _VMEM_RESIDENT_BUDGET
+def _vmem_bytes(rows: int, cols: int, dtype) -> int:
+    """VMEM footprint of one (rows, cols) block: the last dim is padded to
+    128 lanes and the rows to the dtype's sublane tile (8 x 32 bits), so a
+    (T, 1) fp32 block costs as much as a (T, 128) one."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 8 * max(1, 4 // itemsize)
+    return -(-rows // sublanes) * sublanes * -(-cols // 128) * 128 * itemsize
+
+
+# fp32 (block_q, block_k) tiles allowed for what one inner-loop step keeps
+# besides its operand blocks (scores, probabilities, dp, ds).  Read off the
+# compiler at 512 x 512 blocks: where it refused a kernel whose blocks alone
+# fit, its count exceeded them by at most 2.62 MB (fwd), 3.04 MB (dq) and
+# nothing (dk/dv, whose whole-T operands it does not all double-buffer).
+_WORK_TILES = {"fwd": 3, "dq": 3, "dkv": 2}
+
+
+def _resident_vmem_bytes(kernel: str, T: int, D: int, dtype, block_q: int, block_k: int,
+                         rep: int = 1) -> int:
+    """Scoped VMEM the resident form of ``kernel`` ("fwd", "dq" or "dkv")
+    needs: every BlockSpec operand double-buffered by the pipeline, plus
+    the score tiles of one inner-loop step.  An upper bound — for some
+    shapes the compiler gets by with less."""
+    row = lambda n, dt=dtype: _vmem_bytes(n, D, dt)
+    stat = lambda n: _vmem_bytes(n, 1, jnp.float32)  # lse / delta column
+    if kernel == "fwd":    # q, o blocks; k, v whole; lse out
+        blocks = 2 * row(block_q) + 2 * row(T) + stat(block_q)
+    elif kernel == "dq":   # q, do, dq blocks; k, v whole; lse, delta
+        blocks = 3 * row(block_q) + 2 * row(T) + 2 * stat(block_q)
+    else:                  # dkv: q, do, lse, delta whole; k, v, dk, dv blocks
+        acc = dtype if rep == 1 else jnp.float32
+        blocks = 2 * row(T) + 2 * stat(T) + 2 * row(block_k) + 2 * row(block_k, acc)
+    return 2 * blocks + _WORK_TILES[kernel] * _vmem_bytes(block_q, block_k, jnp.float32)
+
+
+def _use_streaming(kernel: str, T: int, D: int, dtype, block_q: int, block_k: int,
+                   rep: int = 1) -> bool:
+    return _resident_vmem_bytes(kernel, T, D, dtype, block_q, block_k, rep) > _VMEM_LIMIT_BYTES
 
 
 def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
@@ -151,7 +181,7 @@ def _flash_fwd_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret, H,
     BH, T, D = q3.shape
     rep = H // KV
     if streaming is None:
-        streaming = _use_streaming(T, D, k3.dtype)
+        streaming = _use_streaming("fwd", T, D, k3.dtype, block_q, block_k)
     kw = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k, seq_len=T)
     out_shape = (
         jax.ShapeDtypeStruct(q3.shape, q3.dtype),
@@ -376,18 +406,31 @@ def _dkv_kernel_stream(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, 
 
 def _flash_bwd_pallas(q3, k3, v3, o3, do3, lse, scale, causal, block_q, block_k, interpret, H, KV,
                       streaming=None):
+    """dq and dk/dv each run resident or streaming on their own VMEM count
+    (``streaming`` forces both, for tests)."""
     BH, T, D = q3.shape
     rep = H // KV
-    if streaming is None:
-        streaming = _use_streaming(T, D, k3.dtype)
-    if streaming:
-        return _flash_bwd_pallas_stream(
-            q3, k3, v3, o3, do3, lse, scale, causal, block_q, block_k, interpret, H, KV
-        )
-    kv_row = lambda b, i: ((b // H) * KV + (b % H) // rep, 0, 0)
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32), axis=-1, keepdims=True)  # (BH, T, 1)
     kw = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k, seq_len=T)
-    dq = pl.pallas_call(
+    args = (q3, k3, v3, do3, lse, delta)
+
+    def stream(kernel):
+        if streaming is not None:
+            return streaming
+        return _use_streaming(kernel, T, D, k3.dtype, block_q, block_k, rep)
+
+    dq = (_dq_stream if stream("dq") else _dq_resident)(args, kw, interpret, H, KV)
+    dk, dv = (_dkv_stream if stream("dkv") else _dkv_resident)(args, kw, interpret, H, KV)
+    return dq, dk, dv
+
+
+def _dq_resident(args, kw, interpret, H, KV):
+    q3 = args[0]
+    BH, T, D = q3.shape
+    rep = H // KV
+    block_q = kw["block_q"]
+    kv_row = lambda b, i: ((b // H) * KV + (b % H) // rep, 0, 0)
+    return pl.pallas_call(
         functools.partial(_dq_kernel, **kw),
         out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
         grid=(BH, T // block_q),
@@ -401,9 +444,16 @@ def _flash_bwd_pallas(q3, k3, v3, o3, do3, lse, scale, causal, block_q, block_k,
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         interpret=interpret,
-    )(q3, k3, v3, do3, lse, delta)
-    # dk/dv: kv-centric grid; q rows of group g are the consecutive
-    # [g*rep, (g+1)*rep) band, walked by the last grid dim
+    )(*args)
+
+
+def _dkv_resident(args, kw, interpret, H, KV):
+    """kv-centric grid; q rows of group g are the consecutive
+    [g*rep, (g+1)*rep) band, walked by the last grid dim."""
+    q3, k3, v3 = args[:3]
+    BH, T, D = q3.shape
+    rep = H // KV
+    block_k = kw["block_k"]
     q_row = lambda b, i, r: ((b // KV) * H + (b % KV) * rep + r, 0, 0)
     kv_blk = lambda b, i, r: (b, i, 0)
     acc_dtype = k3.dtype if rep == 1 else jnp.float32
@@ -427,22 +477,18 @@ def _flash_bwd_pallas(q3, k3, v3, o3, do3, lse, scale, causal, block_q, block_k,
             pl.BlockSpec((1, block_k, D), kv_blk),
         ),
         interpret=interpret,
-    )(q3, k3, v3, do3, lse, delta)
-    return dq, dk.astype(k3.dtype), dv.astype(v3.dtype)
+    )(*args)
+    return dk.astype(k3.dtype), dv.astype(v3.dtype)
 
 
-def _flash_bwd_pallas_stream(q3, k3, v3, o3, do3, lse, scale, causal, block_q, block_k,
-                             interpret, H, KV):
-    """Large-T backward: both kernels stream their inner loop as a grid dim
-    (VMEM O(block)); dk/dv accumulate the GQA group reduction in scratch so
-    outputs are native dtype directly."""
+def _dq_stream(args, kw, interpret, H, KV):
+    q3 = args[0]
     BH, T, D = q3.shape
     rep = H // KV
-    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32), axis=-1, keepdims=True)
-    kw = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k, seq_len=T)
+    block_q, block_k = kw["block_q"], kw["block_k"]
     kv_row_s = lambda b, i, j: ((b // H) * KV + (b % H) // rep, j, 0)
     q_blk_s = lambda b, i, j: (b, i, 0)
-    dq = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_dq_kernel_stream, **kw),
         out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
         grid=(BH, T // block_q, T // block_k),
@@ -457,11 +503,20 @@ def _flash_bwd_pallas_stream(q3, k3, v3, o3, do3, lse, scale, causal, block_q, b
         out_specs=pl.BlockSpec((1, block_q, D), q_blk_s),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
-    )(q3, k3, v3, do3, lse, delta)
+    )(*args)
+
+
+def _dkv_stream(args, kw, interpret, H, KV):
+    """dk/dv accumulate the GQA group reduction in scratch, so outputs are
+    native dtype directly."""
+    q3, k3, v3 = args[:3]
+    BH, T, D = q3.shape
+    rep = H // KV
+    block_q, block_k = kw["block_q"], kw["block_k"]
     # q rows of kv group g are the consecutive [g*rep, (g+1)*rep) band
     q_row_s = lambda b, ki, r, i: ((b // KV) * H + (b % KV) * rep + r, i, 0)
     kv_blk_s = lambda b, ki, r, i: (b, ki, 0)
-    dk, dv = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_dkv_kernel_stream, rep=rep, **kw),
         out_shape=(
             jax.ShapeDtypeStruct(k3.shape, k3.dtype),
@@ -485,5 +540,4 @@ def _flash_bwd_pallas_stream(q3, k3, v3, o3, do3, lse, scale, causal, block_q, b
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
-    )(q3, k3, v3, do3, lse, delta)
-    return dq, dk, dv
+    )(*args)

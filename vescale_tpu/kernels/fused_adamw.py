@@ -27,18 +27,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from . import def_partition
-
-try:  # pallas is TPU-only at runtime; import lazily-safe
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
-
-__all__ = ["fused_adamw_update"]
+__all__ = ["fused_adamw_update", "update_ulps_vs_float64"]
 
 # Flattened leaves are viewed as (rows, _LANES) and each grid step works a
 # (_SUB, _LANES) block: lane dim matches the TPU tile (…, 128) so nothing
@@ -113,8 +106,7 @@ def _fused_local(g, m, v, coef, *, b1, b2, eps, state_dtype, interpret):
 def _partitioned_fused(ndim, b1, b2, eps, state_dtype_name, interpret):
     """One custom_partitioning rule per (rank, hyperparams): elementwise,
     so every output follows the STATE leaf's sharding (m — the ZeRO
-    weight-update shard) and g/v are co-sharded to it.  Registered through
-    the shared :func:`kernels.def_partition` shim."""
+    weight-update shard) and g/v are co-sharded to it."""
     from jax.experimental.custom_partitioning import custom_partitioning
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -149,8 +141,7 @@ def _partitioned_fused(ndim, b1, b2, eps, state_dtype_name, interpret):
 
     dims = " ".join(f"a{i}" for i in range(ndim)) or "..."
     leaf = dims
-    def_partition(
-        fused,
+    fused.def_partition(
         partition=partition,
         infer_sharding_from_operands=infer,
         sharding_rule=f"{leaf}, {leaf}, {leaf}, c -> {leaf}, {leaf}, {leaf}",
@@ -169,3 +160,44 @@ def fused_adamw_update(g, m, v, c1, c2, *, b1, b2, eps, state_dtype, interpret):
         bool(interpret),
     )
     return fn(g, m, v, coef)
+
+
+def update_ulps_vs_float64(u, g, m, v, c1, c2, *, b1, b2, eps) -> float:
+    """The parity measure of the update ``u`` (tests/test_kernels.py,
+    chip_smoke.py): its worst distance, in fp32 ulps of each element, from
+    a float64 evaluation of ``(m' / c1) / (sqrt(v' / c2) + eps)``.
+
+    ``m' = b1*m + (1-b1)*g`` is two products into one add, so fp32 has
+    three roundings of it: both products rounded, or either one fused into
+    the add.  Where the products cancel these differ by tens of ulps of
+    the result, and a compiler picks per fusion (XLA:CPU copies the moment
+    into the fusion of every output and contracts each copy on its own).
+    So the reference is the interval the formula spans over those
+    roundings of ``m'`` and ``v'``, and the measure is how far ``u`` lies
+    outside it (0 inside).  ``g``/``m``/``v`` are the step's inputs and
+    ``c1``/``c2`` the fp32 bias corrections the step used."""
+    g, m, v = (np.asarray(x, np.float32).astype(np.float64) for x in (g, m, v))
+    c1, c2 = (float(np.asarray(c, np.float32)) for c in (c1, c2))
+
+    def r32(x):
+        return x.astype(np.float32).astype(np.float64)
+
+    def roundings(a, x, b, y):
+        # products of two fp32 values are exact in float64
+        p, q = float(np.float32(a)) * x, float(np.float32(b)) * y
+        return r32(r32(p) + r32(q)), r32(p + r32(q)), r32(r32(p) + q)
+
+    us = np.stack([
+        (mm / c1) / (np.sqrt(vv / c2) + eps)
+        for mm in roundings(b1, m, 1.0 - b1, g)
+        for vv in roundings(b2, v, 1.0 - b2, r32(g * g))
+    ])
+    u = np.asarray(u, np.float32)
+    if not np.array_equal(np.isfinite(u), np.isfinite(us[0])):
+        return float("inf")
+    fin = np.isfinite(u)
+    if not fin.any():
+        return 0.0
+    u64 = u[fin].astype(np.float64)
+    outside = np.maximum(np.maximum(us.min(0)[fin] - u64, u64 - us.max(0)[fin]), 0.0)
+    return float(np.max(outside / np.spacing(np.abs(u[fin]))))

@@ -36,14 +36,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-try:  # pallas is TPU-only at runtime; import lazily-safe
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_decode"]
 

@@ -118,7 +118,7 @@ def _xent_kernel_mode(shard_v: int, logits) -> Optional[bool]:
     n_rows = 1
     for d in logits.shape[:-1]:
         n_rows *= int(d)
-    ok = _kernels.has_pallas() and (kmode == "interpret" or _kernels.on_tpu())
+    ok = kmode == "interpret" or _kernels.on_tpu()
     if not ok or xent_blocks(n_rows, shard_v) is None:
         _kernels.record_fallback("fused_xent")
         return None

@@ -34,9 +34,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..kernels import def_partition as _def_partition_shim
 from ..kernels.flash_attention import (  # noqa: F401  (re-exported for tests)
-    _HAS_PALLAS,
     _NEG_INF,
     _flash_bwd_pallas,
     _flash_fwd_pallas,
@@ -156,11 +154,9 @@ def _bwd_4d(q, k, v, o, do, lse, scale, causal, block_q, block_k, interpret, imp
 # jit+mesh model code* keeps the fused kernel (the shard_map wrapper below
 # remains for explicit use).  The rule is defined ONCE per op and carries
 # both implementations via the ``impl`` leg — the XLA fallback of an enabled
-# kernel mode partitions exactly like the kernel, through the shared
-# ``kernels.def_partition`` version shim.  Seq-sharded inputs are
+# kernel mode partitions exactly like the kernel.  Seq-sharded inputs are
 # all-gathered by the need_replication factors; long-context seq sharding
 # belongs to ring/ulysses (parallel/context.py) instead.
-_def_partition = _def_partition_shim  # back-compat alias (pre-kernels name)
 
 
 def _batch_head_axes(mesh, arg_shapes):
@@ -214,8 +210,7 @@ def _partitioned_fwd(scale, causal, block_q, block_k, interpret, impl):
         # needs tp | KV, which every llama/mixtral plan in-tree satisfies
         return mesh, lower, (qsh, lsh), (qsh, qsh, qsh)
 
-    _def_partition(
-        fwd,
+    fwd.def_partition(
         partition=partition,
         infer_sharding_from_operands=infer,
         sharding_rule="b t h d, b t g d, b t g d -> b t h d, b h t",
@@ -248,8 +243,7 @@ def _partitioned_bwd(scale, causal, block_q, block_k, interpret, impl):
 
         return mesh, lower, (qsh, qsh, qsh), (qsh, qsh, qsh, qsh, qsh, lsh)
 
-    _def_partition(
-        bwd,
+    bwd.def_partition(
         partition=partition,
         infer_sharding_from_operands=infer,
         sharding_rule=(
@@ -323,7 +317,7 @@ def flash_attention(
         _kernels.record_fallback("flash_attention")
         return _flash(q, k, v, scale, causal, 0, 0, False, "xla")
 
-    if not _HAS_PALLAS or (not on_tpu and not interpret):
+    if not on_tpu and not interpret:
         return _xla_fallback()
 
     def fit(block: int) -> int:

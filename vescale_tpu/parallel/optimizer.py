@@ -166,19 +166,22 @@ def clip_grad_norm_fp32(grads, max_norm: float, norm_type: int = 2):
     return jax.tree_util.tree_map(lambda g: (g.astype(jnp.float32) * scale).astype(g.dtype), grads), total
 
 
-def _optimizer_step_span():
+def _optimizer_step_span(grads):
     """ndtimeline OPTIMIZER_STEP span for EAGER optimizer steps only.
 
     ``step`` is usually traced inside the jitted train step, where a host
     span would bracket trace time once and then never fire — the in-jit
     device work belongs to the XLA profiler.  Eager call sites (the pipe
-    engine's update loop, examples, debugging) get a real span."""
+    engine's update loop, examples, debugging) get a real span.  A step is
+    being traced exactly when its ``grads`` are tracers."""
     import contextlib
 
     from ..ndtimeline.api import is_active, ndtimeit
     from ..ndtimeline.predefined import OPTIMIZER_STEP
 
-    if is_active() and jax.core.trace_state_clean():
+    if is_active() and not any(
+        isinstance(g, jax.core.Tracer) for g in jax.tree_util.tree_leaves(grads)
+    ):
         return ndtimeit(OPTIMIZER_STEP)
     return contextlib.nullcontext()
 
@@ -198,7 +201,7 @@ class BasicOptimizer:
         return _memtrack.tag_tree(self.tx.init(params), "optimizer_state")
 
     def step(self, params, opt_state, grads):
-        with _optimizer_step_span():
+        with _optimizer_step_span(grads):
             if self.grad_clip is not None:
                 grads, _ = clip_grad_norm_fp32(grads, self.grad_clip)
             updates, opt_state = self.tx.update(grads, opt_state, params)
@@ -368,7 +371,7 @@ class DistributedOptimizer:
         """copy grads -> fp32, unscale, clip, inner step on fp32 master
         shards, copy master -> model params (reference step/:1142-1223
         pipeline); overflow -> skip + scale backoff."""
-        with _optimizer_step_span():
+        with _optimizer_step_span(grads):
             return self._step_impl(params, opt_state, grads)
 
     def _step_impl(self, params, opt_state, grads):

@@ -35,10 +35,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax.core import Literal
-except ImportError:  # pragma: no cover - older/newer jax layouts
-    from jax._src.core import Literal
+from jax.extend.core import Literal
 
 from ..plan import PipelineParallelPlan
 from .pipe_stage import _cuts_by_weight

@@ -164,17 +164,8 @@ def _constrain_auto(z, auto_act_spec: Optional[P], lead: int = 0):
     A bare PartitionSpec resolves against the CONTEXT mesh, whose axis
     types are (Manual, Auto, ...) here — a NamedSharding built from the
     concrete mesh would carry all-Auto types and trip the context-mesh
-    check when sharding propagates (zeros_like etc.).
-
-    jax < 0.5 compat: without the abstract-mesh context machinery
-    (``jax.sharding.get_abstract_mesh``) a bare-PartitionSpec constraint
-    inside shard_map has no mesh to resolve against and raises — there the
-    pin degrades to a no-op (it is a memory-LAYOUT knob, never a semantics
-    change: the parity test asserts identical values either way; GSPMD
-    still places the buffers, just without the explicit hint)."""
+    check when sharding propagates (zeros_like etc.)."""
     if auto_act_spec is None:
-        return z
-    if getattr(jax.sharding, "get_abstract_mesh", None) is None:
         return z
     spec = P(*((None,) * lead + tuple(auto_act_spec)))
     return jax.lax.with_sharding_constraint(z, spec)

@@ -76,8 +76,8 @@ __all__ = [
 class Decline:
     """A structured planner decline: a stable ``VSC12x`` code from the
     shared findings vocabulary (analysis/findings.py) + the human reason.
-    Replaces the free-form reason strings: ``_warn_fallback``, shardcheck's
-    VSC106 and docs/known_failures.md all key on ``code``."""
+    Replaces the free-form reason strings: ``_warn_fallback`` and
+    shardcheck's VSC106 both key on ``code``."""
 
     code: str  # "VSC120".."VSC126"
     message: str

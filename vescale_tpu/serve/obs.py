@@ -28,7 +28,7 @@ must not require a metrics pipeline.
 from __future__ import annotations
 
 import time
-from typing import Dict, Optional
+from typing import Any, Dict, Optional
 
 __all__ = [
     "ServeObservability",
@@ -48,6 +48,8 @@ __all__ = [
     "FLEET_REPLICA_FIELDS_V1",
     "FLEET_REPLICA_FIELDS_V2",
 ]
+
+_UNSET = object()  # _peak's not-yet-looked-up sentinel
 
 ROUTER_SCHEMA_VERSION = 5
 # the frozen /router v1 field set: the freeze contract says fields are
@@ -222,7 +224,7 @@ class ServeObservability:
         self.decode_steps = 0
         self._start = time.perf_counter()
         self._last_decode: Optional[float] = None
-        self._peak: Optional[float] = None
+        self._peak: Any = _UNSET  # published peak FLOP/s, looked up once
         self._last_mfu: Optional[float] = None
         # the MFU numerator needs a one-time AOT lower+compile of the
         # decode program: pay it HERE, before the loop serves anything,
@@ -240,16 +242,18 @@ class ServeObservability:
         fn = getattr(self.engine, "decode_flops_per_step", None)
         return fn() if fn is not None else None
 
-    def _peak_flops(self) -> float:
-        if self._peak is None:
+    def _peak_flops(self) -> Optional[float]:
+        """Published peak of the serving chip; None (MFU stays unpublished)
+        on a device ``calibrate.DEVICE_PEAKS`` does not list."""
+        if self._peak is _UNSET:
+            import jax
+
+            from ..telemetry.calibrate import device_peak_flops
+
             try:
-                import jax
-
-                from ..telemetry.calibrate import device_peak_flops
-
                 self._peak = device_peak_flops(jax.devices()[0])
-            except Exception:
-                self._peak = 1e12
+            except ValueError:
+                self._peak = None
         return self._peak
 
     def calibrated_step_estimate(self) -> Optional[float]:
@@ -306,8 +310,9 @@ class ServeObservability:
             # fiction — publish null (the documented "unavailable" value)
             # rather than an understated gauge
             flops = self._flops() if self.speculative is None else None
-            if flops and dt_s > 0:
-                self._last_mfu = flops / dt_s / self._peak_flops()
+            peak = self._peak_flops()
+            if flops and peak and dt_s > 0:
+                self._last_mfu = flops / dt_s / peak
                 _tel.set_gauge("serve_mfu", self._last_mfu)
 
     # --------------------------------------------------------- providers

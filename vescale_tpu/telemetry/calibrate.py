@@ -61,6 +61,8 @@ __all__ = [
     "compute_cost_us",
     "active_digest",
     "hop_latency_us",
+    "DEVICE_PEAKS",
+    "device_peaks",
     "device_peak_flops",
     "clear_warned",
     "TABLE_FILENAME",
@@ -589,28 +591,32 @@ def hop_latency_us() -> float:
     return t.launch_us() if t is not None else DEFAULT_LAUNCH_US
 
 
+# Published peaks of one chip, keyed by the ``device_kind`` string jax
+# reports for it.  A device that is not here has no peak: callers get an
+# error, never a default.
+DEVICE_PEAKS: Dict[str, Dict[str, object]] = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "hbm_gbps": 819.0,
+        "source": 'Google Cloud documentation, "TPU v5e"',
+    },
+}
+
+
+def device_peaks(device) -> Dict[str, object]:
+    """The :data:`DEVICE_PEAKS` row of ``device`` (anything with a
+    ``device_kind``); an unknown device raises."""
+    kind = getattr(device, "device_kind", None)
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak for device_kind {kind!r} (known: {sorted(DEVICE_PEAKS)}); "
+            "add it to telemetry.calibrate.DEVICE_PEAKS with its source"
+        ) from None
+
+
 def device_peak_flops(device) -> float:
     """Peak (bf16 matmul) FLOP/s of one accelerator chip — the MFU
-    denominator shared by the bench harness and the serve MFU gauge.  TPU
-    generations come from the datasheet table; any other platform prefers
-    the active calibration table's MEASURED ``matmul_gflops`` (an honest
-    achievable-peak on CPU rigs) and falls back to 1e12 so an MFU line
-    still prints rather than dividing by an unknown."""
-    kind = getattr(device, "device_kind", "").lower()
-    plat = getattr(device, "platform", "").lower()
-    if "v6" in kind:
-        return 918e12  # v6e (Trillium) bf16
-    if "v5p" in kind:
-        return 459e12
-    if "v5" in kind or "lite" in kind:
-        return 197e12  # v5e bf16
-    if "v4" in kind:
-        return 275e12
-    if plat == "tpu":
-        return 197e12
-    t = active_table()
-    if t is not None:
-        g = t.meta.get("matmul_gflops")
-        if g:
-            return float(g) * 1e9
-    return 1e12
+    denominator shared by the bench harness and the serve MFU gauge."""
+    return float(device_peaks(device)["bf16_flops"])

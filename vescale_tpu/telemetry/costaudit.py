@@ -542,23 +542,11 @@ _MATMUL_OPCODES = ("dot", "convolution")
 
 
 def device_mem_gbps(device) -> float:
-    """HBM bandwidth (GB/s) of one chip — the roofline's memory roof.  TPU
-    generations from the datasheet; any other platform gets a host-DRAM
-    ballpark so a CPU rig still classifies rather than dividing by an
-    unknown."""
-    kind = getattr(device, "device_kind", "").lower()
-    plat = getattr(device, "platform", "").lower()
-    if "v6" in kind:
-        return 1640.0  # Trillium
-    if "v5p" in kind:
-        return 2765.0
-    if "v5" in kind or "lite" in kind:
-        return 819.0  # v5e
-    if "v4" in kind:
-        return 1228.0
-    if plat == "tpu":
-        return 819.0
-    return 50.0
+    """HBM bandwidth (GB/s) of one chip — the roofline's memory roof, from
+    the same table as the compute peak (``calibrate.DEVICE_PEAKS``)."""
+    from . import calibrate as _cal
+
+    return float(_cal.device_peaks(device)["hbm_gbps"])
 
 
 def _layer_of(op_name: str) -> str:

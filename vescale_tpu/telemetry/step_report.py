@@ -27,14 +27,12 @@ __all__ = ["build_step_report", "write_step_report", "read_step_report"]
 
 
 def _cost_dict(compiled) -> Dict[str, Any]:
-    """Normalize ``compiled.cost_analysis()`` across jax versions (dict on
-    new, list-of-dict per partition on older)."""
+    """``compiled.cost_analysis()`` as a dict; empty where the backend
+    reports none."""
     try:
         cost = compiled.cost_analysis()
     except Exception:
         return {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     return dict(cost) if cost else {}
 
 
