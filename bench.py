@@ -803,14 +803,6 @@ def bench_serve():
     itl_p99 = sched._itl.percentile(0.99)
     shed_rate = sched.counts["shed"] / max(1, sched.counts["submitted"])
     step_real = _median(bare_iters)
-    # serve MFU: compiled decode program FLOPs over the measured step
-    from vescale_tpu.telemetry.calibrate import device_peak_flops
-
-    decode_flops = engine.decode_flops_per_step()
-    serve_mfu = (
-        round(decode_flops / step_real / device_peak_flops(devices[0]), 6)
-        if decode_flops and step_real > 0 else None
-    )
 
     # ---------------------------- throughput multipliers (ISSUE 15)
     # (a) shared-prefix workload leg: the SAME load with the radix-tree
@@ -956,7 +948,6 @@ def bench_serve():
         "goodput_fraction": round(goodput_tokens / max(1, gen_tokens), 4),
         "itl_p50_ms": round(itl_p50 * 1e3, 3) if itl_p50 else None,
         "itl_p99_ms": round(itl_p99 * 1e3, 3) if itl_p99 else None,
-        "serve_mfu": serve_mfu,
         # throughput multipliers (ISSUE 15): shared-prefix + spec-decode legs
         "prefix_savings_frac": round(prefix_savings, 4),
         "prefix_hit_tokens": pc.stats.hit_tokens,

@@ -94,6 +94,60 @@ class Tracer:
         return xplane.summarize(xplane.load(max(files, key=os.path.getmtime)))
 
 
+class SessionTracer:
+    """``--trace 2``: once the measured window has closed and its numbers are
+    taken, one start and stop of the profiler that is thrown away (the first
+    start costs most, and falls into no number), then the program's own trace
+    session (``vescale_tpu.ndtimeline.api.start_trace_session``: profiler,
+    ``vs.*`` spans, counters) over ``TRACE_SECONDS`` of the same traffic.
+    Nothing here is imported, started or allocated before ``begin``."""
+
+    def __init__(self, spec: CellSpec):
+        self.dir = os.path.join(spec.out_dir(), "trace", spec.name)
+        self.started: Optional[float] = None
+        self.stopped: Optional[float] = None
+        self.result: Any = None
+        self.cost_s: Dict[str, float] = {}     # what starting and stopping took, for the [bm] line
+
+    def begin(self) -> None:
+        import jax
+
+        from vescale_tpu.ndtimeline import api as nd
+
+        t0 = time.perf_counter()
+        shutil.rmtree(self.dir, ignore_errors=True)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0     # as the session starts it
+        jax.profiler.start_trace(os.path.join(self.dir, "first"), profiler_options=options)
+        jax.profiler.stop_trace()
+        shutil.rmtree(self.dir, ignore_errors=True)
+        t1 = time.perf_counter()
+        nd.start_trace_session(self.dir)
+        self.started = time.perf_counter()
+        self.cost_s.update(first_start_and_stop=t1 - t0, session_start=self.started - t1)
+
+    def due(self, now: float) -> bool:
+        return self.started is not None and now >= self.started + TRACE_SECONDS
+
+    def end(self):
+        """Stop the session; the trace is read (the session does it, for its
+        clock offset) and deleted at once.  Returns what the session returned."""
+        from vescale_tpu.ndtimeline import api as nd
+
+        t0 = time.perf_counter()
+        self.result = nd.stop_trace_session()
+        self.stopped = time.perf_counter()
+        shutil.rmtree(self.dir, ignore_errors=True)
+        self.cost_s["session_stop_and_load"] = self.stopped - t0
+        return self.result
+
+    def summary(self) -> Optional[Dict[str, Any]]:
+        """The same reduction a ``--trace 1`` run gets, of the session's trace."""
+        if self.result is None or self.result.profile is None:
+            return None
+        return xplane.summarize(self.result.profile)
+
+
 def annotate(name: str):
     """The benchmark's own span around a call into a layer (shows in the
     profiler's host lines; free while no trace is running)."""
@@ -153,17 +207,29 @@ def memory_in_use_bytes(devices) -> int:
 
 
 def result_object(spec: CellSpec, run: RunRecord, devices, *, correct: bool, attempted: int, failed: int,
-                  traced: bool) -> Dict[str, Any]:
-    """The contract's result: end-to-end metrics untraced, per-layer traced.
-    The readers are those of the cell's own checkout (``spec.root``)."""
+                  traced: int) -> Dict[str, Any]:
+    """The contract's result: end-to-end metrics untraced (``traced`` 0),
+    per-layer traced (1), both side by side where the run measured first and
+    traced afterwards (2).  The readers are those of the cell's own checkout
+    (``spec.root``)."""
+    metrics: Dict[str, Dict[str, Any]] = {}
+    if traced != 1:
+        metrics.update(read_metrics(os.path.join(spec.root, "benchmark", "e2e_metrics"), spec.end_to_end, run))
     if traced:
-        metrics = read_metrics(os.path.join(spec.root, "benchmark", "layer_metrics"), spec.per_layer, run)
-    else:
-        metrics = read_metrics(os.path.join(spec.root, "benchmark", "e2e_metrics"), spec.end_to_end, run)
+        metrics.update(read_metrics(os.path.join(spec.root, "benchmark", "layer_metrics"), spec.per_layer, run))
     out = {"correct": bool(correct), "attempted": int(attempted), "failed": int(failed),
            "metrics": metrics, "device": device_block(devices, run)}
     if traced and run.trace is not None:
         out["breakdown"] = {"device_ops": run.trace["device_ops"][:10], "idle_gaps": run.trace["idle_gaps"][:10]}
+    if traced == 2:
+        from benchmark.layer_metrics import _session
+
+        # memory_peak_bytes stays what mode 0 reports (read as the window closed); the reference check and the
+        # session allocate after that, and their peak goes under a key of its own
+        out["device"]["memory_peak_bytes_run"] = int(max(run.memory_peak_bytes, run.memory_peak_bytes_run))
+        session = _session.reduced(run)
+        if session is not None:      # gaps named by the program's own span first, and the decode gap's split
+            out.setdefault("breakdown", {}).update(_session.breakdown(session))
     return out
 
 

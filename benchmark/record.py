@@ -34,6 +34,16 @@ class RunRecord:
     memory_peak_bytes: int = 0
     compile_times: List[float] = dataclasses.field(default_factory=list)  # instants of backend compiles
     trace: Optional[Dict[str, Any]] = None      # benchmark.xplane.summarize(), traced runs only
+    # what the kernel counted over the measured window for the thread that drives the chip and for the
+    # machine (vescale_tpu.telemetry.host_sched_delta of two reads, at the window's two ends); every mode
+    host_sched: Optional[Dict[str, Any]] = None
+    # ---- --trace 2: the seconds traced after the window
+    session: Any = None             # what the program's stop_trace_session returned: .xplane_path, .spans,
+                                    # .counters, .clock_offset_ns / .to_trace_ns(), .profile (the loaded trace)
+    traced_window: Optional[Tuple[float, float]] = None   # the session's start and stop on this record's clock
+    memory_peak_bytes_run: int = 0  # the peak at the run's end (memory_peak_bytes is read as the window closes)
+    traced_steps: List[Tuple[float, float, float, float]] = dataclasses.field(default_factory=list)  # train: t0,
+                                    # batch ready, step call returned, step done, of the steps under the session
     # ---- train
     tokens_per_step: int = 0
     flops_per_token: float = 0.0

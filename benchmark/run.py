@@ -1,6 +1,11 @@
 """The benchmark's one command:
 
-    python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+    python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1|2>
+
+``--trace 0`` measures; ``--trace 1`` puts the window's last seconds under the
+profiler and prints the per-layer metrics; ``--trace 2`` does what 0 does until
+the window has closed, then traces a few seconds of the same traffic through
+the program's own trace session and prints both kinds of metric.
 
 One process, no child.  It needs the chips the cell asks for: without a TPU,
 or with fewer chips, it exits non-zero and prints no result.  The last line of
@@ -33,7 +38,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     args = ap.parse_args(argv)
     if args.seconds <= 0:
         ap.error("--seconds must be positive")
@@ -66,21 +71,26 @@ def main(argv=None) -> int:
     devices = devices[: spec.chips]
 
     from benchmark import serve_cell, train_cell
-    from benchmark.harness import read_metrics, result_object
+    from benchmark.harness import memory_peak_bytes, read_metrics, result_object
 
     runner = {"train": train_cell.run_cell, "serve": serve_cell.run_cell}[spec.kind]
     log(workload=spec.name, config=spec.config_name, traffic=spec.traffic_name, seed=args.seed,
         seconds=args.seconds, trace=args.trace, device=devices[0].device_kind, chips=len(devices),
         compile_cache=cache_dir or os.environ.get("JAX_COMPILATION_CACHE_DIR"))
     rec, correct, attempted, failed, notes = runner(
-        spec, devices, args.seed, args.seconds, bool(args.trace), PROCESS_START)
+        spec, devices, args.seed, args.seconds, args.trace, PROCESS_START)
+    if args.trace == 2:
+        rec.memory_peak_bytes_run = memory_peak_bytes(devices)
     log(setup_s=rec.setup_s, total_s=time.perf_counter() - PROCESS_START, **notes)
     # what the readers can read without a trace, in every run (the result line holds one family only)
+    runq_ns = (rec.host_sched or {}).get("thread_runq_wait_ns")
     layer_dir, e2e_dir = (os.path.join(spec.root, "benchmark", d) for d in ("layer_metrics", "e2e_metrics"))
     log(end_to_end={k: v["value"] for k, v in read_metrics(e2e_dir, spec.end_to_end, rec).items()},
-        per_layer={k: v["value"] for k, v in read_metrics(layer_dir, spec.per_layer, rec).items()})
+        per_layer={k: v["value"] for k, v in read_metrics(layer_dir, spec.per_layer, rec).items()},
+        # the kernel's count for the window; null where it counts nothing (gVisor), so BENCHMARK.json lists no such metric
+        host_runq_wait_ms=None if runq_ns is None else runq_ns / 1e6)
     result = result_object(spec, rec, devices, correct=correct, attempted=attempted, failed=failed,
-                           traced=bool(args.trace))
+                           traced=args.trace)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -12,6 +12,7 @@ per-token times on one clock, and puts its own annotations (``bm.prefill``,
 
 from __future__ import annotations
 
+import dataclasses
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -19,7 +20,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from . import reference, stats, trafficgen
-from .harness import CompileCounter, Tracer, annotate, memory_in_use_bytes, memory_peak_bytes, wait_until
+from .harness import (TRACE_SECONDS, CompileCounter, SessionTracer, Tracer, annotate, memory_in_use_bytes, memory_peak_bytes,
+                      wait_until)
 from .record import RequestRecord, RunRecord
 from .spec import CellSpec, llama_config
 
@@ -35,6 +37,12 @@ LOGITS_TOLERANCE = 4e-2
 CHECK_PROMPT_TOKENS = 320
 CHECK_DECODE_STEPS = 4
 WINDOW_CLOSED = "benchmark window closed"
+# --trace 2: seconds of open-loop arrivals planned past the window (the
+# profiler's first start, the traced seconds, and room to spare), and how long
+# the traced seconds may stretch while they have not yet seen a prefill (the
+# per-layer metrics of prefill need one)
+EXTENSION_S = 20.0
+TRACE_STRETCH = 3.0
 
 
 class ServeCell:
@@ -47,6 +55,7 @@ class ServeCell:
         self.devices = list(devices[:1])
         self._rec: Optional[RunRecord] = None
         self._sched = None
+        self.window_notes: Dict[str, Any] = {}    # for the [bm] line: what on_step saw as the window closed
 
     # ------------------------------------------------------------- set-up
     def build(self, seed: int) -> None:
@@ -113,10 +122,15 @@ class ServeCell:
         engine.prefill, engine.decode = timed_prefill, timed_decode
 
     # ---------------------------------------------------------------- run
-    def run(self, traffic: Dict[str, Any], seed: int, seconds: float, *, traced: bool,
+    def run(self, traffic: Dict[str, Any], seed: int, seconds: float, *, traced: int,
             compiles: CompileCounter, setup_from: float) -> RunRecord:
         """One lead-in and one window of ``traffic``.  ``setup_from`` is the
-        instant set-up is counted from (the process's start)."""
+        instant set-up is counted from (the process's start).  ``traced`` is
+        the command's ``--trace``; with 2 the window's plan is that of 0 to
+        the letter, and the traffic carries on past it under the program's
+        trace session."""
+        from vescale_tpu import telemetry
+        from vescale_tpu.ndtimeline import api as ndtimeline
         from vescale_tpu.resilience.preempt import PreemptionHandler
         from vescale_tpu.serve import ContinuousBatchingScheduler, Request, run_serve_resilient
         from vescale_tpu.serve.fleet import RequestInbox
@@ -124,6 +138,15 @@ class ServeCell:
         kind = traffic["kind"]
         if kind == "open_loop":
             planned = trafficgen.open_loop_requests(traffic, seed, seconds, self.vocab)
+            if traced == 2:
+                # the window's plan stays as it is; a second plan of the same mix, from a
+                # sub-seed of its own, is shifted past the window and appended
+                past = float(traffic["lead_in_s"]) + float(seconds)
+                more = trafficgen.open_loop_requests(
+                    dict(traffic, lead_in_s=0.0), int(np.random.default_rng([int(seed), 7]).integers(1 << 31)),
+                    EXTENSION_S, self.vocab)
+                planned = planned + [dataclasses.replace(p, rid=len(planned) + p.rid, due_s=past + p.due_s)
+                                     for p in more]
         elif kind == "closed_loop":
             planned = trafficgen.closed_loop_requests(traffic, seed, self.vocab)
             done_shares = trafficgen.first_wave_done_shares(traffic, seed)
@@ -135,7 +158,9 @@ class ServeCell:
         rec = RunRecord(kind="serve", chips=1, traffic_kind=kind, slots=self.cache.num_slots,
                         padded_prompt_len=self.cache.max_seq_len, device_kind=self.devices[0].device_kind)
         inbox, handler, stop = RequestInbox(), PreemptionHandler(), threading.Event()
-        tracer = Tracer(self.spec, traced, seconds)
+        tracer = Tracer(self.spec, traced == 1, seconds)
+        after = SessionTracer(self.spec) if traced == 2 else None
+        sched_open: Optional[Dict[str, Any]] = None
         completed_now: List[int] = []
 
         record_token, complete = sched.record_token, sched.complete
@@ -186,20 +211,42 @@ class ServeCell:
         closing = False
 
         def on_step(_step: int, active: int) -> None:
-            nonlocal closing
+            nonlocal closing, sched_open
             now = time.perf_counter()
             rec.loop_steps.append((now, active, len(sched.queue)))
             if closing:
                 return
+            if sched_open is None and now >= window[0]:
+                sched_open = telemetry.host_sched_stats()     # the loop's own thread, as the window opens
             if kind == "closed_loop":
                 for _rid in completed_now:
-                    if now < window[1]:
+                    if now < window[1] or (after is not None and after.stopped is None):
                         closed_loop_next()
                 completed_now.clear()
             tracer.maybe_start(now, window[1])
             if now < window[1]:
                 return
+            if rec.host_sched is None:
+                # the window has closed: what is read at its close is read here, before anything is traced
+                closed = telemetry.host_sched_stats()
+                rec.host_sched = telemetry.host_sched_delta(sched_open or closed, closed)
+                self.window_notes = {
+                    "host_sched_in_window": rec.host_sched,
+                    "program_tracing_in_window": {"ndtimeline": ndtimeline.is_active(),
+                                                  "telemetry": telemetry.is_active()}}
+                if after is not None:
+                    rec.memory_peak_bytes = memory_peak_bytes(self.devices)
+                    rec.compile_times = list(compiles.times)
+                    after.begin()
+                    return
             tracer.maybe_stop(now, window[1])
+            if after is not None and after.stopped is None:
+                seen_a_prefill = any(p[0] >= after.started for p in rec.prefills[-8:])
+                if not after.due(now) or (not seen_a_prefill and now < after.started + TRACE_STRETCH * TRACE_SECONDS):
+                    return
+                rec.session = after.end()
+                rec.traced_window = (after.started, after.stopped)
+                self.window_notes["session_cost_s"] = after.cost_s
             for rid, out in sched.outcomes.items():     # a shed request is answered: it failed
                 if rid in rec.requests and rec.requests[rid].status is None:
                     rec.requests[rid].status = out["status"]
@@ -233,12 +280,13 @@ class ServeCell:
             self._rec = self._sched = None
         if generator is not None and generator.is_alive():
             raise RuntimeError("the load generator did not stop")
-        rec.memory_peak_bytes = memory_peak_bytes(self.devices)
-        rec.compile_times = list(compiles.times)
+        if after is None:
+            rec.memory_peak_bytes = memory_peak_bytes(self.devices)
+            rec.compile_times = list(compiles.times)
         for rid, out in sched.outcomes.items():
             if rid in rec.requests:
                 rec.requests[rid].status = out["status"]
-        rec.trace = tracer.summary()
+        rec.trace = tracer.summary() if after is None else after.summary()
         self.last_scheduler = sched
         return rec
 
@@ -299,7 +347,7 @@ class ServeCell:
         return len(sent), sum(1 for r in sent if r.status in ("shed", "failed"))
 
 
-def run_cell(spec: CellSpec, devices, seed: int, seconds: float, traced: bool, setup_from: float):
+def run_cell(spec: CellSpec, devices, seed: int, seconds: float, traced: int, setup_from: float):
     """The whole of a serve cell's run: (record, correct, attempted, failed, notes)."""
     compiles = CompileCounter().install()
     try:
@@ -322,5 +370,11 @@ def run_cell(spec: CellSpec, devices, seed: int, seconds: float, traced: bool, s
              "token_gaps": {"n": len(gaps), "share_holding_a_prefill": holds(1), "share_holding_two": holds(2)},
              "decode_ms_p1_p50_p99": decode_ms, "prefill_ms_p1_p50_p99": prefill_ms,
              "memory_in_use_bytes_at_close": memory_in_use_bytes(cell.devices),
-             "slowest_decodes_ms_at_s": [[round(d * 1e3, 2), round(at, 2)] for d, at in slowest]}
+             "slowest_decodes_ms_at_s": [[round(d * 1e3, 2), round(at, 2)] for d, at in slowest],
+             **cell.window_notes}
+    if rec.session is not None:     # what the session costs while it is on: the calls under it against the window's
+        under = lambda spans: stats.ms(stats.percentile(
+            [x[1] - x[0] for x in spans if stats.in_window(x[0], rec.traced_window)], 50))
+        notes.update(traced_decode_ms_p50=under(rec.decodes), traced_prefill_ms_p50=under(rec.prefills),
+                     session_counters=rec.session.counters, session_clock_offset_ns=rec.session.clock_offset_ns)
     return rec, correct, attempted, failed, notes

@@ -13,8 +13,8 @@ from typing import List
 
 import numpy as np
 
-from . import flops, reference, trafficgen
-from .harness import CompileCounter, Tracer, annotate, memory_in_use_bytes, memory_peak_bytes
+from . import flops, reference, stats, trafficgen
+from .harness import CompileCounter, SessionTracer, Tracer, annotate, memory_in_use_bytes, memory_peak_bytes
 from .record import RunRecord
 from .spec import CellSpec, device_peaks, llama_config
 
@@ -52,16 +52,21 @@ def _check_losses(losses: List[float], vocab: int) -> List[str]:
     return problems
 
 
-def run_cell(spec: CellSpec, devices, seed: int, seconds: float, traced: bool, setup_from: float):
-    """The whole of a train cell's run: (record, correct, attempted, failed, notes)."""
+def run_cell(spec: CellSpec, devices, seed: int, seconds: float, traced: int, setup_from: float):
+    """The whole of a train cell's run: (record, correct, attempted, failed, notes).
+    ``traced`` is the command's ``--trace``: 0, 1 (the window's last seconds
+    under the profiler) or 2 (the window as 0 has it, then the loop runs on
+    under the program's trace session)."""
     import jax
     import jax.numpy as jnp
 
+    from vescale_tpu import telemetry
     from vescale_tpu.data import TokenDataLoader
     from vescale_tpu.dmodule import parallelize_module
     from vescale_tpu.mesh import DeviceMesh
     from vescale_tpu.models.llama import Llama, llama_plan
     from vescale_tpu.models.nanogpt import cross_entropy_loss
+    from vescale_tpu.ndtimeline import api as ndtimeline
     from vescale_tpu.parallel.optimizer import adamw_lowmem, zero_sharded
     from vescale_tpu.train import make_train_step
 
@@ -117,13 +122,29 @@ def run_cell(spec: CellSpec, devices, seed: int, seconds: float, traced: bool, s
 
         # ---- the window: it opens here and closes at the end of the last
         # step that began before ``seconds`` were over
-        tracer = Tracer(spec, traced, seconds)
+        tracer = Tracer(spec, traced == 1, seconds)
+        after = SessionTracer(spec) if traced == 2 else None
+        sched_open = telemetry.host_sched_stats()    # two plain reads, one at each end; nothing runs between them
         w0 = time.perf_counter()
         closes = w0 + float(seconds)
         rec.setup_s = w0 - setup_from
+        in_window = True
         while True:
             now = time.perf_counter()
-            if now >= closes:
+            if in_window and now >= closes:
+                # the window has closed: its numbers are taken here, before anything is traced
+                in_window = False
+                rec.host_sched = telemetry.host_sched_delta(sched_open, telemetry.host_sched_stats())
+                tracer.maybe_stop(now, closes)
+                rec.window = (w0, max(closes, rec.step_end[-1]))
+                rec.memory_peak_bytes = memory_peak_bytes(devices)    # before the reference runs; a peak over the whole process
+                in_use_at_close = memory_in_use_bytes(devices)
+                rec.compile_times = list(compiles.times)
+                program_tracing = {"ndtimeline": ndtimeline.is_active(), "telemetry": telemetry.is_active()}
+                if after is None:
+                    break
+                after.begin()       # --trace 2: the loop runs on under the program's trace session
+            elif not in_window and after.due(now):
                 break
             tracer.maybe_start(now, closes)   # its start-up is not billed to the data wait
             t0 = time.perf_counter()
@@ -137,17 +158,21 @@ def run_cell(spec: CellSpec, devices, seed: int, seconds: float, traced: bool, s
                 loss = jax.block_until_ready(loss)
             t2 = time.perf_counter()
             with annotate("bm.host"):
-                rec.dispatch_s.append(t_dispatched - t1)
-                rec.data_wait_s.append(t1 - t0)
-                rec.step_s.append(t2 - t0)
-                rec.step_end.append(t2)
-                rec.losses.append(float(loss))
-        tracer.maybe_stop(time.perf_counter(), closes)
-        rec.window = (w0, max(closes, rec.step_end[-1]))
-        rec.memory_peak_bytes = memory_peak_bytes(devices)    # before the reference runs; a peak over the whole process
-        in_use_at_close = memory_in_use_bytes(devices)
-        rec.compile_times = list(compiles.times)
-        rec.trace = tracer.summary()
+                if in_window:
+                    rec.dispatch_s.append(t_dispatched - t1)
+                    rec.data_wait_s.append(t1 - t0)
+                    rec.step_s.append(t2 - t0)
+                    rec.step_end.append(t2)
+                    rec.losses.append(float(loss))
+                else:
+                    rec.traced_steps.append((t0, t1, t_dispatched, t2))
+                    float(loss)
+        if after is not None:
+            rec.session = after.end()
+            rec.traced_window = (after.started, after.stopped)
+            rec.trace = after.summary()
+        else:
+            rec.trace = tracer.summary()
 
         # ---- after the window, on the parameters the window left: the
         # reference's float32 loss and logits of one fresh batch against the
@@ -183,5 +208,12 @@ def run_cell(spec: CellSpec, devices, seed: int, seconds: float, traced: bool, s
              "window_s": rec.window_s, "memory_peak_bytes": rec.memory_peak_bytes,
              "memory_in_use_bytes_at_close": in_use_at_close,
              "compiles_in_window": rec.compiles_in_window(),
-             "window_losses_first_last": rec.losses[:1] + rec.losses[-1:]}
+             "window_losses_first_last": rec.losses[:1] + rec.losses[-1:],
+             "host_sched_in_window": rec.host_sched, "program_tracing_in_window": program_tracing}
+    if rec.traced_steps:    # what the session costs while it is on: the step under it against the window's
+        notes["traced_step_ms_p50"] = stats.ms(stats.percentile([s_[3] - s_[0] for s_ in rec.traced_steps], 50))
+        notes["window_step_ms_p50"] = stats.ms(stats.percentile(rec.step_s, 50))
+        notes["session_counters"] = rec.session.counters
+        notes["session_cost_s"] = after.cost_s
+        notes["session_clock_offset_ns"] = rec.session.clock_offset_ns
     return rec, correct, len(rec.losses), failed, notes

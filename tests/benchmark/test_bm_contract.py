@@ -27,7 +27,9 @@ def _reports(metric, cell):
 
 
 def test_top_level_keys():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer"}
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer",
+                          "trace_in_run"}
+    assert BENCH["trace_in_run"] is True
     assert isinstance(BENCH["run_seconds"], int) and 1 <= BENCH["run_seconds"] <= 51
     assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) <= 64 * 1024
     assert 1 <= len(BENCH["workloads"]) <= 24 and 1 <= len(BENCH["per_layer"]) <= 128

@@ -51,3 +51,16 @@ def _seed_rng():
 
     manual_seed(0)
     yield
+
+
+@pytest.fixture(autouse=True)
+def _dormant_tracing():
+    """No test leaves the program's tracing armed for the next one on its
+    worker: a running trace session is stopped and the ndtimeline gate is
+    put down (``test_reqtrace_dormant_is_free`` and its like depend on it)."""
+    yield
+    from vescale_tpu.ndtimeline import api as nd
+
+    if nd.session_active():
+        nd.stop_trace_session()
+    nd.deinit_ndtimers()

@@ -519,7 +519,7 @@ def test_ndtimeline_runtime_wiring_chrome_trace(tmp_path, mesh2d):
     names = {e["name"] for e in events}
     # all three span families are present
     assert {"forward-compute", "backward-compute", "weight-grad-compute"} <= names, names
-    assert "train-step" in names
+    assert "vs.train-step" in names
     assert {"checkpoint-save", "checkpoint-load", "checkpoint-commit"} <= names, names
     # engine spans carry stage/microbatch tags
     f_ev = [e for e in events if e["name"] == "forward-compute"]
@@ -527,7 +527,7 @@ def test_ndtimeline_runtime_wiring_chrome_trace(tmp_path, mesh2d):
     assert len(f_ev) == 2 * 2  # stages x microbatches
     # cross-rank merge rolls spans up by (step, metric)
     merged = merge_ranks(spans)
-    assert any(k[1] == "train-step" for k in merged)
+    assert any(k[1] == "vs.train-step" for k in merged)
     row = next(v for k, v in merged.items() if k[1] == "forward-compute")
     assert row["max_ms"] >= row["mean_ms"] > 0
 
@@ -637,7 +637,7 @@ def test_ndtimeline_runtime_wiring_fast():
     with tempfile.TemporaryDirectory() as td:
         ckpt.save(td + "/ck", {"m": {"x": vt.distribute_tensor(np.arange(8, dtype=np.float32), mesh, [Shard(0)])}})
     names = {s.metric for s in mgr.flush()}
-    assert {"train-step", "checkpoint-save", "checkpoint-commit"} <= names, names
+    assert {"vs.train-step", "checkpoint-save", "checkpoint-commit"} <= names, names
 
 
 # ------------------------------------------------------------ compile cache

@@ -390,7 +390,7 @@ def test_data_load_span_and_histogram(tmp_path):
         batch = next(iter(loader))
         assert batch["input"].shape == (2, 16)
         loader.close()
-        assert "data-load" in [s.metric for s in mgr.flush()]
+        assert "vs.data-load" in [s.metric for s in mgr.flush()]
         assert telemetry.get_registry().histogram("data_load_seconds").count == 1
     finally:
         nd_api._MANAGER, nd_api._ACTIVE = old_mgr, old_active

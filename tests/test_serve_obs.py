@@ -331,14 +331,8 @@ def test_goodput_vs_raw_accounting(serve_rig):
     assert sched.raw_tokens > sched.goodput_tokens
 
 
-@pytest.mark.parametrize("peak_known", [True, False])
-def test_mfu_and_rate_gauges_published(serve_rig, tmp_path, monkeypatch, peak_known):
+def test_rate_gauges_published(serve_rig, tmp_path):
     eng, cache = serve_rig
-    if peak_known:  # stand-in row: the gauge needs a device the table lists
-        from vescale_tpu.telemetry import calibrate
-
-        monkeypatch.setitem(calibrate.DEVICE_PEAKS, jax.devices()[0].device_kind,
-                            {"bf16_flops": 1e12, "hbm_gbps": 50.0, "source": "test"})
     telemetry.init(out_dir=str(tmp_path), memtrack=False)
     try:
         res, sched = _run(eng, cache, _arrivals(n=3))
@@ -349,23 +343,12 @@ def test_mfu_and_rate_gauges_published(serve_rig, tmp_path, monkeypatch, peak_kn
     g = snap["gauges"]
     assert g["serve_goodput_tokens_per_s"] > 0
     assert g["serve_throughput_tokens_per_s"] >= g["serve_goodput_tokens_per_s"]
-    if peak_known:
-        assert 0 < g["serve_mfu"] < 1  # XLA cost analysis works on CPU
-    else:  # the CPU is not in calibrate.DEVICE_PEAKS: no peak, no MFU gauge
-        assert "serve_mfu" not in g
     assert snap["counters"]["serve_tokens_generated_total"] > 0
     assert snap["counters"]["serve_goodput_tokens_total"] == sched.goodput_tokens
     h = snap["histograms"]
     assert h["serve_itl_seconds"]["count"] > 0
     assert h["serve_ttft_queue_wait_seconds"]["count"] >= 3
     assert h["serve_ttft_prefill_seconds"]["count"] >= 3
-
-
-def test_engine_decode_flops_cached(serve_rig):
-    eng, _ = serve_rig
-    f1 = eng.decode_flops_per_step()
-    assert f1 is None or f1 > 0
-    assert eng.decode_flops_per_step() is f1 or eng.decode_flops_per_step() == f1
 
 
 # ============================================= step-counter attribution

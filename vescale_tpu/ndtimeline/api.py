@@ -4,10 +4,16 @@ init_ndtimers, :318 flush, :293 wait, :309 inc_step)."""
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
-from typing import Optional
+import glob
+import os
+import time
+import weakref
+from typing import Any, Dict, List, Optional
 
-from .timer import NDTimerManager
+from .predefined import SESSION_MARK
+from .timer import NDTimerManager, Span
 from .world_info import WorldInfo
 
 __all__ = [
@@ -20,6 +26,12 @@ __all__ = [
     "ndtimer",
     "get_manager",
     "is_active",
+    "start_trace_session",
+    "stop_trace_session",
+    "session_active",
+    "TraceSession",
+    "register_counter_source",
+    "read_counters",
 ]
 
 _MANAGER: Optional[NDTimerManager] = None
@@ -129,3 +141,180 @@ def ndtimer(metric: str):
         return wrapped
 
     return deco
+
+
+# ------------------------------------------------------------ trace session
+# One start/stop pair that a running process calls to trace itself for a
+# while: the XLA profiler, the ndtimeit spans (whose TraceAnnotations land
+# in the profiler's own trace under their ``vs.`` names) and the counters the
+# working objects keep.  It arms the spans only: ``telemetry.init()`` keeps
+# its own switch (registry, exporters, the ``record_step`` feed that reads
+# the loss on the host), so a session never changes what a step does.
+
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+# objects that count their own work in plain integers (``ServeEngine``):
+# each has ``trace_counters() -> {name: int}``; held weakly, read at a
+# session's two ends, never on a hot path
+_COUNTER_SOURCES: "weakref.WeakSet[Any]" = weakref.WeakSet()
+
+
+def register_counter_source(source: Any) -> None:
+    _COUNTER_SOURCES.add(source)
+
+
+def read_counters() -> Dict[str, int]:
+    """The live counter sources' values, summed by name."""
+    total: Dict[str, int] = {}
+    for values in _counters_by_source().values():
+        for name, value in values.items():
+            total[name] = total.get(name, 0) + value
+    return total
+
+
+def _counters_by_source() -> Dict[Any, Dict[str, int]]:
+    # keyed by weak reference: a dead source's key equals no later one's, even at the same address
+    return {weakref.ref(source): {k: int(v) for k, v in source.trace_counters().items()}
+            for source in list(_COUNTER_SOURCES)}
+
+
+@dataclasses.dataclass
+class TraceSession:
+    """What :func:`stop_trace_session` returns."""
+
+    log_dir: str
+    xplane_path: Optional[str]        # None: no profiler, or it wrote no trace
+    spans: List[Span]                 # the ring, drained (epoch-clock starts)
+    counters: Dict[str, int]          # what was counted while the session ran
+    started: float                    # epoch seconds
+    stopped: float
+    mark_epoch_s: float               # the ``vs.session-mark`` annotation's instant on the spans' clock ...
+    mark_trace_ns: Optional[float]    # ... and on the trace's; None without a trace
+    profile: Any = None               # the loaded ``jax.profiler.ProfileData`` (None without a trace)
+
+    @property
+    def clock_offset_ns(self) -> Optional[float]:
+        """Trace nanoseconds minus epoch nanoseconds (to the float's 256 ns)."""
+        return None if self.mark_trace_ns is None else self.mark_trace_ns - self.mark_epoch_s * 1e9
+
+    def to_trace_ns(self, epoch_s: float) -> Optional[float]:
+        """An instant of the spans' clock on the device trace's clock: lays
+        spans recorded after the fact (``serve-queue-wait``,
+        ``serve-decode-token``) over the profiler's events."""
+        if self.mark_trace_ns is None:
+            return None
+        return (epoch_s - self.mark_epoch_s) * 1e9 + self.mark_trace_ns
+
+
+@dataclasses.dataclass
+class _LiveSession:
+    log_dir: str
+    profiler: bool
+    own_timers: bool
+    started: float
+    counters_at_start: Dict[Any, Dict[str, int]]    # by source: one that dies while the session runs drops out
+    compiles: List[float]
+    on_compile: Any
+    mark_epoch_s: float
+
+
+_SESSION: Optional[_LiveSession] = None
+
+
+def session_active() -> bool:
+    return _SESSION is not None
+
+
+def _mark() -> float:
+    """Emit the marker annotation; its instant on the spans' clock."""
+    import jax
+
+    ann = jax.profiler.TraceAnnotation(SESSION_MARK)
+    t0 = time.time()
+    ann.__enter__()
+    t1 = time.time()
+    ann.__exit__(None, None, None)
+    return (t0 + t1) / 2.0
+
+
+def _mark_trace_ns(profile) -> Optional[float]:
+    for plane in profile.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name == SESSION_MARK:
+                    return float(e.start_ns)
+    return None
+
+
+def start_trace_session(log_dir: str, *, profiler: bool = True, rank: int = 0) -> None:
+    """Start tracing this process: the XLA profiler writing under
+    ``log_dir`` (the host's Python tracer off: the program's own
+    annotations are enough, and cost less), the ndtimeit spans, and the
+    counters.  One session at a time; :func:`stop_trace_session` ends it.
+    ``profiler=False`` arms spans and counters alone (no device trace, no
+    clock offset)."""
+    global _SESSION
+    if _SESSION is not None:
+        raise RuntimeError("a trace session is already running")
+    import jax
+    from jax import monitoring
+
+    if profiler:
+        os.makedirs(log_dir, exist_ok=True)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(log_dir, profiler_options=options)
+    compiles: List[float] = []
+
+    def on_compile(name: str, _seconds: float, **_kw) -> None:
+        if name == BACKEND_COMPILE_EVENT:
+            compiles.append(time.time())
+
+    monitoring.register_event_duration_secs_listener(on_compile)
+    own_timers = not is_active()   # an operator's own init_ndtimers, and its handlers, stay as they are
+    if own_timers:
+        init_ndtimers(rank=rank)
+    _SESSION = _LiveSession(log_dir=log_dir, profiler=profiler, own_timers=own_timers, started=time.time(),
+                            counters_at_start=_counters_by_source(), compiles=compiles, on_compile=on_compile,
+                            mark_epoch_s=_mark())
+
+
+def stop_trace_session() -> TraceSession:
+    """Stop the session.  The spans go dormant first and the ring is
+    drained into the result; then the profiler stops and writes its
+    ``.xplane.pb``, which is read once to find the marker, and with it the
+    offset between the two clocks."""
+    global _SESSION
+    live = _SESSION
+    if live is None:
+        raise RuntimeError("no trace session is running")
+    import jax
+    from jax._src import monitoring as _monitoring
+
+    _SESSION = None
+    stopped = time.time()
+    counters: Dict[str, int] = {}
+    for source, values in _counters_by_source().items():     # a source born in the session counts from zero
+        before = live.counters_at_start.get(source, {})
+        for name, value in values.items():
+            counters[name] = counters.get(name, 0) + value - before.get(name, 0)
+    counters["backend_compiles"] = len(live.compiles)
+    if live.own_timers:
+        spans = get_manager().flush()
+        deinit_ndtimers()
+    else:
+        spans = [s for s in get_manager().tail(1 << 30) if s.start >= live.started]
+    _monitoring.unregister_event_duration_listener(live.on_compile)
+    path = profile = mark_ns = None
+    if live.profiler:
+        jax.profiler.stop_trace()
+        files = glob.glob(os.path.join(live.log_dir, "plugins", "profile", "*", "*.xplane.pb"))
+        if files:
+            path = max(files, key=os.path.getmtime)
+            profile = jax.profiler.ProfileData.from_file(path)
+            mark_ns = _mark_trace_ns(profile)
+    return TraceSession(log_dir=live.log_dir, xplane_path=path, spans=spans, counters=counters,
+                        started=live.started, stopped=stopped, mark_epoch_s=live.mark_epoch_s,
+                        mark_trace_ns=mark_ns, profile=profile)

@@ -11,12 +11,16 @@ span cannot bracket them — the XLA profiler owns that timing."""
 FORWARD_COMPUTE = "forward-compute"
 BACKWARD_COMPUTE = "backward-compute"
 WGRAD_COMPUTE = "weight-grad-compute"
-# train loop (train.py) — host region around the whole jitted step
-TRAIN_STEP = "train-step"
+# Spans a trace session's readers lay over the device trace carry the prefix
+# ``vs.`` (ring metric and TraceAnnotation alike), which tells the program's
+# spans from a caller's own annotations in the profiler's host lines.
+# train loop (train.py) — host region around the call of the jitted step:
+# the enqueue only (the caller waits for the result)
+TRAIN_STEP = "vs.train-step"
 # eager optimizer step (parallel/optimizer.py; in-jit steps are XLA's)
 OPTIMIZER_STEP = "optimizer-step"
 # native loader batch fetch (data/loader.py)
-DATA_LOAD = "data-load"
+DATA_LOAD = "vs.data-load"
 # checkpoint layer (checkpoint/__init__.py, manager.py)
 CHECKPOINT_SAVE = "checkpoint-save"
 CHECKPOINT_LOAD = "checkpoint-load"
@@ -34,6 +38,20 @@ SERVE_DECODE_STEP = "serve-decode-step"
 SERVE_DECODE_TOKEN = "serve-decode-token"
 SERVE_EVICT = "serve-evict"
 SERVE_TERMINAL = "serve-terminal"
+# serve engine and loop, live (``ndtimeit``, so they nest in the profiler's
+# trace; the per-request spans above are recorded after the fact and are
+# laid over it by a trace session's clock offset): the whole of
+# ``ServeEngine.prefill`` / ``decode``; inside each, the wait for the device
+# plus the logits' copy to the host; and in serve/loop.py the host's
+# sampling between two decode calls
+SERVE_PREFILL_CALL = "vs.serve-prefill"
+SERVE_DECODE_CALL = "vs.serve-decode"
+SERVE_PREFILL_FETCH = "vs.serve-prefill.fetch"
+SERVE_DECODE_FETCH = "vs.serve-decode.fetch"
+SERVE_SAMPLE = "vs.serve-sample"
+# the one annotation a trace session (ndtimeline/api.py) emits at its start:
+# its instant is known on the spans' clock and on the trace's
+SESSION_MARK = "vs.session-mark"
 # speculative decoding (serve/speculative.py; ISSUE 15): with a drafter
 # armed each decode iteration forks into a serve-draft span (the drafter's
 # k sequential proposal steps) and a serve-verify span (the target's ONE

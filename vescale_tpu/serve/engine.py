@@ -47,11 +47,11 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..ndtimeline import predefined as _p
+from ..ndtimeline.api import ndtimeit, register_counter_source
 from .kv_cache import PagedKVCache
 
 __all__ = ["ServeEngine", "stack_params_check"]
-
-_UNSET = object()  # decode_flops_per_step's not-yet-computed sentinel
 
 
 def _rmsnorm(x, w, eps):
@@ -122,7 +122,13 @@ class ServeEngine:
         self.params = jax.tree_util.tree_map(self._replicate, params)
         self.stage_bounds = self._stage_bounds(num_stages)
         self._positions = np.arange(cache.max_seq_len, dtype=np.int32)[None, :]
-        self._decode_flops: Any = _UNSET
+        # what this engine has done, in plain integers (a trace session
+        # reads them at its two ends: ``trace_counters``)
+        self.decode_steps = 0
+        self.logits_bytes_to_host = 0
+        self.prefill_tokens_real = 0
+        self.prefill_tokens_padded = 0
+        register_counter_source(self)
         self._build()
 
     # ------------------------------------------------------------- params
@@ -147,8 +153,7 @@ class ServeEngine:
     def swap_params(self, params: Dict[str, Any]) -> Dict[str, Any]:
         """Hot-swap the weight tree WITHOUT rebuilding: every compiled
         program takes ``params`` as an argument, so a tree with identical
-        structure/shapes/dtypes slots straight in — no retrace, and the
-        cached ``decode_flops_per_step`` stays valid.  Host leaves are
+        structure/shapes/dtypes slots straight in — no retrace.  Host leaves are
         replicated exactly as at construction.  Returns the PRIOR tree —
         the rollback handle the rolling-rollout canary swaps back on
         divergence.  Incompatible trees raise before anything is touched
@@ -463,23 +468,28 @@ class ServeEngine:
         n = len(prompt)
         if not (0 < n <= cache.max_seq_len):
             raise ValueError(f"prompt length {n} not in (0, {cache.max_seq_len}]")
-        toks = np.zeros((cache.max_seq_len,), np.int32)
-        toks[:n] = np.asarray(prompt, np.int32)
-        x = self._embed_fn(self.params, toks)
-        ks, vs = [], []
-        for fn in self._stage_fns:
-            x, k, v = fn(self.params, x, self._positions)
-            ks.append(k)
-            vs.append(v)
-        logits = self._head_fn(self.params, x, np.int32(n))
-        import jax.numpy as jnp
+        with ndtimeit(_p.SERVE_PREFILL_CALL):
+            toks = np.zeros((cache.max_seq_len,), np.int32)
+            toks[:n] = np.asarray(prompt, np.int32)
+            x = self._embed_fn(self.params, toks)
+            ks, vs = [], []
+            for fn in self._stage_fns:
+                x, k, v = fn(self.params, x, self._positions)
+                ks.append(k)
+                vs.append(v)
+            logits = self._head_fn(self.params, x, np.int32(n))
+            import jax.numpy as jnp
 
-        k_stack = ks[0] if len(ks) == 1 else jnp.concatenate(ks, axis=0)
-        v_stack = vs[0] if len(vs) == 1 else jnp.concatenate(vs, axis=0)
-        page_row = np.ascontiguousarray(cache.page_table[slot])
-        kd, vd = self._commit_fn(cache.k.data, cache.v.data, k_stack, v_stack, page_row)
-        cache.update(kd, vd)
-        return np.asarray(logits)
+            k_stack = ks[0] if len(ks) == 1 else jnp.concatenate(ks, axis=0)
+            v_stack = vs[0] if len(vs) == 1 else jnp.concatenate(vs, axis=0)
+            page_row = np.ascontiguousarray(cache.page_table[slot])
+            kd, vd = self._commit_fn(cache.k.data, cache.v.data, k_stack, v_stack, page_row)
+            cache.update(kd, vd)
+            with ndtimeit(_p.SERVE_PREFILL_FETCH):   # waits for the device, then copies the row
+                out = np.asarray(logits)
+        self.prefill_tokens_real += n
+        self.prefill_tokens_padded += cache.max_seq_len
+        return out
 
     def decode(self, tokens: np.ndarray) -> np.ndarray:
         """One decode step for every slot (inactive slots write only the
@@ -488,16 +498,31 @@ class ServeEngine:
         Callers advance lengths via ``cache.advance`` for slots whose
         token was real."""
         cache = self.cache
-        logits, kd, vd = self._decode_fn(
-            self.params,
-            cache.k.data,
-            cache.v.data,
-            cache.table_array(),
-            cache.lengths_array(),
-            np.asarray(tokens, np.int32).reshape(cache.num_slots),
-        )
-        cache.update(kd, vd)
-        return np.asarray(logits)
+        with ndtimeit(_p.SERVE_DECODE_CALL):
+            logits, kd, vd = self._decode_fn(
+                self.params,
+                cache.k.data,
+                cache.v.data,
+                cache.table_array(),
+                cache.lengths_array(),
+                np.asarray(tokens, np.int32).reshape(cache.num_slots),
+            )
+            cache.update(kd, vd)
+            with ndtimeit(_p.SERVE_DECODE_FETCH):   # waits for the device, then copies every slot's logits
+                out = np.asarray(logits)
+        self.decode_steps += 1
+        self.logits_bytes_to_host += out.nbytes
+        return out
+
+    def trace_counters(self) -> Dict[str, int]:
+        """The engine's own counts since it was built (a trace session
+        reports what was added while it ran).  ``decode_steps`` and
+        ``logits_bytes_to_host`` are of ``decode`` calls: every slot's fp32
+        row, each step (prefill copies one row, ``decode_multi`` is not
+        counted)."""
+        return {"decode_steps": self.decode_steps, "logits_bytes_to_host": self.logits_bytes_to_host,
+                "prefill_tokens_real": self.prefill_tokens_real,
+                "prefill_tokens_padded": self.prefill_tokens_padded}
 
     def decode_multi(self, tokens: np.ndarray) -> np.ndarray:
         """One batched MULTI-token paged step (the speculative-verify /
@@ -560,35 +585,6 @@ class ServeEngine:
             out = logits[slot, len(chunk) - 1]
             i += len(chunk)
         return np.asarray(out)
-
-    def decode_flops_per_step(self) -> Optional[float]:
-        """XLA's FLOP count for ONE compiled decode step (all slots) — the
-        numerator of the serve MFU gauge (telemetry compile-report
-        convention: the COMPILED program's cost analysis, not an analytic
-        guess).  Lowered once from the live cache arrays (shardings ride
-        along; nothing executes) and cached; backends that cannot report
-        cost analysis return None and MFU stays unpublished."""
-        if self._decode_flops is not _UNSET:
-            return self._decode_flops
-        flops: Optional[float] = None
-        try:
-            from ..telemetry.step_report import _cost_dict
-
-            cache = self.cache
-            compiled = self._decode_fn.lower(
-                self.params,
-                cache.k.data,
-                cache.v.data,
-                cache.table_array(),
-                cache.lengths_array(),
-                np.zeros((cache.num_slots,), np.int32),
-            ).compile()
-            v = _cost_dict(compiled).get("flops")
-            flops = float(v) if v and v > 0 else None
-        except Exception:
-            flops = None
-        self._decode_flops = flops
-        return flops
 
     def replay_greedy(self, prompt: Sequence[int], max_new_tokens: int,
                       *, eos_id: Optional[int] = None,

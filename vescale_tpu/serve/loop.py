@@ -232,6 +232,7 @@ def run_serve_resilient(
     from .. import telemetry as _tel
     from ..analysis import envreg
     from ..ndtimeline import api as _nd
+    from ..ndtimeline.predefined import SERVE_SAMPLE as _SERVE_SAMPLE
     from ..telemetry import costaudit as _ca
     from ..telemetry import ops_server as _ops
 
@@ -785,9 +786,10 @@ def run_serve_resilient(
                     # launches plus a (k+1)-wide verify that drafts
                     # nothing would only add cost
                     logits = engine.decode(tokens)
-                    for slot in sorted(active_slots):
-                        cache.advance(slot)
-                        _sample(slot, engine.greedy(logits[slot]))
+                    with _nd.ndtimeit(_SERVE_SAMPLE):
+                        for slot in sorted(active_slots):
+                            cache.advance(slot)
+                            _sample(slot, engine.greedy(logits[slot]))
                 else:
                     # draft-then-verify (speculative.py): the drafter
                     # proposes k tokens per mirrored slot, the target

@@ -1,4 +1,4 @@
-"""Serve-replica observability state — goodput/MFU accounting + the ops
+"""Serve-replica observability state — goodput accounting + the ops
 endpoint providers.
 
 One ``ServeObservability`` per ``run_serve_resilient`` call.  It owns the
@@ -8,11 +8,6 @@ derived numbers the scheduler's raw ledger cannot answer alone:
     only tokens of COMPLETED requests (scheduler.goodput_tokens);
     ``serve_throughput_tokens_per_s`` counts every sampled token.  The gap
     IS the work wasted on evicted/timed-out/drained requests.
-  * **serve MFU** — the compiled decode program's XLA FLOP count
-    (``ServeEngine.decode_flops_per_step``, the compile-report convention)
-    over the measured step wall time, against
-    ``telemetry.calibrate.device_peak_flops`` — published per decode step
-    as the ``serve_mfu`` gauge.
   * **the `/healthz` and `/router` payloads** — the callables
     ``telemetry.ops_server.maybe_start`` binds to the endpoints.  The
     `/router` schema is FROZEN at ``ROUTER_SCHEMA_VERSION`` (docs/
@@ -48,8 +43,6 @@ __all__ = [
     "FLEET_REPLICA_FIELDS_V1",
     "FLEET_REPLICA_FIELDS_V2",
 ]
-
-_UNSET = object()  # _peak's not-yet-looked-up sentinel
 
 ROUTER_SCHEMA_VERSION = 5
 # the frozen /router v1 field set: the freeze contract says fields are
@@ -224,60 +217,20 @@ class ServeObservability:
         self.decode_steps = 0
         self._start = time.perf_counter()
         self._last_decode: Optional[float] = None
-        self._peak: Any = _UNSET  # published peak FLOP/s, looked up once
-        self._last_mfu: Optional[float] = None
-        # the MFU numerator needs a one-time AOT lower+compile of the
-        # decode program: pay it HERE, before the loop serves anything,
-        # rather than stalling the first telemetry-active decode step
-        # mid-batch (telemetry activated mid-run still resolves lazily)
-        from .. import telemetry as _tel
-
-        if _tel.is_active():
-            self._flops()
-
-    # ------------------------------------------------------------- rates
-    def _flops(self) -> Optional[float]:
-        if self.engine is None:
-            return None
-        fn = getattr(self.engine, "decode_flops_per_step", None)
-        return fn() if fn is not None else None
-
-    def _peak_flops(self) -> Optional[float]:
-        """Published peak of the serving chip; None (MFU stays unpublished)
-        on a device ``calibrate.DEVICE_PEAKS`` does not list."""
-        if self._peak is _UNSET:
-            import jax
-
-            from ..telemetry.calibrate import device_peak_flops
-
-            try:
-                self._peak = device_peak_flops(jax.devices()[0])
-            except ValueError:
-                self._peak = None
-        return self._peak
 
     def calibrated_step_estimate(self) -> Optional[float]:
         """Decode-step seconds estimated from the calibration table — the
         scheduler's cold-start ``retry_after_s`` seed when a table is armed
         (before even the first prefill has run).  Prefers MEASURED
         ``serve_decode`` buckets (harvested from a prior run's tagged decode
-        spans by the cost auditor — audited, not modeled), falling back to
-        the analytic compiled-FLOPs / measured-``matmul_gflops`` estimate."""
+        spans by the cost auditor — audited, not modeled); None without one."""
         from ..telemetry.calibrate import active_table
 
         t = active_table()
         if t is None:
-            return None  # checked FIRST: no table means no extra compile
+            return None
         us = t.op_estimate_us("serve_decode")
-        if us is not None:
-            return float(us) / 1e6
-        g = t.meta.get("matmul_gflops")
-        if not g:
-            return None
-        flops = self._flops()
-        if not flops:
-            return None
-        return float(flops) / (float(g) * 1e9)
+        return None if us is None else float(us) / 1e6
 
     def on_decode_step(self, step: int, dt_s: float, active: int) -> None:
         """Per decode step: advance the rate clocks and publish the
@@ -304,16 +257,6 @@ class ServeObservability:
             )
             _tel.set_gauge("serve_goodput_fraction", goodput / raw if raw > 0 else 1.0)
             _tel.set_gauge("serve_free_pages", sched.cache.free_page_count())
-            # MFU numerator is the SINGLE-token decode program's FLOPs;
-            # with speculation on the step wall covers k+1 drafter steps
-            # plus the batched verify instead, so the ratio would be
-            # fiction — publish null (the documented "unavailable" value)
-            # rather than an understated gauge
-            flops = self._flops() if self.speculative is None else None
-            peak = self._peak_flops()
-            if flops and peak and dt_s > 0:
-                self._last_mfu = flops / dt_s / peak
-                _tel.set_gauge("serve_mfu", self._last_mfu)
 
     # --------------------------------------------------------- providers
     def health(self) -> Dict:
@@ -396,7 +339,7 @@ class ServeObservability:
             "retry_after_s": sched.retry_after_s(),
             "goodput_tokens_per_s": sched.goodput_tokens / up,
             "throughput_tokens_per_s": sched.raw_tokens / up,
-            "mfu": self._last_mfu,
+            "mfu": None,  # frozen v1 field; nothing publishes a utilisation from the host's step wall any more
             "decode_steps": self.decode_steps,
             "serve_step": self.serve_step,
             "uptime_s": round(up, 6),

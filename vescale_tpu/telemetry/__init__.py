@@ -52,6 +52,7 @@ from .api import (
     write_step_report,
 )
 from .exporters import JsonlExporter, parse_prometheus_text, prometheus_text
+from .hoststat import host_sched_delta, host_sched_stats
 from .memory_report import compare_with_aot, device_memory_stats
 from .memtrack import dump_now, flight_recorder, tagged
 from .registry import Counter, Gauge, Histogram, MetricsRegistry
@@ -92,4 +93,6 @@ __all__ = [
     "tagged",
     "compare_with_aot",
     "device_memory_stats",
+    "host_sched_stats",
+    "host_sched_delta",
 ]
