@@ -1311,7 +1311,7 @@ def _bench_kernels_impl(on_tpu, kmode):
     from vescale_tpu.kernels.paged_attention import paged_decode
 
     rows = []
-    for (S, Pmax, page, KV, hd, H) in ((8, 8, 16, 8, 64, 8), (16, 16, 16, 8, 64, 16)) if on_tpu else ((4, 4, 8, 4, 32, 8), (8, 8, 8, 4, 32, 8)):
+    for (S, Pmax, page, KV, hd, H) in ((8, 8, 16, 8, 128, 8), (16, 16, 16, 8, 128, 16)) if on_tpu else ((4, 4, 8, 4, 32, 8), (8, 8, 8, 4, 32, 8)):
         N = S * Pmax + 1
         Tmax = page * Pmax
         kp = jnp.asarray(rng.normal(size=(N, page, KV, hd)), jnp.float32)
@@ -1333,7 +1333,9 @@ def _bench_kernels_impl(on_tpu, kmode):
             return jnp.einsum("skgt,stkd->skgd", p, vs).reshape(S, H, hd)
 
         xla = jax.jit(xla_chain)
-        ker = jax.jit(lambda *a: paged_decode(*a, scale=scale, interpret=interp))
+        # the kernel takes the whole 5-D pool and a layer index: one layer here
+        ker = jax.jit(lambda q, kp, vp, table, lengths: paged_decode(
+            q, kp[None], vp[None], table, lengths, layer=0, scale=scale, interpret=interp))
         t_x, o_x = timed(xla, q, kp, vp, table, lengths)
         t_k, o_k = timed(ker, q, kp, vp, table, lengths)
         rows.append({"shape": {"slots": S, "pages_per_slot": Pmax, "page": page,

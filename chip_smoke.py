@@ -340,6 +340,89 @@ def rel_at_scale(a, b) -> float:
     return float(np.max(np.abs(a - b)) / (np.max(np.abs(b)) or 1.0))
 
 
+def kernels_paged_decode(cfg, sizes, args, on_tpu, interp, key):
+    """``paged_decode`` against the engine's XLA leg, over a 5-D pool of two
+    layers (the second is read; the first is NaN, so a read of the wrong
+    layer fails): the serve phase's own geometry, full, and the two serve
+    cells' of the benchmark with ragged lengths, a third and a fifth of the
+    capacity live, as those cells hold.  On the chip both legs are timed and
+    the kernel's reading rate over the live pages' bytes is logged: smoke
+    numbers for PERF.md, not benchmark metrics."""
+    import jax
+    import jax.numpy as jnp
+
+    from vescale_tpu.kernels.paged_attention import paged_decode
+
+    H, hd, dtype = cfg.num_attention_heads, cfg.head_dim, cfg.dtype
+    S, page, Pmax = sizes.serve_slots, sizes.serve_page, sizes.serve_pages_per_slot
+    # (name, slots, pages per slot, kv heads, mean share of the capacity live)
+    cases = [("serve-phase mha", S, Pmax, H, 0.5), ("serve-phase gqa", S, Pmax, max(1, H // 4), 0.5)]
+    if not sizes.tiny:
+        cases += [("mistral7b_serve_chat", 32, 128, 8, 1 / 3), ("deepseek7b_serve_batch", 32, 96, 32, 1 / 5)]
+    rng = np.random.default_rng(args.seed + 2)
+    for name, S, Pmax, KV, share in cases:
+        N, Tmax, layer = S * Pmax + 1, page * Pmax, 1
+        kp_, kv_, kq, key = jax.random.split(key, 4)
+        pool = lambda k: jnp.stack([jnp.full((N, page, KV, hd), jnp.nan, dtype),
+                                    jax.random.normal(k, (N, page, KV, hd), jnp.float32).astype(dtype)])
+        kp, vp = pool(kp_), pool(kv_)
+        q = jax.random.normal(kq, (S, H, hd), jnp.float32).astype(dtype)
+        table = jnp.asarray(rng.permutation(np.arange(1, N))[: S * Pmax].reshape(S, Pmax), jnp.int32)
+        lengths = jnp.asarray(rng.integers(1, int(min(1.0, 2 * share) * Tmax) + 1, S), jnp.int32)
+        scale = hd ** -0.5
+
+        def xla_chain(q, kp, vp, table, lengths):   # serve/engine.py's XLA leg, line for line
+            ks = jnp.take(kp[layer], table, axis=0).reshape(S, Tmax, KV, hd)
+            vs = jnp.take(vp[layer], table, axis=0).reshape(S, Tmax, KV, hd)
+            qg = (q.astype(jnp.float32) * scale).reshape(S, KV, H // KV, hd)
+            s = jnp.einsum("skgd,stkd->skgt", qg, ks.astype(jnp.float32))
+            mask = jnp.arange(Tmax, dtype=jnp.int32)[None, :] < lengths[:, None]
+            s = jnp.where(mask[:, None, None, :], s, -1e30)
+            p = jax.nn.softmax(s, axis=-1)
+            return jnp.einsum("skgt,stkd->skgd", p, vs.astype(jnp.float32)).reshape(S, H, hd)
+
+        @jax.jit
+        def reference(*a):
+            with jax.default_matmul_precision("highest"):
+                return xla_chain(*a)
+
+        xla_leg = jax.jit(xla_chain)
+        kernel = jax.jit(lambda q, kp, vp, table, lengths: paged_decode(
+            q, kp, vp, table, lengths, layer=layer, scale=scale, interpret=interp))
+        operands = (q, kp, vp, table, lengths)
+        o_r, o_k = reference(*operands), kernel(*operands)
+        err, err_xla = rel_at_scale(o_k, o_r), rel_at_scale(xla_leg(*operands), o_r)
+        live_pages = int(np.sum(-(-np.asarray(lengths) // page)))
+        fields = dict(case=name, slots=S, pages_per_slot=Pmax, page=page, H=H, KV=KV, hd=hd,
+                      dtype=jnp.dtype(dtype).name, live_pages=live_pages, of=S * Pmax,
+                      max_abs_diff_over_max=f"{err:.3e}", xla_leg_max_abs_diff_over_max=f"{err_xla:.3e}")
+        if on_tpu:
+            def median_ms(fn, n=20):
+                jax.block_until_ready(fn(*operands))
+                times = []
+                for _ in range(n):
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(fn(*operands))
+                    times.append(time.perf_counter() - t0)
+                return float(np.median(times)) * 1e3
+
+            t_k, t_x = median_ms(kernel), median_ms(xla_leg)
+            live_bytes = 2 * live_pages * page * KV * hd * jnp.dtype(dtype).itemsize
+            fields.update(kernel_ms=round(t_k, 3), xla_leg_ms=round(t_x, 3),
+                          live_page_bytes=live_bytes, kernel_live_GBps=round(live_bytes / t_k / 1e6, 1),
+                          note="host clock around one call of one layer, dispatch included")
+        log("kernels paged_decode", **fields)
+        # Tolerance.  Both legs read the same bf16 pool and keep fp32 after it;
+        # the kernel's MXU passes round its fp32 operands (q * scale, the
+        # probabilities) to bf16, 2^-9 each, while the reference multiplies in
+        # full fp32: 1e-2 of the largest output bounds a softmax-weighted
+        # mean of such products with room (measured on the v5e: 2.6e-3).
+        # Interpreted in fp32: 1e-5.
+        if not err <= (1e-2 if on_tpu else 1e-5):
+            raise RuntimeError(f"kernels paged_decode {name}: differs by {err}")
+        del kp, vp, q, o_r, o_k
+
+
 def phase_kernels(cfg, sizes, args, on_tpu):
     import jax
     import jax.numpy as jnp
@@ -347,7 +430,6 @@ def phase_kernels(cfg, sizes, args, on_tpu):
     from vescale_tpu.kernels import ulps_at_scale
     from vescale_tpu.kernels.cross_entropy import fused_xent_parts
     from vescale_tpu.kernels.fused_adamw import fused_adamw_update, update_ulps_vs_float64
-    from vescale_tpu.kernels.paged_attention import paged_decode
     from vescale_tpu.ops.flash_attention import _dense_ref, flash_attention
 
     interp = not on_tpu  # the sandbox rehearsal runs the kernels interpreted
@@ -412,46 +494,8 @@ def phase_kernels(cfg, sizes, args, on_tpu):
                 raise RuntimeError(f"kernels flash KV={KV}: {n} differs by {e} > {bound[n]}")
         del q, k, v, w, o_k, g_k
 
-    # ---- paged decode against gather -> masked softmax -> matmul
-    S, page, Pmax = sizes.serve_slots, sizes.serve_page, sizes.serve_pages_per_slot
-    hd, Tmax = cfg.head_dim, sizes.serve_page * sizes.serve_pages_per_slot
-    rng = np.random.default_rng(args.seed + 2)
-    for KV in (H, max(1, H // 4)):
-        N = S * Pmax + 1
-        kp_, kv_, kq, key = jax.random.split(key, 4)
-        kp = jax.random.normal(kp_, (N, page, KV, hd), jnp.float32).astype(dtype)
-        vp = jax.random.normal(kv_, (N, page, KV, hd), jnp.float32).astype(dtype)
-        q = jax.random.normal(kq, (S, H, hd), jnp.float32).astype(dtype)
-        table = jnp.asarray(rng.permutation(np.arange(1, N))[: S * Pmax].reshape(S, Pmax), jnp.int32)
-        lengths = jnp.asarray(rng.integers(1, Tmax + 1, S), jnp.int32)
-        scale = hd ** -0.5
-
-        @jax.jit
-        def xla_leg(q, kp, vp, table, lengths):
-            with jax.default_matmul_precision("highest"):
-                ks = jnp.take(kp, table, axis=0).reshape(S, Tmax, KV, hd).astype(jnp.float32)
-                vs = jnp.take(vp, table, axis=0).reshape(S, Tmax, KV, hd).astype(jnp.float32)
-                qg = (q.astype(jnp.float32) * scale).reshape(S, KV, H // KV, hd)
-                s = jnp.einsum("skgd,stkd->skgt", qg, ks)
-                mask = jnp.arange(Tmax, dtype=jnp.int32)[None, :] < lengths[:, None]
-                s = jnp.where(mask[:, None, None, :], s, -1e30)
-                p = jax.nn.softmax(s, axis=-1)
-                return jnp.einsum("skgt,stkd->skgd", p, vs).reshape(S, H, hd)
-
-        o_x = xla_leg(q, kp, vp, table, lengths)
-        o_k = jax.jit(lambda *a: paged_decode(*a, scale=scale, interpret=interp))(q, kp, vp, table, lengths)
-        err = rel_at_scale(o_k, o_x)
-        log("kernels paged_decode", slots=S, pages_per_slot=Pmax, page=page, H=H, KV=KV, hd=hd,
-            dtype=jnp.dtype(dtype).name, max_abs_diff_over_max=f"{err:.3e}")
-        # Tolerance.  Both legs read the same bf16 pool and keep fp32 after it;
-        # the kernel's MXU passes round its fp32 operands (q * scale, the
-        # probabilities) to bf16, 2^-9 each, while the reference multiplies in
-        # full fp32: 1e-2 of the largest output bounds a softmax-weighted
-        # mean of such products with room (measured on the v5e: 2.6e-3).
-        # Interpreted in fp32: 1e-5.
-        if not err <= (1e-2 if on_tpu else 1e-5):
-            raise RuntimeError(f"kernels paged_decode KV={KV}: differs by {err}")
-        del kp, vp, q, o_x, o_k
+    # ---- paged decode against slice -> gather -> masked softmax -> matmul
+    kernels_paged_decode(cfg, sizes, args, on_tpu, interp, key)
 
     # ---- fused adamw on one FFN leaf: compiled kernel against the jitted XLA chain
     b1, b2, eps = 0.9, 0.999, 1e-8

@@ -113,7 +113,7 @@ def leg_parity():
     table = jnp.asarray(rng.permutation(np.arange(1, N))[: S * Pmax].reshape(S, Pmax), jnp.int32)
     lengths = jnp.asarray([1, 9, 24, 32], jnp.int32)
     scale = 1.0 / (hd ** 0.5)
-    out = paged_decode(qd, kp, vp, table, lengths, scale=scale, interpret=True)
+    out = paged_decode(qd, kp[None], vp[None], table, lengths, layer=0, scale=scale, interpret=True)
     ks = kp[table].reshape(S, Tmax, KV, hd)
     vs = vp[table].reshape(S, Tmax, KV, hd)
     qg = (qd * scale).reshape(S, KV, H // KV, hd)

@@ -60,6 +60,32 @@ def test_resolve_contract_on_cpu(kmode):
     assert kernels.resolve("x") is None
 
 
+# (VESCALE_KERNELS or None for unset, a TPU present, kernel) -> resolve()
+@pytest.mark.parametrize("value,tpu,name,want", [
+    (None, False, "paged_decode", None),      # unset off-TPU: the XLA leg, as before PR 27
+    (None, True, "paged_decode", False),      # unset on TPU: the compiled kernel is the decode path
+    (None, True, "fused_adamw", None),        # the other kernels keep their default
+    (None, True, "flash_attention", None),
+    ("off", True, "paged_decode", None),      # an explicit mode means what it meant, for every kernel
+    ("on", True, "paged_decode", False),
+    ("on", True, "fused_adamw", False),
+    ("on", False, "paged_decode", None),
+    ("interpret", False, "paged_decode", True),
+    ("interpret", True, "fused_xent", True),
+])
+def test_resolve_default_by_platform(monkeypatch, value, tpu, name, want):
+    monkeypatch.delenv("VESCALE_KERNELS", raising=False)
+    if value is not None:
+        monkeypatch.setenv("VESCALE_KERNELS", value)
+    monkeypatch.setattr(kernels, "on_tpu", lambda: tpu)
+    assert kernels.resolve(name) is want
+    assert kernels.mode() == (value or "off")
+    # a shape the kernel does not take is the XLA path in every mode, asked with the flag it would get
+    asked = []
+    assert kernels.resolve(name, supported=lambda interpret: asked.append(interpret)) is None
+    assert asked == ([] if want is None else [want])
+
+
 def test_dispatch_counters_ride_registry_gate(kmode):
     from vescale_tpu import telemetry
 
@@ -97,7 +123,7 @@ def test_kernels_env_registered():
     from vescale_tpu.analysis import envreg
 
     assert envreg.is_registered("VESCALE_KERNELS")
-    assert envreg.lookup("VESCALE_KERNELS").default == "off"
+    assert envreg.lookup("VESCALE_KERNELS").default is None   # unset: each kernel's own default (docs/kernels.md)
 
 
 # ================================================================ flash
@@ -180,6 +206,7 @@ def test_flash_xla_impl_gqa_grads_match_dense(kmode):
 
 # ========================================================== paged decode
 def _paged_ref(q, kp, vp, table, lengths, scale):
+    """The engine's XLA leg over ONE layer's (N, page, KV, hd) pool."""
     S, H, hd = q.shape
     _, page, KV, _ = kp.shape
     Tmax = page * table.shape[1]
@@ -193,10 +220,21 @@ def _paged_ref(q, kp, vp, table, lengths, scale):
     return jnp.einsum("skgt,stkd->skgd", p, vs.astype(jnp.float32)).reshape(S, H, hd)
 
 
-def _paged_case(rng, S, Pmax, page, KV, hd, H, dtype):
+# (S, Pmax, page, KV, hd, H): a toy, and the two serve cells' head layouts at
+# their widths (Mistral's GQA 8 x 4 and DeepSeek's MHA 32 x 1; page 16, hd 128)
+# with slots of more than one block of pages (blocks of 32 and of 8 pages)
+PAGED_TOY = (3, 2, 4, 2, 8, 4)
+PAGED_GQA = (4, 40, 16, 8, 128, 32)
+PAGED_MHA = (4, 12, 16, 32, 128, 32)
+PAGED_LAYOUTS = {"toy": PAGED_TOY, "gqa8x4": PAGED_GQA, "mha32x1": PAGED_MHA}
+
+
+def _paged_case(rng, S, Pmax, page, KV, hd, H, dtype, layers=1):
+    """A 5-D pool of ``layers`` layers, a shuffled page table (no slot's
+    pages are in order or adjacent) and ragged lengths."""
     N = S * Pmax + 1
-    kp = jnp.asarray(rng.normal(size=(N, page, KV, hd)), np.float32).astype(dtype)
-    vp = jnp.asarray(rng.normal(size=(N, page, KV, hd)), np.float32).astype(dtype)
+    kp = jnp.asarray(rng.normal(size=(layers, N, page, KV, hd)), np.float32).astype(dtype)
+    vp = jnp.asarray(rng.normal(size=(layers, N, page, KV, hd)), np.float32).astype(dtype)
     q = jnp.asarray(rng.normal(size=(S, H, hd)), jnp.float32)
     table = jnp.asarray(
         rng.permutation(np.arange(1, N))[: S * Pmax].reshape(S, Pmax), jnp.int32)
@@ -204,37 +242,67 @@ def _paged_case(rng, S, Pmax, page, KV, hd, H, dtype):
     return q, kp, vp, table, lengths
 
 
+def _paged_bound(dtype):
+    return ULP_BOUND if dtype == jnp.float32 else 64.0  # bf16 K/V: coarser inputs
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("page,Pmax", [(4, 4), (8, 2), (6, 3), (16, 1)])
-def test_paged_decode_matches_gather_reference(dtype, page, Pmax):
+@pytest.mark.parametrize(
+    "page,Pmax,layout",
+    [(4, 4, None), (8, 2, None), (6, 3, None), (16, 1, None),
+     (16, 40, "gqa8x4"), (16, 12, "mha32x1")],
+)
+def test_paged_decode_matches_gather_reference(dtype, page, Pmax, layout):
     """Property sweep: page sizes (including non-power-of-two 6),
-    pages-per-slot, dtypes, ragged lengths — all within the ulp bound."""
+    pages-per-slot, dtypes, ragged lengths, both serve cells' head layouts —
+    all within the ulp bound, reading layer 1 of a three-layer pool."""
     from vescale_tpu.kernels.paged_attention import paged_decode
 
     rng = np.random.default_rng(page * 10 + Pmax)
-    S, KV, hd, H = 3, 2, 16, 4
-    q, kp, vp, table, lengths = _paged_case(rng, S, Pmax, page, KV, hd, H, dtype)
+    S, KV, hd, H = (3, 2, 16, 4) if layout is None else (
+        PAGED_LAYOUTS[layout][0], *PAGED_LAYOUTS[layout][3:])
+    q, kp, vp, table, lengths = _paged_case(rng, S, Pmax, page, KV, hd, H, dtype, layers=3)
     scale = 1.0 / np.sqrt(hd)
-    out = paged_decode(q, kp, vp, table, lengths, scale=scale, interpret=True)
-    ref = _paged_ref(q, kp, vp, table, lengths, scale)
-    bound = ULP_BOUND if dtype == jnp.float32 else 64.0  # bf16 K/V: coarser inputs
-    assert ulps_at_scale(out, ref) <= bound
+    out = paged_decode(q, kp, vp, table, lengths, layer=1, scale=scale, interpret=True)
+    ref = _paged_ref(q, kp[1], vp[1], table, lengths, scale)
+    assert ulps_at_scale(out, ref) <= _paged_bound(dtype)
 
 
-def test_paged_decode_edge_lengths():
-    """length=1 (only the fresh token), full slot, and slots sharing no
-    pages — the masking edges the serve loop exercises."""
-    from vescale_tpu.kernels.paged_attention import paged_decode
+@pytest.mark.parametrize("layout", list(PAGED_LAYOUTS))
+def test_paged_decode_edge_lengths(layout):
+    """length=1 (only the fresh token), one position either side of a page
+    and of a block of pages, a full slot, and slots sharing no pages — the
+    masking edges the serve loop exercises."""
+    from vescale_tpu.kernels.paged_attention import _block_pages, paged_decode
 
     rng = np.random.default_rng(7)
-    S, Pmax, page, KV, hd, H = 3, 2, 4, 1, 8, 2
-    q, kp, vp, table, _ = _paged_case(rng, S, Pmax, page, KV, hd, H, jnp.float32)
-    lengths = jnp.asarray([1, page * Pmax, 3], jnp.int32)
+    _, Pmax, page, KV, hd, H = PAGED_LAYOUTS[layout]
+    block = page * _block_pages(Pmax, page, KV, hd, 4)
+    edges = sorted({1, page - 1, page, page + 1, block - 1, block, min(block + 1, page * Pmax), page * Pmax})
+    q, kp, vp, table, _ = _paged_case(rng, len(edges), Pmax, page, KV, hd, H, jnp.float32)
+    lengths = jnp.asarray(edges, jnp.int32)
     scale = 1.0 / np.sqrt(hd)
-    out = paged_decode(q, kp, vp, table, lengths, scale=scale, interpret=True)
-    ref = _paged_ref(q, kp, vp, table, lengths, scale)
+    out = paged_decode(q, kp, vp, table, lengths, layer=0, scale=scale, interpret=True)
+    ref = _paged_ref(q, kp[0], vp[0], table, lengths, scale)
     assert ulps_at_scale(out, ref) <= ULP_BOUND
     assert np.isfinite(np.asarray(out)).all()
+
+
+def test_paged_decode_length_zero_and_past_capacity():
+    """Lengths the engine never passes must still end: 0 fetches nothing and
+    gives zeros (and the slot after it is still served), one past the
+    capacity reads the full slot and no table entry beyond it."""
+    from vescale_tpu.kernels.paged_attention import paged_decode
+
+    rng = np.random.default_rng(5)
+    S, Pmax, page, KV, hd, H = PAGED_TOY
+    q, kp, vp, table, _ = _paged_case(rng, S, Pmax, page, KV, hd, H, jnp.float32)
+    scale, full = 1.0 / np.sqrt(hd), page * Pmax
+    out = paged_decode(q, kp, vp, table, jnp.asarray([0, full + 1, 3], jnp.int32),
+                       layer=0, scale=scale, interpret=True)
+    ref = _paged_ref(q, kp[0], vp[0], table, jnp.asarray([1, full, 3], jnp.int32), scale)
+    assert not np.asarray(out[0]).any()
+    assert ulps_at_scale(out[1:], ref[1:]) <= ULP_BOUND
 
 
 def test_paged_decode_nan_poison_matches_reference():
@@ -248,16 +316,43 @@ def test_paged_decode_nan_poison_matches_reference():
     lengths = jnp.asarray([5, 2, 7], jnp.int32)
     scale = 1.0 / np.sqrt(hd)
     # valid poison: slot 0, position 2 (< 5) of its first page
-    kp1 = kp.at[table[0, 0], 2, 0, 3].set(jnp.nan)
+    kp1 = kp.at[0, table[0, 0], 2, 0, 3].set(jnp.nan)
     # masked poison: slot 1, position 3 of page 0 (>= length 2): stale bytes
-    kp1 = kp1.at[table[1, 0], 3, 1, 0].set(jnp.nan)
-    out = paged_decode(q, kp1, vp, table, lengths, scale=scale, interpret=True)
-    ref = _paged_ref(q, kp1, vp, table, lengths, scale)
+    kp1 = kp1.at[0, table[1, 0], 3, 1, 0].set(jnp.nan)
+    out = paged_decode(q, kp1, vp, table, lengths, layer=0, scale=scale, interpret=True)
+    ref = _paged_ref(q, kp1[0], vp[0], table, lengths, scale)
     nan_rows = np.unique(np.argwhere(np.isnan(np.asarray(out)))[:, 0])
     nan_rows_ref = np.unique(np.argwhere(np.isnan(np.asarray(ref)))[:, 0])
     assert list(nan_rows) == [0] and list(nan_rows_ref) == [0]
     fin = ~np.isnan(np.asarray(ref))
     assert ulps_at_scale(np.asarray(out)[fin], np.asarray(ref)[fin]) <= ULP_BOUND
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("layout", list(PAGED_LAYOUTS))
+def test_paged_decode_reads_only_live_positions_of_its_layer(layout, dtype):
+    """Everything the call has no business reading is NaN — K and V of every
+    position past a slot's length (the tail of its last page, its pages
+    beyond, the null page, unmapped pages) and the whole of the layers the
+    call does not name — and the output is, bit for bit, the clean pool's."""
+    from vescale_tpu.kernels.paged_attention import paged_decode
+
+    rng = np.random.default_rng(13)
+    S, Pmax, page, KV, hd, H = PAGED_LAYOUTS[layout]
+    layer = 2
+    q, kp, vp, table, lengths = _paged_case(rng, S, Pmax, page, KV, hd, H, dtype, layers=3)
+    live = np.zeros(kp.shape[:3], bool)      # (layer, physical page, position in it)
+    for s, n in enumerate(np.asarray(lengths)):
+        pos = np.arange(int(n))
+        live[layer, np.asarray(table)[s, pos // page], pos % page] = True
+    poison = lambda pool: jnp.where(jnp.asarray(live)[..., None, None], pool, jnp.nan)
+    scale = 1.0 / np.sqrt(hd)
+    clean = paged_decode(q, kp, vp, table, lengths, layer=layer, scale=scale, interpret=True)
+    out = paged_decode(q, poison(kp), poison(vp), table, lengths, layer=layer, scale=scale, interpret=True)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
+    ref = _paged_ref(q, kp[layer], vp[layer], table, lengths, scale)
+    assert ulps_at_scale(out, ref) <= _paged_bound(dtype)
 
 
 def test_serve_engine_decode_tokens_identical_off_vs_interpret(kmode):

@@ -273,6 +273,68 @@ def test_engine_paged_decode_matches_full_recompute(model_and_params, tp2_mesh):
     assert got == _reference_tokens(model, params, prompt, 6)
 
 
+def _decode_logits(eng, cache, prompt, steps):
+    """Logits of ``steps`` teacher-forced decode calls after one prefill."""
+    slot = cache.alloc(len(prompt), steps + 1)
+    eng.prefill(prompt, slot)
+    cache.commit_prefill(slot, len(prompt))
+    rows = []
+    for i in range(steps):
+        t = [0] * cache.num_slots
+        t[slot] = 3 + i
+        rows.append(eng.decode(t))
+        cache.advance(slot)
+    return np.stack(rows)
+
+
+def test_engine_default_decode_leg_off_tpu_is_the_xla_program(model_and_params, tp2_mesh, monkeypatch):
+    """With VESCALE_KERNELS unset on a backend that is no TPU the engine
+    builds the XLA leg: the decode program and its logits are, byte for
+    byte, those of VESCALE_KERNELS=off, and the page counters stay 0."""
+    _, params = model_and_params
+    prompt, built = (5, 9, 17, 3, 44), {}
+    for mode in (None, "off"):
+        monkeypatch.delenv("VESCALE_KERNELS", raising=False)
+        if mode is not None:
+            monkeypatch.setenv("VESCALE_KERNELS", mode)
+        cache = _cache(mesh=tp2_mesh)
+        eng = ServeEngine(CFG, tp2_mesh, params, cache)
+        assert eng.kernel_decode is False
+        program = eng._decode_fn.lower(
+            eng.params, cache.k.data, cache.v.data, cache.table_array(), cache.lengths_array(),
+            np.zeros((cache.num_slots,), np.int32)).as_text()
+        built[mode] = (program, _decode_logits(eng, cache, prompt, 4), eng.trace_counters())
+    assert built[None][0] == built["off"][0]
+    assert "custom_call" not in built[None][0]
+    np.testing.assert_array_equal(built[None][1], built["off"][1])
+    assert built[None][2]["decode_pages_read"] == built[None][2]["decode_pages_capacity"] == 0
+    assert built[None][2]["decode_steps"] == 4
+
+
+def test_engine_interpreted_kernel_streams_and_page_counters(model_and_params, tp2_mesh, monkeypatch):
+    """VESCALE_KERNELS=interpret: the kernel leg (per shard of the
+    kv-head-sharded 5-D pool, under the shard_map shim) emits the XLA leg's
+    greedy stream, and ``trace_counters`` says how many pages it fetched:
+    each slot's pages up to its new token, an inactive slot's one."""
+    model, params = model_and_params
+    prompt, n = (5, 9, 17, 3, 44), 6
+    monkeypatch.setenv("VESCALE_KERNELS", "interpret")
+    cache = _cache(num_slots=2, page_size=4, pages_per_slot=4, mesh=tp2_mesh)
+    eng = ServeEngine(CFG, tp2_mesh, params, cache)
+    assert eng.kernel_decode is True
+    assert _gen_tokens(eng, cache, prompt, n) == _reference_tokens(model, params, prompt, n)
+    c = eng.trace_counters()
+    # decode calls at lengths 5..9 of the one active slot: ceil((len + 1) / 4) pages, + 1 for the idle slot
+    assert c["decode_steps"] == n - 1
+    assert c["decode_pages_read"] == sum(-(-(length + 1) // 4) + 1 for length in range(5, 5 + n - 1))
+    assert c["decode_pages_capacity"] == (n - 1) * 2 * 4
+    assert c["decode_pages_read"] <= c["decode_pages_capacity"]
+    # a bfloat16 pool whose shard holds one kv head is a layout the kernel does not take: the XLA leg runs
+    kc = KVCacheConfig(layers=CFG.num_hidden_layers, kv_heads=2, head_dim=CFG.head_dim, num_slots=2,
+                       page_size=4, pages_per_slot=4, dtype=jnp.bfloat16)
+    assert ServeEngine(CFG, tp2_mesh, params, PagedKVCache(kc, tp2_mesh)).kernel_decode is False
+
+
 def test_engine_tokens_invariant_to_page_size_and_slot(model_and_params, tp2_mesh):
     model, params = model_and_params
     prompt = (7, 3, 29)

@@ -212,8 +212,8 @@ register("VESCALE_SHARDCHECK", "str", "warn",
          "Static-analysis mode: `off` disables, `warn` emits warnings, `strict` raises on error-severity findings (docs/observability.md).")
 
 # --- Pallas kernel layer ---------------------------------------------
-register("VESCALE_KERNELS", "str", "off",
-         "Pallas kernel dispatch (docs/kernels.md): `off` = the pre-kernel XLA paths byte-identical, `interpret` = run the kernels through the pallas interpreter on any backend (bit-parity testing), `on` = compiled kernels on TPU (falls back to XLA off-TPU, counted in kernel_fallback_total).")
+register("VESCALE_KERNELS", "str", None,
+         "Pallas kernel dispatch (docs/kernels.md). Unset: `paged_decode` (serve decode attention) is the compiled kernel on TPU and the XLA leg elsewhere, the other kernels are off. Set, for all kernels: `off` = the pre-kernel XLA paths byte-identical, `interpret` = run the kernels through the pallas interpreter on any backend (bit-parity testing), `on` = compiled kernels on TPU (falls back to XLA off-TPU, counted in kernel_fallback_total).")
 
 # --- gradient compression / quantized collectives --------------------
 register("VESCALE_GRAD_COMPRESS", "str", "",

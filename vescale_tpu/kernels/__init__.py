@@ -1,11 +1,14 @@
 """vescale_tpu.kernels — the Pallas kernel layer behind ONE dispatch contract.
 
 Every hand-written TPU kernel in the framework lives in this package and is
-reached through the same three-state knob (``VESCALE_KERNELS``, registered
-in ``analysis.envreg``):
+reached through the same knob (``VESCALE_KERNELS``, registered in
+``analysis.envreg``).  Unset, each kernel takes its own default:
+``paged_decode`` is the compiled kernel on TPU and the XLA leg on every
+other backend (what the platform is, the code can see; PERF.md, PR 27);
+the other three stay ``off``.  Set, it means the same for all four:
 
-  ``off``        (default) the kernels are never consulted — every caller
-                 takes exactly the XLA path it took before this package
+  ``off``        the kernels are never consulted — every caller takes
+                 exactly the XLA path it took before this package
                  existed, byte-identical (asserted by tests/test_kernels.py).
   ``interpret``  the Pallas kernels run through the pallas INTERPRETER on
                  any backend — slow, but it executes the real kernel code
@@ -21,12 +24,13 @@ Kernels in this package:
 
   * ``flash_attention``  — online-softmax fused attention (forward +
     backward); dispatched by ``ops/flash_attention.py``.
-  * ``paged_decode``     — PagedAttention-style serve decode: K/V read
-    straight out of the ``PagedKVCache`` page pool through the per-slot
-    page table (scalar-prefetched BlockSpec index maps), online fp32
-    softmax masked by the slot length — one kernel instead of the
-    gather → masked-softmax → matmul chain; dispatched by
-    ``serve/engine.py``.
+  * ``paged_decode``     — PagedAttention-style serve decode: one kernel a
+    layer over the whole 5-D ``PagedKVCache`` pool (left in HBM), which
+    fetches only the pages each slot holds (per-page DMAs through the
+    scalar-prefetched page table, double-buffered blocks of pages) and
+    runs an online fp32 softmax over them — instead of the slice →
+    gather → masked-softmax → matmul chain over all ``Tmax`` positions;
+    dispatched by ``serve/engine.py``, the default decode path on TPU.
   * ``fused_adamw``      — the adamw_lowmem moment/update elementwise
     chain as one kernel over (g, m, v); dispatched by
     ``parallel/optimizer.py``.
@@ -58,7 +62,7 @@ Contract points:
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Callable, Optional
 
 __all__ = [
     "MODES",
@@ -71,6 +75,9 @@ __all__ = [
 ]
 
 MODES = ("off", "interpret", "on")
+# what an unset VESCALE_KERNELS means for these: compiled on TPU, the XLA leg
+# elsewhere (every other kernel: off)
+DEFAULT_ON_TPU = frozenset({"paged_decode"})
 
 
 def mode() -> str:
@@ -92,28 +99,33 @@ def on_tpu() -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
-def resolve(name: str) -> Optional[bool]:
+def resolve(name: str, *, supported: Callable[[bool], bool] = lambda interpret: True) -> Optional[bool]:
     """One-stop dispatch decision for kernel ``name``.
 
-    Returns ``None`` when the caller must take its XLA path (mode off, or
-    ``on`` off-TPU), else the ``interpret=`` flag to pass to the
-    kernel (True under ``interpret`` mode, False for compiled-on-TPU).
-    Counts the decision into the kernel telemetry (no-op while telemetry
-    is dormant).  Call sites with their own late fallbacks (shape checks)
-    should use :func:`mode` + :func:`record_fallback` instead of counting
-    a dispatch they then abandon.
+    Returns ``None`` when the caller must take its XLA path (mode off,
+    ``on`` off-TPU, or a shape the kernel does not take: ``supported``,
+    asked with the ``interpret`` flag the kernel would get, says no), else
+    the ``interpret=`` flag to pass to the kernel (True under ``interpret``
+    mode, False for compiled-on-TPU).
+    With ``VESCALE_KERNELS`` unset a kernel in :data:`DEFAULT_ON_TPU`
+    resolves as under ``on`` where there is a TPU and as under ``off``
+    where there is none (nothing was asked for, so nothing is counted as
+    a fallback).  Counts the decision into the kernel telemetry (no-op
+    while telemetry is dormant).
     """
+    from ..analysis import envreg
+
     m = mode()
+    if envreg.get_str("VESCALE_KERNELS") is None and name in DEFAULT_ON_TPU and on_tpu():
+        m = "on"    # unset: this kernel's own default
     if m == "off":
         return None
-    if m == "interpret":
-        record_dispatch(name)
-        return True
-    if not on_tpu():  # "on" wants compiled kernels; no TPU -> XLA path
+    interpret = m == "interpret"
+    if (m == "on" and not on_tpu()) or not supported(interpret):  # "on" wants compiled kernels; no TPU -> XLA path
         record_fallback(name)
         return None
     record_dispatch(name)
-    return False
+    return interpret
 
 
 def record_dispatch(name: str) -> None:

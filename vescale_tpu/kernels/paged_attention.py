@@ -1,33 +1,47 @@
-"""Paged-attention decode kernel — K/V read straight from the page pool.
+"""Paged-attention decode kernel — a slot's live pages, straight from the pool.
 
-The PR-10 serve decode step ran, per layer, as four separate XLA ops over
-the WHOLE page pool: scatter the new token's K/V into its page, gather
-every slot's pages into a dense (S, Tmax, KV, hd) view, masked fp32
-softmax over Tmax, then the value matmul.  The gather alone materializes
-``S * Tmax`` K/V rows in HBM per layer per token — the single biggest
-serving-throughput lever named by ROADMAP item 1.
+The XLA decode leg (``serve/engine.py``) moves the WHOLE page pool every
+step, whatever it holds: per layer it slices the layer out of the 5-D
+pool, gathers every slot's ``Pmax`` pages into a dense ``(S, Tmax, KV,
+hd)`` view, upcasts it and runs a masked softmax over all ``Tmax``
+positions.  This kernel does work in proportion to the tokens the cache
+holds:
 
-This kernel (PagedAttention-style, vLLM lineage) replaces the
-gather → softmax → matmul chain with ONE kernel: the per-slot page table
-and length vector ride in as scalar-prefetch operands, so the BlockSpec
-index map addresses the K/V **page pool directly** — grid step ``(s, p)``
-DMAs physical page ``table[s, p]`` into VMEM (the null page 0 for unused
-entries), and an online fp32 softmax accumulates across the slot's pages
-in VMEM scratch.  Nothing dense is ever materialized: HBM traffic is one
-read of the pages the slot actually references plus the (S, H, hd) q/out
-rows.  The cache write of the new token's K/V stays the single scatter it
-always was — it IS the persistence op, not part of attention.
+  * **operands** — the whole ``(L, N, page, KV, hd)`` K and V pools stay
+    in HBM (``pl.ANY``); the layer index, the ``(S,)`` lengths and the
+    ``(S, Pmax)`` page table ride in as scalar-prefetch operands (the
+    layer as an operand, not a constant, so every layer of a decode
+    program is the same Mosaic kernel).  No per-layer slice, no gather.
+  * **grid** — one step a slot.  Inside it a loop over the slot's *live*
+    blocks, ``ceil(len / block)`` of them, a block being ``block_pages``
+    pages (``_block_pages``: about 1 MiB of K, at most 512 positions).
+    Pages are scattered in the pool, so a block is fetched by one DMA a
+    page, K and V each into one of two VMEM buffers: while block ``b`` is
+    computed block ``b + 1`` (or the next slot's first) is in flight.
+    Pages past ``ceil(len / page)`` cost neither a DMA nor compute; an
+    inactive slot (the engine passes length 1) costs one block.
+  * **per block** — for each kv head its ``(block, hd)`` K and V rows are
+    read out of the ``(page, KV, hd)`` page layout by a sublane-strided
+    load (16-bit pools through a 32-bit view of two heads at once, as
+    jax's ragged paged attention does), so nothing is transposed.  All
+    ``H`` query rows go through the MXU against that head's K (the rows of
+    other groups are dropped by a select, which also keeps a NaN of one kv
+    head out of the others), which makes MHA (group 1) and GQA one code
+    path with full-height matmul operands.  Scores, the online softmax
+    (running max, sum and accumulator in VMEM scratch, carried over the
+    slot's blocks) and the accumulation are fp32.
 
-GQA runs natively: q heads are grouped per kv head inside the kernel
-(``H = KV * G``) and scores are computed as a (KV,)-batched matmul, so
-repeated K/V heads are never materialized.
-
-Numerics: fp32 scores/softmax/accumulation exactly like the XLA
-reference; the accumulation ORDER differs (online per-page vs one full-row
-softmax), so parity is ulp-bounded rather than bitwise — the bound is
-asserted in tests/test_kernels.py and documented in docs/kernels.md.
-Fully-masked rows (inactive slots never have them: length >= 1) divide by
-a guarded 1.0 like the flash kernels.
+Numerics: K and V enter the matmuls as the pool holds them — widened to
+fp32 exactly; on the chip the MXU multiplies fp32 operands in one bf16
+pass at default precision, which is what the XLA leg's fp32 einsum does
+there too — and nothing else is rounded below fp32.  Against the XLA
+reference only the accumulation ORDER differs (online per block against
+one full-row softmax), so interpreted parity is ulp-bounded, not bitwise:
+the bound is asserted in tests/test_kernels.py and documented in
+docs/kernels.md.  V rows past a slot's length are zeroed and their scores
+masked, so stale bytes (a NaN in a page's tail, or VMEM a skipped DMA
+never wrote) reach nothing.  A slot of length 0 fetches nothing and its
+output is zeros.
 """
 
 from __future__ import annotations
@@ -39,93 +53,189 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["paged_decode"]
+__all__ = ["paged_decode", "supports"]
 
 _NEG_INF = -1e30
+_BLOCK_BYTES = 1 << 20       # of K in one block (V the same; two buffers each)
+_BLOCK_POSITIONS = 512       # at most: a block's compute is not bounded by the length inside it
 
 
-def _decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, scale, page, kv_heads, group):
-    """Grid (S, Pmax): slot-major, pages fastest (TPU grids run
-    sequentially, so the online-softmax state in scratch carries across a
-    slot's pages).  ``table_ref``/``len_ref`` are the scalar-prefetch
-    operands — the same arrays whose values the k/v index maps read."""
+def _block_pages(pages_per_slot: int, page: int, kv_heads: int, head_dim: int, itemsize: int) -> int:
+    """Pages a block holds, from the shapes alone: as many as ``_BLOCK_BYTES``
+    of K and ``_BLOCK_POSITIONS`` allow (GQA at 8 x 128 bf16: 32 pages of 16,
+    512 positions; MHA at 32 x 128: 8 pages, 128 positions), never more than
+    a slot has."""
+    by_bytes = _BLOCK_BYTES // (page * kv_heads * head_dim * itemsize)
+    return max(1, min(pages_per_slot, by_bytes, max(1, _BLOCK_POSITIONS // page)))
+
+
+def supports(pool_dtype, kv_heads: int, head_dim: int, *, interpret: bool) -> bool:
+    """Whether the kernel takes a pool of this dtype with ``kv_heads`` heads
+    (per shard) of ``head_dim``: 32-bit pools, or bfloat16 with an even
+    number of heads (a 32-bit row holds two); compiled, the strided loads
+    want whole 128-lane rows.  The engine takes its XLA leg otherwise."""
+    dt = jnp.dtype(pool_dtype)
+    if not (dt.itemsize == 4 or (dt == jnp.bfloat16 and kv_heads % 2 == 0)):
+        return False
+    return interpret or head_dim % 128 == 0
+
+
+def _decode_kernel(layer_ref, len_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   k_buf, v_buf, sems, cur_ref, m_scr, l_scr, acc_scr,
+                   *, scale, page, block_pages, kv_heads, group):
+    """Grid (S,), sequential: the buffer a block lands in alternates over the
+    whole call (``cur_ref``, SMEM scratch, survives from one slot to the
+    next), because the last block of a slot prefetches the next slot's first."""
     s = pl.program_id(0)
-    p = pl.program_id(1)
-    n_pages = pl.num_programs(1)
+    n_slots = pl.num_programs(0)
+    H = kv_heads * group
+    T = block_pages * page
+    layer = layer_ref[0]
+    packed = k_buf.dtype.itemsize == 2      # two heads a 32-bit row
 
-    @pl.when(p == 0)
-    def _init():
-        m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
-        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+    def block_dma(slot, block, buf, wait):
+        """Start (or wait for) the copies of the live pages of ``block`` of
+        ``slot`` into buffer ``buf``: one DMA a page for K and one for V."""
+        first = block * block_pages
+        live = jnp.clip(pl.cdiv(len_ref[slot], page) - first, 0, block_pages)
 
-    # (H, hd) -> (KV, G, hd): q heads of kv group g are rows [g*G, (g+1)*G)
-    qg = (q_ref[0].astype(jnp.float32) * scale).reshape(kv_heads, group, -1)
-    k = jnp.transpose(k_ref[0].astype(jnp.float32), (1, 0, 2))  # (KV, page, hd)
-    v = jnp.transpose(v_ref[0].astype(jnp.float32), (1, 0, 2))
-    # (KV, G, page) scores: batched over kv heads, contracted over hd
-    sc = jax.lax.dot_general(
-        qg, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    )
-    pos = p * page + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 2)
-    sc = jnp.where(pos < len_ref[s], sc, _NEG_INF)
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
-    pexp = jnp.exp(sc - m_new[..., None])
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(pexp, axis=-1)
-    acc_scr[...] = acc_scr[...] * alpha[..., None] + jax.lax.dot_general(
-        pexp, v, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    )
-    m_scr[...] = m_new
+        def one_page(i, carry):
+            phys = table_ref[slot, first + i]
+            for hbm, vmem, sem in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                copy = pltpu.make_async_copy(hbm.at[layer, phys], vmem.at[buf, i], sems.at[buf, sem])
+                copy.wait() if wait else copy.start()
+            return carry
 
-    @pl.when(p == n_pages - 1)
-    def _final():
-        l = l_scr[...]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out = acc_scr[...] / l_safe[..., None]  # (KV, G, hd)
-        o_ref[0] = out.reshape(kv_heads * group, -1).astype(o_ref.dtype)
+        jax.lax.fori_loop(0, live, one_page, 0)
+
+    length = len_ref[s]
+    # at least one block a slot (a length of 0 fetches nothing and masks it
+    # all): the last block of a slot is where the next slot's first is started
+    n_blocks = jnp.maximum(pl.cdiv(length, T), 1)
+
+    @pl.when(s == 0)
+    def _first():
+        cur_ref[0] = 0
+        block_dma(0, 0, 0, wait=False)
+
+    m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    q = q_ref[0].astype(jnp.float32) * scale                           # (H, hd)
+    row_head = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // group   # kv head of each q row
+
+    def head_rows(buf_ref, buf, k, keep):
+        """kv head ``k``'s (T, hd) rows of the block in buffer ``buf``, fp32
+        (exact).  ``keep`` (T, hd) zeroes rows past the length, or is None."""
+        rows = buf_ref.at[buf].reshape(T * kv_heads, buf_ref.shape[-1])
+        if not packed:
+            x = rows[pl.ds(k, T, stride=kv_heads), :].astype(jnp.float32)
+            return x if keep is None else jnp.where(keep, x, 0.0)
+        # a 32-bit row holds heads 2j (low half) and 2j+1 (high half) of one position
+        w = rows.bitcast(jnp.uint32)[pl.ds(k // 2, T, stride=kv_heads // 2), :]
+        w = (w << 16) if k % 2 == 0 else (w & jnp.uint32(0xFFFF0000))
+        if keep is not None:
+            w = jnp.where(keep, w, jnp.uint32(0))
+        return pltpu.bitcast(w, jnp.float32)
+
+    def block_body(b, cur):
+        nxt = 1 - cur
+
+        @pl.when(b + 1 < n_blocks)
+        def _():
+            block_dma(s, b + 1, nxt, wait=False)
+
+        @pl.when(jnp.logical_and(b + 1 == n_blocks, s + 1 < n_slots))
+        def _():
+            block_dma(s + 1, 0, nxt, wait=False)
+
+        block_dma(s, b, cur, wait=True)
+        pos = b * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+        valid = pos < length                                          # (1, T)
+        keep = (b * T + jax.lax.broadcasted_iota(jnp.int32, (T, k_buf.shape[-1]), 0)) < length
+
+        sc = jnp.zeros((H, T), jnp.float32)
+        for k in range(kv_heads):
+            kk = head_rows(k_buf, cur, k, None)
+            s_k = jax.lax.dot_general(q, kk, (((1,), (1,)), ((), ())),
+                                      preferred_element_type=jnp.float32)   # (H, T)
+            sc = jnp.where(row_head == k, s_k, sc)
+        sc = jnp.where(valid, sc, _NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        pexp = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
+        m_scr[...] = m_new
+        pv = jnp.zeros(acc_scr.shape, jnp.float32)
+        for k in range(kv_heads):
+            vk = head_rows(v_buf, cur, k, keep)
+            o_k = jax.lax.dot_general(pexp, vk, (((1,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)   # (H, hd)
+            pv = jnp.where(row_head == k, o_k, pv)
+        acc_scr[...] = acc_scr[...] * alpha + pv
+        return nxt
+
+    cur_ref[0] = jax.lax.fori_loop(0, n_blocks, block_body, cur_ref[0])
+
+    l = l_scr[...]
+    o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
-def paged_decode(q, k_pool, v_pool, table, lengths, *, scale, interpret):
-    """One decode-step attention over the paged KV pool.
+# jitted so that the calls of a decode program, one a layer with the same
+# shapes, are traced and lowered once (set-up pays for each lowering)
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_decode(q, k_pool, v_pool, table, lengths, *, layer, scale, interpret):
+    """One decode-step attention of one layer over the paged KV pool.
 
     ``q``: (S, H, hd) new-token queries; ``k_pool``/``v_pool``:
-    (N, page, KV, hd) ONE layer's physical page pool; ``table``:
-    (S, Pmax) int32 physical page ids per slot (0 = the reserved null
-    page); ``lengths``: (S,) int32 valid positions per slot (the new token
-    included).  Returns fp32 (S, H, hd) attention output — callers reshape
-    and cast (the XLA reference's ``.astype(dtype)`` boundary).
+    (L, N, page, KV, hd), the WHOLE pool, every layer (left in HBM; only
+    ``layer``'s live pages are read); ``layer``: int or int32 scalar;
+    ``table``: (S, Pmax) int32 physical page ids per slot (0 = the reserved
+    null page); ``lengths``: (S,) int32 valid positions per slot (the new
+    token included).  Returns fp32 (S, H, hd) attention output — callers
+    reshape and cast (the XLA reference's ``.astype(dtype)`` boundary).
 
     Implementation-only: the caller (serve/engine.py) owns the dispatch
     decision and any shard_map wrapping for a kv-head-sharded pool.
     """
     S, H, hd = q.shape
-    N, page, KV, hd2 = k_pool.shape
-    assert hd == hd2 and H % KV == 0, (q.shape, k_pool.shape)
+    L, N, page, KV, hd2 = k_pool.shape
+    if hd != hd2 or H % KV or v_pool.shape != k_pool.shape or v_pool.dtype != k_pool.dtype:
+        raise ValueError(f"paged_decode: q {q.shape} against pools {k_pool.shape} / {v_pool.shape}")
+    if not supports(k_pool.dtype, KV, hd, interpret=bool(interpret)):
+        raise ValueError(f"paged_decode takes no {k_pool.dtype} pool of {KV} kv heads x {hd} (see supports())")
+    itemsize = jnp.dtype(k_pool.dtype).itemsize
     Pmax = table.shape[1]
     G = H // KV
+    bp = _block_pages(Pmax, page, KV, hd, itemsize)
+    buf = pltpu.VMEM((2, bp, page, KV, hd), k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, Pmax),
+        num_scalar_prefetch=3,
+        grid=(S,),
         in_specs=[
-            pl.BlockSpec((1, H, hd), lambda s, p, t, L: (s, 0, 0)),
-            pl.BlockSpec((1, page, KV, hd), lambda s, p, t, L: (t[s, p], 0, 0, 0)),
-            pl.BlockSpec((1, page, KV, hd), lambda s, p, t, L: (t[s, p], 0, 0, 0)),
+            pl.BlockSpec((1, H, hd), lambda s, *_: (s, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, H, hd), lambda s, p, t, L: (s, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, hd), lambda s, *_: (s, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((KV, G), jnp.float32),
-            pltpu.VMEM((KV, G), jnp.float32),
-            pltpu.VMEM((KV, G, hd), jnp.float32),
+            buf, buf,
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, hd), jnp.float32),
         ],
     )
     return pl.pallas_call(
-        functools.partial(
-            _decode_kernel, scale=float(scale), page=page, kv_heads=KV, group=G
-        ),
+        functools.partial(_decode_kernel, scale=float(scale), page=page, block_pages=bp,
+                          kv_heads=KV, group=G),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(table.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pool, v_pool)
+        name="paged_decode",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.clip(lengths.astype(jnp.int32), 0, Pmax * page),
+      table.astype(jnp.int32), q, k_pool, v_pool)

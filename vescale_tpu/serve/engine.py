@@ -21,15 +21,19 @@ come and go:
 
   **decode** — one token per active slot: project q/k/v for the new
   position, scatter k/v into the page the slot's table maps that position
-  to, then paged attention.  With ``VESCALE_KERNELS`` off that is the XLA
-  chain (gather the slot's pages, mask by length, fp32 softmax, matmul);
-  with a kernel mode enabled it is ONE fused Pallas kernel per layer
-  (``kernels.paged_attention``) reading K/V straight from the page pool
-  through the scalar-prefetched page table — no dense (S, Tmax) gather
-  ever materializes, and a kv-head-sharded cache runs the kernel
-  per-shard inside the existing shard_map shim (zero communication, same
-  collective count as the XLA path).  The mode is latched when the engine
-  is BUILT (compiled programs are static); rebuild to switch.  Inactive
+  to, then paged attention.  On TPU that is ONE Pallas kernel per layer
+  (``kernels.paged_attention``) over the whole 5-D pool, left in HBM: it
+  fetches only the pages each slot holds (through the scalar-prefetched
+  page table) and runs an online fp32 softmax over them, so a step costs
+  what the cache holds, not what it could hold; a kv-head-sharded cache
+  runs the kernel per-shard inside the shard_map shim (zero communication,
+  same collective count as the XLA path).  On other backends, or with
+  ``VESCALE_KERNELS=off``, it is the XLA chain (slice the layer, gather
+  every slot's pages, mask by length, fp32 softmax, matmul), which moves
+  the whole pool every step; ``VESCALE_KERNELS=interpret`` runs the kernel
+  through the Pallas interpreter anywhere.  The leg is latched when the
+  engine is BUILT (compiled programs are static); rebuild to switch.
+  ``decode_multi`` keeps the XLA chain.  Inactive
   slots compute too (static shapes) but write only the reserved null page
   and their logits are ignored.
 
@@ -128,6 +132,8 @@ class ServeEngine:
         self.logits_bytes_to_host = 0
         self.prefill_tokens_real = 0
         self.prefill_tokens_padded = 0
+        self.decode_pages_read = 0      # counted only where the paged_decode kernel was built
+        self.decode_pages_capacity = 0
         register_counter_source(self)
         self._build()
 
@@ -296,10 +302,10 @@ class ServeEngine:
 
         self._commit_fn = jax.jit(commit_prefill, donate_argnums=(0, 1))
 
-        def paged_attention(q, kl, vl, table, valid_len):
-            # q (S,H,hd); kl/vl (N,page,KV,hd); table (S,Pmax); valid (S,)
-            ks = jnp.take(kl, table, axis=0).reshape(S, Tmax, KV, hd)
-            vs = jnp.take(vl, table, axis=0).reshape(S, Tmax, KV, hd)
+        def paged_attention(q, kd, vd, layer, table, valid_len):
+            # q (S,H,hd); kd/vd (L,N,page,KV,hd); table (S,Pmax); valid (S,)
+            ks = jnp.take(kd[layer], table, axis=0).reshape(S, Tmax, KV, hd)
+            vs = jnp.take(vd[layer], table, axis=0).reshape(S, Tmax, KV, hd)
             g = H // KV
             qg = (q.astype(jnp.float32) * scale).reshape(S, KV, g, hd)
             s = jnp.einsum("skgd,stkd->skgt", qg, ks.astype(jnp.float32))
@@ -310,51 +316,50 @@ class ServeEngine:
             return o.reshape(S, H * hd).astype(dtype)
 
         # ---- kernel dispatch (latched at build: the decode program is
-        # compiled once; VESCALE_KERNELS is read here, not per step)
+        # compiled once; VESCALE_KERNELS is read here, not per step).  Unset,
+        # paged_decode is the compiled kernel on TPU and the XLA leg elsewhere
         from .. import kernels as _kernels
+        from ..kernels import paged_attention as _paged
 
-        kernel_interpret = _kernels.resolve("paged_decode")
-        # mesh axis sharding the pool's kv-head dim (dim 3 of the 5-D
-        # cache layout; dim 2 of the per-layer slice the kernel sees) —
-        # the kernel runs per-shard under the shard_map shim there
-        kernel_shard_ax = None
-        if kernel_interpret is not None:
-            for i, p in enumerate(cache.spec.placements):
-                if p.is_shard(3) and self.mesh.shape[i] > 1:
-                    kernel_shard_ax = self.mesh.mesh_dim_names[i]
-                    break
+        # mesh axis sharding the pool's kv-head dim (dim 3 of the 5-D cache
+        # layout) — the kernel runs per-shard under the shard_map shim there
+        kernel_shard_ax, kv_local = None, KV
+        for i, p in enumerate(cache.spec.placements):
+            if p.is_shard(3) and self.mesh.shape[i] > 1:
+                kernel_shard_ax, kv_local = self.mesh.mesh_dim_names[i], KV // self.mesh.shape[i]
+                break
+        kernel_interpret = _kernels.resolve(
+            "paged_decode",
+            supported=lambda interpret: _paged.supports(cache.k.data.dtype, kv_local, hd, interpret=interpret))
+        self.kernel_decode = kernel_interpret is not None
 
-        def paged_attention_kernel(q, kl, vl, table, valid_len):
+        def paged_attention_kernel(q, kd, vd, layer, table, valid_len):
+            # kd/vd: the WHOLE (L, N, page, KV, hd) pools; the kernel reads
+            # only ``layer``'s live pages out of them
             from ..collectives import shard_map
-            from ..kernels.paged_attention import paged_decode
 
-            def body(q_l, kl_l, vl_l, table_l, len_l):
-                return paged_decode(
-                    q_l, kl_l, vl_l, table_l, len_l,
-                    scale=scale, interpret=kernel_interpret,
+            def body(q_l, kd_l, vd_l, table_l, len_l):
+                return _paged.paged_decode(
+                    q_l, kd_l, vd_l, table_l, len_l,
+                    layer=layer, scale=scale, interpret=kernel_interpret,
                 )
 
             if kernel_shard_ax is None:
-                out = body(q, kl, vl, table, valid_len)
+                out = body(q, kd, vd, table, valid_len)
             else:
                 ax = kernel_shard_ax
+                pool = P(None, None, None, ax, None)
                 out = shard_map(
                     body,
                     mesh=self.mesh.jax_mesh,
-                    in_specs=(
-                        P(None, ax, None),
-                        P(None, None, ax, None),
-                        P(None, None, ax, None),
-                        P(),
-                        P(),
-                    ),
+                    in_specs=(P(None, ax, None), pool, pool, P(), P()),
                     out_specs=P(None, ax, None),
                     check_vma=False,
                     axis_names=frozenset({ax}),
-                )(q, kl, vl, table, valid_len)
+                )(q, kd, vd, table, valid_len)
             return out.reshape(S, H * hd).astype(dtype)
 
-        attend = paged_attention if kernel_interpret is None else paged_attention_kernel
+        attend = paged_attention_kernel if self.kernel_decode else paged_attention
 
         def decode(params, kd, vd, table, lengths, tokens):
             x = embed(params, tokens)  # (S, E)
@@ -381,7 +386,7 @@ class ServeEngine:
                 k1, v1 = k[:, 0], v[:, 0]
                 kd = kd.at[l, pg, off].set(k1.astype(kd.dtype))
                 vd = vd.at[l, pg, off].set(v1.astype(vd.dtype))
-                y = attend(q[:, 0], kd[l], vd[l], table, pos + 1)
+                y = attend(q[:, 0], kd, vd, l, table, pos + 1)
                 x = x + dense(y, lp["self_attn"]["o_proj"]["kernel"])
                 xn2 = _rmsnorm(x, lp["post_attention_layernorm"]["weight"], eps).astype(dtype)
                 gt = dense(xn2, lp["mlp"]["gate_proj"]["kernel"])
@@ -498,13 +503,14 @@ class ServeEngine:
         Callers advance lengths via ``cache.advance`` for slots whose
         token was real."""
         cache = self.cache
+        lengths = cache.lengths_array()
         with ndtimeit(_p.SERVE_DECODE_CALL):
             logits, kd, vd = self._decode_fn(
                 self.params,
                 cache.k.data,
                 cache.v.data,
                 cache.table_array(),
-                cache.lengths_array(),
+                lengths,
                 np.asarray(tokens, np.int32).reshape(cache.num_slots),
             )
             cache.update(kd, vd)
@@ -512,6 +518,12 @@ class ServeEngine:
                 out = np.asarray(logits)
         self.decode_steps += 1
         self.logits_bytes_to_host += out.nbytes
+        if self.kernel_decode:
+            # what the kernel fetched: each slot's pages up to its new token
+            # (an inactive slot's one), of the table's S x Pmax
+            page, per_slot = cache.config.page_size, cache.config.pages_per_slot
+            self.decode_pages_read += int(np.minimum(-(-(lengths + 1) // page), per_slot).sum())
+            self.decode_pages_capacity += cache.num_slots * per_slot
         return out
 
     def trace_counters(self) -> Dict[str, int]:
@@ -519,10 +531,16 @@ class ServeEngine:
         reports what was added while it ran).  ``decode_steps`` and
         ``logits_bytes_to_host`` are of ``decode`` calls: every slot's fp32
         row, each step (prefill copies one row, ``decode_multi`` is not
-        counted)."""
+        counted).  ``decode_pages_read`` of ``decode_pages_capacity`` says how
+        far the ``paged_decode`` kernel engaged: the pages of K (and as many
+        of V) it fetched a layer, summed over ``decode`` calls, against the
+        ``slots x pages_per_slot`` the XLA leg gathers; both stay 0 on an
+        engine built with the XLA leg."""
         return {"decode_steps": self.decode_steps, "logits_bytes_to_host": self.logits_bytes_to_host,
                 "prefill_tokens_real": self.prefill_tokens_real,
-                "prefill_tokens_padded": self.prefill_tokens_padded}
+                "prefill_tokens_padded": self.prefill_tokens_padded,
+                "decode_pages_read": self.decode_pages_read,
+                "decode_pages_capacity": self.decode_pages_capacity}
 
     def decode_multi(self, tokens: np.ndarray) -> np.ndarray:
         """One batched MULTI-token paged step (the speculative-verify /
