@@ -10,9 +10,10 @@ partition) it refuses here, before a chip call is spent.  Nothing runs: this
 says nothing about results or times, and is never reported as a chip run.
 
 The program builds its mesh from real devices and places its own arrays, so
-this script hands it the described devices and shapes: it gives the cache and
-the engine shapes where they would allocate (two functions patched for the
-duration, here, not in the program).
+this script hands it the described devices and shapes.  A train cell's step is
+assembled here from the family's module and plan; what a serve cell compiles is
+the family's own (``rehearse_serve`` of ``benchmark/families/<model>.py``: it
+knows its engine's programs and its cache's arrays).
 """
 
 from __future__ import annotations
@@ -21,7 +22,6 @@ import argparse
 import json
 import os
 import sys
-from unittest import mock
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -54,10 +54,8 @@ def rehearse_train(spec, topo_devices) -> None:
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from benchmark.spec import llama_config
     from vescale_tpu.dmodule import parallelize_module
     from vescale_tpu.mesh import DeviceMesh
-    from vescale_tpu.models.llama import Llama, llama_plan
     from vescale_tpu.models.nanogpt import cross_entropy_loss
     from vescale_tpu.parallel.optimizer import adamw_lowmem, zero_sharded
     from vescale_tpu.train import make_train_step
@@ -65,10 +63,10 @@ def rehearse_train(spec, topo_devices) -> None:
     c, t, traffic = spec.config, spec.config["train"], spec.traffic
     dp, tp = int(t["mesh"]["dp"]), int(t["mesh"]["tp"])
     T, B = int(traffic["seq_len"]), int(traffic["global_batch"])
-    cfg = llama_config(c, max_positions=T, use_flash_attention=bool(t["use_flash_attention"]))
     mesh = DeviceMesh(("dp", "tp"), (dp, tp), devices=topo_devices[: dp * tp])
-    dm = parallelize_module(Llama(cfg), mesh, llama_plan(mesh, sequence_parallel=bool(t["sequence_parallel"])))
-    abstract = jax.eval_shape(lambda r: Llama(cfg).init(r, jnp.ones((1, T), jnp.int32)), jax.random.key(0))
+    system = spec.family().build_train(c, t, mesh, T)
+    dm = parallelize_module(system.module, mesh, system.plan)
+    abstract = jax.eval_shape(lambda r: system.module.init(r, jnp.ones((1, T), jnp.int32)), jax.random.key(0))
     shardings = dm.variables_shardings(abstract)
     params = jax.tree_util.tree_map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), abstract, shardings)["params"]
@@ -85,7 +83,7 @@ def rehearse_train(spec, topo_devices) -> None:
         jax.eval_shape(tx.init, params), init.output_shardings)
     batch = {k: jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=replicated) for k in ("input", "target")}
     step = make_train_step(dm, tx, lambda lg, b: cross_entropy_loss(lg, b["target"]), donate=True, with_metrics=False)
-    _report(f"{spec.name}: train step, mesh dp {dp} x tp {tp}, {B} x {T} tokens, depth {cfg.num_hidden_layers}",
+    _report(f"{spec.name}: train step, mesh dp {dp} x tp {tp}, {B} x {T} tokens, depth {c['num_hidden_layers']}",
             step.lower(params, opt_state, batch).compile())
     # the forward that train_cell compares with the reference's logits, after the window
     forward = jax.jit(lambda p, x: dm.apply({"params": p}, x, deterministic=True, rngs=None)[0, jnp.arange(8)])
@@ -93,47 +91,10 @@ def rehearse_train(spec, topo_devices) -> None:
 
 
 def rehearse_serve(spec, topo_devices) -> None:
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from benchmark.spec import llama_config
-    from vescale_tpu.mesh import DeviceMesh
-    from vescale_tpu.models.llama import Llama
-    from vescale_tpu.serve import KVCacheConfig, PagedKVCache, ServeEngine
-    from vescale_tpu.serve import kv_cache as kv_cache_module
-
-    c, s = spec.config, spec.config["serve"]
-    positions = int(s["positions_per_slot"])
-    cfg = llama_config(c, max_positions=positions)
-    mesh = DeviceMesh(("tp",), (1,), devices=topo_devices[:1])
-    replicated = NamedSharding(mesh.jax_mesh, P())
-    abstract = jax.eval_shape(lambda r: Llama(cfg).init(r, jnp.ones((1, 8), jnp.int32)), jax.random.key(0))["params"]
-    params = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, cfg.dtype, sharding=replicated), abstract)
-    kc = KVCacheConfig(layers=cfg.num_hidden_layers, kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
-                       num_slots=int(s["slots"]), page_size=int(s["page_size"]),
-                       pages_per_slot=positions // int(s["page_size"]), dtype=cfg.dtype)
-
-    def shapes_for_zeros(cache_spec):
-        return jax.ShapeDtypeStruct(cache_spec.layout().physical_shape, cache_spec.dtype,
-                                    sharding=cache_spec.named_sharding())
-
-    with mock.patch.object(kv_cache_module, "_zeros_global", shapes_for_zeros), \
-            mock.patch.object(ServeEngine, "_replicate", lambda self, leaf: leaf):
-        cache = PagedKVCache(kc, mesh)
-        engine = ServeEngine(cfg, mesh, params, cache)
-    S, Tmax = cache.num_slots, cache.max_seq_len
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=replicated)
-    weights = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(params)) * 2
-    cache_bytes = 2 * int(np.prod(cache.k.data.shape)) * 2
-    print(json.dumps({"cell": spec.name, "weights_bytes_bf16": weights, "cache_bytes": cache_bytes}), flush=True)
-    x = jax.ShapeDtypeStruct((1, Tmax, cfg.hidden_size), cfg.dtype, sharding=replicated)
-    _report(f"{spec.name}: prefill stage, {Tmax} padded positions, depth {cfg.num_hidden_layers}",
-            engine._stage_fns[0].lower(params, x, i32(1, Tmax)).compile())
-    _report(f"{spec.name}: decode step, {S} slots x {Tmax} positions",
-            engine._decode_fn.lower(params, cache.k.data, cache.v.data, i32(S, kc.pages_per_slot), i32(S), i32(S)).compile())
+    sizes, programs = spec.family().rehearse_serve(spec.name, spec.config, spec.config["serve"], topo_devices)
+    print(json.dumps({"cell": spec.name, **sizes}), flush=True)
+    for title, lowered in programs:
+        _report(title, lowered.compile())
 
 
 def main(argv=None) -> int:

@@ -1,7 +1,11 @@
-"""A serve cell: the program's normal path (``ServeEngine`` +
-``PagedKVCache`` + ``ContinuousBatchingScheduler`` + ``run_serve_resilient``,
-fed through a ``RequestInbox`` as a fleet replica is) under an open or a
-closed loop of generated requests.
+"""A serve cell: the program's normal path (the family's engine and cache +
+``ContinuousBatchingScheduler`` + ``run_serve_resilient``, fed through a
+``RequestInbox`` as a fleet replica is) under an open or a closed loop of
+generated requests.  The model family's own file builds the system
+(``benchmark/families/<model>.py``: weights, cache, engine, vocabulary,
+reference, tolerance); this runner keeps the warm-up, the instrumentation, the
+window, the ledger and the close, and touches the engine and the cache only
+through the surface that ``benchmark/README.md`` ("Adding a family") writes down.
 
 The benchmark takes from the program only the system under test.  It wraps
 its own calls into the engine and the scheduler (instance attributes on the
@@ -23,17 +27,9 @@ from . import reference, stats, trafficgen
 from .harness import (TRACE_SECONDS, CompileCounter, SessionTracer, Tracer, annotate, memory_in_use_bytes, memory_peak_bytes,
                       wait_until)
 from .record import RequestRecord, RunRecord
-from .spec import CellSpec, llama_config
+from .spec import CellSpec
 
-# Tolerance of prefill-then-decode through the paged cache against the
-# reference's full float32 forward, as a share of the largest reference logit.
-# The system computes in bf16 (8 bits of mantissa: 2^-9 = 2e-3 per rounding)
-# through L blocks of about ten roundings each, whose errors add like a random
-# walk: 2e-3 * sqrt(10 L) = 2.5e-2 at L 16.  PR 22 measured 1.2e-2 between two
-# bf16 serve legs at depth 4; this PR's chip runs read 0.9e-2 to 1.1e-2 (PERF.md).
-# A wrong mask, position, page or head mapping moves logits by their own size
-# (order 1); computing in fp8 (2^-4 per rounding) would read about 0.5.
-LOGITS_TOLERANCE = 4e-2
+# the reference check's procedure; its tolerance, with the reason for it, is the family's
 CHECK_PROMPT_TOKENS = 320
 CHECK_DECODE_STEPS = 4
 WINDOW_CLOSED = "benchmark window closed"
@@ -59,34 +55,14 @@ class ServeCell:
 
     # ------------------------------------------------------------- set-up
     def build(self, seed: int) -> None:
-        """Weights made on the device in one jitted call from the seed, in the
-        type they are served in; cache; engine; then every shape the traffic
-        uses is warmed (one padded prefill and one decode step, twice: a
-        program whose second call recompiles must do it here)."""
-        import jax
-        import jax.numpy as jnp
-
-        from vescale_tpu.mesh import DeviceMesh
-        from vescale_tpu.models.llama import Llama
-        from vescale_tpu.serve import KVCacheConfig, PagedKVCache, ServeEngine
-
-        c, s = self.spec.config, self.spec.config["serve"]
-        if s["weight_dtype"] != "bfloat16":
-            raise ValueError("serve cells hold their weights in bfloat16")
-        positions = int(s["positions_per_slot"])
-        cfg = llama_config(c, max_positions=positions)
-        mesh = DeviceMesh(("tp",), (1,), devices=self.devices)
-        self.params = jax.jit(
-            lambda key: jax.tree_util.tree_map(
-                lambda x: x.astype(cfg.dtype), Llama(cfg).init(key, jnp.ones((1, 8), jnp.int32))["params"])
-        )(jax.random.key(seed))
-        kc = KVCacheConfig(
-            layers=cfg.num_hidden_layers, kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
-            num_slots=int(s["slots"]), page_size=int(s["page_size"]),
-            pages_per_slot=positions // int(s["page_size"]), dtype=cfg.dtype)
-        self.cache = PagedKVCache(kc, mesh)
-        self.engine = ServeEngine(cfg, mesh, self.params, self.cache)
-        self.vocab = cfg.vocab_size
+        """The family builds weights, cache and engine from the seed; then
+        every shape the traffic uses is warmed (one padded prefill and one
+        decode step, twice: a program whose second call recompiles must do it
+        here)."""
+        c = self.spec.config
+        self.family = self.spec.family()
+        system = self.family.build_serve(c, c["serve"], self.devices, seed)
+        self.cache, self.engine, self.vocab = system.cache, system.engine, system.vocab
         self._instrument_engine()
         for _ in range(2):
             slot = self.cache.alloc(8, 2)
@@ -315,7 +291,8 @@ class ServeCell:
 
     def check_reference(self, seed: int) -> Tuple[bool, Dict[str, Any]]:
         """Prefill of one seeded prompt and then teacher-forced decode steps
-        through the paged cache, against the reference's full forward."""
+        through the cache, against the family's reference's full forward:
+        logits, never tokens."""
         rng = np.random.default_rng([int(seed), 4])
         n = min(CHECK_PROMPT_TOKENS, self.cache.max_seq_len - CHECK_DECODE_STEPS - 1)
         prompt = [int(t) for t in rng.integers(1, self.vocab - 1, n)]
@@ -330,13 +307,14 @@ class ServeCell:
             rows.append(self.engine.decode(toks)[slot])
             self.cache.advance(slot)
         got = np.stack(rows)
-        want = np.asarray(reference.logits(self.engine.params, self.spec.config, prompt + forced,
-                                           range(n - 1, n + CHECK_DECODE_STEPS)))
+        want = np.asarray(self.family.logits(self.engine.params, self.spec.config, prompt + forced,
+                                             range(n - 1, n + CHECK_DECODE_STEPS)))
         self.cache.reset()
         err = reference.rel_at_scale(got, want)
         agree = float(np.mean(np.argmax(got, -1) == np.argmax(want, -1)))
-        ok = bool(np.isfinite(got).all() and err <= LOGITS_TOLERANCE)
-        return ok, {"logits_max_abs_diff_over_max": err, "tolerance": LOGITS_TOLERANCE,
+        tolerance = self.family.SERVE_LOGITS_TOLERANCE
+        ok = bool(np.isfinite(got).all() and err <= tolerance)
+        return ok, {"logits_max_abs_diff_over_max": err, "tolerance": tolerance,
                     "argmax_agreement": agree, "prompt_tokens": n, "decode_steps": CHECK_DECODE_STEPS}
 
     def attempted_failed(self, rec: RunRecord) -> Tuple[int, int]:

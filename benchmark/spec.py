@@ -1,14 +1,17 @@
 """What a cell is made of, read from data: ``BENCHMARK.json`` names the cell,
 its configuration file and its traffic mix; the files hold the sizes.  The
 harness finds everything by name, so a later PR adds a configuration, a mix,
-a cell or a metric by adding files and entries, never by editing one.
+a cell, a metric or a model family by adding files and entries, never by
+editing one.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import os
+import sys
 from typing import Any, Dict, List
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -44,6 +47,10 @@ class CellSpec:
     def kind(self) -> str:
         return self.config["kind"]
 
+    def family(self):
+        """The module of this configuration's ``model`` (``load_family``)."""
+        return load_family(self.config["model"], self.root)
+
     def out_dir(self) -> str:
         """Scratch inside the checkout (token file, traces); in .gitignore."""
         return os.path.join(self.root, "benchmark_out")
@@ -67,8 +74,7 @@ def load_cell(workload: str, root: str = ROOT) -> CellSpec:
     if cell["config"] not in configs:
         raise SpecError(f"workload {workload!r} names configuration {cell['config']!r}, which configs lacks")
     config = _load_json(os.path.join(root, configs[cell["config"]]["file"]))
-    if config.get("kind") not in ("train", "serve"):
-        raise SpecError(f"configuration {cell['config']!r}: kind must be 'train' or 'serve'")
+    check_config(config, configs[cell["config"]], root)
     traffic = _load_json(os.path.join(root, "benchmark", "traffic", cell["traffic"] + ".json"))
     return CellSpec(
         root=root, name=workload, chips=int(cell["chips"]),
@@ -79,29 +85,65 @@ def load_cell(workload: str, root: str = ROOT) -> CellSpec:
     )
 
 
-def llama_config(config: Dict[str, Any], *, max_positions: int, use_flash_attention: bool = True):
-    """The program's ``LlamaConfig`` from a configuration file whose ``model``
-    is ``llama``: the published keys go through unchanged.  ``max_positions``
-    is the longest sequence this cell runs (the program sizes nothing else by
-    it: rotary phases are computed from positions)."""
-    import jax.numpy as jnp
+def check_config(config: Dict[str, Any], declared: Dict[str, Any], root: str = ROOT) -> None:
+    """What a configuration file must hold whatever its family: the keys the
+    harness itself reads, a family file for its ``model``, and the cut from
+    its source written down (README, "A configuration")."""
+    name = declared["name"]
+    if config.get("kind") not in ("train", "serve"):
+        raise SpecError(f"configuration {name!r}: kind must be 'train' or 'serve'")
+    if not isinstance(config.get(config["kind"]), dict):
+        raise SpecError(f"configuration {name!r}: kind is {config['kind']!r}, so the file needs a {config['kind']!r} block")
+    family_path(config.get("model"), root)
+    for key in ("source", "reduced"):
+        if config.get(key) != declared[key]:
+            raise SpecError(f"configuration {name!r}: {key} is {config.get(key)!r} in its file and {declared[key]!r} "
+                            "in BENCHMARK.json")
+    if not config.get("deployment"):
+        raise SpecError(f"configuration {name!r}: the file states no deployment")
+    for key in config["reduced"]:
+        if key not in config or key not in config.get("published", {}):
+            raise SpecError(f"configuration {name!r}: {key!r} is reduced, so the file holds its value here and its "
+                            "source's value under published")
+    share = config.get("share")
+    if share is not None:
+        # a chip's share of a layer (README, "A chip's share"): over how many chips, and which counts are this chip's
+        if not (isinstance(share.get("chips"), int) and share["chips"] >= 2 and share.get("of")):
+            raise SpecError(f"configuration {name!r}: share needs chips (2 or more) and of (the keys that are a share)")
+        for key in share["of"]:
+            if key not in config["reduced"]:
+                raise SpecError(f"configuration {name!r}: {key!r} is this chip's share, so reduced lists it")
 
-    from vescale_tpu.models.llama import LlamaConfig
 
-    if config.get("model") != "llama":
-        raise SpecError(f"model {config.get('model')!r}: this harness builds 'llama' configurations")
-    if config.get("sliding_window") is not None:
-        raise SpecError("models/llama.py has no sliding-window attention")
-    if config["hidden_size"] != config["num_attention_heads"] * config["head_dim"]:
-        raise SpecError("LlamaConfig derives head_dim as hidden_size / num_attention_heads")
-    return LlamaConfig(
-        vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
-        intermediate_size=config["intermediate_size"], num_hidden_layers=config["num_hidden_layers"],
-        num_attention_heads=config["num_attention_heads"], num_key_value_heads=config["num_key_value_heads"],
-        max_position_embeddings=max_positions, rms_norm_eps=config["rms_norm_eps"],
-        rope_theta=config["rope_theta"], tie_word_embeddings=config["tie_word_embeddings"],
-        use_flash_attention=use_flash_attention, dtype=jnp.bfloat16,
-    )
+# ------------------------------------------------------------ model families
+def family_names(root: str = ROOT) -> List[str]:
+    """The families of a checkout: the files of ``benchmark/families/``, by
+    listing the directory, as metric readers are found."""
+    directory = os.path.join(root, "benchmark", "families")
+    return sorted(f[:-3] for f in (os.listdir(directory) if os.path.isdir(directory) else ())
+                  if f.endswith(".py") and not f.startswith("_"))
+
+
+def family_path(model: Any, root: str = ROOT) -> str:
+    directory = os.path.join(root, "benchmark", "families")
+    if not isinstance(model, str) or model not in family_names(root):
+        raise SpecError(f"model {model!r} has no file under {directory} (has: {family_names(root)}): a family is "
+                        "benchmark/families/<model>.py (README, \"Adding a family\")")
+    return os.path.join(directory, model + ".py")
+
+
+def load_family(model: str, root: str = ROOT):
+    """The family's module.  One module object a file and process, so that its
+    jitted reference compiles once."""
+    path = family_path(model, root)
+    name = f"benchmark_family_{model}"
+    module = sys.modules.get(name)
+    if module is None or os.path.abspath(getattr(module, "__file__", "")) != os.path.abspath(path):
+        spec = importlib.util.spec_from_file_location(name, path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        sys.modules[name] = module
+    return module
 
 
 def device_peaks(device_kind: str, root: str = ROOT) -> Dict[str, float]:

@@ -1,7 +1,10 @@
 """A train cell: the README's assembly (``DeviceMesh`` ->
-``parallelize_module(Llama, llama_plan)`` -> ``dm.init`` -> ``adamw_lowmem``
-[-> ``zero_sharded``] -> ``make_train_step(donate=True)``) stepping over
-batches that ``data/loader.py`` reads from a token file written from the seed.
+``parallelize_module(module, plan)`` of the configuration's model family ->
+``dm.init`` -> ``adamw_lowmem`` [-> ``zero_sharded``] ->
+``make_train_step(donate=True)``) stepping over batches that
+``data/loader.py`` reads from a token file written from the seed.  The family's
+own file (``benchmark/families/<model>.py``) gives the module, its plan, the
+operations a token needs, the reference and the check's tolerances.
 """
 
 from __future__ import annotations
@@ -13,26 +16,12 @@ from typing import List
 
 import numpy as np
 
-from . import flops, reference, stats, trafficgen
+from . import reference, stats, trafficgen
 from .harness import CompileCounter, SessionTracer, Tracer, annotate, memory_in_use_bytes, memory_peak_bytes
 from .record import RunRecord
-from .spec import CellSpec, device_peaks, llama_config
+from .spec import CellSpec, device_peaks
 
-# Tolerance of the step's own loss (bf16 compute, the step's kernels) against
-# the reference's float32 loss on the same parameters and batch.  The loss is a
-# mean over 4096 positions, so per-logit errors mostly cancel: on the initial
-# parameters this PR's chip runs read 3e-5 to 6e-4, on one chip and on four;
-# on the parameters a window of some 220 steps leaves, where the check now is,
-# 8e-5 to 1.0e-3 (PERF.md).  5e-3 is five times the worst reading; chip_smoke.py
-# allows 2e-2 between two bf16 layouts.  A wrong shard, mask or missing
-# all-reduce moves the loss by order 1.
-LOSS_TOLERANCE = 5e-3
-# Because errors cancel in that mean, lower-precision compute could pass it.
-# So the system's forward (the module the step differentiates) is also compared
-# logit by logit at a few seeded positions, as a share of the reference's
-# largest logit: serve_cell.LOGITS_TOLERANCE has the arithmetic (bf16 through L
-# blocks reads about 1e-2; fp8 would read about 0.5).
-LOGITS_TOLERANCE = 4e-2
+# the reference check's procedure; its two tolerances, with their reasons, are the family's
 CHECK_POSITIONS = 8
 # steps on fresh loader batches between the repeated-batch lead-in and the
 # window's opening, so that nothing of the lead-in is still pending inside it
@@ -64,13 +53,13 @@ def run_cell(spec: CellSpec, devices, seed: int, seconds: float, traced: int, se
     from vescale_tpu.data import TokenDataLoader
     from vescale_tpu.dmodule import parallelize_module
     from vescale_tpu.mesh import DeviceMesh
-    from vescale_tpu.models.llama import Llama, llama_plan
     from vescale_tpu.models.nanogpt import cross_entropy_loss
     from vescale_tpu.ndtimeline import api as ndtimeline
     from vescale_tpu.parallel.optimizer import adamw_lowmem, zero_sharded
     from vescale_tpu.train import make_train_step
 
     c, t, traffic = spec.config, spec.config["train"], spec.traffic
+    family = spec.family()
     if traffic["kind"] != "train_steps":
         raise trafficgen.TrafficError(f"a train cell takes train_steps traffic, not {traffic['kind']!r}")
     if (t["param_dtype"], t["compute_dtype"], t["optimizer"], t["moment_dtype"]) != (
@@ -86,13 +75,13 @@ def run_cell(spec: CellSpec, devices, seed: int, seconds: float, traced: int, se
     loader = None
     try:
         # ---- set-up: corpus, model, optimizer, step
+        mesh = DeviceMesh(("dp", "tp"), (dp, tp), devices=devices)
+        system = family.build_train(c, t, mesh, T)
         os.makedirs(spec.out_dir(), exist_ok=True)
         tok_path = os.path.join(spec.out_dir(), f"tokens.{spec.name}.bin")
-        trafficgen.write_token_file(tok_path, c["vocab_size"], T, int(traffic["token_file_sequences"]), seed)
+        trafficgen.write_token_file(tok_path, system.vocab, T, int(traffic["token_file_sequences"]), seed)
         loader = TokenDataLoader(tok_path, batch=B, seq_len=T, seed=seed % (1 << 31))
-        cfg = llama_config(c, max_positions=T, use_flash_attention=bool(t["use_flash_attention"]))
-        mesh = DeviceMesh(("dp", "tp"), (dp, tp), devices=devices)
-        dm = parallelize_module(Llama(cfg), mesh, llama_plan(mesh, sequence_parallel=bool(t["sequence_parallel"])))
+        dm = parallelize_module(system.module, mesh, system.plan)
         params = dm.init(jax.random.key(seed), jnp.ones((1, T), jnp.int32))["params"]
         tx = adamw_lowmem(float(traffic["learning_rate"]))
         if t["zero"]:
@@ -103,7 +92,7 @@ def run_cell(spec: CellSpec, devices, seed: int, seconds: float, traced: int, se
                                donate=True, with_metrics=False)
 
         rec = RunRecord(kind="train", chips=spec.chips, traffic_kind=traffic["kind"], device_kind=devices[0].device_kind,
-                        tokens_per_step=B * T, flops_per_token=flops.llama_train_flops_per_token(c, T))
+                        tokens_per_step=B * T, flops_per_token=family.train_flops_per_token(c, T))
         if devices[0].platform == "tpu":
             rec.peak_flops_per_chip = device_peaks(rec.device_kind, spec.root)["bf16_flops_per_s"]
 
@@ -115,7 +104,7 @@ def run_cell(spec: CellSpec, devices, seed: int, seconds: float, traced: int, se
         for _ in range(max(3, int(traffic["lead_in_steps"]))):
             params, opt_state, loss = step(params, opt_state, batch)
             lead.append(float(jax.block_until_ready(loss)))
-        problems = _check_losses(lead, c["vocab_size"])
+        problems = _check_losses(lead, system.vocab)
         for _ in range(SETTLE_STEPS):
             params, opt_state, loss = step(params, opt_state, {k: jnp.asarray(v) for k, v in loader.next().items()})
         jax.block_until_ready(loss)
@@ -181,16 +170,16 @@ def run_cell(spec: CellSpec, devices, seed: int, seconds: float, traced: int, se
         host = loader.next()
         batch = {k: jnp.asarray(v) for k, v in host.items()}
         rows = sorted(int(r) for r in np.random.default_rng([int(seed), 6]).choice(T, CHECK_POSITIONS, replace=False))
-        ref_loss, want = reference.loss_and_logits(params, c, host["input"], host["target"], rows)
+        ref_loss, want = family.loss_and_logits(params, c, host["input"], host["target"], rows)
         forward = jax.jit(lambda p, x: dm.apply({"params": p}, x, deterministic=True, rngs=None)[0, np.asarray(rows)])
         got = np.asarray(forward(params, batch["input"]), np.float32)
         logits_err = reference.rel_at_scale(got, want)
         params, opt_state, loss = step(params, opt_state, batch)
         sys_loss = float(jax.block_until_ready(loss))
         loss_err = abs(sys_loss - ref_loss)
-        if not loss_err <= LOSS_TOLERANCE:
+        if not loss_err <= family.TRAIN_LOSS_TOLERANCE:
             problems.append(f"loss {sys_loss:.5f} differs from the reference's {ref_loss:.5f} by {loss_err:.2e}")
-        if not (np.isfinite(got).all() and logits_err <= LOGITS_TOLERANCE):
+        if not (np.isfinite(got).all() and logits_err <= family.TRAIN_LOGITS_TOLERANCE):
             problems.append(f"logits differ from the reference's by {logits_err:.2e} of its largest")
     finally:
         compiles.close()
@@ -203,8 +192,8 @@ def run_cell(spec: CellSpec, devices, seed: int, seconds: float, traced: int, se
     notes = {"slowest_steps_ms": [[i, round(s_ * 1e3, 2), round(rec.data_wait_s[i] * 1e3, 2),
                                    round(rec.dispatch_s[i] * 1e3, 2)] for s_, i in slowest],
              "lead_in_losses": lead, "reference_loss": ref_loss, "loss_abs_diff": loss_err,
-             "loss_tolerance": LOSS_TOLERANCE, "logits_max_abs_diff_over_max": logits_err,
-             "logits_tolerance": LOGITS_TOLERANCE, "logits_rows": rows, "problems": problems,
+             "loss_tolerance": family.TRAIN_LOSS_TOLERANCE, "logits_max_abs_diff_over_max": logits_err,
+             "logits_tolerance": family.TRAIN_LOGITS_TOLERANCE, "logits_rows": rows, "problems": problems,
              "window_s": rec.window_s, "memory_peak_bytes": rec.memory_peak_bytes,
              "memory_in_use_bytes_at_close": in_use_at_close,
              "compiles_in_window": rec.compiles_in_window(),

@@ -41,8 +41,8 @@ def _well_formed(result, spec, traced):
 @pytest.mark.parametrize("traced", [False, True])
 def test_cell_runs_to_a_result(tiny_root, workload, runner, traced, monkeypatch):
     # the toy's loss is a mean over 64 positions, not 4096: its bf16 errors cancel eight times less
-    monkeypatch.setattr(train_cell, "LOSS_TOLERANCE", 2e-2)
     spec = load_cell(workload, tiny_root)
+    monkeypatch.setattr(spec.family(), "TRAIN_LOSS_TOLERANCE", 2e-2)
     devices = jax.devices()[: spec.chips]
     rec, correct, attempted, failed, notes = runner(spec, devices, 2**31 + 11, 1.0, traced, time.perf_counter())
     assert correct, str(notes.get("problems") or notes.get("ledger") or notes)

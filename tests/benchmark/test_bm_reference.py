@@ -1,5 +1,5 @@
-"""The plain reference against the program on the CPU at a small size: the
-flax model's logits, and prefill-then-decode through ``ServeEngine``'s paged
+"""Each family's plain reference against the program on the CPU at a small
+size: the flax model's logits, and prefill-then-decode through the engine's
 cache, for a GQA and an MHA preset with an untied head."""
 
 import jax
@@ -7,10 +7,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bm_fixtures import make_tiny_root
+from bm_fixtures import REPO, make_tiny_root
 
 from benchmark import reference, serve_cell
-from benchmark.spec import llama_config, load_cell
+from benchmark.spec import load_cell, load_family
 
 BASE = {"model": "llama", "vocab_size": 384, "hidden_size": 64, "intermediate_size": 160, "num_hidden_layers": 3,
         "num_attention_heads": 4, "head_dim": 16, "rms_norm_eps": 1e-5, "rope_theta": 10000.0,
@@ -19,26 +19,37 @@ PRESETS = {"gqa": dict(BASE, num_key_value_heads=2), "mha": dict(BASE, num_key_v
            "tied": dict(BASE, num_key_value_heads=1, tie_word_embeddings=True, rope_theta=1e6)}
 
 
+def _program_module(model, cfg):
+    """The program's own module of a family, for the presets above."""
+    if model == "llama":
+        from vescale_tpu.models.llama import Llama
+
+        return Llama(cfg)
+    raise KeyError(model)
+
+
 @pytest.mark.parametrize("preset", sorted(PRESETS))
-def test_reference_matches_models_llama_in_float32(preset):
+def test_reference_matches_the_programs_module_in_float32(preset):
     import dataclasses
 
-    from vescale_tpu.models.llama import Llama
-
     c = PRESETS[preset]
-    cfg = dataclasses.replace(llama_config(c, max_positions=48, use_flash_attention=False), dtype=jnp.float32)
+    family = load_family(c["model"], REPO)
+    cfg = dataclasses.replace(family.program_config(c, max_positions=48, use_flash_attention=False), dtype=jnp.float32)
     tokens = np.random.default_rng(0).integers(0, c["vocab_size"], (2, 48)).astype(np.int32)
     with jax.default_matmul_precision("highest"):
-        params = Llama(cfg).init(jax.random.key(1), jnp.asarray(tokens))["params"]
-        want = np.asarray(Llama(cfg).apply({"params": params}, jnp.asarray(tokens)))
+        module = _program_module(c["model"], cfg)
+        params = module.init(jax.random.key(1), jnp.asarray(tokens))["params"]
+        want = np.asarray(module.apply({"params": params}, jnp.asarray(tokens)))
     for b in range(2):
-        got = np.asarray(reference.logits(params, c, tokens[b], range(48)))
+        got = np.asarray(family.logits(params, c, tokens[b], range(48)))
         # both float32: only the order of sums differs
         assert reference.rel_at_scale(got, want[b]) < 2e-5
     targets = np.roll(tokens, -1, axis=1)
     lse = jax.nn.logsumexp(jnp.asarray(want), axis=-1)
     picked = jnp.take_along_axis(jnp.asarray(want), jnp.asarray(targets)[..., None], axis=-1)[..., 0]
-    assert reference.loss(params, c, tokens, targets) == pytest.approx(float(jnp.mean(lse - picked)), abs=1e-5)
+    ref_loss, rows = family.loss_and_logits(params, c, tokens, targets, [0, 47])
+    assert ref_loss == pytest.approx(float(jnp.mean(lse - picked)), abs=1e-5)
+    assert reference.rel_at_scale(rows, want[0][[0, 47]]) < 2e-5
 
 
 @pytest.mark.parametrize("workload", ["tiny_chat", "tiny_batch"])   # a GQA and an MHA configuration
@@ -49,7 +60,8 @@ def test_reference_matches_prefill_then_decode_through_the_engine(tmp_path, work
     ok, detail = cell.check_reference(seed=5)
     assert ok, detail
     # bf16 through two blocks: far inside the tolerance, and not by luck
-    assert 0 < detail["logits_max_abs_diff_over_max"] < serve_cell.LOGITS_TOLERANCE
+    tolerance = cell.family.SERVE_LOGITS_TOLERANCE
+    assert 0 < detail["logits_max_abs_diff_over_max"] < tolerance == detail["tolerance"]
     # and the comparison can fail: against a reference whose first k_proj is negated, the same
     # prefill is far outside the tolerance
     ref_params = jax.tree_util.tree_map(lambda x: x, cell.engine.params)
@@ -60,5 +72,5 @@ def test_reference_matches_prefill_then_decode_through_the_engine(tmp_path, work
     prompt = [int(t) for t in rng.integers(1, cell.vocab - 1, n)]
     slot = cell.cache.alloc(n, 1)
     row = cell.engine.prefill(prompt, slot)
-    wrong = np.asarray(reference.logits(ref_params, spec.config, prompt, [n - 1]))[0]
-    assert reference.rel_at_scale(row, wrong) > serve_cell.LOGITS_TOLERANCE
+    wrong = np.asarray(cell.family.logits(ref_params, spec.config, prompt, [n - 1]))[0]
+    assert reference.rel_at_scale(row, wrong) > tolerance

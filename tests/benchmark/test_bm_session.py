@@ -205,11 +205,11 @@ def tiny_root(tmp_path_factory):
 
 
 def _run(root, workload, mode, monkeypatch):
-    monkeypatch.setattr(train_cell, "LOSS_TOLERANCE", 2e-2)
     monkeypatch.setattr(harness, "TRACE_SECONDS", 0.4)
     monkeypatch.setattr(serve_cell, "TRACE_SECONDS", 0.4)
     monkeypatch.setattr(serve_cell, "EXTENSION_S", 3.0)
     spec = load_cell(workload, root)
+    monkeypatch.setattr(spec.family(), "TRAIN_LOSS_TOLERANCE", 2e-2)   # the toy's loss is a mean over 64 positions
     runner = {"train": train_cell.run_cell, "serve": serve_cell.run_cell}[spec.kind]
     devices = jax.devices()[: spec.chips]
     rec, correct, attempted, failed, notes = runner(spec, devices, 2**31 + 11, 1.0, mode, time.perf_counter())
