@@ -7,7 +7,7 @@ one described device, and compiles it.  Nothing executes, so this says
 nothing about results or times; it catches what interpret mode cannot — a
 kernel the compiler refuses (scoped VMEM, tiling, HBM).  The cases call the
 kernel entry points below the platform dispatch (``_flash``,
-``paged_decode``, ``_fused_local``, ``fused_xent_parts``): code that asks
+``paged_decode``, ``ssm_step``, ``_fused_local``, ``fused_xent_parts``): code that asks
 ``jax.devices()`` still sees the CPU here.
 """
 
@@ -131,6 +131,21 @@ def test_paged_decode_compiles(chip, S, Pmax, page, H, KV, hd, dtype, L):
         return
     compiled = _compile(fn, *args)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20   # no copy of the pool
+
+
+# --------------------------------------------------------------- ssm step
+# (layers, slots, state, heads x head width): granite-4.0-h-small's nine Mamba-2 layers at 64 slots (the
+# benchmark's cell), and a narrower state whose lanes are one block
+@pytest.mark.parametrize("L,S,N,J", [(9, 64, 128, 8192), (2, 8, 64, 1024)], ids=["granite-64-slots", "narrow"])
+def test_ssm_step_compiles_in_place(chip, L, S, N, J):
+    from vescale_tpu.kernels.ssm_step import ssm_step
+
+    sds = lambda shape, dt=f32: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    compiled = ssm_step.lower(sds((L, S, N, J)), sds((S, J)), sds((S, J)), sds((S, N)), sds((S, N)),
+                              layer=sds((1,), jnp.int32), interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == L * S * N * J * 4 and memory.temp_size_in_bytes < 1 << 20   # no copy of the state
 
 
 # ------------------------------------------------------------ fused adamw

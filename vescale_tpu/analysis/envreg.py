@@ -213,7 +213,7 @@ register("VESCALE_SHARDCHECK", "str", "warn",
 
 # --- Pallas kernel layer ---------------------------------------------
 register("VESCALE_KERNELS", "str", None,
-         "Pallas kernel dispatch (docs/kernels.md). Unset: `paged_decode` (serve decode attention) is the compiled kernel on TPU and the XLA leg elsewhere, the other kernels are off. Set, for all kernels: `off` = the pre-kernel XLA paths byte-identical, `interpret` = run the kernels through the pallas interpreter on any backend (bit-parity testing), `on` = compiled kernels on TPU (falls back to XLA off-TPU, counted in kernel_fallback_total).")
+         "Pallas kernel dispatch (docs/kernels.md). Unset: `paged_decode` (serve decode attention) and `ssm_step` (a state-space layer's decode step) are the compiled kernels on TPU and the XLA leg elsewhere, the other kernels are off. Set, for all kernels: `off` = the pre-kernel XLA paths byte-identical, `interpret` = run the kernels through the pallas interpreter on any backend (bit-parity testing), `on` = compiled kernels on TPU (falls back to XLA off-TPU, counted in kernel_fallback_total).")
 
 # --- gradient compression / quantized collectives --------------------
 register("VESCALE_GRAD_COMPRESS", "str", "",
@@ -299,7 +299,7 @@ register("VESCALE_SERVE_PAGE_SIZE", "int", 16,
 register("VESCALE_SERVE_PAGES_PER_SLOT", "int", 4,
          "Max pages one request may hold; page_size x pages_per_slot is the serving max sequence length.")
 register("VESCALE_SERVE_MAX_QUEUE", "int", 64,
-         "Bounded admission queue depth; submissions beyond it are shed with a retry-after hint (docs/serving.md).")
+         "Bounded admission queue depth; submissions beyond it are shed with a retry-after hint (docs/serving.md). Unset, the bound is this default or twice the cache's slots, whichever is larger.")
 register("VESCALE_SERVE_SLO_TTFT_S", "float", 0.0,
          "p99 time-to-first-token SLO budget in seconds; while the rolling p99 exceeds it new submissions are shed (0 disables).")
 register("VESCALE_SERVE_DEADLINE_S", "float", 0.0,

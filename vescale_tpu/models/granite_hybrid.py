@@ -1,0 +1,435 @@
+"""Granite-4.0-H (``granitemoehybrid``): a decoder whose mixers are Mamba-2
+state-space layers with an attention layer every few, and whose feed-forward
+is a routed expert layer beside one shared expert, in every layer.
+
+The block is written ONCE, as pure functions over a plain parameter tree
+(``init_params``), and both serve programs call them: prefill
+(``mamba2_prefill``, ``attention_prefill``) and decode (``mamba2_step``,
+``attention_step``) share the projections, the convolution, the gate, the
+norms, the expert layer (``moe.dropless``) and the head.  No flax module, no
+third copy for training yet (ROADMAP D3).
+
+Equations (HF ``modeling_granitemoehybrid.py``; ISSUE 29 writes them out):
+
+    x0 = embedding_multiplier * E[tokens]
+    x += residual_multiplier * mixer_l(rmsnorm(x))
+    h  = rmsnorm(x);  x += residual_multiplier * (moe(h) + shared(h))
+    logits = rmsnorm(x_L) @ E^T / logits_scaling          (tied head)
+
+``attention``: q, k, v, o without bias, grouped-query, NO positional term,
+causal softmax of ``attention_multiplier * q.k``.  ``mamba`` (Mamba-2, one
+group of B and C shared by all heads): ``[z | xBC | dt] = W_in u``; ``xBC =
+silu(causal depthwise conv1d(xBC) + b)``; ``dt = softplus(dt + dt_bias)``;
+per head ``h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T``, ``y_t = h_t C_t + D
+x_t``; ``y = rmsnorm(y * silu(z)) * w``; ``W_out y``.
+
+Precision: weights and matmul operands are ``config.dtype`` (bfloat16) with
+float32 accumulation; the residual stream, the norms, the gate, the router and
+everything of the state-space recurrence (decay, cumulative sums, the scan's
+own products, the state) are float32.  The embedding is scaled by 12 and each
+layer adds 0.22 of its output, so a bfloat16 residual would round the layers'
+contributions away first.
+
+A chip's share (the ``model-configs`` guide, section 4): ``num_experts`` is
+what the router scores, ``experts_held`` / ``first_expert_held`` say which of
+them this parameter tree holds; ``vocab_size`` is the rows of the embedding
+held here.  The expert layer adds up the part its own experts give; nothing
+stands in for the absent chips.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..moe.dropless import dropless_experts, route_topk
+
+__all__ = [
+    "GraniteHybridConfig", "init_params", "rmsnorm", "embed", "head",
+    "mamba2_prefill", "mamba2_step", "ssd_chunked", "attention_prefill", "attention_step",
+    "paged_attention_xla", "ssm_advance_xla", "expert_layer", "layer_prefill", "layer_step",
+]
+
+F32 = jnp.float32
+# the scan's own products (C B^T, the decayed sums, the chunk states) are a few
+# per cent of a prefill's operations; in float32 they leave the state exact to
+# the recurrence's own rounding
+SCAN_PRECISION = jax.lax.Precision.HIGHEST
+
+
+@dataclasses.dataclass(frozen=True)
+class GraniteHybridConfig:
+    vocab_size: int = 100352            # rows of the (tied) embedding held here
+    hidden_size: int = 4096
+    layer_types: Tuple[str, ...] = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    intermediate_size: int = 768        # width of one routed expert
+    shared_intermediate_size: int = 1536
+    num_experts: int = 72               # the router's outputs: every expert the model has
+    num_experts_per_tok: int = 10
+    experts_held: int = 72              # ... and the contiguous ids this tree holds
+    first_expert_held: int = 0
+    mamba_n_heads: int = 128
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_d_conv: int = 4
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 256
+    embedding_multiplier: float = 12.0
+    residual_multiplier: float = 0.22
+    attention_multiplier: float = 0.0078125
+    logits_scaling: float = 16.0
+    rms_norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16           # weights and matmul operands
+    state_dtype: Any = jnp.float32      # the recurrent state: rewritten every step, so its rounding accumulates
+
+    def __post_init__(self):
+        if not self.layer_types or set(self.layer_types) - {"mamba", "attention"}:
+            raise ValueError(f"layer_types must name 'mamba' or 'attention', got {self.layer_types!r}")
+        if self.mamba_n_groups != 1:
+            raise ValueError("this block shares one group of B and C between all heads (mamba_n_groups = 1)")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
+        if not (0 <= self.first_expert_held and self.first_expert_held + self.experts_held <= self.num_experts
+                and self.experts_held > 0):
+            raise ValueError(f"experts {self.first_expert_held}..{self.first_expert_held + self.experts_held} "
+                             f"are not among the router's {self.num_experts}")
+        if self.num_experts_per_tok > self.num_experts:
+            raise ValueError("num_experts_per_tok exceeds num_experts")
+
+    @property
+    def num_hidden_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def in_proj_dim(self) -> int:
+        return self.d_inner + self.conv_dim + self.mamba_n_heads
+
+    @property
+    def mamba_layers(self) -> Tuple[int, ...]:
+        return tuple(l for l, t in enumerate(self.layer_types) if t == "mamba")
+
+    @property
+    def attention_layers(self) -> Tuple[int, ...]:
+        return tuple(l for l, t in enumerate(self.layer_types) if t == "attention")
+
+    @property
+    def ssm_state_shape(self) -> Tuple[int, int]:
+        """One slot's state in one state-space layer as the cache keeps it:
+        (state, heads x head width), the state dim on sublanes and every
+        head's row side by side on lanes (``kernels/ssm_step.py`` says why)."""
+        return (self.mamba_d_state, self.d_inner)
+
+    @property
+    def conv_tail_shape(self) -> Tuple[int, int]:
+        """One slot's convolution tail in one layer: the last ``d_conv - 1`` inputs."""
+        return (self.mamba_d_conv - 1, self.conv_dim)
+
+
+# ------------------------------------------------------------------ parameters
+def init_params(config: GraniteHybridConfig, key) -> Dict[str, Any]:
+    """Seeded random weights in the types they are served in (jit the call:
+    the float32 draws are then temporaries).  Matrices are normal with
+    variance 1 / fan-in; ``A_log``, ``dt_bias`` and ``D`` are as Mamba-2
+    initialises them (``A`` uniform in [1, 16], ``dt`` log-uniform in
+    [1e-3, 1e-1] through the inverse softplus, ``D`` = 1): with normal draws
+    the decay ``exp(dt A)`` is 0 or 1 and the recurrence does nothing."""
+    c, dt = config, config.dtype
+
+    def normal(k, shape, fan_in, dtype=dt):
+        return (jax.random.normal(k, shape, F32) / math.sqrt(fan_in)).astype(dtype)
+
+    def mamba(k):
+        ks = jax.random.split(k, 6)
+        step = jnp.exp(jax.random.uniform(ks[3], (c.mamba_n_heads,), F32) * (math.log(1e-1) - math.log(1e-3))
+                       + math.log(1e-3))
+        return {
+            "in_proj": normal(ks[0], (c.hidden_size, c.in_proj_dim), c.hidden_size),
+            "conv_weight": jax.random.uniform(ks[1], (c.mamba_d_conv, c.conv_dim), F32, -0.5, 0.5).astype(dt),
+            "conv_bias": jax.random.uniform(ks[2], (c.conv_dim,), F32, -0.5, 0.5).astype(dt),
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "A_log": jnp.log(jax.random.uniform(ks[4], (c.mamba_n_heads,), F32, 1.0, 16.0)),
+            "D": jnp.ones((c.mamba_n_heads,), F32),
+            "norm_weight": jnp.ones((c.d_inner,), dt),
+            "out_proj": normal(ks[5], (c.d_inner, c.hidden_size), c.d_inner),
+        }
+
+    def attention(k):
+        ks = jax.random.split(k, 4)
+        q, kv = c.num_attention_heads * c.head_dim, c.num_key_value_heads * c.head_dim
+        return {"q_proj": normal(ks[0], (c.hidden_size, q), c.hidden_size),
+                "k_proj": normal(ks[1], (c.hidden_size, kv), c.hidden_size),
+                "v_proj": normal(ks[2], (c.hidden_size, kv), c.hidden_size),
+                "o_proj": normal(ks[3], (q, c.hidden_size), q)}
+
+    def moe(k):
+        ks = jax.random.split(k, 7)
+        E, F, S, held = c.hidden_size, c.intermediate_size, c.shared_intermediate_size, c.experts_held
+        return {"router": normal(ks[0], (E, c.num_experts), E, F32),
+                "w_gate": normal(ks[1], (held, E, F), E), "w_up": normal(ks[2], (held, E, F), E),
+                "w_down": normal(ks[3], (held, F, E), F),
+                "shared_gate": normal(ks[4], (E, S), E), "shared_up": normal(ks[5], (E, S), E),
+                "shared_down": normal(ks[6], (S, E), S)}
+
+    params: Dict[str, Any] = {
+        "embed_tokens": {"embedding": normal(jax.random.fold_in(key, 1 << 20), (c.vocab_size, c.hidden_size),
+                                             c.hidden_size)},
+        "norm": {"weight": jnp.ones((c.hidden_size,), dt)},
+    }
+    for l, kind in enumerate(c.layer_types):
+        k_mixer, k_moe = jax.random.split(jax.random.fold_in(key, l))
+        params[f"layers_{l}"] = {
+            "input_layernorm": {"weight": jnp.ones((c.hidden_size,), dt)},
+            "post_attention_layernorm": {"weight": jnp.ones((c.hidden_size,), dt)},
+            "mixer": mamba(k_mixer) if kind == "mamba" else attention(k_mixer),
+            "moe": moe(k_moe),
+        }
+    return params
+
+
+# ------------------------------------------------------------- shared pieces
+def rmsnorm(x, w, eps):
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * w.astype(F32)
+
+
+def _mm(x, w, dtype):
+    """``x @ w`` with operands in ``dtype`` and a float32 result."""
+    return jnp.dot(x.astype(dtype), w.astype(dtype), preferred_element_type=F32)
+
+
+def embed(config: GraniteHybridConfig, params, tokens):
+    return config.embedding_multiplier * jnp.take(params["embed_tokens"]["embedding"], tokens, axis=0).astype(F32)
+
+
+def head(config: GraniteHybridConfig, params, x):
+    """Logits (float32) over the rows of the embedding held here."""
+    xn = rmsnorm(x, params["norm"]["weight"], config.rms_norm_eps)
+    return _mm(xn, params["embed_tokens"]["embedding"].T, config.dtype) / config.logits_scaling
+
+
+# ------------------------------------------------------------ Mamba-2 mixer
+def _mamba_in(c: GraniteHybridConfig, mp, u):
+    """``[z | xBC | dt] = W_in u``; xBC in the weights' type, as the
+    convolution tail is kept (prefill and decode then convolve the same values)."""
+    zxbcdt = _mm(u, mp["in_proj"], c.dtype)
+    z = zxbcdt[..., : c.d_inner]
+    xBC = zxbcdt[..., c.d_inner: c.d_inner + c.conv_dim].astype(c.dtype)
+    dt = jax.nn.softplus(zxbcdt[..., c.d_inner + c.conv_dim:] + mp["dt_bias"].astype(F32))
+    return z, xBC, dt
+
+
+def _mamba_split(c: GraniteHybridConfig, conv_out):
+    act = jax.nn.silu(conv_out)
+    x = act[..., : c.d_inner].reshape(act.shape[:-1] + (c.mamba_n_heads, c.mamba_d_head))
+    B = act[..., c.d_inner: c.d_inner + c.mamba_d_state]
+    C = act[..., c.d_inner + c.mamba_d_state:]
+    return x, B, C
+
+
+def _mamba_out(c: GraniteHybridConfig, mp, y, z):
+    """Gate first, then the norm over all of ``d_inner``, then ``W_out``."""
+    y = rmsnorm(y.reshape(y.shape[:-2] + (c.d_inner,)) * jax.nn.silu(z), mp["norm_weight"], c.rms_norm_eps)
+    return _mm(y, mp["out_proj"], c.dtype)
+
+
+def ssd_chunked(x, dt, A, B, C, chunk: int, initial_state=None):
+    """The recurrence ``h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T``, ``y_t =
+    h_t C_t`` over one sequence by chunks (Mamba-2's state-space duality):
+    inside a chunk a masked, decay-weighted ``(C B^T)`` product, between
+    chunks a scan over the chunk states.  ``x`` (T, H, P), ``dt`` (T, H),
+    ``A`` (H,), ``B`` and ``C`` (T, N); T a multiple of ``chunk``.  Returns
+    ``y`` (T, H, P) and the state after the last position (H, P, N), float32.
+    A position whose ``dt`` is 0 decays nothing and adds nothing."""
+    T, H, P = x.shape
+    N = B.shape[-1]
+    if T % chunk:
+        raise ValueError(f"{T} positions are not a whole number of chunks of {chunk}")
+    n = T // chunk
+    x, dt, B, C = (a.astype(F32) for a in (x, dt, B, C))
+    xd = (x * dt[..., None]).reshape(n, chunk, H, P)
+    Bc, Cc = B.reshape(n, chunk, N), C.reshape(n, chunk, N)
+    cs = jnp.cumsum((dt * A.astype(F32)).reshape(n, chunk, H), axis=1)          # (n, q, H), <= 0
+    ein = lambda spec, *ops: jnp.einsum(spec, *ops, precision=SCAN_PRECISION)
+    # inside a chunk: y_q += sum_{s<=q} (C_q . B_s) exp(cs_q - cs_s) dt_s x_s
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))[None, :, :, None]
+    decay = jnp.exp(jnp.where(causal, cs[:, :, None, :] - cs[:, None, :, :], -jnp.inf))   # (n, q, s, H)
+    y = ein("cqsh,cshp->cqhp", ein("cqn,csn->cqs", Cc, Bc)[..., None] * decay, xd)
+    # what each chunk adds to the state by its end, and the scan over chunks
+    added = ein("cqh,cqhp,cqn->chpn", jnp.exp(cs[:, -1:, :] - cs), xd, Bc)
+    h0 = jnp.zeros((H, P, N), F32) if initial_state is None else initial_state.astype(F32)
+
+    def over_chunks(h, inp):
+        add, total = inp
+        return jnp.exp(total)[:, None, None] * h + add, h
+
+    last, before = jax.lax.scan(over_chunks, h0, (added, cs[:, -1, :]))
+    y = y + ein("cqn,chpn,cqh->cqhp", Cc, before, jnp.exp(cs))
+    return y.reshape(T, H, P), last
+
+
+def mamba2_prefill(c: GraniteHybridConfig, mp, u, length):
+    """One sequence ``u`` (T, E), T a multiple of the chunk, of which the
+    first ``length`` positions are real.  In the pad ``dt`` is forced to 0, so
+    the state stands where the prompt ends, and the convolution tail is taken
+    from the prompt's last ``d_conv - 1`` real inputs (zeros before its
+    start).  Returns the mixer's output (T, E), the state in the cache's
+    layout (N, H P) and type, and the tail (d_conv - 1, conv_dim)."""
+    T, K = u.shape[0], c.mamba_d_conv
+    z, xBC, dt = _mamba_in(c, mp, u)
+    dt = jnp.where((jnp.arange(T) < length)[:, None], dt, 0.0)
+    padded = jnp.concatenate([jnp.zeros((K - 1, c.conv_dim), xBC.dtype), xBC], axis=0)
+    tail = jax.lax.dynamic_slice_in_dim(padded, length, K - 1, axis=0)
+    w = mp["conv_weight"].astype(F32)
+    conv = mp["conv_bias"].astype(F32) + sum(w[k] * padded[k: k + T].astype(F32) for k in range(K))
+    x, B, C = _mamba_split(c, conv)
+    y, state = ssd_chunked(x, dt, -jnp.exp(mp["A_log"].astype(F32)), B, C, c.mamba_chunk_size)
+    y = y + mp["D"].astype(F32)[:, None] * x
+    state = state.transpose(2, 0, 1).reshape(c.ssm_state_shape)          # (H, P, N) -> (N, H P)
+    return _mamba_out(c, mp, y, z), state.astype(c.state_dtype), tail
+
+
+def ssm_advance_xla(ssm, decay, dtx, B, C, *, layer: int):
+    """``h' = decay * h + B (x) dtx`` and ``y = sum_n h' C`` for every slot, on
+    the ``layer``-th state of ``ssm`` (layers, S, N, J); ``decay`` and ``dtx``
+    (S, J), ``B`` and ``C`` (S, N).  Returns ``ssm`` with that layer advanced
+    and ``y`` (S, J).  The XLA leg of ``kernels.ssm_step`` (which reads and
+    writes the state once; this reads it twice)."""
+    h = decay[:, None, :] * ssm[layer].astype(F32) + B[:, :, None] * dtx[:, None, :]
+    return ssm.at[layer].set(h.astype(ssm.dtype)), jnp.sum(h * C[:, :, None], axis=1)
+
+
+def mamba2_step(c: GraniteHybridConfig, mp, u, ssm, tail, *, layer: int, advance=ssm_advance_xla):
+    """The recurrence's one step for every slot: ``u`` (S, E), ``ssm`` the
+    states of all state-space layers (layers, S, N, H P) of which this mixer's
+    is the ``layer``-th, ``tail`` (S, d_conv - 1, conv_dim).
+    ``advance(ssm, decay, dtx, B, C, layer=)`` moves the state (the kernel on
+    TPU).  Returns the output (S, E), ``ssm`` and the tail, advanced."""
+    z, xBC, dt = _mamba_in(c, mp, u)
+    window = jnp.concatenate([tail, xBC[:, None, :].astype(tail.dtype)], axis=1)      # (S, K, conv_dim)
+    conv = mp["conv_bias"].astype(F32) + jnp.sum(mp["conv_weight"].astype(F32)[None] * window.astype(F32), axis=1)
+    x, B, C = _mamba_split(c, conv)
+    decay = jnp.exp(dt * -jnp.exp(mp["A_log"].astype(F32)))                               # (S, H)
+    S = u.shape[0]
+    ssm, y = advance(ssm, jnp.repeat(decay, c.mamba_d_head, axis=1), (dt[..., None] * x).reshape(S, c.d_inner), B, C,
+                     layer=layer)
+    y = y.reshape(x.shape) + mp["D"].astype(F32)[:, None] * x
+    return _mamba_out(c, mp, y, z), ssm, window[:, 1:]
+
+
+# ---------------------------------------------------------- attention mixer
+def _qkv(c: GraniteHybridConfig, ap, u):
+    lead = u.shape[:-1]
+    q = _mm(u, ap["q_proj"], c.dtype).astype(c.dtype).reshape(lead + (c.num_attention_heads, c.head_dim))
+    k = _mm(u, ap["k_proj"], c.dtype).astype(c.dtype).reshape(lead + (c.num_key_value_heads, c.head_dim))
+    v = _mm(u, ap["v_proj"], c.dtype).astype(c.dtype).reshape(lead + (c.num_key_value_heads, c.head_dim))
+    return q, k, v
+
+
+def attention_prefill(c: GraniteHybridConfig, ap, u, *, interpret: Optional[bool] = None):
+    """Causal attention over one sequence ``u`` (T, E) with no positional
+    term; returns the output (T, E) and this layer's K and V (T, KV, hd).
+    Pad positions follow the real ones, so causality keeps them out."""
+    from ..ops.flash_attention import flash_attention
+
+    q, k, v = _qkv(c, ap, u)
+    y = flash_attention(q[None], k[None], v[None], causal=True, scale=c.attention_multiplier, interpret=interpret)[0]
+    return _mm(y.reshape(u.shape[0], -1), ap["o_proj"], c.dtype), k, v
+
+
+def paged_attention_xla(q, k_pool, v_pool, table, valid_len, *, layer: int, scale: float):
+    """Decode attention of one layer without the kernel: gather every slot's
+    pages, mask by length, float32 softmax (the XLA leg of ``ServeEngine``)."""
+    S, H, hd = q.shape
+    KV = k_pool.shape[3]
+    ks = jnp.take(k_pool[layer], table, axis=0).reshape(S, -1, KV, hd)
+    vs = jnp.take(v_pool[layer], table, axis=0).reshape(S, -1, KV, hd)
+    qg = (q.astype(F32) * scale).reshape(S, KV, H // KV, hd)
+    s = jnp.einsum("skgd,stkd->skgt", qg, ks.astype(F32))
+    mask = jnp.arange(ks.shape[1], dtype=jnp.int32)[None, :] < valid_len[:, None]
+    p = jax.nn.softmax(jnp.where(mask[:, None, None, :], s, -1e30), axis=-1)
+    return jnp.einsum("skgt,stkd->skgd", p, vs.astype(F32)).reshape(S, H, hd)
+
+
+def attention_step(c: GraniteHybridConfig, ap, u, k_pool, v_pool, *, layer: int, table, page, offset, valid_len,
+                   attend):
+    """One new position a slot: its K and V go to ``(page, offset)`` of the
+    pool's ``layer`` (the null page for a slot that may not write), then
+    ``attend(q, k_pool, v_pool, table, valid_len, layer=, scale=)`` reads the
+    slot's pages.  Returns the output (S, E) and both pools."""
+    q, k, v = _qkv(c, ap, u)
+    k_pool = k_pool.at[layer, page, offset].set(k.astype(k_pool.dtype))
+    v_pool = v_pool.at[layer, page, offset].set(v.astype(v_pool.dtype))
+    y = attend(q, k_pool, v_pool, table, valid_len, layer=layer, scale=c.attention_multiplier)
+    return _mm(y.reshape(u.shape[0], -1), ap["o_proj"], c.dtype), k_pool, v_pool
+
+
+# -------------------------------------------------------------- expert layer
+def expert_layer(c: GraniteHybridConfig, ep, h, token_mask=None):
+    """``moe(h) + shared(h)`` for tokens ``h`` (N, E): the router scores all
+    ``num_experts``, the ten largest are kept and their gates are a softmax
+    over those ten; the held experts' part is computed without capacity
+    (``moe.dropless``), the shared expert on every token.  Returns the sum
+    (N, E) float32 and how many tokens each held expert got (held,)."""
+    scores = jnp.dot(h.astype(F32), ep["router"].astype(F32), precision=jax.lax.Precision.HIGHEST)
+    idx, gates = route_topk(scores, c.num_experts_per_tok)
+    routed, counts = dropless_experts(h, idx, gates, ep["w_gate"], ep["w_up"], ep["w_down"],
+                                      first_held=c.first_expert_held, token_mask=token_mask, dtype=c.dtype)
+    shared = _mm(jax.nn.silu(_mm(h, ep["shared_gate"], c.dtype)) * _mm(h, ep["shared_up"], c.dtype),
+                 ep["shared_down"], c.dtype)
+    return routed + shared, counts
+
+
+# ------------------------------------------------------------ whole layers
+def _mixer_input(c: GraniteHybridConfig, lp, x):
+    return rmsnorm(x, lp["input_layernorm"]["weight"], c.rms_norm_eps)
+
+
+def _after_mixer(c: GraniteHybridConfig, lp, x, y, token_mask):
+    """The mixer's output into the residual stream, then the expert layer's."""
+    x = x + c.residual_multiplier * y
+    with jax.named_scope("vs.moe"):
+        h = rmsnorm(x, lp["post_attention_layernorm"]["weight"], c.rms_norm_eps)
+        y, counts = expert_layer(c, lp["moe"], h, token_mask=token_mask)
+    return x + c.residual_multiplier * y, counts
+
+
+def layer_prefill(c: GraniteHybridConfig, lp, kind: str, x, length, *, interpret: Optional[bool] = None):
+    """One layer over one padded sequence ``x`` (T, E) float32.  Returns the
+    residual stream and what the layer leaves in the cache: ``(state, tail)``
+    of a state-space layer, ``(k, v)`` of an attention layer.  Pad positions
+    route to no expert."""
+    u = _mixer_input(c, lp, x)
+    if kind == "mamba":
+        with jax.named_scope("vs.mamba"):
+            y, *kept = mamba2_prefill(c, lp["mixer"], u, length)
+    else:
+        with jax.named_scope("vs.attn"):
+            y, *kept = attention_prefill(c, lp["mixer"], u, interpret=interpret)
+    x, _ = _after_mixer(c, lp, x, y, jnp.arange(x.shape[0]) < length)
+    return x, tuple(kept)
+
+
+def layer_step(c: GraniteHybridConfig, lp, kind: str, x, active, mixer_step):
+    """One layer over one new position a slot, ``x`` (S, E) float32;
+    ``mixer_step(u)`` is the mixer over this layer's share of the cache and
+    returns ``(y, *cache)``.  Slots that are not ``active`` route nowhere.
+    Returns the residual stream, the cache's parts and the held experts' counts."""
+    with jax.named_scope("vs.mamba" if kind == "mamba" else "vs.attn"):
+        y, *kept = mixer_step(_mixer_input(c, lp, x))
+    x, counts = _after_mixer(c, lp, x, y, active)
+    return x, tuple(kept), counts

@@ -4,3 +4,4 @@ from .experts_allocator import ExpertsAllocator, BasicExpertsAllocator
 from .token_dispatcher import TokenDispatcher
 from .moe_param_buffer import MoEParamBuffer
 from .moe_optimizer import MoEOptimizer
+from .dropless import dropless_experts, route_topk

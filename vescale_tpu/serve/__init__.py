@@ -31,7 +31,8 @@ from .fleettrace import (
     superseded_rids,
     verify_fleet_journeys,
 )
-from .kv_cache import KVCacheConfig, KVCacheOutOfPages, PagedKVCache
+from .hybrid_engine import HybridServeEngine
+from .kv_cache import KVCacheConfig, KVCacheOutOfPages, PagedKVCache, SlotStateUnsupported
 from .loop import ControlChannel, ServeResult, run_serve_resilient
 from .obs import FleetObservability, ServeObservability
 from .prefix_cache import PrefixCache
@@ -54,6 +55,8 @@ __all__ = [
     "Request",
     "ShedError",
     "ServeEngine",
+    "HybridServeEngine",
+    "SlotStateUnsupported",
     "ServeResult",
     "ServeObservability",
     "FleetObservability",

@@ -36,6 +36,20 @@ a reader.  Every inc/dec folds into the same event-sourced crc digest as
 alloc/commit/free, and ``fingerprint()`` carries the live reference
 total, so the PR-5/PR-10 cross-rank consistency check catches refcount
 divergence exactly like slot-assignment divergence.
+
+Slot state (a hybrid of state-space and attention layers rides this): beside
+the paged K and V, which then cover only the attention layers, the cache may
+hold per-slot arrays of CONSTANT size (``KVCacheConfig.slot_state``: a
+recurrent state, a convolution tail), each ``(layers, num_slots, ...)``.  A
+slot owns its row of each with its pages: one ``alloc`` / ``free`` / ``reset``
+covers both, and because a slot's row is wholly rewritten by its prefill,
+``free`` zeroes nothing and a preempted request re-prefills as ever.  What
+pages allow and a state does not is to be read at an earlier position: a state
+holds the whole prefix folded together, so sharing a prefix
+(:meth:`alloc_shared`) or rewinding a slot (:meth:`rollback`) would need a
+SNAPSHOT of the state at that position, which nothing here takes.  Both raise
+:class:`SlotStateUnsupported` on such a cache, and so do ``PrefixCache`` and
+``SpeculativeDecoder`` when they are built over one.
 """
 
 from __future__ import annotations
@@ -47,7 +61,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-__all__ = ["KVCacheConfig", "KVCacheOutOfPages", "PagedKVCache"]
+__all__ = ["KVCacheConfig", "KVCacheOutOfPages", "PagedKVCache", "SlotStateUnsupported"]
 
 
 class KVCacheOutOfPages(RuntimeError):
@@ -56,13 +70,23 @@ class KVCacheOutOfPages(RuntimeError):
     crash: ``reserve`` is called before any cache byte moves."""
 
 
+class SlotStateUnsupported(NotImplementedError):
+    """The cache holds per-slot recurrent state, and the operation needs the
+    state as it was at an earlier position: a snapshot nobody took."""
+
+
 @dataclasses.dataclass(frozen=True)
 class KVCacheConfig:
     """Static geometry of the paged cache.  ``max_seq_len`` (=
     ``page_size * pages_per_slot``) bounds prompt + generated tokens per
     request; ``num_pages`` defaults to one full allotment per slot plus the
     reserved null page (an intentionally tight pool — set it higher to
-    overcommit slots against typical-shorter-than-max sequences)."""
+    overcommit slots against typical-shorter-than-max sequences).
+
+    ``slot_state`` adds per-slot arrays beside the pages: entries ``(name,
+    layers, shape, dtype)`` give ``PagedKVCache.state[name]`` of shape
+    ``(layers, num_slots) + shape``; ``layers`` here then counts only the
+    layers that keep K and V."""
 
     layers: int
     kv_heads: int
@@ -72,6 +96,7 @@ class KVCacheConfig:
     pages_per_slot: int = 4
     num_pages: Optional[int] = None
     dtype: Any = None  # default jnp.float32
+    slot_state: Tuple[Tuple[str, int, Tuple[int, ...], Any], ...] = ()
 
     def __post_init__(self):
         if min(self.layers, self.kv_heads, self.head_dim) <= 0:
@@ -117,6 +142,16 @@ def _zeros_global(spec):
     return jax.make_array_from_callback(
         shape, sharding, lambda idx: np.zeros(_idx_shape(idx, shape), dt)
     )
+
+
+def _zeros_replicated(shape, dtype, mesh):
+    """A zero-filled array replicated over ``mesh``, made on the devices (a
+    slot state is gigabytes: no host copy)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=NamedSharding(mesh.jax_mesh, P()))()
 
 
 def _idx_shape(idx, shape) -> Tuple[int, ...]:
@@ -173,6 +208,11 @@ class PagedKVCache:
         with _memtrack.tagged("kv_cache"):
             self.k = _memtrack.tag_array(DArray(_zeros_global(self.spec), self.spec))
             self.v = _memtrack.tag_array(DArray(_zeros_global(self.spec), self.spec))
+            # per-slot state beside the pages, replicated over the mesh (see the module docstring)
+            self.state = {
+                name: _memtrack.tag_array(_zeros_replicated((layers, config.num_slots) + tuple(shape), dt, mesh))
+                for name, layers, shape, dt in config.slot_state
+            }
         # ---------------------------------------------- host bookkeeping
         self.page_table = np.zeros((config.num_slots, config.pages_per_slot), np.int32)
         self.lengths = np.zeros((config.num_slots,), np.int32)
@@ -203,6 +243,22 @@ class PagedKVCache:
     @property
     def max_seq_len(self) -> int:
         return self.config.max_seq_len
+
+    @property
+    def has_slot_state(self) -> bool:
+        return bool(self.state)
+
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of slot state one slot owns, all layers together."""
+        return sum(int(a.nbytes) for a in self.state.values()) // self.num_slots
+
+    def refuse_slot_state(self, what: str) -> None:
+        """Raise where ``what`` needs a slot's state as it was at an earlier position."""
+        if self.state:
+            raise SlotStateUnsupported(
+                f"{what} needs a slot's recurrent state as it was at an earlier position, and this cache "
+                f"({', '.join(sorted(self.state))} beside the pages) keeps only the newest: the missing "
+                "mechanism is a snapshot of the state at page boundaries")
 
     def pages_needed(self, tokens: int) -> int:
         return max(1, math.ceil(tokens / self.config.page_size))
@@ -276,6 +332,7 @@ class PagedKVCache:
         slot's leading table entries and allocate FRESH pages only for the
         rest of the request.  The shared pages gain one reference each;
         the slot's prefill then starts at the shared boundary."""
+        self.refuse_slot_state("alloc_shared (prefix sharing)")
         total = prompt_tokens + max_new_tokens
         if total > self.max_seq_len:
             raise KVCacheOutOfPages(
@@ -334,6 +391,7 @@ class PagedKVCache:
         speculative drafter's post-verify rewind (rejected draft positions
         become uncommitted garbage again, overwritten by the next write).
         Pages stay reserved; only the length bookkeeping moves."""
+        self.refuse_slot_state("rollback (speculation)")
         cur = int(self.lengths[slot])
         if not (0 <= length <= cur):
             raise ValueError(f"slot {slot}: rollback to {length} from {cur}")
@@ -415,6 +473,12 @@ class PagedKVCache:
         self.k = DArray(k_data, self.spec)
         self.v = DArray(v_data, self.spec)
 
+    def update_state(self, **arrays) -> None:
+        """Take back the slot state an engine step was given (donated)."""
+        if set(arrays) != set(self.state):
+            raise ValueError(f"slot state is {sorted(self.state)}, got {sorted(arrays)}")
+        self.state = dict(arrays)
+
     def table_array(self) -> np.ndarray:
         return np.ascontiguousarray(self.page_table)
 
@@ -431,14 +495,17 @@ class PagedKVCache:
         exchange is O(1), and deliberately EXCLUDES device bytes (the null
         page legally holds scatter garbage).  The live page-reference
         total rides along so shared-prefix refcount divergence trips the
-        same DesyncError as slot-assignment divergence."""
-        return (
+        same DesyncError as slot-assignment divergence.  A slot's state has
+        no bookkeeping of its own (it goes with the slot, whose assignment the
+        digest holds); a cache that keeps one says so, and how much."""
+        base = (
             self._digest,
             len(self._free_slots),
             len(self._free_pages),
             self._tokens_held,
             int(self._page_refs.sum()),
         )
+        return base + (self.state_bytes_per_slot(),) if self.state else base
 
     def utilization(self) -> float:
         usable = self.num_pages - 1

@@ -93,6 +93,7 @@ class PrefixCache:
     every prefill (:meth:`insert`)."""
 
     def __init__(self, cache: PagedKVCache, max_pages: Optional[int] = None):
+        cache.refuse_slot_state("a prefix cache")     # a state cannot be shared page by page: kv_cache.py says why
         self.cache = cache
         self.page = cache.config.page_size
         # cap on tree-RETAINED pages (0/None = bounded only by the pool);

@@ -164,9 +164,14 @@ class ContinuousBatchingScheduler:
 
             prefix_cache = PrefixCache.from_env(cache)
         self.prefix = prefix_cache
-        self.max_queue = (
-            max_queue if max_queue is not None else envreg.get_int("VESCALE_SERVE_MAX_QUEUE")
-        )
+        if max_queue is None:
+            max_queue = envreg.get_int("VESCALE_SERVE_MAX_QUEUE")
+            if envreg.get_raw("VESCALE_SERVE_MAX_QUEUE") is None:
+                # nobody set a bound: the default may not be smaller than what the
+                # cache admits at once plus as many waiting, or a burst that fits
+                # the slots is shed before admit() has run
+                max_queue = max(max_queue, 2 * cache.num_slots)
+        self.max_queue = max_queue
         if slo_ttft_s is None:
             slo_ttft_s = envreg.get_float("VESCALE_SERVE_SLO_TTFT_S")
         self.slo_ttft_s = float(slo_ttft_s) if slo_ttft_s else 0.0

@@ -171,6 +171,7 @@ class SpeculativeDecoder:
             raise ValueError(f"speculative k must be >= 1, got {k}")
         if drafter_layers is None:
             drafter_layers = envreg.get_int("VESCALE_SPEC_DRAFTER_LAYERS")
+        engine.cache.refuse_slot_state("speculative decoding")    # rejected drafts rewind the cache: kv_cache.py
         self.k = int(k)
         self.target = engine
         tc = engine.cache.config
