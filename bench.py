@@ -719,6 +719,7 @@ def bench_serve():
     from vescale_tpu.resilience import Watchdog, faultsim
     from vescale_tpu.serve import (
         ContinuousBatchingScheduler,
+        DecodeStep,
         KVCacheConfig,
         PagedKVCache,
         Request,
@@ -863,7 +864,7 @@ def bench_serve():
 
         def __init__(self, slots, vocab):
             self._p = np.zeros((vocab,), np.float32)
-            self._d = np.zeros((slots, vocab), np.float32)
+            self._d = DecodeStep(np.zeros((slots,), np.int32), np.zeros((slots, vocab), np.float32))
 
         def prefill(self, prompt, slot):
             return self._p

@@ -687,6 +687,9 @@ def phase_serve(cfg, sizes, args, on_tpu, devices):
             for s in slots:
                 cache.advance(s)
             dec.append(logits[slots])
+            # the ids the decode program took are the host's argmax of its logits, every slot
+            if not np.array_equal(logits.tokens, np.argmax(np.asarray(logits), -1)):
+                raise RuntimeError(f"serve kernels={mode}: decode's tokens are not the argmax of its logits")
         logits_by_mode[mode] = (np.stack(rows), np.stack(dec))
         stats = devices[0].memory_stats() or {}
         log(f"serve kernels={mode}", peak_bytes_in_use=stats.get("peak_bytes_in_use"))

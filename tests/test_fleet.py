@@ -28,6 +28,7 @@ from vescale_tpu.serve import (
     CircuitBreaker,
     ConsistentHashRing,
     ContinuousBatchingScheduler,
+    DecodeStep,
     FleetLedger,
     FleetRouter,
     HttpReplicaClient,
@@ -667,7 +668,7 @@ class _NopEngine:
         import numpy as np
 
         self._p = np.zeros((vocab,), np.float32)
-        self._d = np.zeros((slots, vocab), np.float32)
+        self._d = DecodeStep(np.zeros((slots,), np.int32), np.zeros((slots, vocab), np.float32))
 
     def prefill(self, prompt, slot):
         return self._p

@@ -482,6 +482,7 @@ def test_loop_trace_persistence_scoped_no_handler_leak(tmp_path, monkeypatch):
     from vescale_tpu.ndtimeline.parser_handler import parse_raw_spans
     from vescale_tpu.serve import (
         ContinuousBatchingScheduler,
+        DecodeStep,
         KVCacheConfig,
         PagedKVCache,
         ServeEngine,
@@ -493,7 +494,7 @@ def test_loop_trace_persistence_scoped_no_handler_leak(tmp_path, monkeypatch):
 
         def __init__(self, slots, vocab=8):
             self._p = np.zeros((vocab,), np.float32)
-            self._d = np.zeros((slots, vocab), np.float32)
+            self._d = DecodeStep(np.zeros((slots,), np.int32), np.zeros((slots, vocab), np.float32))
 
         def prefill(self, prompt, slot):
             return self._p

@@ -210,8 +210,10 @@ def test_the_counters_count_what_the_decode_steps_routed(system):
         engine.prefill(tokens(20 + n, n), s)
         cache.commit_prefill(s, n)
         slots.append(s)
-    decode_one(engine, cache, {s: 1 for s in slots})
-    decode_one(engine, cache, {s: 2 for s in slots})
+    first = decode_one(engine, cache, {s: 1 for s in slots})
+    second = decode_one(engine, cache, {s: 2 for s in slots})
+    assert engine.trace_counters()["logits_bytes_to_host"] == before["logits_bytes_to_host"], "nobody read a row yet"
+    assert first[slots[0]].shape == (cfg.vocab_size,) and np.asarray(second).shape == second.shape
     d = {k: v - before[k] for k, v in engine.trace_counters().items()}
     layers, k, held = cfg.num_hidden_layers, cfg.num_experts_per_tok, cfg.experts_held
     assert d["decode_steps"] == 2 and d["moe_assignments"] == 2 * 2 * k * layers
@@ -221,7 +223,7 @@ def test_the_counters_count_what_the_decode_steps_routed(system):
     assert d["moe_busiest_expert_tokens"] * held >= d["moe_assignments_held"], "the busiest is at least the mean"
     assert d["ssm_state_bytes_rw"] == 2 * 2 * SLOTS * cache.state_bytes_per_slot()
     assert d["prefill_tokens_real"] == 14 and d["prefill_bucket_tokens"] == 8 + 16
-    assert d["logits_bytes_to_host"] == 2 * SLOTS * cfg.vocab_size * 4
+    assert d["logits_bytes_to_host"] == (1 + SLOTS) * cfg.vocab_size * 4, "one row of the first step, the second whole"
     cache.reset()
 
 

@@ -674,6 +674,119 @@ def test_loop_serving_dashboard_block(serve_rig, tmp_path):
     assert "serve_ttft_seconds" in snap["histograms"]
 
 
+# =========================================== what a decode step returns
+TIED = ((5, 9), (7, 11))    # two pairs of ids with one row of the head each, the second pair's negated
+
+
+@pytest.fixture(scope="module", params=["dense_tp2", "hybrid"])
+def step_rig(request):
+    """Both engines behind one surface: (engine, cache, vocab, weights whose
+    head gives TIED's ids equal logits, a call that serves other weights and
+    returns the ones before).  The dense one is the loop tests' engine on the
+    tp-2 mesh; the hybrid one is ``test_granite_hybrid``'s toy."""
+    if request.param == "dense_tp2":
+        eng, cache = request.getfixturevalue("serve_rig")
+        vocab, head = CFG.vocab_size, np.array(eng.params["lm_head"]["kernel"]).T
+        tied = lambda rows: {**eng.params, "lm_head": {"kernel": np.ascontiguousarray(rows.T)}}
+        swap = eng.swap_params
+    else:
+        from tests.test_granite_hybrid import PAGE, PAGES, SLOTS, gh, hybrid_cache_config, toy_config
+        from vescale_tpu.serve import HybridServeEngine
+
+        cfg = toy_config()
+        mesh = DeviceMesh(("tp",), (1,), devices=jax.devices()[:1])
+        params = jax.jit(lambda k: gh.init_params(cfg, k))(jax.random.key(7))
+        cache = PagedKVCache(hybrid_cache_config(cfg, num_slots=SLOTS, page_size=PAGE, pages_per_slot=PAGES), mesh)
+        eng = HybridServeEngine(cfg, mesh, params, cache)
+        vocab, head = cfg.vocab_size, np.array(params["embed_tokens"]["embedding"])
+        tied = lambda rows: {**eng.params, "embed_tokens": {"embedding": jnp.asarray(rows)}}
+
+        def swap(new):      # every program takes the tree as an argument
+            prior, eng.params = eng.params, new
+            return prior
+    # one direction, a thousand times a row's length: one pair or the other holds every row's largest logit
+    u = 1e3 * np.random.default_rng(5).standard_normal(head.shape[1]).astype(head.dtype) / np.sqrt(head.shape[1])
+    for (a, b), sign in zip(TIED, (1, -1)):
+        head[a] = head[b] = sign * u
+    return eng, cache, vocab, tied(head), swap
+
+
+def _decode_forced(eng, cache, slots, tok):
+    toks = np.zeros((cache.num_slots,), np.int32)
+    toks[slots] = tok
+    step = eng.decode(toks)
+    for s in slots:
+        cache.advance(s)
+    return step
+
+
+def test_decode_step_tokens_are_the_argmax_of_its_logits_and_a_tie_goes_to_the_lowest_id(step_rig):
+    eng, cache, vocab, tied_params, swap = step_rig
+    cache.reset()
+    prior = swap(tied_params)
+    try:
+        slots = []
+        for prompt in ((2, 3, 4), (13, 21, 34, 55)):
+            slots.append(cache.alloc(len(prompt), 4))
+            eng.prefill(prompt, slots[-1])
+            cache.commit_prefill(slots[-1], len(prompt))
+        for tok in (17, 23, 42):
+            step = _decode_forced(eng, cache, slots, tok)
+            logits = np.asarray(step)
+            assert isinstance(step.tokens, np.ndarray) and step.tokens.dtype == np.int32
+            assert step.tokens.shape == (cache.num_slots,) and step.shape == logits.shape == (cache.num_slots, vocab)
+            np.testing.assert_array_equal(step.tokens, np.argmax(logits, -1))
+            for s in slots:
+                top = np.flatnonzero(logits[s] == logits[s].max())
+                assert tuple(top) in TIED and step.tokens[s] == top[0], (top, step.tokens[s])
+    finally:
+        swap(prior)
+        cache.reset()
+
+
+def test_logits_cross_to_the_host_only_when_a_caller_reads_them(step_rig):
+    eng, cache, vocab, _, _ = step_rig
+    cache.reset()
+    slot = cache.alloc(3, 8)
+    eng.prefill((5, 9, 17), slot)
+    cache.commit_prefill(slot, 3)
+
+    def copied(read):
+        before = eng.trace_counters()["logits_bytes_to_host"]
+        read(_decode_forced(eng, cache, [slot], 3))
+        return eng.trace_counters()["logits_bytes_to_host"] - before
+
+    assert copied(lambda step: (step.tokens[slot], step.shape)) == 0
+    assert copied(lambda step: step[slot]) == vocab * 4
+    assert copied(lambda step: np.asarray(step)) == cache.num_slots * vocab * 4
+    assert copied(lambda step: (step[slot], step[slot], np.stack([step]))) == (2 + cache.num_slots) * vocab * 4
+    # a list of rows indexes as it does an ndarray (chip_smoke.py reads ``logits[slots]``)
+    assert copied(lambda step: np.testing.assert_array_equal(step[[slot, 0]], np.asarray(step)[[slot, 0]])) == (
+        2 + cache.num_slots) * vocab * 4
+    assert copied(lambda step: (len(step), step.dtype)) == 0
+    step = _decode_forced(eng, cache, [slot], 3)
+    assert len(step) == cache.num_slots and step.dtype == np.float32
+    cache.reset()
+
+
+def test_the_loops_streams_are_those_of_a_host_argmax_over_every_row(step_rig):
+    """The ids the decode program takes are, token for token, what
+    ``np.argmax`` of the copied rows gave (``_gen_tokens``); and the loop
+    pays for no logits."""
+    eng, cache, vocab, _, _ = step_rig
+    arrivals = _arrivals(n=4)
+    before = eng.trace_counters()
+    res, sched = _run(eng, cache, arrivals)
+    loop = {k: v - before[k] for k, v in eng.trace_counters().items()}
+    assert res.status == "completed" and loop["decode_steps"] >= 3 and loop["logits_bytes_to_host"] == 0
+    sched.ledger_check()
+    cache.reset()
+    for _, req in arrivals:
+        before = eng.trace_counters()["logits_bytes_to_host"]
+        assert res.outcomes[req.rid]["tokens"] == _gen_tokens(eng, cache, list(req.prompt), req.max_new_tokens)
+        assert eng.trace_counters()["logits_bytes_to_host"] - before == (req.max_new_tokens - 1) * vocab * 4
+
+
 # ==================================================== train->serve handoff
 def test_train_to_serve_handoff_elastic_params_only(tmp_path, model_and_params):
     """Satellite 3: a training checkpoint (params + optimizer, written on a

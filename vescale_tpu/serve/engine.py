@@ -35,7 +35,9 @@ come and go:
   engine is BUILT (compiled programs are static); rebuild to switch.
   ``decode_multi`` keeps the XLA chain.  Inactive
   slots compute too (static shapes) but write only the reserved null page
-  and their logits are ignored.
+  and their logits are ignored.  The same program takes every slot's greedy
+  token, and ``decode`` returns those ids (``DecodeStep``): the logits stay
+  on the device unless a caller reads them.
 
 Decode is a deterministic function of (params, prompt, cache geometry):
 an evicted-and-replayed request regenerates bit-identical tokens in any
@@ -55,7 +57,53 @@ from ..ndtimeline import predefined as _p
 from ..ndtimeline.api import ndtimeit, register_counter_source
 from .kv_cache import PagedKVCache
 
-__all__ = ["ServeEngine", "stack_params_check"]
+__all__ = ["DecodeStep", "ServeEngine", "stack_params_check"]
+
+
+class DecodeStep:
+    """What one ``decode`` call returns.  ``tokens`` is every slot's greedy
+    token, int32 ``(num_slots,)`` on the host: the argmax of the slot's
+    logits row, taken inside the decode program (ties break to the lowest
+    id, a NaN counts as the largest, as ``np.argmax`` has it).  The fp32
+    ``(num_slots, vocab)`` logits stay where the program wrote them and
+    cross to the host only when a caller reads them: ``step[slot]`` copies
+    one row (``step[[a, b]]`` those rows, indexed as an ndarray is),
+    ``np.asarray(step)`` all of them, ``step.shape``, ``step.dtype`` and
+    ``len(step)`` none, and each copy adds its bytes to
+    ``owner.logits_bytes_to_host``."""
+
+    __slots__ = ("tokens", "_logits", "_owner")
+
+    def __init__(self, tokens: np.ndarray, logits, owner=None):
+        self.tokens = tokens
+        self._logits = logits
+        self._owner = owner
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        return tuple(self._logits.shape)
+
+    @property
+    def dtype(self) -> np.dtype:
+        return np.dtype(self._logits.dtype)
+
+    def __len__(self) -> int:
+        return self._logits.shape[0]
+
+    def _to_host(self, rows) -> np.ndarray:
+        out = np.asarray(rows)
+        if self._owner is not None:
+            self._owner.logits_bytes_to_host += out.nbytes
+        return out
+
+    def __getitem__(self, index) -> np.ndarray:
+        if isinstance(index, list):   # numpy takes a list of rows; a jax array refuses one
+            index = np.asarray(index)
+        return self._to_host(self._logits[index])
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        out = self._to_host(self._logits)
+        return out if dtype is None else out.astype(dtype, copy=False)
 
 
 def _rmsnorm(x, w, eps):
@@ -392,9 +440,13 @@ class ServeEngine:
                 gt = dense(xn2, lp["mlp"]["gate_proj"]["kernel"])
                 u = dense(xn2, lp["mlp"]["up_proj"]["kernel"])
                 x = x + dense(jax.nn.silu(gt) * u, lp["mlp"]["down_proj"]["kernel"])
-            logits = head(params, x)
+            logits = jax.lax.with_sharding_constraint(head(params, x), rep_sharding)
+            # the greedy token of every slot, in this program: of the replicated
+            # logits, so every device of a tp mesh holds the same ids
+            next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (
-                jax.lax.with_sharding_constraint(logits, rep_sharding),
+                logits,
+                jax.lax.with_sharding_constraint(next_ids, rep_sharding),
                 jax.lax.with_sharding_constraint(kd, cache_sharding),
                 jax.lax.with_sharding_constraint(vd, cache_sharding),
             )
@@ -496,16 +548,20 @@ class ServeEngine:
         self.prefill_tokens_padded += cache.max_seq_len
         return out
 
-    def decode(self, tokens: np.ndarray) -> np.ndarray:
+    def decode(self, tokens: np.ndarray) -> DecodeStep:
         """One decode step for every slot (inactive slots write only the
         null page): appends each token's K/V at its slot's current length
-        and returns (num_slots, vocab) fp32 logits for the NEXT position.
+        and returns a :class:`DecodeStep` for the NEXT position: every
+        slot's greedy token on the host, and the (num_slots, vocab) fp32
+        logits, which a caller that reads them copies from the device.
         Callers advance lengths via ``cache.advance`` for slots whose
         token was real."""
+        import jax
+
         cache = self.cache
         lengths = cache.lengths_array()
         with ndtimeit(_p.SERVE_DECODE_CALL):
-            logits, kd, vd = self._decode_fn(
+            logits, next_ids, kd, vd = self._decode_fn(
                 self.params,
                 cache.k.data,
                 cache.v.data,
@@ -514,10 +570,9 @@ class ServeEngine:
                 np.asarray(tokens, np.int32).reshape(cache.num_slots),
             )
             cache.update(kd, vd)
-            with ndtimeit(_p.SERVE_DECODE_FETCH):   # waits for the device, then copies every slot's logits
-                out = np.asarray(logits)
+            with ndtimeit(_p.SERVE_DECODE_FETCH):   # waits for the device and the ids
+                out = DecodeStep(jax.device_get(next_ids), logits, self)
         self.decode_steps += 1
-        self.logits_bytes_to_host += out.nbytes
         if self.kernel_decode:
             # what the kernel fetched: each slot's pages up to its new token
             # (an inactive slot's one), of the table's S x Pmax
@@ -529,13 +584,15 @@ class ServeEngine:
     def trace_counters(self) -> Dict[str, int]:
         """The engine's own counts since it was built (a trace session
         reports what was added while it ran).  ``decode_steps`` and
-        ``logits_bytes_to_host`` are of ``decode`` calls: every slot's fp32
-        row, each step (prefill copies one row, ``decode_multi`` is not
-        counted).  ``decode_pages_read`` of ``decode_pages_capacity`` says how
-        far the ``paged_decode`` kernel engaged: the pages of K (and as many
-        of V) it fetched a layer, summed over ``decode`` calls, against the
-        ``slots x pages_per_slot`` the XLA leg gathers; both stay 0 on an
-        engine built with the XLA leg."""
+        ``logits_bytes_to_host`` are of ``decode`` calls: the bytes of fp32
+        logits that callers copied out of their results (none for a step
+        read through ``.tokens`` alone, ``vocab x 4`` a row, ``slots x
+        vocab x 4`` a whole read; prefill copies one row and
+        ``decode_multi`` every row, neither counted).  ``decode_pages_read``
+        of ``decode_pages_capacity`` says how far the ``paged_decode`` kernel
+        engaged: the pages of K (and as many of V) it fetched a layer, summed
+        over ``decode`` calls, against the ``slots x pages_per_slot`` the XLA
+        leg gathers; both stay 0 on an engine built with the XLA leg."""
         return {"decode_steps": self.decode_steps, "logits_bytes_to_host": self.logits_bytes_to_host,
                 "prefill_tokens_real": self.prefill_tokens_real,
                 "prefill_tokens_padded": self.prefill_tokens_padded,
@@ -625,27 +682,29 @@ class ServeEngine:
         cache = self.cache
         slot = cache.alloc(len(prompt), max_new_tokens)
 
-        def _pick(row: np.ndarray) -> int:
+        def _pick(tok: int, row) -> int:
+            # ``tok`` is the greedy token of the logits row that ``row()``
+            # copies to the host: only a firing fault reads it
             if canary and _fs.fires("canary_diverge", ctx="replay"):
-                row = np.array(row, copy=True)
-                j = int(np.argmax(row))
-                row[j] = -row[j]
-            return self.greedy(row)
+                flipped = np.array(row(), copy=True)
+                flipped[tok] = -flipped[tok]
+                return self.greedy(flipped)
+            return tok
 
         try:
-            row = self.prefill(list(prompt), slot)
+            first = self.prefill(list(prompt), slot)
             cache.commit_prefill(slot, len(prompt))
             out: List[int] = []
-            tok = _pick(row)
+            tok = _pick(self.greedy(first), lambda: first)
             out.append(tok)
             for _ in range(max_new_tokens - 1):
                 if eos_id is not None and tok == eos_id:
                     break
                 toks = np.zeros((cache.num_slots,), np.int32)
                 toks[slot] = tok
-                logits = self.decode(toks)
+                step = self.decode(toks)
                 cache.advance(slot)
-                tok = _pick(logits[slot])
+                tok = _pick(int(step.tokens[slot]), lambda: step[slot])
                 out.append(tok)
             return out
         finally:

@@ -46,6 +46,7 @@ import numpy as np
 
 from ..ndtimeline import predefined as _p
 from ..ndtimeline.api import ndtimeit, register_counter_source
+from .engine import DecodeStep
 from .kv_cache import KVCacheConfig, PagedKVCache, SlotStateUnsupported
 
 __all__ = ["HybridServeEngine", "hybrid_cache_config", "prefill_buckets"]
@@ -210,7 +211,10 @@ class HybridServeEngine:
                 else:
                     kd, vd = kept
                 counts.append(n)
-            return gh.head(c, params, x), jnp.stack(counts), kd, vd, ssm, conv
+            logits = gh.head(c, params, x)
+            # every slot's greedy token, in this program (``DecodeStep.tokens``)
+            next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return logits, next_ids, jnp.stack(counts), kd, vd, ssm, conv
 
         self._decode_fn = jax.jit(decode, donate_argnums=(1, 2, 3, 4))
 
@@ -242,11 +246,11 @@ class HybridServeEngine:
 
     def _run_decode(self, table, lengths, tokens):
         cache = self.cache
-        logits, counts, kd, vd, ssm, conv = self._decode_fn(
+        logits, next_ids, counts, kd, vd, ssm, conv = self._decode_fn(
             self.params, cache.k.data, cache.v.data, cache.state["ssm"], cache.state["conv"], table, lengths, tokens)
         cache.update(kd, vd)
         cache.update_state(ssm=ssm, conv=conv)
-        return logits, counts
+        return logits, next_ids, counts
 
     def prefill(self, prompt: Sequence[int], slot: int) -> np.ndarray:
         """Run the prompt through the stack in its bucket, write its K/V into
@@ -269,21 +273,25 @@ class HybridServeEngine:
         self.prefill_bucket_tokens += bucket
         return out
 
-    def decode(self, tokens: np.ndarray) -> np.ndarray:
+    def decode(self, tokens: np.ndarray) -> DecodeStep:
         """One decode step for every slot: each active slot's state advances
         by its token, its K/V lands at its current length, and the
-        (num_slots, vocab) fp32 logits are those of the NEXT position.
-        Callers advance lengths via ``cache.advance``."""
+        :class:`DecodeStep` is that of the NEXT position (every slot's greedy
+        token on the host; the (num_slots, vocab) fp32 logits on the device
+        until a caller reads them).  Callers advance lengths via
+        ``cache.advance``."""
+        import jax
+
         cache, c = self.cache, self.config
         lengths = cache.lengths_array()
         with ndtimeit(_p.SERVE_DECODE_CALL):
-            logits, counts = self._run_decode(cache.table_array(), lengths,
-                                              np.asarray(tokens, np.int32).reshape(cache.num_slots))
-            with ndtimeit(_p.SERVE_DECODE_FETCH):   # waits for the device, then copies every slot's logits
-                out = np.asarray(logits)
-                counts = np.asarray(counts)          # (layers, held): the tokens each held expert got
+            logits, next_ids, counts = self._run_decode(cache.table_array(), lengths,
+                                                        np.asarray(tokens, np.int32).reshape(cache.num_slots))
+            with ndtimeit(_p.SERVE_DECODE_FETCH):   # waits for the device, the ids and the experts' counts
+                # one get: both copies are started, then waited for; counts (layers, held): tokens an expert got
+                next_ids, counts = jax.device_get((next_ids, counts))
+            out = DecodeStep(next_ids, logits, self)
         self.decode_steps += 1
-        self.logits_bytes_to_host += out.nbytes
         self.moe_assignments += int((lengths > 0).sum()) * c.num_experts_per_tok * c.num_hidden_layers
         self.moe_assignments_held += int(counts.sum())
         self.moe_busiest_expert_tokens += int(counts.max(axis=1).sum())
@@ -299,7 +307,8 @@ class HybridServeEngine:
 
     def trace_counters(self) -> Dict[str, int]:
         """The engine's own counts since it was built.  Those ``ServeEngine``
-        has mean the same here (``prefill_tokens_padded`` is the bucket;
+        has mean the same here (``logits_bytes_to_host`` is what callers copied
+        out of ``decode``'s results; ``prefill_tokens_padded`` is the bucket;
         ``decode_pages_*`` are a layer's, and count only with the kernel leg).
         Of ``decode`` calls alone: ``moe_assignments`` = active slots x experts
         per token x layers, ``moe_assignments_held`` those that fell on an

@@ -785,11 +785,13 @@ def run_serve_resilient(
                     # the target's argmaxes either way, and k+1 drafter
                     # launches plus a (k+1)-wide verify that drafts
                     # nothing would only add cost
-                    logits = engine.decode(tokens)
+                    # the step's greedy ids, taken in the decode program; its
+                    # logits stay on the device (nothing here reads them)
+                    next_ids = engine.decode(tokens).tokens
                     with _nd.ndtimeit(_SERVE_SAMPLE):
                         for slot in sorted(active_slots):
                             cache.advance(slot)
-                            _sample(slot, engine.greedy(logits[slot]))
+                            _sample(slot, int(next_ids[slot]))
                 else:
                     # draft-then-verify (speculative.py): the drafter
                     # proposes k tokens per mirrored slot, the target

@@ -267,12 +267,12 @@ class SpeculativeDecoder:
         cur = [int(t) for t in last_tokens]
         drafts = np.zeros((S, self.k), np.int32)
         for i in range(self.k + 1):
-            logits = self.engine.decode(cur)
+            next_ids = self.engine.decode(cur).tokens
             for slot in active_slots:
                 if self.cache.can_advance(slot):
                     self.cache.advance(slot)
                 if i < self.k:
-                    t = int(np.argmax(logits[slot]))
+                    t = int(next_ids[slot])
                     drafts[slot, i] = t
                     cur[slot] = t
         return drafts
