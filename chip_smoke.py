@@ -142,7 +142,7 @@ def write_token_file(path: str, vocab: int, n_tokens: int, seed: int) -> None:
 
 
 def build_trainer(cfg, mesh, *, seed, lr, sequence_parallel, zero):
-    """The README quick-start assembly (bench.py main / run_open_llama)."""
+    """The README quick-start assembly (run_open_llama)."""
     import jax
     import jax.numpy as jnp
 

@@ -25,14 +25,6 @@ same prompt — decode determinism at fleet scope):
             breaker walked closed -> open -> half-open -> closed, and the
             REJOINED replica resolves fresh traffic.
 
-``run_bench()`` is the ``VESCALE_BENCH=fleet`` rung: 2 replicas under a
-5x-capacity overload with a mid-run kill + rejoin — aggregate tokens/s,
-fleet p99 TTFT, shed rate — plus the router-hop overhead line (router
-dispatch vs direct submit, as a fraction of a measured decode step,
-acceptance < 1%) and the tracing-on vs tracing-off hop line
-(``fleet_trace_overhead_frac``: what the ISSUE-14 fleet span chain adds
-per request over the same service-time denominator, same < 1% bar).
-
 Exit 0 on success.  Wired into scripts/run_test.sh and tier-1 via
 tests/test_fleet.py.
 """
@@ -55,24 +47,22 @@ WAVE1 = 12  # rids 0..11, both legs
 WAVE2 = 6   # rids 100..105, kill leg only (post-rejoin traffic)
 
 
-def _prompts(n, base_rid=0, max_new=None):
+def _prompts(n, base_rid=0):
     import numpy as np
 
     rng = np.random.default_rng(23)
     out = []
     for i in range(n):
         prompt = tuple(int(x) for x in rng.integers(1, 60, 3 + (i % 3)))
-        out.append((base_rid + i, prompt, max_new or (4 + (i % 3))))
+        out.append((base_rid + i, prompt, 4 + (i % 3)))
     return out
 
 
 # --------------------------------------------------------------------- child
-def replica_child(profile: str = "smoke") -> None:
+def replica_child() -> None:
     """One fleet replica: llama from a FIXED seed (every replica serves
     identical params — the fleet's determinism contract), fed over the
-    ops endpoints, drained by SIGTERM.  ``profile="bench"`` uses the
-    serve-rung-class model (hidden 64) so the bench's decode-step
-    denominator is a real step, not a toy one."""
+    ops endpoints, drained by SIGTERM."""
     import jax
 
     jax.config.update("jax_platforms", "cpu")
@@ -88,32 +78,23 @@ def replica_child(profile: str = "smoke") -> None:
         serve_replica,
     )
 
-    if profile == "bench":
-        cfg = LlamaConfig(
-            vocab_size=512, hidden_size=64, intermediate_size=128,
-            num_hidden_layers=4, num_attention_heads=8, num_key_value_heads=8,
-            max_position_embeddings=64, dtype=jnp.float32,
-        )
-    else:
-        cfg = LlamaConfig(
-            vocab_size=64, hidden_size=16, intermediate_size=32,
-            num_hidden_layers=2, num_attention_heads=2, num_key_value_heads=2,
-            max_position_embeddings=64, dtype=jnp.float32,
-        )
+    cfg = LlamaConfig(
+        vocab_size=64, hidden_size=16, intermediate_size=32,
+        num_hidden_layers=2, num_attention_heads=2, num_key_value_heads=2,
+        max_position_embeddings=64, dtype=jnp.float32,
+    )
     mesh = DeviceMesh(("tp",), (1,), devices=jax.devices()[:1])
     model = Llama(cfg)
     params = model.init(jax.random.key(0), jnp.ones((1, 8), jnp.int32))["params"]
-    pages = 8 if profile == "bench" else 4  # bench decodes 16-token budgets
     kc = KVCacheConfig(
         layers=cfg.num_hidden_layers, kv_heads=cfg.num_key_value_heads,
         head_dim=cfg.head_dim, num_slots=SLOTS, page_size=4,
-        pages_per_slot=pages,
+        pages_per_slot=4,
     )
     cache = PagedKVCache(kc, mesh)
     engine = ServeEngine(cfg, mesh, params, cache)
     # queue bound comes from the env (the driver's ReplicaSpec sets
-    # VESCALE_SERVE_MAX_QUEUE): the bench rung's tight-queue overload
-    # override must actually reach the replica
+    # VESCALE_SERVE_MAX_QUEUE)
     scheduler = ContinuousBatchingScheduler(cache)
     res = serve_replica(
         engine=engine, scheduler=scheduler, linger_s=1.0, coordinate=False,
@@ -122,7 +103,7 @@ def replica_child(profile: str = "smoke") -> None:
 
 
 # -------------------------------------------------------------------- driver
-def _specs(workdir, n, kill_replica=None, extra_env=None, profile="smoke"):
+def _specs(workdir, n, kill_replica=None, extra_env=None):
     from vescale_tpu.serve import ReplicaSpec
     from vescale_tpu.testing import make_child_env, reserve_port
 
@@ -138,7 +119,7 @@ def _specs(workdir, n, kill_replica=None, extra_env=None, profile="smoke"):
             env["VESCALE_FAULTSIM"] = KILL_SCHEDULE
         specs.append(ReplicaSpec(
             rid,
-            [sys.executable, os.path.abspath(__file__), "--child", profile],
+            [sys.executable, os.path.abspath(__file__), "--child"],
             reserve_port(),
             env=env,
             log_path=os.path.join(workdir, f"{rid}.log"),
@@ -335,191 +316,8 @@ def main() -> None:
         shutil.rmtree(work, ignore_errors=True)
 
 
-# ------------------------------------------------------------------- bench
-def run_bench() -> dict:
-    """The ``VESCALE_BENCH=fleet`` rung: 2 replicas, 5x-capacity overload
-    with a mid-run kill + rejoin, plus the router-hop overhead line."""
-    import shutil
-    import tempfile
-
-    sys.path.insert(0, REPO)
-    from vescale_tpu.serve import (
-        ContinuousBatchingScheduler,
-        FleetRouter,
-        FleetSupervisor,
-        HttpReplicaClient,
-        KVCacheConfig,
-        PagedKVCache,
-        Request,
-        RequestInbox,
-    )
-    from vescale_tpu.serve.router import ReplicaUnreachable  # noqa: F401
-
-    n_replicas = 2
-    bench_queue = 4
-    capacity = n_replicas * (SLOTS + bench_queue)
-    n_requests = 5 * capacity  # the 5x overload
-    work = tempfile.mkdtemp(prefix="fleet_bench_")
-    try:
-        specs = _specs(work, n_replicas, profile="bench",
-                       extra_env={"VESCALE_SERVE_MAX_QUEUE": bench_queue})
-        fr, Client = _router(hedge_s=0.0)
-        sup = FleetSupervisor(specs, max_restarts=2, restart_backoff_s=0.3)
-        sup.start()
-        killed = False
-        try:
-            for s in specs:
-                fr.add_replica(s.replica_id, Client(s.url))
-            _wait_fleet_up(fr, sup, specs)
-            # 16-token decode budgets: real requests decode long past the
-            # smoke's 4-6 tokens, and the hop-overhead amortization below
-            # should not flatter the router with artificially short ones
-            waves = _prompts(n_requests, max_new=16)
-            t0 = time.monotonic()
-            for i, (rid, prompt, max_new) in enumerate(waves):
-                fr.submit(Request(rid=rid, prompt=prompt, max_new_tokens=max_new),
-                          session=f"sess{rid % 7}")
-                if (
-                    not killed
-                    and i >= n_requests // 2
-                    and any("r0" in r.live_on for r in fr.ledger.pending())
-                ):
-                    # mid-overload crash + rejoin, inside the timed window —
-                    # deferred until the victim actually holds live work so
-                    # the rung always exercises a real failover
-                    sup.kill("r0")
-                    killed = True
-                sup.poll()
-                fr.pump()
-            _drain(fr, sup)
-            wall = time.monotonic() - t0
-            c = fr.summary()["counts"]
-            fr.fleet_ledger_check()
-            completed_recs = [rec for rec in fr.ledger.records.values()
-                              if rec.status == "completed"]
-            completed_tokens = sum(len(r.outcome["tokens"]) for r in completed_recs)
-            tokens_per_req = completed_tokens / max(1, len(completed_recs))
-            feeds = {rid: h.feed for rid, h in fr.replicas.items() if h.feed}
-            ttft_p99 = max(
-                (f["ttft_s"]["p99"] or 0.0 for f in feeds.values()), default=0.0
-            )
-            # decode-step denominator for the hop-overhead line: the ITL
-            # p50 the replicas measured (each batched step's wall IS each
-            # slot's inter-token latency) — retry_after_s is seeded from
-            # compile-heavy first prefills on a freshly restarted replica
-            # and would understate the overhead fraction
-            itl = [f["itl_s"]["p50"] for f in feeds.values()
-                   if (f.get("itl_s") or {}).get("p50")]
-            step_p50 = min(itl) if itl else 0.01
-        finally:
-            sup.stop_all(grace_s=30.0)
-
-        # ---- router hop cost vs direct submit (no sockets: the hop being
-        # priced is the router's own bookkeeping — ledger, scoring, ring)
-        class _InstantClient:
-            def poll_router(self):
-                return {"schema_version": 2, "replica_id": "L", "accepting": True,
-                        "draining": False, "queue_depth": 0, "inflight": 0,
-                        "slots": 64, "free_slots": 64, "pages": 64, "free_pages": 64,
-                        "ttft_s": {"p50": None, "p95": None, "p99": None},
-                        "itl_s": {"p50": None, "p95": None, "p99": None},
-                        "shed_rate": 0.0, "retry_after_s": 0.01,
-                        "goodput_tokens_per_s": 0.0, "throughput_tokens_per_s": 0.0,
-                        "mfu": None, "decode_steps": 1, "serve_step": 1,
-                        "uptime_s": 1.0, "rank": 0}
-
-            def submit(self, payload):
-                return {"accepted": True}
-
-            def outcomes(self):
-                return {"outcomes": {}}
-
-        hop_iters = 2000
-        hop_reps = 5  # min-of-reps: the noise-robust estimator — on a
-        # contended CPU single-run jitter swamps the few-us tracing delta
-
-        def _hop_min():
-            best = float("inf")
-            for _ in range(hop_reps):
-                r = FleetRouter(poll_interval_s=3600.0, breaker_failures=3,
-                                breaker_cooldown_s=1.0, dispatch_retries=1,
-                                backoff_s=0.0, backoff_max_s=0.0, hedge_s=0.0)
-                r.add_replica("L", _InstantClient())
-                r.poll(force=True)
-                for i in range(300):  # warm before every timed window
-                    r.submit(Request(rid=1_000_000 + i, prompt=(1, 2),
-                                     max_new_tokens=1))
-                t0 = time.perf_counter()
-                for i in range(hop_iters):
-                    r.submit(Request(rid=i, prompt=(1, 2), max_new_tokens=1))
-                best = min(best, (time.perf_counter() - t0) / hop_iters)
-            return best
-
-        hop_s = _hop_min()
-
-        direct_s = float("inf")
-        for _ in range(hop_reps):
-            inbox = RequestInbox()
-            t0 = time.perf_counter()
-            for i in range(hop_iters):
-                inbox.push(Request(rid=i, prompt=(1, 2), max_new_tokens=1))
-            direct_s = min(direct_s, (time.perf_counter() - t0) / hop_iters)
-        hop_overhead = max(0.0, hop_s - direct_s)
-
-        # ---- tracing-on vs tracing-off hop (ISSUE 14 satellite): the
-        # same router hop with the ndtimeline profiler LIVE, so every
-        # submit emits its fleet-submit/dispatch-attempt/fleet-terminal
-        # chain — the added cost, amortized over a request's decode
-        # service time exactly like the hop itself, must stay < 1%
-        from vescale_tpu.ndtimeline import api as nd_api
-
-        # own-the-profiler guard: a caller that already runs ndtimeline
-        # keeps its manager/handlers (and its baseline hop above was
-        # already traced, so the delta honestly reads ~0 there)
-        own_nd = not nd_api.is_active()
-        if own_nd:
-            nd_api.init_ndtimers(rank=0)
-        try:
-            traced_hop_s = _hop_min()
-        finally:
-            if own_nd:
-                nd_api.deinit_ndtimers()
-        trace_added = max(0.0, traced_hop_s - hop_s)
-        service_s = max(1e-9, tokens_per_req * step_p50)
-
-        return {
-            "metric": "fleet_tokens_per_s_cpu",
-            "value": round(completed_tokens / wall, 2),
-            "unit": "tokens/s",
-            "replicas": n_replicas,
-            "requests": n_requests,
-            "overload_factor": 5,
-            "kill_rejoin": killed,
-            "completed": c["completed"],
-            "shed": c["shed"],
-            "shed_rate": round(c["shed"] / max(1, c["submitted"]), 4),
-            "failovers": c["failovers"],
-            "ttft_p99_ms": round(ttft_p99 * 1e3, 3),
-            "wall_s": round(wall, 2),
-            "router_hop_us": round(hop_s * 1e6, 2),
-            "router_hop_traced_us": round(traced_hop_s * 1e6, 2),
-            "direct_submit_us": round(direct_s * 1e6, 2),
-            "decode_step_p50_ms": round(step_p50 * 1e3, 3),
-            # ONE router hop per request, amortized over the request's
-            # decode service time (tokens/request x measured ITL p50) —
-            # the fraction the router adds to serving a request
-            "router_hop_overhead_frac": round(hop_overhead / service_s, 5),
-            # tracing-on minus tracing-off hop over the same denominator:
-            # what the fleet-trace span chain adds per request
-            "fleet_trace_overhead_frac": round(trace_added / service_s, 5),
-            "acceptance_lt": 0.01,
-        }
-    finally:
-        shutil.rmtree(work, ignore_errors=True)
-
-
 if __name__ == "__main__":
     if len(sys.argv) > 1 and sys.argv[1] == "--child":
-        replica_child(sys.argv[2] if len(sys.argv) > 2 else "smoke")
+        replica_child()
     else:
         main()

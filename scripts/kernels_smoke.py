@@ -41,7 +41,7 @@ os.environ["VESCALE_KERNELS"] = "off"
 
 import numpy as np  # noqa: E402
 
-ULP_BOUND = 8.0  # ulps at tensor scale (docs/kernels.md); bench records actuals
+ULP_BOUND = 8.0  # ulps at tensor scale (docs/kernels.md)
 
 
 def _set_mode(mode: str) -> None:

@@ -13,8 +13,7 @@ grad-compression stack (ROADMAP item 2; EQuARX, arXiv:2506.17615):
            per 64-element block).  A bf16-grad psum is compiled and
            measured alongside; on XLA CPU it upcasts to f32 on the wire,
            so its ratio matches fp32's — the number reported is what the
-           compiled program actually moves.  Per-iteration wall time for
-           both is reported (VESCALE_BENCH=quantcomm emits the bench line).
+           compiled program actually moves.
 
   replay   the emulator's quantized mode (emulator/quantized.py) replays
            the rig's reduction on the driver host: quantize once with the
@@ -24,7 +23,7 @@ grad-compression stack (ROADMAP item 2; EQuARX, arXiv:2506.17615):
            contract of the emulator quantized-ring mode.
 
   e2e      the 350M-class CPU training smoke (the scaled-down llama config
-           every CPU bench round uses — same code path as the real 350M,
+           of the CPU rounds — same code path as the real 350M,
            sized for tier-1): 8-virtual-device dp training via a shard_map
            step whose ONLY difference between runs is the grad reduction
            (``dp_grad_reduce``: exact pmean vs int8 quantized).  Asserts
@@ -334,27 +333,6 @@ def emulator_digest() -> str:
     return _digest(out)
 
 
-def run_bench() -> dict:
-    """The VESCALE_BENCH=quantcomm rung: rig bytes + step-time comparison
-    as one JSON-able record (bench.py dispatch prints it)."""
-    stats, digests = run_rig()
-    return {
-        "metric": "quantcomm_bytes_ratio_cpu",
-        "value": round(stats["ratio_vs_f32"], 4),
-        "unit": "x_fewer_grad_bytes_f32_vs_int8",
-        "ratio_vs_bf16": round(stats["ratio_vs_bf16"], 4),
-        "allreduce_ms_f32": stats["allreduce_ms_f32"],
-        "allreduce_ms_int8": stats["allreduce_ms_int8"],
-        "bytes_f32": stats["bytes_f32"],
-        "bytes_bf16_as_compiled": stats["bytes_bf16_as_compiled"],
-        "bytes_int8": stats["bytes_int8"],
-        "grad_elements": stats["grad_elements"],
-        "world": WORLD,
-        "block": BLOCK,
-        "emulator_bitwise": digests[0] == emulator_digest(),
-    }
-
-
 def main() -> None:
     t0 = time.monotonic()
     stats, digests = run_rig()
@@ -386,7 +364,5 @@ if __name__ == "__main__":
         child_rig()
     elif "--child-e2e" in sys.argv:
         child_e2e()
-    elif "--bench" in sys.argv:
-        print(json.dumps(run_bench()))
     else:
         main()

@@ -15,10 +15,9 @@ fallback pair) and reports, per pair:
                        zero retrace (ISSUE 2 acceptance)
   ok                   value-exactness vs the logical input
 
-Emits ONE JSON metric line (``"metric": "redistribute_bench"``) on stdout —
-the same contract as bench.py, which exposes this battery as
-``VESCALE_BENCH=redistribute``.  Wired into tier-1 via
-tests/test_redistribute_plan.py (like scripts/telemetry_smoke.py).
+Emits ONE JSON metric line (``"metric": "redistribute_bench"``) on stdout.
+Wired into tier-1 via tests/test_redistribute_plan.py (like
+scripts/telemetry_smoke.py).
 """
 
 from __future__ import annotations

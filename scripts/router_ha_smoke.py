@@ -20,11 +20,6 @@ ledger only in memory.  The battery:
 4. the promoted router re-announces on ``/fleet`` v5: the ``ha`` block
    reports role=leader at the bumped epoch over live HTTP.
 
-``run_bench()`` is the ``VESCALE_BENCH=routerha`` rung: the journal
-append cost per dispatch hop (plain router vs journaled router, same
-no-socket instant-client harness as the fleet rung), amortized over a
-MEASURED request decode service time — the <1% acceptance bar.
-
 Run directly: ``python scripts/router_ha_smoke.py`` (wired into
 scripts/run_test.sh and tests/test_routerha.py).
 """
@@ -218,150 +213,8 @@ def main() -> None:
         shutil.rmtree(work, ignore_errors=True)
 
 
-# ------------------------------------------------------------------- bench
-def run_bench() -> dict:
-    """The ``VESCALE_BENCH=routerha`` rung: journal append overhead per
-    dispatch hop, amortized over a MEASURED request service time."""
-    import shutil
-    import tempfile
-
-    _scripts_on_path()
-    import fleet_smoke
-
-    from vescale_tpu.serve import (
-        FleetJournal,
-        FleetRouter,
-        FleetSupervisor,
-        Request,
-    )
-
-    work = tempfile.mkdtemp(prefix="routerha_bench_")
-    try:
-        # ---- real mini-leg: one bench replica behind a JOURNALED router
-        # gives the service-time denominator (tokens/request x ITL p50)
-        # and proves the journal rides a real battery without incident
-        n_requests, max_new = 16, 16
-        specs = fleet_smoke._specs(work, 1, profile="bench")
-        fr, Client = fleet_smoke._router(
-            journal=FleetJournal(os.path.join(work, "journal"))
-        )
-        sup = FleetSupervisor(specs, max_restarts=1, restart_backoff_s=0.3)
-        sup.start()
-        try:
-            for s in specs:
-                fr.add_replica(s.replica_id, Client(s.url))
-            fleet_smoke._wait_fleet_up(fr, sup, specs)
-            for rid, prompt, mn in fleet_smoke._prompts(n_requests, max_new=max_new):
-                fr.submit(Request(rid=rid, prompt=prompt, max_new_tokens=mn),
-                          session=f"sess{rid % 5}")
-                sup.poll()
-                fr.pump()
-            fleet_smoke._drain(fr, sup)
-            fr.fleet_ledger_check()
-            jstats = fr.journal.stats()
-            completed = [r for r in fr.ledger.records.values()
-                         if r.status == "completed"]
-            tokens_per_req = (
-                sum(len(r.outcome["tokens"]) for r in completed)
-                / max(1, len(completed))
-            )
-            feeds = [h.feed for h in fr.replicas.values() if h.feed]
-            itl = [f["itl_s"]["p50"] for f in feeds
-                   if (f.get("itl_s") or {}).get("p50")]
-            step_p50 = min(itl) if itl else 0.01
-        finally:
-            sup.stop_all(grace_s=30.0)
-
-        # ---- hop cost, plain vs journaled (no sockets — same harness as
-        # the fleet rung: the instant client isolates the router's own
-        # bookkeeping, so the delta is exactly the journal's append+flush
-        # at the placement barrier)
-        class _InstantClient:
-            def poll_router(self):
-                return {"schema_version": 2, "replica_id": "L", "accepting": True,
-                        "draining": False, "queue_depth": 0, "inflight": 0,
-                        "slots": 64, "free_slots": 64, "pages": 64, "free_pages": 64,
-                        "ttft_s": {"p50": None, "p95": None, "p99": None},
-                        "itl_s": {"p50": None, "p95": None, "p99": None},
-                        "shed_rate": 0.0, "retry_after_s": 0.01,
-                        "goodput_tokens_per_s": 0.0, "throughput_tokens_per_s": 0.0,
-                        "mfu": None, "decode_steps": 1, "serve_step": 1,
-                        "uptime_s": 1.0, "rank": 0}
-
-            def submit(self, payload):
-                return {"accepted": True}
-
-            def outcomes(self):
-                return {"outcomes": {}}
-
-        hop_iters = 2000
-        hop_reps = 5  # min-of-reps: noise-robust on a contended CPU
-
-        def _hop_min(mk_router):
-            best = float("inf")
-            for _ in range(hop_reps):
-                r = mk_router()
-                r.add_replica("L", _InstantClient())
-                r.poll(force=True)
-                for i in range(300):  # warm before every timed window
-                    r.submit(Request(rid=1_000_000 + i, prompt=(1, 2),
-                                     max_new_tokens=1))
-                t0 = time.perf_counter()
-                for i in range(hop_iters):
-                    r.submit(Request(rid=i, prompt=(1, 2), max_new_tokens=1))
-                best = min(best, (time.perf_counter() - t0) / hop_iters)
-            return best
-
-        hop_kw = dict(poll_interval_s=3600.0, breaker_failures=3,
-                      breaker_cooldown_s=1.0, dispatch_retries=1,
-                      backoff_s=0.0, backoff_max_s=0.0, hedge_s=0.0)
-        plain_s = _hop_min(lambda: FleetRouter(**hop_kw))
-        rep_counter = [0]  # each rep journals into a FRESH directory
-
-        def _mk_journaled():
-            rep_counter[0] += 1
-            return FleetRouter(
-                journal=FleetJournal(
-                    os.path.join(work, "hopj", str(rep_counter[0]))
-                ),
-                **hop_kw,
-            )
-
-        journal_s = _hop_min(_mk_journaled)
-        journal_added = max(0.0, journal_s - plain_s)
-        service_s = max(1e-9, tokens_per_req * step_p50)
-
-        return {
-            "metric": "routerha_journal_overhead_frac",
-            # TWO framed appends (submit + dispatch) and ONE buffered
-            # flush per hop — the placement barrier — amortized over the
-            # request's decode service time, exactly like the router-hop
-            # line in the fleet rung
-            "value": round(journal_added / service_s, 5),
-            "unit": "frac",
-            "router_hop_us": round(plain_s * 1e6, 2),
-            "router_hop_journal_us": round(journal_s * 1e6, 2),
-            "journal_added_us": round(journal_added * 1e6, 2),
-            "tokens_per_req": round(tokens_per_req, 2),
-            "decode_step_p50_ms": round(step_p50 * 1e3, 3),
-            "service_ms": round(service_s * 1e3, 3),
-            "fsync": jstats["fsync"],
-            "journal_appends": jstats["appends"],
-            "journal_snapshots": jstats["snapshots"],
-            "completed": len(completed),
-            "acceptance_lt": 0.01,
-        }
-    finally:
-        shutil.rmtree(work, ignore_errors=True)
-
-
 if __name__ == "__main__":
     if len(sys.argv) > 1 and sys.argv[1] == "--router":
         router_child()
-    elif len(sys.argv) > 1 and sys.argv[1] == "--child":
-        _scripts_on_path()
-        import fleet_smoke
-
-        fleet_smoke.replica_child(sys.argv[2] if len(sys.argv) > 2 else "smoke")
     else:
         main()

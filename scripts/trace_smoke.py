@@ -72,8 +72,10 @@ def child(root: str) -> None:
     raw_path = os.path.join(root, f"spans_r{me}.jsonl")
     init_ndtimers(rank=me, mesh=mesh, handlers=[LocalRawHandler(raw_path)])
 
-    # ---- clock sync over the control plane (every rank gets the vector)
-    cs = trace.estimate_clock_offsets()
+    # ---- clock sync over the control plane (every rank gets the vector).
+    # 32 rounds: the offset is a median of entry skews, and on a loaded
+    # host 8 of them leave it most of a millisecond from the truth
+    cs = trace.estimate_clock_offsets(rounds=32)
     print(f"residual_us={cs.residual_us:.1f}")
     if me == 0:
         with open(os.path.join(root, "clock.json"), "w") as f:
@@ -83,8 +85,12 @@ def child(root: str) -> None:
     from vescale_tpu.ndtimeline.api import get_manager
 
     for step in range(STEPS):
-        vdist.barrier(f"trace_smoke_step{step}")
         with ndtimeit(TRAIN_STEP):
+            # the barrier is INSIDE the span: neither rank leaves it before
+            # both have entered, so the two spans share an instant by
+            # construction and only a wrong offset can pull them apart (a
+            # barrier before the span leaves that to the scheduler)
+            vdist.barrier(f"trace_smoke_step{step}")
             x = jnp.sum(jnp.ones((128, 128)) * (step + 1))
             jax.block_until_ready(x)
             role = "send" if me == 0 else "recv"
@@ -132,8 +138,8 @@ def child(root: str) -> None:
         back = trace.spans_from_perfetto(trace_path)
         assert len(back) == len(merged), "span round-trip lost events"
         # both ranks' TRAIN_STEP spans for one step overlap after alignment
-        # (the per-step barrier synchronized them to well under the step
-        # duration; raw clocks could legally disagree by more)
+        # (each holds the step's barrier, so they overlap in truth; raw
+        # clocks could legally disagree by more)
         by_step = {}
         for s in merged:
             if s.metric == TRAIN_STEP:

@@ -1,6 +1,6 @@
 """5D composition: PP x DP x EP + ZeRO + distributed checkpoint on the
-virtual 8-device mesh (2x2x2) — the toy-scale rung of the BASELINE ladder's
-"Llama-3-405B 5D + distributed checkpoint" config."""
+virtual 8-device mesh (2x2x2) — a toy-scale "Llama-3-405B 5D + distributed
+checkpoint" config."""
 
 import numpy as np
 import pytest

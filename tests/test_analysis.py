@@ -490,13 +490,13 @@ def _lint(src):
 def test_lint_flags_direct_env_reads_not_writes():
     f = _lint("""
         import os
-        a = os.environ.get("VESCALE_BENCH")
-        b = os.getenv("VESCALE_BENCH")
-        c = os.environ["VESCALE_BENCH"]
-        d = "VESCALE_BENCH" in os.environ
-        os.environ["VESCALE_BENCH"] = "1"          # write: fine
-        os.environ.setdefault("VESCALE_BENCH", "") # write: fine
-        del os.environ["VESCALE_BENCH"]            # write: fine
+        a = os.environ.get("VESCALE_KERNELS")
+        b = os.getenv("VESCALE_KERNELS")
+        c = os.environ["VESCALE_KERNELS"]
+        d = "VESCALE_KERNELS" in os.environ
+        os.environ["VESCALE_KERNELS"] = "1"          # write: fine
+        os.environ.setdefault("VESCALE_KERNELS", "") # write: fine
+        del os.environ["VESCALE_KERNELS"]            # write: fine
     """)
     assert len([x for x in f if x.code.code == "VSC201"]) == 4
 
@@ -509,7 +509,7 @@ def test_lint_flags_unregistered_names_and_suppression():
     assert f2 == []
     f3 = _lint(f'x = "{bogus}"  # vescale-lint: disable=all\n')
     assert f3 == []
-    assert _lint('x = "VESCALE_BENCH"\n') == []  # registered
+    assert _lint('x = "VESCALE_KERNELS"\n') == []  # registered
     assert _lint('y = "VESCALE_IO_BACKOFF_"\n') == []  # family prefix
 
 
@@ -602,7 +602,6 @@ def test_lint_repo_is_green():
     rep = lint_paths([
         os.path.join(REPO, "vescale_tpu"),
         os.path.join(REPO, "scripts"),
-        os.path.join(REPO, "bench.py"),
         os.path.join(REPO, "chip_smoke.py"),
         os.path.join(REPO, "__graft_entry__.py"),
         os.path.join(REPO, "examples"),

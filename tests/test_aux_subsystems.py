@@ -533,7 +533,7 @@ def test_ndtimeline_runtime_wiring_chrome_trace(tmp_path, mesh2d):
 
 
 def test_auto_inc_step_double_increment_warns_once():
-    """ISSUE 2 satellite (ADVICE double-increment hazard): with
+    """ISSUE 2 satellite (double-increment hazard): with
     auto_inc_step=True (default), a loop that ALSO advances the ndtimeline
     counter manually between steps double-counts the global step — the
     train step detects the externally-moved counter and warns exactly

@@ -1,5 +1,5 @@
 """Flash-attention kernel tests (interpret mode on CPU; the real-chip run
-happens in bench.py)."""
+happens in chip_smoke.py and the benchmark's train cells)."""
 
 import numpy as np
 import pytest

@@ -215,8 +215,8 @@ def test_spmd_pipeline_blocks(mesh1d):
 
 def test_pipeline_blocks_auto_act_spec_parity():
     """r5: auto_act_spec pins the microbatch stash / carries / backward
-    stash to a dp x tp activation layout on the AUTO axes (the 405B
-    memory-fit knob, AOT_405B_REPORT.json) without changing values — fwd
+    stash to a dp x tp activation layout on the AUTO axes (the
+    memory-fit knob of a deep pipeline) without changing values — fwd
     and grads match the unconstrained pipeline bitwise-ish."""
     from jax.sharding import PartitionSpec as P
 
@@ -746,7 +746,7 @@ def test_profile_costs_measures_stages():
         assert costs_f.bd[s] + costs_f.w[s] < 50 * ref_b
         assert ref_b < 50 * (costs_f.bd[s] + costs_f.w[s])
 
-    # host-overhead calibration (ADVICE r2): subtracting the decimated-batch
+    # host-overhead calibration: subtracting the decimated-batch
     # baseline keeps costs positive and never above the raw measurement
     costs_c = engine.profile_costs(params, batch, num_microbatches=4,
                                    calibrate_host_overhead=True)

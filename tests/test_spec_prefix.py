@@ -314,7 +314,7 @@ def test_tree_insert_cap_eviction_protects_attach_path():
 
 def test_cache_reset_drops_tree_references_too():
     """Regression: a driver that resets the cache while DISCARDING its
-    PrefixCache (bench run_mult) must get the whole pool back — the dead
+    PrefixCache must get the whole pool back — the dead
     tree's retained pages may not leak out of the pool permanently."""
     c = _cache(num_slots=2, page_size=4, pages_per_slot=4)
     t = PrefixCache(c)

@@ -464,15 +464,11 @@ def test_record_trace_metrics_feeds_dashboard_blocks():
         telemetry.shutdown()
 
 
-def test_bench_embeds_cost_model_digest():
-    sys.path.insert(0, REPO)
-    import bench
-
-    assert bench._cost_model_line() == {"kind": "analytic"}
+def test_active_digest_follows_the_armed_table():
+    assert calibrate.active_digest() is None
     t = _table([("all_reduce", 8, 4096, 100e-6)])
     calibrate.set_active(t)
-    line = bench._cost_model_line()
-    assert line == {"kind": "calibrated", "calibration_digest": t.digest()}
+    assert calibrate.active_digest() == t.digest()
 
 
 # ------------------------------------------------------------ smoke (CI)

@@ -3,7 +3,7 @@
 Commands (default with no command: ``lint`` + ``examples``):
 
   lint [paths...]      vescale-lint over the given paths (default: the
-                       whole repo — package, scripts, bench, examples,
+                       whole repo — package, scripts, examples,
                        tests)
   examples             validate the examples/ training configs: every
                        model sharding plan audited (VSC107), and the
@@ -53,8 +53,8 @@ _REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 
 def _default_lint_paths() -> List[str]:
     paths = []
-    for rel in ("vescale_tpu", "scripts", "examples", "tests", "bench.py",
-                "chip_smoke.py", "__graft_entry__.py"):
+    for rel in ("vescale_tpu", "scripts", "examples", "tests", "chip_smoke.py",
+                "__graft_entry__.py"):
         p = os.path.join(_REPO, rel)
         if os.path.exists(p):
             paths.append(p)

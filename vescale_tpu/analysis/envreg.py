@@ -425,24 +425,6 @@ register("VESCALE_COSTAUDIT_CADENCE_S", "float", 30.0,
 register("VESCALE_COSTAUDIT_HARVEST", "bool", True,
          "Let the per-step auditor harvest tagged ndtimeline spans into the active calibration table (online recalibration); off = audit-only (divergence is reported but the table never moves).")
 
-# --- bench harness ---------------------------------------------------
-register("VESCALE_BENCH", "str", None,
-         "Which bench rung to run (e.g. `serve`, `redistribute`, `memtrack`, `watchdog`); unset = default MFU line.")
-register("VESCALE_BENCH_STEP_REPORT", "bool", False,
-         "Add a compile-time step report to the bench MFU line (a second compile of the step program).")
-
-# --- AOT report scripts ----------------------------------------------
-register("VESCALE_AOT_MODEL", "str", "8b",
-         "Model config for scripts/aot_8b_report.py (`8b`, `70b`, `405b`, `mixtral`).")
-register("VESCALE_AOT_FP8", "bool", False,
-         "AOT-report the fp8 variant.")
-register("VESCALE_AOT_ZB", "bool", False,
-         "AOT-report the zero-bubble schedule variant.")
-register("VESCALE_AOT_CHILD", "bool", False,
-         "Marks an AOT-report subprocess (internal; set by the driver).")
-register("VESCALE_AOT_DEBUG", "bool", False,
-         "Verbose AOT-report debugging output.")
-
 # --- entry / misc ----------------------------------------------------
 register("VESCALE_FP8_ON_TPU", "bool", False,
          "Allow the fp8 example on real TPU backends (off = CPU emulation only).")

@@ -35,7 +35,7 @@ Five rules, each a lesson this codebase already paid for once:
           latch) is exempt.
 
   VSC208  a priced decision must enter the cost-audit ledger: PACKAGE
-          code (files under vescale_tpu/ — tests, scripts and bench
+          code (files under vescale_tpu/ — tests and scripts
           call the cost model to inspect it, not to decide) that calls
           ``simulate_schedule``/``estimate_stage_costs`` inside a
           function with no ``record_prediction`` reference is choosing
@@ -130,7 +130,7 @@ class _Lint(ast.NodeVisitor):
         )
         self._vsc207_seen: Set[int] = set()
         self._vsc208_seen: Set[int] = set()
-        # VSC208 applies only to package code: tests/scripts/bench call
+        # VSC208 applies only to package code: tests and scripts call
         # the cost model to inspect it, not to decide by it
         self._in_package = "vescale_tpu" in parts
         # exempt ONLY the vescale_tpu/kernels package itself — a nested

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 Every entry point that compiles for the chip (``chip_smoke.py``,
-``bench.py``, ``scripts/bench_1b_sweep.py``, the example trainers) calls
+``benchmark/run.py``, the example trainers) calls
 :func:`use_compile_cache` first, so that a second process — or a second
 call on a machine that keeps its disk — loads the step program instead of
 compiling it again.  The directory is part of the cache key, so it must not

@@ -186,7 +186,7 @@ def collective_wire_bytes(text: str, default_group: int = 1) -> Dict[str, float]
     group size n): all-reduce ``2(n-1)/n * R``, all-gather ``(n-1)/n * R``,
     reduce-scatter ``(n-1) * R`` (its input is ``n*R``), all-to-all
     ``(n-1)/n * R``, collective-permute ``R``.  This is the measurement
-    surface of the quantcomm bench: the payload DTYPE comes from the
+    surface of scripts/quantcomm_smoke.py: the payload DTYPE comes from the
     program, so an int8-compressed reduction shows its real packed bytes.
     Keys: logical op (quantized ops remapped per the wire convention) plus
     ``<op>:int8`` tags; ``total`` sums the logical keys only."""

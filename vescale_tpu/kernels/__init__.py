@@ -158,7 +158,7 @@ def ulps_at_scale(a, b) -> float:
     patterns must agree exactly: a kernel that overflows to Inf (or
     drops/creates a NaN) where the reference doesn't returns ``inf``, a
     parity failure, never an excluded element.  One definition, imported
-    by bench.py, scripts/kernels_smoke.py and tests/test_kernels.py, so
+    by scripts/kernels_smoke.py and tests/test_kernels.py, so
     the asserted bound cannot drift between them."""
     import numpy as np
 

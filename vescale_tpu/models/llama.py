@@ -1,7 +1,7 @@
 """LLaMA family — Llama-2 / Llama-3 / OpenLlama.
 
-Model rungs of the config ladder (BASELINE.md): the reference's examples
-train HF llama checkpoints (legacy/examples/llama2_4D_finetune/llama_train.py,
+The reference's examples train HF llama checkpoints
+(legacy/examples/llama2_4D_finetune/llama_train.py,
 open_llama_4D_benchmark/) with a 4D sharding plan
 (open_llama_4D_benchmark/sharding_plan.py).  This is an idiomatic flax
 re-implementation: RMSNorm, rotary embeddings, grouped-query attention,

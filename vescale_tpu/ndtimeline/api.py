@@ -61,8 +61,8 @@ def init_ndtimers(rank: int = 0, mesh=None, handlers=(), max_spans: int = 100_00
 
 def deinit_ndtimers() -> None:
     """Deactivate the profiler and drop the global manager — the inverse
-    of :func:`init_ndtimers`, for A/B overhead rungs (bench.py measures a
-    traced leg then restores the dormant no-op state) and test teardown.
+    of :func:`init_ndtimers`, for an A/B of a traced leg against the
+    dormant no-op state, and for test teardown.
     Buffered spans that were never flushed are discarded."""
     global _MANAGER, _ACTIVE
     _ACTIVE = False

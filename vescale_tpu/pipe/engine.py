@@ -446,7 +446,7 @@ class PipeEngine:
         per-call host cost (jax.linearize / vjp re-trace, dict bookkeeping)
         that is roughly SIZE-INDEPENDENT, while the device work scales with
         the microbatch — so raw wall times flatten the stage ratios the
-        scheduler cares about (ADVICE r2).  Calibration re-profiles on a
+        scheduler cares about.  Calibration re-profiles on a
         sequence-decimated copy of the minibatch and subtracts the
         per-(kind, stage) medians: what remains is the size-scaling
         (device) component.  Costs are clamped at a tenth of the raw

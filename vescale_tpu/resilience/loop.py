@@ -214,9 +214,8 @@ def run_resilient(
     bounds every coordination collective.
 
     NOTE: the anomaly guard reads the loss on the host every step (the
-    same sync ``telemetry.record_step`` opts into); ``VESCALE_BENCH=
-    resilience`` / ``VESCALE_BENCH=watchdog`` measure the
-    armed-but-quiescent overhead."""
+    same sync ``telemetry.record_step`` opts into); the
+    armed-but-quiescent overhead is not measured on the chip."""
     if (loader is None) == (batch_fn is None):
         raise ValueError("exactly one of loader / batch_fn is required")
     if total_steps <= 0:
@@ -348,7 +347,7 @@ def run_resilient(
         )
         rows = allgather_ints(vec, tag="resilience_coord", timeout_s=barrier_timeout_s)
         if rows.shape[0] == 1:
-            # coordinate=True on one process (tests, bench): a single row
+            # coordinate=True on one process (tests): a single row
             # cannot mismatch — skip the compares, keep the counters honest
             if fp is not None:
                 _tel.count("consistency_checks_total")

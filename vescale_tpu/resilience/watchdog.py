@@ -36,8 +36,8 @@ Env knobs (read by ``run_resilient`` when arming from the environment):
                               supervisor can count hangs separately)
 
 Quiescent cost: one ``time.monotonic()`` + two attribute writes per
-``beat`` and a sleeping thread — ``VESCALE_BENCH=watchdog`` measures the
-armed-but-quiescent per-step overhead end to end (target <<1%).
+``beat`` and a sleeping thread; the armed-but-quiescent per-step
+overhead is not measured on the chip.
 """
 
 from __future__ import annotations
@@ -99,7 +99,7 @@ class Watchdog:
         self.exit_code = int(exit_code)
         self.dump_dir = dump_dir
         self.on_hang = on_hang
-        self.fired = 0  # stalls detected (tests/bench read this)
+        self.fired = 0  # stalls detected (tests read this)
         self.last_bundle: Optional[Dict[str, Any]] = None
         self._last_beat = time.monotonic()
         self._step: Optional[int] = None

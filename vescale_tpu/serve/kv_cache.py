@@ -226,8 +226,7 @@ class PagedKVCache:
         self._free_slots: List[int] = sorted(range(config.num_slots), reverse=True)
         # event-sourced digest: every mutation folds into a running crc, so
         # fingerprint() is O(1) per step (recomputing over the whole table
-        # made the per-step control exchange cost ~tens of us — measured by
-        # the VESCALE_BENCH=serve overhead rung)
+        # grew the per-step control exchange with the table)
         self._digest = 0
         self._tokens_held = 0
 
@@ -453,7 +452,7 @@ class PagedKVCache:
     def reset(self) -> None:
         """Return every slot and page to the pool (device bytes stay —
         stale pages are legal: nothing reads past a slot's length).  Lets a
-        bench/driver reuse one COMPILED engine across runs instead of
+        driver reuse one COMPILED engine across runs instead of
         rebuilding (and recompiling) per run.  EVERY reference is dropped,
         the prefix tree's included — a PrefixCache built over this cache
         must be discarded (or ``reset``) with it, never carried across."""

@@ -332,7 +332,7 @@ class PrefixCache:
     def reset(self) -> None:
         """Drop the whole tree: every retained page loses its tree
         reference (returning to the pool unless a live slot still maps
-        it) — bench/driver reuse of one compiled engine across runs."""
+        it) — a driver's reuse of one compiled engine across runs."""
         stack = [self.root]
         while stack:
             n = stack.pop()

@@ -930,7 +930,6 @@ class FleetRouter:
                 else self._tag_counter
             )
             # span tag only — skip the recompute entirely while dormant
-            # (this is the hop cost the bench's <1% bar measures)
             score = (
                 self.score(h.feed, h.pending_local)
                 if (h.feed and fleettrace.is_active())
@@ -1157,8 +1156,8 @@ class FleetRouter:
     def drain(
         self, timeout_s: float = 120.0, poll_slice_s: Optional[float] = None
     ) -> None:
-        """Pump until every submitted request is terminal (the smoke /
-        bench driver).  Raises TimeoutError with the stuck rids if the
+        """Pump until every submitted request is terminal (the smoke
+        driver).  Raises TimeoutError with the stuck rids if the
         fleet cannot settle inside ``timeout_s``."""
         deadline = self._now() + timeout_s
         slice_s = poll_slice_s if poll_slice_s is not None else self.poll_interval_s
@@ -1408,7 +1407,7 @@ class FleetRouter:
         self.ledger.check()
 
     def summary(self) -> Dict[str, Any]:
-        """Aggregate fleet stats for the bench rung / smoke print."""
+        """Aggregate fleet stats for the smoke print."""
         per_replica = {
             h.id: {
                 "breaker": h.breaker.state,

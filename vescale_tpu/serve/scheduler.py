@@ -157,7 +157,7 @@ class ContinuousBatchingScheduler:
         # radix-tree prefix cache (prefix_cache.py): admission consults it
         # for page-granular prompt-prefix hits.  Explicit instance wins;
         # otherwise VESCALE_SERVE_PREFIX_CACHE=1 builds one from env so
-        # every driver (loop, fleet replica, bench) gets it with zero
+        # every driver (loop, fleet replica) gets it with zero
         # call-site changes
         if prefix_cache is None and envreg.get_bool("VESCALE_SERVE_PREFIX_CACHE"):
             from .prefix_cache import PrefixCache

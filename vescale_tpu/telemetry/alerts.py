@@ -33,7 +33,7 @@ Rule grammar (docs/observability.md "Alerting"):
   * :class:`ManualRule` — code-driven: :func:`raise_alert` /
     :func:`resolve` walk the same lifecycle for watchers whose condition
     lives outside the store (watchdog stall, stale calibration table,
-    AOT drift, bench staleness).
+    AOT drift).
 
 Gating contract (memtrack precedent): dormant hooks ``evaluate`` /
 ``raise_alert`` / ``resolve`` ARE the module no-op references (identity-
@@ -305,8 +305,7 @@ class ZScoreRule(Rule):
 class ManualRule(Rule):
     """Code-driven rule: :func:`raise_alert`/:func:`resolve` flip it.  The
     migration target for watchers whose condition lives outside the store
-    (watchdog stall, stale calibration table, AOT drift, bench-TPU
-    staleness)."""
+    (watchdog stall, stale calibration table, AOT drift)."""
 
     kind = "manual"
 

@@ -306,8 +306,8 @@ def write_step_report(
     ``out_dir`` is configured — persist it as ``<out_dir>/<name>_report.json``.
     No-op while dormant.
 
-    ``aot_report``: path to (or loaded dict of) a matching
-    ``AOT_*_REPORT.json`` — the report gains an ``aot_drift`` section
+    ``aot_report``: path to (or loaded dict of) an ahead-of-time
+    compile report of the same program — the report gains an ``aot_drift`` section
     diffing the compiled step's memory footprint against the AOT budget,
     and drift beyond 10% warns (see memory_report.compare_with_aot)."""
     st = _STATE

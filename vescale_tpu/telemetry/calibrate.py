@@ -248,8 +248,8 @@ class CalibrationTable:
         return t
 
     def digest(self) -> str:
-        """Stable short content hash — BENCH lines and plan-cache keys
-        record it so a perf number names the cost model that priced it.
+        """Stable short content hash — plan-cache keys record it, so a
+        plan names the cost model that priced it.
         Memoized until the next ``add_sample``/``ingest_spans``."""
         if self._digest is None:
             blob = json.dumps(self.to_json(), sort_keys=True).encode()
@@ -529,7 +529,7 @@ def table_for(mesh) -> Optional[CalibrationTable]:
 
 def active_digest() -> Optional[str]:
     """Digest of the armed NON-EMPTY table, else None.  The signature the
-    planner's cache key and bench lines embed: an empty table is
+    planner's and the pipe schedule chooser's cache keys embed: an empty table is
     cost-model-identical to no table and must key identically."""
     t = active_table()
     if t is None or len(t) == 0:
@@ -618,5 +618,5 @@ def device_peaks(device) -> Dict[str, object]:
 
 def device_peak_flops(device) -> float:
     """Peak (bf16 matmul) FLOP/s of one accelerator chip — the MFU
-    denominator shared by the bench harness and the serve MFU gauge."""
+    denominator of the cost audit's roofline and the serve MFU gauge."""
     return float(device_peaks(device)["bf16_flops"])

@@ -381,7 +381,7 @@ class CostAudit:
             return [dict(r) for r in self._ledger.values()]
 
     def summary(self) -> Dict[str, Any]:
-        """The bench ``audit`` block: predicted-vs-measured rollup for the
+        """The ``audit`` block: predicted-vs-measured rollup for the
         run's own plans."""
         with self._lock:
             return {
@@ -508,7 +508,7 @@ def get_auditor() -> Optional[CostAudit]:
 
 
 def audit_summary() -> Optional[Dict]:
-    """Module-level summary (bench's audit block); None while dormant."""
+    """Module-level summary (the audit block); None while dormant."""
     a = _AUDIT
     return a.summary() if a is not None else None
 

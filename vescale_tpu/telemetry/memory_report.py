@@ -120,8 +120,8 @@ def live_array_census(
 
 # ----------------------------------------------------------- AOT drift
 def aot_memory_budget(aot: Dict[str, Any]) -> Optional[Dict[str, Any]]:
-    """Extract the per-device memory budget from an ``AOT_*_REPORT.json``
-    document.  Prefers the measured fp32-compile bytes (same basis as a
+    """Extract the per-device memory budget from an ahead-of-time compile
+    report.  Prefers the measured fp32-compile bytes (same basis as a
     fresh CPU/AOT compile of the step); falls back to the bf16-basis total.
     None when the document carries neither."""
     measured = (aot.get("measured") or {}).get("per_device_bytes_fp32_compile")

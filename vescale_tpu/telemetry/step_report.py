@@ -56,7 +56,7 @@ def build_step_report(
     the optimized HLO).  Fields XLA cannot provide on a backend come back
     None rather than raising — the report must degrade, not fail a run.
 
-    ``aot_report`` (path or loaded AOT_*_REPORT.json dict): attaches an
+    ``aot_report`` (path or loaded ahead-of-time compile report): attaches an
     ``aot_drift`` section diffing the measured memory footprint against the
     AOT budget (memory_report.compare_with_aot; None when either side lacks
     a usable byte count).

@@ -22,7 +22,7 @@ from .dmodule.api import DModule
 
 __all__ = ["make_train_step", "make_eval_step"]
 
-# double-increment guard (ADVICE): with auto_inc_step (default), a loop that
+# double-increment guard: with auto_inc_step (default), a loop that
 # ALSO advances the ndtimeline counter manually (inc_step() /
 # flush(next_iteration=True)) per step silently double-counts the global
 # step.  SHARED across every make_train_step fn: any auto-inc step records
