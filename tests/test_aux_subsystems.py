@@ -658,6 +658,7 @@ def test_use_compile_cache_places_the_cache_from_outside(monkeypatch, backend, e
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     before = jax.config.jax_compilation_cache_dir
+    threshold = jax.config.jax_persistent_cache_min_compile_time_secs
     try:
         got = compile_cache.use_compile_cache()
         if expect_set:
@@ -667,5 +668,8 @@ def test_use_compile_cache_places_the_cache_from_outside(monkeypatch, backend, e
         else:
             assert got is None
             assert jax.config.jax_compilation_cache_dir == before
+        # on an accelerator every program is kept, wherever the cache lies; on the CPU nothing is touched
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == (threshold if backend == "cpu" else 0.0)
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", threshold)

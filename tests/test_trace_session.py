@@ -194,9 +194,12 @@ def test_engine_counters_say_what_a_tiny_serve_run_implies(serve_rig, tmp_path):
     assert c["decode_steps"] >= 3
     # the loop takes each step's ids and reads no logits row: nothing crossed to the host
     assert c["logits_bytes_to_host"] == 0
+    # each prefill adds its prompt and the rung it was padded to (this cache is shorter than the smallest rung of
+    # the ladder, so it has the one rung): a run states its mean rung and its pad share from the counters alone
     real = sum(len(r.prompt) for _, r in arrivals)
-    assert c["prefill_tokens_real"] == real and c["prefill_tokens_padded"] == len(arrivals) * POSITIONS
-    assert c["prefill_tokens_padded"] - c["prefill_tokens_real"] == len(arrivals) * POSITIONS - real
+    rungs = [next(b for b in eng.buckets if b >= len(r.prompt)) for _, r in arrivals]
+    assert eng.buckets == [POSITIONS] and c["prefill_calls"] == len(arrivals)
+    assert c["prefill_tokens_real"] == real and c["prefill_tokens_padded"] == sum(rungs)
     # outside a session the engine counts on, and the next session reports its own share only
     _serve(eng, cache, n=1)
     nd.start_trace_session(str(tmp_path / "again"), profiler=False)

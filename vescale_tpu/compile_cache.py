@@ -26,15 +26,20 @@ def use_compile_cache() -> Optional[str]:
     """Turn the persistent compilation cache on for a run on an accelerator.
     Where ``JAX_COMPILATION_CACHE_DIR`` is set the directory is JAX's to
     read from the environment and none is set in code (returns None);
-    otherwise the cache goes to :data:`DEFAULT_CACHE_DIR` (returned).  On
+    otherwise the cache goes to :data:`DEFAULT_CACHE_DIR` (returned).
+    Either way every program is kept, however short its compile (JAX's own
+    threshold is a second): a serve engine warms some twenty small programs,
+    embed, head and commit a prefill rung, each of which the chip's compiler
+    takes 0.2-0.6 s to build and 0.05 s to load (PERF.md §6, PR 32).  On
     the CPU backend nothing is set (returns None): an XLA:CPU executable is
     tied to the CPU features of the machine that built it, and reloading
     one elsewhere warns at best."""
-    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        return None
     import jax
 
     if jax.default_backend() == "cpu":
+        return None
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return None
     jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     return DEFAULT_CACHE_DIR

@@ -7,17 +7,22 @@ the engine through ``checkpoint.load``'s elastic preflight — no weight
 conversion, no serving-specific checkpoint format.
 
 Two compiled paths, both STATIC-shaped so XLA never retraces as requests
-come and go:
+come and go (``warm()`` compiles every shape of both; the first ``prefill``
+runs it if nobody has):
 
-  **prefill** — the prompt padded to the cache's ``max_seq_len`` runs the
-  full stack once, reusing the flash-attention kernel path
-  (``ops.flash_attention``: Pallas on TPU, the same dense fallback the
+  **prefill** — the prompt padded to the next rung of a short ladder
+  (``prefill_buckets`` over the cache's geometry: 128, 256, 512, 1024, 1536,
+  ..., ``max_seq_len``; a prompt of 100 tokens runs 128 positions, not the
+  cache's 1536) runs the full stack once, reusing the flash-attention kernel
+  path (``ops.flash_attention``: Pallas on TPU, the same dense fallback the
   training forward takes off-TPU) and the training ``rotary`` phase math;
-  per-layer K/V land in the slot's reserved pages via one scatter.  The
-  layer stack is partitioned with the pipe engine's stage-split
-  (``pipe.pipe_stage._cuts_by_weight``) into ``num_stages`` separately
-  compiled segments — the cut points a prefill/decode-disaggregated
-  deployment would place its pipeline boundaries on.
+  per-layer K/V of the rung's positions land in the slot's first pages via
+  one scatter (the mask is causal, so the pad never reaches a real
+  position).  The layer stack is partitioned with the pipe engine's
+  stage-split (``pipe.pipe_stage._cuts_by_weight``) into ``num_stages``
+  separately compiled segments — the cut points a
+  prefill/decode-disaggregated deployment would place its pipeline
+  boundaries on.
 
   **decode** — one token per active slot: project q/k/v for the new
   position, scatter k/v into the page the slot's table maps that position
@@ -57,7 +62,33 @@ from ..ndtimeline import predefined as _p
 from ..ndtimeline.api import ndtimeit, register_counter_source
 from .kv_cache import PagedKVCache
 
-__all__ = ["DecodeStep", "ServeEngine", "stack_params_check"]
+__all__ = ["DecodeStep", "ServeEngine", "prefill_buckets", "stack_params_check"]
+
+# A prefill under some hundred positions streams the weights and gets little
+# faster (the DeepSeek serve cut on a v5e: 6.96 ms of the device at 128
+# positions, 8.24 at 256, 12.9 at 512; PERF.md §6, PR 32), so the dense ladder
+# starts here.  From _HALF_STEPS_FROM on a compute-bound prefill costs what it
+# is padded to, and a ladder that only doubled would pad a prompt of 1025
+# tokens to 2048: there the step between (1536, 3072) is a rung too.
+_SMALLEST_RUNG = 128
+_HALF_STEPS_FROM = 1024
+
+
+def prefill_buckets(chunk: int, max_seq_len: int, smallest: int = 0) -> List[int]:
+    """The lengths a prefill is padded to, for both engines: multiples of
+    ``chunk`` that double (from 1024 on with the half step between, so no rung
+    is over 1.5 x the one below), then ``max_seq_len``.  Rungs under
+    ``smallest`` are left out; a cache shorter than that has the one rung."""
+    if max_seq_len % chunk:
+        raise ValueError(f"max_seq_len {max_seq_len} is not a whole number of scan chunks of {chunk}")
+    buckets, b = [], chunk
+    while b < max_seq_len:
+        buckets.append(b)
+        half = b + b // 2
+        if b >= _HALF_STEPS_FROM and half % chunk == 0 and half < max_seq_len:
+            buckets.append(half)
+        b *= 2
+    return [b for b in buckets if b >= smallest] + [max_seq_len]
 
 
 class DecodeStep:
@@ -173,11 +204,15 @@ class ServeEngine:
         stack_params_check(params, c.num_hidden_layers)
         self.params = jax.tree_util.tree_map(self._replicate, params)
         self.stage_bounds = self._stage_bounds(num_stages)
+        # a function of the cache's geometry: whole pages, so a rung's K/V is a whole number of page writes
+        self.buckets = prefill_buckets(cache.config.page_size, cache.max_seq_len, smallest=_SMALLEST_RUNG)
+        self._warmed = False
         self._positions = np.arange(cache.max_seq_len, dtype=np.int32)[None, :]
         # what this engine has done, in plain integers (a trace session
         # reads them at its two ends: ``trace_counters``)
         self.decode_steps = 0
         self.logits_bytes_to_host = 0
+        self.prefill_calls = 0
         self.prefill_tokens_real = 0
         self.prefill_tokens_padded = 0
         self.decode_pages_read = 0      # counted only where the paged_decode kernel was built
@@ -296,7 +331,7 @@ class ServeEngine:
             return logits.astype(jnp.float32)
 
         def block_prefill(lp, x, positions):
-            """One decoder block over the full padded prompt: returns the
+            """One decoder block over the prompt padded to its rung: returns the
             residual stream plus this layer's K/V for the cache."""
             B, T, E = x.shape
             xn = _rmsnorm(x, lp["input_layernorm"]["weight"], eps).astype(dtype)
@@ -315,11 +350,16 @@ class ServeEngine:
             x = x + dense(jax.nn.silu(g) * u, lp["mlp"]["down_proj"]["kernel"])
             return x, k[0], v[0]
 
+        # every layer has the block's shapes, so jitted the block is traced and
+        # lowered once a rung whatever the depth (XLA inlines the calls): with a
+        # ladder of rungs to warm, the set-up pays that once a rung, not once a layer
+        block = jax.jit(block_prefill)
+
         def make_stage(lo, hi):
             def stage(params, x, positions):
                 ks, vs = [], []
                 for l in range(lo, hi):
-                    x, k, v = block_prefill(params[f"layers_{l}"], x, positions)
+                    x, k, v = block(params[f"layers_{l}"], x, positions)
                     ks.append(k)
                     vs.append(v)
                 return x, jnp.stack(ks), jnp.stack(vs)
@@ -337,10 +377,11 @@ class ServeEngine:
         self._head_fn = jax.jit(head_last)
 
         def commit_prefill(kd, vd, k_stack, v_stack, page_row):
-            # (L, Tmax, KV, hd) -> per-page blocks scattered into the pool;
-            # table entries beyond the reserved pages are 0 = the null page
-            kp = k_stack.reshape(c.num_hidden_layers, Pmax, page, KV, hd)
-            vp = v_stack.reshape(c.num_hidden_layers, Pmax, page, KV, hd)
+            # (L, rung, KV, hd) -> the rung's pages scattered into the pool
+            # (page_row is the slot's first rung // page table entries);
+            # entries beyond the reserved pages are 0 = the null page
+            kp = k_stack.reshape(c.num_hidden_layers, -1, page, KV, hd)
+            vp = v_stack.reshape(c.num_hidden_layers, -1, page, KV, hd)
             kd = kd.at[:, page_row].set(kp.astype(kd.dtype))
             vd = vd.at[:, page_row].set(vp.astype(vd.dtype))
             return (
@@ -515,37 +556,76 @@ class ServeEngine:
         self._make_multi = make_multi
         self._multi_fns: Dict[int, Any] = {}
 
+    def warm(self) -> "ServeEngine":
+        """Compile and run every program of the serving path: each rung of the
+        prefill ladder (into the null page only: a page row of zeros, so no
+        slot's pages or length are touched) and the decode step (no slot
+        active).  Twice over, as ``HybridServeEngine.warm`` does: the first
+        call of all sees the cache's arrays as they were allocated, every later
+        one as a program returned them, and a program that compiles again for
+        those does it here.  The first ``prefill`` of an engine's life runs
+        this if nobody has; no ``prefill`` or ``decode`` compiles after it
+        (``decode_multi`` lowers a width when it first meets it)."""
+        cache = self.cache
+        S, page = cache.num_slots, cache.config.page_size
+        self._warmed = True
+        for _ in range(2):
+            for rung in self.buckets:
+                self._run_prefill(np.zeros((rung,), np.int32), 1, np.zeros((rung // page,), np.int32))
+            self._run_decode(np.zeros((S, cache.config.pages_per_slot), np.int32), np.zeros((S,), np.int32),
+                             np.zeros((S,), np.int32))
+        return self
+
     # ---------------------------------------------------------------- API
+    def _run_prefill(self, toks: np.ndarray, n: int, page_row: np.ndarray):
+        """The programs of one prefill at ``len(toks)`` positions; the logits
+        row of position ``n - 1``, still on the device."""
+        import jax.numpy as jnp
+
+        cache = self.cache
+        x = self._embed_fn(self.params, toks)
+        positions = self._positions[:, : len(toks)]
+        ks, vs = [], []
+        for fn in self._stage_fns:
+            x, k, v = fn(self.params, x, positions)
+            ks.append(k)
+            vs.append(v)
+        logits = self._head_fn(self.params, x, np.int32(n))
+        k_stack = ks[0] if len(ks) == 1 else jnp.concatenate(ks, axis=0)
+        v_stack = vs[0] if len(vs) == 1 else jnp.concatenate(vs, axis=0)
+        kd, vd = self._commit_fn(cache.k.data, cache.v.data, k_stack, v_stack, page_row)
+        cache.update(kd, vd)
+        return logits
+
+    def _run_decode(self, table, lengths, tokens):
+        cache = self.cache
+        logits, next_ids, kd, vd = self._decode_fn(self.params, cache.k.data, cache.v.data, table, lengths, tokens)
+        cache.update(kd, vd)
+        return logits, next_ids
+
     def prefill(self, prompt: Sequence[int], slot: int) -> np.ndarray:
         """Run the prompt through the stack, write its K/V into ``slot``'s
         reserved pages, and return the next-token logits (fp32, host).
-        One compiled program per stage — shapes are static (prompt padded
-        to ``max_seq_len``), so repeat calls never retrace."""
+        One compiled program per stage and rung: the prompt is padded to the
+        smallest of ``self.buckets`` that holds it, every rung is compiled by
+        ``warm()``, so repeat calls never retrace."""
         cache = self.cache
         n = len(prompt)
         if not (0 < n <= cache.max_seq_len):
             raise ValueError(f"prompt length {n} not in (0, {cache.max_seq_len}]")
+        if not self._warmed:
+            self.warm()
+        rung = next(b for b in self.buckets if b >= n)
         with ndtimeit(_p.SERVE_PREFILL_CALL):
-            toks = np.zeros((cache.max_seq_len,), np.int32)
+            toks = np.zeros((rung,), np.int32)
             toks[:n] = np.asarray(prompt, np.int32)
-            x = self._embed_fn(self.params, toks)
-            ks, vs = [], []
-            for fn in self._stage_fns:
-                x, k, v = fn(self.params, x, self._positions)
-                ks.append(k)
-                vs.append(v)
-            logits = self._head_fn(self.params, x, np.int32(n))
-            import jax.numpy as jnp
-
-            k_stack = ks[0] if len(ks) == 1 else jnp.concatenate(ks, axis=0)
-            v_stack = vs[0] if len(vs) == 1 else jnp.concatenate(vs, axis=0)
-            page_row = np.ascontiguousarray(cache.page_table[slot])
-            kd, vd = self._commit_fn(cache.k.data, cache.v.data, k_stack, v_stack, page_row)
-            cache.update(kd, vd)
+            page_row = np.ascontiguousarray(cache.page_table[slot, : rung // cache.config.page_size])
+            logits = self._run_prefill(toks, n, page_row)
             with ndtimeit(_p.SERVE_PREFILL_FETCH):   # waits for the device, then copies the row
                 out = np.asarray(logits)
+        self.prefill_calls += 1
         self.prefill_tokens_real += n
-        self.prefill_tokens_padded += cache.max_seq_len
+        self.prefill_tokens_padded += rung
         return out
 
     def decode(self, tokens: np.ndarray) -> DecodeStep:
@@ -561,15 +641,8 @@ class ServeEngine:
         cache = self.cache
         lengths = cache.lengths_array()
         with ndtimeit(_p.SERVE_DECODE_CALL):
-            logits, next_ids, kd, vd = self._decode_fn(
-                self.params,
-                cache.k.data,
-                cache.v.data,
-                cache.table_array(),
-                lengths,
-                np.asarray(tokens, np.int32).reshape(cache.num_slots),
-            )
-            cache.update(kd, vd)
+            logits, next_ids = self._run_decode(
+                cache.table_array(), lengths, np.asarray(tokens, np.int32).reshape(cache.num_slots))
             with ndtimeit(_p.SERVE_DECODE_FETCH):   # waits for the device and the ids
                 out = DecodeStep(jax.device_get(next_ids), logits, self)
         self.decode_steps += 1
@@ -588,12 +661,15 @@ class ServeEngine:
         logits that callers copied out of their results (none for a step
         read through ``.tokens`` alone, ``vocab x 4`` a row, ``slots x
         vocab x 4`` a whole read; prefill copies one row and
-        ``decode_multi`` every row, neither counted).  ``decode_pages_read``
+        ``decode_multi`` every row, neither counted).  ``prefill_tokens_padded``
+        adds the rung each of the ``prefill_calls`` ran at, so padded / calls is
+        the mean rung and 1 - real / padded the pad share.  ``decode_pages_read``
         of ``decode_pages_capacity`` says how far the ``paged_decode`` kernel
         engaged: the pages of K (and as many of V) it fetched a layer, summed
         over ``decode`` calls, against the ``slots x pages_per_slot`` the XLA
         leg gathers; both stay 0 on an engine built with the XLA leg."""
         return {"decode_steps": self.decode_steps, "logits_bytes_to_host": self.logits_bytes_to_host,
+                "prefill_calls": self.prefill_calls,
                 "prefill_tokens_real": self.prefill_tokens_real,
                 "prefill_tokens_padded": self.prefill_tokens_padded,
                 "decode_pages_read": self.decode_pages_read,

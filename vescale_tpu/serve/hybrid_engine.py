@@ -16,9 +16,11 @@ Two kinds of compiled program, all static-shaped and all compiled by
 ``warm()`` before the engine is handed over:
 
   **prefill**, one program a BUCKET: the prompt is padded to the next of
-  ``chunk, 2 chunk, 4 chunk, ..., max_seq_len`` (the chunked scan wants whole
-  chunks; padding to ``max_seq_len`` would cost a short prompt six times its
-  work).  In the pad the state-space step size is forced to 0, so the state
+  ``prefill_buckets(chunk, max_seq_len)`` (the rule's home is ``serve/engine.py``,
+  whose ``ServeEngine`` pads by it too): ``chunk, 2 chunk, 4 chunk, ...,
+  max_seq_len`` (the chunked scan wants whole chunks; padding to
+  ``max_seq_len`` would cost a short prompt six times its work).  In the pad
+  the state-space step size is forced to 0, so the state
   stands where the prompt ends; the convolution tail is the prompt's last real
   inputs; the logits row is the last real position's; K and V of the bucket's
   positions go to the slot's pages (what lies past its reserved pages to the
@@ -40,27 +42,16 @@ position, which ``PagedKVCache`` with slot state does not keep; ``num_stages``
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, Optional, Sequence
 
 import numpy as np
 
 from ..ndtimeline import predefined as _p
 from ..ndtimeline.api import ndtimeit, register_counter_source
-from .engine import DecodeStep
+from .engine import DecodeStep, prefill_buckets
 from .kv_cache import KVCacheConfig, PagedKVCache, SlotStateUnsupported
 
 __all__ = ["HybridServeEngine", "hybrid_cache_config", "prefill_buckets"]
-
-
-def prefill_buckets(chunk: int, max_seq_len: int) -> List[int]:
-    """Multiples of the chunk that double, then ``max_seq_len``."""
-    if max_seq_len % chunk:
-        raise ValueError(f"max_seq_len {max_seq_len} is not a whole number of scan chunks of {chunk}")
-    buckets, b = [], chunk
-    while b < max_seq_len:
-        buckets.append(b)
-        b *= 2
-    return buckets + [max_seq_len]
 
 
 def hybrid_cache_config(config, *, num_slots: int, page_size: int, pages_per_slot: int,
