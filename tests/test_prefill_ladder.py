@@ -33,6 +33,8 @@ LADDER = [128, 256, 512, 640]
     (24, 2400, 128, [192, 384, 768, 1536, 2304, 2400]),  # pages that are no power of two: whole pages all the same
     (8, 32, 0, [8, 16, 32]),
     (256, 4096, 0, [256, 512, 1024, 1536, 2048, 3072, 4096]),
+    (128, 8192, 0, [128, 256, 512, 1024, 1536, 2048, 3072, 4096, 5120, 6144, 7168, 8192]),   # from 4096 on, quarter steps
+    (128, 6144, 128, [128, 256, 512, 1024, 1536, 2048, 3072, 4096, 5120, 6144]),
     (256, 1500, 0, ValueError),                         # no whole number of chunks: refused as before
     (16, 1000, 128, ValueError),
 ])

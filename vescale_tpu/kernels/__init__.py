@@ -3,10 +3,10 @@
 Every hand-written TPU kernel in the framework lives in this package and is
 reached through the same knob (``VESCALE_KERNELS``, registered in
 ``analysis.envreg``).  Unset, each kernel takes its own default:
-``paged_decode`` and ``ssm_step`` are the compiled kernels on TPU and the
+``paged_decode``, ``paged_decode_latent`` and ``ssm_step`` are the compiled kernels on TPU and the
 XLA leg on every other backend (what the platform is, the code can see;
 PERF.md, PR 27 and PR 29); the other three stay ``off``.  Set, it means the
-same for all five:
+same for all six:
 
   ``off``        the kernels are never consulted — every caller takes
                  exactly the XLA path it took before this package
@@ -32,6 +32,11 @@ Kernels in this package:
     runs an online fp32 softmax over them — instead of the slice →
     gather → masked-softmax → matmul chain over all ``Tmax`` positions;
     dispatched by ``serve/engine.py``, the default decode path on TPU.
+  * ``paged_decode_latent`` — its sibling over a LATENT pool (multi-head
+    latent attention's absorbed decode): one row a position serves every
+    head's score and, by its leading columns, the values, so a page is
+    fetched once for both; dispatched by ``models/deepseek_v2.py`` under
+    ``serve/hybrid_engine.py``, the default on TPU.
   * ``ssm_step``         — a state-space (Mamba-2) layer's decode step
     over every slot's recurrent state, in place: one read and one write of
     the state where XLA reads it twice; dispatched by
@@ -82,7 +87,7 @@ __all__ = [
 MODES = ("off", "interpret", "on")
 # what an unset VESCALE_KERNELS means for these: compiled on TPU, the XLA leg
 # elsewhere (every other kernel: off)
-DEFAULT_ON_TPU = frozenset({"paged_decode", "ssm_step"})
+DEFAULT_ON_TPU = frozenset({"paged_decode", "paged_decode_latent", "ssm_step"})
 
 
 def mode() -> str:

@@ -27,6 +27,7 @@ __all__ = [
     "_use_streaming",
     "_flash_fwd_pallas",
     "_flash_bwd_pallas",
+    "_flash_fwd_serve",
 ]
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/where VPU-safe
@@ -225,6 +226,86 @@ def _flash_fwd_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret, H,
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ),
         interpret=interpret,
+    )(q3, k3, v3)
+
+
+# ------------------------------------------------- forward for a serve prefill
+# The kernels above widen their blocks to float32 before the products (the
+# backward recomputes the same probabilities, and training compares losses to
+# 1e-3), which on the chip is several MXU passes a product: the device trace of
+# a long prefill read 29% of the MXU's peak from them (PERF.md, PR 34).  A serve
+# prefill never differentiates its attention, so this forward multiplies the
+# operands AS THEY ARE (bfloat16 blocks, float32 accumulation; the probabilities
+# rounded to the values' type for the second product, as the decode kernels do),
+# keeps the softmax in float32, writes no logsumexp, masks only the blocks the
+# diagonal crosses, and takes values of another width than the scores.
+_SERVE_VMEM_LIMIT_BYTES = 48 * 1024 * 1024     # of the chip's 128 MiB: a head's whole K and V at 8192 positions
+
+
+def _fwd_kernel_serve(q_ref, k_ref, v_ref, o_ref, *, scale, block_q, block_k):
+    """Grid (H, T / block_q), causal.  The head's whole K (T, D) and V (T, Dv)
+    stay in VMEM across its query blocks; the loop runs over the key blocks up
+    to the diagonal, unmasked below it."""
+    qi = pl.program_id(1)
+    q = (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)          # (block_q, D), scaled once a block
+    first_row = qi * block_q
+    unmasked = (first_row + 1) // block_k                                    # key blocks wholly at or below the first row
+    last = (first_row + block_q - 1) // block_k + 1
+    row = first_row + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+
+    def step(j, carry, masked):
+        m, l, acc = carry
+        k = k_ref[0, pl.ds(j * block_k, block_k), :]
+        v = v_ref[0, pl.ds(j * block_k, block_k), :]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        if masked:
+            col = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+            s = jnp.where(row >= col, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * alpha + jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                                                    preferred_element_type=jnp.float32)
+        return m_new, l_new, acc_new
+
+    carry = (jnp.full((block_q, 1), _NEG_INF, jnp.float32), jnp.zeros((block_q, 1), jnp.float32),
+             jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32))
+    carry = jax.lax.fori_loop(0, unmasked, lambda j, c: step(j, c, False), carry)
+    _m, l, acc = jax.lax.fori_loop(unmasked, last, lambda j, c: step(j, c, True), carry)
+    o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def _serve_vmem_bytes(T: int, D: int, Dv: int, dtype, block_q: int, block_k: int) -> int:
+    """Scoped VMEM of :func:`_fwd_kernel_serve`: every operand double-buffered, and the step's score tiles."""
+    blocks = _vmem_bytes(block_q, D, dtype) + _vmem_bytes(block_q, Dv, dtype) + _vmem_bytes(T, D, dtype) + _vmem_bytes(T, Dv, dtype)
+    return 2 * blocks + _WORK_TILES["fwd"] * _vmem_bytes(block_q, block_k, jnp.float32)
+
+
+def _flash_fwd_serve(q3, k3, v3, scale, block_q, block_k, interpret, name=None):
+    """Causal forward alone over q3, k3 (H, T, D) and v3 (H, T, Dv), one
+    sequence, no grouped heads; returns (H, T, Dv) in q3's type.  A head's K
+    and V must fit ``_SERVE_VMEM_LIMIT_BYTES`` (16k positions at these widths)."""
+    H, T, D = q3.shape
+    Dv = v3.shape[-1]
+    need = _serve_vmem_bytes(T, D, Dv, k3.dtype, block_q, block_k)
+    if need > _SERVE_VMEM_LIMIT_BYTES:
+        raise ValueError(f"the serve flash forward keeps a head's K and V in VMEM: {T} positions of {D} / {Dv} need "
+                         f"{need} bytes of {_SERVE_VMEM_LIMIT_BYTES} (a streaming form is not written)")
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel_serve, scale=scale, block_q=block_q, block_k=block_k),
+        out_shape=jax.ShapeDtypeStruct((H, T, Dv), q3.dtype),
+        grid=(H, T // block_q),
+        in_specs=[
+            pl.BlockSpec((1, block_q, D), lambda h, i: (h, i, 0)),
+            pl.BlockSpec((1, T, D), lambda h, i: (h, 0, 0)),
+            pl.BlockSpec((1, T, Dv), lambda h, i: (h, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, Dv), lambda h, i: (h, i, 0)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"),
+                                             vmem_limit_bytes=_SERVE_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name=name,
     )(q3, k3, v3)
 
 

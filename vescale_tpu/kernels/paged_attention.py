@@ -53,7 +53,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["paged_decode", "supports"]
+__all__ = ["paged_decode", "supports", "paged_decode_latent", "supports_latent"]
 
 _NEG_INF = -1e30
 _BLOCK_BYTES = 1 << 20       # of K in one block (V the same; two buffers each)
@@ -239,3 +239,135 @@ def paged_decode(q, k_pool, v_pool, table, lengths, *, layer, scale, interpret):
         name="paged_decode",
     )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.clip(lengths.astype(jnp.int32), 0, Pmax * page),
       table.astype(jnp.int32), q, k_pool, v_pool)
+
+
+# ------------------------------------------------------------- the latent form
+# Multi-head latent attention's decode (the absorbed form): the pool holds ONE
+# row a position, shared by every head, and the values are the row's first
+# ``latent`` columns.  So a block of pages is fetched once (one DMA a page, as
+# above) and serves both products: all ``H`` absorbed queries against the
+# block's rows through the MXU for the scores, the probabilities against the
+# same rows' leading columns for the mix.  With 128 heads on one row that is
+# about 240 operations a byte read: the v5e's ridge, where the kernel above
+# (one or four heads a row) sits far on the memory side.
+def supports_latent(pool_dtype, row: int, latent: int, page: int, *, interpret: bool) -> bool:
+    """Whether :func:`paged_decode_latent` takes a pool of this dtype with
+    rows ``row`` wide of which the first ``latent`` are the values: compiled,
+    both are whole 128-lane tiles and a page is a whole sublane tile of the
+    dtype (16 rows of bfloat16, 8 of float32)."""
+    dt = jnp.dtype(pool_dtype)
+    if dt not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)) or not 0 < latent <= row:
+        return False
+    return interpret or (row % 128 == 0 and latent % 128 == 0 and page % (32 // dt.itemsize) == 0)
+
+
+def _latent_kernel(layer_ref, len_ref, table_ref, q_ref, pool_hbm, o_ref,
+                   buf, sems, cur_ref, m_scr, l_scr, acc_scr, *, scale, page, block_pages, latent):
+    """Grid (S,), sequential; the buffers alternate over the whole call as in ``_decode_kernel``."""
+    s = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    T = block_pages * page
+    row = buf.shape[-1]
+    layer = layer_ref[0]
+
+    def block_dma(slot, block, b, wait):
+        first = block * block_pages
+        live = jnp.clip(pl.cdiv(len_ref[slot], page) - first, 0, block_pages)
+
+        def one_page(i, carry):
+            copy = pltpu.make_async_copy(pool_hbm.at[layer, table_ref[slot, first + i]], buf.at[b, i], sems.at[b])
+            copy.wait() if wait else copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, live, one_page, 0)
+
+    length = len_ref[s]
+    n_blocks = jnp.maximum(pl.cdiv(length, T), 1)
+
+    @pl.when(s == 0)
+    def _first():
+        cur_ref[0] = 0
+        block_dma(0, 0, 0, wait=False)
+
+    m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+    q = q_ref[0]                                                       # (H, row), the pool's type
+
+    def block_body(b, cur):
+        nxt = 1 - cur
+
+        @pl.when(b + 1 < n_blocks)
+        def _():
+            block_dma(s, b + 1, nxt, wait=False)
+
+        @pl.when(jnp.logical_and(b + 1 == n_blocks, s + 1 < n_slots))
+        def _():
+            block_dma(s + 1, 0, nxt, wait=False)
+
+        block_dma(s, b, cur, wait=True)
+        rows = buf[cur].reshape(T, row)
+        # rows past the length are stale pool bytes, or VMEM a skipped DMA never wrote: zeroed, and their scores masked
+        keep = (b * T + jax.lax.broadcasted_iota(jnp.int32, (T, row), 0)) < length
+        rows = jnp.where(keep, rows, jnp.zeros_like(rows))
+        sc = scale * jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)  # (H, T)
+        valid = (b * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)) < length
+        sc = jnp.where(valid, sc, _NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        pexp = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
+        m_scr[...] = m_new
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            pexp.astype(rows.dtype), rows[:, :latent], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return nxt
+
+    cur_ref[0] = jax.lax.fori_loop(0, n_blocks, block_body, cur_ref[0])
+    l = l_scr[...]
+    o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "latent", "interpret"))
+def paged_decode_latent(q, pool, table, lengths, *, layer, scale, latent, interpret):
+    """One decode-step attention of one layer over a LATENT paged pool.
+
+    ``q``: (S, H, row) absorbed queries in the pool's type (the products run
+    on operands of that type with float32 accumulation, as the XLA leg's do);
+    ``pool``: (L, N, page, 1, row), every layer, left in HBM; ``table``,
+    ``lengths``, ``layer`` as :func:`paged_decode` takes them.  Returns
+    float32 (S, H, latent): ``sum_t softmax_t(scale q . row_t) row_t[:latent]``
+    over the slot's first ``lengths`` positions (zeros for a length of 0).
+    Only the slot's live pages are read, once, for scores and values both."""
+    S, H, row = q.shape
+    L, N, page, one, row2 = pool.shape
+    if one != 1 or row != row2 or q.dtype != pool.dtype:
+        raise ValueError(f"paged_decode_latent: q {q.shape} {q.dtype} against a pool {pool.shape} {pool.dtype}")
+    if not supports_latent(pool.dtype, row, latent, page, interpret=bool(interpret)):
+        raise ValueError(f"paged_decode_latent takes no {pool.dtype} pool of rows {row} / {latent} in pages of {page} "
+                         "(see supports_latent())")
+    Pmax = table.shape[1]
+    bp = _block_pages(Pmax, page, 1, row, jnp.dtype(pool.dtype).itemsize)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[pl.BlockSpec((1, H, row), lambda s, *_: (s, 0, 0)), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, H, latent), lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, bp, page, row), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, latent), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, scale=float(scale), page=page, block_pages=bp, latent=latent),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, H, latent), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_decode_latent",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.clip(lengths.astype(jnp.int32), 0, Pmax * page),
+      table.astype(jnp.int32), q, pool.reshape(L, N, page, row))

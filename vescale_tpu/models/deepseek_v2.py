@@ -1,0 +1,509 @@
+"""DeepSeek-V2 (``deepseek_v2``): a decoder whose attention is multi-head
+LATENT attention (MLA) and whose feed-forward, after a leading dense layer, is
+a group-limited routed expert layer beside shared experts.
+
+The block is written ONCE, as pure functions over a plain parameter tree
+(``init_params``), and both serve programs call them, as in
+``models/granite_hybrid.py``; the last section of this file is what
+``serve.HybridServeEngine`` asks of a model's module (the cache's geometry,
+the bodies of its two programs, the counters of a decode step).
+
+Equations (HF ``modeling_deepseek.py``; ISSUE 34 writes them out).  Pre-norm
+residual blocks, an untied head, a final norm.  Attention, every layer::
+
+    c_q = norm(W_qa x);  q_h = W_qb,h c_q = q_nope,h (128) | q_pe,h (64)
+    W_kva x = c_kv (512) | k_pe (64, one for all heads);  c = norm(c_kv)
+    rotary (YaRN frequencies) on q_pe,h and k_pe, over interleaved pairs
+    scale = 192^-0.5 * mscale^2
+
+  * **expanded** (prefill; what the source computes): ``k_h = W_uk,h c | k_pe``,
+    ``v_h = W_uv,h c``, causal softmax, ``o = W_o concat_h(P_h v_h)``: scores
+    192 wide, values 128 wide, through the blocked flash forward;
+  * **absorbed** (decode; the same numbers): ``q'_h = W_uk,h^T q_nope,h |
+    q_pe,h`` (576), ``s_h,t = q'_h . row_t``, ``u_h = sum_t p_h,t c_t`` (512),
+    ``o = W_o concat_h(W_uv,h u_h)``: all 128 heads read ONE row a position.
+
+The cache row of a position is ``c_t | k_pe,t`` (after the norm, after the
+rotary): 576 numbers in ``config.dtype``, padded with zeros to ``cache_row``
+(640, whole 128-lane tiles: what the chip's layout of a 576-wide row occupies
+anyway), in a paged cache of the latent form (``serve/kv_cache.py``): one pool,
+no value pool.  ``kv_b`` is kept as its two halves in the layouts the absorbed
+products read (``kv_b_k`` (H, 128, 512), ``kv_b_v`` (H, 512, 128)); the
+expanded form multiplies by the same two arrays, so there is no second copy.
+
+Layer 0 is a SwiGLU MLP of ``intermediate_size``; every later layer scores all
+``num_experts`` by a float32 softmax, keeps ``topk_group`` of ``n_group``
+groups, then ``num_experts_per_tok`` experts, gates not renormalised, times
+``routed_scaling_factor`` (``moe.dropless.route_group_limited``), beside one
+SwiGLU of ``n_shared_experts x moe_intermediate_size``.
+
+Precision: weights and matmul operands ``config.dtype`` (bfloat16) with
+float32 accumulation; residual stream, norms, rotary, router and softmax
+float32.  A chip's share: as in ``models/granite_hybrid.py`` (``num_experts``
+is what the router scores; ``experts_held`` / ``first_expert_held`` which of
+them this tree holds; ``vocab_size`` the rows of embedding and head held here).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..moe.dropless import dropless_experts, route_group_limited
+
+__all__ = [
+    "DeepseekV2Config", "init_params", "rmsnorm", "embed", "head", "yarn_inv_freq", "yarn_mscale", "rotary",
+    "mla_prefill", "mla_step", "latent_attention_xla", "dense_mlp", "expert_layer", "layer_prefill", "layer_step",
+    "cache_config", "prefill_chunk", "decode_kernels", "serve_prefill", "serve_decode", "STEP_COUNTERS",
+    "step_counters", "prefill_counters",
+]
+
+F32 = jnp.float32
+LANES = 128
+FLASH_NAME = "mla_flash_fwd"        # the prefill attention's kernel, as the device trace names it
+# Random weights of variance 1 / fan-in make every expert's output as large as the residual stream, and the
+# gates (a peaked softmax's probabilities times 16) reach 3.5: a token whose sixth and seventh expert a
+# rounding difference swaps then moves by a tenth of its own size, the next layer's router sees that and
+# swaps more, and two computations of the same model in different precisions part by their whole range
+# (PERF.md, section 6, PR 34: 0.47 to 1.3 of the largest logit on the chip).  In a trained model one
+# expert's marginal contribution is small beside the stream.  So ``init_params`` draws the routed experts'
+# down projections this much narrower: the routed part stays a few per cent of the stream, a swapped
+# expert moves a logit row by 5e-3 of the largest, and a wrong gate scale still shows.
+ROUTED_DOWN_GAIN = 1.0 / 64.0
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepseekV2Config:
+    vocab_size: int = 102400            # rows of the embedding and of the head held here
+    hidden_size: int = 5120
+    num_hidden_layers: int = 60
+    first_k_dense_replace: int = 1      # layers before the first expert layer
+    intermediate_size: int = 12288      # the dense layers' MLP
+    moe_intermediate_size: int = 1536   # width of one routed expert
+    n_shared_experts: int = 2
+    num_experts: int = 160              # the router's outputs: every expert the model has
+    num_experts_per_tok: int = 6
+    n_group: int = 8
+    topk_group: int = 3
+    routed_scaling_factor: float = 16.0
+    experts_held: int = 160             # ... and the contiguous ids this tree holds
+    first_expert_held: int = 0
+    num_attention_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 10000.0
+    rope_factor: float = 40.0           # rope_scaling (type "yarn")
+    rope_original_max_position_embeddings: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 0.707
+    rope_mscale_all_dim: float = 0.707
+    rms_norm_eps: float = 1e-6
+    prefill_chunk: int = 128            # the prefill ladder's first rung: the flash forward's smallest whole block
+    dtype: Any = jnp.bfloat16           # weights, matmul operands and the cache's rows
+
+    def __post_init__(self):
+        if not (0 <= self.first_expert_held and self.first_expert_held + self.experts_held <= self.num_experts
+                and self.experts_held > 0):
+            raise ValueError(f"experts {self.first_expert_held}..{self.first_expert_held + self.experts_held} "
+                             f"are not among the router's {self.num_experts}")
+        if self.num_experts % self.n_group or not 0 < self.topk_group <= self.n_group:
+            raise ValueError(f"{self.num_experts} experts do not lie in {self.n_group} groups of which "
+                             f"{self.topk_group} are kept")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError("the rotary part of a head is made of pairs")
+        if not 0 < self.first_k_dense_replace <= self.num_hidden_layers:
+            raise ValueError("first_k_dense_replace counts the leading dense layers, at least one")
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_row(self) -> int:
+        """What a position leaves in the cache: the latent and the one rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def cache_row(self) -> int:
+        """... as the pool keeps it: padded with zeros to whole lane tiles."""
+        return -(-self.latent_row // LANES) * LANES
+
+    @property
+    def softmax_scale(self) -> float:
+        return self.qk_head_dim ** -0.5 * yarn_mscale(self.rope_factor, self.rope_mscale_all_dim) ** 2
+
+    @property
+    def groups_held(self) -> Tuple[int, ...]:
+        """The routing groups whose experts this tree holds (whole groups, or the count is not whole)."""
+        per = self.num_experts // self.n_group
+        return tuple(g for g in range(self.n_group)
+                     if self.first_expert_held <= g * per and (g + 1) * per <= self.first_expert_held + self.experts_held)
+
+
+# ------------------------------------------------------------------ parameters
+def init_params(config: DeepseekV2Config, key) -> Dict[str, Any]:
+    """Seeded random weights in the types they are served in (jit the call:
+    the float32 draws are then temporaries).  Matrices are normal with
+    variance 1 / fan-in, but for two: the router (float32) is drawn twice as
+    wide, so that its softmax over all experts is not flat and the groups
+    differ; and the routed experts' down projections ``ROUTED_DOWN_GAIN`` times
+    as wide (the constant says why)."""
+    c, dt = config, config.dtype
+    E, H = c.hidden_size, c.num_attention_heads
+
+    def normal(k, shape, fan_in, dtype=dt, gain=1.0):
+        return (jax.random.normal(k, shape, F32) * (gain / math.sqrt(fan_in))).astype(dtype)
+
+    def attention(k):
+        ks = jax.random.split(k, 6)
+        return {"q_a": normal(ks[0], (E, c.q_lora_rank), E),
+                "q_a_norm": jnp.ones((c.q_lora_rank,), dt),
+                "q_b": normal(ks[1], (c.q_lora_rank, H * c.qk_head_dim), c.q_lora_rank),
+                "kv_a": normal(ks[2], (E, c.latent_row), E),
+                "kv_a_norm": jnp.ones((c.kv_lora_rank,), dt),
+                # kv_b's two halves, a head at a time: W_uk (H, nope, C) and W_uv (H, C, v)
+                "kv_b_k": normal(ks[3], (H, c.qk_nope_head_dim, c.kv_lora_rank), c.kv_lora_rank),
+                "kv_b_v": normal(ks[4], (H, c.kv_lora_rank, c.v_head_dim), c.kv_lora_rank),
+                "o": normal(ks[5], (H * c.v_head_dim, E), H * c.v_head_dim)}
+
+    def swiglu(k, width):
+        ks = jax.random.split(k, 3)
+        return {"gate": normal(ks[0], (E, width), E), "up": normal(ks[1], (E, width), E),
+                "down": normal(ks[2], (width, E), width)}
+
+    def moe(k):
+        ks = jax.random.split(k, 5)
+        F, held = c.moe_intermediate_size, c.experts_held
+        return {"router": normal(ks[0], (E, c.num_experts), E, F32, gain=2.0),
+                "w_gate": normal(ks[1], (held, E, F), E), "w_up": normal(ks[2], (held, E, F), E),
+                "w_down": normal(ks[3], (held, F, E), F, gain=ROUTED_DOWN_GAIN),
+                "shared": swiglu(ks[4], c.n_shared_experts * F)}
+
+    params: Dict[str, Any] = {
+        "embed_tokens": {"embedding": normal(jax.random.fold_in(key, 1 << 20), (c.vocab_size, E), E)},
+        "lm_head": {"kernel": normal(jax.random.fold_in(key, 1 << 21), (E, c.vocab_size), E)},
+        "norm": {"weight": jnp.ones((E,), dt)},
+    }
+    for l in range(c.num_hidden_layers):
+        k_attn, k_mlp = jax.random.split(jax.random.fold_in(key, l))
+        params[f"layers_{l}"] = {
+            "input_layernorm": {"weight": jnp.ones((E,), dt)},
+            "post_attention_layernorm": {"weight": jnp.ones((E,), dt)},
+            "self_attn": attention(k_attn),
+            "mlp": moe(k_mlp) if l >= c.first_k_dense_replace else swiglu(k_mlp, c.intermediate_size),
+        }
+    return params
+
+
+# ------------------------------------------------------------- shared pieces
+def rmsnorm(x, w, eps):
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * w.astype(F32)
+
+
+def _mm(x, w, dtype):
+    """``x @ w`` with operands in ``dtype`` and a float32 result."""
+    return jnp.dot(x.astype(dtype), w.astype(dtype), preferred_element_type=F32)
+
+
+def embed(config: DeepseekV2Config, params, tokens):
+    return jnp.take(params["embed_tokens"]["embedding"], tokens, axis=0).astype(F32)
+
+
+def head(config: DeepseekV2Config, params, x):
+    """Logits (float32) over the rows of the vocabulary held here."""
+    return _mm(rmsnorm(x, params["norm"]["weight"], config.rms_norm_eps), params["lm_head"]["kernel"], config.dtype)
+
+
+def _swiglu(p, h, dtype):
+    return _mm(jax.nn.silu(_mm(h, p["gate"], dtype)) * _mm(h, p["up"], dtype), p["down"], dtype)
+
+
+# --------------------------------------------------------------------- rotary
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(config: DeepseekV2Config) -> np.ndarray:
+    """The rotary frequencies (dim / 2,) under YaRN: a pair that turns more
+    than ``beta_fast`` times over the original length keeps its frequency, one
+    that turns fewer than ``beta_slow`` times has it divided by ``factor``, a
+    linear ramp between."""
+    c = config
+    dim, base = c.qk_rope_head_dim, c.rope_theta
+    plain = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def correction_dim(rotations):
+        return dim * math.log(c.rope_original_max_position_embeddings / (rotations * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(c.rope_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(c.rope_beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / ((high if high != low else high + 0.001) - low), 0, 1)
+    keep = 1.0 - ramp
+    return (plain / c.rope_factor * (1.0 - keep) + plain * keep).astype(np.float32)
+
+
+def rotary(config: DeepseekV2Config, x, positions):
+    """Rotate the interleaved pairs ``(2i, 2i+1)`` of ``x`` (..., dim) by
+    ``positions`` times the YaRN frequencies, in place: ``positions``
+    broadcasts against ``x``'s leading axes with one axis of size 1 added for
+    ``dim`` (a (T,) vector for ``x`` (T, dim); ``positions[:, None]`` for (T,
+    H, dim); ``positions[None, :]`` for (H, T, dim)).  The source first
+    brings the pairs to halves and its result stays so; queries and keys get
+    the same order, so scores do not see it, and keeping the pairs where they
+    are needs no strided access: ``x cos + (x R) sin`` with ``R`` the (dim,
+    dim) matrix that takes each pair ``(a, b)`` to ``(-b, a)`` (entries 0 and
+    +-1: the product is exact).  Float32.  The cos/sin multiplier ``mscale /
+    mscale_all_dim`` is applied as it stands."""
+    c = config
+    dim = x.shape[-1]
+    angle = positions.astype(F32)[..., None] * jnp.asarray(np.repeat(yarn_inv_freq(c), 2))      # (..., dim)
+    m = yarn_mscale(c.rope_factor, c.rope_mscale) / yarn_mscale(c.rope_factor, c.rope_mscale_all_dim)
+    turn = np.zeros((dim, dim), np.float32)
+    turn[np.arange(1, dim, 2), np.arange(0, dim, 2)] = -1.0
+    turn[np.arange(0, dim, 2), np.arange(1, dim, 2)] = 1.0
+    x = x.astype(F32)
+    turned = jnp.dot(x, jnp.asarray(turn), precision=jax.lax.Precision.HIGHEST)
+    return x * (jnp.cos(angle) * m) + turned * (jnp.sin(angle) * m)
+
+
+# ------------------------------------------------------------------ attention
+def _queries(c: DeepseekV2Config, ap, u, positions, *, head_major: bool = False):
+    """``q_nope`` (T, H, nope) and the rotated ``q_pe`` (T, H, rope) in the
+    operands' type; ``head_major``: (H, T, .), as the flash forward reads
+    them, straight from the product."""
+    T, H = u.shape[0], c.num_attention_heads
+    cq = rmsnorm(_mm(u, ap["q_a"], c.dtype), ap["q_a_norm"], c.rms_norm_eps).astype(c.dtype)
+    w = ap["q_b"].astype(c.dtype).reshape(c.q_lora_rank, H, c.qk_head_dim)
+    q = jnp.einsum("tr,rhd->htd" if head_major else "tr,rhd->thd", cq, w, preferred_element_type=F32).astype(c.dtype)
+    where = positions[None, :] if head_major else positions[:, None]
+    return q[..., : c.qk_nope_head_dim], rotary(c, q[..., c.qk_nope_head_dim:], where).astype(c.dtype)
+
+
+def _latent_rows(c: DeepseekV2Config, ap, u, positions):
+    """The cache rows of ``u``'s positions (T, cache_row) in ``c.dtype``:
+    the normed latent, the rotated key, the zero pad."""
+    kv = _mm(u, ap["kv_a"], c.dtype)
+    latent = rmsnorm(kv[:, : c.kv_lora_rank], ap["kv_a_norm"], c.rms_norm_eps)
+    k_pe = rotary(c, kv[:, c.kv_lora_rank:], positions)
+    pad = jnp.zeros((u.shape[0], c.cache_row - c.latent_row), F32)
+    return jnp.concatenate([latent, k_pe, pad], axis=-1).astype(c.dtype)
+
+
+def mla_prefill(c: DeepseekV2Config, ap, u, *, interpret: Optional[bool] = None):
+    """The EXPANDED form over one sequence ``u`` (T, E) from position 0:
+    per-head keys and values from the latent, causal attention with scores
+    ``qk_head_dim`` wide and values ``v_head_dim`` wide through the blocked
+    flash forward (no (T, T) tensor).  Returns the output (T, E) and the
+    positions' cache rows (T, cache_row).  Pad positions follow the real ones,
+    so causality keeps them out."""
+    from ..ops.flash_attention import flash_attention_forward
+
+    T, H = u.shape[0], c.num_attention_heads
+    positions = jnp.arange(T, dtype=jnp.int32)
+    q_nope, q_pe = _queries(c, ap, u, positions, head_major=True)
+    rows = _latent_rows(c, ap, u, positions)
+    latent, k_pe = rows[:, : c.kv_lora_rank], rows[:, c.kv_lora_rank: c.latent_row]
+    # head-major (H, T, .) as the kernel reads them, in the operands' type straight from the products
+    k_nope = jnp.einsum("tc,hdc->htd", latent, ap["kv_b_k"].astype(c.dtype), preferred_element_type=F32).astype(c.dtype)
+    v = jnp.einsum("tc,hcd->htd", latent, ap["kv_b_v"].astype(c.dtype), preferred_element_type=F32).astype(c.dtype)
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe[None], (H, T, c.qk_rope_head_dim))], axis=-1)
+    y = flash_attention_forward(q, k, v, scale=c.softmax_scale, interpret=interpret, name=FLASH_NAME)
+    return jnp.einsum("htd,hde->te", y, ap["o"].astype(c.dtype).reshape(H, c.v_head_dim, c.hidden_size),
+                      preferred_element_type=F32), rows
+
+
+def latent_attention_xla(q, pool, table, valid_len, *, layer: int, scale: float, latent: int):
+    """Decode attention of one layer in the absorbed form without the kernel:
+    gather every slot's pages, mask by length, float32 softmax.  ``q`` (S, H,
+    row) in the pool's type, ``pool`` (L, N, page, 1, row); the values are the
+    rows' first ``latent`` columns.  Returns (S, H, latent) float32."""
+    S, H, row = q.shape
+    rows = jnp.take(pool[layer], table, axis=0).reshape(S, -1, row)                      # (S, Tmax, row)
+    mask = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] < valid_len[:, None]
+    rows = jnp.where(mask[:, :, None], rows, jnp.zeros_like(rows))     # stale bytes past the length reach nothing
+    # operands widened exactly (the kernel multiplies them as they are, with float32 accumulation: the same numbers)
+    s = scale * jnp.einsum("shr,str->sht", q.astype(F32), rows.astype(F32))
+    p = jax.nn.softmax(jnp.where(mask[:, None, :], s, -1e30), axis=-1)
+    return jnp.einsum("sht,stc->shc", p.astype(rows.dtype).astype(F32), rows[..., :latent].astype(F32))
+
+
+def mla_step(c: DeepseekV2Config, ap, u, pool, *, layer: int, table, page, offset, positions, valid_len, attend):
+    """The ABSORBED form, one new position a slot: ``u`` (S, E); the
+    position's row goes to ``(page, offset)`` of the pool's ``layer`` (the
+    null page for a slot that may not write), then ``attend(q', pool, table,
+    valid_len, layer=, scale=, latent=)`` reads the slot's pages with the
+    576-wide absorbed queries.  Returns the output (S, E) and the pool."""
+    S, H = u.shape[0], c.num_attention_heads
+    q_nope, q_pe = _queries(c, ap, u, positions)
+    pool = pool.at[layer, page, offset, 0].set(_latent_rows(c, ap, u, positions).astype(pool.dtype))
+    # the heads lead both operands of the absorbed products (a batch axis elsewhere the CPU's runtime refuses in bfloat16)
+    q_abs = jnp.einsum("hsd,hdc->hsc", q_nope.transpose(1, 0, 2), ap["kv_b_k"].astype(c.dtype),
+                       preferred_element_type=F32).transpose(1, 0, 2)
+    pad = jnp.zeros((S, H, c.cache_row - c.latent_row), pool.dtype)
+    q = jnp.concatenate([q_abs.astype(pool.dtype), q_pe.astype(pool.dtype), pad], axis=-1)
+    mixed = attend(q, pool, table, valid_len, layer=layer, scale=c.softmax_scale, latent=c.kv_lora_rank)
+    y = jnp.einsum("hsc,hcd->hsd", mixed.astype(c.dtype).transpose(1, 0, 2), ap["kv_b_v"].astype(c.dtype),
+                   preferred_element_type=F32).transpose(1, 0, 2)
+    return _mm(y.reshape(S, H * c.v_head_dim), ap["o"], c.dtype), pool
+
+
+# ---------------------------------------------------------------- feed-forward
+def dense_mlp(c: DeepseekV2Config, mp, h):
+    return _swiglu(mp, h, c.dtype)
+
+
+def expert_layer(c: DeepseekV2Config, ep, h, token_mask=None):
+    """``sum over kept and held e of g_e E_e(h) + S(h)`` for tokens ``h`` (N,
+    E).  Returns the sum (N, E) float32, how many tokens each held expert got
+    (held,), and how many of the tokens' kept groups lie on this chip (a scalar)."""
+    scores = jnp.dot(h.astype(F32), ep["router"].astype(F32), precision=jax.lax.Precision.HIGHEST)
+    idx, gates, kept = route_group_limited(scores, c.num_experts_per_tok, n_group=c.n_group, topk_group=c.topk_group,
+                                           scale=c.routed_scaling_factor)
+    routed, counts = dropless_experts(h, idx, gates, ep["w_gate"], ep["w_up"], ep["w_down"],
+                                      first_held=c.first_expert_held, token_mask=token_mask, dtype=c.dtype)
+    here = kept[:, np.asarray(c.groups_held, np.int32)]
+    if token_mask is not None:
+        here = here & token_mask[:, None]
+    return routed + _swiglu(ep["shared"], h, c.dtype), counts, jnp.sum(here.astype(jnp.int32))
+
+
+# ------------------------------------------------------------ whole layers
+def _after_attention(c: DeepseekV2Config, lp, l: int, x, y, token_mask):
+    x = x + y
+    h = rmsnorm(x, lp["post_attention_layernorm"]["weight"], c.rms_norm_eps)
+    if l < c.first_k_dense_replace:
+        with jax.named_scope("vs.mlp"):
+            return x + dense_mlp(c, lp["mlp"], h), None
+    with jax.named_scope("vs.moe"):
+        y, counts, groups = expert_layer(c, lp["mlp"], h, token_mask=token_mask)
+    return x + y, (counts, groups)
+
+
+def layer_prefill(c: DeepseekV2Config, lp, l: int, x, length, *, interpret: Optional[bool] = None):
+    """Layer ``l`` over one padded sequence ``x`` (T, E) float32.  Returns the
+    residual stream and the positions' cache rows.  Pad positions route to no expert."""
+    with jax.named_scope("vs.attn"):
+        y, rows = mla_prefill(c, lp["self_attn"], rmsnorm(x, lp["input_layernorm"]["weight"], c.rms_norm_eps),
+                              interpret=interpret)
+    x, _ = _after_attention(c, lp, l, x, y, jnp.arange(x.shape[0]) < length)
+    return x, rows
+
+
+def layer_step(c: DeepseekV2Config, lp, l: int, x, active, attention_step):
+    """Layer ``l`` over one new position a slot, ``x`` (S, E) float32;
+    ``attention_step(u)`` is :func:`mla_step` over this layer of the pool.
+    Returns the residual stream, the pool, and of an expert layer ``(counts,
+    groups kept here)`` (None of a dense one)."""
+    with jax.named_scope("vs.attn"):
+        y, pool = attention_step(rmsnorm(x, lp["input_layernorm"]["weight"], c.rms_norm_eps))
+    x, routed = _after_attention(c, lp, l, x, y, active)
+    return x, pool, routed
+
+
+# ------------------------------------------- what the serve engine asks of a model
+def cache_config(config: DeepseekV2Config, *, num_slots: int, page_size: int, pages_per_slot: int,
+                 num_pages: Optional[int] = None):
+    """The cache's geometry: a latent pool of every layer's rows, no value
+    pool, no slot state."""
+    from ..serve.kv_cache import KVCacheConfig
+
+    return KVCacheConfig(layers=config.num_hidden_layers, kv_heads=1, head_dim=config.cache_row, num_slots=num_slots,
+                         page_size=page_size, pages_per_slot=pages_per_slot, num_pages=num_pages, dtype=config.dtype,
+                         latent=True)
+
+
+def prefill_chunk(config: DeepseekV2Config) -> int:
+    """The prefill ladder's first rung."""
+    return config.prefill_chunk
+
+
+def decode_kernels(config: DeepseekV2Config, cache) -> Dict[str, Any]:
+    """The decode step's kernels, latched at build: ``{"decode": the
+    ``interpret`` flag of ``paged_decode_latent``, or None for the XLA leg}``."""
+    from .. import kernels as _kernels
+    from ..kernels import paged_attention as _paged
+
+    return {"decode": _kernels.resolve(
+        "paged_decode_latent",
+        supported=lambda interp: _paged.supports_latent(cache.k.data.dtype, config.cache_row, config.kv_lora_rank,
+                                                        cache.config.page_size, interpret=interp))}
+
+
+def serve_prefill(c: DeepseekV2Config, params, arrays, tokens, length, page_row, slot, *, page: int,
+                  interpret: Optional[bool] = None):
+    """The prefill program's body: ``tokens`` (bucket,) through the stack in
+    the expanded form; every layer's rows go to the slot's pages (``page_row``:
+    what lies past its reserved pages is the null page).  Returns the last real
+    position's logits row and the cache's arrays."""
+    x = embed(c, params, tokens)
+    kept = []
+    for l in range(c.num_hidden_layers):
+        x, rows = layer_prefill(c, params[f"layers_{l}"], l, x, length, interpret=interpret)
+        kept.append(rows)
+    last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=0, keepdims=True)
+    pool = arrays["k"]
+    pages = jnp.stack(kept).reshape(len(kept), -1, page, 1, c.cache_row)
+    return head(c, params, last)[0], {"k": pool.at[:, page_row].set(pages.astype(pool.dtype))}
+
+
+def serve_decode(c: DeepseekV2Config, params, arrays, table, lengths, tokens, *, active, write_page, write_offset,
+                 kernels: Dict[str, Any]):
+    """The decode program's body, one token a slot in the absorbed form.
+    Returns the logits (S, vocab), the step's counts ``{"experts": (expert
+    layers, held) tokens an expert got, "groups": (expert layers,) kept groups
+    that lie here}`` and the cache's arrays."""
+    from ..kernels import paged_attention as _paged
+
+    kernel_interpret = kernels["decode"]
+
+    def attend(q, pool, table, valid_len, *, layer, scale, latent):
+        if kernel_interpret is not None:
+            return _paged.paged_decode_latent(q, pool, table, valid_len, layer=layer, scale=scale, latent=latent,
+                                              interpret=kernel_interpret)
+        return latent_attention_xla(q, pool, table, valid_len, layer=layer, scale=scale, latent=latent)
+
+    x = embed(c, params, tokens)                    # (S, E)
+    pool, experts, groups = arrays["k"], [], []
+    for l in range(c.num_hidden_layers):
+        lp = params[f"layers_{l}"]
+        step = lambda u, lp=lp, l=l: mla_step(c, lp["self_attn"], u, pool, layer=l, table=table, page=write_page,
+                                               offset=write_offset, positions=lengths, valid_len=lengths + 1,
+                                               attend=attend)
+        x, pool, routed = layer_step(c, lp, l, x, active, step)
+        if routed is not None:
+            experts.append(routed[0])
+            groups.append(routed[1])
+    return head(c, params, x), {"experts": jnp.stack(experts), "groups": jnp.stack(groups)}, {"k": pool}
+
+
+# counters of this model beside those every model's engine keeps (``HybridServeEngine.trace_counters``)
+STEP_COUNTERS = ("latent_bytes_read", "prefill_attn_flops", "moe_groups_kept_here")
+
+
+def step_counters(config: DeepseekV2Config, cache, lengths: np.ndarray, counts: Dict[str, np.ndarray]) -> Dict[str, int]:
+    """What one decode step adds: the latent pages its attention had to read
+    (live pages x page bytes x layers) and the kept groups that lay here."""
+    kc = cache.config
+    live = int(np.minimum(-(-(lengths + 1) // kc.page_size), kc.pages_per_slot)[lengths > 0].sum())
+    page_bytes = kc.page_size * kc.head_dim * jnp.dtype(config.dtype).itemsize
+    return {"latent_bytes_read": live * page_bytes * config.num_hidden_layers,
+            "moe_groups_kept_here": int(counts["groups"].sum())}
+
+
+def prefill_counters(config: DeepseekV2Config, bucket: int) -> Dict[str, int]:
+    """What one prefill of ``bucket`` positions adds: causal attention's
+    useful operations at the real widths (scores and values, half the square)."""
+    c = config
+    per_pair = 2 * (c.qk_head_dim + c.v_head_dim)
+    return {"prefill_attn_flops": c.num_attention_heads * per_pair * bucket * bucket // 2 * c.num_hidden_layers}

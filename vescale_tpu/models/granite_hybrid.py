@@ -52,6 +52,8 @@ __all__ = [
     "GraniteHybridConfig", "init_params", "rmsnorm", "embed", "head",
     "mamba2_prefill", "mamba2_step", "ssd_chunked", "attention_prefill", "attention_step",
     "paged_attention_xla", "ssm_advance_xla", "expert_layer", "layer_prefill", "layer_step",
+    "cache_config", "prefill_chunk", "decode_kernels", "serve_prefill", "serve_decode", "STEP_COUNTERS",
+    "step_counters", "prefill_counters",
 ]
 
 F32 = jnp.float32
@@ -433,3 +435,139 @@ def layer_step(c: GraniteHybridConfig, lp, kind: str, x, active, mixer_step):
         y, *kept = mixer_step(_mixer_input(c, lp, x))
     x, counts = _after_mixer(c, lp, x, y, active)
     return x, tuple(kept), counts
+
+
+# ------------------------------------------- what the serve engine asks of a model
+# (``serve/hybrid_engine.py``, "The seam")
+def cache_config(config: GraniteHybridConfig, *, num_slots: int, page_size: int, pages_per_slot: int,
+                 num_pages: Optional[int] = None):
+    """The cache geometry of a hybrid model: pages for its attention layers
+    only, and a recurrent state and a convolution tail a slot for each
+    state-space layer."""
+    from ..serve.kv_cache import KVCacheConfig
+
+    if not config.attention_layers or not config.mamba_layers:
+        raise ValueError("a hybrid has layers of both kinds")
+    m = len(config.mamba_layers)
+    return KVCacheConfig(
+        layers=len(config.attention_layers), kv_heads=config.num_key_value_heads, head_dim=config.head_dim,
+        num_slots=num_slots, page_size=page_size, pages_per_slot=pages_per_slot, num_pages=num_pages,
+        dtype=config.dtype,
+        slot_state=(("ssm", m, config.ssm_state_shape, config.state_dtype),
+                    ("conv", m, config.conv_tail_shape, config.dtype)))
+
+
+def prefill_chunk(config: GraniteHybridConfig) -> int:
+    """The prefill ladder's first rung: the chunked scan wants whole chunks."""
+    return config.mamba_chunk_size
+
+
+def decode_kernels(config: GraniteHybridConfig, cache) -> Dict[str, Any]:
+    """The decode step's kernels, latched at build: ``{"decode":, "ssm_step":}``
+    each kernel's ``interpret`` flag, or None for its XLA leg (the kernels on
+    TPU, the XLA legs elsewhere, as ``ServeEngine`` decides)."""
+    from .. import kernels as _kernels
+    from ..kernels import paged_attention as _paged
+    from ..kernels import ssm_step as _ssm
+
+    c = config
+    return {
+        "decode": _kernels.resolve(
+            "paged_decode",
+            supported=lambda interp: _paged.supports(cache.k.data.dtype, c.num_key_value_heads, c.head_dim,
+                                                     interpret=interp)),
+        "ssm_step": _kernels.resolve(
+            "ssm_step", supported=lambda interp: _ssm.supports(c.state_dtype, *c.ssm_state_shape, interpret=interp)),
+    }
+
+
+def _cache_rows(c: GraniteHybridConfig) -> Dict[int, int]:
+    """Where each layer's share of the cache lies: its row of the state arrays, or its layer of the pools."""
+    row = {l: i for i, l in enumerate(c.mamba_layers)}
+    row.update({l: i for i, l in enumerate(c.attention_layers)})
+    return row
+
+
+def serve_prefill(c: GraniteHybridConfig, params, arrays, tokens, length, page_row, slot, *, page: int,
+                  interpret: Optional[bool] = None):
+    """The prefill program's body: ``tokens`` (bucket,) through the stack; K
+    and V of the bucket's positions go to the slot's pages, the state and the
+    tail to the slot's rows.  Returns the last real position's logits row and
+    the cache's arrays."""
+    kd, vd, ssm, conv = arrays["k"], arrays["v"], arrays["ssm"], arrays["conv"]
+    x = embed(c, params, tokens)
+    states, tails, ks, vs = [], [], [], []
+    for l, kind in enumerate(c.layer_types):
+        x, kept = layer_prefill(c, params[f"layers_{l}"], kind, x, length, interpret=interpret)
+        if kind == "mamba":
+            states.append(kept[0])
+            tails.append(kept[1])
+        else:
+            ks.append(kept[0])
+            vs.append(kept[1])
+    last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=0, keepdims=True)
+    logits = head(c, params, last)[0]
+    pages = lambda stack: jnp.stack(stack).reshape(len(stack), -1, page, *stack[0].shape[1:])
+    kd = kd.at[:, page_row].set(pages(ks).astype(kd.dtype))
+    vd = vd.at[:, page_row].set(pages(vs).astype(vd.dtype))
+    ssm = jax.lax.dynamic_update_slice_in_dim(ssm, jnp.stack(states)[:, None].astype(ssm.dtype), slot, axis=1)
+    conv = jax.lax.dynamic_update_slice_in_dim(conv, jnp.stack(tails)[:, None].astype(conv.dtype), slot, axis=1)
+    return logits, {"k": kd, "v": vd, "ssm": ssm, "conv": conv}
+
+
+def serve_decode(c: GraniteHybridConfig, params, arrays, table, lengths, tokens, *, active, write_page, write_offset,
+                 kernels: Dict[str, Any]):
+    """The decode program's body, one token a slot: the recurrence's one step
+    over every slot's state (read and written whole: the step's largest
+    traffic beside the expert weights; on TPU the ``ssm_step`` kernel, which
+    passes over it once), paged attention over a one-layer-a-period pool (the
+    ``paged_decode`` kernel on TPU, the XLA leg elsewhere), the expert layer
+    over the active slots.  Returns the logits (S, vocab), ``{"experts":
+    (layers, held) tokens an expert got}`` and the cache's arrays."""
+    from ..kernels import paged_attention as _paged
+    from ..kernels import ssm_step as _ssm
+
+    kd, vd, ssm, conv = arrays["k"], arrays["v"], arrays["ssm"], arrays["conv"]
+    row = _cache_rows(c)
+
+    def attend(q, kd, vd, table, valid_len, *, layer, scale):
+        if kernels["decode"] is not None:
+            return _paged.paged_decode(q, kd, vd, table, valid_len, layer=layer, scale=scale,
+                                       interpret=kernels["decode"])
+        return paged_attention_xla(q, kd, vd, table, valid_len, layer=layer, scale=scale)
+
+    def advance(ssm, decay, dtx, B, C, *, layer):
+        if kernels["ssm_step"] is not None:
+            return _ssm.ssm_step(ssm, decay, dtx, B, C, layer=layer, interpret=kernels["ssm_step"])
+        return ssm_advance_xla(ssm, decay, dtx, B, C, layer=layer)
+
+    x = embed(c, params, tokens)                    # (S, E)
+    counts = []
+    for l, kind in enumerate(c.layer_types):
+        lp, i = params[f"layers_{l}"], row[l]
+        if kind == "mamba":
+            step = lambda u, lp=lp, i=i: mamba2_step(c, lp["mixer"], u, ssm, conv[i], layer=i, advance=advance)
+        else:
+            step = lambda u, lp=lp, i=i: attention_step(
+                c, lp["mixer"], u, kd, vd, layer=i, table=table, page=write_page, offset=write_offset,
+                valid_len=lengths + 1, attend=attend)
+        x, kept, n = layer_step(c, lp, kind, x, active, step)
+        if kind == "mamba":
+            ssm, conv = kept[0], conv.at[i].set(kept[1])
+        else:
+            kd, vd = kept
+        counts.append(n)
+    return head(c, params, x), {"experts": jnp.stack(counts)}, {"k": kd, "v": vd, "ssm": ssm, "conv": conv}
+
+
+# this model's own counter beside those every model's engine keeps: the slot state read and written (every
+# slot's, every step)
+STEP_COUNTERS = ("ssm_state_bytes_rw",)
+
+
+def step_counters(config: GraniteHybridConfig, cache, lengths, counts) -> Dict[str, int]:
+    return {"ssm_state_bytes_rw": 2 * cache.state_bytes_per_slot() * cache.num_slots}
+
+
+def prefill_counters(config: GraniteHybridConfig, bucket: int) -> Dict[str, int]:
+    return {}

@@ -4,4 +4,4 @@ from .experts_allocator import ExpertsAllocator, BasicExpertsAllocator
 from .token_dispatcher import TokenDispatcher
 from .moe_param_buffer import MoEParamBuffer
 from .moe_optimizer import MoEOptimizer
-from .dropless import dropless_experts, route_topk
+from .dropless import dropless_experts, route_group_limited, route_topk

@@ -38,10 +38,11 @@ from ..kernels.flash_attention import (  # noqa: F401  (re-exported for tests)
     _NEG_INF,
     _flash_bwd_pallas,
     _flash_fwd_pallas,
+    _flash_fwd_serve,
     _use_streaming,
 )
 
-__all__ = ["flash_attention", "flash_attention_sharded"]
+__all__ = ["flash_attention", "flash_attention_forward", "flash_attention_sharded"]
 
 
 # ---------------------------------------------------------------- reference
@@ -57,6 +58,16 @@ def _dense_ref(q, k, v, scale, causal):
         s = jnp.where(mask[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype), v)
+
+
+def _fit_block(block: int, T: int) -> int:
+    """Largest halving of the requested block that divides T (not under 8), so
+    e.g. T=768 stays on the flash path with 256-blocks instead of silently
+    falling back to dense O(T^2)."""
+    b = min(block, T)
+    while b > 8 and T % b:
+        b //= 2
+    return b
 
 
 # ------------------------------------------------------------- custom vjp
@@ -320,21 +331,45 @@ def flash_attention(
     if not on_tpu and not interpret:
         return _xla_fallback()
 
-    def fit(block: int) -> int:
-        # largest power-of-two block <= requested that divides T, so e.g.
-        # T=768 stays on the flash path with 256-blocks instead of silently
-        # falling back to dense O(T^2)
-        b = min(block, T)
-        while b > 8 and T % b:
-            b //= 2
-        return b
-
-    block_q, block_k = fit(block_q), fit(block_k)
+    block_q, block_k = _fit_block(block_q, T), _fit_block(block_k, T)
     if T % block_q or T % block_k:
         return _xla_fallback()
     if kmode != "off":
         _kernels.record_dispatch("flash_attention")
     return _flash(q, k, v, scale, causal, block_q, block_k, interpret, "pallas")
+
+
+def flash_attention_forward(q, k, v, *, scale: Optional[float] = None, block_q: int = 512, block_k: int = 512,
+                            interpret: Optional[bool] = None, name: Optional[str] = None):
+    """The CAUSAL forward alone, head-major, for a program that never
+    differentiates it (a serve prefill): ``q`` and ``k`` (H, T, D), ``v`` (H, T,
+    Dv) where ``Dv`` may differ from ``D`` (latent attention's expanded form
+    scores 192 wide and mixes values 128 wide), one sequence, no grouped heads.
+    Returns (H, T, Dv) in ``q``'s type.  Head-major so that a caller whose
+    projections can write that layout pays no transpose on the way in.
+
+    Dispatch as :func:`flash_attention`: on TPU, under ``interpret=True`` or
+    ``VESCALE_KERNELS=interpret``, the serve forward's kernel
+    (``kernels.flash_attention._flash_fwd_serve``: products on the operands as
+    they are, float32 softmax; ``name`` is the kernel's in the device trace);
+    elsewhere the dense product, which forms the (T, T) scores and is for the
+    CPU at small sizes.  No custom_vjp and no partition rule: one device,
+    forward only."""
+    H, T, D = q.shape
+    if k.shape != q.shape or v.shape[:2] != (H, T):
+        raise ValueError(f"flash_attention_forward: q {q.shape}, k {k.shape}, v {v.shape}")
+    scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    from .. import kernels as _kernels
+
+    if interpret is None:
+        interpret = _kernels.mode() == "interpret"
+    block_q, block_k = _fit_block(block_q, T), _fit_block(block_k, T)
+    if (not _kernels.on_tpu() and not interpret) or T % block_q or T % block_k:
+        s = jnp.einsum("hqd,hkd->hqk", q, k, preferred_element_type=jnp.float32) * scale
+        s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None], s, _NEG_INF)
+        return jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(s, axis=-1).astype(q.dtype), v,
+                          preferred_element_type=jnp.float32).astype(q.dtype)
+    return _flash_fwd_serve(q, k, v, scale, block_q, block_k, interpret, name=name)
 
 
 def flash_attention_sharded(

@@ -72,21 +72,25 @@ __all__ = ["DecodeStep", "ServeEngine", "prefill_buckets", "stack_params_check"]
 # tokens to 2048: there the step between (1536, 3072) is a rung too.
 _SMALLEST_RUNG = 128
 _HALF_STEPS_FROM = 1024
+# From here on attention is a third and more of a prefill and grows with the square of the rung: a prompt of
+# 4,600 tokens padded to 6144 costs 1.2 x the matrix products and 1.44 x the attention of one padded to 5120
+# (PERF.md §6, PR 34), so the quarter steps are rungs too (5120, 7168 under a cache of 8192 positions).
+_QUARTER_STEPS_FROM = 4096
 
 
 def prefill_buckets(chunk: int, max_seq_len: int, smallest: int = 0) -> List[int]:
     """The lengths a prefill is padded to, for both engines: multiples of
     ``chunk`` that double (from 1024 on with the half step between, so no rung
-    is over 1.5 x the one below), then ``max_seq_len``.  Rungs under
-    ``smallest`` are left out; a cache shorter than that has the one rung."""
+    is over 1.5 x the one below; from 4096 on with the quarter steps, 1.25 x),
+    then ``max_seq_len``.  Rungs under ``smallest`` are left out; a cache
+    shorter than that has the one rung."""
     if max_seq_len % chunk:
         raise ValueError(f"max_seq_len {max_seq_len} is not a whole number of scan chunks of {chunk}")
     buckets, b = [], chunk
     while b < max_seq_len:
         buckets.append(b)
-        half = b + b // 2
-        if b >= _HALF_STEPS_FROM and half % chunk == 0 and half < max_seq_len:
-            buckets.append(half)
+        steps = (b // 4, b // 2, 3 * b // 4) if b >= _QUARTER_STEPS_FROM else (b // 2,) if b >= _HALF_STEPS_FROM else ()
+        buckets.extend(b + step for step in steps if (b + step) % chunk == 0 and b + step < max_seq_len)
         b *= 2
     return [b for b in buckets if b >= smallest] + [max_seq_len]
 

@@ -37,6 +37,13 @@ alloc/commit/free, and ``fingerprint()`` carries the live reference
 total, so the PR-5/PR-10 cross-rank consistency check catches refcount
 divergence exactly like slot-assignment divergence.
 
+The latent form (``KVCacheConfig.latent``; multi-head latent attention rides
+this): a position leaves ONE row a layer, shared by every head, from which
+keys and values are both read, so the cache is the ``k`` pool alone,
+``(layers, num_pages, page_size, 1, row)``, and ``v`` is None.  Nothing of the
+host side changes: a latent page is allocated, shared (:meth:`alloc_shared`),
+rolled back and fingerprinted like any other.
+
 Slot state (a hybrid of state-space and attention layers rides this): beside
 the paged K and V, which then cover only the attention layers, the cache may
 hold per-slot arrays of CONSTANT size (``KVCacheConfig.slot_state``: a
@@ -57,7 +64,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import zlib
-from typing import Any, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -86,7 +93,11 @@ class KVCacheConfig:
     ``slot_state`` adds per-slot arrays beside the pages: entries ``(name,
     layers, shape, dtype)`` give ``PagedKVCache.state[name]`` of shape
     ``(layers, num_slots) + shape``; ``layers`` here then counts only the
-    layers that keep K and V."""
+    layers that keep K and V.
+
+    ``latent`` says the pool's rows are latents that serve as keys and values
+    both: ``kv_heads`` is 1, ``head_dim`` the row's width, and there is no
+    value pool."""
 
     layers: int
     kv_heads: int
@@ -97,8 +108,11 @@ class KVCacheConfig:
     num_pages: Optional[int] = None
     dtype: Any = None  # default jnp.float32
     slot_state: Tuple[Tuple[str, int, Tuple[int, ...], Any], ...] = ()
+    latent: bool = False
 
     def __post_init__(self):
+        if self.latent and self.kv_heads != 1:
+            raise ValueError("a latent cache keeps one row a position for all heads: kv_heads must be 1")
         if min(self.layers, self.kv_heads, self.head_dim) <= 0:
             raise ValueError("layers/kv_heads/head_dim must be positive")
         if min(self.num_slots, self.page_size, self.pages_per_slot) <= 0:
@@ -207,7 +221,7 @@ class PagedKVCache:
         )
         with _memtrack.tagged("kv_cache"):
             self.k = _memtrack.tag_array(DArray(_zeros_global(self.spec), self.spec))
-            self.v = _memtrack.tag_array(DArray(_zeros_global(self.spec), self.spec))
+            self.v = None if config.latent else _memtrack.tag_array(DArray(_zeros_global(self.spec), self.spec))
             # per-slot state beside the pages, replicated over the mesh (see the module docstring)
             self.state = {
                 name: _memtrack.tag_array(_zeros_replicated((layers, config.num_slots) + tuple(shape), dt, mesh))
@@ -464,13 +478,27 @@ class PagedKVCache:
         self._free_pages = list(range(1, self.num_pages))
 
     # ------------------------------------------------------- device plumbing
-    def update(self, k_data, v_data) -> None:
+    def update(self, k_data, v_data=None) -> None:
         """Re-wrap the engine step's donated outputs (same spec: the
-        compiled program preserves the sharding)."""
+        compiled program preserves the sharding).  A latent cache has no ``v``."""
         from ..darray import DArray
 
+        if (v_data is None) != self.config.latent:
+            raise ValueError("a latent cache takes back its one pool, any other its two")
         self.k = DArray(k_data, self.spec)
-        self.v = DArray(v_data, self.spec)
+        self.v = None if v_data is None else DArray(v_data, self.spec)
+
+    def arrays(self) -> Dict[str, Any]:
+        """Everything the cache keeps on the device, by name, as an engine's
+        programs take it (and give it back: :meth:`update_arrays`): the pools
+        ``k`` (and ``v``), then the slot state's arrays in their order."""
+        pools = {"k": self.k.data} if self.v is None else {"k": self.k.data, "v": self.v.data}
+        return {**pools, **self.state}
+
+    def update_arrays(self, arrays: Dict[str, Any]) -> None:
+        self.update(arrays["k"], arrays.get("v"))
+        if self.state:
+            self.update_state(**{name: arrays[name] for name in self.state})
 
     def update_state(self, **arrays) -> None:
         """Take back the slot state an engine step was given (donated)."""
