@@ -42,7 +42,10 @@ runs it if nobody has):
   slots compute too (static shapes) but write only the reserved null page
   and their logits are ignored.  The same program takes every slot's greedy
   token, and ``decode`` returns those ids (``DecodeStep``): the logits stay
-  on the device unless a caller reads them.
+  on the device unless a caller reads them.  ``decode`` only LAUNCHES its
+  step (``DecodeAhead``, shared with ``HybridServeEngine``): the ids are read
+  when somebody reads them, and a ``DecodeFeed`` feeds the next step from
+  them as they lie on the device, so the serve loop keeps one step in flight.
 
 Decode is a deterministic function of (params, prompt, cache geometry):
 an evicted-and-replayed request regenerates bit-identical tokens in any
@@ -62,7 +65,7 @@ from ..ndtimeline import predefined as _p
 from ..ndtimeline.api import ndtimeit, register_counter_source
 from .kv_cache import PagedKVCache
 
-__all__ = ["DecodeStep", "ServeEngine", "prefill_buckets", "stack_params_check"]
+__all__ = ["DecodeAhead", "DecodeFeed", "DecodeStep", "ServeEngine", "prefill_buckets", "stack_params_check"]
 
 # A prefill under some hundred positions streams the weights and gets little
 # faster (the DeepSeek serve cut on a v5e: 6.96 ms of the device at 128
@@ -96,23 +99,43 @@ def prefill_buckets(chunk: int, max_seq_len: int, smallest: int = 0) -> List[int
 
 
 class DecodeStep:
-    """What one ``decode`` call returns.  ``tokens`` is every slot's greedy
-    token, int32 ``(num_slots,)`` on the host: the argmax of the slot's
-    logits row, taken inside the decode program (ties break to the lowest
-    id, a NaN counts as the largest, as ``np.argmax`` has it).  The fp32
-    ``(num_slots, vocab)`` logits stay where the program wrote them and
-    cross to the host only when a caller reads them: ``step[slot]`` copies
-    one row (``step[[a, b]]`` those rows, indexed as an ndarray is),
+    """The step one ``decode`` call LAUNCHED: the call enqueues the program and
+    returns this at once, and the host waits for the device only when something
+    here is read.  ``tokens`` is every slot's greedy token, int32
+    ``(num_slots,)`` on the host: the argmax of the slot's logits row, taken
+    inside the decode program (ties break to the lowest id, a NaN counts as the
+    largest, as ``np.argmax`` has it).  The first read of them (here, or by the
+    ``decode`` call that this step feeds through a :class:`DecodeFeed`) waits
+    for the program, copies the ids under the ``vs.serve-decode.fetch`` span and
+    adds the step to its engine's counters; until then ``read`` is False and the
+    ids lie on the device, where the next step can take them as they are.  The
+    fp32 ``(num_slots, vocab)`` logits stay where the program wrote them and
+    cross to the host only when a caller reads them: ``step[slot]`` copies one
+    row (``step[[a, b]]`` those rows, indexed as an ndarray is),
     ``np.asarray(step)`` all of them, ``step.shape``, ``step.dtype`` and
     ``len(step)`` none, and each copy adds its bytes to
-    ``owner.logits_bytes_to_host``."""
+    ``owner.logits_bytes_to_host`` (and reads the ids, if nobody has).  A stub
+    engine gives the ids as an ndarray: such a step is read from the start."""
 
-    __slots__ = ("tokens", "_logits", "_owner")
+    __slots__ = ("_tokens", "_ids", "_logits", "_owner", "_launch")
 
-    def __init__(self, tokens: np.ndarray, logits, owner=None):
-        self.tokens = tokens
+    def __init__(self, tokens, logits, owner=None, launch=None):
+        host = isinstance(tokens, np.ndarray)
+        self._tokens = tokens if host else None
+        self._ids = None if host else tokens    # the device's copy: what the next step is fed from
         self._logits = logits
         self._owner = owner
+        self._launch = launch                   # the engine's own note of the launch, until the step is read
+
+    @property
+    def read(self) -> bool:
+        return self._tokens is not None
+
+    @property
+    def tokens(self) -> np.ndarray:
+        if self._tokens is None:
+            self._owner._read_step(self)
+        return self._tokens
 
     @property
     def shape(self) -> Tuple[int, ...]:
@@ -126,6 +149,7 @@ class DecodeStep:
         return self._logits.shape[0]
 
     def _to_host(self, rows) -> np.ndarray:
+        self.tokens     # a step that anybody looked at is read, and counted
         out = np.asarray(rows)
         if self._owner is not None:
             self._owner.logits_bytes_to_host += out.nbytes
@@ -140,6 +164,168 @@ class DecodeStep:
         out = self._to_host(self._logits)
         return out if dtype is None else out.astype(dtype, copy=False)
 
+
+class DecodeFeed:
+    """The argument of ``decode`` that feeds a step FROM THE DEVICE: every slot
+    takes the id that ``step`` (the step launched before, read or not) made for
+    it, as it lies on the device, but for the slots of ``fresh`` (``{slot:
+    token}``: prefilled since, so their first token is the host's), which a
+    program of a few bytes merges in.  The serve loop passes one whenever a
+    step is in flight; the call that takes it waits for ``step``'s ids after it
+    has enqueued its own program, so the device goes from one into the next."""
+
+    __slots__ = ("step", "fresh")
+
+    def __init__(self, step: DecodeStep, fresh: Optional[Dict[int, int]] = None):
+        self.step = step
+        self.fresh = fresh or {}
+
+
+class DecodeAhead:
+    """``decode`` as both engines have it (:class:`ServeEngine`,
+    ``HybridServeEngine``), one step deep: a call enqueues its program and
+    returns the :class:`DecodeStep` unread.  What the engine gives:
+    ``_run_decode(table, lengths, tokens) -> (logits, ids, counts or None)``,
+    the decode program over the cache's arrays (``tokens`` a device array of
+    ``_ids_sharding``), ``kernel_decode``, and ``_count_step`` where a read
+    step adds to more than the counters kept here."""
+
+    def _init_decode_ahead(self, ids_sharding) -> None:
+        import jax
+        import jax.numpy as jnp
+
+        # every form of ``tokens`` reaches the decode program as a device array of the sharding its own ids
+        # have (the host's through a device_put): one signature, so one executable, whatever feeds a step
+        self._ids_sharding = ids_sharding
+        self._merge_fn = jax.jit(lambda ids, tokens, fresh: jnp.where(fresh, tokens, ids), out_shardings=ids_sharding)
+        self.decode_steps = 0
+        self.decode_steps_ahead = 0
+        self.logits_bytes_to_host = 0
+        self.decode_pages_read = 0      # counted only where the paged_decode kernel was built
+        self.decode_pages_capacity = 0
+
+    def _host_tokens(self, tokens):
+        import jax
+
+        return jax.device_put(np.asarray(tokens, np.int32).reshape(self.cache.num_slots), self._ids_sharding)
+
+    def _merged_tokens(self, ids, fresh: Dict[int, int]):
+        tokens, mask = np.zeros((self.cache.num_slots,), np.int32), np.zeros((self.cache.num_slots,), bool)
+        for slot, token in fresh.items():
+            tokens[slot], mask[slot] = token, True
+        return self._merge_fn(ids, tokens, mask)
+
+    def _warm_decode(self) -> None:
+        """The decode step (no slot active) in every form the loop feeds it: the
+        host's tokens, the last step's ids as they are, and those with a fresh
+        slot's token merged in."""
+        cache = self.cache
+        table = np.zeros((cache.num_slots, cache.config.pages_per_slot), np.int32)
+        zeros = np.zeros((cache.num_slots,), np.int32)      # every slot's length, and its token
+        ids = self._run_decode(table, zeros, self._host_tokens(zeros))[1]
+        ids = self._run_decode(table, zeros, ids)[1]
+        self._run_decode(table, zeros, self._merged_tokens(ids, {0: 0}))
+
+    def decode(self, tokens) -> DecodeStep:
+        """Launch one decode step for every slot (inactive slots write only the
+        null page): each slot's token goes through the stack, what it leaves in
+        the cache lands at the slot's current length, and the
+        :class:`DecodeStep` returned, unread, is that of the NEXT position.
+        ``tokens`` is the host's ``(num_slots,)`` ids, or a :class:`DecodeFeed`
+        naming the step launched before: then the ids come from the device, and
+        once this step is enqueued the call waits for that one's ids (inside
+        this call's ``vs.serve-decode`` span, under ``.fetch``), so that on
+        return the step before is read and this one is in flight.  Callers
+        advance lengths via ``cache.advance`` for the slots whose token was
+        real, after the call: a launch takes the lengths as they stand."""
+        cache = self.cache
+        lengths = cache.lengths_array()
+        before = tokens.step if isinstance(tokens, DecodeFeed) else None
+        with ndtimeit(_p.SERVE_DECODE_CALL):
+            if before is None:
+                fed = self._host_tokens(tokens)
+            else:
+                fed = self._merged_tokens(before._ids, tokens.fresh) if tokens.fresh else before._ids
+            logits, ids, counts = self._run_decode(cache.table_array(), lengths, fed)
+            ahead = before is not None and not before.read
+            out = DecodeStep(ids, logits, self, (lengths, counts, ahead))
+            if ahead:
+                self._read_step(before)     # the device goes from that step straight into this one
+        return out
+
+    def _read_step(self, step: DecodeStep) -> None:
+        import jax
+
+        lengths, counts, ahead = step._launch
+        with ndtimeit(_p.SERVE_DECODE_FETCH):   # waits for the device, then copies the ids (and the step's counts)
+            step._tokens, counts = jax.device_get((step._ids, counts))
+        step._launch = None
+        self.decode_steps += 1
+        self.decode_steps_ahead += ahead
+        self._count_step(lengths, counts)
+
+    def _count_step(self, lengths: np.ndarray, counts) -> None:
+        if self.kernel_decode:
+            # what the kernel fetched: each slot's pages up to its new token
+            # (an inactive slot's one), of the table's S x Pmax
+            cache = self.cache
+            page, per_slot = cache.config.page_size, cache.config.pages_per_slot
+            self.decode_pages_read += int(np.minimum(-(-(lengths + 1) // page), per_slot).sum())
+            self.decode_pages_capacity += cache.num_slots * per_slot
+
+    @staticmethod
+    def greedy(logits_row: np.ndarray) -> int:
+        """Deterministic greedy sample (ties break to the lowest id)."""
+        return int(np.argmax(logits_row))
+
+    def replay_greedy(self, prompt: Sequence[int], max_new_tokens: int,
+                      *, eos_id: Optional[int] = None,
+                      canary: bool = False) -> List[int]:
+        """Standalone greedy generation through the CURRENT weights on a
+        temporarily allocated slot — the rollout canary's replay
+        primitive (and the golden-baseline recorder before a swap).  The
+        slot is freed before returning, so a drained replica's cache is
+        untouched; callers must only run this while the slot can be
+        reserved (the rollout path replays after the drain, when the
+        whole pool is free).
+
+        ``canary=True`` marks a post-swap verification replay: each
+        greedy step consults the ``canary_diverge`` faultsim hook, which
+        (when armed and due) flips the sign of the step's top logit — the
+        deterministic bad-checkpoint stand-in that proves the
+        auto-rollback path without a genuinely corrupt restore."""
+        from ..resilience import faultsim as _fs
+
+        cache = self.cache
+        slot = cache.alloc(len(prompt), max_new_tokens)
+
+        def _pick(tok: int, row) -> int:
+            # ``tok`` is the greedy token of the logits row that ``row()``
+            # copies to the host: only a firing fault reads it
+            if canary and _fs.fires("canary_diverge", ctx="replay"):
+                flipped = np.array(row(), copy=True)
+                flipped[tok] = -flipped[tok]
+                return self.greedy(flipped)
+            return tok
+
+        try:
+            first = self.prefill(list(prompt), slot)
+            cache.commit_prefill(slot, len(prompt))
+            out: List[int] = []
+            tok = _pick(self.greedy(first), lambda: first)
+            out.append(tok)
+            for _ in range(max_new_tokens - 1):
+                if eos_id is not None and tok == eos_id:
+                    break
+                toks = np.zeros((cache.num_slots,), np.int32)
+                toks[slot] = tok
+                step = self.decode(toks)
+                cache.advance(slot)
+                tok = _pick(int(step.tokens[slot]), lambda: step[slot])
+                out.append(tok)
+            return out
+        finally:
+            cache.free(slot)
 
 def _rmsnorm(x, w, eps):
     import jax
@@ -167,7 +353,7 @@ def stack_params_check(params: Dict[str, Any], num_layers: int) -> None:
             raise ValueError(f"params missing layers_{l} (num_hidden_layers={num_layers})")
 
 
-class ServeEngine:
+class ServeEngine(DecodeAhead):
     """Compiled prefill/decode over ``cache``.  ``config`` is the training
     ``LlamaConfig`` (the one the checkpoint was trained with); ``params``
     is the flax ``params`` tree (np / jax / DArray leaves — host leaves are
@@ -214,13 +400,9 @@ class ServeEngine:
         self._positions = np.arange(cache.max_seq_len, dtype=np.int32)[None, :]
         # what this engine has done, in plain integers (a trace session
         # reads them at its two ends: ``trace_counters``)
-        self.decode_steps = 0
-        self.logits_bytes_to_host = 0
         self.prefill_calls = 0
         self.prefill_tokens_real = 0
         self.prefill_tokens_padded = 0
-        self.decode_pages_read = 0      # counted only where the paged_decode kernel was built
-        self.decode_pages_capacity = 0
         register_counter_source(self)
         self._build()
 
@@ -497,6 +679,7 @@ class ServeEngine:
             )
 
         self._decode_fn = jax.jit(decode, donate_argnums=(1, 2))
+        self._init_decode_ahead(rep_sharding)
 
         # ---- multi-token step factory (speculative verify + prefix-cache
         # suffix prefill): the token width W is a COMPILE-TIME constant —
@@ -564,20 +747,18 @@ class ServeEngine:
         """Compile and run every program of the serving path: each rung of the
         prefill ladder (into the null page only: a page row of zeros, so no
         slot's pages or length are touched) and the decode step (no slot
-        active).  Twice over, as ``HybridServeEngine.warm`` does: the first
+        active, in each form of its tokens: ``_warm_decode``).  Twice over, as ``HybridServeEngine.warm`` does: the first
         call of all sees the cache's arrays as they were allocated, every later
         one as a program returned them, and a program that compiles again for
         those does it here.  The first ``prefill`` of an engine's life runs
         this if nobody has; no ``prefill`` or ``decode`` compiles after it
         (``decode_multi`` lowers a width when it first meets it)."""
-        cache = self.cache
-        S, page = cache.num_slots, cache.config.page_size
+        page = self.cache.config.page_size
         self._warmed = True
         for _ in range(2):
             for rung in self.buckets:
                 self._run_prefill(np.zeros((rung,), np.int32), 1, np.zeros((rung // page,), np.int32))
-            self._run_decode(np.zeros((S, cache.config.pages_per_slot), np.int32), np.zeros((S,), np.int32),
-                             np.zeros((S,), np.int32))
+            self._warm_decode()
         return self
 
     # ---------------------------------------------------------------- API
@@ -605,7 +786,7 @@ class ServeEngine:
         cache = self.cache
         logits, next_ids, kd, vd = self._decode_fn(self.params, cache.k.data, cache.v.data, table, lengths, tokens)
         cache.update(kd, vd)
-        return logits, next_ids
+        return logits, next_ids, None
 
     def prefill(self, prompt: Sequence[int], slot: int) -> np.ndarray:
         """Run the prompt through the stack, write its K/V into ``slot``'s
@@ -623,7 +804,7 @@ class ServeEngine:
         with ndtimeit(_p.SERVE_PREFILL_CALL):
             toks = np.zeros((rung,), np.int32)
             toks[:n] = np.asarray(prompt, np.int32)
-            page_row = np.ascontiguousarray(cache.page_table[slot, : rung // cache.config.page_size])
+            page_row = cache.page_table[slot, : rung // cache.config.page_size].copy()
             logits = self._run_prefill(toks, n, page_row)
             with ndtimeit(_p.SERVE_PREFILL_FETCH):   # waits for the device, then copies the row
                 out = np.asarray(logits)
@@ -632,36 +813,14 @@ class ServeEngine:
         self.prefill_tokens_padded += rung
         return out
 
-    def decode(self, tokens: np.ndarray) -> DecodeStep:
-        """One decode step for every slot (inactive slots write only the
-        null page): appends each token's K/V at its slot's current length
-        and returns a :class:`DecodeStep` for the NEXT position: every
-        slot's greedy token on the host, and the (num_slots, vocab) fp32
-        logits, which a caller that reads them copies from the device.
-        Callers advance lengths via ``cache.advance`` for slots whose
-        token was real."""
-        import jax
-
-        cache = self.cache
-        lengths = cache.lengths_array()
-        with ndtimeit(_p.SERVE_DECODE_CALL):
-            logits, next_ids = self._run_decode(
-                cache.table_array(), lengths, np.asarray(tokens, np.int32).reshape(cache.num_slots))
-            with ndtimeit(_p.SERVE_DECODE_FETCH):   # waits for the device and the ids
-                out = DecodeStep(jax.device_get(next_ids), logits, self)
-        self.decode_steps += 1
-        if self.kernel_decode:
-            # what the kernel fetched: each slot's pages up to its new token
-            # (an inactive slot's one), of the table's S x Pmax
-            page, per_slot = cache.config.page_size, cache.config.pages_per_slot
-            self.decode_pages_read += int(np.minimum(-(-(lengths + 1) // page), per_slot).sum())
-            self.decode_pages_capacity += cache.num_slots * per_slot
-        return out
-
     def trace_counters(self) -> Dict[str, int]:
         """The engine's own counts since it was built (a trace session
-        reports what was added while it ran).  ``decode_steps`` and
-        ``logits_bytes_to_host`` are of ``decode`` calls: the bytes of fp32
+        reports what was added while it ran).  ``decode_steps`` counts the
+        decode steps READ (a ``decode`` call launches its step; whoever reads
+        its ids first counts it), ``decode_steps_ahead`` those of them that
+        were launched while the step before was still unread (the pipeline
+        engaged; the rest started cold).  ``logits_bytes_to_host`` is of
+        ``decode`` calls: the bytes of fp32
         logits that callers copied out of their results (none for a step
         read through ``.tokens`` alone, ``vocab x 4`` a row, ``slots x
         vocab x 4`` a whole read; prefill copies one row and
@@ -672,7 +831,8 @@ class ServeEngine:
         engaged: the pages of K (and as many of V) it fetched a layer, summed
         over ``decode`` calls, against the ``slots x pages_per_slot`` the XLA
         leg gathers; both stay 0 on an engine built with the XLA leg."""
-        return {"decode_steps": self.decode_steps, "logits_bytes_to_host": self.logits_bytes_to_host,
+        return {"decode_steps": self.decode_steps, "decode_steps_ahead": self.decode_steps_ahead,
+                "logits_bytes_to_host": self.logits_bytes_to_host,
                 "prefill_calls": self.prefill_calls,
                 "prefill_tokens_real": self.prefill_tokens_real,
                 "prefill_tokens_padded": self.prefill_tokens_padded,
@@ -740,60 +900,6 @@ class ServeEngine:
             out = logits[slot, len(chunk) - 1]
             i += len(chunk)
         return np.asarray(out)
-
-    def replay_greedy(self, prompt: Sequence[int], max_new_tokens: int,
-                      *, eos_id: Optional[int] = None,
-                      canary: bool = False) -> List[int]:
-        """Standalone greedy generation through the CURRENT weights on a
-        temporarily allocated slot — the rollout canary's replay
-        primitive (and the golden-baseline recorder before a swap).  The
-        slot is freed before returning, so a drained replica's cache is
-        untouched; callers must only run this while the slot can be
-        reserved (the rollout path replays after the drain, when the
-        whole pool is free).
-
-        ``canary=True`` marks a post-swap verification replay: each
-        greedy step consults the ``canary_diverge`` faultsim hook, which
-        (when armed and due) flips the sign of the step's top logit — the
-        deterministic bad-checkpoint stand-in that proves the
-        auto-rollback path without a genuinely corrupt restore."""
-        from ..resilience import faultsim as _fs
-
-        cache = self.cache
-        slot = cache.alloc(len(prompt), max_new_tokens)
-
-        def _pick(tok: int, row) -> int:
-            # ``tok`` is the greedy token of the logits row that ``row()``
-            # copies to the host: only a firing fault reads it
-            if canary and _fs.fires("canary_diverge", ctx="replay"):
-                flipped = np.array(row(), copy=True)
-                flipped[tok] = -flipped[tok]
-                return self.greedy(flipped)
-            return tok
-
-        try:
-            first = self.prefill(list(prompt), slot)
-            cache.commit_prefill(slot, len(prompt))
-            out: List[int] = []
-            tok = _pick(self.greedy(first), lambda: first)
-            out.append(tok)
-            for _ in range(max_new_tokens - 1):
-                if eos_id is not None and tok == eos_id:
-                    break
-                toks = np.zeros((cache.num_slots,), np.int32)
-                toks[slot] = tok
-                step = self.decode(toks)
-                cache.advance(slot)
-                tok = _pick(int(step.tokens[slot]), lambda: step[slot])
-                out.append(tok)
-            return out
-        finally:
-            cache.free(slot)
-
-    @staticmethod
-    def greedy(logits_row: np.ndarray) -> int:
-        """Deterministic greedy sample (ties break to the lowest id)."""
-        return int(np.argmax(logits_row))
 
 
 def _as_tree(params: Dict[str, Any]) -> Dict[str, Any]:

@@ -1,8 +1,10 @@
 """Serve engine for decoders whose block is not the Llama block: the block's
 mathematics, its share of the cache and its counters come from the MODEL'S
 MODULE, and this class keeps what every such model needs once: the prefill
-ladder, ``warm()``, donation, the ``DecodeStep``, the ``vs.serve-*`` spans and
-``trace_counters()``.  Two models plug in today:
+ladder, ``warm()``, donation, the ``vs.serve-*`` spans and ``trace_counters()``;
+``decode`` itself, one step deep (a call launches its step and returns the
+``DecodeStep`` unread; a ``DecodeFeed`` feeds the next from the device), is
+``engine.DecodeAhead``'s, shared with ``ServeEngine``.  Two models plug in today:
 
   * ``models/granite_hybrid.py`` (the class's name is from it): state-space
     mixers with a per-slot recurrent state beside the paged K/V of their few
@@ -63,7 +65,8 @@ Two kinds of compiled program, all static-shaped and all compiled by
   every state array is wholly rewritten.
 
   **decode**, one token a slot, every slot.  The cache's arrays are donated:
-  a second copy would not fit.
+  a second copy would not fit.  The step's counts (``counts["experts"]`` and
+  the model's own) come to the host with its ids, when the step is read.
 
 What this engine refuses: ``decode_multi`` and ``prefill_suffix`` (speculation,
 prefix sharing).  Over a cache with slot state they need a state at an earlier
@@ -82,13 +85,13 @@ import numpy as np
 
 from ..ndtimeline import predefined as _p
 from ..ndtimeline.api import ndtimeit, register_counter_source
-from .engine import DecodeStep, prefill_buckets
+from .engine import DecodeAhead, prefill_buckets
 from .kv_cache import KVCacheConfig, PagedKVCache
 
 __all__ = ["HybridServeEngine", "hybrid_cache_config", "prefill_buckets"]
 
 # what the engine counts for every model (``trace_counters``); a model's own follow (``STEP_COUNTERS``)
-COUNTERS = ("decode_steps", "logits_bytes_to_host", "prefill_tokens_real", "prefill_tokens_padded",
+COUNTERS = ("decode_steps", "decode_steps_ahead", "logits_bytes_to_host", "prefill_tokens_real", "prefill_tokens_padded",
             "prefill_bucket_tokens", "decode_pages_read", "decode_pages_capacity", "moe_assignments",
             "moe_assignments_held", "moe_busiest_expert_tokens", "moe_expert_slots", "moe_layer_steps",
             "moe_experts_touched")
@@ -106,7 +109,7 @@ def hybrid_cache_config(config, *, num_slots: int, page_size: int, pages_per_slo
                                           pages_per_slot=pages_per_slot, num_pages=num_pages)
 
 
-class HybridServeEngine:
+class HybridServeEngine(DecodeAhead):
     """Compiled prefill (a program a bucket) and decode over ``cache``, a
     ``PagedKVCache`` built from :func:`hybrid_cache_config`.  ``config`` is the
     model's config object (the module that defines its class is the model's),
@@ -180,11 +183,13 @@ class HybridServeEngine:
         self._array_names = names
         self._prefill_fn = jax.jit(prefill, donate_argnums=donated)
         self._decode_fn = jax.jit(decode, donate_argnums=donated)
+        self._init_decode_ahead(jax.sharding.SingleDeviceSharding(self.mesh.jax_mesh.devices.flat[0]))
 
     def warm(self) -> "HybridServeEngine":
         """Compile and run every program: each prefill bucket (into the null
         page and slot 0's state, which its next prefill rewrites) and the
-        decode step (no slot active).  Twice over: the first call of all sees
+        decode step (no slot active, in each form of its tokens:
+        ``_warm_decode``).  Twice over: the first call of all sees
         the cache's arrays as they were allocated, every later one sees them as
         a program returned them, and a program that compiles again for those
         does it here.  Nothing compiles after this."""
@@ -193,8 +198,7 @@ class HybridServeEngine:
             for bucket in self.buckets:
                 self._run_prefill(np.zeros((bucket,), np.int32), 1,
                                   np.zeros((bucket // cache.config.page_size,), np.int32), 0)
-            self._run_decode(np.zeros((cache.num_slots, cache.config.pages_per_slot), np.int32),
-                             np.zeros((cache.num_slots,), np.int32), np.zeros((cache.num_slots,), np.int32))
+            self._warm_decode()
         return self
 
     # ---------------------------------------------------------------- API
@@ -225,7 +229,7 @@ class HybridServeEngine:
         with ndtimeit(_p.SERVE_PREFILL_CALL):
             toks = np.zeros((bucket,), np.int32)
             toks[:n] = np.asarray(prompt, np.int32)
-            page_row = np.ascontiguousarray(cache.page_table[slot, : bucket // cache.config.page_size])
+            page_row = cache.page_table[slot, : bucket // cache.config.page_size].copy()
             logits = self._run_prefill(toks, n, page_row, slot)
             with ndtimeit(_p.SERVE_PREFILL_FETCH):
                 out = np.asarray(logits)
@@ -239,42 +243,22 @@ class HybridServeEngine:
         for name, value in counts.items():
             setattr(self, name, getattr(self, name) + value)
 
-    def decode(self, tokens: np.ndarray) -> DecodeStep:
-        """One decode step for every slot: each active slot's token goes
-        through the stack, what it leaves in the cache lands at the slot's
-        current length, and the :class:`DecodeStep` is that of the NEXT
-        position (every slot's greedy token on the host; the (num_slots,
-        vocab) fp32 logits on the device until a caller reads them).  Callers
-        advance lengths via ``cache.advance``."""
-        import jax
-
-        cache, c = self.cache, self.config
-        lengths = cache.lengths_array()
-        with ndtimeit(_p.SERVE_DECODE_CALL):
-            logits, next_ids, counts = self._run_decode(cache.table_array(), lengths,
-                                                        np.asarray(tokens, np.int32).reshape(cache.num_slots))
-            with ndtimeit(_p.SERVE_DECODE_FETCH):   # waits for the device, the ids and the step's counts
-                # one get: the copies are started, then waited for
-                next_ids, counts = jax.device_get((next_ids, counts))
-            out = DecodeStep(next_ids, logits, self)
+    def _count_step(self, lengths: np.ndarray, counts) -> None:
+        c = self.config
         experts = counts["experts"]                 # (expert layers, held): tokens an expert got
-        self.decode_steps += 1
         self.moe_assignments += int((lengths > 0).sum()) * c.num_experts_per_tok * experts.shape[0]
         self.moe_assignments_held += int(experts.sum())
         self.moe_busiest_expert_tokens += int(experts.max(axis=1).sum())
         self.moe_expert_slots += int(experts.size)
         self.moe_layer_steps += int(experts.shape[0])
         self.moe_experts_touched += int((experts > 0).sum())
-        self._add(self.model.step_counters(c, cache, lengths, counts))
-        if self.kernel_decode:
-            page, per_slot = cache.config.page_size, cache.config.pages_per_slot
-            self.decode_pages_read += int(np.minimum(-(-(lengths + 1) // page), per_slot).sum())
-            self.decode_pages_capacity += cache.num_slots * per_slot
-        return out
+        self._add(self.model.step_counters(c, self.cache, lengths, counts))
+        super()._count_step(lengths, counts)
 
     def trace_counters(self) -> Dict[str, int]:
         """The engine's own counts since it was built.  Those ``ServeEngine``
-        has mean the same here (``logits_bytes_to_host`` is what callers copied
+        has mean the same here (``decode_steps`` / ``decode_steps_ahead`` are
+        of steps read; ``logits_bytes_to_host`` is what callers copied
         out of ``decode``'s results; ``prefill_tokens_padded`` is the bucket;
         ``decode_pages_*`` are a layer's, and count only with the kernel leg).
         Of ``decode`` calls alone: ``moe_assignments`` = active slots x experts
@@ -298,8 +282,3 @@ class HybridServeEngine:
 
     def prefill_suffix(self, prompt: Sequence[int], slot: int, matched: int) -> np.ndarray:
         self._refuse("prefill_suffix (a prefix-cache hit)", "prefill-from-a-boundary")
-
-    @staticmethod
-    def greedy(logits_row: np.ndarray) -> int:
-        """Deterministic greedy sample (ties break to the lowest id)."""
-        return int(np.argmax(logits_row))

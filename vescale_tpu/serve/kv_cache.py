@@ -507,10 +507,13 @@ class PagedKVCache:
         self.state = dict(arrays)
 
     def table_array(self) -> np.ndarray:
-        return np.ascontiguousarray(self.page_table)
+        """A snapshot: a decode step is launched and not waited for, and the
+        runtime may read a host argument after the call returns, while
+        ``free`` and ``advance`` write the live arrays in place."""
+        return self.page_table.copy()
 
     def lengths_array(self) -> np.ndarray:
-        return np.ascontiguousarray(self.lengths)
+        return self.lengths.copy()
 
     # ------------------------------------------------------------ agreement
     def fingerprint(self) -> Tuple[int, ...]:

@@ -10,7 +10,8 @@ step.  Failure playbook (docs/serving.md has the full matrix):
                          a hung train step — stack dump, flight record,
                          (optional) abort so the supervisor restarts and
                          queued clients retry.
-  request deadline       timeout cancellation at the step boundary: the
+  request deadline       timeout cancellation at the step boundary (the
+                         decode step in flight is read first): the
                          request is EXPLICITLY rejected (``timed_out``),
                          its slot and pages freed, the batch marches on.
   slow decode            injected via faultsim ``slow_decode``; a p99-TTFT
@@ -62,7 +63,7 @@ from ..resilience import faultsim as _fs
 from ..resilience.preempt import PreemptionHandler
 from ..resilience.watchdog import Watchdog
 from . import reqtrace
-from .engine import ServeEngine
+from .engine import DecodeFeed, DecodeStep, ServeEngine
 from .obs import ServeObservability
 from .scheduler import ContinuousBatchingScheduler, Request
 
@@ -193,6 +194,21 @@ def run_serve_resilient(
     the newest request; a drain rejects queued requests re-queueable; a
     deadline rejects explicitly.  ``ServeResult.outcomes`` is the ledger.
 
+    One decode step is kept in flight (docs/serving.md, "One decode step
+    in flight"): an iteration launches step k, fed from step k-1's ids as
+    they lie on the device (``engine.decode(DecodeFeed(...))``; the host's
+    first token for a slot prefilled since), and only then reads step
+    k-1, records its ids and keeps its books while the device works on k.
+    Lengths advance at the launch; a request that ends by its token count
+    gets no further launch; an EOS is learned one step late (the extra
+    step's id is dropped); an id is recorded only for the slot that still
+    holds the request it was launched for.  Every boundary that may
+    evict, cancel, begin a drain, run a ``/control`` job or exit reads the
+    step in flight first, so its outcome is that of a loop that read each
+    step at once, and every stream is ``engine.replay_greedy``'s.  With
+    ``speculative`` every step is read in the iteration that launched it.
+    ``on_step`` runs with that one step possibly unread.
+
     Fleet mode (serve/fleet.py): ``inbox`` (a ``RequestInbox``) feeds the
     loop NETWORK submissions — drained into ``scheduler.submit`` at every
     step boundary, with an ``VESCALE_SERVE_IDLE_S`` sleep when the
@@ -279,6 +295,9 @@ def run_serve_resilient(
     retained_params = None  # old tree parked by a committed swap (two-phase)
     result = ServeResult(status="completed")
     cache = scheduler.cache
+    # the decode step in flight: launched, its ids not yet read, with the
+    # request each stepped slot held at the launch.  At most one.
+    pending: Optional[Tuple[DecodeStep, Dict[int, Any]]] = None
 
     # ------------------------------------------- observability wiring
     # goodput/MFU accounting + the /healthz + /router providers; the ops
@@ -595,7 +614,8 @@ def run_serve_resilient(
         token_crc = zlib.crc32(int(token).to_bytes(4, "little", signed=False), token_crc)
 
     def _finish_done(step: int) -> None:
-        """Complete slots that hit EOS or their token budget."""
+        """Complete slots that hit EOS or their token budget, by the tokens
+        the host has read."""
         for slot in sorted(list(scheduler.active)):
             inf = scheduler.active[slot]
             done = len(inf.tokens) >= inf.req.max_new_tokens or (
@@ -603,8 +623,91 @@ def run_serve_resilient(
             )
             if done:
                 scheduler.complete(slot)
+                if draining:
+                    result.drained += 1
                 _event("complete", rid=inf.req.rid, slot=slot, at_step=step,
                        tokens=len(inf.tokens))
+
+    def _record(flight: Tuple[DecodeStep, Dict[int, Any]]) -> Dict[int, Tuple[Any, int]]:
+        """Read a launched step's ids (the wait for the device, unless the
+        ``decode`` call that it fed has waited already) and record each for
+        the slot that STILL holds the request it was launched for: a slot
+        cancelled, evicted or completed since, or taken by another request,
+        drops its id.  Returns ``{slot: (request, 1 token)}`` of those kept."""
+        dstep, slots = flight
+        next_ids = dstep.tokens
+        kept: Dict[int, Tuple[Any, int]] = {}
+        with _nd.ndtimeit(_SERVE_SAMPLE):
+            for slot in sorted(slots):
+                if scheduler.active.get(slot) is slots[slot]:
+                    _sample(slot, int(next_ids[slot]))
+                    kept[slot] = (slots[slot], 1)
+        return kept
+
+    def _close_step(step: int, dt: float, width: int,
+                    emitted: Dict[int, Tuple[Any, int]],
+                    predicted_s: Optional[float] = None) -> None:
+        """The host's books for one decode step that was READ, ``width`` slots
+        wide, and the tokens it gave (``emitted``: ``{slot: (request,
+        count)}``); then the requests those tokens end, and the step's line.
+        ``dt`` is the wall time the host spent on it: with a step in flight
+        the wait for it, so a step's period and each slot's inter-token
+        latency.  The device works on the next step meanwhile."""
+        if predicted_s is not None:
+            pid = _ca.record_prediction(
+                "serve_step", predicted_us=predicted_s * 1e6,
+                detail={"active": width},
+            )
+            _ca.record_measurement(pid, measured_us=dt * 1e6)
+        scheduler.observe_step_time(dt)
+        reqtrace.decode_step(step, dt, width)
+        for slot, (inf, m) in emitted.items():
+            # a speculative step amortizes the wall over every token it
+            # emitted for the slot
+            per_tok = dt / max(1, m)
+            for j in range(m):
+                scheduler.observe_itl(per_tok)
+                reqtrace.decode_token(
+                    inf.req.rid, slot, len(inf.tokens) - m + j, per_tok
+                )
+        _tel.count("serve_decode_steps_total")
+        obs.on_decode_step(step, dt, width)
+        _finish_done(step)
+        # serve's auto_inc_step: every span emitted since the last line
+        # (prefill, decode, terminals) carries the CURRENT profiler step —
+        # advance the counter and record the per-step line NOW so the
+        # steps.jsonl spans rollup attributes them to this decode step, not
+        # a stale training step
+        if _nd.is_active():
+            mgr = _nd.get_manager()
+            span_step = mgr.step
+            mgr.inc_step()
+        else:
+            span_step = step
+        _tel.record_step(
+            {
+                "step": span_step,
+                "serve_step": step,
+                "step_time_s": dt,
+                "active": width,
+                "queue_depth": len(scheduler.queue),
+            },
+            kind="serve",
+        )
+
+    def _settle(step: int) -> None:
+        """Read the step in flight, if there is one, and complete what it
+        ended: after this the scheduler holds every token the device has
+        made, as it did at every boundary before steps were launched ahead.
+        Every decision that reads a token or changes the slot set calls
+        this first."""
+        nonlocal pending
+        if pending is None:
+            return
+        flight, pending = pending, None
+        t0 = time.perf_counter()
+        kept = _record(flight)
+        _close_step(step, time.perf_counter() - t0, len(flight[1]), kept)
 
     step = 0
     try:
@@ -665,6 +768,8 @@ def run_serve_resilient(
                             _rollout_state("draining", step,
                                            inflight=len(scheduler.active))
                 if reload_job is not None:
+                    # the drain is judged by what the device has made
+                    _settle(step)
                     op = reload_job.get("op", "reload")
                     if op == "commit" or not scheduler.active:
                         if op != "commit":
@@ -688,6 +793,18 @@ def run_serve_resilient(
                 )
             else:
                 preempt_now = handler.requested()
+
+            # a boundary that may evict, cancel, begin the drain or find
+            # nothing left to step first reads the step in flight, so its
+            # verdict is the one the tokens give (flags are the agreed ones:
+            # every rank settles at the same boundaries)
+            if pending is not None and (
+                oom_fired or rt_fired or wall_mask
+                or (preempt_now and not draining)
+                or scheduler.step_deadline_due(step)
+                or not scheduler.active
+            ):
+                _settle(step)
 
             # ------------------------------------------------- faults
             if oom_fired and scheduler.active:
@@ -769,29 +886,54 @@ def run_serve_resilient(
                     scheduler.step_time_estimate() if _ca.is_active() else None
                 )
                 t0 = time.perf_counter()
-                # last sampled token of each active slot feeds this step
-                tokens = [0] * cache.num_slots
-                active_slots = []
+                # the slots this step moves, and what feeds each: the id the
+                # step in flight is making for it, as it lies on the device,
+                # or (prefilled since) the host's last token.  A request whose
+                # budget the id in flight fills ends by its count: nothing
+                # more is launched for it
+                flight = pending[1] if pending is not None else {}
+                stepped: Dict[int, Any] = {}
+                fresh: Dict[int, int] = {}
                 for slot, inf in scheduler.active.items():
-                    tokens[slot] = inf.tokens[-1]
-                    active_slots.append(slot)
-                emitted_per_slot = {slot: 1 for slot in active_slots}
+                    unread = flight.get(slot) is inf
+                    if len(inf.tokens) + unread >= inf.req.max_new_tokens:
+                        continue
+                    stepped[slot] = inf
+                    if not unread:
+                        fresh[slot] = inf.tokens[-1]
+                active_slots = sorted(stepped)
                 drafted_rows = (speculative.drafted_slots(active_slots)
                                 if speculative is not None else [])
                 if speculative is None or not drafted_rows:
-                    # plain decode — also the speculative path's fallback
-                    # when EVERY active slot degraded to undrafted (the
-                    # drafter pool couldn't mirror them): the stream is
-                    # the target's argmaxes either way, and k+1 drafter
-                    # launches plus a (k+1)-wide verify that drafts
-                    # nothing would only add cost
-                    # the step's greedy ids, taken in the decode program; its
-                    # logits stay on the device (nothing here reads them)
-                    next_ids = engine.decode(tokens).tokens
-                    with _nd.ndtimeit(_SERVE_SAMPLE):
-                        for slot in sorted(active_slots):
+                    # plain decode, one step deep — also the speculative
+                    # path's fallback when EVERY active slot degraded to
+                    # undrafted (the drafter pool couldn't mirror them): the
+                    # stream is the target's argmaxes either way, and k+1
+                    # drafter launches plus a (k+1)-wide verify that drafts
+                    # nothing would only add cost.  The step's greedy ids are
+                    # taken in the decode program and its logits stay on the
+                    # device (nothing here reads them)
+                    before, pending = pending, None
+                    if stepped:
+                        if before is None:
+                            feed = np.zeros((cache.num_slots,), np.int32)
+                            for slot, tok in fresh.items():
+                                feed[slot] = tok
+                        else:
+                            feed = DecodeFeed(before[0], fresh)
+                        # launch; with a step in flight the call then waits
+                        # for THAT step's ids, the device already in this one
+                        pending = (engine.decode(feed), stepped)
+                        for slot in active_slots:
+                            # a step appends one position to every slot it
+                            # stepped, whatever the token
                             cache.advance(slot)
-                            _sample(slot, int(next_ids[slot]))
+                    if speculative is not None:
+                        # a drafter needs every token on the host before it
+                        # drafts again: its loop reads each step at once
+                        before, pending = pending, None
+                    width = len(before[1]) if before is not None else 0
+                    emitted = _record(before) if before is not None else {}
                 else:
                     # draft-then-verify (speculative.py): the drafter
                     # proposes k tokens per mirrored slot, the target
@@ -801,6 +943,9 @@ def run_serve_resilient(
                     # stays BITWISE plain decode, only the number of
                     # target launches per token changes
                     spec = speculative
+                    tokens = [0] * cache.num_slots
+                    for slot, tok in fresh.items():
+                        tokens[slot] = tok
                     d0 = time.perf_counter()
                     drafts = spec.draft(tokens, drafted_rows)
                     reqtrace.draft(step, spec.k,
@@ -813,16 +958,17 @@ def run_serve_resilient(
                     vlogits = engine.decode_multi(toks)
                     verify_s = time.perf_counter() - v0
                     drafted_now = accepted_now = 0
-                    for slot in sorted(active_slots):
-                        inf = scheduler.active[slot]
+                    width, emitted = len(active_slots), {}
+                    for slot in active_slots:
+                        inf = stepped[slot]
                         budget = inf.req.max_new_tokens - len(inf.tokens)
-                        emitted, accepted = spec.accept(
+                        out, accepted = spec.accept(
                             drafts[slot], vlogits[slot], budget, inf.req.eos_id
                         )
-                        for tok in emitted:
+                        for tok in out:
                             cache.advance(slot)
                             _sample(slot, tok)
-                        emitted_per_slot[slot] = len(emitted)
+                        emitted[slot] = (inf, len(out))
                         if slot not in spec.undrafted:
                             drafted_now += min(spec.k, budget)
                             accepted_now += accepted
@@ -842,30 +988,6 @@ def run_serve_resilient(
                     if rate is not None:
                         _tel.set_gauge("serve_spec_accept_rate", rate)
                 dt = time.perf_counter() - t0
-                if predicted_step_s is not None:
-                    pid = _ca.record_prediction(
-                        "serve_step", predicted_us=predicted_step_s * 1e6,
-                        detail={"active": len(active_slots)},
-                    )
-                    _ca.record_measurement(pid, measured_us=dt * 1e6)
-                scheduler.observe_step_time(dt)
-                # the batched step's wall time IS each active slot's
-                # inter-token latency: one ITL observation + one
-                # decode-token span (in the slot's lane) per sampled token
-                # (a speculative step amortizes the wall over every token
-                # it emitted for the slot)
-                reqtrace.decode_step(step, dt, len(active_slots))
-                for slot in active_slots:
-                    inf = scheduler.active[slot]
-                    m = emitted_per_slot[slot]
-                    per_tok = dt / max(1, m)
-                    for j in range(m):
-                        scheduler.observe_itl(per_tok)
-                        reqtrace.decode_token(
-                            inf.req.rid, slot, len(inf.tokens) - m + j, per_tok
-                        )
-                _tel.count("serve_decode_steps_total")
-                obs.on_decode_step(step, dt, len(active_slots))
                 if _fs.fires("replica_kill", ctx=f"serve_step{step}"):
                     # an abrupt replica crash MID-LOAD (consulted only on
                     # decode steps with in-flight work, so the kill always
@@ -876,33 +998,10 @@ def run_serve_resilient(
                     _event("replica_kill", at_step=step,
                            inflight=len(scheduler.active))
                     os._exit(envreg.get_int("VESCALE_FAULTSIM_KILL_EXIT_CODE"))
-                if draining:
-                    before = scheduler.counts["completed"]
-                    _finish_done(step)
-                    result.drained += scheduler.counts["completed"] - before
-                else:
-                    _finish_done(step)
-                # serve's auto_inc_step: every span this iteration emitted
-                # (prefill, decode, terminals) carries the CURRENT profiler
-                # step — advance the counter and record the per-step line
-                # NOW so the steps.jsonl spans rollup attributes them to
-                # this decode step, not a stale training step
-                if _nd.is_active():
-                    mgr = _nd.get_manager()
-                    span_step = mgr.step
-                    mgr.inc_step()
-                else:
-                    span_step = step
-                _tel.record_step(
-                    {
-                        "step": span_step,
-                        "serve_step": step,
-                        "step_time_s": dt,
-                        "active": len(active_slots),
-                        "queue_depth": len(scheduler.queue),
-                    },
-                    kind="serve",
-                )
+                if width:
+                    # a step was read in this iteration (with one in flight,
+                    # the one launched before)
+                    _close_step(step, dt, width, emitted, predicted_step_s)
             if on_step is not None:
                 on_step(step, len(scheduler.active))
             if (

@@ -673,6 +673,17 @@ class ContinuousBatchingScheduler:
             if (now_s - self.active[slot].admit_wall) > wall_deadline_s
         ]
 
+    @staticmethod
+    def _step_over(inf: _InFlight, step: int) -> bool:
+        d = inf.req.deadline_steps
+        return d is not None and step - inf.submit_step > d
+
+    def step_deadline_due(self, step: int) -> bool:
+        """Whether :meth:`expire_active` would cancel an in-flight request at
+        ``step`` for its step deadline (computed, not applied: the serve loop
+        reads the decode step in flight before such a boundary)."""
+        return any(self._step_over(inf, step) for inf in self.active.values())
+
     def expire_active(self, step: int, force_slots: Sequence[int] = (),
                       wall_slots: Sequence[int] = ()) -> List[int]:
         """Timeout cancellation at a step boundary: step-deadline expiry,
@@ -682,9 +693,8 @@ class ContinuousBatchingScheduler:
         out: List[int] = []
         for slot in sorted(self.active):
             inf = self.active[slot]
-            d = inf.req.deadline_steps
             forced = slot in force_slots
-            step_over = d is not None and step - inf.submit_step > d
+            step_over = self._step_over(inf, step)
             if forced or step_over or slot in wall_slots:
                 reason = "injected request_timeout" if forced else (
                     "step deadline" if step_over else "wall deadline"
