@@ -94,6 +94,20 @@ def test_flash_compiles(chip, T, H, KV, D, dtype, backward):
     _compile(fwd_bwd if backward else fwd, q, kv, kv)
 
 
+# the forward under the mask that is causal over blocks of 4 (generation by diffusion over blocks: SDAR's
+# prefill, 32 query heads over 4 key heads), at the first, a middle and the last rung of its ladder
+@pytest.mark.parametrize("T", [128, 512, 2048])
+def test_block_masked_flash_forward_compiles(chip, T):
+    from vescale_tpu.ops.flash_attention import _fit_block, _flash_fwd_pallas, _to3
+
+    q = jax.ShapeDtypeStruct((1, T, 32, 128), bf16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((1, T, 4, 128), bf16, sharding=chip)
+    block = _fit_block(512, T)
+    compiled = _compile(lambda q, k, v: _flash_fwd_pallas(_to3(q), _to3(k), _to3(v), 128 ** -0.5, True, block, block, False,
+                                                          32, 4, mask_block=4)[0], q, kv, kv)
+    assert "block_flash_fwd" in compiled.as_text()
+
+
 # ----------------------------------------------------------- paged decode
 # (slots, pages_per_slot, page, H, KV, hd, dtype, layers): the two serve cells
 # of the benchmark at their depths (Mistral GQA 8 x 4, DeepSeek MHA 32 x 1),
@@ -108,6 +122,8 @@ PAGED_CASES = [
     (16, 128, 16, 16, 16, 128, bf16, 1),
     (16, 64, 16, 12, 12, 64, f32, 1),
     (8, 16, 8, 32, 32, 128, f32, 2),
+    # SDAR's pass: a block's 4 x 32 query rows a slot ride as 4 groups (key heads) of 32, 128 slots, six layers
+    (128, 128, 16, 128, 4, 128, bf16, 6),
 ]
 
 

@@ -24,6 +24,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "_NEG_INF",
+    "BLOCK_MASK_NAME",
     "_use_streaming",
     "_flash_fwd_pallas",
     "_flash_bwd_pallas",
@@ -31,10 +32,19 @@ __all__ = [
 ]
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/where VPU-safe
+BLOCK_MASK_NAME = "block_flash_fwd"     # the forward under a block mask, as the device trace names it
 
 
 # ------------------------------------------------------------------ forward
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, block_k, seq_len):
+def _visible_to(q_pos, mask_block: int):
+    """The last key position a query at ``q_pos`` sees under the causal mask:
+    itself, or with ``mask_block`` > 1 (causal over BLOCKS of that many
+    positions, full attention inside one: generation by diffusion over blocks)
+    the last position of its block."""
+    return q_pos if mask_block == 1 else (q_pos // mask_block + 1) * mask_block - 1
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, block_k, seq_len, mask_block=1):
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * scale  # (block_q, D)
     D = q.shape[-1]
@@ -49,7 +59,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, 
     m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q,), jnp.float32)
     acc0 = jnp.zeros((block_q, D), jnp.float32)
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    # the mask's blocks divide the tiles (the caller checks), so a tile's last row still bounds what its rows see
+    q_pos = _visible_to(qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0), mask_block)
 
     def body(j, carry):
         m, l, acc = carry
@@ -127,7 +138,7 @@ def _use_streaming(kernel: str, T: int, D: int, dtype, block_q: int, block_k: in
 
 
 def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                       *, scale, causal, block_q, block_k, seq_len):
+                       *, scale, causal, block_q, block_k, seq_len, mask_block=1):
     """Streaming forward: grid (BH, nq, nk) — k/v arrive one block per grid
     step; online-softmax state lives in VMEM scratch across the nk steps."""
     qi = pl.program_id(1)
@@ -146,7 +157,7 @@ def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_sc
         v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
         if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+            q_pos = _visible_to(qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0), mask_block)
             k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
         m_prev = m_scr[:, 0]
@@ -175,15 +186,25 @@ def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_sc
 
 
 def _flash_fwd_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret, H, KV,
-                      streaming=None):
+                      streaming=None, mask_block=1):
     """q3: (B*H, T, D); k3/v3: (B*KV, T, D) — GQA never materializes the
     repeated K/V heads; the BlockSpec index map routes each q head to its
-    kv group (rows are consecutive per group, llama repeat convention)."""
+    kv group (rows are consecutive per group, llama repeat convention).
+    ``mask_block`` > 1 (with ``causal``) is the mask of generation by diffusion
+    over blocks: a row sees the keys up to the end of its own block of that
+    many positions.  A static parameter: at its default the kernels are traced
+    as they were before it existed."""
     BH, T, D = q3.shape
     rep = H // KV
     if streaming is None:
         streaming = _use_streaming("fwd", T, D, k3.dtype, block_q, block_k)
     kw = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k, seq_len=T)
+    name = None
+    if mask_block != 1:
+        if not causal or block_q % mask_block or block_k % mask_block:
+            raise ValueError(f"a block mask of {mask_block} positions is causal over blocks that divide the tiles "
+                             f"({block_q} x {block_k})")
+        kw["mask_block"], name = mask_block, BLOCK_MASK_NAME
     out_shape = (
         jax.ShapeDtypeStruct(q3.shape, q3.dtype),
         jax.ShapeDtypeStruct((BH, T, 1), jnp.float32),
@@ -209,6 +230,7 @@ def _flash_fwd_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret, H,
                 pltpu.VMEM((block_q, D), jnp.float32),
             ],
             interpret=interpret,
+            name=name,
         )(q3, k3, v3)
     kv_row = lambda b, i: ((b // H) * KV + (b % H) // rep, 0, 0)
     grid = (BH, T // block_q)
@@ -226,6 +248,7 @@ def _flash_fwd_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret, H,
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ),
         interpret=interpret,
+        name=name,
     )(q3, k3, v3)
 
 

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.flash_attention import (  # noqa: F401  (re-exported for tests)
+    BLOCK_MASK_NAME,
     _NEG_INF,
     _flash_bwd_pallas,
     _flash_fwd_pallas,
@@ -81,10 +82,11 @@ def _from3(x, B, H):
     return jnp.transpose(x.reshape(B, H, T, D), (0, 2, 1, 3))
 
 
-def _xla_fwd_4d(q, k, v, scale, causal):
+def _xla_fwd_4d(q, k, v, scale, causal, mask_block=1):
     """Dense (o, lse) with the kernel's GQA layout and lse convention —
     the fallback leg of the shared partition rule (mode != off only; the
-    off-mode fallback is the bare ``_dense_ref``)."""
+    off-mode fallback is the bare ``_dense_ref``).  ``mask_block`` > 1: causal
+    over blocks of that many positions (:func:`_block_masked_forward`)."""
     B, T, H, D = q.shape
     G = k.shape[2]
     rep = H // G
@@ -92,6 +94,9 @@ def _xla_fwd_4d(q, k, v, scale, causal):
     s = scale * jnp.einsum("bqgrd,bkgd->bgrqk", qg, k.astype(jnp.float32))
     if causal:
         mask = jnp.tril(jnp.ones((T, T), bool))
+        if mask_block != 1:
+            block = jnp.arange(T, dtype=jnp.int32) // mask_block
+            mask = block[None, :] <= block[:, None]
         s = jnp.where(mask[None, None, None], s, _NEG_INF)
     m = jnp.max(s, axis=-1)
     p = jnp.exp(s - m[..., None])
@@ -285,6 +290,28 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, impl, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _block_masked_forward(q, k, v, scale, mask_block, block_q, block_k, interpret):
+    """The forward alone under the mask that is causal over BLOCKS of
+    ``mask_block`` positions (position i sees j iff ``j // mask_block <= i //
+    mask_block``): what a model that generates by diffusion over blocks
+    prefills under.  No custom_vjp and no partition rule (one device, never
+    differentiated); the GQA kernel on TPU or interpreted, the dense product
+    elsewhere."""
+    from .. import kernels as _kernels
+
+    B, T, H, D = q.shape
+    G = k.shape[2]
+    if interpret is None:
+        interpret = _kernels.mode() == "interpret"
+    block_q, block_k = _fit_block(block_q, T), _fit_block(block_k, T)
+    tiles = not (T % block_q or T % block_k or block_q % mask_block or block_k % mask_block)
+    if (_kernels.on_tpu() or interpret) and tiles:
+        o3, _lse = _flash_fwd_pallas(_to3(q), _to3(k), _to3(v), scale, True, block_q, block_k, interpret, H, G,
+                                     mask_block=mask_block)
+        return _from3(o3, B, H)
+    return _xla_fwd_4d(q, k, v, scale, True, mask_block)[0]
+
+
 def flash_attention(
     q,
     k,
@@ -294,6 +321,7 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 512,
     interpret: Optional[bool] = None,
+    mask_block: int = 1,
 ):
     """Fused attention over (B, T, H, D) q with (B, T, G, D) k/v, G | H —
     GQA/MQA run natively: the kernels route each q head to its kv group via
@@ -305,12 +333,21 @@ def flash_attention(
     under ``VESCALE_KERNELS=interpret`` (which resolves an unset
     ``interpret`` to True — CPU tier-1 then exercises the kernel path);
     anywhere else the jnp dense reference runs.  ``VESCALE_KERNELS=off``
-    reproduces the pre-kernel-layer dispatch byte-for-byte."""
+    reproduces the pre-kernel-layer dispatch byte-for-byte.
+
+    ``mask_block`` > 1 (static; ``causal`` must hold) makes the mask causal
+    over blocks of that many positions, full inside a block, FORWARD ONLY
+    (:func:`_block_masked_forward`: a serve prefill's); at its default of 1
+    nothing of the path below changes."""
     B, T, H, D = q.shape
     G = k.shape[2]
     if H % max(G, 1):
         raise ValueError(f"q heads {H} not a multiple of kv heads {G}")
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    if mask_block != 1:
+        if not causal or mask_block < 1:
+            raise ValueError(f"mask_block={mask_block} is a causal mask over blocks of 1 or more positions")
+        return _block_masked_forward(q, k, v, scale, int(mask_block), block_q, block_k, interpret)
     from .. import kernels as _kernels
 
     kmode = _kernels.mode()

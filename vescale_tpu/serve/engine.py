@@ -65,7 +65,7 @@ from ..ndtimeline import predefined as _p
 from ..ndtimeline.api import ndtimeit, register_counter_source
 from .kv_cache import PagedKVCache
 
-__all__ = ["DecodeAhead", "DecodeFeed", "DecodeStep", "ServeEngine", "prefill_buckets", "stack_params_check"]
+__all__ = ["BlockSchedule", "DecodeAhead", "DecodeFeed", "DecodeStep", "ServeEngine", "prefill_buckets", "stack_params_check"]
 
 # A prefill under some hundred positions streams the weights and gets little
 # faster (the DeepSeek serve cut on a v5e: 6.96 ms of the device at 128
@@ -115,17 +115,29 @@ class DecodeStep:
     ``np.asarray(step)`` all of them, ``step.shape``, ``step.dtype`` and
     ``len(step)`` none, and each copy adds its bytes to
     ``owner.logits_bytes_to_host`` (and reads the ids, if nobody has).  A stub
-    engine gives the ids as an ndarray: such a step is read from the start."""
+    engine gives the ids as an ndarray: such a step is read from the start.
 
-    __slots__ = ("_tokens", "_ids", "_logits", "_owner", "_launch")
+    **A step that moves a block** (an engine whose ``block`` is a
+    :class:`BlockSchedule`: generation by diffusion over blocks of ``B``
+    positions).  ``tokens`` is ``(num_slots, B)``: each slot's block as the pass
+    left it, which after a commit pass is the block's final tokens, and how
+    many of them a slot YIELDS (none, or up to ``B``) is the schedule's to say,
+    not the step's.  The logits are ``(num_slots, B, vocab)``, one row a position
+    of the block, not shifted; ``step.block(slot)`` copies a slot's ``B`` rows.
+    ``step[slot]`` stays ONE row, so that a caller of the host-token form reads
+    what it reads of any engine: the row of the position its token was revealed
+    at (``rows``, which the launch noted from the lengths)."""
 
-    def __init__(self, tokens, logits, owner=None, launch=None):
+    __slots__ = ("_tokens", "_ids", "_logits", "_owner", "_launch", "_rows")
+
+    def __init__(self, tokens, logits, owner=None, launch=None, rows=None):
         host = isinstance(tokens, np.ndarray)
         self._tokens = tokens if host else None
         self._ids = None if host else tokens    # the device's copy: what the next step is fed from
         self._logits = logits
         self._owner = owner
         self._launch = launch                   # the engine's own note of the launch, until the step is read
+        self._rows = rows                       # (num_slots,) the row of a block that ``step[slot]`` gives, or None
 
     @property
     def read(self) -> bool:
@@ -158,7 +170,15 @@ class DecodeStep:
     def __getitem__(self, index) -> np.ndarray:
         if isinstance(index, list):   # numpy takes a list of rows; a jax array refuses one
             index = np.asarray(index)
+        if self._rows is not None:
+            return self._to_host(self._logits[index, self._rows[index]])
         return self._to_host(self._logits[index])
+
+    def block(self, slot: int) -> np.ndarray:
+        """Every row of ``slot``'s block, ``(B, vocab)`` (a step that moves blocks)."""
+        if self._rows is None:
+            raise TypeError("this step moved one position a slot: step[slot] is its row")
+        return self._to_host(self._logits[slot])
 
     def __array__(self, dtype=None, copy=None) -> np.ndarray:
         out = self._to_host(self._logits)
@@ -172,13 +192,68 @@ class DecodeFeed:
     token}``: prefilled since, so their first token is the host's), which a
     program of a few bytes merges in.  The serve loop passes one whenever a
     step is in flight; the call that takes it waits for ``step``'s ids after it
-    has enqueued its own program, so the device goes from one into the next."""
+    has enqueued its own program, so the device goes from one into the next.
 
-    __slots__ = ("step", "fresh")
+    For an engine whose steps move blocks nothing is fed at all: a slot's open
+    block lies in the cache's slot state, where the pass before left it.
+    ``slots`` (``{slot: tokens the host will take from this pass}``) then names
+    the slots this pass MOVES (every other slot's block is held as it is), and
+    ``step`` may be None (no step in flight): it only says what to wait for."""
 
-    def __init__(self, step: DecodeStep, fresh: Optional[Dict[int, int]] = None):
+    __slots__ = ("step", "fresh", "slots")
+
+    def __init__(self, step: Optional[DecodeStep], fresh: Optional[Dict[int, int]] = None,
+                 slots: Optional[Dict[int, int]] = None):
         self.step = step
         self.fresh = fresh or {}
+        self.slots = slots
+
+
+class BlockSchedule:
+    """What a pass does to a slot's open block under the STATIC schedule of
+    generation by diffusion over blocks (``low_confidence_static``): the
+    host's mirror of the state an engine's decode program keeps on the device,
+    which is what lets the serve loop launch a pass before it has read the last
+    one: it knows what each will yield without looking.
+
+    A block of ``B`` positions starts masked but for the ``n mod B`` last tokens
+    of a prompt of ``n`` (the first block alone); a denoising pass reveals
+    ``transfers(k)`` of the masked positions at its ``k``-th pass (``B / T``,
+    the first ``B mod T`` passes one more; never more than are masked); when none
+    is masked the next pass is the COMMIT pass, which leaves the block's K and V
+    and yields its tokens: ``B`` less the prompt's, and no more than the request
+    is still owed.  So a whole block is ``T + 1`` passes for ``B`` tokens.
+
+    ``OWN_PASS`` and ``HOLD`` are what the decode program of such an engine
+    reads in a slot's place in ``tokens`` beside a token id (0 or more: the
+    host-token form, the token REVEALED at the slot's length, teacher-forced):
+    run the pass the block's state asks for, or leave the slot's block as it is."""
+
+    OWN_PASS, HOLD = -1, -2
+
+    def __init__(self, block_length: int, denoising_steps: int):
+        if not 0 < denoising_steps <= block_length:
+            raise ValueError(f"{denoising_steps} denoising steps for a block of {block_length}")
+        self.B, self.T = int(block_length), int(denoising_steps)
+
+    def transfers(self, k: int) -> int:
+        return self.B // self.T + (k < self.B % self.T)
+
+    def open(self, prompt_len: int) -> List[int]:
+        """The open block of a slot just prefilled: ``[masked, passes done, revealed by the prompt]``."""
+        r = prompt_len % self.B
+        return [self.B - r, 0, r]
+
+    def plan(self, state: List[int], owed: int) -> Tuple[int, int, int]:
+        """Advance ``state`` by one pass; ``(skip, count, positions)``: the pass
+        yields ``tokens[skip: skip + count]`` of the block as it leaves it and
+        settles ``positions`` more positions of the cache."""
+        masked, k, revealed = state
+        if masked:
+            state[0], state[1] = masked - min(self.transfers(k), masked), k + 1
+            return 0, 0, 0
+        state[:] = [self.B, 0, 0]
+        return revealed, min(self.B - revealed, owed), self.B - revealed
 
 
 class DecodeAhead:
@@ -188,7 +263,12 @@ class DecodeAhead:
     ``_run_decode(table, lengths, tokens) -> (logits, ids, counts or None)``,
     the decode program over the cache's arrays (``tokens`` a device array of
     ``_ids_sharding``), ``kernel_decode``, and ``_count_step`` where a read
-    step adds to more than the counters kept here."""
+    step adds to more than the counters kept here.  ``block`` is None where a
+    step moves one position a slot and yields its one token, and the engine's
+    :class:`BlockSchedule` where it moves a block (the serve loop asks it what
+    a pass yields; ``_fed`` and ``_note`` are then the engine's own)."""
+
+    block: Optional[BlockSchedule] = None
 
     def _init_decode_ahead(self, ids_sharding) -> None:
         import jax
@@ -215,6 +295,19 @@ class DecodeAhead:
             tokens[slot], mask[slot] = token, True
         return self._merge_fn(ids, tokens, mask)
 
+    def _fed(self, tokens):
+        """What the decode program takes for ``tokens``: the host's ids, or a
+        :class:`DecodeFeed`'s (the step before's, with the fresh slots' merged in)."""
+        if not isinstance(tokens, DecodeFeed):
+            return self._host_tokens(tokens)
+        return self._merged_tokens(tokens.step._ids, tokens.fresh) if tokens.fresh else tokens.step._ids
+
+    def _note(self, tokens, lengths: np.ndarray) -> Tuple[Optional[np.ndarray], int]:
+        """Of a launch: ``(rows, yields)`` of its :class:`DecodeStep`: which row of a
+        block ``step[slot]`` gives (None: the slot's one row), and how many tokens
+        the host will take from the step beyond one a slot (a block engine's count)."""
+        return None, 0
+
     def _warm_decode(self) -> None:
         """The decode step (no slot active) in every form the loop feeds it: the
         host's tokens, the last step's ids as they are, and those with a fresh
@@ -237,18 +330,17 @@ class DecodeAhead:
         this call's ``vs.serve-decode`` span, under ``.fetch``), so that on
         return the step before is read and this one is in flight.  Callers
         advance lengths via ``cache.advance`` for the slots whose token was
-        real, after the call: a launch takes the lengths as they stand."""
+        real, after the call: a launch takes the lengths as they stand.  (An
+        engine whose steps move blocks says in its own docstring what a pass
+        does with ``tokens``, and what the step returned is of.)"""
         cache = self.cache
         lengths = cache.lengths_array()
         before = tokens.step if isinstance(tokens, DecodeFeed) else None
         with ndtimeit(_p.SERVE_DECODE_CALL):
-            if before is None:
-                fed = self._host_tokens(tokens)
-            else:
-                fed = self._merged_tokens(before._ids, tokens.fresh) if tokens.fresh else before._ids
-            logits, ids, counts = self._run_decode(cache.table_array(), lengths, fed)
+            logits, ids, counts = self._run_decode(cache.table_array(), lengths, self._fed(tokens))
             ahead = before is not None and not before.read
-            out = DecodeStep(ids, logits, self, (lengths, counts, ahead))
+            rows, yields = self._note(tokens, lengths)
+            out = DecodeStep(ids, logits, self, (lengths, counts, ahead, yields), rows)
             if ahead:
                 self._read_step(before)     # the device goes from that step straight into this one
         return out
@@ -256,21 +348,23 @@ class DecodeAhead:
     def _read_step(self, step: DecodeStep) -> None:
         import jax
 
-        lengths, counts, ahead = step._launch
+        lengths, counts, ahead, yields = step._launch
         with ndtimeit(_p.SERVE_DECODE_FETCH):   # waits for the device, then copies the ids (and the step's counts)
             step._tokens, counts = jax.device_get((step._ids, counts))
         step._launch = None
         self.decode_steps += 1
         self.decode_steps_ahead += ahead
-        self._count_step(lengths, counts)
+        self._count_step(lengths, counts, yields)
 
-    def _count_step(self, lengths: np.ndarray, counts) -> None:
+    def _count_step(self, lengths: np.ndarray, counts, yields: int = 0) -> None:
         if self.kernel_decode:
-            # what the kernel fetched: each slot's pages up to its new token
-            # (an inactive slot's one), of the table's S x Pmax
+            # what the kernel fetched: each slot's pages up to its new token (up to the end of its open block,
+            # where a step moves a block; an inactive slot's one), of the table's S x Pmax
             cache = self.cache
             page, per_slot = cache.config.page_size, cache.config.pages_per_slot
-            self.decode_pages_read += int(np.minimum(-(-(lengths + 1) // page), per_slot).sum())
+            width = 1 if self.block is None else self.block.B
+            reach = lengths // width * width + width
+            self.decode_pages_read += int(np.minimum(-(-reach // page), per_slot).sum())
             self.decode_pages_capacity += cache.num_slots * per_slot
 
     @staticmethod
@@ -312,6 +406,18 @@ class DecodeAhead:
             first = self.prefill(list(prompt), slot)
             cache.commit_prefill(slot, len(prompt))
             out: List[int] = []
+            if self.block is not None:
+                # by blocks, as the serve loop generates: passes until the budget is filled (or an EOS is out)
+                state = self.block.open(len(prompt))
+                while len(out) < max_new_tokens and not (eos_id is not None and out and out[-1] == eos_id):
+                    skip, count, positions = self.block.plan(state, max_new_tokens - len(out))
+                    step = self.decode(DecodeFeed(None, slots={slot: count}))
+                    cache.advance(slot, positions)
+                    for j in range(skip, skip + count):
+                        out.append(_pick(int(step.tokens[slot, j]), lambda: step.block(slot)[j]))
+                        if eos_id is not None and out[-1] == eos_id:
+                            break
+                return out
             tok = _pick(self.greedy(first), lambda: first)
             out.append(tok)
             for _ in range(max_new_tokens - 1):

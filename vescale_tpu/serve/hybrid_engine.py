@@ -4,7 +4,7 @@ MODULE, and this class keeps what every such model needs once: the prefill
 ladder, ``warm()``, donation, the ``vs.serve-*`` spans and ``trace_counters()``;
 ``decode`` itself, one step deep (a call launches its step and returns the
 ``DecodeStep`` unread; a ``DecodeFeed`` feeds the next from the device), is
-``engine.DecodeAhead``'s, shared with ``ServeEngine``.  Two models plug in today:
+``engine.DecodeAhead``'s, shared with ``ServeEngine``.  Three models plug in today:
 
   * ``models/granite_hybrid.py`` (the class's name is from it): state-space
     mixers with a per-slot recurrent state beside the paged K/V of their few
@@ -12,7 +12,11 @@ ladder, ``warm()``, donation, the ``vs.serve-*`` spans and ``trace_counters()``;
   * ``models/deepseek_v2.py``: latent attention over a LATENT paged cache
     (one pool, no values, no slot state), prefill in the expanded form and
     decode in the absorbed one, group-limited routed experts after a dense
-    first layer.
+    first layer;
+  * ``models/sdar_moe.py``: generation by DIFFUSION OVER BLOCKS.  A decode
+    step is a pass over every slot's open block of ``B`` positions and yields
+    none or up to ``B`` tokens a slot ("A block engine", below); grouped-query
+    attention with a per-head norm over paged K/V, 128 routed experts a layer.
 
 A second engine class beside :class:`ServeEngine`, behind the same surface
 (``prefill(prompt, slot)``, ``decode(tokens)``, ``params``,
@@ -48,7 +52,12 @@ gives, as plain functions of the config:
   ``STEP_COUNTERS``, ``step_counters(config, cache, lengths, counts)``,
   ``prefill_counters(config, bucket)``
       the names of the model's own counters and what one decode step, and one
-      prefill, adds to them.
+      prefill, adds to them;
+  ``block_schedule(config)`` (only a model that generates by blocks)
+      its ``engine.BlockSchedule``; ``serve_decode`` then returns ``(logits (S,
+      B, vocab), ids (S, B), counts, arrays)`` and takes ``write_page`` /
+      ``write_offset`` of each slot's open BLOCK, whose first position is
+      ``lengths // B * B``.
 
 Two kinds of compiled program, all static-shaped and all compiled by
 ``warm()`` before the engine is handed over:
@@ -64,15 +73,47 @@ Two kinds of compiled program, all static-shaped and all compiled by
   (what lies past its reserved pages to the null page), and a slot's row of
   every state array is wholly rewritten.
 
-  **decode**, one token a slot, every slot.  The cache's arrays are donated:
-  a second copy would not fit.  The step's counts (``counts["experts"]`` and
-  the model's own) come to the host with its ids, when the step is read.
+  **decode**, every slot: one token a slot, or one pass over a slot's open
+  block.  The cache's arrays are donated: a second copy would not fit.  The
+  step's counts (``counts["experts"]`` and the model's own) come to the host
+  with its ids, when the step is read.
+
+**A block engine** (``engine.block`` is the model's ``BlockSchedule``; the
+serve loop learns from it that a step yields a COUNT a slot, no flag is
+passed).  A slot's open block (its ``B`` ids, which of them are masked, the pass
+it is at) is slot state of the cache: the prefill of a prompt of ``n`` tokens
+writes K/V of its whole blocks, opens the block after them with the prompt's
+last ``n mod B`` tokens revealed, and returns the logits row of position ``n -
+1`` (NOT shifted: a row predicts its own position); every pass then writes the
+block's K/V at the block's own positions, attends ``block start + B`` positions
+(all that came before, and the block itself in full), and takes confidence,
+selection and the new ids in the program; the pass that finds nothing masked is
+the commit pass: its K/V are of the final tokens, its ids are the block's, and
+it leaves the state a fresh block.  ``decode(tokens)`` is ONE program in two
+uses:
+
+  * ``decode(DecodeFeed(step before or None, slots={slot: tokens to take}))``,
+    the serve loop's: the named slots run the pass their state asks for, every
+    other slot's block is held; nothing is fed, because the state is on the
+    device; ``step.tokens`` is ``(S, B)``, and the host, which mirrors the
+    static schedule (``BlockSchedule.plan``), takes ``tokens[slot, skip: skip +
+    count]`` of a commit pass and moves the length by the block;
+  * ``decode(host tokens (S,))``, the host-token form (what a reference check
+    and a replay call: one token a slot, then ``cache.advance(slot)``): THE
+    SAME PROGRAM teacher-forced.  The fed token is revealed at the slot's
+    length ``L``, i.e. at position ``L mod B`` of its open block, the positions
+    after it stay masked, nothing else is revealed, and ``step[slot]`` is the
+    row of position ``L``: the model's logits there with positions ``<= L``
+    holding their tokens and the rest of ``L``'s block masked, under the block
+    mask.  At the block's last position that pass is the commit pass.
 
 What this engine refuses: ``decode_multi`` and ``prefill_suffix`` (speculation,
-prefix sharing).  Over a cache with slot state they need a state at an earlier
-position, which ``PagedKVCache`` does not keep (:class:`SlotStateUnsupported`);
-over a cache of pages alone (the latent form) nothing stands in their way but
-the programs, which are not written (``NotImplementedError`` names them).
+prefix sharing).  Over a cache with slot state (a recurrence's, an open
+block's) they need a state at an earlier position, which ``PagedKVCache`` does
+not keep (:class:`SlotStateUnsupported`); over a cache of pages alone (the
+latent form) nothing stands in their way but the programs, which are not
+written (``NotImplementedError`` names them); a block engine has neither
+program, and a verify step of one token a position is not what its passes are.
 ``num_stages`` > 1 and a mesh of more than one device have no program here yet.
 """
 
@@ -85,7 +126,7 @@ import numpy as np
 
 from ..ndtimeline import predefined as _p
 from ..ndtimeline.api import ndtimeit, register_counter_source
-from .engine import DecodeAhead, prefill_buckets
+from .engine import BlockSchedule, DecodeAhead, DecodeFeed, prefill_buckets
 from .kv_cache import KVCacheConfig, PagedKVCache
 
 __all__ = ["HybridServeEngine", "hybrid_cache_config", "prefill_buckets"]
@@ -95,6 +136,10 @@ COUNTERS = ("decode_steps", "decode_steps_ahead", "logits_bytes_to_host", "prefi
             "prefill_bucket_tokens", "decode_pages_read", "decode_pages_capacity", "moe_assignments",
             "moe_assignments_held", "moe_busiest_expert_tokens", "moe_expert_slots", "moe_layer_steps",
             "moe_experts_touched")
+# ... and for a model that generates by blocks: slot-passes of the slots a pass moved, those of them that were
+# commit passes, the tokens the host took, and the query rows that still had something to decide (masked
+# positions at the start of their pass), each summed over the passes read
+BLOCK_COUNTERS = ("block_passes", "block_commit_passes", "block_tokens_emitted", "block_positions_masked")
 
 
 def _model_of(config):
@@ -137,8 +182,13 @@ class HybridServeEngine(DecodeAhead):
         self.buckets = prefill_buckets(self.model.prefill_chunk(c), cache.max_seq_len)
         if any(b % cache.config.page_size for b in self.buckets):
             raise ValueError(f"page_size {cache.config.page_size} does not divide the prefill buckets {self.buckets}")
+        # a model that generates by blocks brings its schedule; a step of any other moves one position a slot
+        self.block: Optional[BlockSchedule] = self.model.block_schedule(c) if hasattr(self.model, "block_schedule") else None
+        if self.block is not None and cache.config.page_size % self.block.B:
+            raise ValueError(f"a block of {self.block.B} positions must divide the page of {cache.config.page_size}: "
+                             "a block that straddled a page would be written to two")
         # what this engine has done, in plain integers (``trace_counters``)
-        self.counter_names = COUNTERS + tuple(self.model.STEP_COUNTERS)
+        self.counter_names = COUNTERS + (BLOCK_COUNTERS if self.block is not None else ()) + tuple(self.model.STEP_COUNTERS)
         for name in self.counter_names:
             setattr(self, name, 0)
         register_counter_source(self)
@@ -164,19 +214,27 @@ class HybridServeEngine(DecodeAhead):
                                                  page=page, interpret=self.interpret)
             return (logits,) + tuple(arrays[name] for name in names)
 
+        block = self.block
+
         def decode(params, *rest):
             table, lengths, tokens = rest[n:]
-            # where each slot's new position lands, as in ServeEngine.decode: a position past the reserved
-            # pages, or a slot that holds nothing yet (before its prefill, or free), writes the null page
+            # where each slot's new position lands (the first of its open block, where a step moves a block),
+            # as in ServeEngine.decode: a position past the reserved pages, or a slot that holds nothing yet
+            # (before its prefill, or free), writes the null page
             active = lengths > 0
-            valid = (lengths < Pmax * page) & active
-            safe = jnp.where(valid, lengths, 0)
+            first = lengths if block is None else lengths // block.B * block.B
+            valid = (first < Pmax * page) & active
+            safe = jnp.where(valid, first, 0)
             write_page = jnp.where(valid, jnp.take_along_axis(table, (safe // page)[:, None], axis=1)[:, 0], 0)
-            logits, counts, arrays = model.serve_decode(
+            out = model.serve_decode(
                 c, params, dict(zip(names, rest[:n])), table, lengths, tokens, active=active, write_page=write_page,
                 write_offset=safe % page, kernels=kernels)
-            # every slot's greedy token, in this program (``DecodeStep.tokens``)
-            next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            if block is None:
+                logits, counts, arrays = out
+                # every slot's greedy token, in this program (``DecodeStep.tokens``)
+                next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                logits, next_ids, counts, arrays = out       # the block as the pass leaves it: the model's own selection
             return (logits, next_ids, counts) + tuple(arrays[name] for name in names)
 
         donated = tuple(range(1, 1 + n))
@@ -216,11 +274,33 @@ class HybridServeEngine(DecodeAhead):
         self.cache.update_arrays(dict(zip(self._array_names, arrays)))
         return logits, next_ids, counts
 
+    # ------------------------------------------------- a block engine's own
+    def _fed(self, tokens):
+        if self.block is None or not isinstance(tokens, DecodeFeed):
+            return super()._fed(tokens)     # (a block engine's host tokens: each revealed at its slot's length)
+        fed = np.full((self.cache.num_slots,), BlockSchedule.HOLD, np.int32)
+        fed[list(tokens.slots)] = BlockSchedule.OWN_PASS
+        return self._host_tokens(fed)
+
+    def _note(self, tokens, lengths: np.ndarray):
+        if self.block is None:
+            return super()._note(tokens, lengths)
+        return lengths % self.block.B, sum(tokens.slots.values()) if isinstance(tokens, DecodeFeed) else 0
+
+    def _warm_decode(self) -> None:
+        if self.block is None:
+            return super()._warm_decode()
+        cache = self.cache
+        table = np.zeros((cache.num_slots, cache.config.pages_per_slot), np.int32)
+        zeros = np.zeros((cache.num_slots,), np.int32)
+        for tokens in (zeros, DecodeFeed(None, slots={})):      # both uses are one executable: warmed twice over
+            self._run_decode(table, zeros, self._fed(tokens))
+
     def prefill(self, prompt: Sequence[int], slot: int) -> np.ndarray:
         """Run the prompt through the stack in its bucket, write what its
         positions leave in the cache into ``slot``'s reserved pages (and its
         state into ``slot``'s rows), and return the next-token logits (fp32,
-        host)."""
+        host); of a block engine the last prompt position's own row."""
         cache = self.cache
         n = len(prompt)
         if not (0 < n <= cache.max_seq_len):
@@ -243,17 +323,23 @@ class HybridServeEngine(DecodeAhead):
         for name, value in counts.items():
             setattr(self, name, getattr(self, name) + value)
 
-    def _count_step(self, lengths: np.ndarray, counts) -> None:
+    def _count_step(self, lengths: np.ndarray, counts, yields: int = 0) -> None:
         c = self.config
         experts = counts["experts"]                 # (expert layers, held): tokens an expert got
-        self.moe_assignments += int((lengths > 0).sum()) * c.num_experts_per_tok * experts.shape[0]
+        positions = int((lengths > 0).sum())        # that went through the stack for a request: one an active slot
+        if self.block is not None:
+            passes, commits, masked = (int(x) for x in counts["block"])
+            positions = passes * self.block.B       # ... or a block a slot that the pass moved
+            self._add({"block_passes": passes, "block_commit_passes": commits, "block_tokens_emitted": yields,
+                       "block_positions_masked": masked})
+        self.moe_assignments += positions * c.num_experts_per_tok * experts.shape[0]
         self.moe_assignments_held += int(experts.sum())
         self.moe_busiest_expert_tokens += int(experts.max(axis=1).sum())
         self.moe_expert_slots += int(experts.size)
         self.moe_layer_steps += int(experts.shape[0])
         self.moe_experts_touched += int((experts > 0).sum())
         self._add(self.model.step_counters(c, self.cache, lengths, counts))
-        super()._count_step(lengths, counts)
+        super()._count_step(lengths, counts, yields)
 
     def trace_counters(self) -> Dict[str, int]:
         """The engine's own counts since it was built.  Those ``ServeEngine``
@@ -261,18 +347,25 @@ class HybridServeEngine(DecodeAhead):
         of steps read; ``logits_bytes_to_host`` is what callers copied
         out of ``decode``'s results; ``prefill_tokens_padded`` is the bucket;
         ``decode_pages_*`` are a layer's, and count only with the kernel leg).
-        Of ``decode`` calls alone: ``moe_assignments`` = active slots x experts
-        per token x expert layers, ``moe_assignments_held`` those that fell on
+        Of ``decode`` calls alone: ``moe_assignments`` = positions that went
+        through the stack for a request (one an active slot; ``B`` a slot that a
+        block engine's pass moved) x experts per token x expert layers, ``moe_assignments_held`` those that fell on
         an expert held here; ``moe_busiest_expert_tokens`` the largest count of
         one expert, summed over layers and steps (``moe_layer_steps`` of them),
         and ``moe_expert_slots`` = held experts x layers x steps (busiest /
         layer steps over held / slots = max over mean), ``moe_experts_touched``
         those of them that got a token (their weights are read).
-        ``prefill_bucket_tokens`` the bucket lengths.  The model's own follow
-        (its module's ``STEP_COUNTERS`` says what each counts)."""
+        ``prefill_bucket_tokens`` the bucket lengths.  A block engine's four
+        (``BLOCK_COUNTERS``, above), then the model's own (its module's
+        ``STEP_COUNTERS`` says what each counts)."""
         return {k: getattr(self, k) for k in self.counter_names}
 
     def _refuse(self, what: str, program: str):
+        if self.block is not None:
+            raise NotImplementedError(
+                f"{what}: an engine that generates by blocks (blocks of {self.block.B}, {type(self.config).__name__}) "
+                f"has no {program} program, and its open block is slot state that a rewind or a shared prefix "
+                "would need at an earlier position")
         self.cache.refuse_slot_state(what)
         raise NotImplementedError(f"{what}: this engine has no {program} program yet (the cache, pages alone, would "
                                   "allow it)")

@@ -57,6 +57,18 @@ holds the whole prefix folded together, so sharing a prefix
 SNAPSHOT of the state at that position, which nothing here takes.  Both raise
 :class:`SlotStateUnsupported` on such a cache, and so do ``PrefixCache`` and
 ``SpeculativeDecoder`` when they are built over one.
+
+An open block (a model that generates by diffusion over blocks rides the slot
+state too: ``models/sdar_moe.py``): while a slot's block of ``B`` positions is
+being denoised, its ids, which of them are still masked and the pass it is at
+are slot state, and every pass writes the block's K and V at the block's own
+positions, PAST ``lengths``: provisional bytes, which no other slot can read
+and which the next pass overwrites.  ``lengths`` counts the settled positions
+only; the pass that runs the block's final tokens (the commit pass) leaves
+their K and V, and the host then moves the length by the block
+(``advance(slot, positions)``).  Pages are reserved for prompt + budget, and a
+page holds whole blocks (the engine checks that ``B`` divides the page), so the
+last block, which may run past the budget, never leaves the reserved pages.
 """
 
 from __future__ import annotations
@@ -269,7 +281,7 @@ class PagedKVCache:
         """Raise where ``what`` needs a slot's state as it was at an earlier position."""
         if self.state:
             raise SlotStateUnsupported(
-                f"{what} needs a slot's recurrent state as it was at an earlier position, and this cache "
+                f"{what} needs a slot's state (a recurrence's, an open block's) as it was at an earlier position, and this cache "
                 f"({', '.join(sorted(self.state))} beside the pages) keeps only the newest: the missing "
                 "mechanism is a snapshot of the state at page boundaries")
 
@@ -389,12 +401,14 @@ class PagedKVCache:
         self._tokens_held += prompt_tokens
         self._fold(2, slot, prompt_tokens)
 
-    def advance(self, slot: int) -> None:
-        """One decoded token landed in the cache (position ``lengths``)."""
-        if self.lengths[slot] >= int(self._pages_held[slot]) * self.config.page_size:
+    def advance(self, slot: int, positions: int = 1) -> None:
+        """``positions`` more positions of the slot are settled in the cache
+        (from ``lengths`` on): the one of a decoded token, or the block an
+        engine that generates by blocks has just committed."""
+        if self.lengths[slot] + positions > int(self._pages_held[slot]) * self.config.page_size:
             raise KVCacheOutOfPages(f"slot {slot} is full ({int(self.lengths[slot])} tokens)")
-        self.lengths[slot] += 1
-        self._tokens_held += 1
+        self.lengths[slot] += positions
+        self._tokens_held += positions
 
     def can_advance(self, slot: int) -> bool:
         return self.lengths[slot] < int(self._pages_held[slot]) * self.config.page_size

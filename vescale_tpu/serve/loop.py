@@ -199,15 +199,30 @@ def run_serve_resilient(
     they lie on the device (``engine.decode(DecodeFeed(...))``; the host's
     first token for a slot prefilled since), and only then reads step
     k-1, records its ids and keeps its books while the device works on k.
-    Lengths advance at the launch; a request that ends by its token count
-    gets no further launch; an EOS is learned one step late (the extra
-    step's id is dropped); an id is recorded only for the slot that still
-    holds the request it was launched for.  Every boundary that may
+    A slot's length advances at the launch by the positions the step
+    settles in the cache: one, whatever the token; or, where a step moves a
+    block (below), none for a denoising pass and the block for its commit
+    pass.  A request that ends by its token count gets no further launch;
+    an EOS is learned one step late (the extra step's ids are dropped); ids
+    are recorded only for the slot that still holds the request they were
+    launched for.  Every boundary that may
     evict, cancel, begin a drain, run a ``/control`` job or exit reads the
     step in flight first, so its outcome is that of a loop that read each
     step at once, and every stream is ``engine.replay_greedy``'s.  With
     ``speculative`` every step is read in the iteration that launched it.
     ``on_step`` runs with that one step possibly unread.
+
+    A step yields a COUNT of tokens a slot, which the loop learns from the
+    engine (``engine.block``, a ``BlockSchedule``, where generation is by
+    diffusion over blocks; one token a slot without it).  Such an engine's
+    prefill yields no token: the request's first tokens, and its TTFT, come
+    with its first block's commit pass, and every block's tokens are
+    recorded together, in order, when that pass is read; a pass in between
+    yields none.  The schedule is static, so the host knows at the launch
+    what the pass in flight will yield (``_InFlight.block`` mirrors the
+    slot's open block) and the pipeline stays one step deep; a request gets
+    exactly ``max_new_tokens``, its last block cut where the budget ends.
+    ``speculative`` and a prefix cache are refused for such an engine.
 
     Fleet mode (serve/fleet.py): ``inbox`` (a ``RequestInbox``) feeds the
     loop NETWORK submissions — drained into ``scheduler.submit`` at every
@@ -295,9 +310,18 @@ def run_serve_resilient(
     retained_params = None  # old tree parked by a committed swap (two-phase)
     result = ServeResult(status="completed")
     cache = scheduler.cache
-    # the decode step in flight: launched, its ids not yet read, with the
-    # request each stepped slot held at the launch.  At most one.
-    pending: Optional[Tuple[DecodeStep, Dict[int, Any]]] = None
+    # what a step yields: one token a slot, or (``block``, the engine's
+    # ``BlockSchedule``) what its schedule says of the slot's open block
+    block = getattr(engine, "block", None)
+    if block is not None and (speculative is not None or scheduler.prefix is not None):
+        raise NotImplementedError(
+            "speculative= and a prefix cache need a step of one token a position and a cache without slot "
+            f"state; {type(engine).__name__} generates by blocks of {block.B}, whose open block is slot state"
+        )
+    # the decode step in flight: launched, its ids not yet read, with what
+    # each stepped slot held at the launch and will be given of the step's
+    # ids: ``{slot: (request, skip, count)}``.  At most one.
+    pending: Optional[Tuple[DecodeStep, Dict[int, Tuple[Any, int, int]]]] = None
 
     # ------------------------------------------- observability wiring
     # goodput/MFU accounting + the /healthz + /router providers; the ops
@@ -528,10 +552,30 @@ def run_serve_resilient(
             handler.request()  # a PEER is being preempted; drain together
         return preempt_any, oom_any, rt_any, wall_any
 
+    def _first_token(inf, now: float) -> float:
+        """The request's first token is out: its TTFT, anchored at
+        SUBMISSION (under load the queue wait is the dominant term, and the
+        SLO shed path must see it).  The queue-wait component was observed
+        at admission (scheduler); per-tenant TTFT rides along once tenants
+        are in play (a non-default class, or weights configured); the
+        zero-config single-tenant path observes exactly what it always did."""
+        ttft = now - inf.submit_wall
+        tenant = inf.req.tenant
+        scheduler.observe_ttft(
+            ttft,
+            tenant=(
+                tenant
+                if (tenant != "default" or scheduler.tenant_weights)
+                else None
+            ),
+        )
+        return ttft
+
     def _prefill_admitted(step: int) -> None:
         """Admit queued requests into free slots and prefill them; the
         first sampled token is recorded immediately (its latency IS the
-        TTFT)."""
+        TTFT).  Where a step moves a block the prefill yields no token: it
+        opens the slot's first block, and the TTFT comes with that block."""
         admitted = scheduler.admit(step)
         for inf in admitted:
             _beat(step, "prefill")
@@ -574,8 +618,10 @@ def run_serve_resilient(
                 speculative.admit(
                     inf.slot, inf.req.prompt, inf.req.max_new_tokens
                 )
-            tok = engine.greedy(logits)
-            _sample(inf.slot, tok)
+            if block is None:
+                _sample(inf.slot, engine.greedy(logits))
+            else:
+                inf.block = block.open(len(inf.req.prompt))
             now = time.perf_counter()
             prefill_s = now - inf.admit_wall
             reqtrace.prefill(inf.req.rid, inf.slot, prefill_s,
@@ -584,26 +630,14 @@ def run_serve_resilient(
             # first measured bound on a step of this model (conservative —
             # a decode step is cheaper than a full prefill)
             scheduler.seed_step_time(prefill_s)
-            # TTFT anchors at SUBMISSION: under load the queue wait is the
-            # dominant term, and the SLO shed path must see it.  The
-            # queue-wait component was observed at admission (scheduler);
-            # this is the rest — the decomposition's prefill half
-            ttft = now - inf.submit_wall
-            # per-tenant TTFT rides along once tenants are in play (a
-            # non-default class, or weights configured); the zero-config
-            # single-tenant path observes exactly what it always did
-            tenant = inf.req.tenant
-            scheduler.observe_ttft(
-                ttft,
-                tenant=(
-                    tenant
-                    if (tenant != "default" or scheduler.tenant_weights)
-                    else None
-                ),
-            )
             _tel.observe("serve_ttft_prefill_seconds", prefill_s)
-            _event("admit", rid=inf.req.rid, slot=inf.slot, at_step=step,
-                   replays=inf.replays, ttft_s=round(ttft, 6))
+            if block is None:
+                # the prefill's half of the TTFT decomposition closes it
+                _event("admit", rid=inf.req.rid, slot=inf.slot, at_step=step,
+                       replays=inf.replays, ttft_s=round(_first_token(inf, now), 6))
+            else:
+                _event("admit", rid=inf.req.rid, slot=inf.slot, at_step=step,
+                       replays=inf.replays)
 
     def _sample(slot: int, token: int) -> None:
         nonlocal token_crc
@@ -628,20 +662,33 @@ def run_serve_resilient(
                 _event("complete", rid=inf.req.rid, slot=slot, at_step=step,
                        tokens=len(inf.tokens))
 
-    def _record(flight: Tuple[DecodeStep, Dict[int, Any]]) -> Dict[int, Tuple[Any, int]]:
+    def _record(flight: Tuple[DecodeStep, Dict[int, Tuple[Any, int, int]]]) -> Dict[int, Tuple[Any, int]]:
         """Read a launched step's ids (the wait for the device, unless the
-        ``decode`` call that it fed has waited already) and record each for
-        the slot that STILL holds the request it was launched for: a slot
-        cancelled, evicted or completed since, or taken by another request,
-        drops its id.  Returns ``{slot: (request, 1 token)}`` of those kept."""
+        ``decode`` call that it fed has waited already) and record, in
+        order, those the launch meant for each slot (its one; of a block,
+        ``[skip: skip + count]``, up to an EOS) for the slot that STILL
+        holds the request it was launched for: a slot cancelled, evicted or
+        completed since, or taken by another request, drops its ids.
+        Returns ``{slot: (request, tokens recorded)}`` of those kept."""
         dstep, slots = flight
-        next_ids = dstep.tokens
+        # plain ints, a row a slot (of one id, or of a block's): read once
+        next_ids = dstep.tokens.reshape(cache.num_slots, -1).tolist()
         kept: Dict[int, Tuple[Any, int]] = {}
         with _nd.ndtimeit(_SERVE_SAMPLE):
             for slot in sorted(slots):
-                if scheduler.active.get(slot) is slots[slot]:
-                    _sample(slot, int(next_ids[slot]))
-                    kept[slot] = (slots[slot], 1)
+                inf, skip, count = slots[slot]
+                if scheduler.active.get(slot) is not inf:
+                    continue
+                if count and not inf.tokens and block is not None:
+                    _event("first_tokens", rid=inf.req.rid, slot=slot,
+                           ttft_s=round(_first_token(inf, time.perf_counter()), 6))
+                eos, taken = inf.req.eos_id, 0
+                for tok in next_ids[slot][skip:skip + count]:
+                    _sample(slot, tok)
+                    taken += 1
+                    if eos is not None and tok == eos:
+                        break
+                kept[slot] = (inf, taken)
         return kept
 
     def _close_step(step: int, dt: float, width: int,
@@ -662,9 +709,15 @@ def run_serve_resilient(
         scheduler.observe_step_time(dt)
         reqtrace.decode_step(step, dt, width)
         for slot, (inf, m) in emitted.items():
-            # a speculative step amortizes the wall over every token it
-            # emitted for the slot
-            per_tok = dt / max(1, m)
+            # a step that gave a slot several tokens (a speculative verify,
+            # a block's commit pass) amortizes over them its wall and that of
+            # the steps since the slot's last token that gave it none (a
+            # block's denoising passes): a token's latency is its share of
+            # the time its request waited for it
+            inf.unyielded_s += dt
+            if not m:
+                continue
+            per_tok, inf.unyielded_s = inf.unyielded_s / m, 0.0
             for j in range(m):
                 scheduler.observe_itl(per_tok)
                 reqtrace.decode_token(
@@ -886,21 +939,30 @@ def run_serve_resilient(
                     scheduler.step_time_estimate() if _ca.is_active() else None
                 )
                 t0 = time.perf_counter()
-                # the slots this step moves, and what feeds each: the id the
+                # the slots this step moves, what each will be given of its
+                # ids (one token; of a block, what the schedule says: none
+                # from a denoising pass), and what feeds each: the id the
                 # step in flight is making for it, as it lies on the device,
-                # or (prefilled since) the host's last token.  A request whose
-                # budget the id in flight fills ends by its count: nothing
+                # or (prefilled since) the host's last token; a block lies
+                # on the device as the pass before left it.  A request whose
+                # budget the ids in flight fill ends by its count: nothing
                 # more is launched for it
                 flight = pending[1] if pending is not None else {}
-                stepped: Dict[int, Any] = {}
+                stepped: Dict[int, Tuple[Any, int, int]] = {}
                 fresh: Dict[int, int] = {}
+                settles: Dict[int, int] = {}
                 for slot, inf in scheduler.active.items():
-                    unread = flight.get(slot) is inf
-                    if len(inf.tokens) + unread >= inf.req.max_new_tokens:
+                    unread = flight.get(slot, (None,))[0] is inf
+                    owed = inf.req.max_new_tokens - len(inf.tokens) - (flight[slot][2] if unread else 0)
+                    if owed <= 0:
                         continue
-                    stepped[slot] = inf
-                    if not unread:
-                        fresh[slot] = inf.tokens[-1]
+                    if block is None:
+                        stepped[slot], settles[slot] = (inf, 0, 1), 1
+                        if not unread:
+                            fresh[slot] = inf.tokens[-1]
+                    else:
+                        skip, count, settles[slot] = block.plan(inf.block, owed)
+                        stepped[slot] = (inf, skip, count)
                 active_slots = sorted(stepped)
                 drafted_rows = (speculative.drafted_slots(active_slots)
                                 if speculative is not None else [])
@@ -915,7 +977,10 @@ def run_serve_resilient(
                     # device (nothing here reads them)
                     before, pending = pending, None
                     if stepped:
-                        if before is None:
+                        if block is not None:
+                            feed = DecodeFeed(before[0] if before is not None else None,
+                                              slots={slot: stepped[slot][2] for slot in active_slots})
+                        elif before is None:
                             feed = np.zeros((cache.num_slots,), np.int32)
                             for slot, tok in fresh.items():
                                 feed[slot] = tok
@@ -926,8 +991,10 @@ def run_serve_resilient(
                         pending = (engine.decode(feed), stepped)
                         for slot in active_slots:
                             # a step appends one position to every slot it
-                            # stepped, whatever the token
-                            cache.advance(slot)
+                            # stepped, whatever the token; a block's commit
+                            # pass the block, a denoising pass nothing
+                            if settles[slot]:
+                                cache.advance(slot, settles[slot])
                     if speculative is not None:
                         # a drafter needs every token on the host before it
                         # drafts again: its loop reads each step at once
@@ -960,7 +1027,7 @@ def run_serve_resilient(
                     drafted_now = accepted_now = 0
                     width, emitted = len(active_slots), {}
                     for slot in active_slots:
-                        inf = stepped[slot]
+                        inf = stepped[slot][0]
                         budget = inf.req.max_new_tokens - len(inf.tokens)
                         out, accepted = spec.accept(
                             drafts[slot], vlogits[slot], budget, inf.req.eos_id

@@ -132,6 +132,14 @@ class _InFlight:
     # prompt tokens served from cached prefix pages (prefix_cache.py): the
     # loop prefills only prompt[prefix_hit:]
     prefix_hit: int = 0
+    # where a decode step moves a block and yields a count of tokens (an
+    # engine with a ``BlockSchedule``): the host's mirror of the slot's open
+    # block, which the serve loop opens at the prefill and advances at every
+    # launch; None where a step yields one token
+    block: Optional[List[int]] = None
+    # wall of the decode steps since the request's last token that gave it
+    # none (a block's denoising passes): the next token's latency holds it
+    unyielded_s: float = 0.0
 
 
 class ContinuousBatchingScheduler:
