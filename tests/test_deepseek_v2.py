@@ -46,14 +46,16 @@ def toy_config(**changes):
     return dataclasses.replace(FAMILY.program_config(TOY, prefill_chunk=8), dtype=jnp.float32, **changes)
 
 
-@pytest.fixture(scope="module", params=["xla_legs", "experts_sorted", "kernels_interpreted"])
+@pytest.fixture(scope="module", params=["xla_legs", "experts_sorted", "kernels_interpreted", "experts_padded"])
 def system(request):
-    """The toy engine, three times: as a CPU builds it (the XLA decode leg, the
-    dense prefill attention, the batched expert product); with the expert
-    layer's limit turned to 0 while the programs are traced, so that both take
-    the sorted, grouped product a real prefill takes; and with the Pallas
+    """The toy engine, four times: as a CPU builds it (the XLA decode leg, the
+    dense prefill attention, all experts on all tokens); with both of the
+    expert layer's limits turned to 0 while the programs are traced, so that
+    both take the sorted, grouped product a real prefill takes; with the Pallas
     kernels a TPU would compile (``paged_decode_latent``, the flash forward
-    with two head widths) run through the interpreter."""
+    with two head widths) run through the interpreter; and with the first limit
+    alone turned to 0, so that both are candidates for the padded batched
+    product that a 256- or 512-rung prefill takes at the real size."""
     from vescale_tpu.moe import dropless
 
     cfg = toy_config()
@@ -61,12 +63,15 @@ def system(request):
     params = jax.jit(lambda k: ds.init_params(cfg, k))(jax.random.key(7))
     cache = PagedKVCache(hybrid_cache_config(cfg, num_slots=SLOTS, page_size=PAGE, pages_per_slot=PAGES), mesh)
     with pytest.MonkeyPatch.context() as patch:
-        if request.param == "experts_sorted":
+        if request.param in ("experts_sorted", "experts_padded"):
             patch.setattr(dropless, "DENSE_MAX_TOKENS", 0)
+        if request.param == "experts_sorted":
+            patch.setattr(dropless, "PADDED_MAX_MEAN_ROWS", 0)
         if request.param == "kernels_interpreted":
             patch.setenv("VESCALE_KERNELS", "interpret")
         engine = HybridServeEngine(cfg, mesh, params, cache).warm()     # every program is traced here
     assert engine.kernel_decode == (request.param == "kernels_interpreted")
+    assert engine._decode_padded_candidate == (request.param == "experts_padded")
     return cfg, mesh, params, cache, engine
 
 

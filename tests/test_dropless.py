@@ -1,0 +1,152 @@
+"""The three forms of ``moe/dropless.py:dropless_experts`` (all experts on all
+tokens, the sorted grouped product, the padded batched product) against a
+plain float32 loop over tokens and their ``k`` experts, the rule that chooses
+among them, and the fall-back on the device when a router sends one expert
+more rows than the pad."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from vescale_tpu.moe import dropless
+from vescale_tpu.moe.dropless import (DENSE_MAX_TOKENS, PADDED_MAX_MEAN_ROWS, ROW_PAD, dropless_experts, fits_pad,
+                                      padded_candidate, route_topk)
+
+D, F = 16, 12
+
+
+def _loop(x, idx, gates, w_gate, w_up, w_down, first, mask):
+    """A token at a time, a kept expert at a time, in float64."""
+    held = w_gate.shape[0]
+    out = np.zeros((x.shape[0], w_down.shape[-1]))
+    counts = np.zeros((held,), np.int64)
+    for n in range(x.shape[0]):
+        if not mask[n]:
+            continue
+        for e, g in zip(idx[n], gates[n]):
+            if first <= e < first + held:
+                a, b = x[n] @ w_gate[e - first], x[n] @ w_up[e - first]
+                out[n] += g * ((a / (1.0 + np.exp(-a)) * b) @ w_down[e - first])
+                counts[e - first] += 1
+    return out, counts
+
+
+def _problem(N, E, k, held, seed, favourite=None):
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(N, D))
+    scores = rng.normal(size=(N, E))
+    if favourite is not None:
+        scores[:, favourite] += 9.0         # every token keeps it
+    weights = [rng.normal(size=s) for s in ((held, D, F), (held, D, F), (held, F, D))]
+    idx, gates = route_topk(jnp.asarray(scores, jnp.float32), k)
+    mask = np.ones((N,), bool)
+    mask[::7] = False                       # tokens that route nowhere
+    return x, idx, gates, weights, mask
+
+
+def _run(x, idx, gates, weights, first, mask):
+    fn = jax.jit(lambda *a: dropless_experts(*a, first_held=first, token_mask=jnp.asarray(mask)))
+    args = (jnp.asarray(x, jnp.float32), idx, gates, *(jnp.asarray(w, jnp.float32) for w in weights))
+    # the program as traced (a CPU lowers ``ragged_dot`` to plain products, so its name is gone from the lowered text) and as lowered
+    return fn(*args), str(jax.make_jaxpr(fn)(*args)) + fn.lower(*args).as_text()
+
+
+def _form(text):
+    """Which forms the program holds: a choice on the device, a grouped product."""
+    assert ("stablehlo.case" in text) == ("cond[" in text)
+    return {"cond": "stablehlo.case" in text, "ragged": "ragged_dot" in text}
+
+
+def rel(got, want):
+    return float(np.max(np.abs(np.asarray(got) - want)) / np.max(np.abs(want)))
+
+
+# N, all experts, k, held, first held, and the forms the program must hold
+SHAPES = {
+    "all_on_all_64_tokens": (64, 16, 4, 8, 4, {"cond": False, "ragged": False}),
+    "padded_512_tokens_128_held_top8": (512, 160, 8, 128, 16, {"cond": True, "ragged": True}),
+    "sorted_2048_tokens_8_held": (2048, 12, 3, 8, 2, {"cond": False, "ragged": True}),
+}
+
+
+@pytest.mark.parametrize("shape", SHAPES)
+def test_each_form_is_the_loop_over_tokens_and_their_experts(shape):
+    N, E, k, held, first, forms = SHAPES[shape]
+    assert held < E and first > 0
+    x, idx, gates, weights, mask = _problem(N, E, k, held, seed=N)
+    (got, counts), text = _run(x, idx, gates, weights, first, mask)
+    assert _form(text) == forms
+    want, want_counts = _loop(x, np.asarray(idx), np.asarray(gates), *weights, first, mask)
+    assert rel(got, want) < 1e-5
+    assert list(np.asarray(counts)) == list(want_counts)
+    if forms["cond"]:
+        assert bool(fits_pad(np.asarray(counts))), "this case is to take the padded branch"
+    assert not np.asarray(got)[~mask].any(), "a masked token routes nowhere"
+
+
+def test_an_expert_with_more_rows_than_the_pad_falls_back_on_the_device_to_the_same_result():
+    """Every one of 256 tokens keeps one held expert: a candidate by its shape
+    (8 experts of 128 places for 512 pairs), over the pad by its counts."""
+    N, E, k, held, first = 256, 12, 2, 8, 2
+    assert padded_candidate(N, k, held)
+    x, idx, gates, weights, mask = _problem(N, E, k, held, seed=1, favourite=first + 3)
+    (got, counts), text = _run(x, idx, gates, weights, first, mask)
+    assert _form(text) == {"cond": True, "ragged": True}
+    counts = np.asarray(counts)
+    assert counts[3] == mask.sum() > ROW_PAD and not bool(fits_pad(counts))
+    want, want_counts = _loop(x, np.asarray(idx), np.asarray(gates), *weights, first, mask)
+    assert rel(got, want) < 1e-5 and list(counts) == list(want_counts)
+    # the same router under the pad: the other branch of the same program's text, the same loop
+    few = mask & (np.arange(N) < ROW_PAD)
+    (got, counts), _ = _run(x, idx, gates, weights, first, few)
+    assert bool(fits_pad(np.asarray(counts))) and np.asarray(counts)[3] == few.sum()
+    assert rel(got, _loop(x, np.asarray(idx), np.asarray(gates), *weights, first, few)[0]) < 1e-5
+
+
+def test_both_branches_of_a_candidate_call_give_the_same_numbers(monkeypatch):
+    """The padded form against the sorted one on the same operands, bfloat16
+    products as they are served: the same products, so what differs is the
+    order in which a token's k terms are added."""
+    N, E, k, held = 384, 32, 4, 32
+    x, idx, gates, weights, mask = _problem(N, E, k, held, seed=5)
+    args = (jnp.asarray(x, jnp.float32), idx, gates, *(jnp.asarray(w, jnp.bfloat16) for w in weights))
+    call = lambda: jax.jit(lambda *a: dropless_experts(*a, token_mask=jnp.asarray(mask)))(*args)
+    assert padded_candidate(N, k, held)
+    padded, counts = call()
+    assert bool(fits_pad(np.asarray(counts)))
+    monkeypatch.setattr(dropless, "PADDED_MAX_MEAN_ROWS", 0)
+    assert not padded_candidate(N, k, held)
+    alone, counts_alone = call()
+    assert rel(padded, np.asarray(alone)) < 1e-6 and list(np.asarray(counts)) == list(np.asarray(counts_alone))
+
+
+@pytest.mark.parametrize("N", [1, 32, 64, DENSE_MAX_TOKENS])
+def test_a_call_of_few_tokens_holds_neither_a_choice_nor_a_grouped_product(N):
+    """Granite's and DeepSeek-V2's decode steps (64 and 32 tokens) compile as
+    they did: all experts on all tokens, nothing else in the program."""
+    x, idx, gates, weights, mask = _problem(N, 16, 4, 8, seed=2)
+    _, text = _run(x, idx, gates, weights, 4, mask)
+    assert _form(text) == {"cond": False, "ragged": False}
+    assert "stablehlo.sort" not in text
+
+
+@pytest.mark.parametrize("N, k, held, candidate", [
+    (128, 8, 128, False),       # a decode step of 128 tokens: all on all
+    (512, 8, 128, True),        # SDAR's pass: 32 rows an expert
+    (1024, 8, 128, True),       # ... its 1024 rung: 64
+    (2048, 8, 128, False),      # ... its 2048 rung: 128, the busiest expert never fits
+    (256, 10, 36, True),        # Granite's 256 rung: 71 pairs a held expert (half of them land elsewhere)
+    (512, 10, 36, False),       # ... its 512 rung: 142
+    (64, 10, 36, False), (32, 6, 40, False),    # both neighbours' decode steps
+    (512, 6, 40, True),         # DeepSeek-V2's 512 rung: 77
+    (4096, 6, 40, False),       # ... and the rungs its long prompts take: 614
+])
+def test_the_static_half_of_the_choice_reads_tokens_choices_and_held_experts(N, k, held, candidate):
+    assert padded_candidate(N, k, held) is candidate
+    assert PADDED_MAX_MEAN_ROWS <= ROW_PAD, "a mean over the pad can never fit it"
+
+
+def test_the_predicate_is_the_same_on_the_hosts_copy_of_the_counts_layer_by_layer():
+    counts = np.array([[0, ROW_PAD, 3], [ROW_PAD + 1, 0, 0], [5, 5, 5]])
+    assert list(fits_pad(counts)) == [True, False, True] == [bool(fits_pad(jnp.asarray(row))) for row in counts]

@@ -39,14 +39,16 @@ def toy_config(**changes):
     return dataclasses.replace(FAMILY.program_config(TOY), dtype=jnp.float32, **changes)
 
 
-@pytest.fixture(scope="module", params=["experts_by_shape", "experts_sorted", "kernels_interpreted"])
+@pytest.fixture(scope="module", params=["experts_by_shape", "experts_sorted", "kernels_interpreted", "experts_padded"])
 def system(request):
-    """The toy engine, three times: as the expert layer chooses by the token
-    count (every toy shape is under its limit: the batched product); with that
-    limit turned to 0 while the programs are traced, so that prefill and
-    decode both take the sorted, grouped product a real prefill takes; and
-    with the Pallas kernels a TPU would compile (``ssm_step``,
-    ``paged_decode``, flash attention) run through the interpreter."""
+    """The toy engine, four times: as the expert layer chooses by its shapes
+    (every toy shape is under its first limit: all experts on all tokens); with
+    both its limits turned to 0 while the programs are traced, so that prefill
+    and decode both take the sorted, grouped product a long prefill takes; with
+    the Pallas kernels a TPU would compile (``ssm_step``, ``paged_decode``,
+    flash attention) run through the interpreter; and with the first limit
+    alone turned to 0, so that both are candidates for the padded batched
+    product that a 256-rung prefill takes at the real size."""
     from vescale_tpu.moe import dropless
 
     cfg = toy_config()
@@ -54,12 +56,15 @@ def system(request):
     params = jax.jit(lambda k: gh.init_params(cfg, k))(jax.random.key(7))
     cache = PagedKVCache(hybrid_cache_config(cfg, num_slots=SLOTS, page_size=PAGE, pages_per_slot=PAGES), mesh)
     with pytest.MonkeyPatch.context() as patch:
-        if request.param == "experts_sorted":
+        if request.param in ("experts_sorted", "experts_padded"):
             patch.setattr(dropless, "DENSE_MAX_TOKENS", 0)
+        if request.param == "experts_sorted":
+            patch.setattr(dropless, "PADDED_MAX_MEAN_ROWS", 0)
         if request.param == "kernels_interpreted":
             patch.setenv("VESCALE_KERNELS", "interpret")
         engine = HybridServeEngine(cfg, mesh, params, cache).warm()     # every program is traced here
     assert engine.kernel_ssm_step == engine.kernel_decode == (request.param == "kernels_interpreted")
+    assert engine._decode_padded_candidate == (request.param == "experts_padded")
     return cfg, mesh, params, cache, engine
 
 
@@ -220,6 +225,7 @@ def test_the_counters_count_what_the_decode_steps_routed(system):
     assert 0 < d["moe_assignments_held"] <= d["moe_assignments"]
     assert d["moe_expert_slots"] == 2 * layers * held and 0 < d["moe_experts_touched"] <= d["moe_expert_slots"]
     assert d["moe_layer_steps"] == 2 * layers
+    assert d["moe_padded_layer_steps"] == (2 * layers if engine._decode_padded_candidate else 0), "2 rows fit any pad"
     assert d["moe_busiest_expert_tokens"] * held >= d["moe_assignments_held"], "the busiest is at least the mean"
     assert d["ssm_state_bytes_rw"] == 2 * 2 * SLOTS * cache.state_bytes_per_slot()
     assert d["prefill_tokens_real"] == 14 and d["prefill_bucket_tokens"] == 8 + 16
@@ -241,18 +247,22 @@ def _per_token_loop(x, scores, k, w_gate, w_up, w_down, first, held):
     return out
 
 
-@pytest.mark.parametrize("N", [40, 160], ids=["batched", "sorted"])
+@pytest.mark.parametrize("N,k", [(40, 3), (144, 2), (160, 3)], ids=["all_on_all", "padded", "sorted"])
 @pytest.mark.parametrize("first,held", [(0, 8), (0, 4), (4, 4), (2, 3)])
-def test_the_dropless_layer_is_a_per_token_loop_under_routing_so_uneven_that_capacity_would_drop(first, held, N):
-    from vescale_tpu.moe.dropless import DENSE_MAX_TOKENS
+def test_the_dropless_layer_is_a_per_token_loop_under_routing_so_uneven_that_capacity_would_drop(first, held, N, k):
+    from vescale_tpu.moe.dropless import DENSE_MAX_TOKENS, ROW_PAD, padded_candidate
 
-    assert 40 <= DENSE_MAX_TOKENS < 160, "one case for each of the layer's two shapes"
-    d, f, E, k = 16, 12, 8, 3
+    # one case for each of the layer's three forms: few tokens; a candidate whose busiest expert, kept by every one of the
+    # 123 tokens that route, fits the pad; and 137 on one expert, which a call that is no candidate sorts and one that is
+    # sends to the sorted form on the device
+    assert 40 <= DENSE_MAX_TOKENS < 144 and padded_candidate(144, 2, held)
+    assert 144 - len(range(0, 144, 7)) <= ROW_PAD < 160 - len(range(0, 160, 7))
+    d, f, E = 16, 12, 8
     rng = np.random.default_rng(3)
     x = rng.normal(size=(N, d))
     scores = rng.normal(size=(N, E))
     scores[:, 1] += 6.0            # every token keeps experts 1 and 5: N tokens each, capacity is 3 N / 4
-    scores[:, 5] += 5.0
+    scores[:, 5] += 5.0 if k == 3 else 1.0     # (of two choices the second goes to 5 mostly: every share gets some)
     w_gate, w_up, w_down = rng.normal(size=(held, d, f)), rng.normal(size=(held, d, f)), rng.normal(size=(held, f, d))
     idx, gates = route_topk(jnp.asarray(scores, jnp.float32), k)
     capacity = TokenDispatcher.capacity_for(N, E, k, 2.0)

@@ -43,15 +43,18 @@ def toy_config(dtype=jnp.float32, **changes):
     return dataclasses.replace(FAMILY.program_config(TOY, prefill_chunk=8), dtype=dtype, **changes)
 
 
-@pytest.fixture(scope="module", params=["xla_legs", "kernels_interpreted_experts_sorted", "bfloat16"])
+@pytest.fixture(scope="module", params=["xla_legs", "kernels_interpreted_experts_sorted", "bfloat16", "experts_padded"])
 def system(request):
-    """The toy engine, three times: as a CPU builds it in float32 (the XLA
-    decode leg, the dense block-masked attention, the batched expert product);
+    """The toy engine, four times: as a CPU builds it in float32 (the XLA
+    decode leg, the dense block-masked attention, all experts on all tokens);
     with the Pallas kernels a TPU compiles run through the interpreter
     (``paged_decode`` over a block's grouped query rows, the flash forward under
-    the block mask) and the expert layer's limit turned to 0 while the programs
-    are traced, so that a pass takes the sorted, grouped product that 512
-    positions take at the real size; and in bfloat16, as it is served."""
+    the block mask) and both of the expert layer's limits turned to 0 while the
+    programs are traced, so that a pass takes the sorted, grouped product that a
+    long prefill takes at the real size; in bfloat16, as it is served; and with
+    the first limit alone turned to 0, so that every program is a candidate for
+    the padded batched product, which a pass of 512 positions takes at the real
+    size (few rows an expert: the choice on the device takes it every call)."""
     from vescale_tpu.moe import dropless
 
     cfg = toy_config(jnp.bfloat16 if request.param == "bfloat16" else jnp.float32)
@@ -61,9 +64,13 @@ def system(request):
     with pytest.MonkeyPatch.context() as patch:
         if request.param.startswith("kernels_interpreted"):
             patch.setattr(dropless, "DENSE_MAX_TOKENS", 0)
+            patch.setattr(dropless, "PADDED_MAX_MEAN_ROWS", 0)
             patch.setenv("VESCALE_KERNELS", "interpret")
+        if request.param == "experts_padded":
+            patch.setattr(dropless, "DENSE_MAX_TOKENS", 0)
         engine = HybridServeEngine(cfg, mesh, params, cache).warm()     # every program is traced here
     assert engine.kernel_decode == request.param.startswith("kernels_interpreted")
+    assert engine._decode_padded_candidate == (request.param == "experts_padded")
     limit = BF16_AT_TOY_WIDTHS if request.param == "bfloat16" else TIGHT
     return cfg, params, cache, engine, limit
 
@@ -290,6 +297,8 @@ def test_the_counters_of_one_request_are_the_schedules_arithmetic(system, tmp_pa
     assert len(res.outcomes[0]["tokens"]) == 7
     assert (c["block_passes"], c["block_commit_passes"], c["block_tokens_emitted"], c["block_positions_masked"]) == (13, 3, 7, 23)
     assert c["decode_steps"] == 13 and c["moe_layer_steps"] == 13 * cfg.num_hidden_layers
+    # every expert layer of a candidate call fit the pad (4 rows a pass against 128 places): the padded form, each time
+    assert c["moe_padded_layer_steps"] == (c["moe_layer_steps"] if _engine._decode_padded_candidate else 0)
     assert c["moe_assignments"] == c["moe_assignments_held"] == 13 * B * cfg.num_experts_per_tok * cfg.num_hidden_layers
     assert c["prefill_attn_flops"] == sd.prefill_counters(cfg, 16)["prefill_attn_flops"] \
         == cfg.num_hidden_layers * FAMILY.block_prefill_attention_flops(TOY, 16)

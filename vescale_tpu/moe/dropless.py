@@ -12,16 +12,43 @@ lives elsewhere adds nothing here (the exchange that would carry it there is
 not this module's; on one chip the layer runs without it), and tokens masked
 out (slots that are not active, pad positions) route nowhere.
 
-Two shapes, one result (the choice is by the token count, never a knob).  At
-most ``DENSE_MAX_TOKENS`` tokens (a decode step): every held expert runs on
-every token as one batched product and the gates, zero where a token did not
-keep the expert, weigh the sum.  A grouped product works in row tiles of 128,
-so with so few tokens each touched expert costs it a whole tile anyway, and
-the chip's trace (PERF.md, PR 29) showed the grouped kernel streaming the
-expert weights at half the memory's rate where the batched product streams
-them at nine tenths: a decode step is those weights' read.  More tokens (a
-prefill) are sorted, as above; there the batched product's work would grow
-with tokens x held experts.
+Three forms, one result; the choice reads shapes and counts, never a knob or a
+model's name.  What decides is how many ROWS AN EXPERT gets.
+
+* *All on all.*  At most ``DENSE_MAX_TOKENS`` tokens (a decode step of one
+  position a slot): every held expert runs on every token as one batched
+  product and the gates, zero where a token did not keep the expert, weigh the
+  sum.  A grouped product works in row tiles of 128, so with so few tokens
+  each touched expert costs it a whole tile anyway, and the chip's trace
+  (PERF.md, PR 29) showed the grouped kernel streaming the expert weights at
+  half the memory's rate where the batched product streams them at nine
+  tenths: a decode step is those weights' read.
+* *Sorted.*  The pairs on held experts are sorted by expert and go through
+  ``jax.lax.ragged_dot``, as above: work linear in the pairs, and the form for
+  many rows an expert (a long prefill), where all-on-all would grow with
+  tokens x held experts.
+* *Padded.*  More tokens than all-on-all can carry but FEW ROWS AN EXPERT (a
+  pass of 128 slots x 4 positions over 128 experts: 32; a short prefill):
+  each held expert's rows are gathered, in the sorted order, into ``ROW_PAD``
+  places (zeros behind its count), the three products run as batched products
+  over ``(held, ROW_PAD, .)``, and a token's ``k`` results are gathered back
+  from place ``expert x ROW_PAD + (place in the order - the expert's first)``
+  under its gates.  The same products on the same operands as the sorted
+  form; only the order of a token's ``k`` terms may differ.  The pad is one
+  row tile: the grouped kernel spends a tile on a touched expert whatever its
+  count, so up to there the padded rows cost the MXU nothing it was not
+  already spending, the form stays bound by the weights' read, and XLA's
+  batched product streams them at twice the grouped kernel's rate (PERF.md
+  section 6, PR 37, "crossover": 558-665 GB/s against 252-340 at 16 to 64 rows
+  an expert, at two models' widths).
+
+The rule.  ``N <= DENSE_MAX_TOKENS``: all on all.  Otherwise, if the pairs
+would fit the pad on average with room for a router's unevenness
+(:func:`padded_candidate`: ``N k <= held x PADDED_MAX_MEAN_ROWS``), the call
+holds both other forms and chooses ON THE DEVICE, from the counts it has
+(:func:`fits_pad`: the busiest held expert got at most ``ROW_PAD`` rows: the
+padded form; else the sorted one, same result).  Otherwise the sorted form
+alone.
 
 ``moe.layer.MoEMLP`` / ``TokenDispatcher`` (capacity, one-hot masks, expert
 biases) stay as they are for training; ROADMAP D4 moves them here.
@@ -29,15 +56,26 @@ biases) stay as they are for training; ROADMAP D4 moves them here.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["route_topk", "route_group_limited", "dropless_experts", "DENSE_MAX_TOKENS"]
+__all__ = ["route_topk", "route_group_limited", "dropless_experts", "padded_candidate", "fits_pad", "DENSE_MAX_TOKENS", "ROW_PAD",
+           "PADDED_MAX_MEAN_ROWS"]
 
 # one row tile of a grouped product: up to here each touched expert costs it a tile, sorted or not
 DENSE_MAX_TOKENS = 128
+# the padded form's places an expert: the same row tile, so a touched expert costs no more rows than the grouped product spends
+ROW_PAD = 128
+# pairs a held expert (N k / held) up to which a call holds the padded form.  PERF.md section 6, PR 37, "crossover": at 16 / 32 /
+# 64 / 96 / 128 rows an expert the padded form streamed the weights 2.1-3.4 times as fast as the sorted one wherever it fit, so
+# the bound is one of FIT, not of speed: a uniform router's busiest of 128 experts gets mean + 2.6 sqrt(mean), past the pad from
+# a mean of about 100 on, and such a call would compile a branch it never takes
+PADDED_MAX_MEAN_ROWS = 96
+# what a call holds: one form, or the padded and the sorted one under a choice on the device
+ALL_ON_ALL, SORTED, PADDED_OR_SORTED = "all_on_all", "sorted", "padded_or_sorted"
 
 
 def route_topk(scores, k: int) -> Tuple[jax.Array, jax.Array]:
@@ -68,6 +106,18 @@ def route_group_limited(scores, k: int, *, n_group: int, topk_group: int, scale:
     return idx.astype(jnp.int32), top * scale, kept
 
 
+def padded_candidate(N: int, k: int, held: int) -> bool:
+    """The static half of the choice: may a call of ``N`` tokens with ``k`` experts each over ``held`` held experts
+    take the padded form?  (The other half is :func:`fits_pad`, of the counts.)"""
+    return N > DENSE_MAX_TOKENS and N * k <= held * PADDED_MAX_MEAN_ROWS
+
+
+def fits_pad(counts):
+    """The half on the device: did no held expert get more rows than the pad?  ``counts`` (..., held), the
+    device's or the host's copy of them: the serve engine asks this of the same integers."""
+    return counts.max(axis=-1) <= ROW_PAD
+
+
 def dropless_experts(x, idx, gates, w_gate, w_up, w_down, *, first_held: int = 0,
                      token_mask: Optional[jax.Array] = None, dtype=None):
     """``sum over kept and held e of g_e * W_down,e (silu(W_gate,e x) * W_up,e x)``.
@@ -81,7 +131,17 @@ def dropless_experts(x, idx, gates, w_gate, w_up, w_down, *, first_held: int = 0
     """
     held = w_gate.shape[0]
     N, k = idx.shape
-    dtype = w_gate.dtype if dtype is None else dtype
+    form = ALL_ON_ALL if N <= DENSE_MAX_TOKENS else PADDED_OR_SORTED if padded_candidate(N, k, held) else SORTED
+    return _experts(x, idx, gates, w_gate, w_up, w_down, token_mask, first_held=first_held,
+                    dtype=w_gate.dtype if dtype is None else dtype, form=form)
+
+
+# jitted inside its caller's program: a model's layers have one shape, so the layer is traced and lowered once a
+# program and not once a layer (a call that holds two forms is twice the text; warm set-up is tracing and lowering)
+@functools.partial(jax.jit, static_argnames=("first_held", "dtype", "form"))
+def _experts(x, idx, gates, w_gate, w_up, w_down, token_mask, *, first_held, dtype, form):
+    held = w_gate.shape[0]
+    N, k = idx.shape
     local = idx - first_held
     here = (local >= 0) & (local < held)
     if token_mask is not None:
@@ -90,7 +150,7 @@ def dropless_experts(x, idx, gates, w_gate, w_up, w_down, *, first_held: int = 0
     group = jnp.where(here, local, held).reshape(N * k)
     counts = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
     kept = jnp.where(here, gates, 0.0)                               # (N, k): a pair that adds nothing here weighs 0
-    if N <= DENSE_MAX_TOKENS:
+    if form == ALL_ON_ALL:
         # every held expert on every token; a token's gate for an expert it did not keep is 0
         weight = jnp.einsum("nk,nke->ne", kept, jax.nn.one_hot(local, held, dtype=jnp.float32))
         xb = jnp.broadcast_to(x.astype(dtype)[None], (held, N, x.shape[-1]))
@@ -99,15 +159,36 @@ def dropless_experts(x, idx, gates, w_gate, w_up, w_down, *, first_held: int = 0
         out = jnp.einsum("enf,efd->end", hidden, w_down.astype(dtype), preferred_element_type=jnp.float32)
         return jnp.einsum("end,ne->nd", out, weight), counts
     order = jnp.argsort(group, stable=True)
-    xs = jnp.take(x, order // k, axis=0).astype(dtype)
-    product = lambda a, w: jax.lax.ragged_dot(a, w.astype(dtype), counts, preferred_element_type=jnp.float32)
-    hidden = (jax.nn.silu(product(xs, w_gate)) * product(xs, w_up)).astype(dtype)
-    ys = product(hidden, w_down)                                    # (N * k, d); rows past the groups are undefined
-    # back to the tokens, a choice at a time, under the gates; a pair that is not here lies past the groups, and
-    # its row is dropped where it is read.  (N, d) at a time: zeroing the rows first and un-sorting them whole
-    # passed four times over (N * k, d) in float32, 66 ms of a 396 ms prefill of 8192 tokens (PERF.md, PR 34)
+    # where each pair went in that order: a pair that is not here lies past the groups
     back = jnp.zeros((N * k,), jnp.int32).at[order].set(jnp.arange(N * k, dtype=jnp.int32)).reshape(N, k)
-    out = jnp.zeros((N, ys.shape[-1]), jnp.float32)
-    for j in range(k):
-        out = out + jnp.where(here[:, j, None], jnp.take(ys, back[:, j], axis=0), 0.0) * kept[:, j, None]
-    return out, counts
+
+    def gathered(ys, at):
+        # back to the tokens, a choice at a time, under the gates; a pair that is not here is dropped where it is
+        # read.  (N, d) at a time: zeroing the rows first and un-sorting them whole passed four times over
+        # (N * k, d) in float32, 66 ms of a 396 ms prefill of 8192 tokens (PERF.md, PR 34)
+        out = jnp.zeros((N, ys.shape[-1]), jnp.float32)
+        for j in range(k):
+            out = out + jnp.where(here[:, j, None], jnp.take(ys, at[:, j], axis=0), 0.0) * kept[:, j, None]
+        return out
+
+    def sorted_form():
+        xs = jnp.take(x, order // k, axis=0).astype(dtype)
+        product = lambda a, w: jax.lax.ragged_dot(a, w.astype(dtype), counts, preferred_element_type=jnp.float32)
+        hidden = (jax.nn.silu(product(xs, w_gate)) * product(xs, w_up)).astype(dtype)
+        return gathered(product(hidden, w_down), back)             # (N * k, d); rows past the groups are undefined
+
+    def padded_form():
+        # expert e's rows are places offsets[e] .. + counts[e] of the order; behind its count, zeros
+        offsets = jnp.cumsum(counts) - counts
+        place = jnp.arange(ROW_PAD, dtype=jnp.int32)
+        token = jnp.take(order, jnp.minimum(offsets[:, None] + place, N * k - 1)) // k                 # (held, P)
+        xp = jnp.where((place < counts[:, None])[..., None], jnp.take(x, token, axis=0), 0).astype(dtype)
+        product = lambda a, w: jnp.einsum("epa,eab->epb", a, w.astype(dtype), preferred_element_type=jnp.float32)
+        hidden = (jax.nn.silu(product(xp, w_gate)) * product(xp, w_up)).astype(dtype)                   # (held, P, f)
+        ys = product(hidden, w_down).reshape(held * ROW_PAD, -1)
+        inside = back - jnp.take(offsets, jnp.clip(local, 0, held - 1))
+        return gathered(ys, jnp.where(here, local * ROW_PAD + inside, 0))
+
+    if form == SORTED:
+        return sorted_form(), counts
+    return jax.lax.cond(fits_pad(counts), padded_form, sorted_form), counts

@@ -135,7 +135,7 @@ __all__ = ["HybridServeEngine", "hybrid_cache_config", "prefill_buckets"]
 COUNTERS = ("decode_steps", "decode_steps_ahead", "logits_bytes_to_host", "prefill_tokens_real", "prefill_tokens_padded",
             "prefill_bucket_tokens", "decode_pages_read", "decode_pages_capacity", "moe_assignments",
             "moe_assignments_held", "moe_busiest_expert_tokens", "moe_expert_slots", "moe_layer_steps",
-            "moe_experts_touched")
+            "moe_experts_touched", "moe_padded_layer_steps")
 # ... and for a model that generates by blocks: slot-passes of the slots a pass moved, those of them that were
 # commit passes, the tokens the host took, and the query rows that still had something to decide (masked
 # positions at the start of their pass), each summed over the passes read
@@ -187,6 +187,12 @@ class HybridServeEngine(DecodeAhead):
         if self.block is not None and cache.config.page_size % self.block.B:
             raise ValueError(f"a block of {self.block.B} positions must divide the page of {cache.config.page_size}: "
                              "a block that straddled a page would be written to two")
+        # may a decode call's expert layer take its padded form?  Its rows are static, so this is latched as the programs are
+        from ..moe.dropless import fits_pad, padded_candidate    # (jax comes with it: imported late, as everywhere in serve/)
+
+        decode_rows = cache.num_slots * (self.block.B if self.block is not None else 1)
+        self._decode_padded_candidate = padded_candidate(decode_rows, c.num_experts_per_tok, c.experts_held)
+        self._fits_pad = fits_pad
         # what this engine has done, in plain integers (``trace_counters``)
         self.counter_names = COUNTERS + (BLOCK_COUNTERS if self.block is not None else ()) + tuple(self.model.STEP_COUNTERS)
         for name in self.counter_names:
@@ -338,6 +344,8 @@ class HybridServeEngine(DecodeAhead):
         self.moe_expert_slots += int(experts.size)
         self.moe_layer_steps += int(experts.shape[0])
         self.moe_experts_touched += int((experts > 0).sum())
+        if self._decode_padded_candidate:           # the device's own predicate, on the integers it read
+            self.moe_padded_layer_steps += int(self._fits_pad(experts).sum())
         self._add(self.model.step_counters(c, self.cache, lengths, counts))
         super()._count_step(lengths, counts, yields)
 
@@ -354,7 +362,12 @@ class HybridServeEngine(DecodeAhead):
         one expert, summed over layers and steps (``moe_layer_steps`` of them),
         and ``moe_expert_slots`` = held experts x layers x steps (busiest /
         layer steps over held / slots = max over mean), ``moe_experts_touched``
-        those of them that got a token (their weights are read).
+        those of them that got a token (their weights are read);
+        ``moe_padded_layer_steps`` the layer steps whose expert layer took its
+        padded form (``moe.dropless``: the call's shape made it a candidate,
+        ``padded_candidate``, and its busiest expert fit the pad, ``fits_pad``:
+        the layer's own two functions on the counts the step returned), so over
+        ``moe_layer_steps`` the share of expert layers that did.
         ``prefill_bucket_tokens`` the bucket lengths.  A block engine's four
         (``BLOCK_COUNTERS``, above), then the model's own (its module's
         ``STEP_COUNTERS`` says what each counts)."""
