@@ -268,7 +268,8 @@ def test_a_step_is_read_once_by_whoever_reads_first_and_counted_then(rig):
     toks[slot] = 9
     first = eng.decode(toks)
     cache.advance(slot)
-    assert isinstance(first, DecodeStep) and not first.read and eng.trace_counters() == start, "launched, not read"
+    launched = dict(start, decode_launches=start["decode_launches"] + 1)     # counted as launched at once, as read later
+    assert isinstance(first, DecodeStep) and not first.read and eng.trace_counters() == launched, "launched, not read"
     second = eng.decode(DecodeFeed(first))          # waits for the first step's ids once its own program is enqueued
     cache.advance(slot)
     assert first.read and not second.read

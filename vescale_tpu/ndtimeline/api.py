@@ -111,16 +111,28 @@ def is_active() -> bool:
     return _ACTIVE and _MANAGER is not None
 
 
-def ndtimeit(metric: str, tags=None):
+_DORMANT = contextlib.nullcontext()   # stateless, so one serves every dormant site
+
+
+def ndtimeit(metric: str, tags=None, **ids):
     """Context manager: with ndtimeit("forward-compute"): ...
 
     A no-op (``nullcontext``) until the profiler is explicitly
     initialized: the runtime wiring (pipe engine, train step, checkpoint)
     calls this on every operation, and dormant instrumentation must not
     build TraceAnnotations, take locks, or grow a ring buffer nobody
-    flushes."""
+    flushes.
+
+    A span's identifiers are given as keyword arguments
+    (``ndtimeit(SERVE_DECODE_LAUNCH, launch=n)``): a site on a hot path
+    builds no dictionary, formats no string and reads no clock of its own
+    before the one test here.  Armed, they are the span's tags (with
+    ``tags``, where a caller has a dictionary already), in the ring and on
+    the ``TraceAnnotation`` alike."""
     if not is_active():
-        return contextlib.nullcontext()
+        return _DORMANT
+    if ids:
+        tags = {**tags, **ids} if tags else ids
     return _MANAGER.timeit(metric, tags)
 
 

@@ -49,6 +49,36 @@ SERVE_DECODE_CALL = "vs.serve-decode"
 SERVE_PREFILL_FETCH = "vs.serve-prefill.fetch"
 SERVE_DECODE_FETCH = "vs.serve-decode.fetch"
 SERVE_SAMPLE = "vs.serve-sample"
+# A LAUNCH HAS A NUMBER (``engine.launches``: one sequence for decode steps
+# and prefills, a plain integer kept whether or not anything traces).  With a
+# decode step in flight the spans above no longer bracket the program they
+# started (``vs.serve-decode`` opens at launch k and closes when step k-1 is
+# read), so the ENQUEUE alone has a span of its own inside each, tagged
+# ``launch=<n>`` (a prefill's also ``rung`` and ``slot``), and the ``.fetch``
+# that later waits for that program carries the same ``launch``: the span
+# that caused it.  The tags are the event's stats in the profiler's trace,
+# where a reader joins a launch to the device's program by kind and order
+# (benchmark/layer_metrics/_programs.py).
+SERVE_DECODE_LAUNCH = "vs.serve-decode.launch"
+SERVE_PREFILL_LAUNCH = "vs.serve-prefill.launch"
+# the serve loop's iteration, tiled (serve/loop.py; one call site each): what
+# the top of an iteration does before admission (beat, fault hooks, control
+# jobs, the verdicts that evict or cancel, ``_settle`` where a boundary forces
+# it); from the inbox's drain through ``scheduler.admit`` to the first
+# ``engine.prefill`` (tagged ``admitted=<n>``); ``_close_step`` whole, once a
+# read step; the caller's ``on_step``; the idle slice's sleep (nothing active,
+# nothing queued).  With ``vs.serve-sample``, ``vs.serve-decode`` and
+# ``vs.serve-prefill`` they cover an iteration but for the loop's own lines.
+SERVE_BOUNDARY = "vs.serve-boundary"
+SERVE_ADMIT = "vs.serve-admit"
+SERVE_BOOKS = "vs.serve-books"
+SERVE_HOOK = "vs.serve-hook"
+SERVE_IDLE = "vs.serve-idle"
+# after the fact, beside serve-queue-wait and with the request's rid: from
+# ``RequestInbox.push`` (which stamps the request as it queues it) to the
+# loop's drain that took it.  inbox-wait + queue-wait + prefill tile a
+# network-fed request's time to its first token.
+SERVE_INBOX_WAIT = "serve-inbox-wait"
 # the one annotation a trace session (ndtimeline/api.py) emits at its start:
 # its instant is known on the spans' clock and on the trace's
 SESSION_MARK = "vs.session-mark"

@@ -35,6 +35,35 @@ class Span:
     tags: Optional[Dict[str, Any]] = None
 
 
+class _Timed:
+    """One live span (``NDTimerManager.timeit``)."""
+
+    __slots__ = ("_mgr", "_metric", "_tags", "_ann", "_t0")
+
+    def __init__(self, mgr: "NDTimerManager", metric: str, tags: Optional[Dict[str, Any]]):
+        self._mgr, self._metric, self._tags = mgr, metric, tags
+
+    def __enter__(self):
+        self._ann = jax.profiler.TraceAnnotation(self._metric, **self._tags) if self._tags else \
+            jax.profiler.TraceAnnotation(self._metric)
+        self._ann.__enter__()
+        self._t0 = time.time()
+        return self
+
+    def tag(self, **ids) -> None:
+        """Identifiers known only once the region runs (``admitted=<n>``):
+        onto the ring's span and the open annotation alike.  Dormant,
+        ``ndtimeit`` yields None and a site tests for that."""
+        self._tags = {**self._tags, **ids} if self._tags else ids
+        self._ann.set_metadata(**ids)
+
+    def __exit__(self, *exc):
+        dur = time.time() - self._t0
+        self._ann.__exit__(*exc)
+        self._mgr.record(self._metric, self._t0, dur, self._tags)
+        return False
+
+
 class NDTimerManager:
     """Collects spans into a bounded ring buffer; flush() drains to
     handlers.  Thread-safe; nestable via context managers."""
@@ -79,23 +108,17 @@ class NDTimerManager:
 
     def timeit(self, metric: str, tags=None):
         """Context manager measuring a host region + annotating the device
-        trace (shows up in XLA profiler captures)."""
-        mgr = self
+        trace (shows up in XLA profiler captures).  ``tags`` go to the ring's
+        span and, as the event's stats, to the ``TraceAnnotation``: a span's
+        identifiers (``launch=<n>``) are in the trace and in the ring alike.
 
-        class _Ctx:
-            def __enter__(self):
-                self._ann = jax.profiler.TraceAnnotation(metric)
-                self._ann.__enter__()
-                self._t0 = time.time()
-                return self
-
-            def __exit__(self, *exc):
-                dur = time.time() - self._t0
-                self._ann.__exit__(*exc)
-                mgr.record(metric, self._t0, dur, tags)
-                return False
-
-        return _Ctx()
+        Two clocks, two records: the ring's span is read on ``time.time()``
+        INSIDE the annotation, so it is shorter than the annotation by the
+        annotation's own cost (a microsecond or two while a profiler runs).
+        A reader that lays a span over the device's events trusts the
+        annotation, which is on the trace's clock; a reader of durations
+        trusts the ring, which leaves the tracing's own cost out."""
+        return _Timed(self, metric, tags)
 
     def decorator(self, metric: str):
         def deco(fn):

@@ -277,12 +277,25 @@ class DecodeAhead:
         # every form of ``tokens`` reaches the decode program as a device array of the sharding its own ids
         # have (the host's through a device_put): one signature, so one executable, whatever feeds a step
         self._ids_sharding = ids_sharding
-        self._merge_fn = jax.jit(lambda ids, tokens, fresh: jnp.where(fresh, tokens, ids), out_shardings=ids_sharding)
+
+        def decode_merge(ids, tokens, fresh):   # named for the ``XLA Modules`` line: a step's programs begin ``jit_decode``
+            return jnp.where(fresh, tokens, ids)
+
+        self._merge_fn = jax.jit(decode_merge, out_shardings=ids_sharding)
+        # launches (``decode`` / ``prefill`` calls that enqueued their programs; ``warm`` counts none): their sum
+        # is the NUMBER a launch carries to what it causes, one sequence for both kinds (``launches``)
+        self.decode_launches = 0
+        self.prefill_launches = 0
         self.decode_steps = 0
         self.decode_steps_ahead = 0
         self.logits_bytes_to_host = 0
         self.decode_pages_read = 0      # counted only where the paged_decode kernel was built
         self.decode_pages_capacity = 0
+
+    @property
+    def launches(self) -> int:
+        """The number the next launch takes (``launch=<n>`` on its spans)."""
+        return self.decode_launches + self.prefill_launches
 
     def _host_tokens(self, tokens):
         import jax
@@ -337,10 +350,13 @@ class DecodeAhead:
         lengths = cache.lengths_array()
         before = tokens.step if isinstance(tokens, DecodeFeed) else None
         with ndtimeit(_p.SERVE_DECODE_CALL):
-            logits, ids, counts = self._run_decode(cache.table_array(), lengths, self._fed(tokens))
+            n = self.launches
+            with ndtimeit(_p.SERVE_DECODE_LAUNCH, launch=n):    # the enqueue alone
+                logits, ids, counts = self._run_decode(cache.table_array(), lengths, self._fed(tokens))
+            self.decode_launches += 1
             ahead = before is not None and not before.read
             rows, yields = self._note(tokens, lengths)
-            out = DecodeStep(ids, logits, self, (lengths, counts, ahead, yields), rows)
+            out = DecodeStep(ids, logits, self, (lengths, counts, ahead, yields, n), rows)
             if ahead:
                 self._read_step(before)     # the device goes from that step straight into this one
         return out
@@ -348,8 +364,9 @@ class DecodeAhead:
     def _read_step(self, step: DecodeStep) -> None:
         import jax
 
-        lengths, counts, ahead, yields = step._launch
-        with ndtimeit(_p.SERVE_DECODE_FETCH):   # waits for the device, then copies the ids (and the step's counts)
+        lengths, counts, ahead, yields, n = step._launch
+        # waits for the device, then copies the ids (and the step's counts); ``launch`` names the span that caused it
+        with ndtimeit(_p.SERVE_DECODE_FETCH, launch=n):
             step._tokens, counts = jax.device_get((step._ids, counts))
         step._launch = None
         self.decode_steps += 1
@@ -648,7 +665,7 @@ class ServeEngine(DecodeAhead):
         block = jax.jit(block_prefill)
 
         def make_stage(lo, hi):
-            def stage(params, x, positions):
+            def prefill_stage(params, x, positions):
                 ks, vs = [], []
                 for l in range(lo, hi):
                     x, k, v = block(params[f"layers_{l}"], x, positions)
@@ -656,19 +673,24 @@ class ServeEngine(DecodeAhead):
                     vs.append(v)
                 return x, jnp.stack(ks), jnp.stack(vs)
 
-            return jax.jit(stage)
+            return jax.jit(prefill_stage)
 
-        self._embed_fn = jax.jit(lambda p, toks: embed(p, toks)[None])
+        # the programs' names tell their kind on the ``XLA Modules`` line: every program of a prefill begins
+        # ``jit_prefill``, every program of a decode step ``jit_decode``
+        def prefill_embed(p, toks):
+            return embed(p, toks)[None]
+
+        self._embed_fn = jax.jit(prefill_embed)
         self._stage_fns = [make_stage(lo, hi) for lo, hi in self.stage_bounds]
 
-        def head_last(params, x, length):
+        def prefill_head(params, x, length):
             last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1, keepdims=False)
             logits = head(params, last)[0]
             return jax.lax.with_sharding_constraint(logits, rep_sharding)
 
-        self._head_fn = jax.jit(head_last)
+        self._head_fn = jax.jit(prefill_head)
 
-        def commit_prefill(kd, vd, k_stack, v_stack, page_row):
+        def prefill_commit(kd, vd, k_stack, v_stack, page_row):
             # (L, rung, KV, hd) -> the rung's pages scattered into the pool
             # (page_row is the slot's first rung // page table entries);
             # entries beyond the reserved pages are 0 = the null page
@@ -681,7 +703,7 @@ class ServeEngine(DecodeAhead):
                 jax.lax.with_sharding_constraint(vd, cache_sharding),
             )
 
-        self._commit_fn = jax.jit(commit_prefill, donate_argnums=(0, 1))
+        self._commit_fn = jax.jit(prefill_commit, donate_argnums=(0, 1))
 
         def paged_attention(q, kd, vd, layer, table, valid_len):
             # q (S,H,hd); kd/vd (L,N,page,KV,hd); table (S,Pmax); valid (S,)
@@ -908,11 +930,14 @@ class ServeEngine(DecodeAhead):
             self.warm()
         rung = next(b for b in self.buckets if b >= n)
         with ndtimeit(_p.SERVE_PREFILL_CALL):
-            toks = np.zeros((rung,), np.int32)
-            toks[:n] = np.asarray(prompt, np.int32)
-            page_row = cache.page_table[slot, : rung // cache.config.page_size].copy()
-            logits = self._run_prefill(toks, n, page_row)
-            with ndtimeit(_p.SERVE_PREFILL_FETCH):   # waits for the device, then copies the row
+            launch = self.launches
+            with ndtimeit(_p.SERVE_PREFILL_LAUNCH, launch=launch, rung=rung, slot=slot):    # the enqueue alone
+                toks = np.zeros((rung,), np.int32)
+                toks[:n] = np.asarray(prompt, np.int32)
+                page_row = cache.page_table[slot, : rung // cache.config.page_size].copy()
+                logits = self._run_prefill(toks, n, page_row)
+            self.prefill_launches += 1
+            with ndtimeit(_p.SERVE_PREFILL_FETCH, launch=launch):   # waits for the device, then copies the row
                 out = np.asarray(logits)
         self.prefill_calls += 1
         self.prefill_tokens_real += n
@@ -921,7 +946,13 @@ class ServeEngine(DecodeAhead):
 
     def trace_counters(self) -> Dict[str, int]:
         """The engine's own counts since it was built (a trace session
-        reports what was added while it ran).  ``decode_steps`` counts the
+        reports what was added while it ran).  ``decode_launches`` and
+        ``prefill_launches`` count the ``decode`` / ``prefill`` calls that
+        enqueued their programs (their sum numbers the launches: the
+        ``launch=<n>`` tag of ``vs.serve-decode.launch``,
+        ``vs.serve-prefill.launch`` and the ``.fetch`` that reads each); a step
+        launched inside a session and read after it is in ``decode_launches``
+        and not in ``decode_steps``.  ``decode_steps`` counts the
         decode steps READ (a ``decode`` call launches its step; whoever reads
         its ids first counts it), ``decode_steps_ahead`` those of them that
         were launched while the step before was still unread (the pipeline
@@ -937,7 +968,8 @@ class ServeEngine(DecodeAhead):
         engaged: the pages of K (and as many of V) it fetched a layer, summed
         over ``decode`` calls, against the ``slots x pages_per_slot`` the XLA
         leg gathers; both stay 0 on an engine built with the XLA leg."""
-        return {"decode_steps": self.decode_steps, "decode_steps_ahead": self.decode_steps_ahead,
+        return {"decode_launches": self.decode_launches, "prefill_launches": self.prefill_launches,
+                "decode_steps": self.decode_steps, "decode_steps_ahead": self.decode_steps_ahead,
                 "logits_bytes_to_host": self.logits_bytes_to_host,
                 "prefill_calls": self.prefill_calls,
                 "prefill_tokens_real": self.prefill_tokens_real,

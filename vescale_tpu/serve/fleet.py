@@ -39,7 +39,7 @@ import subprocess
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Deque, Dict, List, Optional, Sequence
+from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 from .router import request_from_payload
 from .scheduler import TERMINAL, ContinuousBatchingScheduler, Request
@@ -55,11 +55,14 @@ __all__ = [
 class RequestInbox:
     """Thread-safe request hand-off: the ops thread pushes, the serve
     loop drains at step boundaries.  ``close()`` lets a driver end an
-    inbox-fed loop cleanly (the loop exits once everything is terminal)."""
+    inbox-fed loop cleanly (the loop exits once everything is terminal).
+    ``push`` stamps ``time.perf_counter()`` beside the request it queues:
+    the anchor of the ``serve-inbox-wait`` span, which the loop records at
+    the drain that takes the request (``drain_stamped``)."""
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._pending: Deque[Request] = deque()
+        self._pending: Deque[Tuple[Request, float]] = deque()
         self._closed = False
         self.pushed_total = 0
 
@@ -67,15 +70,19 @@ class RequestInbox:
         with self._lock:
             if self._closed:
                 return False
-            self._pending.append(req)
+            self._pending.append((req, time.perf_counter()))
             self.pushed_total += 1
             return True
 
-    def drain(self) -> List[Request]:
+    def drain_stamped(self) -> List[Tuple[Request, float]]:
+        """Everything pushed since the last drain, each with its push instant."""
         with self._lock:
             out = list(self._pending)
             self._pending.clear()
         return out
+
+    def drain(self) -> List[Request]:
+        return [req for req, _ in self.drain_stamped()]
 
     def close(self) -> None:
         with self._lock:

@@ -132,10 +132,10 @@ from .kv_cache import KVCacheConfig, PagedKVCache
 __all__ = ["HybridServeEngine", "hybrid_cache_config", "prefill_buckets"]
 
 # what the engine counts for every model (``trace_counters``); a model's own follow (``STEP_COUNTERS``)
-COUNTERS = ("decode_steps", "decode_steps_ahead", "logits_bytes_to_host", "prefill_tokens_real", "prefill_tokens_padded",
-            "prefill_bucket_tokens", "decode_pages_read", "decode_pages_capacity", "moe_assignments",
-            "moe_assignments_held", "moe_busiest_expert_tokens", "moe_expert_slots", "moe_layer_steps",
-            "moe_experts_touched", "moe_padded_layer_steps")
+COUNTERS = ("decode_launches", "prefill_launches", "decode_steps", "decode_steps_ahead", "logits_bytes_to_host",
+            "prefill_tokens_real", "prefill_tokens_padded", "prefill_bucket_tokens", "decode_pages_read",
+            "decode_pages_capacity", "moe_assignments", "moe_assignments_held", "moe_busiest_expert_tokens",
+            "moe_expert_slots", "moe_layer_steps", "moe_experts_touched", "moe_padded_layer_steps")
 # ... and for a model that generates by blocks: slot-passes of the slots a pass moved, those of them that were
 # commit passes, the tokens the host took, and the query rows that still had something to decide (masked
 # positions at the start of their pass), each summed over the passes read
@@ -313,11 +313,14 @@ class HybridServeEngine(DecodeAhead):
             raise ValueError(f"prompt length {n} not in (0, {cache.max_seq_len}]")
         bucket = next(b for b in self.buckets if b >= n)
         with ndtimeit(_p.SERVE_PREFILL_CALL):
-            toks = np.zeros((bucket,), np.int32)
-            toks[:n] = np.asarray(prompt, np.int32)
-            page_row = cache.page_table[slot, : bucket // cache.config.page_size].copy()
-            logits = self._run_prefill(toks, n, page_row, slot)
-            with ndtimeit(_p.SERVE_PREFILL_FETCH):
+            launch = self.launches
+            with ndtimeit(_p.SERVE_PREFILL_LAUNCH, launch=launch, rung=bucket, slot=slot):  # the enqueue alone
+                toks = np.zeros((bucket,), np.int32)
+                toks[:n] = np.asarray(prompt, np.int32)
+                page_row = cache.page_table[slot, : bucket // cache.config.page_size].copy()
+                logits = self._run_prefill(toks, n, page_row, slot)
+            self.prefill_launches += 1
+            with ndtimeit(_p.SERVE_PREFILL_FETCH, launch=launch):
                 out = np.asarray(logits)
         self.prefill_tokens_real += n
         self.prefill_tokens_padded += bucket
@@ -351,7 +354,8 @@ class HybridServeEngine(DecodeAhead):
 
     def trace_counters(self) -> Dict[str, int]:
         """The engine's own counts since it was built.  Those ``ServeEngine``
-        has mean the same here (``decode_steps`` / ``decode_steps_ahead`` are
+        has mean the same here (``decode_launches`` / ``prefill_launches`` are
+        of calls that enqueued; ``decode_steps`` / ``decode_steps_ahead`` are
         of steps read; ``logits_bytes_to_host`` is what callers copied
         out of ``decode``'s results; ``prefill_tokens_padded`` is the bucket;
         ``decode_pages_*`` are a layer's, and count only with the kernel leg).

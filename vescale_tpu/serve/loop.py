@@ -263,7 +263,7 @@ def run_serve_resilient(
     from .. import telemetry as _tel
     from ..analysis import envreg
     from ..ndtimeline import api as _nd
-    from ..ndtimeline.predefined import SERVE_SAMPLE as _SERVE_SAMPLE
+    from ..ndtimeline import predefined as _p
     from ..telemetry import costaudit as _ca
     from ..telemetry import ops_server as _ops
 
@@ -576,7 +576,10 @@ def run_serve_resilient(
         first sampled token is recorded immediately (its latency IS the
         TTFT).  Where a step moves a block the prefill yields no token: it
         opens the slot's first block, and the TTFT comes with that block."""
-        admitted = scheduler.admit(step)
+        with _nd.ndtimeit(_p.SERVE_ADMIT) as span:      # the scheduler's and the allocator's work
+            admitted = scheduler.admit(step)
+            if span is not None:
+                span.tag(admitted=len(admitted))
         for inf in admitted:
             _beat(step, "prefill")
             inf.admit_wall = time.perf_counter()
@@ -674,7 +677,7 @@ def run_serve_resilient(
         # plain ints, a row a slot (of one id, or of a block's): read once
         next_ids = dstep.tokens.reshape(cache.num_slots, -1).tolist()
         kept: Dict[int, Tuple[Any, int]] = {}
-        with _nd.ndtimeit(_SERVE_SAMPLE):
+        with _nd.ndtimeit(_p.SERVE_SAMPLE):
             for slot in sorted(slots):
                 inf, skip, count = slots[slot]
                 if scheduler.active.get(slot) is not inf:
@@ -700,53 +703,54 @@ def run_serve_resilient(
         ``dt`` is the wall time the host spent on it: with a step in flight
         the wait for it, so a step's period and each slot's inter-token
         latency.  The device works on the next step meanwhile."""
-        if predicted_s is not None:
-            pid = _ca.record_prediction(
-                "serve_step", predicted_us=predicted_s * 1e6,
-                detail={"active": width},
-            )
-            _ca.record_measurement(pid, measured_us=dt * 1e6)
-        scheduler.observe_step_time(dt)
-        reqtrace.decode_step(step, dt, width)
-        for slot, (inf, m) in emitted.items():
-            # a step that gave a slot several tokens (a speculative verify,
-            # a block's commit pass) amortizes over them its wall and that of
-            # the steps since the slot's last token that gave it none (a
-            # block's denoising passes): a token's latency is its share of
-            # the time its request waited for it
-            inf.unyielded_s += dt
-            if not m:
-                continue
-            per_tok, inf.unyielded_s = inf.unyielded_s / m, 0.0
-            for j in range(m):
-                scheduler.observe_itl(per_tok)
-                reqtrace.decode_token(
-                    inf.req.rid, slot, len(inf.tokens) - m + j, per_tok
+        with _nd.ndtimeit(_p.SERVE_BOOKS):
+            if predicted_s is not None:
+                pid = _ca.record_prediction(
+                    "serve_step", predicted_us=predicted_s * 1e6,
+                    detail={"active": width},
                 )
-        _tel.count("serve_decode_steps_total")
-        obs.on_decode_step(step, dt, width)
-        _finish_done(step)
-        # serve's auto_inc_step: every span emitted since the last line
-        # (prefill, decode, terminals) carries the CURRENT profiler step —
-        # advance the counter and record the per-step line NOW so the
-        # steps.jsonl spans rollup attributes them to this decode step, not
-        # a stale training step
-        if _nd.is_active():
-            mgr = _nd.get_manager()
-            span_step = mgr.step
-            mgr.inc_step()
-        else:
-            span_step = step
-        _tel.record_step(
-            {
-                "step": span_step,
-                "serve_step": step,
-                "step_time_s": dt,
-                "active": width,
-                "queue_depth": len(scheduler.queue),
-            },
-            kind="serve",
-        )
+                _ca.record_measurement(pid, measured_us=dt * 1e6)
+            scheduler.observe_step_time(dt)
+            reqtrace.decode_step(step, dt, width)
+            for slot, (inf, m) in emitted.items():
+                # a step that gave a slot several tokens (a speculative verify,
+                # a block's commit pass) amortizes over them its wall and that of
+                # the steps since the slot's last token that gave it none (a
+                # block's denoising passes): a token's latency is its share of
+                # the time its request waited for it
+                inf.unyielded_s += dt
+                if not m:
+                    continue
+                per_tok, inf.unyielded_s = inf.unyielded_s / m, 0.0
+                for j in range(m):
+                    scheduler.observe_itl(per_tok)
+                    reqtrace.decode_token(
+                        inf.req.rid, slot, len(inf.tokens) - m + j, per_tok
+                    )
+            _tel.count("serve_decode_steps_total")
+            obs.on_decode_step(step, dt, width)
+            _finish_done(step)
+            # serve's auto_inc_step: every span emitted since the last line
+            # (prefill, decode, terminals) carries the CURRENT profiler step —
+            # advance the counter and record the per-step line NOW so the
+            # steps.jsonl spans rollup attributes them to this decode step, not
+            # a stale training step
+            if _nd.is_active():
+                mgr = _nd.get_manager()
+                span_step = mgr.step
+                mgr.inc_step()
+            else:
+                span_step = step
+            _tel.record_step(
+                {
+                    "step": span_step,
+                    "serve_step": step,
+                    "step_time_s": dt,
+                    "active": width,
+                    "queue_depth": len(scheduler.queue),
+                },
+                kind="serve",
+            )
 
     def _settle(step: int) -> None:
         """Read the step in flight, if there is one, and complete what it
@@ -770,152 +774,159 @@ def run_serve_resilient(
                     f"serve loop exceeded max_steps={max_steps} with "
                     f"{len(scheduler.queue)} queued / {len(scheduler.active)} active"
                 )
-            _fs.set_step(step)
-            _beat(step, "boundary")
-            # liveness, not just decode progress: the /router feed's
-            # serve_step advances every boundary, so a fleet router can
-            # tell "idle" from "wedged" (stale-feed breaker trip)
-            obs.serve_step = step
-            if _fs.fires("hang", ctx=f"serve_step{step}"):
-                # wedged decode: stall past every deadline — the watchdog's
-                # detect/dump/abort path is the only way out, as in training
-                time.sleep(envreg.get_float("VESCALE_FAULTSIM_HANG_S"))
-            if _fs.fires("preempt", ctx=f"serve_step{step}"):
-                handler.request()
-            oom_fired = _fs.fires("oom", ctx=f"serve_step{step}")
-            rt_fired = _fs.fires("request_timeout", ctx=f"serve_step{step}")
+            # what the top of an iteration does before admission; a step that a boundary reads first
+            # (``_settle``) nests its ``.fetch``, ``vs.serve-sample`` and ``vs.serve-books`` inside
+            with _nd.ndtimeit(_p.SERVE_BOUNDARY):
+                _fs.set_step(step)
+                _beat(step, "boundary")
+                # liveness, not just decode progress: the /router feed's
+                # serve_step advances every boundary, so a fleet router can
+                # tell "idle" from "wedged" (stale-feed breaker trip)
+                obs.serve_step = step
+                if _fs.fires("hang", ctx=f"serve_step{step}"):
+                    # wedged decode: stall past every deadline — the watchdog's
+                    # detect/dump/abort path is the only way out, as in training
+                    time.sleep(envreg.get_float("VESCALE_FAULTSIM_HANG_S"))
+                if _fs.fires("preempt", ctx=f"serve_step{step}"):
+                    handler.request()
+                oom_fired = _fs.fires("oom", ctx=f"serve_step{step}")
+                rt_fired = _fs.fires("request_timeout", ctx=f"serve_step{step}")
 
-            # ------------------------------------------------ arrivals
-            while (
-                not draining
-                and next_arrival < len(arrivals)
-                and arrivals[next_arrival][0] <= step
-            ):
-                _, req = arrivals[next_arrival]
-                next_arrival += 1
-                scheduler.submit(req, step)
-            if inbox is not None:
-                # network submissions (fleet mode): drained at the step
-                # boundary so scheduler state stays single-threaded; a
-                # malformed/duplicate wire submission is rejected and
-                # counted, never allowed to kill the serving loop.
-                # Mid-drain arrivals still enter the ledger — the exit
-                # flush below terminates them preempted_requeue.
-                for req in inbox.drain():
-                    try:
-                        scheduler.submit(req, step)
-                    except ValueError as e:
-                        _tel.count("serve_inbox_rejected_total")
-                        _event("inbox_reject", rid=getattr(req, "rid", -1),
-                               at_step=step, error=str(e))
+                # ------------------------------------------------ arrivals
+                while (
+                    not draining
+                    and next_arrival < len(arrivals)
+                    and arrivals[next_arrival][0] <= step
+                ):
+                    _, req = arrivals[next_arrival]
+                    next_arrival += 1
+                    scheduler.submit(req, step)
+                if inbox is not None:
+                    # network submissions (fleet mode): drained at the step
+                    # boundary so scheduler state stays single-threaded; a
+                    # malformed/duplicate wire submission is rejected and
+                    # counted, never allowed to kill the serving loop.
+                    # Mid-drain arrivals still enter the ledger — the exit
+                    # flush below terminates them preempted_requeue.
+                    for req, pushed_at in inbox.drain_stamped():
+                        try:
+                            scheduler.submit(req, step)
+                        except ValueError as e:
+                            _tel.count("serve_inbox_rejected_total")
+                            _event("inbox_reject", rid=getattr(req, "rid", -1),
+                                   at_step=step, error=str(e))
+                        else:
+                            reqtrace.inbox_wait(req.rid, pushed_at)
 
-            # -------------------------------------------- weight rollout
-            if control is not None:
-                if reload_job is None:
-                    reload_job = control.take()
+                # -------------------------------------------- weight rollout
+                if control is not None:
+                    if reload_job is None:
+                        reload_job = control.take()
+                        if reload_job is not None:
+                            reload_t0 = time.perf_counter()
+                            if reload_job.get("op", "reload") != "commit":
+                                # admission pauses from here (the /router feed
+                                # drops `accepting`); in-flight decodes out
+                                _rollout_state("draining", step,
+                                               inflight=len(scheduler.active))
                     if reload_job is not None:
-                        reload_t0 = time.perf_counter()
-                        if reload_job.get("op", "reload") != "commit":
-                            # admission pauses from here (the /router feed
-                            # drops `accepting`); in-flight decodes out
-                            _rollout_state("draining", step,
-                                           inflight=len(scheduler.active))
-                if reload_job is not None:
-                    # the drain is judged by what the device has made
+                        # the drain is judged by what the device has made
+                        _settle(step)
+                        op = reload_job.get("op", "reload")
+                        if op == "commit" or not scheduler.active:
+                            if op != "commit":
+                                _ftrace.rollout_stage(
+                                    obs.replica_id, "drain",
+                                    time.perf_counter() - reload_t0,
+                                )
+                            _perform_reload(step)
+                            reload_job = None
+
+                # ------------------------------------------- control plane
+                # wall-deadline verdicts are rank-LOCAL clock reads: compute
+                # before the exchange so every rank applies the OR-agreed set
+                # (one rank's clock crossing the budget must not desync peers)
+                wall_mask = 0
+                for slot in scheduler.wall_expired_slots(time.perf_counter(), wall_deadline_s):
+                    wall_mask |= 1 << slot
+                if coord:
+                    preempt_now, oom_fired, rt_fired, wall_mask = _coordinate(
+                        step, oom_fired, rt_fired, wall_mask
+                    )
+                else:
+                    preempt_now = handler.requested()
+
+                # a boundary that may evict, cancel, begin the drain or find
+                # nothing left to step first reads the step in flight, so its
+                # verdict is the one the tokens give (flags are the agreed ones:
+                # every rank settles at the same boundaries)
+                if pending is not None and (
+                    oom_fired or rt_fired or wall_mask
+                    or (preempt_now and not draining)
+                    or scheduler.step_deadline_due(step)
+                    or not scheduler.active
+                ):
                     _settle(step)
-                    op = reload_job.get("op", "reload")
-                    if op == "commit" or not scheduler.active:
-                        if op != "commit":
-                            _ftrace.rollout_stage(
-                                obs.replica_id, "drain",
-                                time.perf_counter() - reload_t0,
-                            )
-                        _perform_reload(step)
-                        reload_job = None
 
-            # ------------------------------------------- control plane
-            # wall-deadline verdicts are rank-LOCAL clock reads: compute
-            # before the exchange so every rank applies the OR-agreed set
-            # (one rank's clock crossing the budget must not desync peers)
-            wall_mask = 0
-            for slot in scheduler.wall_expired_slots(time.perf_counter(), wall_deadline_s):
-                wall_mask |= 1 << slot
-            if coord:
-                preempt_now, oom_fired, rt_fired, wall_mask = _coordinate(
-                    step, oom_fired, rt_fired, wall_mask
+                # ------------------------------------------------- faults
+                if oom_fired and scheduler.active:
+                    # mid-batch OOM: evict the newest request, replay it later
+                    # — the batch survives, nothing is lost
+                    victim = scheduler.requeue_newest(reason="injected oom")
+                    _event("oom_evict", rid=victim, at_step=step)
+                force_slots: List[int] = []
+                if rt_fired and scheduler.active:
+                    # the OLDEST in-flight request's deadline is forced expired
+                    force_slots = [min(scheduler.active,
+                                       key=lambda s: (scheduler.active[s].admit_step, s))]
+
+                # ------------------------------------- timeout cancellation
+                scheduler.timeout_queued(step)
+                wall_slots = [s for s in range(cache.num_slots) if wall_mask & (1 << s)]
+                expired = scheduler.expire_active(
+                    step, force_slots=force_slots, wall_slots=wall_slots,
                 )
-            else:
-                preempt_now = handler.requested()
+                for rid in expired:
+                    _event("request_timeout", rid=rid, at_step=step)
 
-            # a boundary that may evict, cancel, begin the drain or find
-            # nothing left to step first reads the step in flight, so its
-            # verdict is the one the tokens give (flags are the agreed ones:
-            # every rank settles at the same boundaries)
-            if pending is not None and (
-                oom_fired or rt_fired or wall_mask
-                or (preempt_now and not draining)
-                or scheduler.step_deadline_due(step)
-                or not scheduler.active
-            ):
-                _settle(step)
-
-            # ------------------------------------------------- faults
-            if oom_fired and scheduler.active:
-                # mid-batch OOM: evict the newest request, replay it later
-                # — the batch survives, nothing is lost
-                victim = scheduler.requeue_newest(reason="injected oom")
-                _event("oom_evict", rid=victim, at_step=step)
-            force_slots: List[int] = []
-            if rt_fired and scheduler.active:
-                # the OLDEST in-flight request's deadline is forced expired
-                force_slots = [min(scheduler.active,
-                                   key=lambda s: (scheduler.active[s].admit_step, s))]
-
-            # ------------------------------------- timeout cancellation
-            scheduler.timeout_queued(step)
-            wall_slots = [s for s in range(cache.num_slots) if wall_mask & (1 << s)]
-            expired = scheduler.expire_active(
-                step, force_slots=force_slots, wall_slots=wall_slots,
-            )
-            for rid in expired:
-                _event("request_timeout", rid=rid, at_step=step)
-
-            # ------------------------------------------------ drain / done
-            if preempt_now and not draining:
-                draining = True
-                obs.draining = True  # /healthz reports the drain live
-                _tel.count("resilience_preemptions_total")
-                _event("drain_begin", at_step=step,
-                       inflight=len(scheduler.active), queued=len(scheduler.queue))
-                result.rejected_on_drain = len(scheduler.reject_queued("preempted"))
-            if draining and not scheduler.active:
-                # a mid-drain eviction may have requeued its victim: flush
-                # it as re-queueable too — the ledger must end all-terminal
-                result.rejected_on_drain += len(scheduler.reject_queued("preempted"))
-                result.status = "preempted"
-                break
-            if (
-                not draining
-                and next_arrival >= len(arrivals)
-                and (inbox is None or inbox.closed)
-                and scheduler.all_terminal()
-            ):
-                # close() may have raced this iteration's drain: anything
-                # push()ed before the close is still owed service — drain
-                # once more and only exit when the inbox is truly empty
-                # (push-after-close is refused at push(), so this final
-                # drain is exhaustive)
-                late = inbox.drain() if inbox is not None else ()
-                if not late:
-                    result.status = "completed"
+                # ------------------------------------------------ drain / done
+                if preempt_now and not draining:
+                    draining = True
+                    obs.draining = True  # /healthz reports the drain live
+                    _tel.count("resilience_preemptions_total")
+                    _event("drain_begin", at_step=step,
+                           inflight=len(scheduler.active), queued=len(scheduler.queue))
+                    result.rejected_on_drain = len(scheduler.reject_queued("preempted"))
+                if draining and not scheduler.active:
+                    # a mid-drain eviction may have requeued its victim: flush
+                    # it as re-queueable too — the ledger must end all-terminal
+                    result.rejected_on_drain += len(scheduler.reject_queued("preempted"))
+                    result.status = "preempted"
                     break
-                for req in late:
-                    try:
-                        scheduler.submit(req, step)
-                    except ValueError as e:
-                        _tel.count("serve_inbox_rejected_total")
-                        _event("inbox_reject", rid=getattr(req, "rid", -1),
-                               at_step=step, error=str(e))
+                if (
+                    not draining
+                    and next_arrival >= len(arrivals)
+                    and (inbox is None or inbox.closed)
+                    and scheduler.all_terminal()
+                ):
+                    # close() may have raced this iteration's drain: anything
+                    # push()ed before the close is still owed service — drain
+                    # once more and only exit when the inbox is truly empty
+                    # (push-after-close is refused at push(), so this final
+                    # drain is exhaustive)
+                    late = inbox.drain_stamped() if inbox is not None else ()
+                    if not late:
+                        result.status = "completed"
+                        break
+                    for req, pushed_at in late:
+                        try:
+                            scheduler.submit(req, step)
+                        except ValueError as e:
+                            _tel.count("serve_inbox_rejected_total")
+                            _event("inbox_reject", rid=getattr(req, "rid", -1),
+                                   at_step=step, error=str(e))
+                        else:
+                            reqtrace.inbox_wait(req.rid, pushed_at)
 
             # ---------------------------------------------- admit + decode
             if speculative is not None:
@@ -1070,7 +1081,8 @@ def run_serve_resilient(
                     # the one launched before)
                     _close_step(step, dt, width, emitted, predicted_step_s)
             if on_step is not None:
-                on_step(step, len(scheduler.active))
+                with _nd.ndtimeit(_p.SERVE_HOOK):
+                    on_step(step, len(scheduler.active))
             if (
                 inbox is not None
                 and not draining
@@ -1085,7 +1097,8 @@ def run_serve_resilient(
                 if idle_sleep_s is None:
                     idle_sleep_s = envreg.get_float("VESCALE_SERVE_IDLE_S")
                 if idle_sleep_s:
-                    time.sleep(idle_sleep_s)
+                    with _nd.ndtimeit(_p.SERVE_IDLE):      # no request to serve: the chip's idle here has a name
+                        time.sleep(idle_sleep_s)
             if fleet_trace_every and step % fleet_trace_every == 0:
                 # crash-durable tracing: this boundary's spans reach the
                 # raw stream before the next decode step can kill us

@@ -3,9 +3,10 @@
 Training earned a cross-rank trace timeline in PR 9; this module gives
 every *serving* request the same treatment: a span chain
 
-    submit -> [queue-wait -> prefill -> decode-token[i]*]* -> terminal
+    [inbox-wait ->] submit -> [queue-wait -> prefill -> decode-token[i]*]* -> terminal
 
-emitted through the existing ndtimeline span machinery (Span objects into
+(``inbox-wait`` only for a request that came through a ``RequestInbox``: from
+its push to the loop's drain that submitted it) emitted through the existing ndtimeline span machinery (Span objects into
 the global ``NDTimerManager`` ring), so per-rank streams merge with
 ``telemetry.trace.merge_traces`` + PR-9 clock offsets into ONE Perfetto
 timeline.  Rendering contract (ChromeTraceHandler):
@@ -44,6 +45,7 @@ from ..ndtimeline.api import get_manager, is_active
 __all__ = [
     "SERVE_SPAN_METRICS",
     "TERMINAL_OUTCOMES",
+    "inbox_wait",
     "submit",
     "queue_wait",
     "prefill",
@@ -61,6 +63,7 @@ __all__ = [
 # the full serve request-lifecycle span vocabulary (docs/observability.md)
 SERVE_SPAN_METRICS = frozenset(
     (
+        _p.SERVE_INBOX_WAIT,
         _p.SERVE_SUBMIT,
         _p.SERVE_QUEUE_WAIT,
         _p.SERVE_PREFILL,
@@ -89,6 +92,21 @@ def _record(metric: str, start: float, duration: float, tags: Dict) -> None:
 # (time.time(), the ndtimeline convention) by subtracting the delta from
 # "now" at emission — the two clocks only need to agree over the span's
 # own length, never absolutely.
+
+def inbox_wait(rid: int, pushed_at: float) -> None:
+    """Emitted at the DRAIN that takes a network-fed request off its
+    ``RequestInbox``, once the scheduler has taken it (a submission it
+    refuses leaves none), covering [push, submitted]: the wait before the
+    scheduler has seen the request at all (a boundary's length, or a decode step's
+    when one is read first), which ``serve-queue-wait`` cannot hold because
+    it begins at the loop's ``submit``.  ``pushed_at`` is the
+    ``perf_counter`` instant ``RequestInbox.push`` stamped (the clock is
+    read here, behind the gate).  No slot yet: host lane."""
+    if not is_active():
+        return
+    wait_s = time.perf_counter() - pushed_at
+    _record(_p.SERVE_INBOX_WAIT, time.time() - wait_s, wait_s, {"rid": rid})
+
 
 def submit(rid: int, step: int, tag: Optional[int] = None) -> None:
     """The chain's root: a zero-duration span at submission, flow SEND.
@@ -285,6 +303,9 @@ def verify_request_chains(
     ``fleettrace.superseded_rids(ledger, replica_id)``.
 
     Completeness per outcome:
+      * every ``serve-inbox-wait`` span (a request that came through a
+        ``RequestInbox``; an arrivals-fed one has none) holds a
+        ``serve-submit`` span of its own at its end;
       * >=1 ``serve-submit`` span and >=1 ``serve-terminal`` span whose
         LAST occurrence's ``outcome`` tag equals the ledger status
         (a resubmitted rid legitimately carries older terminal spans, and
@@ -320,6 +341,15 @@ def verify_request_chains(
         subs = c.get(_p.SERVE_SUBMIT, [])
         if not subs:
             problems.append(f"rid {rid}: chain has no submit span")
+        # an inbox wait ends where the loop has submitted the request it took: each holds a submit of its own at
+        # its end (both are stamped on one clock; a millisecond of room for a clock that was stepped)
+        unmatched = [s.start for s in subs]
+        for w in c.get(_p.SERVE_INBOX_WAIT, ()):
+            own = [t for t in unmatched if w.start - 1e-3 <= t <= w.start + w.duration + 1e-3]
+            if not own:
+                problems.append(f"rid {rid}: an inbox-wait span with no submit span at its end")
+                continue
+            unmatched.remove(max(own))
         terms = c.get(_p.SERVE_TERMINAL, [])
         if not terms:
             problems.append(f"rid {rid}: chain has no terminal span")
