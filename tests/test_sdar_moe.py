@@ -247,7 +247,8 @@ def test_the_pipelined_loop_and_a_loop_that_reads_every_step_at_once_give_the_sa
     assert a["block_tokens_emitted"] == sum(m for _, _, m in ARRIVALS) and a["logits_bytes_to_host"] == 0
 
 
-@pytest.mark.parametrize("fault, step", [("oom", 3), ("oom", 7), ("request_timeout", 4), ("request_timeout", 8)])
+@pytest.mark.parametrize("fault, step", [("oom", 3), ("oom", 7), ("request_timeout", 4), ("request_timeout", 8),
+                                         ("request_timeout", 9)])
 def test_an_eviction_and_a_cancel_mid_block_end_all_terminal_and_replay_to_the_same_tokens(system, fault, step):
     """Three requests on free slots from step 0; the boundary that evicts (the
     newest) or cancels (the oldest) first reads the pass in flight, mid-block.
@@ -267,8 +268,9 @@ def test_an_eviction_and_a_cancel_mid_block_end_all_terminal_and_replay_to_the_s
         assert all(o["status"] == "completed" and o["tokens"] == want[rid] for rid, o in res.outcomes.items())
     else:
         assert res.outcomes[0]["status"] == "timed_out" and "request_timeout" in res.outcomes[0]["reason"]
-        # rid 0 (prompt of 8: whole blocks) had ``step`` passes read before the boundary: 4 tokens every 5 passes
-        assert res.outcomes[0]["tokens"] == want[0][: 4 * (step // 5)]
+        # rid 0 (prompt of 8: whole blocks) had ``step`` calls read before the boundary: its first block's 4 tokens
+        # with the fifth (4 denoising passes, then the call that commits it and opens the next), 4 more every 4 calls
+        assert res.outcomes[0]["tokens"] == want[0][: 4 * ((step - 1) // 4)]
         assert all(res.outcomes[rid]["tokens"] == want[rid] for rid in (1, 2))
 
 
@@ -286,8 +288,9 @@ def test_an_eos_inside_a_block_ends_the_request_there(system):
 
 def test_the_counters_of_one_request_are_the_schedules_arithmetic(system, tmp_path):
     """A prompt of 10 (2 revealed in the first block) and a budget of 7: blocks
-    of 2, 4 and 1 of 4 tokens: 3 + 5 + 5 passes, 3 of them commit passes; masked
-    query rows 2 + 1 and twice 4 + 3 + 2 + 1."""
+    of 2, 4 and 1 of 4 tokens: 3 + 5 + 5 units of 4 rows, 3 of them commits;
+    masked query rows 2 + 1 and twice 4 + 3 + 2 + 1.  Two of the commits rode
+    with the first pass of the block after them, so the calls are 2 + 4 + 4 + 1."""
     cfg, _params, _cache, _engine, _limit = system
     nd.start_trace_session(str(tmp_path / "one"), profiler=False)
     try:
@@ -296,12 +299,115 @@ def test_the_counters_of_one_request_are_the_schedules_arithmetic(system, tmp_pa
         c = nd.stop_trace_session().counters
     assert len(res.outcomes[0]["tokens"]) == 7
     assert (c["block_passes"], c["block_commit_passes"], c["block_tokens_emitted"], c["block_positions_masked"]) == (13, 3, 7, 23)
-    assert c["decode_steps"] == 13 and c["moe_layer_steps"] == 13 * cfg.num_hidden_layers
+    assert (c["block_commits_fused"], c["block_commits_deferred"]) == (2, 0)
+    assert c["decode_steps"] == 11 and c["moe_layer_steps"] == 11 * cfg.num_hidden_layers
     # every expert layer of a candidate call fit the pad (4 rows a pass against 128 places): the padded form, each time
     assert c["moe_padded_layer_steps"] == (c["moe_layer_steps"] if _engine._decode_padded_candidate else 0)
     assert c["moe_assignments"] == c["moe_assignments_held"] == 13 * B * cfg.num_experts_per_tok * cfg.num_hidden_layers
     assert c["prefill_attn_flops"] == sd.prefill_counters(cfg, 16)["prefill_attn_flops"] \
         == cfg.num_hidden_layers * FAMILY.block_prefill_attention_flops(TOY, 16)
+    if _engine.kernel_decode:
+        # a page of 8: the slot's rows read 2 pages up to position 16 and 3 past it, a commit place the block's own
+        ends = [12] * 2 + [16, 12] + [16] * 3 + [20, 16] + [20] * 4
+        assert c["decode_pages_read"] == sum(-(-end // PAGE) for end in ends) + 11 * (SLOTS - 1)
+
+
+# ------------------------------------------- a commit in the call that opens the next block
+@pytest.mark.parametrize("n, budget", [(8, 12), (9, 7), (10, 9), (11, 14), (12, 5), (7, 4), (6, 3)],
+                         ids=lambda v: str(v))
+def test_a_request_of_n_blocks_takes_n_t_plus_one_calls_and_its_tokens_are_the_reference_generators(system, n, budget):
+    """Prompts of every residue mod 4 and budgets that end mid-block, one
+    request at a time through the serve loop: every block but the last commits
+    in the call that runs the first pass of the block after it, so the calls are
+    the published loop's passes less one a block but the last (``n T + 1`` for
+    ``n`` whole blocks); the tokens are the unfused replay's, and the reference
+    generator's wherever its own margins vouch for them."""
+    _cfg, params, _cache, engine, limit = system
+    req = Request(rid=0, prompt=tuple(tokens(300 + n, n)), max_new_tokens=budget)
+    want = _golden(system, req)
+    before = engine.trace_counters()
+    res, _ = _run(system, [(0, req)])
+    c = {name: value - before[name] for name, value in engine.trace_counters().items()}
+    assert res.outcomes[0]["tokens"] == want and len(want) == budget
+    reference, passes = FAMILY.generate(params, TOY, req.prompt, budget)
+    blocks = sum(commit for *_rest, commit in passes)
+    assert blocks == -(-(n % B + budget) // B)
+    assert c["decode_steps"] == len(passes) - (blocks - 1) and c["block_passes"] == len(passes)
+    assert (c["block_commit_passes"], c["block_commits_fused"], c["block_commits_deferred"]) == (blocks, blocks - 1, 0)
+    if n % B == 0 and budget % B == 0:
+        assert c["decode_steps"] == blocks * TOY["assumed"]["denoising_steps"] + 1
+    if limit == TIGHT and _vouched(params, req, 20 * TIGHT) is not None:
+        assert reference == want
+
+
+def _pool_rows(cache, slot, upto):
+    """K and V of ``slot``'s positions ``0 .. upto`` as the pool holds them, (2, layers, upto, KV, hd)."""
+    arrays, at = cache.arrays(), np.arange(upto)
+    pages = cache.page_table[slot][at // PAGE]
+    return np.stack([np.asarray(arrays[name], np.float32)[:, pages, at % PAGE] for name in ("k", "v")])
+
+
+@pytest.mark.parametrize("n", [8, 10], ids=lambda n: f"prompt_{n}")
+def test_a_block_committed_in_a_fused_call_leaves_the_teacher_forced_k_and_v_and_the_next_block_reads_them(system, n):
+    """Four blocks generated by hand as the serve loop does it, three of them
+    committed in the call that opens the next: every call's open rows are the
+    reference's rows over the same ids (``check_blocks``' comparison; the first
+    pass of a block reads the block before it as the SAME call wrote it), and at
+    the end the pool holds, position for position, what the host-token form
+    leaves when it is fed the same tokens one at a time (its commits are calls
+    of their own: its commit rows are dead)."""
+    _cfg, params, cache, engine, limit = system
+    schedule, prompt, budget = engine.block, tokens(400 + n, n), 4 * B - n % B
+    cache.reset()
+    slot = cache.alloc(n, budget, slot=1)
+    engine.prefill(prompt, slot)
+    cache.commit_prefill(slot, n)
+    state, settled, out, fused, worst = schedule.open(n), list(prompt), [], 0, 0.0
+    while len(out) < budget:
+        going_in = np.asarray(cache.state["block_ids"][0, slot])
+        fuse = schedule.fuses(state, budget - len(out))
+        skip, count, positions = schedule.plan(state, budget - len(out), fuse)
+        step = engine.decode(DecodeFeed(None, slots={slot: count}, fused=[slot] * fuse))
+        cache.advance(slot, positions)
+        first = len(settled) // B * B
+        if positions:
+            assert step.tokens[slot].tolist() == going_in.tolist(), "a commit returns the block it committed"
+            settled = settled[:first] + going_in.tolist()
+            out += going_in.tolist()[skip: skip + count]
+            first, going_in, fused = first + B, np.full((B,), MASK), fused + fuse
+        if not positions or fuse:       # the call's open rows: a denoising pass (of the block after the committed one)
+            worst = max(worst, rel(step.block(slot), FAMILY.block_logits(params, TOY, settled[:first], going_in)))
+    assert fused == 3 and worst < limit
+    fused_pool = _pool_rows(cache, slot, len(settled))
+    cache.reset()
+    slot = cache.alloc(n, budget, slot=1)
+    engine.prefill(prompt, slot)
+    cache.commit_prefill(slot, n)
+    for tok in out:
+        toks = np.zeros((cache.num_slots,), np.int32)
+        toks[slot] = tok
+        engine.decode(toks).tokens
+        cache.advance(slot)
+    forced_pool = _pool_rows(cache, slot, len(settled))
+    assert rel(fused_pool, forced_pool) < (1e-6 if limit == TIGHT else 2.0 ** -7)
+    cache.reset()
+
+
+def test_more_slots_ready_to_commit_than_places_hold_the_extras_one_call_and_give_the_same_tokens(system):
+    """Four requests prefilled in one iteration are at the same pass of their
+    blocks; the toy's four slots have two places for commit rows, so at the call
+    that would fuse all four two are held, and from then on they are a call behind."""
+    _cfg, _params, cache, engine, _limit = system
+    assert engine.block.commit_places(SLOTS) == 2
+    reqs = [(0, Request(rid=rid, prompt=tuple(tokens(500 + rid, 8)), max_new_tokens=12)) for rid in range(4)]
+    want = {req.rid: _golden(system, req) for _, req in reqs}
+    before = engine.trace_counters()
+    res, _ = _run(system, reqs)
+    c = {name: value - before[name] for name, value in engine.trace_counters().items()}
+    assert {rid: out["tokens"] for rid, out in res.outcomes.items()} == want
+    # two slots held at the fifth call; after it the four are two and two, and nobody waits again
+    assert (c["block_commits_deferred"], c["block_commits_fused"], c["block_commit_passes"]) == (2, 8, 12)
+    assert c["block_passes"] == 4 * 15 and c["decode_steps"] == 3 * 4 + 1 + 1
 
 
 def test_what_a_block_engine_refuses_it_refuses_by_name(system):
@@ -354,6 +460,42 @@ def test_the_hosts_mirror_yields_the_budget_in_the_reference_generators_passes(b
                 break
             masked -= min(block // steps + (k < block % steps), masked)
     assert got == budget and (passes, commits) == (want_passes, want_commits) and length == total
+
+
+@pytest.mark.parametrize("block, steps", [(4, 4), (4, 2), (8, 4), (8, 3), (4, 1)])
+@pytest.mark.parametrize("prompt_len, budget", [(8, 8), (9, 7), (10, 1), (11, 13), (5, 4), (12, 23)])
+def test_the_hosts_mirror_of_fused_calls_yields_the_budget_in_a_call_fewer_a_block_but_the_last(block, steps, prompt_len, budget):
+    """``plan`` with ``fuse``: the yields sum to the budget, a unit (a pass or a
+    commit) is ``block`` live rows and there are as many as the published loop
+    has passes, every commit but the request's last rides with the next block's
+    first pass, and the cache's length moves a block at a commit."""
+    schedule = BlockSchedule(block, steps)
+    state, length, got, calls, units, commits, rode = schedule.open(prompt_len), prompt_len, 0, 0, 0, 0, 0
+    while got < budget:
+        fuse = schedule.fuses(state, budget - got)
+        masked_before = state[0]
+        skip, count, positions = schedule.plan(state, budget - got, fuse)
+        calls, units, got, length = calls + 1, units + 1 + fuse, got + count, length + positions
+        assert (positions > 0) == (masked_before == 0) and (count > 0) == (positions > 0)
+        if positions:
+            commits, rode = commits + 1, rode + fuse
+            assert length % block == 0 and skip == (length - positions) % block and count <= block - skip
+            # a fused call leaves the block after it one pass on; a commit alone leaves it fresh
+            assert state == ([block - schedule.transfers(0), 1, 0] if fuse else [block, 0, 0])
+            assert fuse == (got < budget)
+    total = -(-(prompt_len + budget) // block) * block
+    want_units, n = 0, prompt_len
+    for start in range(prompt_len // block * block, total, block):
+        masked = block - max(0, n - start)
+        for k in range(steps + 1):
+            want_units += 1
+            if not masked:
+                break
+            masked -= min(block // steps + (k < block % steps), masked)
+    blocks = (total - prompt_len // block * block) // block
+    assert got == budget and length == total and (commits, rode) == (blocks, blocks - 1)
+    assert units == want_units and calls == units - rode
+    assert -(-128 // steps) <= schedule.commit_places(128) <= min(128, 2 * 128 // steps)
 
 
 def test_the_cache_settles_a_block_at_a_time_inside_the_reserved_pages(system):

@@ -21,13 +21,17 @@ its static low-confidence schedule, greedy:
     the ``B / T`` (``denoising_steps`` T) with the largest confidence (the
     softmax probability of their argmax, over the whole vocabulary) take their
     argmax;
-  * when no position is masked, one more pass over the final tokens (the COMMIT
-    pass) leaves the block's K and V in the cache, and the block is out.
+  * when no position is masked, the final tokens go through the stack once more
+    (the COMMIT), which leaves the block's K and V in the cache, and the block
+    is out.  The commit rides in the call that runs the first denoising pass of
+    the block after it, as B more rows of that call (``serve_decode``,
+    ``FUSED``); only a request's last block commits in a call of its own.
 
-So a whole block costs T + 1 passes for B tokens, and slots are at different
-passes of their blocks in one program call.  Both programs are written once,
-as pure functions over a plain parameter tree, as ``models/deepseek_v2.py``
-does; the last section is what ``serve.HybridServeEngine`` asks of a model's
+So a whole block costs T calls for B tokens (T + 1 units of B rows through the
+stack, as the published loop has passes), a request of n blocks n T + 1, and
+slots are at different passes of their blocks in one program call.  Both
+programs are written once, as pure functions over a plain parameter tree, as
+``models/deepseek_v2.py`` does; the last section is what ``serve.HybridServeEngine`` asks of a model's
 module, with ``block_schedule`` for the engine's host-side mirror
 (``serve/hybrid_engine.py``, "A block engine", says what ``serve_decode`` is
 given and gives, and the teacher-forced use of the same program).
@@ -37,7 +41,7 @@ an exact tie or a one-in-151,936 draw: a tie of confidences goes to the lower
 position (``torch.topk`` leaves it open), and a position that takes the mask id
 ITSELF as its argmax counts as revealed (the published loop, which finds the
 masked positions by comparing ids, would mask it again and leave its block
-without a commit pass).
+without a commit).
 
 Precision: weights and matmul operands ``config.dtype`` (bfloat16) with
 float32 accumulation; residual stream, norms, rotary, router, softmax and
@@ -371,18 +375,34 @@ def serve_prefill(c: SdarMoeConfig, params, arrays, tokens, length, page_row, sl
 
 
 def serve_decode(c: SdarMoeConfig, params, arrays, table, lengths, tokens, *, active, write_page, write_offset,
-                 kernels: Dict[str, Any]):
-    """The decode program's body: ONE PASS over every slot's open block (B
-    positions a slot, S x B through the stack).  ``tokens`` (S,) says what the
-    pass is for a slot: ``BlockSchedule.OWN_PASS`` the pass its state asks for
-    (denoise, or commit where nothing is masked), ``HOLD`` none (its block and
-    the cache stay as they are), an id the teacher-forced use (the id is
-    revealed at position ``lengths mod B`` of the block and nothing else is).
-    Returns the logits (S, B, vocab), the block as the pass leaves it (S, B),
-    ``{"experts": (layers, held) tokens an expert got, "block": (slot-passes,
-    commit passes, masked query rows) of the slots moved}`` and the cache's
+                 next_page, next_offset, kernels: Dict[str, Any]):
+    """The decode program's body: ONE CALL over every slot's open block (B
+    positions a slot, its OPEN ROWS) and over ``C = commit_places(S)`` places of
+    B COMMIT ROWS each, ``(S + C) x B`` rows through the stack.  ``tokens`` (S,)
+    says what the call is for a slot: ``BlockSchedule.OWN_PASS`` the one pass its
+    state asks for (denoise, or commit alone where nothing is masked: the
+    block's final ids go through as its open rows and its state is a fresh
+    block), ``HOLD`` none (its block and the cache stay as they are), an id the
+    teacher-forced use (the id is revealed at position ``lengths mod B`` of the
+    block and nothing else is), and ``FUSED``, of a slot whose block has nothing
+    masked: the block's final ids go through as the COMMIT ROWS of the next free
+    place (a running count over the slots that fuse; their K and V land at the
+    block's positions and they see ``block start + B`` positions, as a commit
+    alone has it) and the slot's open rows are the block AFTER it, all masked, at
+    its first denoising pass: its K and V land at ``next_page`` /
+    ``next_offset``, it sees ``block start + 2 B`` positions, the committed
+    block's final K and V among them (a layer writes before it attends), and
+    head, confidence and selection run on the open rows alone.  To ``attend``
+    the places are C more slots of the same call (the slot's own table row; an
+    unused place has length 0), and their rows route to no expert.  A ``FUSED``
+    that finds something masked, or every place taken, is an ``OWN_PASS``.
+    Returns the open rows' logits (S, B, vocab), a block a slot (S, B): the one
+    the call committed where it fused, else the open block as the pass leaves
+    it; ``{"experts": (layers, held) tokens an expert got, "block": (units of B
+    rows that went through for a request, those that were commits, masked query
+    rows, commits that rode in a place) of the slots moved}`` and the cache's
     arrays; a slot whose pass found nothing masked has committed, and its state
-    is a fresh block."""
+    is a fresh block; a slot that fused has the block after it, one pass on."""
     from ..kernels import paged_attention as _paged
     from ..serve.engine import BlockSchedule
 
@@ -392,6 +412,7 @@ def serve_decode(c: SdarMoeConfig, params, arrays, table, lengths, tokens, *, ac
         return paged_attention_xla(q, kd, vd, table, valid_len, layer=layer, scale=scale)
 
     B, S = c.block_length, lengths.shape[0]
+    C = block_schedule(c).commit_places(S)
     kd, vd = arrays["k"], arrays["v"]
     held_ids, held_masked, held_pass = arrays["block_ids"][0], arrays["block_masked"][0], arrays["block_pass"][0]
     j = jnp.arange(B, dtype=lengths.dtype)
@@ -399,32 +420,46 @@ def serve_decode(c: SdarMoeConfig, params, arrays, table, lengths, tokens, *, ac
     forced = tokens >= 0
     moving = active & (tokens != BlockSchedule.HOLD)
     here = forced[:, None] & (j[None, :] == (lengths - start)[:, None])
-    ids = jnp.where(here, tokens[:, None], held_ids)
-    masked = held_masked & ~here
-    page = jnp.where(moving, write_page, 0)
-    positions = start[:, None] + j[None, :]
-    live = jnp.repeat(moving, B)
-    x = embed(c, params, ids.reshape(S * B))
+    # the slots that fuse, each to the place its rank among them names
+    fuse = moving & (tokens == BlockSchedule.FUSED) & ~jnp.any(held_masked, axis=-1)
+    rank = jnp.cumsum(fuse) - 1
+    fuse = fuse & (rank < C)
+    source = jnp.zeros((C,), jnp.int32).at[jnp.where(fuse, rank, C)].set(jnp.arange(S, dtype=jnp.int32), mode="drop")
+    used = jnp.arange(C) < jnp.sum(fuse)
+    # open rows: the slot's block as its state has it, or the block after the one it commits
+    ids = jnp.where(fuse[:, None], c.mask_token_id, jnp.where(here, tokens[:, None], held_ids))
+    masked = fuse[:, None] | (held_masked & ~here)
+    passes = jnp.where(fuse, 0, held_pass)
+    first = jnp.concatenate([start + B * fuse, start[source]])                      # (S + C,) of every B rows
+    page = jnp.concatenate([jnp.where(moving, jnp.where(fuse, next_page, write_page), 0),
+                            jnp.where(used, write_page[source], 0)])
+    offset = jnp.concatenate([jnp.where(fuse, next_offset, write_offset), write_offset[source]])
+    valid_len = jnp.concatenate([start + B * fuse + B, jnp.where(used, start[source] + B, 0)])
+    tables = jnp.concatenate([table, table[source]])
+    positions = first[:, None] + j[None, :]
+    live = jnp.repeat(jnp.concatenate([moving, used]), B)
+    x = embed(c, params, jnp.concatenate([ids, held_ids[source]]).reshape((S + C) * B))
     experts = []
     for l in range(c.num_hidden_layers):
         lp = params[f"layers_{l}"]
-        step = lambda u, lp=lp, l=l: attention_pass(c, lp["self_attn"], u, kd, vd, layer=l, table=table, page=page,
-                                                    offset=write_offset, positions=positions, valid_len=start + B,
+        step = lambda u, lp=lp, l=l: attention_pass(c, lp["self_attn"], u, kd, vd, layer=l, table=tables, page=page,
+                                                    offset=offset, positions=positions, valid_len=valid_len,
                                                     attend=attend)
         x, kd, vd, n = layer_pass(c, lp, x, live, step)
         experts.append(n)
     with jax.named_scope("vs.unmask"):
-        logits = head(c, params, x).reshape(S, B, -1)
-        new_ids, new_masked = unmask(c, logits, ids, masked, held_pass, moving & ~forced)
+        logits = head(c, params, x[: S * B]).reshape(S, B, -1)
+        new_ids, new_masked = unmask(c, logits, ids, masked, passes, moving & ~forced)
     commit = moving & ~jnp.any(masked, axis=-1)             # nothing was masked: the K and V just written are final
     counts = {"experts": jnp.stack(experts),
-              "block": jnp.stack([jnp.sum(moving), jnp.sum(commit), jnp.sum(masked & moving[:, None])]).astype(jnp.int32)}
+              "block": jnp.stack([jnp.sum(moving) + jnp.sum(fuse), jnp.sum(commit) + jnp.sum(fuse),
+                                  jnp.sum(masked & moving[:, None]), jnp.sum(fuse)]).astype(jnp.int32)}
     keep, fresh = ~moving[:, None], commit[:, None]
     state = {"block_ids": jnp.where(keep, held_ids, jnp.where(fresh, c.mask_token_id, new_ids)),
              "block_masked": jnp.where(keep, held_masked, fresh | new_masked),
-             "block_pass": jnp.where(moving, jnp.where(commit, 0, held_pass + 1), held_pass)}
-    return logits, new_ids, counts, {"k": kd, "v": vd, **{name: value[None].astype(arrays[name].dtype)
-                                                          for name, value in state.items()}}
+             "block_pass": jnp.where(moving, jnp.where(commit, 0, passes + 1), held_pass)}
+    return logits, jnp.where(fuse[:, None], held_ids, new_ids), counts, {
+        "k": kd, "v": vd, **{name: value[None].astype(arrays[name].dtype) for name, value in state.items()}}
 
 
 # counters of this model beside those every model's engine keeps (``HybridServeEngine.trace_counters``): the

@@ -120,7 +120,8 @@ class DecodeStep:
     **A step that moves a block** (an engine whose ``block`` is a
     :class:`BlockSchedule`: generation by diffusion over blocks of ``B``
     positions).  ``tokens`` is ``(num_slots, B)``: each slot's block as the pass
-    left it, which after a commit pass is the block's final tokens, and how
+    left it, of a call that committed a block (alone, or with the first pass
+    of the block after it) that block's final tokens, and how
     many of them a slot YIELDS (none, or up to ``B``) is the schedule's to say,
     not the step's.  The logits are ``(num_slots, B, vocab)``, one row a position
     of the block, not shifted; ``step.block(slot)`` copies a slot's ``B`` rows.
@@ -196,40 +197,60 @@ class DecodeFeed:
 
     For an engine whose steps move blocks nothing is fed at all: a slot's open
     block lies in the cache's slot state, where the pass before left it.
-    ``slots`` (``{slot: tokens the host will take from this pass}``) then names
-    the slots this pass MOVES (every other slot's block is held as it is), and
-    ``step`` may be None (no step in flight): it only says what to wait for."""
+    ``slots`` (``{slot: tokens the host will take from this call}``) then names
+    the slots this call MOVES (every other slot's block is held as it is), and
+    ``step`` may be None (no step in flight): it only says what to wait for.
+    ``fused`` names those of them whose block has nothing masked and that commit
+    it AND run the first pass of the block after it in this one call
+    (``BlockSchedule.FUSED``; the others run the one pass their state asks for),
+    ``deferred`` how many slots the host held back from this call because every
+    place for commit rows was taken (a count for the engine's counters)."""
 
-    __slots__ = ("step", "fresh", "slots")
+    __slots__ = ("step", "fresh", "slots", "fused", "deferred")
 
     def __init__(self, step: Optional[DecodeStep], fresh: Optional[Dict[int, int]] = None,
-                 slots: Optional[Dict[int, int]] = None):
+                 slots: Optional[Dict[int, int]] = None, fused: Sequence[int] = (), deferred: int = 0):
         self.step = step
         self.fresh = fresh or {}
         self.slots = slots
+        self.fused = fused
+        self.deferred = deferred
 
 
 class BlockSchedule:
-    """What a pass does to a slot's open block under the STATIC schedule of
+    """What a call does to a slot's open block under the STATIC schedule of
     generation by diffusion over blocks (``low_confidence_static``): the
     host's mirror of the state an engine's decode program keeps on the device,
-    which is what lets the serve loop launch a pass before it has read the last
+    which is what lets the serve loop launch a call before it has read the last
     one: it knows what each will yield without looking.
 
     A block of ``B`` positions starts masked but for the ``n mod B`` last tokens
     of a prompt of ``n`` (the first block alone); a denoising pass reveals
     ``transfers(k)`` of the masked positions at its ``k``-th pass (``B / T``,
     the first ``B mod T`` passes one more; never more than are masked); when none
-    is masked the next pass is the COMMIT pass, which leaves the block's K and V
-    and yields its tokens: ``B`` less the prompt's, and no more than the request
-    is still owed.  So a whole block is ``T + 1`` passes for ``B`` tokens.
+    is masked the block waits for its COMMIT, which runs its final ids through
+    the stack once more to leave the block's K and V and yields its tokens: ``B``
+    less the prompt's, and no more than the request is still owed.  A commit
+    rides in the call that opens the next block (``fuse``: the block's ids go
+    through as commit rows of the SAME call that runs the first denoising pass of
+    the block after it), so a whole block is ``T`` calls for ``B`` tokens; alone
+    it is a call of its own, ``T + 1`` a block, which is what a request's LAST
+    block takes (nothing comes after it), and every block of a caller that does
+    not ask to fuse.  A request of ``n`` blocks is ``n T + 1`` calls.  Either way
+    a commit is a unit of ``B`` rows through the stack, as a denoising pass is.
 
-    ``OWN_PASS`` and ``HOLD`` are what the decode program of such an engine
-    reads in a slot's place in ``tokens`` beside a token id (0 or more: the
-    host-token form, the token REVEALED at the slot's length, teacher-forced):
-    run the pass the block's state asks for, or leave the slot's block as it is."""
+    At most ``commit_places(slots)`` slots fuse in one call (the program has
+    that many places for commit rows, not one a slot: about ``slots / T`` commit
+    at any call); a slot that finds them taken is held for that call.
 
-    OWN_PASS, HOLD = -1, -2
+    ``OWN_PASS``, ``FUSED`` and ``HOLD`` are what the decode program of such an
+    engine reads in a slot's place in ``tokens`` beside a token id (0 or more:
+    the host-token form, the token REVEALED at the slot's length,
+    teacher-forced): run the one pass the block's state asks for (denoise, or
+    commit alone), commit the block and run the first pass of the block after
+    it, or leave the slot's block as it is."""
+
+    OWN_PASS, HOLD, FUSED = -1, -2, -3
 
     def __init__(self, block_length: int, denoising_steps: int):
         if not 0 < denoising_steps <= block_length:
@@ -239,20 +260,34 @@ class BlockSchedule:
     def transfers(self, k: int) -> int:
         return self.B // self.T + (k < self.B % self.T)
 
+    def commit_places(self, slots: int) -> int:
+        """How many of ``slots`` slots may fuse in one call: the ``slots / T`` that
+        commit at a call when their phases are spread evenly, and a quarter more.
+        A place costs every call ``B`` rows whatever commits, a slot held back one
+        call of its own, and holding spreads the phases, so the room is small."""
+        return min(slots, -(-5 * slots // (4 * self.T)))
+
     def open(self, prompt_len: int) -> List[int]:
         """The open block of a slot just prefilled: ``[masked, passes done, revealed by the prompt]``."""
         r = prompt_len % self.B
         return [self.B - r, 0, r]
 
-    def plan(self, state: List[int], owed: int) -> Tuple[int, int, int]:
-        """Advance ``state`` by one pass; ``(skip, count, positions)``: the pass
-        yields ``tokens[skip: skip + count]`` of the block as it leaves it and
-        settles ``positions`` more positions of the cache."""
+    def fuses(self, state: List[int], owed: int) -> bool:
+        """Is ``state`` a block waiting for its commit whose request is owed
+        tokens after it?  Its commit may then ride with the next block's first pass."""
+        masked, _k, revealed = state
+        return not masked and owed > self.B - revealed
+
+    def plan(self, state: List[int], owed: int, fuse: bool = False) -> Tuple[int, int, int]:
+        """Advance ``state`` by one call; ``(skip, count, positions)``: the call
+        yields ``tokens[skip: skip + count]`` of the block it returns and
+        settles ``positions`` more positions of the cache.  With ``fuse`` (and
+        :meth:`fuses`) a commit leaves the block after it one pass on."""
         masked, k, revealed = state
         if masked:
             state[0], state[1] = masked - min(self.transfers(k), masked), k + 1
             return 0, 0, 0
-        state[:] = [self.B, 0, 0]
+        state[:] = [self.B - self.transfers(0), 1, 0] if fuse and self.fuses(state, owed) else [self.B, 0, 0]
         return revealed, min(self.B - revealed, owed), self.B - revealed
 
 
@@ -315,11 +350,12 @@ class DecodeAhead:
             return self._host_tokens(tokens)
         return self._merged_tokens(tokens.step._ids, tokens.fresh) if tokens.fresh else tokens.step._ids
 
-    def _note(self, tokens, lengths: np.ndarray) -> Tuple[Optional[np.ndarray], int]:
-        """Of a launch: ``(rows, yields)`` of its :class:`DecodeStep`: which row of a
-        block ``step[slot]`` gives (None: the slot's one row), and how many tokens
-        the host will take from the step beyond one a slot (a block engine's count)."""
-        return None, 0
+    def _note(self, tokens, lengths: np.ndarray) -> Tuple[Optional[np.ndarray], Any]:
+        """Of a launch: ``(rows, note)``: which row of a block ``step[slot]`` of
+        its :class:`DecodeStep` gives (None: the slot's one row), and what
+        ``_count_step`` is told of the launch when the step is read (a block
+        engine's own: what the host will take from the step, and which slots fused)."""
+        return None, None
 
     def _warm_decode(self) -> None:
         """The decode step (no slot active) in every form the loop feeds it: the
@@ -355,8 +391,8 @@ class DecodeAhead:
                 logits, ids, counts = self._run_decode(cache.table_array(), lengths, self._fed(tokens))
             self.decode_launches += 1
             ahead = before is not None and not before.read
-            rows, yields = self._note(tokens, lengths)
-            out = DecodeStep(ids, logits, self, (lengths, counts, ahead, yields, n), rows)
+            rows, note = self._note(tokens, lengths)
+            out = DecodeStep(ids, logits, self, (lengths, counts, ahead, note, n), rows)
             if ahead:
                 self._read_step(before)     # the device goes from that step straight into this one
         return out
@@ -364,24 +400,26 @@ class DecodeAhead:
     def _read_step(self, step: DecodeStep) -> None:
         import jax
 
-        lengths, counts, ahead, yields, n = step._launch
+        lengths, counts, ahead, note, n = step._launch
         # waits for the device, then copies the ids (and the step's counts); ``launch`` names the span that caused it
         with ndtimeit(_p.SERVE_DECODE_FETCH, launch=n):
             step._tokens, counts = jax.device_get((step._ids, counts))
         step._launch = None
         self.decode_steps += 1
         self.decode_steps_ahead += ahead
-        self._count_step(lengths, counts, yields)
+        self._count_step(lengths, counts, note)
 
-    def _count_step(self, lengths: np.ndarray, counts, yields: int = 0) -> None:
+    def _count_step(self, lengths: np.ndarray, counts, note=None) -> None:
+        self._count_pages(lengths + 1)
+
+    def _count_pages(self, *reaches: np.ndarray) -> None:
+        """One decode call's pages: ``reaches`` are the positions each row of the
+        kernel's table was read up to (a slot's new token; an inactive slot's one)."""
         if self.kernel_decode:
-            # what the kernel fetched: each slot's pages up to its new token (up to the end of its open block,
-            # where a step moves a block; an inactive slot's one), of the table's S x Pmax
+            # what the kernel fetched, of the table's S x Pmax
             cache = self.cache
             page, per_slot = cache.config.page_size, cache.config.pages_per_slot
-            width = 1 if self.block is None else self.block.B
-            reach = lengths // width * width + width
-            self.decode_pages_read += int(np.minimum(-(-reach // page), per_slot).sum())
+            self.decode_pages_read += sum(int(np.minimum(-(-reach // page), per_slot).sum()) for reach in reaches)
             self.decode_pages_capacity += cache.num_slots * per_slot
 
     @staticmethod

@@ -57,7 +57,8 @@ gives, as plain functions of the config:
       its ``engine.BlockSchedule``; ``serve_decode`` then returns ``(logits (S,
       B, vocab), ids (S, B), counts, arrays)`` and takes ``write_page`` /
       ``write_offset`` of each slot's open BLOCK, whose first position is
-      ``lengths // B * B``.
+      ``lengths // B * B``, and ``next_page`` / ``next_offset`` of the block
+      after it (a call that commits a block and opens the next writes both).
 
 Two kinds of compiled program, all static-shaped and all compiled by
 ``warm()`` before the engine is handed over:
@@ -74,9 +75,10 @@ Two kinds of compiled program, all static-shaped and all compiled by
   every state array is wholly rewritten.
 
   **decode**, every slot: one token a slot, or one pass over a slot's open
-  block.  The cache's arrays are donated: a second copy would not fit.  The
-  step's counts (``counts["experts"]`` and the model's own) come to the host
-  with its ids, when the step is read.
+  block (with, in the same call, the commit of the block before it).  The
+  cache's arrays are donated: a second copy would not fit.  The step's counts
+  (``counts["experts"]`` and the model's own) come to the host with its ids,
+  when the step is read.
 
 **A block engine** (``engine.block`` is the model's ``BlockSchedule``; the
 serve loop learns from it that a step yields a COUNT a slot, no flag is
@@ -87,17 +89,24 @@ last ``n mod B`` tokens revealed, and returns the logits row of position ``n -
 1`` (NOT shifted: a row predicts its own position); every pass then writes the
 block's K/V at the block's own positions, attends ``block start + B`` positions
 (all that came before, and the block itself in full), and takes confidence,
-selection and the new ids in the program; the pass that finds nothing masked is
-the commit pass: its K/V are of the final tokens, its ids are the block's, and
-it leaves the state a fresh block.  ``decode(tokens)`` is ONE program in two
-uses:
+selection and the new ids in the program; a block with nothing masked waits for
+its COMMIT, which runs its final ids through the stack once more: their K/V are
+the block's, its ids the block's tokens.  A commit rides in the call that opens
+the next block (``BlockSchedule.FUSED``: the final ids go through as B commit
+rows in one of the program's ``commit_places`` places, the slot's open rows are
+the block after it, all masked, at its first pass, and the state it leaves is
+that block one pass on), so a block of ``B`` tokens is ``T`` calls; alone
+(``OWN_PASS`` on a block with nothing masked: a request's last block, and every
+block of a caller that names no ``fused`` slots) it is a call of its own that
+leaves the state a fresh block.  ``decode(tokens)`` is ONE program in two uses:
 
-  * ``decode(DecodeFeed(step before or None, slots={slot: tokens to take}))``,
-    the serve loop's: the named slots run the pass their state asks for, every
+  * ``decode(DecodeFeed(step before or None, slots={slot: tokens to take},
+    fused=[slots]))``, the serve loop's: the named slots run the pass their
+    state asks for (those of ``fused`` commit and open the next block), every
     other slot's block is held; nothing is fed, because the state is on the
     device; ``step.tokens`` is ``(S, B)``, and the host, which mirrors the
     static schedule (``BlockSchedule.plan``), takes ``tokens[slot, skip: skip +
-    count]`` of a commit pass and moves the length by the block;
+    count]`` of a call that committed and moves the length by the block;
   * ``decode(host tokens (S,))``, the host-token form (what a reference check
     and a replay call: one token a slot, then ``cache.advance(slot)``): THE
     SAME PROGRAM teacher-forced.  The fed token is revealed at the slot's
@@ -105,7 +114,8 @@ uses:
     after it stay masked, nothing else is revealed, and ``step[slot]`` is the
     row of position ``L``: the model's logits there with positions ``<= L``
     holding their tokens and the rest of ``L``'s block masked, under the block
-    mask.  At the block's last position that pass is the commit pass.
+    mask.  At the block's last position that pass is the commit, alone: in
+    this form the program's commit rows are dead.
 
 What this engine refuses: ``decode_multi`` and ``prefill_suffix`` (speculation,
 prefix sharing).  Over a cache with slot state (a recurrence's, an open
@@ -136,10 +146,14 @@ COUNTERS = ("decode_launches", "prefill_launches", "decode_steps", "decode_steps
             "prefill_tokens_real", "prefill_tokens_padded", "prefill_bucket_tokens", "decode_pages_read",
             "decode_pages_capacity", "moe_assignments", "moe_assignments_held", "moe_busiest_expert_tokens",
             "moe_expert_slots", "moe_layer_steps", "moe_experts_touched", "moe_padded_layer_steps")
-# ... and for a model that generates by blocks: slot-passes of the slots a pass moved, those of them that were
-# commit passes, the tokens the host took, and the query rows that still had something to decide (masked
-# positions at the start of their pass), each summed over the passes read
-BLOCK_COUNTERS = ("block_passes", "block_commit_passes", "block_tokens_emitted", "block_positions_masked")
+# ... and for a model that generates by blocks, in UNITS of B rows that went through the stack for a request (a
+# denoising pass or a commit; a slot that fuses in a call is two, its commit and the next block's first pass):
+# the units, those of them that were commits, the tokens the host took, the query rows that still had something
+# to decide (masked positions at the start of their pass), the commits that rode with a denoising unit of their
+# slot, and the slot-calls the host held back because every place for commit rows was taken, each summed over
+# the calls read
+BLOCK_COUNTERS = ("block_passes", "block_commit_passes", "block_tokens_emitted", "block_positions_masked",
+                  "block_commits_fused", "block_commits_deferred")
 
 
 def _model_of(config):
@@ -190,7 +204,9 @@ class HybridServeEngine(DecodeAhead):
         # may a decode call's expert layer take its padded form?  Its rows are static, so this is latched as the programs are
         from ..moe.dropless import fits_pad, padded_candidate    # (jax comes with it: imported late, as everywhere in serve/)
 
-        decode_rows = cache.num_slots * (self.block.B if self.block is not None else 1)
+        decode_rows = cache.num_slots           # ... a block engine's: every slot's open rows and the commit places'
+        if self.block is not None:
+            decode_rows = (cache.num_slots + self.block.commit_places(cache.num_slots)) * self.block.B
         self._decode_padded_candidate = padded_candidate(decode_rows, c.num_experts_per_tok, c.experts_held)
         self._fits_pad = fits_pad
         # what this engine has done, in plain integers (``trace_counters``)
@@ -228,13 +244,21 @@ class HybridServeEngine(DecodeAhead):
             # as in ServeEngine.decode: a position past the reserved pages, or a slot that holds nothing yet
             # (before its prefill, or free), writes the null page
             active = lengths > 0
+
+            def landing(first):
+                valid = (first < Pmax * page) & active
+                safe = jnp.where(valid, first, 0)
+                return jnp.where(valid, jnp.take_along_axis(table, (safe // page)[:, None], axis=1)[:, 0], 0), safe % page
+
             first = lengths if block is None else lengths // block.B * block.B
-            valid = (first < Pmax * page) & active
-            safe = jnp.where(valid, first, 0)
-            write_page = jnp.where(valid, jnp.take_along_axis(table, (safe // page)[:, None], axis=1)[:, 0], 0)
+            write_page, write_offset = landing(first)
+            # (a call that commits a block and opens the next writes that one too)
+            after = {}
+            if block is not None:
+                after["next_page"], after["next_offset"] = landing(first + block.B)
             out = model.serve_decode(
                 c, params, dict(zip(names, rest[:n])), table, lengths, tokens, active=active, write_page=write_page,
-                write_offset=safe % page, kernels=kernels)
+                write_offset=write_offset, kernels=kernels, **after)
             if block is None:
                 logits, counts, arrays = out
                 # every slot's greedy token, in this program (``DecodeStep.tokens``)
@@ -286,12 +310,17 @@ class HybridServeEngine(DecodeAhead):
             return super()._fed(tokens)     # (a block engine's host tokens: each revealed at its slot's length)
         fed = np.full((self.cache.num_slots,), BlockSchedule.HOLD, np.int32)
         fed[list(tokens.slots)] = BlockSchedule.OWN_PASS
+        fed[list(tokens.fused)] = BlockSchedule.FUSED
         return self._host_tokens(fed)
 
     def _note(self, tokens, lengths: np.ndarray):
         if self.block is None:
             return super()._note(tokens, lengths)
-        return lengths % self.block.B, sum(tokens.slots.values()) if isinstance(tokens, DecodeFeed) else 0
+        fused, yields, deferred = np.zeros((self.cache.num_slots,), bool), 0, 0
+        if isinstance(tokens, DecodeFeed):
+            fused[list(tokens.fused)] = True
+            yields, deferred = sum(tokens.slots.values()), tokens.deferred
+        return lengths % self.block.B, (yields, fused, deferred)
 
     def _warm_decode(self) -> None:
         if self.block is None:
@@ -332,15 +361,23 @@ class HybridServeEngine(DecodeAhead):
         for name, value in counts.items():
             setattr(self, name, getattr(self, name) + value)
 
-    def _count_step(self, lengths: np.ndarray, counts, yields: int = 0) -> None:
+    def _count_step(self, lengths: np.ndarray, counts, note=None) -> None:
         c = self.config
         experts = counts["experts"]                 # (expert layers, held): tokens an expert got
         positions = int((lengths > 0).sum())        # that went through the stack for a request: one an active slot
-        if self.block is not None:
-            passes, commits, masked = (int(x) for x in counts["block"])
-            positions = passes * self.block.B       # ... or a block a slot that the pass moved
-            self._add({"block_passes": passes, "block_commit_passes": commits, "block_tokens_emitted": yields,
-                       "block_positions_masked": masked})
+        if self.block is None:
+            super()._count_step(lengths, counts)
+        else:
+            B = self.block.B
+            yields, fused, deferred = note
+            units, commits, masked, rode = (int(x) for x in counts["block"])
+            positions = units * B                   # ... or B a unit (a pass, a commit) of the slots the call moved
+            self._add({"block_passes": units, "block_commit_passes": commits, "block_tokens_emitted": yields,
+                       "block_positions_masked": masked, "block_commits_fused": rode, "block_commits_deferred": deferred})
+            # the kernel's rows: every slot's, up to the end of its open block (of the block after it where the
+            # slot fused), and the commit places', up to the end of the block they commit (an unused one: nothing)
+            end = lengths // B * B + B
+            self._count_pages(end + B * fused, end[fused])
         self.moe_assignments += positions * c.num_experts_per_tok * experts.shape[0]
         self.moe_assignments_held += int(experts.sum())
         self.moe_busiest_expert_tokens += int(experts.max(axis=1).sum())
@@ -350,7 +387,6 @@ class HybridServeEngine(DecodeAhead):
         if self._decode_padded_candidate:           # the device's own predicate, on the integers it read
             self.moe_padded_layer_steps += int(self._fits_pad(experts).sum())
         self._add(self.model.step_counters(c, self.cache, lengths, counts))
-        super()._count_step(lengths, counts, yields)
 
     def trace_counters(self) -> Dict[str, int]:
         """The engine's own counts since it was built.  Those ``ServeEngine``
@@ -360,8 +396,8 @@ class HybridServeEngine(DecodeAhead):
         out of ``decode``'s results; ``prefill_tokens_padded`` is the bucket;
         ``decode_pages_*`` are a layer's, and count only with the kernel leg).
         Of ``decode`` calls alone: ``moe_assignments`` = positions that went
-        through the stack for a request (one an active slot; ``B`` a slot that a
-        block engine's pass moved) x experts per token x expert layers, ``moe_assignments_held`` those that fell on
+        through the stack for a request (one an active slot; ``B`` a unit, a pass
+        or a commit, of a block engine's call) x experts per token x expert layers, ``moe_assignments_held`` those that fell on
         an expert held here; ``moe_busiest_expert_tokens`` the largest count of
         one expert, summed over layers and steps (``moe_layer_steps`` of them),
         and ``moe_expert_slots`` = held experts x layers x steps (busiest /
@@ -372,7 +408,7 @@ class HybridServeEngine(DecodeAhead):
         ``padded_candidate``, and its busiest expert fit the pad, ``fits_pad``:
         the layer's own two functions on the counts the step returned), so over
         ``moe_layer_steps`` the share of expert layers that did.
-        ``prefill_bucket_tokens`` the bucket lengths.  A block engine's four
+        ``prefill_bucket_tokens`` the bucket lengths.  A block engine's six
         (``BLOCK_COUNTERS``, above), then the model's own (its module's
         ``STEP_COUNTERS`` says what each counts)."""
         return {k: getattr(self, k) for k in self.counter_names}

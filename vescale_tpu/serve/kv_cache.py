@@ -64,9 +64,10 @@ being denoised, its ids, which of them are still masked and the pass it is at
 are slot state, and every pass writes the block's K and V at the block's own
 positions, PAST ``lengths``: provisional bytes, which no other slot can read
 and which the next pass overwrites.  ``lengths`` counts the settled positions
-only; the pass that runs the block's final tokens (the commit pass) leaves
-their K and V, and the host then moves the length by the block
-(``advance(slot, positions)``).  Pages are reserved for prompt + budget, and a
+only; the call that runs the block's final tokens (its commit, which rides
+with the first pass of the block after it, written one block further past
+``lengths``) leaves their K and V, and the host then moves the length by the
+block (``advance(slot, positions)``).  Pages are reserved for prompt + budget, and a
 page holds whole blocks (the engine checks that ``B`` divides the page), so the
 last block, which may run past the budget, never leaves the reserved pages.
 """

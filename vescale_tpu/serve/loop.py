@@ -201,8 +201,8 @@ def run_serve_resilient(
     k-1, records its ids and keeps its books while the device works on k.
     A slot's length advances at the launch by the positions the step
     settles in the cache: one, whatever the token; or, where a step moves a
-    block (below), none for a denoising pass and the block for its commit
-    pass.  A request that ends by its token count gets no further launch;
+    block (below), none for a denoising pass and the block for the call
+    that commits it.  A request that ends by its token count gets no further launch;
     an EOS is learned one step late (the extra step's ids are dropped); ids
     are recorded only for the slot that still holds the request they were
     launched for.  Every boundary that may
@@ -216,12 +216,17 @@ def run_serve_resilient(
     engine (``engine.block``, a ``BlockSchedule``, where generation is by
     diffusion over blocks; one token a slot without it).  Such an engine's
     prefill yields no token: the request's first tokens, and its TTFT, come
-    with its first block's commit pass, and every block's tokens are
-    recorded together, in order, when that pass is read; a pass in between
-    yields none.  The schedule is static, so the host knows at the launch
-    what the pass in flight will yield (``_InFlight.block`` mirrors the
-    slot's open block) and the pipeline stays one step deep; a request gets
-    exactly ``max_new_tokens``, its last block cut where the budget ends.
+    with its first block's commit, and every block's tokens are recorded
+    together, in order, when that call is read; a pass in between yields
+    none.  A commit rides in the call that runs the first pass of the block
+    after it (``BlockSchedule.fuses``: the request is owed more), so a block
+    of ``B`` tokens is ``T`` calls and a request of ``n`` blocks ``n T + 1``;
+    the program has ``commit_places`` places for such commits a call, and a
+    slot that finds them taken is held for that call.  The schedule is
+    static, so the host knows at the launch what the call in flight will
+    yield (``_InFlight.block`` mirrors the slot's open block) and the
+    pipeline stays one step deep; a request gets exactly
+    ``max_new_tokens``, its last block cut where the budget ends.
     ``speculative`` and a prefix cache are refused for such an engine.
 
     Fleet mode (serve/fleet.py): ``inbox`` (a ``RequestInbox``) feeds the
@@ -313,6 +318,7 @@ def run_serve_resilient(
     # what a step yields: one token a slot, or (``block``, the engine's
     # ``BlockSchedule``) what its schedule says of the slot's open block
     block = getattr(engine, "block", None)
+    commit_places = block.commit_places(cache.num_slots) if block is not None else 0
     if block is not None and (speculative is not None or scheduler.prefix is not None):
         raise NotImplementedError(
             "speculative= and a prefix cache need a step of one token a position and a cache without slot "
@@ -714,7 +720,7 @@ def run_serve_resilient(
             reqtrace.decode_step(step, dt, width)
             for slot, (inf, m) in emitted.items():
                 # a step that gave a slot several tokens (a speculative verify,
-                # a block's commit pass) amortizes over them its wall and that of
+                # a block's commit) amortizes over them its wall and that of
                 # the steps since the slot's last token that gave it none (a
                 # block's denoising passes): a token's latency is its share of
                 # the time its request waited for it
@@ -962,6 +968,11 @@ def run_serve_resilient(
                 stepped: Dict[int, Tuple[Any, int, int]] = {}
                 fresh: Dict[int, int] = {}
                 settles: Dict[int, int] = {}
+                # a block engine's: the slots whose commit rides with their next
+                # block's first pass, and those that found every place for commit
+                # rows taken and are held this call
+                fused: List[int] = []
+                deferred: List[int] = []
                 for slot, inf in scheduler.active.items():
                     unread = flight.get(slot, (None,))[0] is inf
                     owed = inf.req.max_new_tokens - len(inf.tokens) - (flight[slot][2] if unread else 0)
@@ -971,9 +982,16 @@ def run_serve_resilient(
                         stepped[slot], settles[slot] = (inf, 0, 1), 1
                         if not unread:
                             fresh[slot] = inf.tokens[-1]
-                    else:
-                        skip, count, settles[slot] = block.plan(inf.block, owed)
-                        stepped[slot] = (inf, skip, count)
+                        continue
+                    fuse = block.fuses(inf.block, owed)
+                    if fuse and len(fused) == commit_places:
+                        stepped[slot], settles[slot] = (inf, 0, 0), 0
+                        deferred.append(slot)
+                        continue
+                    skip, count, settles[slot] = block.plan(inf.block, owed, fuse)
+                    stepped[slot] = (inf, skip, count)
+                    if fuse:
+                        fused.append(slot)
                 active_slots = sorted(stepped)
                 drafted_rows = (speculative.drafted_slots(active_slots)
                                 if speculative is not None else [])
@@ -990,7 +1008,9 @@ def run_serve_resilient(
                     if stepped:
                         if block is not None:
                             feed = DecodeFeed(before[0] if before is not None else None,
-                                              slots={slot: stepped[slot][2] for slot in active_slots})
+                                              slots={slot: stepped[slot][2] for slot in active_slots
+                                                     if slot not in deferred},
+                                              fused=fused, deferred=len(deferred))
                         elif before is None:
                             feed = np.zeros((cache.num_slots,), np.int32)
                             for slot, tok in fresh.items():
@@ -1002,8 +1022,8 @@ def run_serve_resilient(
                         pending = (engine.decode(feed), stepped)
                         for slot in active_slots:
                             # a step appends one position to every slot it
-                            # stepped, whatever the token; a block's commit
-                            # pass the block, a denoising pass nothing
+                            # stepped, whatever the token; the call that commits
+                            # a block the block, a denoising pass nothing
                             if settles[slot]:
                                 cache.advance(slot, settles[slot])
                     if speculative is not None:
