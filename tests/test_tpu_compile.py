@@ -124,6 +124,8 @@ PAGED_CASES = [
     (8, 16, 8, 32, 32, 128, f32, 2),
     # SDAR's pass: a block's 4 x 32 query rows a slot ride as 4 groups (key heads) of 32, 128 slots, six layers
     (128, 128, 16, 128, 4, 128, bf16, 6),
+    # Falcon-H1-34B: 20 query rows on 4 key heads, five a key head (not a whole number of 8-row tiles, no power of two)
+    (128, 96, 16, 20, 4, 128, bf16, 6),
 ]
 
 
@@ -162,6 +164,70 @@ def test_ssm_step_compiles_in_place(chip, L, S, N, J):
     assert "tpu_custom_call" in compiled.as_text()
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == L * S * N * J * 4 and memory.temp_size_in_bytes < 1 << 20   # no copy of the state
+
+
+def test_ssm_step_compiles_in_place_with_two_groups_and_a_state_of_256(chip):
+    """Falcon-H1-34B's six layers at 128 slots: a block of (256, 1024) picks the column of its lanes' group."""
+    from vescale_tpu.kernels.ssm_step import ssm_step, supports
+
+    L, S, N, J, G = 6, 128, 256, 4096, 2
+    assert supports(f32, N, J, interpret=False, groups=G)
+    sds = lambda shape, dt=f32: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    compiled = ssm_step.lower(sds((L, S, N, J)), sds((S, J)), sds((S, J)), sds((S, G, N)), sds((S, G, N)),
+                              layer=sds((1,), jnp.int32), interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == L * S * N * J * 4 and memory.temp_size_in_bytes < 1 << 20   # no copy of the state
+
+
+# ------------------------------------------------- whole programs of a cell
+class _JaxOnATpu:
+    """``jax`` as ``ops/flash_attention.py`` sees it, but for the platform of ``jax.devices()[0]``."""
+
+    def __getattr__(self, name):
+        return getattr(jax, name)
+
+    def devices(self, *_args):
+        import types
+
+        return [types.SimpleNamespace(platform="tpu")]
+
+
+@pytest.mark.parametrize("program,kernels_in_it", [("decode step", 12), ("bucket of 512", 6)], ids=["decode", "rung512"])
+def test_falcon_h1s_decode_program_and_a_rung_compile_at_the_cells_size(chip, program, kernels_in_it):
+    """``falconh1_34b_serve_batch``'s decode step (128 slots x 1536 positions:
+    six ``ssm_step`` and six ``paged_decode`` kernels) and the 512 rung of its
+    prefill ladder (six grouped-query flash forwards at 20 / 4 heads), from
+    shapes alone, as ``benchmark/rehearse.py`` lowers them.  The program asks
+    ``jax.devices()`` for its platform and would take its CPU legs here, so the
+    test answers for it while the programs are traced."""
+    import importlib
+    from unittest import mock
+
+    from benchmark.spec import load_cell
+    from vescale_tpu import kernels
+
+    flash_ops = importlib.import_module("vescale_tpu.ops.flash_attention")    # (``ops`` exports the function under this name)
+    spec = load_cell("falconh1_34b_serve_batch")
+    family, config = spec.family(), spec.config
+    (device,) = chip.device_set
+    with mock.patch.object(kernels, "on_tpu", lambda: True), mock.patch.object(flash_ops, "jax", _JaxOnATpu()):
+        sizes, programs = family.rehearse_serve(spec.name, config, config["serve"], [device])
+    titles = [title for title, _ in programs]
+    assert sum("prefill, bucket of" in t for t in titles) == 5 and "decode step, 128 slots x 1536 positions" in titles[-1]
+    assert sizes["weights_bytes"] == family.weight_bytes(config)
+    assert sizes["slot_state_bytes"] == 128 * family.state_bytes_per_slot(config, config["serve"])
+    (lowered,) = [low for title, low in programs if program in title]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels_in_it
+    # the pools are written in place: no copy of one (1.2 GB each) to another layout and back around a scatter
+    assert not [line for line in text.splitlines() if " copy(" in line and "= bf16[6,12289,16,4,128]" in line]
+    memory = compiled.memory_analysis()
+    cache_bytes = sizes["kv_pool_bytes"] + sizes["slot_state_bytes"]
+    assert memory.argument_size_in_bytes >= sum(sizes.values()) and memory.alias_size_in_bytes >= cache_bytes   # in place
+    # 16 GB of HBM: the arguments (weights, pools, state) and the program's temporaries, with room for the logits
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9 and memory.temp_size_in_bytes < 0.5e9, memory
 
 
 # ------------------------------------------------------------ fused adamw

@@ -21,12 +21,18 @@ place and reduces it to ``y`` while it is in VMEM: one read, one write.
     the kernel is two broadcasts, a multiply-add and a sublane reduction:
     nothing is transposed, and no lane is idle (a head width of 64 alone
     would fill half of each vector register).
+  * **groups** — where the heads read ``G`` groups of ``B`` and ``C`` (``(S,
+    G, N)``; Falcon-H1 has two), group ``g`` owns the lanes ``[g J / G, (g +
+    1) J / G)``, a block never straddles two groups, and its columns are those
+    of the group its lanes lie in: the block's index picks them, the kernel's
+    body is the same.  One group, ``(S, N)``, is the program it was before.
   * **operands** — the whole state array stays where it is and is aliased to
     the output: the grid visits the blocks of one layer (the layer index rides
     in as a scalar-prefetch operand, so every layer of a decode program is the
     same Mosaic kernel) and every other byte of it is untouched.
   * **grid** — ``(slots, J / block)``, both parallel; a block is ``(N,
-    block)`` float32, 1 MiB at the default.
+    block)`` float32 of at most ``_BLOCK_BYTES`` (1 MiB: 2048 lanes at N =
+    128, 1024 at N = 256; in and out double-buffered, 4 MiB of VMEM).
 
 Numerics: float32 throughout, the same operations in the same order as the
 XLA leg (``models/granite_hybrid.py``), except the order of the sum over
@@ -45,23 +51,26 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["ssm_step", "supports"]
 
-_BLOCK_LANES = 2048      # of J in one block: (128, 2048) float32 is 1 MiB, in and out double-buffered
+_BLOCK_BYTES = 1 << 20   # of the state in one block, float32: in and out double-buffered
 
 
-def _block(lanes: int) -> int:
-    block = min(_BLOCK_LANES, lanes)
+def _block(state_dim: int, lanes: int) -> int:
+    """Lanes of one block: as many as ``_BLOCK_BYTES`` hold of a float32 state
+    ``state_dim`` deep, halved until they divide ``lanes`` (a group's lanes)."""
+    block = min(max(_BLOCK_BYTES // (4 * state_dim), 1), lanes)
     while lanes % block:
         block //= 2
     return block
 
 
-def supports(state_dtype, state_dim: int, lanes: int, *, interpret: bool) -> bool:
-    """Whether the kernel takes a state of this type and shape: float32 (a
-    16-bit state would want 16 rows a tile and its own rounding), and,
-    compiled, whole (8, 128) tiles."""
-    if jnp.dtype(state_dtype) != jnp.float32:
+def supports(state_dtype, state_dim: int, lanes: int, *, interpret: bool, groups: int = 1) -> bool:
+    """Whether the kernel takes a state of this type and shape, its lanes in
+    ``groups`` groups: float32 (a 16-bit state would want 16 rows a tile and
+    its own rounding), whole groups, and, compiled, whole (8, 128) tiles in a
+    group's block."""
+    if jnp.dtype(state_dtype) != jnp.float32 or lanes % groups:
         return False
-    return interpret or (state_dim % 8 == 0 and lanes % 128 == 0)
+    return interpret or (state_dim % 8 == 0 and _block(state_dim, lanes // groups) % 128 == 0)
 
 
 def _step_kernel(layer_ref, decay_ref, dtx_ref, b_ref, c_ref, h_ref, h_out_ref, y_ref):
@@ -76,15 +85,22 @@ def ssm_step(state, decay, dtx, B, C, *, layer, interpret: bool):
     """One step of one layer for every slot.  ``state`` (layers, S, N, J)
     float32, updated in place at ``layer`` (an int32 scalar or array of one);
     ``decay`` and ``dtx`` (S, J): each head's ``exp(dt A)`` repeated over its
-    head width, and ``dt x``; ``B`` and ``C`` (S, N).  Returns the state array
-    and ``y`` (S, J) float32."""
+    head width, and ``dt x``; ``B`` and ``C`` (S, N), or (S, G, N) where the
+    lanes lie in ``G`` groups.  Returns the state array and ``y`` (S, J) float32."""
     _layers, S, N, J = state.shape
-    T = _block(J)
+    G = 1 if B.ndim == 2 else B.shape[1]
+    if C.shape != B.shape or J % G:
+        raise ValueError(f"ssm_step: B {B.shape} and C {C.shape} against a state of {J} lanes")
+    T = _block(N, J // G)
     f32 = jnp.float32
     row = lambda a: a.astype(f32).reshape(S, 1, J)
-    col = lambda a: a.astype(f32).reshape(S, N, 1)
+    col = lambda a: a.astype(f32).reshape(S * G, N, 1)
     rows = pl.BlockSpec((1, 1, T), lambda s, j, layer: (s, 0, j))
-    cols = pl.BlockSpec((1, N, 1), lambda s, j, layer: (s, 0, 0))
+    if G == 1:
+        cols = pl.BlockSpec((1, N, 1), lambda s, j, layer: (s, 0, 0))
+    else:       # the column of the group the block's lanes lie in
+        per_group = J // G // T
+        cols = pl.BlockSpec((1, N, 1), lambda s, j, layer: (s * G + j // per_group, 0, 0))
     block = pl.BlockSpec((1, 1, N, T), lambda s, j, layer: (layer[0], s, 0, j))
     new_state, y = pl.pallas_call(
         _step_kernel,

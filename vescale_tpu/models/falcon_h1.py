@@ -1,0 +1,420 @@
+"""Falcon-H1 (``falcon_h1``): a decoder whose every layer runs a Mamba-2
+state-space mixer AND a grouped-query attention mixer side by side on the same
+normed input and adds both to the residual stream, over a dense SwiGLU, with
+twelve fixed multipliers (muP) on the way.
+
+The block is written ONCE, as pure functions over a plain parameter tree
+(``init_params``), and both serve programs call them.  What the state-space
+mixer shares with Granite-4.0-H it imports from ``models/granite_hybrid.py``
+(``mamba2_prefill`` over ``ssd_chunked``, ``mamba2_step``, the XLA legs of both
+decode kernels), which is written for ``G`` groups of B and C; the
+rotary term is ``models/sdar_moe.py``'s.  No flax module, no training copy.
+
+Equations (HF ``modeling_falcon_h1.py``; ISSUE 43 writes them out):
+
+    x0 = embedding_multiplier * E[tokens]
+    u  = rmsnorm(x; input_layernorm)
+    x += ssm_out_multiplier * mamba(ssm_in_multiplier * u)
+       + attention_out_multiplier * attn(attention_in_multiplier * u)
+    h  = rmsnorm(x; pre_ff_layernorm)
+    x += mlp_multipliers[1] * W_down(W_up h * silu(mlp_multipliers[0] * W_gate h))
+    logits = lm_head_multiplier * W_head rmsnorm(x_L; final_layernorm)      (head untied)
+
+``attn``: q, k, v, o without bias, grouped-query (query head ``h`` reads key
+head ``h // (H / KV)``; 20 on 4 at 34B, five a key head), ``k = key_multiplier *
+W_k u``, rotary over the whole head (``rotate_half`` pairs) on q and k, causal
+softmax of ``q.k / sqrt(head_dim)``.  ``mamba`` (Mamba-2 with ``G`` groups of B
+and C, two at 34B): ``p = W_in u`` times ``ssm_multipliers`` segment by segment
+(z, x, B, C, dt); ``[z | xBC | dt] = p``; ``xBC = silu(causal depthwise
+conv1d(xBC) + b)``; ``dt = softplus(dt + dt_bias)``; per head ``h`` of group ``g
+= h // (H / G)``: ``S_t = exp(dt_t A_h) S_{t-1} + dt_t x_t B_{g,t}^T``, ``y_t =
+S_t C_{g,t} + D_h x_t``; ``y = rmsnorm_per_group(y * silu(z)) * w`` (the gate
+first, then each group of ``d_ssm / G`` channels normed by its own mean
+square); ``W_out y``.
+
+Precision, as ``granite_hybrid.py`` states it: weights and matmul operands are
+``config.dtype`` (bfloat16) with float32 accumulation; the residual stream, the
+norms, the gate, the rotary term and everything of the recurrence are float32,
+the state float32.
+
+The cache: EVERY layer owns a row of the state arrays (``ssm``, ``conv``) AND a
+layer of the K/V pools; a prefill writes both for every layer and a decode
+step reads and writes both.
+
+A chip's share: ``vocab_size`` is the rows of the embedding and of the head
+held here; nothing stands in for the absent chips.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from .granite_hybrid import F32, _mm, mamba2_prefill, mamba2_step, paged_attention_xla, rmsnorm, ssm_advance_xla
+from .sdar_moe import rotary
+
+__all__ = [
+    "FalconH1Config", "init_params", "embed", "head", "in_scale", "attention_prefill", "attention_step", "mlp",
+    "layer", "cache_config", "prefill_chunk", "decode_kernels", "serve_prefill", "serve_decode",
+    "STEP_COUNTERS", "step_counters", "prefill_counters", "BRANCH_GAIN",
+]
+
+# Random weights at variance 1 / fan-in under this model's multipliers would make every branch a rounding
+# error of the stream (the state-space branch enters at 0.25 x ... x 0.088, the head scales by 0.0078), and a
+# comparison with the reference would then hold whatever a branch computed.  So ``init_params`` draws each
+# matrix that a multiplier follows (or whose input one scales) wider by that multiplier's inverse: every
+# pre-activation the block's nonlinearities see (the convolution's, the gates', the softmax's scores, the
+# logits) then has unit variance, as the trained model's multipliers are there to arrange; and the three
+# branches' last projections (``out_proj``, ``o_proj``, ``down_proj``) this much of that, so that each branch
+# adds about a quarter of the stream's size a layer, as Granite's residual multiplier of 0.22 does.
+BRANCH_GAIN = 0.25
+
+
+@dataclasses.dataclass(frozen=True)
+class FalconH1Config:
+    vocab_size: int = 261120            # rows of the embedding and of the (untied) head held here
+    hidden_size: int = 5120
+    num_hidden_layers: int = 72
+    num_attention_heads: int = 20
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    intermediate_size: int = 21504
+    mamba_n_heads: int = 32
+    mamba_d_head: int = 128             # heads x head width is the source's ``mamba_d_ssm``
+    mamba_d_state: int = 256
+    mamba_d_conv: int = 4
+    mamba_n_groups: int = 2
+    mamba_chunk_size: int = 128
+    rope_theta: float = 1e11
+    embedding_multiplier: float = 5.656854249492381
+    lm_head_multiplier: float = 0.0078125
+    ssm_in_multiplier: float = 0.25
+    ssm_out_multiplier: float = 0.08838834764831845
+    ssm_multipliers: Tuple[float, ...] = (0.3535533905932738, 0.25, 0.1767766952966369, 0.5, 0.3535533905932738)
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 0.0375
+    key_multiplier: float = 0.011048543456039804
+    mlp_multipliers: Tuple[float, float] = (0.1767766952966369, 0.011160714285714284)
+    rms_norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16           # weights and matmul operands
+    state_dtype: Any = jnp.float32      # the recurrent state: rewritten every step, so its rounding accumulates
+
+    def __post_init__(self):
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
+        if self.mamba_n_heads % self.mamba_n_groups:
+            raise ValueError("the state-space heads come in whole groups")
+        if self.head_dim % 2:
+            raise ValueError("a head is made of rotary pairs")
+        if len(self.ssm_multipliers) != 5 or len(self.mlp_multipliers) != 2:
+            raise ValueError("ssm_multipliers are five (z, x, B, C, dt) and mlp_multipliers two (gate, down)")
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def in_proj_dim(self) -> int:
+        return self.d_inner + self.conv_dim + self.mamba_n_heads
+
+    @property
+    def in_segments(self) -> Tuple[int, ...]:
+        """Widths of the in-projection's segments, in ``ssm_multipliers``' order: z, x, B, C, dt."""
+        GN = self.mamba_n_groups * self.mamba_d_state
+        return (self.d_inner, self.d_inner, GN, GN, self.mamba_n_heads)
+
+    @property
+    def ssm_state_shape(self) -> Tuple[int, int]:
+        """One slot's state in one layer as the cache keeps it: (state, heads x
+        head width); group ``g``'s heads are the lanes ``[g, g + 1) x d_inner / G``."""
+        return (self.mamba_d_state, self.d_inner)
+
+    @property
+    def conv_tail_shape(self) -> Tuple[int, int]:
+        return (self.mamba_d_conv - 1, self.conv_dim)
+
+
+# ------------------------------------------------------------------ parameters
+def init_params(config: FalconH1Config, key) -> Dict[str, Any]:
+    """Seeded random weights in the types they are served in (jit the call).
+    Matrices are normal with variance ``(gain / multiplier)^2 / fan-in``
+    (``BRANCH_GAIN`` says which gain and why); ``A_log``, ``dt_bias`` and ``D``
+    as Mamba-2 initialises them (``granite_hybrid.init_params`` says why)."""
+    c, dt = config, config.dtype
+    E, F = c.hidden_size, c.intermediate_size
+    in_gain = 1.0 / (math.sqrt(E) * c.ssm_in_multiplier * in_scale(c))      # (in_proj_dim,): a segment's own
+
+    def normal(k, shape, fan_in, gain=1.0):
+        return (jax.random.normal(k, shape, F32) * (gain / math.sqrt(fan_in))).astype(dt)
+
+    def mamba(k):
+        ks = jax.random.split(k, 6)
+        step = jnp.exp(jax.random.uniform(ks[3], (c.mamba_n_heads,), F32) * (math.log(1e-1) - math.log(1e-3))
+                       + math.log(1e-3))
+        return {
+            "in_proj": (jax.random.normal(ks[0], (E, c.in_proj_dim), F32) * in_gain).astype(dt),
+            "conv_weight": jax.random.uniform(ks[1], (c.mamba_d_conv, c.conv_dim), F32, -0.5, 0.5).astype(dt),
+            "conv_bias": jax.random.uniform(ks[2], (c.conv_dim,), F32, -0.5, 0.5).astype(dt),
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "A_log": jnp.log(jax.random.uniform(ks[4], (c.mamba_n_heads,), F32, 1.0, 16.0)),
+            "D": jnp.ones((c.mamba_n_heads,), F32),
+            "norm_weight": jnp.ones((c.d_inner,), dt),
+            "out_proj": normal(ks[5], (c.d_inner, E), c.d_inner, BRANCH_GAIN / c.ssm_out_multiplier),
+        }
+
+    def attention(k):
+        ks = jax.random.split(k, 4)
+        q, kv = c.num_attention_heads * c.head_dim, c.num_key_value_heads * c.head_dim
+        return {"q_proj": normal(ks[0], (E, q), E, 1.0 / c.attention_in_multiplier),
+                "k_proj": normal(ks[1], (E, kv), E, 1.0 / (c.attention_in_multiplier * c.key_multiplier)),
+                "v_proj": normal(ks[2], (E, kv), E, 1.0 / c.attention_in_multiplier),
+                "o_proj": normal(ks[3], (q, E), q, BRANCH_GAIN / c.attention_out_multiplier)}
+
+    def feed_forward(k):
+        ks = jax.random.split(k, 3)
+        return {"gate_proj": normal(ks[0], (E, F), E, 1.0 / c.mlp_multipliers[0]),
+                "up_proj": normal(ks[1], (E, F), E),
+                "down_proj": normal(ks[2], (F, E), F, BRANCH_GAIN / c.mlp_multipliers[1])}
+
+    params: Dict[str, Any] = {
+        "embed_tokens": {"embedding": normal(jax.random.fold_in(key, 1 << 20), (c.vocab_size, E), 1.0,
+                                             1.0 / c.embedding_multiplier)},
+        "lm_head": {"kernel": normal(jax.random.fold_in(key, 1 << 21), (E, c.vocab_size), E,
+                                     1.0 / c.lm_head_multiplier)},
+        "final_layernorm": {"weight": jnp.ones((E,), dt)},
+    }
+    for l in range(c.num_hidden_layers):
+        k_mamba, k_attn, k_ff = jax.random.split(jax.random.fold_in(key, l), 3)
+        params[f"layers_{l}"] = {
+            "input_layernorm": {"weight": jnp.ones((E,), dt)},
+            "pre_ff_layernorm": {"weight": jnp.ones((E,), dt)},
+            "mamba": mamba(k_mamba),
+            "self_attn": attention(k_attn),
+            "feed_forward": feed_forward(k_ff),
+        }
+    return params
+
+
+# ------------------------------------------------------------- shared pieces
+def embed(config: FalconH1Config, params, tokens):
+    return config.embedding_multiplier * jnp.take(params["embed_tokens"]["embedding"], tokens, axis=0).astype(F32)
+
+
+def head(config: FalconH1Config, params, x):
+    """Logits (float32) over the rows of the head held here."""
+    xn = rmsnorm(x, params["final_layernorm"]["weight"], config.rms_norm_eps)
+    return config.lm_head_multiplier * _mm(xn, params["lm_head"]["kernel"], config.dtype)
+
+
+def in_scale(c: FalconH1Config):
+    """``ssm_multipliers`` laid over the in-projection's outputs, (in_proj_dim,) float32."""
+    return jnp.concatenate([jnp.full((n,), m, F32) for n, m in zip(c.in_segments, c.ssm_multipliers)])
+
+
+# ---------------------------------------------------------- attention mixer
+def _qkv(c: FalconH1Config, ap, u, positions):
+    """Queries (N, H, hd) and keys (N, KV, hd), rotated by ``positions`` (N,),
+    the keys scaled by ``key_multiplier`` first; values (N, KV, hd); all in
+    ``c.dtype``."""
+    N, H, KV, hd = u.shape[0], c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    q = _mm(u, ap["q_proj"], c.dtype).reshape(N, H, hd)
+    k = c.key_multiplier * _mm(u, ap["k_proj"], c.dtype).reshape(N, KV, hd)
+    v = _mm(u, ap["v_proj"], c.dtype).reshape(N, KV, hd)
+    return rotary(q, positions, c.rope_theta).astype(c.dtype), rotary(k, positions, c.rope_theta).astype(c.dtype), \
+        v.astype(c.dtype)
+
+
+def attention_prefill(c: FalconH1Config, ap, u, *, interpret: Optional[bool] = None):
+    """Causal attention over one sequence ``u`` (T, E) from position 0, through
+    the flash forward (its grouped-query kernel on TPU, which routes a query
+    head to its key head by the block's index: five a key head is an index like
+    any other); returns the output (T, E) and this layer's K and V (T, KV, hd).
+    Pad positions follow the real ones, so causality keeps them out."""
+    from ..ops.flash_attention import flash_attention
+
+    q, k, v = _qkv(c, ap, u, jnp.arange(u.shape[0], dtype=jnp.int32))
+    y = flash_attention(q[None], k[None], v[None], causal=True, scale=c.head_dim ** -0.5, interpret=interpret)[0]
+    return _mm(y.reshape(u.shape[0], -1), ap["o_proj"], c.dtype), k, v
+
+
+def attention_step(c: FalconH1Config, ap, u, k_pool, v_pool, *, layer: int, table, page, offset, positions,
+                   valid_len, attend):
+    """One new position a slot, at ``positions`` (S,): its K and V go to
+    ``(page, offset)`` of the pool's ``layer`` (the null page for a slot that
+    may not write), then ``attend(q, k_pool, v_pool, table, valid_len, layer=,
+    scale=)`` reads the slot's pages.  Returns the output (S, E) and both pools."""
+    q, k, v = _qkv(c, ap, u, positions)
+    k_pool = k_pool.at[layer, page, offset].set(k.astype(k_pool.dtype))
+    v_pool = v_pool.at[layer, page, offset].set(v.astype(v_pool.dtype))
+    y = attend(q, k_pool, v_pool, table, valid_len, layer=layer, scale=c.head_dim ** -0.5)
+    return _mm(y.reshape(u.shape[0], -1), ap["o_proj"], c.dtype), k_pool, v_pool
+
+
+# ------------------------------------------------------------ the dense MLP
+def mlp(c: FalconH1Config, fp, h):
+    gate = c.mlp_multipliers[0] * _mm(h, fp["gate_proj"], c.dtype)
+    return c.mlp_multipliers[1] * _mm(_mm(h, fp["up_proj"], c.dtype) * jax.nn.silu(gate), fp["down_proj"], c.dtype)
+
+
+# ------------------------------------------------------------ whole layers
+def layer(c: FalconH1Config, lp, x, mamba_mixer, attention_mixer):
+    """One layer over ``x`` (T, E) or (S, E) float32: both mixers on the same
+    normed input (each under its own multiplier), their sum into the stream,
+    then the MLP's.  ``mamba_mixer(u)`` and ``attention_mixer(u)`` are the
+    mixers over this layer's share of the cache, a prefill's or a step's, and
+    return ``(y, *what the layer leaves in the cache)``; those come back beside
+    the stream."""
+    u = rmsnorm(x, lp["input_layernorm"]["weight"], c.rms_norm_eps)
+    with jax.named_scope("vs.mamba"):
+        ym, *kept_m = mamba_mixer(c.ssm_in_multiplier * u)
+    with jax.named_scope("vs.attn"):
+        ya, *kept_a = attention_mixer(c.attention_in_multiplier * u)
+    x = x + c.ssm_out_multiplier * ym + c.attention_out_multiplier * ya
+    with jax.named_scope("vs.mlp"):
+        x = x + mlp(c, lp["feed_forward"], rmsnorm(x, lp["pre_ff_layernorm"]["weight"], c.rms_norm_eps))
+    return x, tuple(kept_m), tuple(kept_a)
+
+
+# ------------------------------------------- what the serve engine asks of a model
+# (``serve/hybrid_engine.py``, "The seam")
+def cache_config(config: FalconH1Config, *, num_slots: int, page_size: int, pages_per_slot: int,
+                 num_pages: Optional[int] = None):
+    """Pages AND a recurrent state and a convolution tail a slot, for every layer."""
+    from ..serve.kv_cache import KVCacheConfig
+
+    L = config.num_hidden_layers
+    return KVCacheConfig(
+        layers=L, kv_heads=config.num_key_value_heads, head_dim=config.head_dim, num_slots=num_slots,
+        page_size=page_size, pages_per_slot=pages_per_slot, num_pages=num_pages, dtype=config.dtype,
+        slot_state=(("ssm", L, config.ssm_state_shape, config.state_dtype),
+                    ("conv", L, config.conv_tail_shape, config.dtype)))
+
+
+def prefill_chunk(config: FalconH1Config) -> int:
+    """The prefill ladder's first rung: the chunked scan wants whole chunks."""
+    return config.mamba_chunk_size
+
+
+def decode_kernels(config: FalconH1Config, cache) -> Dict[str, Any]:
+    """``{"decode":, "ssm_step":}``, each kernel's ``interpret`` flag, or None
+    for its XLA leg (the kernels on TPU, the XLA legs elsewhere)."""
+    from .. import kernels as _kernels
+    from ..kernels import paged_attention as _paged
+    from ..kernels import ssm_step as _ssm
+
+    c = config
+    return {
+        "decode": _kernels.resolve(
+            "paged_decode",
+            supported=lambda interp: _paged.supports(cache.k.data.dtype, c.num_key_value_heads, c.head_dim,
+                                                     interpret=interp)),
+        "ssm_step": _kernels.resolve(
+            "ssm_step", supported=lambda interp: _ssm.supports(c.state_dtype, *c.ssm_state_shape, interpret=interp,
+                                                               groups=c.mamba_n_groups)),
+    }
+
+
+def _write_pages(pool, rows, page_row, page: int):
+    """``rows`` (L, T, KV, hd), every layer's K or V of a rung's positions, into
+    the pages ``page_row`` (T / page,) of ``pool`` (L, pages, page, KV, hd): one
+    slab of all layers a page, updated in place.  (One scatter over the page
+    axis makes the compiler re-lay out the WHOLE pool and back around it where
+    a row of the pool is 4 heads wide, 3.7 ms a copy at this pool's 1.2 GB:
+    PERF.md section 6, PR 43.)"""
+    L, T = rows.shape[:2]
+    slabs = rows.reshape(L, T // page, page, *rows.shape[2:]).astype(pool.dtype)
+
+    def one_page(p, pool):
+        slab = jax.lax.dynamic_slice_in_dim(slabs, p, 1, axis=1)
+        return jax.lax.dynamic_update_slice(pool, slab, (0, page_row[p], 0, 0, 0))
+
+    return jax.lax.fori_loop(0, T // page, one_page, pool)
+
+
+def serve_prefill(c: FalconH1Config, params, arrays, tokens, length, page_row, slot, *, page: int,
+                  interpret: Optional[bool] = None):
+    """The prefill program's body: ``tokens`` (bucket,) through the stack; every
+    layer's K and V of the bucket's positions go to the slot's pages, its state
+    and tail to the slot's rows.  Returns the last real position's logits row
+    and the cache's arrays."""
+    kd, vd, ssm, conv = arrays["k"], arrays["v"], arrays["ssm"], arrays["conv"]
+    scale = in_scale(c)
+    x = embed(c, params, tokens)
+    states, tails, ks, vs = [], [], [], []
+    for l in range(c.num_hidden_layers):
+        lp = params[f"layers_{l}"]
+        x, (state, tail), (k, v) = layer(
+            c, lp, x,
+            lambda u, lp=lp: mamba2_prefill(c, lp["mamba"], u, length, in_scale=scale),
+            lambda u, lp=lp: attention_prefill(c, lp["self_attn"], u, interpret=interpret))
+        states.append(state)
+        tails.append(tail)
+        ks.append(k)
+        vs.append(v)
+    last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=0, keepdims=True)
+    logits = head(c, params, last)[0]
+    kd, vd = _write_pages(kd, jnp.stack(ks), page_row, page), _write_pages(vd, jnp.stack(vs), page_row, page)
+    ssm = jax.lax.dynamic_update_slice_in_dim(ssm, jnp.stack(states)[:, None].astype(ssm.dtype), slot, axis=1)
+    conv = jax.lax.dynamic_update_slice_in_dim(conv, jnp.stack(tails)[:, None].astype(conv.dtype), slot, axis=1)
+    return logits, {"k": kd, "v": vd, "ssm": ssm, "conv": conv}
+
+
+def serve_decode(c: FalconH1Config, params, arrays, table, lengths, tokens, *, active, write_page, write_offset,
+                 kernels: Dict[str, Any]):
+    """The decode program's body, one token a slot: in every layer the
+    recurrence's one step over every slot's state (read and written whole: with
+    the weights the step's largest traffic; the ``ssm_step`` kernel on TPU) and
+    paged attention over that layer's pool (the ``paged_decode`` kernel on TPU),
+    then the MLP.  Returns the logits (S, vocab), no counts of its own (a dense
+    model: nothing is routed) and the cache's arrays."""
+    from ..kernels import paged_attention as _paged
+    from ..kernels import ssm_step as _ssm
+
+    del active          # every slot goes through the dense layers; a slot that holds nothing writes the null page
+    kd, vd, ssm, conv = arrays["k"], arrays["v"], arrays["ssm"], arrays["conv"]
+
+    def attend(q, kd, vd, table, valid_len, *, layer, scale):
+        if kernels["decode"] is not None:
+            return _paged.paged_decode(q, kd, vd, table, valid_len, layer=layer, scale=scale,
+                                       interpret=kernels["decode"])
+        return paged_attention_xla(q, kd, vd, table, valid_len, layer=layer, scale=scale)
+
+    def advance(ssm, decay, dtx, B, C, *, layer):
+        if kernels["ssm_step"] is not None:
+            return _ssm.ssm_step(ssm, decay, dtx, B, C, layer=layer, interpret=kernels["ssm_step"])
+        return ssm_advance_xla(ssm, decay, dtx, B, C, layer=layer)
+
+    scale = in_scale(c)
+    x = embed(c, params, tokens)                    # (S, E)
+    for l in range(c.num_hidden_layers):
+        lp = params[f"layers_{l}"]
+        x, (ssm, tail), (kd, vd) = layer(
+            c, lp, x,
+            lambda u, lp=lp, l=l: mamba2_step(c, lp["mamba"], u, ssm, conv[l], layer=l, advance=advance, in_scale=scale),
+            lambda u, lp=lp, l=l: attention_step(
+                c, lp["self_attn"], u, kd, vd, layer=l, table=table, page=write_page, offset=write_offset,
+                positions=lengths, valid_len=lengths + 1, attend=attend))
+        conv = conv.at[l].set(tail)
+    return head(c, params, x), {}, {"k": kd, "v": vd, "ssm": ssm, "conv": conv}
+
+
+# this model's own counters beside those every model's engine keeps: the slot state read and written (every
+# slot's, every layer's, every step), and the chunks of ``mamba_chunk_size`` positions that prefills put
+# through the chunked scan (a rung's, pad and all: the scan runs over the whole rung)
+STEP_COUNTERS = ("ssm_state_bytes_rw", "prefill_scan_chunks")
+
+
+def step_counters(config: FalconH1Config, cache, lengths, counts) -> Dict[str, int]:
+    return {"ssm_state_bytes_rw": 2 * cache.state_bytes_per_slot() * cache.num_slots}
+
+
+def prefill_counters(config: FalconH1Config, bucket: int) -> Dict[str, int]:
+    return {"prefill_scan_chunks": bucket // config.mamba_chunk_size}

@@ -23,6 +23,15 @@ silu(causal depthwise conv1d(xBC) + b)``; ``dt = softplus(dt + dt_bias)``;
 per head ``h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T``, ``y_t = h_t C_t + D
 x_t``; ``y = rmsnorm(y * silu(z)) * w``; ``W_out y``.
 
+The Mamba-2 mixer's functions (``mamba2_prefill``, ``mamba2_step``,
+``ssd_chunked``, ``ssm_advance_xla`` and the helpers under them) are written
+for ``G = mamba_n_groups`` groups of B and C: head ``h`` reads group ``h // (H
+/ G)``, and the gated norm is taken over each group's ``d_inner / G`` channels.
+This model has one; ``models/falcon_h1.py``, which has two, imports them.
+Where there is one group ``B`` and ``C`` carry no group axis, ``(T, N)``, and
+the arithmetic is what it was before groups were written; where there are
+several they are ``(T, G, N)``.
+
 Precision: weights and matmul operands are ``config.dtype`` (bfloat16) with
 float32 accumulation; the residual stream, the norms, the gate, the router and
 everything of the state-space recurrence (decay, cumulative sums, the scan's
@@ -95,7 +104,7 @@ class GraniteHybridConfig:
         if not self.layer_types or set(self.layer_types) - {"mamba", "attention"}:
             raise ValueError(f"layer_types must name 'mamba' or 'attention', got {self.layer_types!r}")
         if self.mamba_n_groups != 1:
-            raise ValueError("this block shares one group of B and C between all heads (mamba_n_groups = 1)")
+            raise ValueError("this model shares one group of B and C between all heads (mamba_n_groups = 1)")
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
         if not (0 <= self.first_expert_held and self.first_expert_held + self.experts_held <= self.num_experts
@@ -225,27 +234,40 @@ def head(config: GraniteHybridConfig, params, x):
 
 
 # ------------------------------------------------------------ Mamba-2 mixer
-def _mamba_in(c: GraniteHybridConfig, mp, u):
-    """``[z | xBC | dt] = W_in u``; xBC in the weights' type, as the
+def _mamba_in(c, mp, u, scale=None):
+    """``[z | xBC | dt] = W_in u``, times ``scale`` (in_proj_dim,) where a
+    model multiplies the projection's segments; xBC in the weights' type, as the
     convolution tail is kept (prefill and decode then convolve the same values)."""
     zxbcdt = _mm(u, mp["in_proj"], c.dtype)
+    if scale is not None:
+        zxbcdt = zxbcdt * scale
     z = zxbcdt[..., : c.d_inner]
     xBC = zxbcdt[..., c.d_inner: c.d_inner + c.conv_dim].astype(c.dtype)
     dt = jax.nn.softplus(zxbcdt[..., c.d_inner + c.conv_dim:] + mp["dt_bias"].astype(F32))
     return z, xBC, dt
 
 
-def _mamba_split(c: GraniteHybridConfig, conv_out):
+def _mamba_split(c, conv_out):
+    """``x`` (..., H, P) and ``B``, ``C``: (..., N) of one group, (..., G, N) of several."""
     act = jax.nn.silu(conv_out)
+    G, GN = c.mamba_n_groups, c.mamba_n_groups * c.mamba_d_state
     x = act[..., : c.d_inner].reshape(act.shape[:-1] + (c.mamba_n_heads, c.mamba_d_head))
-    B = act[..., c.d_inner: c.d_inner + c.mamba_d_state]
-    C = act[..., c.d_inner + c.mamba_d_state:]
+    B = act[..., c.d_inner: c.d_inner + GN]
+    C = act[..., c.d_inner + GN:]
+    if G > 1:
+        B, C = (a.reshape(a.shape[:-1] + (G, c.mamba_d_state)) for a in (B, C))
     return x, B, C
 
 
-def _mamba_out(c: GraniteHybridConfig, mp, y, z):
-    """Gate first, then the norm over all of ``d_inner``, then ``W_out``."""
-    y = rmsnorm(y.reshape(y.shape[:-2] + (c.d_inner,)) * jax.nn.silu(z), mp["norm_weight"], c.rms_norm_eps)
+def _mamba_out(c, mp, y, z):
+    """Gate first, then the norm over each group's share of ``d_inner`` (all of
+    it where there is one group), then ``W_out``."""
+    y = y.reshape(y.shape[:-2] + (c.d_inner,)) * jax.nn.silu(z)
+    if c.mamba_n_groups == 1:
+        y = rmsnorm(y, mp["norm_weight"], c.rms_norm_eps)
+    else:
+        grouped = y.shape[:-1] + (c.mamba_n_groups, c.d_inner // c.mamba_n_groups)
+        y = rmsnorm(y.reshape(grouped), mp["norm_weight"].reshape(grouped[-2:]), c.rms_norm_eps).reshape(y.shape)
     return _mm(y, mp["out_proj"], c.dtype)
 
 
@@ -254,9 +276,26 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, initial_state=None):
     h_t C_t`` over one sequence by chunks (Mamba-2's state-space duality):
     inside a chunk a masked, decay-weighted ``(C B^T)`` product, between
     chunks a scan over the chunk states.  ``x`` (T, H, P), ``dt`` (T, H),
-    ``A`` (H,), ``B`` and ``C`` (T, N); T a multiple of ``chunk``.  Returns
-    ``y`` (T, H, P) and the state after the last position (H, P, N), float32.
-    A position whose ``dt`` is 0 decays nothing and adds nothing."""
+    ``A`` (H,), ``B`` and ``C`` (T, N), or (T, G, N) where the heads read ``G``
+    groups (head ``h`` group ``h // (H / G)``); T a multiple of ``chunk``.
+    Returns ``y`` (T, H, P) and the state after the last position (H, P, N),
+    float32.  A position whose ``dt`` is 0 decays nothing and adds nothing."""
+    if B.ndim == 2:
+        return _ssd_one_group(x, dt, A, B, C, chunk, initial_state)
+    T, H, P = x.shape
+    G, N = B.shape[1:]
+    if H % G:
+        raise ValueError(f"{H} heads do not divide into {G} groups")
+    # a group is a scan of its own over its heads: the one-group arithmetic, once a group
+    heads = lambda a: a.reshape(a.shape[:1] + (G, H // G) + a.shape[2:])
+    h0 = jnp.zeros((H, P, N), F32) if initial_state is None else initial_state
+    y, last = jax.vmap(lambda *group: _ssd_one_group(*group[:5], chunk, group[5]), in_axes=(1, 1, 0, 1, 1, 0),
+                       out_axes=(1, 0))(heads(x), heads(dt), A.reshape(G, H // G), B, C, h0.reshape(G, H // G, P, N))
+    return y.reshape(T, H, P), last.reshape(H, P, N)
+
+
+def _ssd_one_group(x, dt, A, B, C, chunk: int, initial_state=None):
+    """:func:`ssd_chunked` where every head reads the same ``B`` and ``C`` (T, N)."""
     T, H, P = x.shape
     N = B.shape[-1]
     if T % chunk:
@@ -284,15 +323,16 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, initial_state=None):
     return y.reshape(T, H, P), last
 
 
-def mamba2_prefill(c: GraniteHybridConfig, mp, u, length):
+def mamba2_prefill(c, mp, u, length, *, in_scale=None):
     """One sequence ``u`` (T, E), T a multiple of the chunk, of which the
     first ``length`` positions are real.  In the pad ``dt`` is forced to 0, so
     the state stands where the prompt ends, and the convolution tail is taken
     from the prompt's last ``d_conv - 1`` real inputs (zeros before its
-    start).  Returns the mixer's output (T, E), the state in the cache's
-    layout (N, H P) and type, and the tail (d_conv - 1, conv_dim)."""
+    start).  ``in_scale`` as :func:`_mamba_in` takes it.  Returns the mixer's
+    output (T, E), the state in the cache's layout (N, H P) and type, and the
+    tail (d_conv - 1, conv_dim)."""
     T, K = u.shape[0], c.mamba_d_conv
-    z, xBC, dt = _mamba_in(c, mp, u)
+    z, xBC, dt = _mamba_in(c, mp, u, in_scale)
     dt = jnp.where((jnp.arange(T) < length)[:, None], dt, 0.0)
     padded = jnp.concatenate([jnp.zeros((K - 1, c.conv_dim), xBC.dtype), xBC], axis=0)
     tail = jax.lax.dynamic_slice_in_dim(padded, length, K - 1, axis=0)
@@ -305,23 +345,35 @@ def mamba2_prefill(c: GraniteHybridConfig, mp, u, length):
     return _mamba_out(c, mp, y, z), state.astype(c.state_dtype), tail
 
 
+def _over_lanes(a, lanes: int):
+    """``B`` or ``C`` laid against the state's lanes: (S, N) -> (S, N, 1), one
+    column for all; (S, G, N) -> (S, N, J), each group's column over the ``J /
+    G`` lanes of its heads."""
+    if a.ndim == 2:
+        return a[:, :, None]
+    return jnp.repeat(a.transpose(0, 2, 1), lanes // a.shape[1], axis=2)
+
+
 def ssm_advance_xla(ssm, decay, dtx, B, C, *, layer: int):
     """``h' = decay * h + B (x) dtx`` and ``y = sum_n h' C`` for every slot, on
     the ``layer``-th state of ``ssm`` (layers, S, N, J); ``decay`` and ``dtx``
-    (S, J), ``B`` and ``C`` (S, N).  Returns ``ssm`` with that layer advanced
-    and ``y`` (S, J).  The XLA leg of ``kernels.ssm_step`` (which reads and
-    writes the state once; this reads it twice)."""
-    h = decay[:, None, :] * ssm[layer].astype(F32) + B[:, :, None] * dtx[:, None, :]
-    return ssm.at[layer].set(h.astype(ssm.dtype)), jnp.sum(h * C[:, :, None], axis=1)
+    (S, J), ``B`` and ``C`` (S, N), or (S, G, N) where the lanes lie in ``G``
+    groups.  Returns ``ssm`` with that layer advanced and ``y`` (S, J).  The XLA
+    leg of ``kernels.ssm_step`` (which reads and writes the state once; this
+    reads it twice)."""
+    J = ssm.shape[-1]
+    h = decay[:, None, :] * ssm[layer].astype(F32) + _over_lanes(B, J) * dtx[:, None, :]
+    return ssm.at[layer].set(h.astype(ssm.dtype)), jnp.sum(h * _over_lanes(C, J), axis=1)
 
 
-def mamba2_step(c: GraniteHybridConfig, mp, u, ssm, tail, *, layer: int, advance=ssm_advance_xla):
+def mamba2_step(c, mp, u, ssm, tail, *, layer: int, advance=ssm_advance_xla, in_scale=None):
     """The recurrence's one step for every slot: ``u`` (S, E), ``ssm`` the
     states of all state-space layers (layers, S, N, H P) of which this mixer's
     is the ``layer``-th, ``tail`` (S, d_conv - 1, conv_dim).
     ``advance(ssm, decay, dtx, B, C, layer=)`` moves the state (the kernel on
-    TPU).  Returns the output (S, E), ``ssm`` and the tail, advanced."""
-    z, xBC, dt = _mamba_in(c, mp, u)
+    TPU); ``in_scale`` as :func:`_mamba_in` takes it.  Returns the output (S,
+    E), ``ssm`` and the tail, advanced."""
+    z, xBC, dt = _mamba_in(c, mp, u, in_scale)
     window = jnp.concatenate([tail, xBC[:, None, :].astype(tail.dtype)], axis=1)      # (S, K, conv_dim)
     conv = mp["conv_bias"].astype(F32) + jnp.sum(mp["conv_weight"].astype(F32)[None] * window.astype(F32), axis=1)
     x, B, C = _mamba_split(c, conv)

@@ -4,7 +4,7 @@ MODULE, and this class keeps what every such model needs once: the prefill
 ladder, ``warm()``, donation, the ``vs.serve-*`` spans and ``trace_counters()``;
 ``decode`` itself, one step deep (a call launches its step and returns the
 ``DecodeStep`` unread; a ``DecodeFeed`` feeds the next from the device), is
-``engine.DecodeAhead``'s, shared with ``ServeEngine``.  Three models plug in today:
+``engine.DecodeAhead``'s, shared with ``ServeEngine``.  Four models plug in today:
 
   * ``models/granite_hybrid.py`` (the class's name is from it): state-space
     mixers with a per-slot recurrent state beside the paged K/V of their few
@@ -16,7 +16,11 @@ ladder, ``warm()``, donation, the ``vs.serve-*`` spans and ``trace_counters()``;
   * ``models/sdar_moe.py``: generation by DIFFUSION OVER BLOCKS.  A decode
     step is a pass over every slot's open block of ``B`` positions and yields
     none or up to ``B`` tokens a slot ("A block engine", below); grouped-query
-    attention with a per-head norm over paged K/V, 128 routed experts a layer.
+    attention with a per-head norm over paged K/V, 128 routed experts a layer;
+  * ``models/falcon_h1.py``: a state-space mixer AND a rotary grouped-query
+    attention mixer side by side in EVERY layer, so every layer owns a row of
+    the state arrays and a layer of the K/V pools; two groups of B and C; a
+    dense MLP (no experts: the engine's ``moe_*`` counters stay 0).
 
 A second engine class beside :class:`ServeEngine`, behind the same surface
 (``prefill(prompt, slot)``, ``decode(tokens)``, ``params``,
@@ -48,7 +52,8 @@ gives, as plain functions of the config:
       ``write_offset`` (S,) where each slot's new position lands (the null
       page for a slot that may not write: the engine reckons it, once);
       ``counts["experts"]`` (expert layers, held) is the tokens each held
-      expert got, whatever else ``counts`` holds is the model's own;
+      expert got (a dense model leaves it out), whatever else ``counts`` holds
+      is the model's own;
   ``STEP_COUNTERS``, ``step_counters(config, cache, lengths, counts)``,
   ``prefill_counters(config, bucket)``
       the names of the model's own counters and what one decode step, and one
@@ -207,7 +212,9 @@ class HybridServeEngine(DecodeAhead):
         decode_rows = cache.num_slots           # ... a block engine's: every slot's open rows and the commit places'
         if self.block is not None:
             decode_rows = (cache.num_slots + self.block.commit_places(cache.num_slots)) * self.block.B
-        self._decode_padded_candidate = padded_candidate(decode_rows, c.num_experts_per_tok, c.experts_held)
+        # (a dense model's config names no experts, its steps return no ``counts["experts"]``, and ``moe_*`` stay 0)
+        self._decode_padded_candidate = hasattr(c, "experts_held") and padded_candidate(
+            decode_rows, c.num_experts_per_tok, c.experts_held)
         self._fits_pad = fits_pad
         # what this engine has done, in plain integers (``trace_counters``)
         self.counter_names = COUNTERS + (BLOCK_COUNTERS if self.block is not None else ()) + tuple(self.model.STEP_COUNTERS)
@@ -363,7 +370,7 @@ class HybridServeEngine(DecodeAhead):
 
     def _count_step(self, lengths: np.ndarray, counts, note=None) -> None:
         c = self.config
-        experts = counts["experts"]                 # (expert layers, held): tokens an expert got
+        experts = counts.get("experts")             # (expert layers, held): tokens an expert got; a dense model: none
         positions = int((lengths > 0).sum())        # that went through the stack for a request: one an active slot
         if self.block is None:
             super()._count_step(lengths, counts)
@@ -378,14 +385,15 @@ class HybridServeEngine(DecodeAhead):
             # slot fused), and the commit places', up to the end of the block they commit (an unused one: nothing)
             end = lengths // B * B + B
             self._count_pages(end + B * fused, end[fused])
-        self.moe_assignments += positions * c.num_experts_per_tok * experts.shape[0]
-        self.moe_assignments_held += int(experts.sum())
-        self.moe_busiest_expert_tokens += int(experts.max(axis=1).sum())
-        self.moe_expert_slots += int(experts.size)
-        self.moe_layer_steps += int(experts.shape[0])
-        self.moe_experts_touched += int((experts > 0).sum())
-        if self._decode_padded_candidate:           # the device's own predicate, on the integers it read
-            self.moe_padded_layer_steps += int(self._fits_pad(experts).sum())
+        if experts is not None:
+            self.moe_assignments += positions * c.num_experts_per_tok * experts.shape[0]
+            self.moe_assignments_held += int(experts.sum())
+            self.moe_busiest_expert_tokens += int(experts.max(axis=1).sum())
+            self.moe_expert_slots += int(experts.size)
+            self.moe_layer_steps += int(experts.shape[0])
+            self.moe_experts_touched += int((experts > 0).sum())
+            if self._decode_padded_candidate:           # the device's own predicate, on the integers it read
+                self.moe_padded_layer_steps += int(self._fits_pad(experts).sum())
         self._add(self.model.step_counters(c, self.cache, lengths, counts))
 
     def trace_counters(self) -> Dict[str, int]:
