@@ -512,6 +512,38 @@ def test_the_cache_settles_a_block_at_a_time_inside_the_reserved_pages(system):
     cache.reset()
 
 
+# (L, KV, hd, page, T, reserved): SDAR's toy rungs over its pages of 8, Falcon-H1's over its pages of 4 (both two layers,
+# two key heads of 16), and the cells' own row, 4 key heads of 128 on pages of 16, in bfloat16 as they are served
+PAGE_WRITER_CASES = [(2, 2, 16, 8, 8, 1), (2, 2, 16, 8, 16, 2), (2, 2, 16, 8, 32, 3), (2, 2, 16, 8, 64, 5),
+                     (2, 2, 16, 4, 8, 2), (2, 2, 16, 4, 16, 3), (2, 2, 16, 4, 32, 5), (6, 4, 128, 16, 128, 3)]
+
+
+@pytest.mark.parametrize("L, KV, hd, page, T, reserved", PAGE_WRITER_CASES)
+def test_the_page_writer_leaves_what_the_scatter_leaves_on_every_page_but_the_null_page(L, KV, hd, page, T, reserved):
+    """``serve.kv_cache.write_pages`` (the prefill programs of SDAR and
+    Falcon-H1) against ``pool.at[:, page_row].set(...)``: a rung of ``T``
+    positions of which the slot has reserved ``reserved`` pages, so the tail of
+    ``page_row`` repeats page 0.  The loop writes the null page's slabs one
+    after another where the scatter writes them in no order; nobody reads that
+    page, and every other page, named or not, is the same."""
+    from vescale_tpu.serve.kv_cache import write_pages
+
+    dtype = jnp.bfloat16 if hd == 128 else jnp.float32
+    rng = np.random.default_rng(T + page)
+    n_pages = 2 * (T // page) + 3
+    pool = jnp.asarray(rng.standard_normal((L, n_pages, page, KV, hd)), dtype)
+    rows = jnp.asarray(rng.standard_normal((L, T, KV, hd)), jnp.float32)       # cast to the pool's type on the way in
+    named = rng.permutation(np.arange(1, n_pages))[:min(reserved, T // page)]
+    page_row = jnp.asarray(np.concatenate([named, np.zeros(T // page - len(named), np.int64)]), jnp.int32)
+    got = jax.jit(lambda pool, rows, page_row: write_pages(pool, rows, page_row, page), donate_argnums=0)(
+        jnp.copy(pool), rows, page_row)
+    want = pool.at[:, page_row].set(rows.reshape(L, T // page, page, KV, hd).astype(dtype))
+    assert got.shape == pool.shape and got.dtype == pool.dtype
+    np.testing.assert_array_equal(np.asarray(got[:, 1:], np.float32), np.asarray(want[:, 1:], np.float32))
+    untouched = np.setdiff1d(np.arange(1, n_pages), named)
+    np.testing.assert_array_equal(np.asarray(got[:, untouched], np.float32), np.asarray(pool[:, untouched], np.float32))
+
+
 # --------------------------------------------------- the flash forward's mask
 @pytest.mark.parametrize("streaming", [False, True], ids=["resident", "streaming"])
 def test_the_flash_forward_under_the_block_mask_is_the_dense_softmax_under_it(streaming):

@@ -193,14 +193,11 @@ class _JaxOnATpu:
         return [types.SimpleNamespace(platform="tpu")]
 
 
-@pytest.mark.parametrize("program,kernels_in_it", [("decode step", 12), ("bucket of 512", 6)], ids=["decode", "rung512"])
-def test_falcon_h1s_decode_program_and_a_rung_compile_at_the_cells_size(chip, program, kernels_in_it):
-    """``falconh1_34b_serve_batch``'s decode step (128 slots x 1536 positions:
-    six ``ssm_step`` and six ``paged_decode`` kernels) and the 512 rung of its
-    prefill ladder (six grouped-query flash forwards at 20 / 4 heads), from
-    shapes alone, as ``benchmark/rehearse.py`` lowers them.  The program asks
-    ``jax.devices()`` for its platform and would take its CPU legs here, so the
-    test answers for it while the programs are traced."""
+def _cells_programs(chip, cell):
+    """A serve cell's programs from shapes alone, as ``benchmark/rehearse.py``
+    lowers them: ``(family, config, sizes, [(title, lowered)])``.  The program
+    asks ``jax.devices()`` for its platform and would take its CPU legs here, so
+    this answers for it while the programs are traced."""
     import importlib
     from unittest import mock
 
@@ -208,26 +205,58 @@ def test_falcon_h1s_decode_program_and_a_rung_compile_at_the_cells_size(chip, pr
     from vescale_tpu import kernels
 
     flash_ops = importlib.import_module("vescale_tpu.ops.flash_attention")    # (``ops`` exports the function under this name)
-    spec = load_cell("falconh1_34b_serve_batch")
+    spec = load_cell(cell)
     family, config = spec.family(), spec.config
     (device,) = chip.device_set
     with mock.patch.object(kernels, "on_tpu", lambda: True), mock.patch.object(flash_ops, "jax", _JaxOnATpu()):
         sizes, programs = family.rehearse_serve(spec.name, config, config["serve"], [device])
+    return family, config, sizes, programs
+
+
+def _assert_in_place_and_fits(compiled, sizes, pool):
+    """The pools are written in place: no copy of one (``pool``, its shape as
+    the compiled text writes it) to another layout and back around a scatter
+    over the page axis, the cache's bytes aliased, and 16 GB of HBM hold the
+    arguments (weights, pools, state) and the program's temporaries, with room
+    for the logits."""
+    assert not [line for line in compiled.as_text().splitlines() if " copy(" in line and f"= {pool}" in line]
+    memory = compiled.memory_analysis()
+    cache_bytes = sizes["kv_pool_bytes"] + sizes["slot_state_bytes"]
+    assert memory.argument_size_in_bytes >= sum(sizes.values()) and memory.alias_size_in_bytes >= cache_bytes, memory
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9 and memory.temp_size_in_bytes < 0.5e9, memory
+
+
+@pytest.mark.parametrize("program,kernels_in_it", [("decode step", 12), ("bucket of 512", 6)], ids=["decode", "rung512"])
+def test_falcon_h1s_decode_program_and_a_rung_compile_at_the_cells_size(chip, program, kernels_in_it):
+    """``falconh1_34b_serve_batch``'s decode step (128 slots x 1536 positions:
+    six ``ssm_step`` and six ``paged_decode`` kernels) and the 512 rung of its
+    prefill ladder (six grouped-query flash forwards at 20 / 4 heads)."""
+    family, config, sizes, programs = _cells_programs(chip, "falconh1_34b_serve_batch")
     titles = [title for title, _ in programs]
     assert sum("prefill, bucket of" in t for t in titles) == 5 and "decode step, 128 slots x 1536 positions" in titles[-1]
     assert sizes["weights_bytes"] == family.weight_bytes(config)
     assert sizes["slot_state_bytes"] == 128 * family.state_bytes_per_slot(config, config["serve"])
     (lowered,) = [low for title, low in programs if program in title]
     compiled = lowered.compile()
-    text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == kernels_in_it
-    # the pools are written in place: no copy of one (1.2 GB each) to another layout and back around a scatter
-    assert not [line for line in text.splitlines() if " copy(" in line and "= bf16[6,12289,16,4,128]" in line]
-    memory = compiled.memory_analysis()
-    cache_bytes = sizes["kv_pool_bytes"] + sizes["slot_state_bytes"]
-    assert memory.argument_size_in_bytes >= sum(sizes.values()) and memory.alias_size_in_bytes >= cache_bytes   # in place
-    # 16 GB of HBM: the arguments (weights, pools, state) and the program's temporaries, with room for the logits
-    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9 and memory.temp_size_in_bytes < 0.5e9, memory
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == kernels_in_it
+    _assert_in_place_and_fits(compiled, sizes, "bf16[6,12289,16,4,128]")        # 1.2 GB a pool
+
+
+def test_sdars_rung_of_512_compiles_at_the_cells_size_and_writes_its_pools_in_place(chip):
+    """``sdar30b_serve_blockgen``'s 512 rung (six flash forwards under the
+    block mask; the expert layers' sorted products are the compiler's own
+    ``ragged-dot``).  Its pools have Falcon-H1's row, 4 key heads of 128, and
+    go through the same page writer: a scatter cost FOUR copies of a 1.6 GB
+    pool a prefill here (PERF.md section 6, PR 44)."""
+    family, config, sizes, programs = _cells_programs(chip, "sdar30b_serve_blockgen")
+    titles = [title for title, _ in programs]
+    assert sum("prefill, rung of" in t for t in titles) == 6 and "one pass, 128 slots x 4 positions" in titles[-1]
+    assert sizes["weights_bytes"] == family.weight_bytes(config)
+    (lowered,) = [low for title, low in programs if "rung of 512" in title]
+    compiled = lowered.compile()
+    kernel_calls = [line for line in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("block_flash_fwd" in line for line in kernel_calls) == 6
+    _assert_in_place_and_fits(compiled, sizes, "bf16[6,16385,16,4,128]")        # 1.6 GB a pool
 
 
 # ------------------------------------------------------------ fused adamw

@@ -322,29 +322,14 @@ def decode_kernels(config: FalconH1Config, cache) -> Dict[str, Any]:
     }
 
 
-def _write_pages(pool, rows, page_row, page: int):
-    """``rows`` (L, T, KV, hd), every layer's K or V of a rung's positions, into
-    the pages ``page_row`` (T / page,) of ``pool`` (L, pages, page, KV, hd): one
-    slab of all layers a page, updated in place.  (One scatter over the page
-    axis makes the compiler re-lay out the WHOLE pool and back around it where
-    a row of the pool is 4 heads wide, 3.7 ms a copy at this pool's 1.2 GB:
-    PERF.md section 6, PR 43.)"""
-    L, T = rows.shape[:2]
-    slabs = rows.reshape(L, T // page, page, *rows.shape[2:]).astype(pool.dtype)
-
-    def one_page(p, pool):
-        slab = jax.lax.dynamic_slice_in_dim(slabs, p, 1, axis=1)
-        return jax.lax.dynamic_update_slice(pool, slab, (0, page_row[p], 0, 0, 0))
-
-    return jax.lax.fori_loop(0, T // page, one_page, pool)
-
-
 def serve_prefill(c: FalconH1Config, params, arrays, tokens, length, page_row, slot, *, page: int,
                   interpret: Optional[bool] = None):
     """The prefill program's body: ``tokens`` (bucket,) through the stack; every
     layer's K and V of the bucket's positions go to the slot's pages, its state
     and tail to the slot's rows.  Returns the last real position's logits row
     and the cache's arrays."""
+    from ..serve.kv_cache import write_pages
+
     kd, vd, ssm, conv = arrays["k"], arrays["v"], arrays["ssm"], arrays["conv"]
     scale = in_scale(c)
     x = embed(c, params, tokens)
@@ -361,7 +346,7 @@ def serve_prefill(c: FalconH1Config, params, arrays, tokens, length, page_row, s
         vs.append(v)
     last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=0, keepdims=True)
     logits = head(c, params, last)[0]
-    kd, vd = _write_pages(kd, jnp.stack(ks), page_row, page), _write_pages(vd, jnp.stack(vs), page_row, page)
+    kd, vd = write_pages(kd, jnp.stack(ks), page_row, page), write_pages(vd, jnp.stack(vs), page_row, page)
     ssm = jax.lax.dynamic_update_slice_in_dim(ssm, jnp.stack(states)[:, None].astype(ssm.dtype), slot, axis=1)
     conv = jax.lax.dynamic_update_slice_in_dim(conv, jnp.stack(tails)[:, None].astype(conv.dtype), slot, axis=1)
     return logits, {"k": kd, "v": vd, "ssm": ssm, "conv": conv}

@@ -350,6 +350,8 @@ def serve_prefill(c: SdarMoeConfig, params, arrays, tokens, length, page_row, sl
     rewrites them); the slot's open block is the prompt's last ``length mod B``
     tokens, revealed, and masks.  Returns position ``length - 1``'s logits row
     (not shifted) and the cache's arrays."""
+    from ..serve.kv_cache import write_pages
+
     B, T = c.block_length, tokens.shape[0]
     position = jnp.arange(T, dtype=jnp.int32)
     ids = jnp.where(position < length, tokens, c.mask_token_id).astype(jnp.int32)
@@ -361,9 +363,8 @@ def serve_prefill(c: SdarMoeConfig, params, arrays, tokens, length, page_row, sl
         ks.append(k)
         vs.append(v)
     logits = head(c, params, jax.lax.dynamic_index_in_dim(x, length - 1, axis=0, keepdims=True))[0]
-    pages = lambda stack: jnp.stack(stack).reshape(len(stack), -1, page, *stack[0].shape[1:])
-    kd = arrays["k"].at[:, page_row].set(pages(ks).astype(arrays["k"].dtype))
-    vd = arrays["v"].at[:, page_row].set(pages(vs).astype(arrays["v"].dtype))
+    kd = write_pages(arrays["k"], jnp.stack(ks), page_row, page)
+    vd = write_pages(arrays["v"], jnp.stack(vs), page_row, page)
     revealed = length % B
     opened = jax.lax.dynamic_slice_in_dim(jnp.pad(ids, (0, B)), length - revealed, B)
     j = jnp.arange(B)

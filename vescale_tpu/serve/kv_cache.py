@@ -185,6 +185,30 @@ def _idx_shape(idx, shape) -> Tuple[int, ...]:
     return tuple(len(range(*s.indices(n))) for s, n in zip(idx, shape))
 
 
+def write_pages(pool, rows, page_row, page: int):
+    """``rows`` (L, T, KV, hd), every layer's K or V of a rung's positions, into
+    the pages ``page_row`` (T / page,) of ``pool`` (L, pages, page, KV, hd): one
+    slab of all layers a page, updated in place.  (One scatter over the page
+    axis makes the compiler re-lay out the WHOLE pool and back around it where
+    a row of the pool is 4 heads wide, 3.7 ms a copy at a pool of 1.2 GB:
+    PERF.md section 6, PR 43.)  The prefill programs of ``models/falcon_h1.py``
+    and ``models/sdar_moe.py`` write through it; a family whose compiled rung
+    holds no such copy (pools with rows of 8 or 32 heads, or the latent form's
+    one row) keeps its scatter, which has no loop to run (PERF.md section 6,
+    PR 44).  Entries of ``page_row`` past a slot's reserved pages name page 0,
+    the null page: they land there one after another, and nobody reads it."""
+    import jax
+
+    L, T = rows.shape[:2]
+    slabs = rows.reshape(L, T // page, page, *rows.shape[2:]).astype(pool.dtype)
+
+    def one_page(p, pool):
+        slab = jax.lax.dynamic_slice_in_dim(slabs, p, 1, axis=1)
+        return jax.lax.dynamic_update_slice(pool, slab, (0, page_row[p], 0, 0, 0))
+
+    return jax.lax.fori_loop(0, T // page, one_page, pool)
+
+
 class PagedKVCache:
     """Slot-allocated paged K/V storage + deterministic host bookkeeping.
 
