@@ -259,6 +259,34 @@ def test_sdars_rung_of_512_compiles_at_the_cells_size_and_writes_its_pools_in_pl
     _assert_in_place_and_fits(compiled, sizes, "bf16[6,16385,16,4,128]")        # 1.6 GB a pool
 
 
+@pytest.mark.parametrize("program,kernels_in_it", [("decode step", 5), ("rung of 512 positions", 5)], ids=["decode", "rung512"])
+def test_lagunas_decode_program_and_a_rung_compile_at_the_cells_size_and_copy_no_pool_of_either_kind(chip, program, kernels_in_it):
+    """``lagunaxs2_serve_mixedlen``'s decode step (128 slots: two ``paged_decode``
+    at 48 query heads over the pages, three at 64 over the rings read as pages)
+    and the 512 rung of its prefill ladder (two causal flash forwards, three
+    ``window_flash_fwd``).  Neither holds a copy of a pool of EITHER kind: the
+    full layers' pages (rows of 8 key heads, through ``write_pages``) or the
+    sliding layers' rings (a slot's rows rewritten by one ``dynamic_update_slice``
+    a prefill, one row a slot by a scatter a step, read through a reshape)."""
+    family, config, sizes, programs = _cells_programs(chip, "lagunaxs2_serve_mixedlen")
+    titles = [title for title, _ in programs]
+    assert sum("prefill, rung of" in t for t in titles) == 12 and "decode step, 128 slots x 8192 positions" in titles[-1]
+    assert sizes["weights_bytes"] == family.weight_bytes(config)
+    assert sizes["kv_pool_bytes"] + sizes["slot_state_bytes"] == family.cache_bytes(config, config["serve"])
+    (lowered,) = [low for title, low in programs if program in title]
+    compiled = lowered.compile()
+    kernel_calls = [line for line in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    ours = [line for line in kernel_calls if "ragged-dot" not in line.split(" = ")[0]]      # (the sorted experts' products are the compiler's own)
+    assert len(ours) == kernels_in_it
+    if "rung" in program:
+        assert sum("window_flash_fwd" in line for line in ours) == 3
+    else:
+        assert sum("f32[128,64,128]" in line for line in ours) == 3 and sum("f32[128,48,128]" in line for line in ours) == 2
+    _assert_in_place_and_fits(compiled, sizes, "bf16[2,28672,16,8,128]")        # 1.88 GB a pool
+    for ring in ("bf16[3,128,512,8,128]", "bf16[3,4096,16,8,128]"):             # 0.40 GB a ring, as the cache and as the kernel see it
+        assert not [line for line in compiled.as_text().splitlines() if " copy(" in line and f"= {ring}" in line]
+
+
 # ------------------------------------------------------------ fused adamw
 @pytest.mark.parametrize("shape", [(4096, 14336), (4097,)], ids=["ffn-leaf", "ragged-tail"])
 def test_fused_adamw_compiles(chip, shape):

@@ -25,6 +25,7 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = [
     "_NEG_INF",
     "BLOCK_MASK_NAME",
+    "WINDOW_NAME",
     "_use_streaming",
     "_flash_fwd_pallas",
     "_flash_bwd_pallas",
@@ -33,6 +34,7 @@ __all__ = [
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/where VPU-safe
 BLOCK_MASK_NAME = "block_flash_fwd"     # the forward under a block mask, as the device trace names it
+WINDOW_NAME = "window_flash_fwd"        # ... and under a sliding window
 
 
 # ------------------------------------------------------------------ forward
@@ -44,7 +46,8 @@ def _visible_to(q_pos, mask_block: int):
     return q_pos if mask_block == 1 else (q_pos // mask_block + 1) * mask_block - 1
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, block_k, seq_len, mask_block=1):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, block_k, seq_len, mask_block=1,
+                window=None):
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * scale  # (block_q, D)
     D = q.shape[-1]
@@ -61,6 +64,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, 
     acc0 = jnp.zeros((block_q, D), jnp.float32)
     # the mask's blocks divide the tiles (the caller checks), so a tile's last row still bounds what its rows see
     q_pos = _visible_to(qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0), mask_block)
+    # a sliding window (static, causal; a row sees the ``window`` newest positions, itself among them): the key
+    # blocks before the one that holds the tile's first row's oldest visible position are not visited
+    first = 0 if window is None else jnp.maximum(qi * block_q - (window - 1), 0) // block_k
 
     def body(j, carry):
         m, l, acc = carry
@@ -69,7 +75,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
         if causal:
             k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            keep = q_pos >= k_pos if window is None else (q_pos >= k_pos) & (q_pos - k_pos < window)
+            s = jnp.where(keep, s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[:, None])
         alpha = jnp.exp(m - m_new)
@@ -79,7 +86,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, 
         )
         return m_new, l_new, acc_new
 
-    m, l, acc = jax.lax.fori_loop(0, nk, body, (m0, l0, acc0))
+    m, l, acc = jax.lax.fori_loop(first, nk, body, (m0, l0, acc0))
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
     # (1, block_q, 1) block: trailing singleton satisfies TPU tiling rules
@@ -138,7 +145,7 @@ def _use_streaming(kernel: str, T: int, D: int, dtype, block_q: int, block_k: in
 
 
 def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                       *, scale, causal, block_q, block_k, seq_len, mask_block=1):
+                       *, scale, causal, block_q, block_k, seq_len, mask_block=1, window=None):
     """Streaming forward: grid (BH, nq, nk) — k/v arrive one block per grid
     step; online-softmax state lives in VMEM scratch across the nk steps."""
     qi = pl.program_id(1)
@@ -159,7 +166,8 @@ def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_sc
         if causal:
             q_pos = _visible_to(qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0), mask_block)
             k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            keep = q_pos >= k_pos if window is None else (q_pos >= k_pos) & (q_pos - k_pos < window)
+            s = jnp.where(keep, s, _NEG_INF)
         m_prev = m_scr[:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[:, None])
@@ -172,8 +180,12 @@ def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_sc
 
     if causal:
         # blocks fully above the diagonal contribute nothing; skip compute
-        # (the DMA for the block still happens — data-independent grid)
-        pl.when(j * block_k <= qi * block_q + block_q - 1)(compute)
+        # (the DMA for the block still happens — data-independent grid); under a window so do the
+        # blocks wholly older than the tile's first row's oldest visible position
+        live = j * block_k <= qi * block_q + block_q - 1
+        if window is not None:
+            live = jnp.logical_and(live, j * block_k + block_k - 1 >= qi * block_q - (window - 1))
+        pl.when(live)(compute)
     else:
         compute()
 
@@ -186,14 +198,17 @@ def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_sc
 
 
 def _flash_fwd_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret, H, KV,
-                      streaming=None, mask_block=1):
+                      streaming=None, mask_block=1, window=None):
     """q3: (B*H, T, D); k3/v3: (B*KV, T, D) — GQA never materializes the
     repeated K/V heads; the BlockSpec index map routes each q head to its
     kv group (rows are consecutive per group, llama repeat convention).
     ``mask_block`` > 1 (with ``causal``) is the mask of generation by diffusion
     over blocks: a row sees the keys up to the end of its own block of that
-    many positions.  A static parameter: at its default the kernels are traced
-    as they were before it existed."""
+    many positions.  ``window`` (with ``causal``, and no block mask) is a sliding
+    window: a row sees the ``window`` newest positions, itself among them, and
+    the key loop starts at the block that holds the oldest of them.  Both are
+    static parameters: at their defaults the kernels are traced as they were
+    before either existed."""
     BH, T, D = q3.shape
     rep = H // KV
     if streaming is None:
@@ -205,6 +220,10 @@ def _flash_fwd_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret, H,
             raise ValueError(f"a block mask of {mask_block} positions is causal over blocks that divide the tiles "
                              f"({block_q} x {block_k})")
         kw["mask_block"], name = mask_block, BLOCK_MASK_NAME
+    if window is not None:
+        if not causal or mask_block != 1 or window < 1:
+            raise ValueError(f"a window of {window} positions is causal, of 1 or more positions, and takes no block mask")
+        kw["window"], name = int(window), WINDOW_NAME
     out_shape = (
         jax.ShapeDtypeStruct(q3.shape, q3.dtype),
         jax.ShapeDtypeStruct((BH, T, 1), jnp.float32),

@@ -62,7 +62,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["route_topk", "route_group_limited", "dropless_experts", "padded_candidate", "fits_pad", "DENSE_MAX_TOKENS", "ROW_PAD",
+__all__ = ["route_topk", "route_group_limited", "route_sigmoid_topk", "dropless_experts", "padded_candidate", "fits_pad", "DENSE_MAX_TOKENS", "ROW_PAD",
            "PADDED_MAX_MEAN_ROWS"]
 
 # one row tile of a grouped product: up to here each touched expert costs it a tile, sorted or not
@@ -104,6 +104,17 @@ def route_group_limited(scores, k: int, *, n_group: int, topk_group: int, scale:
     kept = jnp.zeros((N, n_group), bool).at[jnp.arange(N)[:, None], groups].set(True)
     top, idx = jax.lax.top_k(jnp.where(jnp.repeat(kept, E // n_group, axis=1), probs, 0.0), k)
     return idx.astype(jnp.int32), top * scale, kept
+
+
+def route_sigmoid_topk(scores, k: int, *, scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid routing: each of a token's router scores (N, E) through a
+    float32 sigmoid on its own (no softmax over the experts), the ``k`` largest,
+    their gates renormalised to sum 1 over those ``k``, times ``scale`` (the
+    convention a ``routed_scaling_factor`` is published under where the scores
+    are sigmoids); no groups, no selection bias.  Returns ids (N, k) int32 and
+    gates (N, k) float32, as :func:`route_topk` does."""
+    top, idx = jax.lax.top_k(jax.nn.sigmoid(scores.astype(jnp.float32)), k)
+    return idx.astype(jnp.int32), top * (scale / jnp.sum(top, axis=-1, keepdims=True))
 
 
 def padded_candidate(N: int, k: int, held: int) -> bool:

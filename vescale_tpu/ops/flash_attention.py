@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from ..kernels.flash_attention import (  # noqa: F401  (re-exported for tests)
     BLOCK_MASK_NAME,
+    WINDOW_NAME,
     _NEG_INF,
     _flash_bwd_pallas,
     _flash_fwd_pallas,
@@ -82,11 +83,13 @@ def _from3(x, B, H):
     return jnp.transpose(x.reshape(B, H, T, D), (0, 2, 1, 3))
 
 
-def _xla_fwd_4d(q, k, v, scale, causal, mask_block=1):
+def _xla_fwd_4d(q, k, v, scale, causal, mask_block=1, window=None):
     """Dense (o, lse) with the kernel's GQA layout and lse convention —
     the fallback leg of the shared partition rule (mode != off only; the
     off-mode fallback is the bare ``_dense_ref``).  ``mask_block`` > 1: causal
-    over blocks of that many positions (:func:`_block_masked_forward`)."""
+    over blocks of that many positions (:func:`_masked_forward`);
+    ``window``: a row sees the ``window`` newest positions
+    (:func:`_masked_forward`)."""
     B, T, H, D = q.shape
     G = k.shape[2]
     rep = H // G
@@ -97,6 +100,9 @@ def _xla_fwd_4d(q, k, v, scale, causal, mask_block=1):
         if mask_block != 1:
             block = jnp.arange(T, dtype=jnp.int32) // mask_block
             mask = block[None, :] <= block[:, None]
+        if window is not None:
+            position = jnp.arange(T, dtype=jnp.int32)
+            mask = mask & (position[:, None] - position[None, :] < window)
         s = jnp.where(mask[None, None, None], s, _NEG_INF)
     m = jnp.max(s, axis=-1)
     p = jnp.exp(s - m[..., None])
@@ -290,13 +296,15 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, impl, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _block_masked_forward(q, k, v, scale, mask_block, block_q, block_k, interpret):
-    """The forward alone under the mask that is causal over BLOCKS of
-    ``mask_block`` positions (position i sees j iff ``j // mask_block <= i //
-    mask_block``): what a model that generates by diffusion over blocks
-    prefills under.  No custom_vjp and no partition rule (one device, never
-    differentiated); the GQA kernel on TPU or interpreted, the dense product
-    elsewhere."""
+def _masked_forward(q, k, v, scale, block_q, block_k, interpret, mask_block=1, window=None):
+    """The forward alone under one of the two masks a serve prefill brings:
+    causal over BLOCKS of ``mask_block`` positions (position i sees j iff ``j //
+    mask_block <= i // mask_block``: what a model that generates by diffusion
+    over blocks prefills under), or a causal sliding ``window`` (i sees j iff
+    ``0 <= i - j < window``: the window layers of a model that mixes window and
+    full attention; the kernel's key loop starts at the window's first block).
+    No partition rule (one device, never differentiated); the GQA kernel on TPU
+    or interpreted, the dense product elsewhere."""
     from .. import kernels as _kernels
 
     B, T, H, D = q.shape
@@ -306,10 +314,23 @@ def _block_masked_forward(q, k, v, scale, mask_block, block_q, block_k, interpre
     block_q, block_k = _fit_block(block_q, T), _fit_block(block_k, T)
     tiles = not (T % block_q or T % block_k or block_q % mask_block or block_k % mask_block)
     if (_kernels.on_tpu() or interpret) and tiles:
-        o3, _lse = _flash_fwd_pallas(_to3(q), _to3(k), _to3(v), scale, True, block_q, block_k, interpret, H, G,
-                                     mask_block=mask_block)
+        masks = {"mask_block": mask_block} if window is None else {"window": window}
+        o3, _lse = _flash_fwd_pallas(_to3(q), _to3(k), _to3(v), scale, True, block_q, block_k, interpret, H, G, **masks)
         return _from3(o3, B, H)
-    return _xla_fwd_4d(q, k, v, scale, True, mask_block)[0]
+    return _xla_fwd_4d(q, k, v, scale, True, mask_block, window)[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _windowed(q, k, v, scale, window, block_q, block_k, interpret):
+    return _masked_forward(q, k, v, scale, block_q, block_k, interpret, window=window)
+
+
+def _windowed_refuses_grad(*_args):
+    raise NotImplementedError("flash_attention(window=...) is forward only: the backward kernels know no window, so "
+                              "a model that trains through window attention has no path here yet")
+
+
+_windowed.defvjp(_windowed_refuses_grad, _windowed_refuses_grad)
 
 
 def flash_attention(
@@ -322,6 +343,7 @@ def flash_attention(
     block_k: int = 512,
     interpret: Optional[bool] = None,
     mask_block: int = 1,
+    window: Optional[int] = None,
 ):
     """Fused attention over (B, T, H, D) q with (B, T, G, D) k/v, G | H —
     GQA/MQA run natively: the kernels route each q head to its kv group via
@@ -337,17 +359,27 @@ def flash_attention(
 
     ``mask_block`` > 1 (static; ``causal`` must hold) makes the mask causal
     over blocks of that many positions, full inside a block, FORWARD ONLY
-    (:func:`_block_masked_forward`: a serve prefill's); at its default of 1
-    nothing of the path below changes."""
+    (:func:`_masked_forward`: a serve prefill's); at its default of 1
+    nothing of the path below changes.
+
+    ``window`` (static; ``causal`` must hold, no ``mask_block``) is a sliding
+    window: position i sees j iff ``0 <= i - j < window``, FORWARD ONLY
+    (:func:`_masked_forward`, the kernel named ``window_flash_fwd`` in a device
+    trace; differentiating through it raises ``NotImplementedError``); at its
+    default of None nothing of the path below changes."""
     B, T, H, D = q.shape
     G = k.shape[2]
     if H % max(G, 1):
         raise ValueError(f"q heads {H} not a multiple of kv heads {G}")
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    if window is not None:
+        if not causal or mask_block != 1 or window < 1:
+            raise ValueError(f"window={window} is a causal window of 1 or more positions, without a block mask")
+        return _windowed(q, k, v, scale, int(window), block_q, block_k, interpret)
     if mask_block != 1:
         if not causal or mask_block < 1:
             raise ValueError(f"mask_block={mask_block} is a causal mask over blocks of 1 or more positions")
-        return _block_masked_forward(q, k, v, scale, int(mask_block), block_q, block_k, interpret)
+        return _masked_forward(q, k, v, scale, block_q, block_k, interpret, mask_block=int(mask_block))
     from .. import kernels as _kernels
 
     kmode = _kernels.mode()

@@ -4,7 +4,7 @@ MODULE, and this class keeps what every such model needs once: the prefill
 ladder, ``warm()``, donation, the ``vs.serve-*`` spans and ``trace_counters()``;
 ``decode`` itself, one step deep (a call launches its step and returns the
 ``DecodeStep`` unread; a ``DecodeFeed`` feeds the next from the device), is
-``engine.DecodeAhead``'s, shared with ``ServeEngine``.  Four models plug in today:
+``engine.DecodeAhead``'s, shared with ``ServeEngine``.  Five models plug in today:
 
   * ``models/granite_hybrid.py`` (the class's name is from it): state-space
     mixers with a per-slot recurrent state beside the paged K/V of their few
@@ -20,7 +20,12 @@ ladder, ``warm()``, donation, the ``vs.serve-*`` spans and ``trace_counters()``;
   * ``models/falcon_h1.py``: a state-space mixer AND a rotary grouped-query
     attention mixer side by side in EVERY layer, so every layer owns a row of
     the state arrays and a layer of the K/V pools; two groups of B and C; a
-    dense MLP (no experts: the engine's ``moe_*`` counters stay 0).
+    dense MLP (no experts: the engine's ``moe_*`` counters stay 0);
+  * ``models/laguna.py``: window and full attention MIXED, with more query
+    heads and another rotary term on the window layers: the full layers keep
+    pages, a window layer a RING a slot of the newest ``window`` positions (slot
+    state, read as pages under an arithmetic table by the same ``paged_decode``);
+    256 small sigmoid-routed experts beside a shared one after a dense first layer.
 
 A second engine class beside :class:`ServeEngine`, behind the same surface
 (``prefill(prompt, slot)``, ``decode(tokens)``, ``params``,

@@ -58,6 +58,17 @@ SNAPSHOT of the state at that position, which nothing here takes.  Both raise
 :class:`SlotStateUnsupported` on such a cache, and so do ``PrefixCache`` and
 ``SpeculativeDecoder`` when they are built over one.
 
+A ring (a model that mixes window and full attention rides the slot state too:
+``models/laguna.py``): a window layer needs the newest ``window`` positions of a
+sequence and nothing else, so it keeps no pages: its K and V live in per-slot
+arrays ``(window layers, num_slots, window, kv_heads, head_dim)``, position ``p``
+at row ``p mod window``, 6.3 MB a slot whatever the sequence's length, while
+``layers`` counts the full-attention layers alone and admission (``can_admit``,
+``alloc``) counts their pages alone: the pool may then be smaller than
+``num_slots * pages_per_slot`` on purpose (``num_pages``).  A ring keeps no
+history (a row is overwritten ``window`` positions later), so what reads a slot
+at an earlier position is refused exactly as for a recurrent state.
+
 An open block (a model that generates by diffusion over blocks rides the slot
 state too: ``models/sdar_moe.py``): while a slot's block of ``B`` positions is
 being denoised, its ids, which of them are still masked and the pass it is at
@@ -306,7 +317,7 @@ class PagedKVCache:
         """Raise where ``what`` needs a slot's state as it was at an earlier position."""
         if self.state:
             raise SlotStateUnsupported(
-                f"{what} needs a slot's state (a recurrence's, an open block's) as it was at an earlier position, and this cache "
+                f"{what} needs a slot's state (a recurrence's, an open block's, a ring's) as it was at an earlier position, and this cache "
                 f"({', '.join(sorted(self.state))} beside the pages) keeps only the newest: the missing "
                 "mechanism is a snapshot of the state at page boundaries")
 
