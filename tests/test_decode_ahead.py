@@ -269,6 +269,8 @@ def test_a_step_is_read_once_by_whoever_reads_first_and_counted_then(rig):
     first = eng.decode(toks)
     cache.advance(slot)
     launched = dict(start, decode_launches=start["decode_launches"] + 1)     # counted as launched at once, as read later
+    if "moe_expert_layer_calls" in start:           # ... with the expert layers its program holds (none the grouped kernel here)
+        launched["moe_expert_layer_calls"] += eng._expert_layers
     assert isinstance(first, DecodeStep) and not first.read and eng.trace_counters() == launched, "launched, not read"
     second = eng.decode(DecodeFeed(first))          # waits for the first step's ids once its own program is enqueued
     cache.advance(slot)

@@ -53,7 +53,8 @@ def system(request):
     expert layer's limits turned to 0 while the programs are traced, so that
     both take the sorted, grouped product a real prefill takes; with the Pallas
     kernels a TPU would compile (``paged_decode_latent``, the flash forward
-    with two head widths) run through the interpreter; and with the first limit
+    with two head widths, and, both limits at 0 here too, the grouped SwiGLU
+    kernel that is the sorted form's leg there) run through the interpreter; and with the first limit
     alone turned to 0, so that both are candidates for the padded batched
     product that a 256- or 512-rung prefill takes at the real size."""
     from vescale_tpu.moe import dropless
@@ -63,10 +64,12 @@ def system(request):
     params = jax.jit(lambda k: ds.init_params(cfg, k))(jax.random.key(7))
     cache = PagedKVCache(hybrid_cache_config(cfg, num_slots=SLOTS, page_size=PAGE, pages_per_slot=PAGES), mesh)
     with pytest.MonkeyPatch.context() as patch:
-        if request.param in ("experts_sorted", "experts_padded"):
+        if request.param in ("experts_sorted", "kernels_interpreted", "experts_padded"):
             patch.setattr(dropless, "DENSE_MAX_TOKENS", 0)
-        if request.param == "experts_sorted":
+        if request.param in ("experts_sorted", "kernels_interpreted"):
             patch.setattr(dropless, "PADDED_MAX_MEAN_ROWS", 0)
+        if request.param == "experts_padded":
+            patch.setattr(dropless, "PADDED_MIN_MEAN_ROWS", 0)          # (a toy program is a few rows an expert)
         if request.param == "kernels_interpreted":
             patch.setenv("VESCALE_KERNELS", "interpret")
         engine = HybridServeEngine(cfg, mesh, params, cache).warm()     # every program is traced here

@@ -1,8 +1,9 @@
 """The three forms of ``moe/dropless.py:dropless_experts`` (all experts on all
-tokens, the sorted grouped product, the padded batched product) against a
-plain float32 loop over tokens and their ``k`` experts, the rule that chooses
-among them, and the fall-back on the device when a router sends one expert
-more rows than the pad."""
+tokens, the sorted grouped product on both of its legs, ``jax.lax.ragged_dot``
+and the grouped SwiGLU kernel under the interpreter, and the padded batched
+product) against a plain float32 loop over tokens and their ``k`` experts, the
+rule that chooses among them, and the fall-back on the device when a router
+sends one expert more rows than the pad."""
 
 import jax
 import jax.numpy as jnp
@@ -10,8 +11,8 @@ import numpy as np
 import pytest
 
 from vescale_tpu.moe import dropless
-from vescale_tpu.moe.dropless import (DENSE_MAX_TOKENS, PADDED_MAX_MEAN_ROWS, ROW_PAD, dropless_experts, fits_pad,
-                                      padded_candidate, route_topk)
+from vescale_tpu.moe.dropless import (DENSE_MAX_TOKENS, PADDED_MAX_MEAN_ROWS, PADDED_MIN_MEAN_ROWS, ROW_PAD, dropless_experts,
+                                      fits_pad, padded_candidate, route_topk)
 
 D, F = 16, 12
 
@@ -32,16 +33,20 @@ def _loop(x, idx, gates, w_gate, w_up, w_down, first, mask):
     return out, counts
 
 
-def _problem(N, E, k, held, seed, favourite=None):
+def _problem(N, E, k, held, seed, favourite=None, shunned=None, routes_every=None):
     rng = np.random.default_rng(seed)
     x = rng.normal(size=(N, D))
     scores = rng.normal(size=(N, E))
     if favourite is not None:
         scores[:, favourite] += 9.0         # every token keeps it
+    if shunned is not None:
+        scores[:, shunned] -= 9.0           # ... and none this one
     weights = [rng.normal(size=s) for s in ((held, D, F), (held, D, F), (held, F, D))]
     idx, gates = route_topk(jnp.asarray(scores, jnp.float32), k)
     mask = np.ones((N,), bool)
     mask[::7] = False                       # tokens that route nowhere
+    if routes_every is not None:
+        mask &= np.arange(N) % routes_every == 1        # ... most of them
     return x, idx, gates, weights, mask
 
 
@@ -53,46 +58,68 @@ def _run(x, idx, gates, weights, first, mask):
 
 
 def _form(text):
-    """Which forms the program holds: a choice on the device, a grouped product."""
+    """Which forms the program holds: a choice on the device, a grouped product of the compiler's, the grouped kernel
+    (whose interpreted body holds choices of its own: with it the text says nothing of the layer's)."""
     assert ("stablehlo.case" in text) == ("cond[" in text)
-    return {"cond": "stablehlo.case" in text, "ragged": "ragged_dot" in text}
+    kernel = "pallas_call" in text
+    return {"cond": None if kernel else "stablehlo.case" in text, "ragged": "ragged_dot" in text, "kernel": kernel}
 
 
 def rel(got, want):
     return float(np.max(np.abs(np.asarray(got) - want)) / np.max(np.abs(want)))
 
 
-# N, all experts, k, held, first held, and the forms the program must hold
+# N, all experts, k, held, first held, the forms the program must hold (its sorted form on the XLA leg), and what the router is bent to
 SHAPES = {
-    "all_on_all_64_tokens": (64, 16, 4, 8, 4, {"cond": False, "ragged": False}),
-    "padded_512_tokens_128_held_top8": (512, 160, 8, 128, 16, {"cond": True, "ragged": True}),
-    "sorted_2048_tokens_8_held": (2048, 12, 3, 8, 2, {"cond": False, "ragged": True}),
+    "all_on_all_64_tokens": (64, 16, 4, 8, 4, {"cond": False, "ragged": False}, {}),
+    "padded_512_tokens_128_held_top8": (512, 160, 8, 128, 16, {"cond": True, "ragged": True}, {}),
+    "sorted_2048_tokens_8_held": (2048, 12, 3, 8, 2, {"cond": False, "ragged": True}, {}),
+    # 19 rows an expert by the shapes, under the pad's lower bound: one expert gets every token (eight row tiles of 32), one none
+    "sorted_an_expert_over_a_row_tile_and_one_with_no_row": (300, 40, 2, 32, 4, {"cond": False, "ragged": True}, {"favourite": 9, "shunned": 11}),
+    # three pairs in four land on experts held elsewhere: row tiles of 256 that no expert fills
+    "sorted_1024_tokens_6_of_24_held": (1024, 24, 4, 6, 10, {"cond": False, "ragged": True}, {}),
+    # one token in five routes: 144 by the shapes, 25 by the counts
+    "sorted_640_tokens_most_masked": (640, 10, 3, 4, 3, {"cond": False, "ragged": True}, {"routes_every": 5}),
 }
+# the sorted form's legs: ``jax.lax.ragged_dot``, as a CPU takes it, and the grouped kernel through the interpreter
+LEGS = {"xla_leg": None, "grouped_kernel": "interpret"}
 
 
+@pytest.mark.parametrize("leg", LEGS)
 @pytest.mark.parametrize("shape", SHAPES)
-def test_each_form_is_the_loop_over_tokens_and_their_experts(shape):
-    N, E, k, held, first, forms = SHAPES[shape]
+def test_each_form_is_the_loop_over_tokens_and_their_experts(shape, leg, monkeypatch):
+    N, E, k, held, first, forms, bent = SHAPES[shape]
     assert held < E and first > 0
-    x, idx, gates, weights, mask = _problem(N, E, k, held, seed=N)
+    if LEGS[leg]:
+        monkeypatch.setenv("VESCALE_KERNELS", LEGS[leg])
+        if not forms["cond"]:       # the sorted form ALONE is the kernel's; a candidate's program is the same on both legs
+            forms = {"cond": None if forms["ragged"] else False, "ragged": False, "kernel": forms["ragged"]}
+    x, idx, gates, weights, mask = _problem(N, E, k, held, seed=N, **bent)
     (got, counts), text = _run(x, idx, gates, weights, first, mask)
-    assert _form(text) == forms
+    assert _form(text) == {"kernel": False, **forms}
     want, want_counts = _loop(x, np.asarray(idx), np.asarray(gates), *weights, first, mask)
     assert rel(got, want) < 1e-5
     assert list(np.asarray(counts)) == list(want_counts)
     if forms["cond"]:
         assert bool(fits_pad(np.asarray(counts))), "this case is to take the padded branch"
+    if "favourite" in bent:
+        assert want_counts[bent["favourite"] - first] == mask.sum() > 4 * ROW_PAD // 2 and want_counts[bent["shunned"] - first] == 0
     assert not np.asarray(got)[~mask].any(), "a masked token routes nowhere"
 
 
-def test_an_expert_with_more_rows_than_the_pad_falls_back_on_the_device_to_the_same_result():
+@pytest.mark.parametrize("leg", LEGS)
+def test_an_expert_with_more_rows_than_the_pad_falls_back_on_the_device_to_the_same_result(leg, monkeypatch):
     """Every one of 256 tokens keeps one held expert: a candidate by its shape
-    (8 experts of 128 places for 512 pairs), over the pad by its counts."""
+    (8 experts of 128 places for 512 pairs), over the pad by its counts; the
+    branch it falls back to is the sorted form's XLA leg wherever the kernel is
+    the sorted form's own (its program is the same on both legs)."""
     N, E, k, held, first = 256, 12, 2, 8, 2
     assert padded_candidate(N, k, held)
+    if LEGS[leg]:
+        monkeypatch.setenv("VESCALE_KERNELS", LEGS[leg])
     x, idx, gates, weights, mask = _problem(N, E, k, held, seed=1, favourite=first + 3)
     (got, counts), text = _run(x, idx, gates, weights, first, mask)
-    assert _form(text) == {"cond": True, "ragged": True}
+    assert _form(text) == {"cond": True, "ragged": True, "kernel": False}
     counts = np.asarray(counts)
     assert counts[3] == mask.sum() > ROW_PAD and not bool(fits_pad(counts))
     want, want_counts = _loop(x, np.asarray(idx), np.asarray(gates), *weights, first, mask)
@@ -121,13 +148,29 @@ def test_both_branches_of_a_candidate_call_give_the_same_numbers(monkeypatch):
     assert rel(padded, np.asarray(alone)) < 1e-6 and list(np.asarray(counts)) == list(np.asarray(counts_alone))
 
 
+def test_both_legs_of_the_sorted_form_give_the_same_numbers(monkeypatch):
+    """The grouped kernel against ``jax.lax.ragged_dot`` on the same operands,
+    bfloat16 products as they are served: the same products with the hidden
+    rounded at the same place, so what differs is the order of a product's
+    partial sums."""
+    N, E, k, held = 1536, 24, 4, 8
+    x, idx, gates, weights, mask = _problem(N, E, k, held, seed=6)
+    args = (jnp.asarray(x, jnp.float32), idx, gates, *(jnp.asarray(w, jnp.bfloat16) for w in weights))
+    call = lambda: jax.jit(lambda *a: dropless_experts(*a, first_held=8, token_mask=jnp.asarray(mask)))(*args)
+    assert dropless.expert_form(N, k, held) == dropless.SORTED
+    xla, counts = call()
+    monkeypatch.setenv("VESCALE_KERNELS", "interpret")
+    kernel, counts_kernel = call()
+    assert rel(kernel, np.asarray(xla)) < 1e-6 and list(np.asarray(counts)) == list(np.asarray(counts_kernel))
+
+
 @pytest.mark.parametrize("N", [1, 32, 64, DENSE_MAX_TOKENS])
 def test_a_call_of_few_tokens_holds_neither_a_choice_nor_a_grouped_product(N):
     """Granite's and DeepSeek-V2's decode steps (64 and 32 tokens) compile as
     they did: all experts on all tokens, nothing else in the program."""
     x, idx, gates, weights, mask = _problem(N, 16, 4, 8, seed=2)
     _, text = _run(x, idx, gates, weights, 4, mask)
-    assert _form(text) == {"cond": False, "ragged": False}
+    assert _form(text) == {"cond": False, "ragged": False, "kernel": False}
     assert "stablehlo.sort" not in text
 
 
@@ -141,10 +184,16 @@ def test_a_call_of_few_tokens_holds_neither_a_choice_nor_a_grouped_product(N):
     (64, 10, 36, False), (32, 6, 40, False),    # both neighbours' decode steps
     (512, 6, 40, True),         # DeepSeek-V2's 512 rung: 77
     (4096, 6, 40, False),       # ... and the rungs its long prompts take: 614
+    (256, 8, 128, False),       # SDAR's 256 rung: 16, under the lower bound: the pad would be seven eighths zeros
+    (256, 8, 256, False), (512, 8, 256, False),     # Laguna's 256 and 512 rungs: 8 and 16
+    (1024, 8, 256, True), (3072, 8, 256, True),     # ... its 1024 rung, at the bound, to its 3072 rung: 32 to 96
+    (4096, 8, 256, False),      # ... and the five above: 128 and more
 ])
 def test_the_static_half_of_the_choice_reads_tokens_choices_and_held_experts(N, k, held, candidate):
     assert padded_candidate(N, k, held) is candidate
-    assert PADDED_MAX_MEAN_ROWS <= ROW_PAD, "a mean over the pad can never fit it"
+    assert 0 < PADDED_MIN_MEAN_ROWS < PADDED_MAX_MEAN_ROWS <= ROW_PAD, "a mean over the pad can never fit it"
+    form = dropless.expert_form(N, k, held)
+    assert form == (dropless.PADDED_OR_SORTED if candidate else dropless.ALL_ON_ALL if N <= DENSE_MAX_TOKENS else dropless.SORTED)
 
 
 def test_the_predicate_is_the_same_on_the_hosts_copy_of_the_counts_layer_by_layer():

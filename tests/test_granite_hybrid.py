@@ -46,7 +46,8 @@ def system(request):
     both its limits turned to 0 while the programs are traced, so that prefill
     and decode both take the sorted, grouped product a long prefill takes; with
     the Pallas kernels a TPU would compile (``ssm_step``, ``paged_decode``,
-    flash attention) run through the interpreter; and with the first limit
+    flash attention, and, both limits at 0 here too, the grouped SwiGLU kernel
+    that is the sorted form's leg there) run through the interpreter; and with the first limit
     alone turned to 0, so that both are candidates for the padded batched
     product that a 256-rung prefill takes at the real size."""
     from vescale_tpu.moe import dropless
@@ -56,10 +57,12 @@ def system(request):
     params = jax.jit(lambda k: gh.init_params(cfg, k))(jax.random.key(7))
     cache = PagedKVCache(hybrid_cache_config(cfg, num_slots=SLOTS, page_size=PAGE, pages_per_slot=PAGES), mesh)
     with pytest.MonkeyPatch.context() as patch:
-        if request.param in ("experts_sorted", "experts_padded"):
+        if request.param in ("experts_sorted", "kernels_interpreted", "experts_padded"):
             patch.setattr(dropless, "DENSE_MAX_TOKENS", 0)
-        if request.param == "experts_sorted":
+        if request.param in ("experts_sorted", "kernels_interpreted"):
             patch.setattr(dropless, "PADDED_MAX_MEAN_ROWS", 0)
+        if request.param == "experts_padded":
+            patch.setattr(dropless, "PADDED_MIN_MEAN_ROWS", 0)          # (a toy program is a few rows an expert)
         if request.param == "kernels_interpreted":
             patch.setenv("VESCALE_KERNELS", "interpret")
         engine = HybridServeEngine(cfg, mesh, params, cache).warm()     # every program is traced here
@@ -226,6 +229,10 @@ def test_the_counters_count_what_the_decode_steps_routed(system):
     assert d["moe_expert_slots"] == 2 * layers * held and 0 < d["moe_experts_touched"] <= d["moe_expert_slots"]
     assert d["moe_layer_steps"] == 2 * layers
     assert d["moe_padded_layer_steps"] == (2 * layers if engine._decode_padded_candidate else 0), "2 rows fit any pad"
+    # of launched programs, the two prefills with the two steps: every one's expert layers, and those that are the grouped
+    # kernel outside any choice on the device (the fixture that interprets the kernels turns both limits to 0)
+    assert d["moe_expert_layer_calls"] == 4 * layers
+    assert d["moe_grouped_layer_calls"] == (4 * layers if engine.kernel_decode else 0)
     assert d["moe_busiest_expert_tokens"] * held >= d["moe_assignments_held"], "the busiest is at least the mean"
     assert d["ssm_state_bytes_rw"] == 2 * 2 * SLOTS * cache.state_bytes_per_slot()
     assert d["prefill_tokens_real"] == 14 and d["prefill_bucket_tokens"] == 8 + 16

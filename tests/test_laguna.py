@@ -65,9 +65,15 @@ def system(request):
     """The toy engine, twice: with the XLA legs the CPU takes, and with the
     Pallas kernels a TPU would compile (``paged_decode`` over pages and over the
     ring at 3 and 4 query rows a key head, the windowed and the causal flash
-    forward) run through the interpreter."""
+    forward, and, with both of the expert layer's limits turned to 0 while the
+    programs are traced, the grouped SwiGLU kernel that is the sorted form's leg
+    there) run through the interpreter."""
+    from vescale_tpu.moe import dropless
+
     with pytest.MonkeyPatch.context() as patch:
         if request.param == "kernels_interpreted":
+            patch.setattr(dropless, "DENSE_MAX_TOKENS", 0)
+            patch.setattr(dropless, "PADDED_MAX_MEAN_ROWS", 0)
             patch.setenv("VESCALE_KERNELS", "interpret")
         cfg = toy_config()
         params, cache, engine = build(cfg)
@@ -265,6 +271,8 @@ def test_the_counters_count_the_ring_and_the_window(system):
     assert d["prefill_full_attn_flops"] == 4 * 16 * (6 + 6) * (8 * 9 // 2 + 16 * 17 // 2)
     assert d["moe_layer_steps"] == 4 and d["moe_expert_slots"] == 4 * 8 and d["moe_assignments"] == 2 * 2 * 4
     assert d["moe_assignments_held"] == d["moe_assignments"], "every expert is held; the idle slot routes nowhere"
+    # two prefills and a step, four expert layers each; the grouped kernel's where the fixture turned the layer's limits to 0
+    assert d["moe_expert_layer_calls"] == 3 * 4 and d["moe_grouped_layer_calls"] == (3 * 4 if engine.kernel_decode else 0)
     assert d["decode_pages_read"] == (2 + 4 + 1 if engine.kernel_decode else 0)     # ONE full layer's pages
     for name in lg.STEP_COUNTERS:
         assert name in d

@@ -50,8 +50,9 @@ def system(request):
     with the Pallas kernels a TPU compiles run through the interpreter
     (``paged_decode`` over a block's grouped query rows, the flash forward under
     the block mask) and both of the expert layer's limits turned to 0 while the
-    programs are traced, so that a pass takes the sorted, grouped product that a
-    long prefill takes at the real size; in bfloat16, as it is served; and with
+    programs are traced, so that a pass takes the sorted form that a long
+    prefill takes at the real size, on the leg a TPU takes: the grouped SwiGLU
+    kernel, interpreted; in bfloat16, as it is served; and with
     the first limit alone turned to 0, so that every program is a candidate for
     the padded batched product, which a pass of 512 positions takes at the real
     size (few rows an expert: the choice on the device takes it every call)."""
@@ -68,6 +69,7 @@ def system(request):
             patch.setenv("VESCALE_KERNELS", "interpret")
         if request.param == "experts_padded":
             patch.setattr(dropless, "DENSE_MAX_TOKENS", 0)
+            patch.setattr(dropless, "PADDED_MIN_MEAN_ROWS", 0)          # (a toy pass is 4 rows an expert)
         engine = HybridServeEngine(cfg, mesh, params, cache).warm()     # every program is traced here
     assert engine.kernel_decode == request.param.startswith("kernels_interpreted")
     assert engine._decode_padded_candidate == (request.param == "experts_padded")

@@ -7,7 +7,7 @@ one described device, and compiles it.  Nothing executes, so this says
 nothing about results or times; it catches what interpret mode cannot — a
 kernel the compiler refuses (scoped VMEM, tiling, HBM).  The cases call the
 kernel entry points below the platform dispatch (``_flash``,
-``paged_decode``, ``ssm_step``, ``_fused_local``, ``fused_xent_parts``): code that asks
+``paged_decode``, ``ssm_step``, ``grouped_swiglu``, ``_fused_local``, ``fused_xent_parts``): code that asks
 ``jax.devices()`` still sees the CPU here.
 """
 
@@ -180,6 +180,29 @@ def test_ssm_step_compiles_in_place_with_two_groups_and_a_state_of_256(chip):
     assert memory.alias_size_in_bytes == L * S * N * J * 4 and memory.temp_size_in_bytes < 1 << 20   # no copy of the state
 
 
+# ---------------------------------------------------------- grouped SwiGLU
+# (rung, choices a token, held experts, hidden, an expert's width): the four MoE cells' expert layers at the shortest and
+# the longest rung that takes the sorted form (Laguna's 256 and 8192, SDAR's 256 and 2048, Granite's 512, DeepSeek-V2's
+# 8192, whose three matrices of 47 MB pass in tiles over the width)
+GROUPED_CASES = {"laguna-256": (256, 8, 256, 2048, 512), "laguna-8192": (8192, 8, 256, 2048, 512), "sdar-256": (256, 8, 128, 2048, 768),
+                 "sdar-2048": (2048, 8, 128, 2048, 768), "granite-512": (512, 10, 36, 4096, 768), "deepseekv2-8192": (8192, 6, 40, 5120, 1536)}
+
+
+@pytest.mark.parametrize("case", GROUPED_CASES)
+def test_grouped_swiglu_compiles_at_the_cells_widths_and_rungs(chip, case):
+    from vescale_tpu.kernels.grouped_swiglu import grouped_swiglu, row_tiles, tiles
+
+    N, k, held, d, f = GROUPED_CASES[case]
+    tm, tf = tiles(d, f, bf16, N * k / held)
+    assert (tf == f) == (case != "deepseekv2-8192") and f % tf == 0 and tm % 16 == 0
+    sds = lambda shape, dt=bf16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    rows = row_tiles(N * k, held, tm) * tm
+    compiled = grouped_swiglu.lower(sds((rows, d)), sds((held,), jnp.int32), sds((held, d, f)), sds((held, d, f)), sds((held, f, d)),
+                                    tm=tm, tf=tf, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20      # nothing of the rows' or the experts' size beside the operands
+
+
 # ------------------------------------------------- whole programs of a cell
 class _JaxOnATpu:
     """``jax`` as ``ops/flash_attention.py`` sees it, but for the platform of ``jax.devices()[0]``."""
@@ -244,8 +267,9 @@ def test_falcon_h1s_decode_program_and_a_rung_compile_at_the_cells_size(chip, pr
 
 def test_sdars_rung_of_512_compiles_at_the_cells_size_and_writes_its_pools_in_place(chip):
     """``sdar30b_serve_blockgen``'s 512 rung (six flash forwards under the
-    block mask; the expert layers' sorted products are the compiler's own
-    ``ragged-dot``).  Its pools have Falcon-H1's row, 4 key heads of 128, and
+    block mask; 32 rows an expert, so each of the six expert layers is a choice
+    on the device whose fall-back branch is the sorted form's XLA leg, the
+    compiler's own ``ragged-dot``, as before the grouped kernel).  Its pools have Falcon-H1's row, 4 key heads of 128, and
     go through the same page writer: a scatter cost FOUR copies of a 1.6 GB
     pool a prefill here (PERF.md section 6, PR 44)."""
     family, config, sizes, programs = _cells_programs(chip, "sdar30b_serve_blockgen")
@@ -255,16 +279,17 @@ def test_sdars_rung_of_512_compiles_at_the_cells_size_and_writes_its_pools_in_pl
     (lowered,) = [low for title, low in programs if "rung of 512" in title]
     compiled = lowered.compile()
     kernel_calls = [line for line in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    assert sum("block_flash_fwd" in line for line in kernel_calls) == 6
+    assert sum("block_flash_fwd" in line for line in kernel_calls) == 6 and not any("grouped_swiglu" in line for line in kernel_calls)
     _assert_in_place_and_fits(compiled, sizes, "bf16[6,16385,16,4,128]")        # 1.6 GB a pool
 
 
-@pytest.mark.parametrize("program,kernels_in_it", [("decode step", 5), ("rung of 512 positions", 5)], ids=["decode", "rung512"])
+@pytest.mark.parametrize("program,kernels_in_it", [("decode step", 5), ("rung of 512 positions", 9)], ids=["decode", "rung512"])
 def test_lagunas_decode_program_and_a_rung_compile_at_the_cells_size_and_copy_no_pool_of_either_kind(chip, program, kernels_in_it):
     """``lagunaxs2_serve_mixedlen``'s decode step (128 slots: two ``paged_decode``
     at 48 query heads over the pages, three at 64 over the rings read as pages)
     and the 512 rung of its prefill ladder (two causal flash forwards, three
-    ``window_flash_fwd``).  Neither holds a copy of a pool of EITHER kind: the
+    ``window_flash_fwd``, and the four expert layers' ``grouped_swiglu``: 16
+    rows an expert, the sorted form alone).  Neither holds a copy of a pool of EITHER kind: the
     full layers' pages (rows of 8 key heads, through ``write_pages``) or the
     sliding layers' rings (a slot's rows rewritten by one ``dynamic_update_slice``
     a prefill, one row a slot by a scatter a step, read through a reshape)."""
@@ -276,10 +301,11 @@ def test_lagunas_decode_program_and_a_rung_compile_at_the_cells_size_and_copy_no
     (lowered,) = [low for title, low in programs if program in title]
     compiled = lowered.compile()
     kernel_calls = [line for line in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    ours = [line for line in kernel_calls if "ragged-dot" not in line.split(" = ")[0]]      # (the sorted experts' products are the compiler's own)
+    ours = [line for line in kernel_calls if "ragged-dot" not in line.split(" = ")[0]]      # (a sorted form on its XLA leg is the compiler's own)
     assert len(ours) == kernels_in_it
     if "rung" in program:
-        assert sum("window_flash_fwd" in line for line in ours) == 3
+        assert sum("window_flash_fwd" in line for line in ours) == 3 and sum("grouped_swiglu" in line for line in ours) == 4
+        assert ours == kernel_calls                             # ... and on the kernel's leg there is none of those
     else:
         assert sum("f32[128,64,128]" in line for line in ours) == 3 and sum("f32[128,48,128]" in line for line in ours) == 2
     _assert_in_place_and_fits(compiled, sizes, "bf16[2,28672,16,8,128]")        # 1.88 GB a pool

@@ -3,10 +3,10 @@
 Every hand-written TPU kernel in the framework lives in this package and is
 reached through the same knob (``VESCALE_KERNELS``, registered in
 ``analysis.envreg``).  Unset, each kernel takes its own default:
-``paged_decode``, ``paged_decode_latent`` and ``ssm_step`` are the compiled kernels on TPU and the
-XLA leg on every other backend (what the platform is, the code can see;
-PERF.md, PR 27 and PR 29); the other three stay ``off``.  Set, it means the
-same for all six:
+``paged_decode``, ``paged_decode_latent``, ``ssm_step`` and ``grouped_experts`` are the compiled
+kernels on TPU and the XLA leg on every other backend (what the platform is,
+the code can see; PERF.md, PR 27, PR 29 and PR 46); the other three stay
+``off``.  Set, it means the same for all seven:
 
   ``off``        the kernels are never consulted — every caller takes
                  exactly the XLA path it took before this package
@@ -41,6 +41,13 @@ Kernels in this package:
     over every slot's recurrent state, in place: one read and one write of
     the state where XLA reads it twice; dispatched by
     ``serve/hybrid_engine.py``, the default on TPU.
+  * ``grouped_experts``  — the sorted form of a dropless expert layer
+    (``kernels/grouped_swiglu.py``): one grid over row tiles of the (token,
+    expert) pairs in expert order; a tile's expert, a scalar-prefetch operand,
+    picks the three SwiGLU weight blocks, which stay in VMEM across that
+    expert's tiles, and the hidden never leaves VMEM — instead of three
+    ``jax.lax.ragged_dot`` with the hidden between them in HBM; dispatched by
+    ``moe/dropless.py``, the default on TPU.
   * ``fused_adamw``      — the adamw_lowmem moment/update elementwise
     chain as one kernel over (g, m, v); dispatched by
     ``parallel/optimizer.py``.
@@ -87,7 +94,7 @@ __all__ = [
 MODES = ("off", "interpret", "on")
 # what an unset VESCALE_KERNELS means for these: compiled on TPU, the XLA leg
 # elsewhere (every other kernel: off)
-DEFAULT_ON_TPU = frozenset({"paged_decode", "paged_decode_latent", "ssm_step"})
+DEFAULT_ON_TPU = frozenset({"paged_decode", "paged_decode_latent", "ssm_step", "grouped_experts"})
 
 
 def mode() -> str:

@@ -3,10 +3,10 @@
 The router scores every expert the model has; this chip (or rank) holds the
 contiguous ids ``first_held .. first_held + held``, and computes the part of
 the result that its own experts give.  The (token, expert) pairs that fall on
-held experts are sorted by expert and go through grouped matrix products
-(``jax.lax.ragged_dot``: on TPU one Mosaic kernel a product, which reads each
-expert's weights once a row tile it spans), and come back to their tokens
-weighted by the gates.  No capacity, no ``(N, E, C)`` mask, nothing dropped:
+held experts are sorted by expert and go through grouped matrix products (on
+TPU one kernel, ``kernels/grouped_swiglu.py``, which brings each expert's three
+matrices into VMEM once for that expert's own rows), and come back to their
+tokens weighted by the gates.  No capacity, no ``(N, E, C)`` mask, nothing dropped:
 work and memory are linear in tokens x experts-per-token.  A pair whose expert
 lives elsewhere adds nothing here (the exchange that would carry it there is
 not this module's; on one chip the layer runs without it), and tokens masked
@@ -18,15 +18,30 @@ model's name.  What decides is how many ROWS AN EXPERT gets.
 * *All on all.*  At most ``DENSE_MAX_TOKENS`` tokens (a decode step of one
   position a slot): every held expert runs on every token as one batched
   product and the gates, zero where a token did not keep the expert, weigh the
-  sum.  A grouped product works in row tiles of 128, so with so few tokens
+  sum.  ``ragged_dot`` works in row tiles of 128, so with so few tokens
   each touched expert costs it a whole tile anyway, and the chip's trace
-  (PERF.md, PR 29) showed the grouped kernel streaming the expert weights at
+  (PERF.md, PR 29) showed it streaming the expert weights at
   half the memory's rate where the batched product streams them at nine
   tenths: a decode step is those weights' read.
-* *Sorted.*  The pairs on held experts are sorted by expert and go through
-  ``jax.lax.ragged_dot``, as above: work linear in the pairs, and the form for
-  many rows an expert (a long prefill), where all-on-all would grow with
-  tokens x held experts.
+* *Sorted.*  The pairs on held experts are sorted by expert, each expert's
+  rows from a multiple of a row tile on, and go through ONE grouped SwiGLU
+  kernel (``kernels/grouped_swiglu.py``: a grid over row tiles; a tile's expert
+  picks the weight blocks, which stay in VMEM across that expert's tiles while
+  the next expert's arrive; the hidden never leaves VMEM; an expert with no row
+  costs no read).  Work linear in the pairs that landed here: the form for many
+  rows an expert (a long prefill), where all-on-all would grow with tokens x
+  held experts, and for very few (a short prefill over many small experts),
+  where the padded form is mostly zeros.  The kernel alone streams a layer's
+  expert weights at 550-700 GB/s from 8 to 96 rows an expert at four models'
+  widths and is bound by the MXU from about 128 rows on; with the gather of the
+  rows before it and the un-sort after it, which stay XLA's, the layer reads
+  300-660 GB/s there (PERF.md section 6, PR 46, the table of three forms).
+  Dispatched as the package's other kernels are (``kernels.resolve``): compiled
+  on TPU, and everywhere else, and under ``VESCALE_KERNELS=off``, the XLA leg it
+  replaced: three ``jax.lax.ragged_dot`` over the rows in plain sorted order,
+  the hidden between them in HBM, 30-350 GB/s on the same table.  That leg is
+  also what a padded candidate falls back to, on every backend (the rule,
+  below).
 * *Padded.*  More tokens than all-on-all can carry but FEW ROWS AN EXPERT (a
   pass of 128 slots x 4 positions over 128 experts: 32; a short prefill):
   each held expert's rows are gathered, in the sorted order, into ``ROW_PAD``
@@ -35,20 +50,26 @@ model's name.  What decides is how many ROWS AN EXPERT gets.
   from place ``expert x ROW_PAD + (place in the order - the expert's first)``
   under its gates.  The same products on the same operands as the sorted
   form; only the order of a token's ``k`` terms may differ.  The pad is one
-  row tile: the grouped kernel spends a tile on a touched expert whatever its
-  count, so up to there the padded rows cost the MXU nothing it was not
+  row tile of the MXU: a grouped product spends one on a touched expert whatever
+  its count, so up to there the padded rows cost the MXU nothing it was not
   already spending, the form stays bound by the weights' read, and XLA's
-  batched product streams them at twice the grouped kernel's rate (PERF.md
+  batched product streams them at twice ``ragged_dot``'s rate (PERF.md
   section 6, PR 37, "crossover": 558-665 GB/s against 252-340 at 16 to 64 rows
-  an expert, at two models' widths).
+  an expert, at two models' widths).  Against the grouped kernel (PR 46, the
+  same table with a third column) it keeps the middle: from 32 rows an expert
+  up to the pad the two lie within a few per cent of each other at most
+  widths, and below 32 the pad's zeros cost it a fifth to a third.
 
 The rule.  ``N <= DENSE_MAX_TOKENS``: all on all.  Otherwise, if the pairs
-would fit the pad on average with room for a router's unevenness
-(:func:`padded_candidate`: ``N k <= held x PADDED_MAX_MEAN_ROWS``), the call
+would fill a quarter of the pad on average and fit it with room for a router's
+unevenness (:func:`padded_candidate`: ``held x PADDED_MIN_MEAN_ROWS <= N k <=
+held x PADDED_MAX_MEAN_ROWS``), the call
 holds both other forms and chooses ON THE DEVICE, from the counts it has
 (:func:`fits_pad`: the busiest held expert got at most ``ROW_PAD`` rows: the
-padded form; else the sorted one, same result).  Otherwise the sorted form
-alone.
+padded form; else the sorted one on its XLA leg, same result: with the kernel
+in the branch that is rarely taken, the compiler built the padded branch
+slower at one model's widths).  Otherwise the sorted form alone, the grouped
+kernel on TPU.
 
 ``moe.layer.MoEMLP`` / ``TokenDispatcher`` (capacity, one-hot masks, expert
 biases) stay as they are for training; ROADMAP D4 moves them here.
@@ -62,10 +83,10 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["route_topk", "route_group_limited", "route_sigmoid_topk", "dropless_experts", "padded_candidate", "fits_pad", "DENSE_MAX_TOKENS", "ROW_PAD",
-           "PADDED_MAX_MEAN_ROWS"]
+__all__ = ["route_topk", "route_group_limited", "route_sigmoid_topk", "dropless_experts", "padded_candidate", "fits_pad", "expert_form",
+           "grouped_leg", "DENSE_MAX_TOKENS", "ROW_PAD", "PADDED_MIN_MEAN_ROWS", "PADDED_MAX_MEAN_ROWS"]
 
-# one row tile of a grouped product: up to here each touched expert costs it a tile, sorted or not
+# one row tile of the MXU: up to here each touched expert costs a grouped product a tile, sorted or not
 DENSE_MAX_TOKENS = 128
 # the padded form's places an expert: the same row tile, so a touched expert costs no more rows than the grouped product spends
 ROW_PAD = 128
@@ -74,6 +95,9 @@ ROW_PAD = 128
 # the bound is one of FIT, not of speed: a uniform router's busiest of 128 experts gets mean + 2.6 sqrt(mean), past the pad from
 # a mean of about 100 on, and such a call would compile a branch it never takes
 PADDED_MAX_MEAN_ROWS = 96
+# ... and from which: below a quarter of the pad three quarters of every padded array are zeros, and the grouped kernel, whose
+# row tile follows the rows, streams the weights faster (PERF.md section 6, PR 46, the table of three forms)
+PADDED_MIN_MEAN_ROWS = 32
 # what a call holds: one form, or the padded and the sorted one under a choice on the device
 ALL_ON_ALL, SORTED, PADDED_OR_SORTED = "all_on_all", "sorted", "padded_or_sorted"
 
@@ -120,7 +144,22 @@ def route_sigmoid_topk(scores, k: int, *, scale: float = 1.0) -> Tuple[jax.Array
 def padded_candidate(N: int, k: int, held: int) -> bool:
     """The static half of the choice: may a call of ``N`` tokens with ``k`` experts each over ``held`` held experts
     take the padded form?  (The other half is :func:`fits_pad`, of the counts.)"""
-    return N > DENSE_MAX_TOKENS and N * k <= held * PADDED_MAX_MEAN_ROWS
+    return N > DENSE_MAX_TOKENS and held * PADDED_MIN_MEAN_ROWS <= N * k <= held * PADDED_MAX_MEAN_ROWS
+
+
+def expert_form(N: int, k: int, held: int) -> str:
+    """What a call of these shapes holds: ``ALL_ON_ALL``, ``SORTED``, or ``PADDED_OR_SORTED`` (both, under the
+    choice on the device)."""
+    return ALL_ON_ALL if N <= DENSE_MAX_TOKENS else PADDED_OR_SORTED if padded_candidate(N, k, held) else SORTED
+
+
+def grouped_leg(dtype, d: int, f: int) -> Optional[bool]:
+    """Which leg the sorted form of experts ``d`` x ``f`` in ``dtype`` takes, by ``kernels.resolve``: the grouped
+    kernel's ``interpret`` flag, or None for ``jax.lax.ragged_dot``."""
+    from .. import kernels                                      # (Pallas comes with it: imported late, as the models do)
+    from ..kernels import grouped_swiglu as _grouped
+
+    return kernels.resolve("grouped_experts", supported=lambda interpret: _grouped.supports(dtype, d, f, interpret=interpret))
 
 
 def fits_pad(counts):
@@ -140,18 +179,19 @@ def dropless_experts(x, idx, gates, w_gate, w_up, w_down, *, first_held: int = 0
     operand type (default: the weights').  Returns the result (N, d) float32
     and the tokens each held expert got (held,) int32.
     """
-    held = w_gate.shape[0]
+    held, d, f = w_gate.shape
     N, k = idx.shape
-    form = ALL_ON_ALL if N <= DENSE_MAX_TOKENS else PADDED_OR_SORTED if padded_candidate(N, k, held) else SORTED
-    return _experts(x, idx, gates, w_gate, w_up, w_down, token_mask, first_held=first_held,
-                    dtype=w_gate.dtype if dtype is None else dtype, form=form)
+    form = expert_form(N, k, held)
+    dtype = w_gate.dtype if dtype is None else dtype
+    return _experts(x, idx, gates, w_gate, w_up, w_down, token_mask, first_held=first_held, dtype=dtype, form=form,
+                    grouped=grouped_leg(dtype, d, f) if form == SORTED else None)
 
 
 # jitted inside its caller's program: a model's layers have one shape, so the layer is traced and lowered once a
 # program and not once a layer (a call that holds two forms is twice the text; warm set-up is tracing and lowering)
-@functools.partial(jax.jit, static_argnames=("first_held", "dtype", "form"))
-def _experts(x, idx, gates, w_gate, w_up, w_down, token_mask, *, first_held, dtype, form):
-    held = w_gate.shape[0]
+@functools.partial(jax.jit, static_argnames=("first_held", "dtype", "form", "grouped"))
+def _experts(x, idx, gates, w_gate, w_up, w_down, token_mask, *, first_held, dtype, form, grouped):
+    held, d, f = w_gate.shape
     N, k = idx.shape
     local = idx - first_held
     here = (local >= 0) & (local < held)
@@ -182,11 +222,31 @@ def _experts(x, idx, gates, w_gate, w_up, w_down, token_mask, *, first_held, dty
             out = out + jnp.where(here[:, j, None], jnp.take(ys, at[:, j], axis=0), 0.0) * kept[:, j, None]
         return out
 
-    def sorted_form():
+    def ragged_form():
         xs = jnp.take(x, order // k, axis=0).astype(dtype)
         product = lambda a, w: jax.lax.ragged_dot(a, w.astype(dtype), counts, preferred_element_type=jnp.float32)
         hidden = (jax.nn.silu(product(xs, w_gate)) * product(xs, w_up)).astype(dtype)
         return gathered(product(hidden, w_down), back)             # (N * k, d); rows past the groups are undefined
+
+    def grouped_form():
+        # the kernel's layout: expert e's rows from a multiple of its row tile on, what is left of e's last tile filled
+        # with dummies.  One stable sort lays it out (e's pairs, then e's dummies; every pair that is not here and every
+        # unused dummy behind the last expert) and a second inverts it: a sort of a hundred thousand integers is a
+        # twentieth of a millisecond where a gather of as many from a table is one (PERF.md section 6, PR 46)
+        from ..kernels import grouped_swiglu as _grouped
+
+        tm, tf = _grouped.tiles(d, f, dtype, N * k / held)
+        rows = _grouped.row_tiles(N * k, held, tm) * tm
+        fill = jnp.arange(rows - N * k, dtype=jnp.int32)                    # the dummies: tm an expert, then what rounds N k up
+        expert, nth = fill // tm, fill % tm
+        filler = jnp.where((expert < held) & (nth < jnp.take(-counts % tm, jnp.minimum(expert, held - 1))), expert, held)
+        iota = jnp.arange(rows, dtype=jnp.int32)
+        _, at = jax.lax.sort((jnp.concatenate([group, filler]), iota), num_keys=1, is_stable=True)
+        _, place = jax.lax.sort((at, iota), num_keys=1)             # where each pair went, the dummies behind
+        xs = jnp.take(x.astype(dtype), jnp.where(at < N * k, at // k, 0), axis=0, mode="clip")
+        ys = _grouped.grouped_swiglu(xs, counts, w_gate.astype(dtype), w_up.astype(dtype), w_down.astype(dtype),
+                                     tm=tm, tf=tf, interpret=grouped)
+        return gathered(ys, jnp.where(here, place[:N * k].reshape(N, k), 0))
 
     def padded_form():
         # expert e's rows are places offsets[e] .. + counts[e] of the order; behind its count, zeros
@@ -201,5 +261,7 @@ def _experts(x, idx, gates, w_gate, w_up, w_down, token_mask, *, first_held, dty
         return gathered(ys, jnp.where(here, local * ROW_PAD + inside, 0))
 
     if form == SORTED:
-        return sorted_form(), counts
-    return jax.lax.cond(fits_pad(counts), padded_form, sorted_form), counts
+        return (ragged_form if grouped is None else grouped_form)(), counts     # the sorted form's two legs
+    # a candidate falls back to the XLA leg on every backend: with the kernel as the branch it rarely takes, the compiler
+    # built the padded branch it does take 0.14 ms a layer slower at 128 experts of 2048 x 768 (PERF.md section 6, PR 46)
+    return jax.lax.cond(fits_pad(counts), padded_form, ragged_form), counts

@@ -155,7 +155,8 @@ __all__ = ["HybridServeEngine", "hybrid_cache_config", "prefill_buckets"]
 COUNTERS = ("decode_launches", "prefill_launches", "decode_steps", "decode_steps_ahead", "logits_bytes_to_host",
             "prefill_tokens_real", "prefill_tokens_padded", "prefill_bucket_tokens", "decode_pages_read",
             "decode_pages_capacity", "moe_assignments", "moe_assignments_held", "moe_busiest_expert_tokens",
-            "moe_expert_slots", "moe_layer_steps", "moe_experts_touched", "moe_padded_layer_steps")
+            "moe_expert_slots", "moe_layer_steps", "moe_experts_touched", "moe_padded_layer_steps",
+            "moe_expert_layer_calls", "moe_grouped_layer_calls")
 # ... and for a model that generates by blocks, in UNITS of B rows that went through the stack for a request (a
 # denoising pass or a commit; a slot that fuses in a call is two, its commit and the next block's first pass):
 # the units, those of them that were commits, the tokens the host took, the query rows that still had something
@@ -212,15 +213,26 @@ class HybridServeEngine(DecodeAhead):
             raise ValueError(f"a block of {self.block.B} positions must divide the page of {cache.config.page_size}: "
                              "a block that straddled a page would be written to two")
         # may a decode call's expert layer take its padded form?  Its rows are static, so this is latched as the programs are
-        from ..moe.dropless import fits_pad, padded_candidate    # (jax comes with it: imported late, as everywhere in serve/)
+        from ..moe import dropless      # (jax comes with it: imported late, as everywhere in serve/)
 
         decode_rows = cache.num_slots           # ... a block engine's: every slot's open rows and the commit places'
         if self.block is not None:
             decode_rows = (cache.num_slots + self.block.commit_places(cache.num_slots)) * self.block.B
         # (a dense model's config names no experts, its steps return no ``counts["experts"]``, and ``moe_*`` stay 0)
-        self._decode_padded_candidate = hasattr(c, "experts_held") and padded_candidate(
+        self._decode_padded_candidate = hasattr(c, "experts_held") and dropless.padded_candidate(
             decode_rows, c.num_experts_per_tok, c.experts_held)
-        self._fits_pad = fits_pad
+        self._fits_pad = dropless.fits_pad
+        # the expert layers of a program (the params' ``w_gate`` leaves, (held, d, f) each) and, by its rows, how many of
+        # them are the grouped kernel outside any choice on the device (the sorted form alone, on the kernel's leg):
+        # latched here, as the programs latch it
+        import jax
+
+        experts = [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(params) if getattr(path[-1], "key", None) == "w_gate"]
+        grouped = bool(experts) and dropless.grouped_leg(c.dtype, *experts[0].shape[1:]) is not None
+        self._expert_layers = len(experts)
+        self._decode_rows = decode_rows
+        self._grouped_layers = {rows: len(experts) for rows in (*self.buckets, decode_rows) if grouped and dropless.expert_form(
+            rows, c.num_experts_per_tok, c.experts_held) == dropless.SORTED}
         # what this engine has done, in plain integers (``trace_counters``)
         self.counter_names = COUNTERS + (BLOCK_COUNTERS if self.block is not None else ()) + tuple(self.model.STEP_COUNTERS)
         for name in self.counter_names:
@@ -343,6 +355,15 @@ class HybridServeEngine(DecodeAhead):
         for tokens in (zeros, DecodeFeed(None, slots={})):      # both uses are one executable: warmed twice over
             self._run_decode(table, zeros, self._fed(tokens))
 
+    def decode(self, tokens):
+        step = super().decode(tokens)
+        self._count_expert_layers(self._decode_rows)
+        return step
+
+    def _count_expert_layers(self, rows: int) -> None:
+        """A launched program of ``rows`` rows: its expert layers, and those that are the grouped kernel."""
+        self._add({"moe_expert_layer_calls": self._expert_layers, "moe_grouped_layer_calls": self._grouped_layers.get(rows, 0)})
+
     def prefill(self, prompt: Sequence[int], slot: int) -> np.ndarray:
         """Run the prompt through the stack in its bucket, write what its
         positions leave in the cache into ``slot``'s reserved pages (and its
@@ -366,6 +387,7 @@ class HybridServeEngine(DecodeAhead):
         self.prefill_tokens_real += n
         self.prefill_tokens_padded += bucket
         self.prefill_bucket_tokens += bucket
+        self._count_expert_layers(bucket)
         self._add(self.model.prefill_counters(self.config, bucket))
         return out
 
@@ -420,7 +442,13 @@ class HybridServeEngine(DecodeAhead):
         padded form (``moe.dropless``: the call's shape made it a candidate,
         ``padded_candidate``, and its busiest expert fit the pad, ``fits_pad``:
         the layer's own two functions on the counts the step returned), so over
-        ``moe_layer_steps`` the share of expert layers that did.
+        ``moe_layer_steps`` the share of expert layers that did.  Of LAUNCHED
+        programs, prefills and decode calls both: ``moe_expert_layer_calls``
+        the expert layers they hold, ``moe_grouped_layer_calls`` those of them
+        that are the grouped kernel outside any choice on the device (by the
+        program's rows ``moe.dropless.expert_form`` says the sorted form
+        alone, and its leg is the kernel's: ``grouped_leg``; what a
+        candidate's ``cond`` took at a prefill is in no counter).
         ``prefill_bucket_tokens`` the bucket lengths.  A block engine's six
         (``BLOCK_COUNTERS``, above), then the model's own (its module's
         ``STEP_COUNTERS`` says what each counts)."""
