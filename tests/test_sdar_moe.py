@@ -21,7 +21,7 @@ from vescale_tpu.resilience import faultsim
 from vescale_tpu.serve import (ContinuousBatchingScheduler, DecodeFeed, HybridServeEngine, KVCacheOutOfPages,
                                PagedKVCache, PrefixCache, Request, SlotStateUnsupported, run_serve_resilient)
 from vescale_tpu.serve.engine import BlockSchedule
-from vescale_tpu.serve.hybrid_engine import BLOCK_COUNTERS, hybrid_cache_config
+from vescale_tpu.serve.hybrid_engine import BLOCK_COUNTERS, RowsByDemand, hybrid_cache_config
 
 FAMILY = load_family("sdar_moe")
 # hidden 64, two layers, 4 query heads over 2 key heads of 16, 8 experts of 32 with 2 a token; blocks of 4 in 4 steps
@@ -312,6 +312,103 @@ def test_the_counters_of_one_request_are_the_schedules_arithmetic(system, tmp_pa
         # a page of 8: the slot's rows read 2 pages up to position 16 and 3 past it, a commit place the block's own
         ends = [12] * 2 + [16, 12] + [16] * 3 + [20, 16] + [20] * 4
         assert c["decode_pages_read"] == sum(-(-end // PAGE) for end in ends) + 11 * (SLOTS - 1)
+
+
+# ------------------------------------------------- a pass keeps no logits
+OPEN = {1: tokens(31, 9), 3: tokens(32, 14)}        # two slots, 1 and 2 positions into their open blocks
+
+
+def _one_pass_unread(system):
+    """Both slots prefilled and one pass launched over them: the step, unread,
+    and the reference's ``B`` rows of logits for each slot's block as the pass
+    found it."""
+    _cfg, params, cache, engine, _limit = system
+    cache.reset()
+    want, plans = {}, {}
+    for slot, prompt in OPEN.items():
+        assert cache.alloc(len(prompt), 2 * B, slot=slot) == slot
+        engine.prefill(prompt, slot)
+        cache.commit_prefill(slot, len(prompt))
+        plans[slot] = engine.block.plan(engine.block.open(len(prompt)), B)
+        before = np.asarray(cache.state["block_ids"][0, slot])
+        want[slot] = np.asarray(FAMILY.block_logits(params, TOY, prompt[: len(prompt) // B * B], before))
+    return engine.decode(DecodeFeed(None, slots={slot: plan[1] for slot, plan in plans.items()})), want
+
+
+READS = {     # what a caller reads -> (the rows it gets, the reference's, how many rows were made for it)
+    "a_slot": lambda step, want: (step[1], want[1][1], 1),                      # the row of the slot's length: position 9 of 8..11
+    "slots": lambda step, want: (step[[3, 1]], np.stack([want[3][2], want[1][1]]), 2),
+    "slots_as_an_array": lambda step, want: (step[np.asarray([1, 3])], np.stack([want[1][1], want[3][2]]), 2),
+    "a_block": lambda step, want: (step.block(3), want[3], B),
+    "every_row": lambda step, want: (np.asarray(step)[[1, 3]], np.stack([want[1], want[3]]), SLOTS * B),
+}
+
+
+@pytest.mark.parametrize("read", ["nothing", *READS])
+def test_a_steps_logits_rows_are_made_when_a_caller_reads_them_and_are_the_references(system, read, monkeypatch):
+    """The pass's program returns the open rows' hidden state and no logits:
+    ``shape`` / ``dtype`` / ``len`` are the configuration's and wait for nothing;
+    ``step[slot]``, ``step[[a, b]]``, ``step.block(slot)`` and ``np.asarray(step)``
+    run the head over the rows asked for, which are the reference's to the
+    fixture's limit, and count their bytes and their rows."""
+    _cfg, _params, cache, engine, limit = system
+    step, want = _one_pass_unread(system)
+    was = engine.trace_counters()
+    assert isinstance(step._logits, RowsByDemand) and not step.read
+    if read == "nothing":
+        monkeypatch.setattr(engine, "_head_fn", None)                           # (a call of it would raise)
+        monkeypatch.setattr(step._logits, "_hidden", None)                      # ... and so would a look at the device's rows
+        assert step.shape == (SLOTS, B, TOY["vocab_size"]) and step.dtype == np.float32 and len(step) == SLOTS
+        assert not step.read and engine.trace_counters() == was
+    else:
+        got, ref, rows = READS[read](step, want)
+        assert isinstance(got, np.ndarray) and got.dtype == np.float32 and got.shape == ref.shape
+        assert rel(got, ref) < limit and step.read
+        now = engine.trace_counters()
+        assert now["logits_rows_made"] - was["logits_rows_made"] == rows
+        assert now["logits_bytes_to_host"] - was["logits_bytes_to_host"] == rows * TOY["vocab_size"] * 4
+    cache.reset()
+
+
+def test_a_serve_loop_makes_no_logits_row_and_a_sampling_caller_makes_the_rows_it_reads(system):
+    """Nobody reads logits in a greedy loop; ``replay_greedy``'s canary hook
+    (``step.block(slot)[j]`` when its fault fires) is a caller of the rows by demand."""
+    _cfg, _params, cache, engine, _limit = system
+    was = engine.trace_counters()["logits_rows_made"]
+    res, _ = _run(system, _requests()[:4])
+    assert res.status == "completed" and engine.trace_counters()["logits_rows_made"] == was
+    prompt = tokens(41, 9)
+    plain = engine.replay_greedy(prompt, 6)
+    faultsim.arm(faultsim.parse_schedule("canary_diverge:call=2,count=1"))
+    try:
+        flipped = engine.replay_greedy(prompt, 6, canary=True)
+    finally:
+        faultsim.disarm()
+    # the one token whose top logit the fault flipped (the block on the device is what it was: the rest agree)
+    assert sum(a != b for a, b in zip(flipped, plain)) == 1 and engine.trace_counters()["logits_rows_made"] == was + B
+    cache.reset()
+
+
+def test_the_toy_engine_with_the_head_kernel_interpreted_yields_the_xla_legs_tokens():
+    """Two engines over the same weights, one with ``head_select`` interpreted
+    in its pass (every other kernel on its XLA leg in both): the same streams,
+    token for token, and the same rows by demand."""
+    from vescale_tpu import kernels
+
+    cfg = toy_config()
+    mesh = DeviceMesh(("tp",), (1,), devices=jax.devices()[:1])
+    params = jax.jit(lambda k: sd.init_params(cfg, k))(jax.random.key(7))
+    engines = {}
+    for leg in ("xla", "kernel"):
+        cache = PagedKVCache(hybrid_cache_config(cfg, num_slots=SLOTS, page_size=PAGE, pages_per_slot=PAGES), mesh)
+        with pytest.MonkeyPatch.context() as patch:
+            if leg == "kernel":
+                patch.setattr(kernels, "resolve", lambda name, **kw: True if name == "head_select" else None)
+            engines[leg] = HybridServeEngine(cfg, mesh, params, cache).warm()
+        assert engines[leg].kernel_head_select == (leg == "kernel") and not engines[leg].kernel_decode
+    for seed, n, budget in ((51, 9, 11), (52, 8, 8), (53, 14, 6), (54, 5, 13)):
+        streams = [engine.replay_greedy(tokens(seed, n), budget) for engine in engines.values()]
+        assert streams[0] == streams[1] and len(streams[0]) == budget
 
 
 # ------------------------------------------- a commit in the call that opens the next block

@@ -2,7 +2,9 @@
 the ``DecodeStep`` of both engines, the serve loop's one-token path, the stub
 engines the tests drive the loop with, and the flash forward, whose new
 ``mask_block`` parameter at its default must compile and compute the causal
-kernel of before, bit for bit."""
+kernel of before, bit for bit.  And since a block engine's step makes its
+logits rows by demand (PR 47): every other engine's step still holds the array
+its decode program wrote, and a block engine's answers as that array would."""
 
 import jax
 import jax.numpy as jnp
@@ -12,14 +14,16 @@ import pytest
 from test_decode_ahead import LLAMA, PAGE, PAGES, SLOTS, _prompt, _RecordingEngine
 from test_deepseek_v2 import toy_config as deepseek_toy
 from test_granite_hybrid import toy_config as granite_toy
+from test_sdar_moe import toy_config as sdar_toy
 from vescale_tpu.mesh import DeviceMesh
 from vescale_tpu.models import deepseek_v2 as ds
 from vescale_tpu.models import granite_hybrid as gh
+from vescale_tpu.models import sdar_moe as sd
 from vescale_tpu.models.llama import Llama
 from vescale_tpu.ops.flash_attention import _flash_fwd_pallas, _to3, flash_attention
 from vescale_tpu.serve import (ContinuousBatchingScheduler, DecodeFeed, DecodeStep, HybridServeEngine, KVCacheConfig,
                                PagedKVCache, Request, ServeEngine, run_serve_resilient)
-from vescale_tpu.serve.hybrid_engine import BLOCK_COUNTERS, hybrid_cache_config
+from vescale_tpu.serve.hybrid_engine import BLOCK_COUNTERS, RowsByDemand, hybrid_cache_config
 
 
 def _engine(model):
@@ -29,7 +33,7 @@ def _engine(model):
         cache = PagedKVCache(KVCacheConfig(layers=2, kv_heads=2, head_dim=LLAMA.head_dim, num_slots=SLOTS,
                                            page_size=PAGE, pages_per_slot=PAGES), mesh)
         return ServeEngine(LLAMA, mesh, params, cache).warm(), cache
-    cfg, module = (granite_toy(), gh) if model == "granite" else (deepseek_toy(), ds)
+    cfg, module = {"granite": (granite_toy(), gh), "deepseek_v2": (deepseek_toy(), ds), "sdar": (sdar_toy(), sd)}[model]
     params = jax.jit(lambda k: module.init_params(cfg, k))(jax.random.key(7))
     cache = PagedKVCache(hybrid_cache_config(cfg, num_slots=SLOTS, page_size=PAGE, pages_per_slot=PAGES), mesh)
     return HybridServeEngine(cfg, mesh, params, cache).warm(), cache
@@ -53,6 +57,7 @@ def _an_engine_of_one_token_a_step(model):
         step = engine.decode(toks)
         cache.advance(slot)
         assert isinstance(step, DecodeStep) and step._rows is None and step.shape == (SLOTS, LLAMA.vocab_size)
+        assert isinstance(step._logits, jax.Array)            # what the decode program wrote: nothing is made by demand
         assert step.tokens.shape == (SLOTS,) and step.tokens.dtype == np.int32
         assert int(np.argmax(step[slot])) == int(step.tokens[slot]) and step[[slot]].shape == (1, LLAMA.vocab_size)
         with pytest.raises(TypeError, match="one position a slot"):
@@ -66,10 +71,41 @@ def _an_engine_of_one_token_a_step(model):
                               install_signal_handlers=False, coordinate=False)
     sched.ledger_check()
     assert res.outcomes[0]["tokens"] == by_hand
+    assert engine.trace_counters().get("logits_rows_made", 0) == 0 and engine.trace_counters()["logits_bytes_to_host"] > 0
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     held = (cache.k.data, cache.v.data) if isinstance(engine, ServeEngine) else tuple(cache.arrays().values())
     ids = engine._decode_fn.lower(engine.params, *held, i32(SLOTS, PAGES), i32(SLOTS), i32(SLOTS)).out_info[1]
     assert (ids.shape, ids.dtype) == ((SLOTS,), jnp.int32)
+    cache.reset()
+
+
+def _a_block_engines_step_answers_as_the_array_it_no_longer_holds():
+    """A toy block engine in the host-token form (the reference check's): the
+    step's logits are ``RowsByDemand``; ``step[slot]`` is one row, ``step[[a,
+    b]]`` two, ``step.block(slot)`` the block's and ``np.asarray(step)`` all of
+    them, each the same numbers whichever way they are asked for, each counted
+    by its bytes and its rows; shape, dtype and length wait for nothing."""
+    engine, cache = _engine("sdar")
+    B, vocab = engine.block.B, engine.config.vocab_size
+    prompt = _prompt(12, 6)
+    slot = cache.alloc(len(prompt), 4)
+    engine.prefill(prompt, slot)
+    cache.commit_prefill(slot, len(prompt))
+    toks = np.zeros((SLOTS,), np.int32)
+    toks[slot] = 5
+    step = engine.decode(toks)
+    assert isinstance(step._logits, RowsByDemand) and list(step._rows) == list(cache.lengths_array() % B)
+    assert step.shape == (SLOTS, B, vocab) and step.dtype == np.float32 and len(step) == SLOTS and not step.read
+    assert engine.trace_counters()["logits_rows_made"] == 0 == engine.trace_counters()["logits_bytes_to_host"]
+    whole = np.asarray(step)
+    assert whole.shape == (SLOTS, B, vocab) and whole.dtype == np.float32 and step.read
+    row, rows, block = step[slot], step[[slot, 0]], step.block(slot)
+    assert row.shape == (vocab,) and rows.shape == (2, vocab) and block.shape == (B, vocab)
+    at = int(step._rows[slot])
+    assert np.array_equal(row, whole[slot, at]) and np.array_equal(rows[0], row) and np.array_equal(rows[1], whole[0, step._rows[0]])
+    assert np.array_equal(block, whole[slot]) and int(np.argmax(row)) >= 0
+    made = SLOTS * B + 1 + 2 + B
+    assert engine.trace_counters()["logits_rows_made"] == made and engine.trace_counters()["logits_bytes_to_host"] == made * vocab * 4
     cache.reset()
 
 
@@ -115,6 +151,7 @@ def _the_flash_forward_at_the_mask_parameters_default():
 CASES = {"llama": lambda: _an_engine_of_one_token_a_step("llama"),
          "granite": lambda: _an_engine_of_one_token_a_step("granite"),
          "deepseek_v2": lambda: _an_engine_of_one_token_a_step("deepseek_v2"),
+         "block_engine_rows_by_demand": _a_block_engines_step_answers_as_the_array_it_no_longer_holds,
          "stub_engine": _a_stub_engine_without_a_schedule,
          "flash_mask_default": _the_flash_forward_at_the_mask_parameters_default}
 

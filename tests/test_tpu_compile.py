@@ -7,7 +7,7 @@ one described device, and compiles it.  Nothing executes, so this says
 nothing about results or times; it catches what interpret mode cannot — a
 kernel the compiler refuses (scoped VMEM, tiling, HBM).  The cases call the
 kernel entry points below the platform dispatch (``_flash``,
-``paged_decode``, ``ssm_step``, ``grouped_swiglu``, ``_fused_local``, ``fused_xent_parts``): code that asks
+``paged_decode``, ``ssm_step``, ``grouped_swiglu``, ``head_select``, ``_fused_local``, ``fused_xent_parts``): code that asks
 ``jax.devices()`` still sees the CPU here.
 """
 
@@ -203,6 +203,24 @@ def test_grouped_swiglu_compiles_at_the_cells_widths_and_rungs(chip, case):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20      # nothing of the rows' or the experts' size beside the operands
 
 
+# ------------------------------------------------------ head to selection
+def test_head_select_compiles_at_sdars_cell_size_and_leaves_three_vectors(chip):
+    """512 open rows on the head of 151,936 columns (1,187 x 128: the last of its
+    149 tiles of 1,024 is ragged): nothing of the logits' size beside the
+    operands, inside the VMEM a kernel has without asking for more (as the
+    float32 form is at the most rows ``supports`` lets through)."""
+    from vescale_tpu.kernels.head_select import head_select, supports
+
+    sds = lambda shape, dt=bf16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    assert supports(f32, 256, 2048, interpret=False) and supports(bf16, 512, 2048, interpret=False)
+    head_select.lower(sds((256, 2048), f32), sds((2048, 151936), f32), interpret=False).compile()
+    compiled = head_select.lower(sds((512, 2048)), sds((2048, 151936)), interpret=False).compile()
+    assert '"scoped_memory_configs":[{' not in compiled.as_text()
+    assert "tpu_custom_call" in compiled.as_text() and "151936]" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1 << 20 and memory.output_size_in_bytes < 1 << 16
+
+
 # ------------------------------------------------- whole programs of a cell
 class _JaxOnATpu:
     """``jax`` as ``ops/flash_attention.py`` sees it, but for the platform of ``jax.devices()[0]``."""
@@ -281,6 +299,42 @@ def test_sdars_rung_of_512_compiles_at_the_cells_size_and_writes_its_pools_in_pl
     kernel_calls = [line for line in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     assert sum("block_flash_fwd" in line for line in kernel_calls) == 6 and not any("grouped_swiglu" in line for line in kernel_calls)
     _assert_in_place_and_fits(compiled, sizes, "bf16[6,16385,16,4,128]")        # 1.6 GB a pool
+
+
+@pytest.mark.parametrize("leg", ["kernel", "xla"])
+def test_sdars_pass_compiles_at_the_cells_size_and_holds_no_logits(chip, leg, monkeypatch):
+    """``sdar30b_serve_blockgen``'s decode call (128 slots x 4 open rows and 40
+    places of 4 commit rows: six ``paged_decode``, six expert layers as choices
+    on the device, and ``head_select``).  A pass keeps no logits (PR 47): on the
+    kernel's leg no instruction or output of the program has the logits' shape
+    in either layout, ``f32[128,4,151936]`` (what the parent's program
+    returned, 311 MB, through a 1.5 ms layout copy) or ``f32[512,151936]`` (the
+    head's product); on the XLA leg (``VESCALE_KERNELS=off``) the product is a
+    temporary, and the three-dimensional array is still never formed.  What the
+    program returns in their place is the open rows' hidden state."""
+    if leg == "xla":
+        monkeypatch.setenv("VESCALE_KERNELS", "off")
+    _family, _config, sizes, programs = _cells_programs(chip, "sdar30b_serve_blockgen")
+    (lowered,) = [low for title, low in programs if "one pass, 128 slots x 4 positions" in title]
+    hidden, ids = lowered.out_info[:2]
+    assert (hidden.shape, hidden.dtype, ids.shape) == ((512, 2048), jnp.float32, (128, 4))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    kernel_calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert "f32[128,4,151936]" not in text
+    if leg == "kernel":
+        assert "f32[512,151936]" not in text
+        assert sum("paged_decode" in line for line in kernel_calls) == 6 and sum("head_select" in line for line in kernel_calls) == 1
+        (call,) = [line for line in kernel_calls if "head_select" in line]
+        assert "[512,128]" not in call.split("custom_call_target")[0]           # (an expert layer's signature in the cell's op table)
+        # the kernel asks for no more VMEM than a kernel has: a call that does makes the compiler build EVERY fusion of the
+        # program under another scoped limit (the six expert layers read 0.15 ms slower each: PERF.md section 6, PR 47)
+        assert '"scoped_memory_configs":[{' not in text
+        _assert_in_place_and_fits(compiled, sizes, "bf16[6,16385,16,4,128]")
+    else:                                                                       # (every kernel's XLA leg: the gathered pages are 1.1 GB of temporaries)
+        assert "f32[512,151936]" in text and not any("head_select" in line or "paged_decode" in line for line in kernel_calls)
+        memory = compiled.memory_analysis()
+        assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9, memory
 
 
 @pytest.mark.parametrize("program,kernels_in_it", [("decode step", 5), ("rung of 512 positions", 9)], ids=["decode", "rung512"])

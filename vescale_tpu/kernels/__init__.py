@@ -3,10 +3,10 @@
 Every hand-written TPU kernel in the framework lives in this package and is
 reached through the same knob (``VESCALE_KERNELS``, registered in
 ``analysis.envreg``).  Unset, each kernel takes its own default:
-``paged_decode``, ``paged_decode_latent``, ``ssm_step`` and ``grouped_experts`` are the compiled
+``paged_decode``, ``paged_decode_latent``, ``ssm_step``, ``grouped_experts`` and ``head_select`` are the compiled
 kernels on TPU and the XLA leg on every other backend (what the platform is,
-the code can see; PERF.md, PR 27, PR 29 and PR 46); the other three stay
-``off``.  Set, it means the same for all seven:
+the code can see; PERF.md, PR 27, PR 29, PR 46 and PR 47); the other three stay
+``off``.  Set, it means the same for all eight:
 
   ``off``        the kernels are never consulted — every caller takes
                  exactly the XLA path it took before this package
@@ -48,6 +48,12 @@ Kernels in this package:
     expert's tiles, and the hidden never leaves VMEM — instead of three
     ``jax.lax.ragged_dot`` with the hidden between them in HBM; dispatched by
     ``moe/dropless.py``, the default on TPU.
+  * ``head_select``      — from the head's product to what a block
+    diffusion pass selects by (``kernels/head_select.py``): a row's largest
+    logit, its id and the softmax denominator, reduced tile by tile of the
+    vocabulary as the weights stream past once, so that no logits are
+    written; dispatched by ``models/sdar_moe.py`` under
+    ``serve/hybrid_engine.py``, the default on TPU.
   * ``fused_adamw``      — the adamw_lowmem moment/update elementwise
     chain as one kernel over (g, m, v); dispatched by
     ``parallel/optimizer.py``.
@@ -94,7 +100,7 @@ __all__ = [
 MODES = ("off", "interpret", "on")
 # what an unset VESCALE_KERNELS means for these: compiled on TPU, the XLA leg
 # elsewhere (every other kernel: off)
-DEFAULT_ON_TPU = frozenset({"paged_decode", "paged_decode_latent", "ssm_step", "grouped_experts"})
+DEFAULT_ON_TPU = frozenset({"paged_decode", "paged_decode_latent", "ssm_step", "grouped_experts", "head_select"})
 
 
 def mode() -> str:

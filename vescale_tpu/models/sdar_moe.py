@@ -65,7 +65,8 @@ from .granite_hybrid import paged_attention_xla
 
 __all__ = [
     "SdarMoeConfig", "init_params", "rmsnorm", "embed", "head", "rotary", "attention_prefill", "attention_pass",
-    "expert_layer", "layer_prefill", "layer_pass", "unmask", "cache_config", "prefill_chunk", "decode_kernels",
+    "expert_layer", "layer_prefill", "layer_pass", "logit_stats", "head_stats", "unmask", "cache_config", "prefill_chunk",
+    "decode_kernels",
     "block_schedule", "serve_prefill", "serve_decode", "STEP_COUNTERS", "step_counters", "prefill_counters",
 ]
 
@@ -276,20 +277,40 @@ def layer_pass(c: SdarMoeConfig, lp, x, live, attention_step):
 
 
 # ------------------------------------------------------- from logits to a block
-def unmask(c: SdarMoeConfig, logits, ids, masked, passes, may_reveal):
-    """The static low-confidence schedule's one step: ``logits`` (S, B, vocab)
-    of a pass over blocks ``ids`` (S, B) of which ``masked`` (S, B) are still
-    to decide, at their ``passes`` (S,)-th denoising pass.  Every position's
-    greedy token and its confidence (the softmax probability of that token,
-    over the whole vocabulary); of a slot's masked positions the ``B / T`` most
+def logit_stats(logits):
+    """Of rows of logits ``(N, vocab)``, what a selection asks of each: ``top``
+    (the largest logit), ``best`` (its id, int32: a tie goes to the lowest id)
+    and ``denominator = sum(exp(logit - top))`` over the whole vocabulary, the
+    softmax's: the probability of ``best`` is its inverse."""
+    top = jnp.max(logits, axis=-1)
+    return top, jnp.argmax(logits, axis=-1).astype(jnp.int32), jnp.sum(jnp.exp(logits - top[:, None]), axis=-1)
+
+
+def head_stats(c: SdarMoeConfig, params, x, interpret: Optional[bool]):
+    """:func:`logit_stats` of the head's rows over ``x`` (N, E), on either leg:
+    ``interpret`` None the XLA leg (the logits ``(N, vocab)`` are made, and read
+    three times), else the ``head_select`` kernel's flag (the same product, tile
+    by tile of the vocabulary, and no logits)."""
+    if interpret is None:
+        return logit_stats(head(c, params, x))
+    from ..kernels.head_select import head_select
+
+    return head_select(rmsnorm(x, params["norm"]["weight"], c.rms_norm_eps).astype(c.dtype),
+                       params["lm_head"]["kernel"].astype(c.dtype), interpret=interpret)
+
+
+def unmask(c: SdarMoeConfig, best, denominator, ids, masked, passes, may_reveal):
+    """The static low-confidence schedule's one step over blocks ``ids`` (S, B)
+    of which ``masked`` (S, B) are still to decide, at their ``passes``
+    (S,)-th denoising pass: ``best`` (S, B) every position's greedy token and
+    ``denominator`` (S, B) its softmax's (:func:`logit_stats`: the position's
+    confidence, the softmax probability of that token over the whole
+    vocabulary, is its inverse); of a slot's masked positions the ``B / T`` most
     confident (the first ``B mod T`` passes one more; never more than are
     masked; none where ``may_reveal`` (S,) is False) take their token.  Returns
     the new ids and the new mask."""
     B, T = c.block_length, c.denoising_steps
-    top = jnp.max(logits, axis=-1)
-    best = jnp.argmax(logits, axis=-1).astype(ids.dtype)
-    confidence = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]), axis=-1)            # exp(top - logsumexp)
-    confidence = jnp.where(masked, confidence, -jnp.inf)
+    confidence = jnp.where(masked, 1.0 / denominator, -jnp.inf)                      # exp(top - logsumexp)
     per_pass = jnp.asarray([B // T + (k < B % T) for k in range(T)], jnp.int32)
     count = jnp.minimum(per_pass[jnp.minimum(passes, T - 1)], jnp.sum(masked, axis=-1).astype(jnp.int32))
     count = jnp.where(may_reveal, count, 0)
@@ -298,7 +319,7 @@ def unmask(c: SdarMoeConfig, logits, ids, masked, passes, may_reveal):
     ahead = (confidence[:, None, :] > confidence[:, :, None]) | (
         (confidence[:, None, :] == confidence[:, :, None]) & (j[None, None, :] < j[None, :, None]))
     take = masked & (jnp.sum(ahead, axis=-1) < count[:, None])
-    return jnp.where(take, best, ids), masked & ~take
+    return jnp.where(take, best.astype(ids.dtype), ids), masked & ~take
 
 
 # ------------------------------------------- what the serve engine asks of a model
@@ -322,15 +343,18 @@ def prefill_chunk(config: SdarMoeConfig) -> int:
 
 
 def decode_kernels(config: SdarMoeConfig, cache) -> Dict[str, Any]:
-    """The pass's kernel, latched at build: ``{"decode": the ``interpret`` flag
-    of ``paged_decode``, or None for the XLA leg}``."""
+    """The pass's kernels, latched at build: ``{"decode": the ``interpret`` flag
+    of ``paged_decode``, "head_select": that of ``head_select`` over the open
+    rows of every slot, or None for the XLA leg}``."""
     from .. import kernels as _kernels
+    from ..kernels import head_select as _head
     from ..kernels import paged_attention as _paged
 
-    return {"decode": _kernels.resolve(
-        "paged_decode",
-        supported=lambda interp: _paged.supports(cache.k.data.dtype, config.num_key_value_heads, config.head_dim,
-                                                 interpret=interp))}
+    rows = cache.num_slots * config.block_length
+    return {"decode": _kernels.resolve("paged_decode", supported=lambda interp: _paged.supports(
+                cache.k.data.dtype, config.num_key_value_heads, config.head_dim, interpret=interp)),
+            "head_select": _kernels.resolve("head_select", supported=lambda interp: _head.supports(
+                config.dtype, rows, config.hidden_size, interpret=interp))}
 
 
 def block_schedule(config: SdarMoeConfig):
@@ -393,11 +417,15 @@ def serve_decode(c: SdarMoeConfig, params, arrays, table, lengths, tokens, *, ac
     its first denoising pass: its K and V land at ``next_page`` /
     ``next_offset``, it sees ``block start + 2 B`` positions, the committed
     block's final K and V among them (a layer writes before it attends), and
-    head, confidence and selection run on the open rows alone.  To ``attend``
+    head, confidence and selection run on the open rows alone (:func:`head_stats`
+    on the leg ``kernels["head_select"]`` names: on the kernel's NO LOGITS ARE
+    MADE, on the XLA leg's they are the program's temporary).  To ``attend``
     the places are C more slots of the same call (the slot's own table row; an
     unused place has length 0), and their rows route to no expert.  A ``FUSED``
     that finds something masked, or every place taken, is an ``OWN_PASS``.
-    Returns the open rows' logits (S, B, vocab), a block a slot (S, B): the one
+    Returns the open rows' final hidden state (S x B, E) float32, slot-major (the
+    rows :func:`head` makes logits of: the engine makes those a caller reads,
+    when it reads them), a block a slot (S, B): the one
     the call committed where it fused, else the open block as the pass leaves
     it; ``{"experts": (layers, held) tokens an expert got, "block": (units of B
     rows that went through for a request, those that were commits, masked query
@@ -448,9 +476,10 @@ def serve_decode(c: SdarMoeConfig, params, arrays, table, lengths, tokens, *, ac
                                                     attend=attend)
         x, kd, vd, n = layer_pass(c, lp, x, live, step)
         experts.append(n)
+    hidden = x[: S * B]
     with jax.named_scope("vs.unmask"):
-        logits = head(c, params, x[: S * B]).reshape(S, B, -1)
-        new_ids, new_masked = unmask(c, logits, ids, masked, passes, moving & ~forced)
+        _top, best, denominator = head_stats(c, params, hidden, kernels["head_select"])
+        new_ids, new_masked = unmask(c, best.reshape(S, B), denominator.reshape(S, B), ids, masked, passes, moving & ~forced)
     commit = moving & ~jnp.any(masked, axis=-1)             # nothing was masked: the K and V just written are final
     counts = {"experts": jnp.stack(experts),
               "block": jnp.stack([jnp.sum(moving) + jnp.sum(fuse), jnp.sum(commit) + jnp.sum(fuse),
@@ -459,7 +488,7 @@ def serve_decode(c: SdarMoeConfig, params, arrays, table, lengths, tokens, *, ac
     state = {"block_ids": jnp.where(keep, held_ids, jnp.where(fresh, c.mask_token_id, new_ids)),
              "block_masked": jnp.where(keep, held_masked, fresh | new_masked),
              "block_pass": jnp.where(moving, jnp.where(commit, 0, passes + 1), held_pass)}
-    return logits, jnp.where(fuse[:, None], held_ids, new_ids), counts, {
+    return hidden, jnp.where(fuse[:, None], held_ids, new_ids), counts, {
         "k": kd, "v": vd, **{name: value[None].astype(arrays[name].dtype) for name, value in state.items()}}
 
 

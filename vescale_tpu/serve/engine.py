@@ -127,7 +127,11 @@ class DecodeStep:
     of the block, not shifted; ``step.block(slot)`` copies a slot's ``B`` rows.
     ``step[slot]`` stays ONE row, so that a caller of the host-token form reads
     what it reads of any engine: the row of the position its token was revealed
-    at (``rows``, which the launch noted from the lengths)."""
+    at (``rows``, which the launch noted from the lengths).  Such a step's logits
+    are not an array the program wrote but one that is made BY DEMAND
+    (``hybrid_engine.RowsByDemand``, which answers ``shape`` / ``dtype`` and an
+    index as an array does): the rows a caller reads are computed then, from the
+    hidden state the pass returned, and no others ever are."""
 
     __slots__ = ("_tokens", "_ids", "_logits", "_owner", "_launch", "_rows")
 

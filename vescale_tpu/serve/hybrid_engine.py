@@ -64,8 +64,11 @@ gives, as plain functions of the config:
       the names of the model's own counters and what one decode step, and one
       prefill, adds to them;
   ``block_schedule(config)`` (only a model that generates by blocks)
-      its ``engine.BlockSchedule``; ``serve_decode`` then returns ``(logits (S,
-      B, vocab), ids (S, B), counts, arrays)`` and takes ``write_page`` /
+      its ``engine.BlockSchedule``; ``serve_decode`` then returns ``(hidden (S x
+      B, E), ids (S, B), counts, arrays)``, the open rows' final hidden state
+      where another model's returns logits (``head(config, params, hidden
+      rows)`` is what makes logits of them: the engine runs it over the rows a
+      caller reads, when it reads them), and takes ``write_page`` /
       ``write_offset`` of each slot's open BLOCK, whose first position is
       ``lengths // B * B``, and ``next_page`` / ``next_offset`` of the block
       after it (a call that commits a block and opens the next writes both).
@@ -101,7 +104,14 @@ block's K/V at the block's own positions, attends ``block start + B`` positions
 (all that came before, and the block itself in full), and takes confidence,
 selection and the new ids in the program; a block with nothing masked waits for
 its COMMIT, which runs its final ids through the stack once more: their K/V are
-the block's, its ids the block's tokens.  A commit rides in the call that opens
+the block's, its ids the block's tokens.  A PASS KEEPS NO LOGITS: its program
+returns the open rows' hidden state (``S x B`` rows of the hidden size, 4 MB
+where the logits are 311), and the step's ``(S, B, vocab)`` logits are a
+:class:`RowsByDemand`: ``step[slot]``, ``step[[a, b]]``, ``step.block(slot)`` and
+``np.asarray(step)`` run the model's ``head`` over the rows asked for (a small
+program, compiled once a row count) and copy them out, ``step.shape`` /
+``step.dtype`` / ``len(step)`` touch nothing; ``logits_rows_made`` counts the
+rows so made (none in a serve loop that samples greedily).  A commit rides in the call that opens
 the next block (``BlockSchedule.FUSED``: the final ids go through as B commit
 rows in one of the program's ``commit_places`` places, the slot's open rows are
 the block after it, all masked, at its first pass, and the state it leaves is
@@ -149,11 +159,11 @@ from ..ndtimeline.api import ndtimeit, register_counter_source
 from .engine import BlockSchedule, DecodeAhead, DecodeFeed, prefill_buckets
 from .kv_cache import KVCacheConfig, PagedKVCache
 
-__all__ = ["HybridServeEngine", "hybrid_cache_config", "prefill_buckets"]
+__all__ = ["HybridServeEngine", "RowsByDemand", "hybrid_cache_config", "prefill_buckets"]
 
 # what the engine counts for every model (``trace_counters``); a model's own follow (``STEP_COUNTERS``)
 COUNTERS = ("decode_launches", "prefill_launches", "decode_steps", "decode_steps_ahead", "logits_bytes_to_host",
-            "prefill_tokens_real", "prefill_tokens_padded", "prefill_bucket_tokens", "decode_pages_read",
+            "logits_rows_made", "prefill_tokens_real", "prefill_tokens_padded", "prefill_bucket_tokens", "decode_pages_read",
             "decode_pages_capacity", "moe_assignments", "moe_assignments_held", "moe_busiest_expert_tokens",
             "moe_expert_slots", "moe_layer_steps", "moe_experts_touched", "moe_padded_layer_steps",
             "moe_expert_layer_calls", "moe_grouped_layer_calls")
@@ -165,6 +175,36 @@ COUNTERS = ("decode_launches", "prefill_launches", "decode_steps", "decode_steps
 # the calls read
 BLOCK_COUNTERS = ("block_passes", "block_commit_passes", "block_tokens_emitted", "block_positions_masked",
                   "block_commits_fused", "block_commits_deferred")
+
+
+class RowsByDemand:
+    """What a block engine's ``DecodeStep`` holds where the logits lay: the
+    ``(S, B, vocab)`` float32 logits of a pass's open rows as an array that is
+    NOT THERE until it is read.  ``shape``, ``dtype`` and ``len`` are the
+    configuration's and touch no device value; an index (whatever numpy takes
+    over the first two axes: a slot, slots, ``(slots, rows)``, ``...``) runs the
+    model's ``head``, with the parameters the pass ran on, over those rows of
+    the hidden state the pass returned and gives them as a device array, and
+    adds them to the engine's ``logits_rows_made``; ``np.asarray`` is every row."""
+
+    __slots__ = ("_engine", "_params", "_hidden", "shape")
+    dtype = np.dtype(np.float32)
+
+    def __init__(self, engine: "HybridServeEngine", params, hidden):
+        self._engine, self._params, self._hidden = engine, params, hidden
+        self.shape = (engine.cache.num_slots, engine.block.B, engine.config.vocab_size)
+
+    def __len__(self) -> int:
+        return self.shape[0]
+
+    def __getitem__(self, index):
+        S, B, vocab = self.shape
+        which = np.arange(S * B, dtype=np.int32).reshape(S, B)[index]
+        self._engine.logits_rows_made += which.size
+        return self._engine._head_fn(self._params, self._hidden, which.reshape(-1)).reshape(which.shape + (vocab,))
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        return np.asarray(self[...], dtype=dtype)
 
 
 def _model_of(config):
@@ -288,13 +328,19 @@ class HybridServeEngine(DecodeAhead):
                 # every slot's greedy token, in this program (``DecodeStep.tokens``)
                 next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             else:
-                logits, next_ids, counts, arrays = out       # the block as the pass leaves it: the model's own selection
+                # the block as the pass leaves it, the model's own selection; and where the logits would be, the open
+                # rows' hidden state: a pass keeps no logits (``RowsByDemand``)
+                logits, next_ids, counts, arrays = out
             return (logits, next_ids, counts) + tuple(arrays[name] for name in names)
+
+        def head_rows(params, hidden, which):       # the rows of logits a caller reads, when it reads them
+            return model.head(c, params, hidden[which])
 
         donated = tuple(range(1, 1 + n))
         self._array_names = names
         self._prefill_fn = jax.jit(prefill, donate_argnums=donated)
         self._decode_fn = jax.jit(decode, donate_argnums=donated)
+        self._head_fn = jax.jit(head_rows) if block is not None else None
         self._init_decode_ahead(jax.sharding.SingleDeviceSharding(self.mesh.jax_mesh.devices.flat[0]))
 
     def warm(self) -> "HybridServeEngine":
@@ -324,8 +370,11 @@ class HybridServeEngine(DecodeAhead):
         return logits
 
     def _run_decode(self, table, lengths, tokens):
-        logits, next_ids, counts, *arrays = self._decode_fn(self.params, *self._held(), table, lengths, tokens)
+        params = self.params
+        logits, next_ids, counts, *arrays = self._decode_fn(params, *self._held(), table, lengths, tokens)
         self.cache.update_arrays(dict(zip(self._array_names, arrays)))
+        if self.block is not None:
+            logits = RowsByDemand(self, params, logits)
         return logits, next_ids, counts
 
     # ------------------------------------------------- a block engine's own
@@ -428,7 +477,10 @@ class HybridServeEngine(DecodeAhead):
         has mean the same here (``decode_launches`` / ``prefill_launches`` are
         of calls that enqueued; ``decode_steps`` / ``decode_steps_ahead`` are
         of steps read; ``logits_bytes_to_host`` is what callers copied
-        out of ``decode``'s results; ``prefill_tokens_padded`` is the bucket;
+        out of ``decode``'s results, ``logits_rows_made`` the rows of logits a
+        block engine's steps computed because a caller read them (another
+        engine's decode program makes every slot's row, and this stays 0);
+        ``prefill_tokens_padded`` is the bucket;
         ``decode_pages_*`` are a layer's, and count only with the kernel leg).
         Of ``decode`` calls alone: ``moe_assignments`` = positions that went
         through the stack for a request (one an active slot; ``B`` a unit, a pass
