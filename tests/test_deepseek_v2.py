@@ -17,6 +17,7 @@ import pytest
 from benchmark.spec import SpecError, load_family
 from vescale_tpu.kernels.paged_attention import paged_decode_latent, supports_latent
 from vescale_tpu.mesh import DeviceMesh
+from vescale_tpu.models import blocks
 from vescale_tpu.models import deepseek_v2 as ds
 from vescale_tpu.moe import route_group_limited
 from vescale_tpu.serve import (ContinuousBatchingScheduler, HybridServeEngine, KVCacheConfig, PagedKVCache, PrefixCache,
@@ -139,10 +140,9 @@ def test_the_absorbed_form_is_the_expanded_form_on_the_same_weights():
     pool = pool.at[0, jnp.asarray([3, 1, 5, 2])].set(rows.reshape(4, PAGE, 1, cfg.cache_row))   # pages out of order
     pool = pool.at[0, 2, PAGE - 1].set(37.0)        # the last position is the step's to write
     table = jnp.asarray([[3, 1, 5, 2]], jnp.int32)
-    attend = lambda q, pool, table, valid, **kw: ds.latent_attention_xla(q, pool, table, valid, **kw)
     y1, pool = jax.jit(lambda u1, pool: ds.mla_step(
         cfg, ap, u1, pool, layer=0, table=table, page=jnp.asarray([2]), offset=jnp.asarray([PAGE - 1]),
-        positions=jnp.asarray([T - 1]), valid_len=jnp.asarray([T]), attend=attend))(u[T - 1:], pool)
+        positions=jnp.asarray([T - 1]), valid_len=jnp.asarray([T]), interpret=None))(u[T - 1:], pool)
     assert rel(y1[0], y[T - 1]) < TIGHT
     assert rel(pool[0, 2, PAGE - 1, 0], rows[T - 1]) < TIGHT
 
@@ -150,7 +150,7 @@ def test_the_absorbed_form_is_the_expanded_form_on_the_same_weights():
 # -------------------------------------------------------------------- rotary
 def test_yarn_frequencies_and_mscale_are_the_hand_computed_ones():
     cfg = FAMILY.program_config(dict(TOY, qk_rope_head_dim=64))
-    inv = ds.yarn_inv_freq(cfg)
+    inv = ds.inv_freq(cfg)
     plain = 10000.0 ** (-np.arange(32) / 32.0)
     # correction range: 64 ln(4096 / (2 pi r)) / (2 ln 10000) at r = 32 and 1: 10.46 -> 10, 22.50 -> 23
     low = math.floor(64 * math.log(4096 / (32 * 2 * math.pi)) / (2 * math.log(10000)))
@@ -160,8 +160,8 @@ def test_yarn_frequencies_and_mscale_are_the_hand_computed_ones():
     np.testing.assert_allclose(inv[23:], plain[23:] / 40, rtol=1e-6)             # slow pairs are stretched 40-fold
     np.testing.assert_allclose(inv[15], plain[15] * (1 - 5 / 13) + plain[15] / 40 * (5 / 13), rtol=1e-6)
     np.testing.assert_allclose(inv, FAMILY.yarn_inv_freq(dict(TOY, qk_rope_head_dim=64)), rtol=1e-6)
-    assert ds.yarn_mscale(40, 0.707) == pytest.approx(0.1 * 0.707 * math.log(40) + 1) == pytest.approx(1.2608, abs=1e-4)
-    assert ds.yarn_mscale(1, 0.707) == 1.0
+    assert blocks.yarn_mscale(40, 0.707) == pytest.approx(0.1 * 0.707 * math.log(40) + 1) == pytest.approx(1.2608, abs=1e-4)
+    assert blocks.yarn_mscale(1, 0.707) == 1.0
     real = FAMILY.program_config(dict(TOY, qk_nope_head_dim=128, qk_rope_head_dim=64))
     assert real.softmax_scale == pytest.approx(192 ** -0.5 * 1.5896, rel=1e-4)
 
@@ -169,7 +169,7 @@ def test_yarn_frequencies_and_mscale_are_the_hand_computed_ones():
 def test_rotary_turns_interleaved_pairs_and_scores_depend_on_the_distance_alone():
     cfg = toy_config()
     x = jax.random.normal(jax.random.key(0), (6, cfg.qk_rope_head_dim), jnp.float32)
-    inv = ds.yarn_inv_freq(cfg)
+    inv = ds.inv_freq(cfg)
     got = np.asarray(ds.rotary(cfg, x, jnp.arange(6)))
     for t in range(6):
         for i in range(cfg.qk_rope_head_dim // 2):
@@ -350,7 +350,7 @@ def test_the_latent_decode_kernel_is_the_xla_leg_on_ragged_lengths_a_null_page_a
     valid = jnp.asarray(lengths, jnp.int32)
     for layer in (0, 1):
         got = paged_decode_latent(q, pool, table, valid, layer=layer, scale=0.3, latent=128, interpret=True)
-        want = ds.latent_attention_xla(q, pool, table, valid, layer=layer, scale=0.3, latent=128)
+        want = paged_decode_latent(q, pool, table, valid, layer=layer, scale=0.3, latent=128, interpret=None)
         assert got.shape == (len(lengths), 8, 128) and got.dtype == jnp.float32
         assert np.isfinite(np.asarray(got)).all() and not np.asarray(got[0]).any()
         assert float(jnp.max(jnp.abs(got - want))) < bound * float(jnp.max(jnp.abs(want)))

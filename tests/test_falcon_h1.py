@@ -1,5 +1,5 @@
 """The Falcon-H1 block on the serve path (``models/falcon_h1.py``, the Mamba-2
-functions of ``models/granite_hybrid.py`` at G = 2, ``kernels/ssm_step.py``
+functions of ``models/mamba2.py`` at G = 2, ``kernels/ssm_step.py``
 with groups, ``serve/hybrid_engine.py`` over a cache whose every layer has
 pages and slot state) at a small size on the CPU, against the plain float32
 reference of ``benchmark/families/falcon_h1.py`` (which imports nothing of the
@@ -15,7 +15,7 @@ import pytest
 from benchmark.spec import SpecError, load_family
 from vescale_tpu.mesh import DeviceMesh
 from vescale_tpu.models import falcon_h1 as fh
-from vescale_tpu.models import granite_hybrid as gh
+from vescale_tpu.models import blocks, mamba2
 from vescale_tpu.serve import (ContinuousBatchingScheduler, HybridServeEngine, PagedKVCache, Request,
                                SlotStateUnsupported, run_serve_resilient)
 from vescale_tpu.serve.hybrid_engine import hybrid_cache_config
@@ -112,14 +112,14 @@ def test_chunked_scan_with_two_groups_is_the_sequential_recurrence_under_every_b
     scale = fh.in_scale(cfg)
     u = jax.random.normal(jax.random.key(length), (bucket, cfg.hidden_size), jnp.float32)
     u = u.at[length:].set(37.0)                       # a pad that would show if anything read it
-    y, state, tail = jax.jit(lambda u: gh.mamba2_prefill(cfg, mp, u, length, in_scale=scale))(u)
+    y, state, tail = jax.jit(lambda u: mamba2.mamba2_prefill(cfg, mp, u, length, in_scale=scale))(u)
     want = FAMILY.mamba_mixer(mp, u[:length], heads=cfg.mamba_n_heads, head_width=cfg.mamba_d_head,
                               state=cfg.mamba_d_state, groups=2, multipliers=cfg.ssm_multipliers, eps=cfg.rms_norm_eps)
     assert rel(y[:length], want) < TIGHT
     # the state and the tail: the program's own one-step recurrence fed the real positions one by one
     h = jnp.zeros((1, 1) + cfg.ssm_state_shape, jnp.float32)          # (layers, slots, N, H P)
     t = jnp.zeros((1,) + cfg.conv_tail_shape, jnp.float32)
-    step = jax.jit(lambda u1, h, t: gh.mamba2_step(cfg, mp, u1, h, t, layer=0, in_scale=scale))
+    step = jax.jit(lambda u1, h, t: mamba2.mamba2_step(cfg, mp, u1, h, t, layer=0, in_scale=scale))
     for i in range(length):
         y1, h, t = step(u[i][None], h, t)
         assert rel(y1[0], want[i]) < 4 * TIGHT
@@ -134,7 +134,7 @@ def test_each_group_of_heads_reads_its_own_b_and_c():
     x, B, C = (jnp.asarray(rng.normal(size=s), jnp.float32) for s in ((T, H, P), (T, G, N), (T, G, N)))
     dt = jnp.asarray(rng.uniform(0.01, 0.5, size=(T, H)), jnp.float32)
     A = -jnp.asarray([1.0, 9.0, 3.0, 0.5], jnp.float32)
-    y, last = gh.ssd_chunked(x, dt, A, B, C, 8)
+    y, last = mamba2.ssd_chunked(x, dt, A, B, C, 8)
     h, want = np.zeros((H, P, N)), np.zeros((T, H, P))
     for i in range(T):
         for head in range(H):
@@ -142,8 +142,8 @@ def test_each_group_of_heads_reads_its_own_b_and_c():
             h[head] = np.exp(float(dt[i, head] * A[head])) * h[head] + np.outer(np.asarray(dt[i, head] * x[i, head]), B[i, g])
             want[i, head] = h[head] @ np.asarray(C[i, g])
     assert rel(y, want) < 1e-5 and rel(last, h) < 1e-5
-    _, cut = gh.ssd_chunked(x[:8], dt[:8], A, B[:8], C[:8], 8)
-    y2, rest = gh.ssd_chunked(x[8:], dt[8:], A, B[8:], C[8:], 8, initial_state=cut)
+    _, cut = mamba2.ssd_chunked(x[:8], dt[:8], A, B[:8], C[:8], 8)
+    y2, rest = mamba2.ssd_chunked(x[8:], dt[8:], A, B[8:], C[8:], 8, initial_state=cut)
     assert rel(rest, last) < 1e-6 and rel(y2, y[8:]) < 1e-5
 
 
@@ -157,13 +157,13 @@ def test_with_one_group_the_generalised_scan_step_and_kernel_give_granites_resul
     dt = jnp.asarray(rng.uniform(0.01, 0.5, size=(T, H)), jnp.float32)
     A = -jnp.asarray(rng.uniform(1, 16, size=(H,)), jnp.float32)
     same = lambda a, b: all(bool(jnp.all(p == q)) for p, q in zip(a, b))
-    assert same(gh.ssd_chunked(x, dt, A, B[:, None], C[:, None], 8), gh.ssd_chunked(x, dt, A, B, C, 8))
+    assert same(mamba2.ssd_chunked(x, dt, A, B[:, None], C[:, None], 8), mamba2.ssd_chunked(x, dt, A, B, C, 8))
     J = H * P
     state = jnp.asarray(rng.normal(size=(L, S, N, J)), jnp.float32)
     decay = jnp.asarray(rng.uniform(0.1, 1.0, size=(S, J)), jnp.float32)
     dtx, Bs, Cs = (jnp.asarray(rng.normal(size=s), jnp.float32) for s in ((S, J), (S, N), (S, N)))
-    assert same(gh.ssm_advance_xla(state, decay, dtx, Bs[:, None], Cs[:, None], layer=1),
-                gh.ssm_advance_xla(state, decay, dtx, Bs, Cs, layer=1))
+    assert same(ssm_step(state, decay, dtx, Bs[:, None], Cs[:, None], layer=1, interpret=None),
+                ssm_step(state, decay, dtx, Bs, Cs, layer=1, interpret=None))
     assert same(ssm_step(jnp.array(state), decay, dtx, Bs[:, None], Cs[:, None], layer=1, interpret=True),
                 ssm_step(jnp.array(state), decay, dtx, Bs, Cs, layer=1, interpret=True))
 
@@ -178,7 +178,7 @@ def test_the_ssm_step_kernel_with_groups_is_the_xla_leg_and_leaves_the_other_lay
     state = jnp.asarray(rng.normal(size=(L, S, N, J)), jnp.float32)
     decay = jnp.asarray(rng.uniform(0.1, 1.0, size=(S, J)), jnp.float32)
     dtx, B, C = (jnp.asarray(rng.normal(size=shape), jnp.float32) for shape in ((S, J), (S, G, N), (S, G, N)))
-    want_state, want_y = gh.ssm_advance_xla(state, decay, dtx, B, C, layer=layer)
+    want_state, want_y = ssm_step(state, decay, dtx, B, C, layer=layer, interpret=None)
     got_state, got_y = ssm_step(jnp.array(state), decay, dtx, B, C, layer=layer, interpret=True)
     assert rel(got_y, want_y) < 1e-6 and rel(got_state[layer], want_state[layer]) < 1e-6
     assert bool(jnp.all(got_state[0] == state[0]))
@@ -359,8 +359,8 @@ def test_the_init_rule_gives_every_branch_a_visible_share_of_the_stream():
     x = fh.embed(cfg, params, jnp.asarray(tokens(9, 32)))
     assert 0.7 < rms(x) < 1.4
     lp = params["layers_0"]
-    u = gh.rmsnorm(x, lp["input_layernorm"]["weight"], cfg.rms_norm_eps)
-    ym = gh.mamba2_prefill(cfg, lp["mamba"], cfg.ssm_in_multiplier * u, 32, in_scale=fh.in_scale(cfg))[0]
+    u = blocks.rmsnorm(x, lp["input_layernorm"]["weight"], cfg.rms_norm_eps)
+    ym = mamba2.mamba2_prefill(cfg, lp["mamba"], cfg.ssm_in_multiplier * u, 32, in_scale=fh.in_scale(cfg))[0]
     ya = fh.attention_prefill(cfg, lp["self_attn"], cfg.attention_in_multiplier * u)[0]
     yf = fh.mlp(cfg, lp["feed_forward"], u)
     for branch in (cfg.ssm_out_multiplier * ym, cfg.attention_out_multiplier * ya, yf):

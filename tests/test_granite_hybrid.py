@@ -1,5 +1,5 @@
 """The Granite-4.0-H block on the serve path (``models/granite_hybrid.py``,
-``moe/dropless.py``, the slot state of ``serve/kv_cache.py``,
+``models/mamba2.py``, ``moe/dropless.py``, the slot state of ``serve/kv_cache.py``,
 ``serve/hybrid_engine.py``) at a small size on the CPU, against the plain
 float32 reference of ``benchmark/families/granite_hybrid.py`` (which imports
 nothing of the program) and against per-token loops written here."""
@@ -14,6 +14,7 @@ import pytest
 from benchmark.spec import SpecError, load_family
 from vescale_tpu.mesh import DeviceMesh
 from vescale_tpu.models import granite_hybrid as gh
+from vescale_tpu.models import mamba2
 from vescale_tpu.moe import TokenDispatcher, dropless_experts, route_topk
 from vescale_tpu.serve import (ContinuousBatchingScheduler, HybridServeEngine, PagedKVCache, PrefixCache, Request,
                                SlotStateUnsupported, SpeculativeDecoder)
@@ -97,7 +98,7 @@ def test_chunked_scan_prefill_is_the_sequential_recurrence_under_every_buckets_p
     mp = gh.init_params(cfg, jax.random.key(1))["layers_0"]["mixer"]
     u = jax.random.normal(jax.random.key(length), (bucket, cfg.hidden_size), jnp.float32)
     u = u.at[length:].set(37.0)                       # a pad that would show if anything read it
-    y, state, tail = jax.jit(lambda u: gh.mamba2_prefill(cfg, mp, u, length))(u)
+    y, state, tail = jax.jit(lambda u: mamba2.mamba2_prefill(cfg, mp, u, length))(u)
     # the output: the reference's position-at-a-time scan over the real positions alone
     want = FAMILY.mamba_mixer(mp, u[:length], heads=cfg.mamba_n_heads, head_width=cfg.mamba_d_head,
                               state=cfg.mamba_d_state, eps=cfg.rms_norm_eps)
@@ -105,7 +106,7 @@ def test_chunked_scan_prefill_is_the_sequential_recurrence_under_every_buckets_p
     # the state and the tail: the program's own one-step recurrence fed the real positions one by one
     h = jnp.zeros((1, 1) + cfg.ssm_state_shape, jnp.float32)          # (layers, slots, N, H P)
     t = jnp.zeros((1,) + cfg.conv_tail_shape, jnp.float32)
-    step = jax.jit(lambda u1, h, t: gh.mamba2_step(cfg, mp, u1, h, t, layer=0))
+    step = jax.jit(lambda u1, h, t: mamba2.mamba2_step(cfg, mp, u1, h, t, layer=0))
     for i in range(length):
         y1, h, t = step(u[i][None], h, t)
         assert rel(y1[0], want[i]) < 5 * TIGHT
@@ -119,9 +120,9 @@ def test_a_position_with_step_size_zero_leaves_the_state_alone():
     x, B, C = (jnp.asarray(rng.normal(size=s), jnp.float32) for s in ((T, H, P), (T, N), (T, N)))
     dt = jnp.asarray(rng.uniform(0.01, 0.5, size=(T, H)), jnp.float32).at[11:].set(0.0)
     A = -jnp.asarray([1.0, 9.0], jnp.float32)
-    _, whole = gh.ssd_chunked(x, dt, A, B, C, 8)
-    _, cut = gh.ssd_chunked(x[:8], dt[:8], A, B[:8], C[:8], 8)
-    _, rest = gh.ssd_chunked(x[8:], dt[8:], A, B[8:], C[8:], 8, initial_state=cut)
+    _, whole = mamba2.ssd_chunked(x, dt, A, B, C, 8)
+    _, cut = mamba2.ssd_chunked(x[:8], dt[:8], A, B[:8], C[:8], 8)
+    _, rest = mamba2.ssd_chunked(x[8:], dt[8:], A, B[8:], C[8:], 8, initial_state=cut)
     assert rel(whole, rest) < 1e-6
     h = np.zeros((H, P, N))
     for i in range(11):
@@ -407,7 +408,7 @@ def test_the_ssm_step_kernel_is_the_xla_leg_and_leaves_the_other_layers_alone(la
     state = jnp.asarray(rng.normal(size=(L, S, N, J)), jnp.float32)
     decay = jnp.asarray(rng.uniform(0.1, 1.0, size=(S, J)), jnp.float32)
     dtx, B, C = (jnp.asarray(rng.normal(size=shape), jnp.float32) for shape in ((S, J), (S, N), (S, N)))
-    want_state, want_y = gh.ssm_advance_xla(state, decay, dtx, B, C, layer=layer)
+    want_state, want_y = ssm_step(state, decay, dtx, B, C, layer=layer, interpret=None)
     got_state, got_y = ssm_step(jnp.array(state), decay, dtx, B, C, layer=layer, interpret=True)
     assert rel(got_y, want_y) < 1e-6 and rel(got_state[layer], want_state[layer]) < 1e-6
     for other in set(range(L)) - {layer}:

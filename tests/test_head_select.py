@@ -1,6 +1,6 @@
 """The head-to-selection kernel (``kernels/head_select.py``), interpreted on the
-CPU, against the XLA leg it replaces (``models/sdar_moe.py:logit_stats`` over
-the head's product): the largest logit of a row and its id exactly, the softmax
+CPU, against the op's XLA leg (``logit_stats`` over the head's product, made
+whole): the largest logit of a row and its id exactly, the softmax
 denominator to a few float32 steps at its scale; and where the benchmark's op
 table files the kernel's device event."""
 
@@ -12,7 +12,6 @@ import pytest
 from vescale_tpu import kernels
 from vescale_tpu.kernels import ulps_at_scale
 from vescale_tpu.kernels.head_select import head_select, supports
-from vescale_tpu.models import sdar_moe as sd
 
 f32, bf16 = jnp.float32, jnp.bfloat16
 DENOMINATOR_ULPS = 8        # an online sum against a two-pass one, at the sum's scale
@@ -31,7 +30,7 @@ def _operands(rows, d, vocab, dtype, seed=0):
 def _both(x, w, dtype, **tiles):
     x, w = jnp.asarray(x, dtype), jnp.asarray(w, dtype)
     got = head_select(x, w, interpret=True, **tiles)
-    want = sd.logit_stats(jnp.dot(x, w, preferred_element_type=f32))
+    want = head_select(x, w, interpret=None)
     return [np.asarray(a) for a in got], [np.asarray(a) for a in want]
 
 
@@ -131,7 +130,7 @@ def _the_op_table_files_the_kernels_event_under_unmask():
     assert mechanism_of("%fusion = f32[512,128]{1,0} fusion(f32[512,2048] %x)", signatures) == "experts"
     rows, E, V = 512, spec.config["hidden_size"], spec.config["vocab_size"]
     x, w = jax.ShapeDtypeStruct((rows, E), bf16), jax.ShapeDtypeStruct((E, V), bf16)
-    hlo = head_select.trace(x, w, interpret=False).lower(lowering_platforms=("tpu",)).as_text(dialect="hlo")
+    hlo = jax.jit(lambda x, w: head_select(x, w, interpret=False)).trace(x, w).lower(lowering_platforms=("tpu",)).as_text(dialect="hlo")
     (call,) = [line for line in hlo.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
     assert f"bf16[{E},{V}]" in call and "[512,128]" not in call
     assert mechanism_of(call, signatures) == "unmask"

@@ -206,18 +206,10 @@ def test_flash_xla_impl_gqa_grads_match_dense(kmode):
 
 # ========================================================== paged decode
 def _paged_ref(q, kp, vp, table, lengths, scale):
-    """The engine's XLA leg over ONE layer's (N, page, KV, hd) pool."""
-    S, H, hd = q.shape
-    _, page, KV, _ = kp.shape
-    Tmax = page * table.shape[1]
-    ks = jnp.take(kp, table, axis=0).reshape(S, Tmax, KV, hd)
-    vs = jnp.take(vp, table, axis=0).reshape(S, Tmax, KV, hd)
-    qg = (q.astype(jnp.float32) * scale).reshape(S, KV, H // KV, hd)
-    s = jnp.einsum("skgd,stkd->skgt", qg, ks.astype(jnp.float32))
-    mask = jnp.arange(Tmax, dtype=jnp.int32)[None, :] < lengths[:, None]
-    s = jnp.where(mask[:, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("skgt,stkd->skgd", p, vs.astype(jnp.float32)).reshape(S, H, hd)
+    """The op's XLA leg (``interpret=None``) over ONE layer's (N, page, KV, hd) pool."""
+    from vescale_tpu.kernels.paged_attention import paged_decode
+
+    return paged_decode(q, kp[None], vp[None], table, lengths, layer=0, scale=scale, interpret=None)
 
 
 # (S, Pmax, page, KV, hd, H): a toy, and the two serve cells' head layouts at

@@ -16,6 +16,7 @@ import pytest
 
 from benchmark.spec import SpecError, load_family
 from vescale_tpu.mesh import DeviceMesh
+from vescale_tpu.models import blocks
 from vescale_tpu.models import laguna as lg
 from vescale_tpu.serve import (ContinuousBatchingScheduler, HybridServeEngine, PagedKVCache, PrefixCache, Request,
                                SlotStateUnsupported, run_serve_resilient)
@@ -378,7 +379,7 @@ def test_the_init_rule_gives_a_sliding_layers_attention_a_visible_share_of_the_s
     live = jnp.ones((32,), bool)
     x, _k, _v = lg.layer_prefill(cfg, params["layers_0"], 0, x, live)
     lp = params["layers_1"]
-    u = lg.rmsnorm(x, lp["input_layernorm"]["weight"], cfg.rms_norm_eps)
+    u = blocks.rmsnorm(x, lp["input_layernorm"]["weight"], cfg.rms_norm_eps)
     y, _k, _v = lg.attention_prefill(cfg, lp["self_attn"], u, lg.SLIDING)
     assert 0.05 < rms(y) / rms(x) < 0.65, rms(y) / rms(x)
     q, k, _v, gate = lg._qkvg(cfg, lp["self_attn"], u, jnp.arange(32), lg.SLIDING)

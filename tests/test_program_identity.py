@@ -1,0 +1,141 @@
+"""The serve programs of the five families behind ``HybridServeEngine`` are WHAT
+THEIR FUNCTIONS COMPUTE, not where those are written: each family's prefill at
+its first two rungs and its decode step, at the toy widths of the family's own
+test file in the type they are served in (bfloat16), on both legs
+(``VESCALE_KERNELS`` unset: the XLA legs a CPU takes; ``interpret``: the Pallas
+kernels a TPU compiles, through the interpreter), traced and lowered, never run.
+
+Pinned per program: a digest of the jaxpr, kernel bodies and all (it holds no
+source location), and how many operations of the lowered module lie under each
+``jax.named_scope`` the benchmark's per-layer readers match (``vs.attn``,
+``vs.moe``, ``vs.mamba``, ``vs.mlp``, ``vs.unmask``): a scope is not in a
+jaxpr's text.  Taken on the parent of the PR that gave the shared blocks one
+home each (e1809cb: this file on that tree, ``PROGRAMS`` printed by ``python
+tests/test_program_identity.py``).  A PR that changes these programs on purpose
+takes them anew."""
+
+import collections
+import dataclasses
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import test_deepseek_v2
+import test_falcon_h1
+import test_granite_hybrid
+import test_laguna
+import test_sdar_moe
+from vescale_tpu.mesh import DeviceMesh
+from vescale_tpu.moe import dropless
+from vescale_tpu.serve import HybridServeEngine, PagedKVCache
+from vescale_tpu.serve.hybrid_engine import hybrid_cache_config
+
+FAMILIES = {"granite_hybrid": test_granite_hybrid, "deepseek_v2": test_deepseek_v2, "sdar_moe": test_sdar_moe,
+            "falcon_h1": test_falcon_h1, "laguna": test_laguna}
+LEGS = {"xla_legs": None, "kernels_interpreted": "interpret"}
+WHICH = ("prefill_rung_1", "prefill_rung_2", "decode")
+
+PROGRAMS = {
+    "granite_hybrid/xla_legs/prefill_rung_1": ('ddbafc293ce0716a', 'vs.attn=47 vs.mamba=483 vs.moe=160'),
+    "granite_hybrid/xla_legs/prefill_rung_2": ('bedcb3aa605f41d9', 'vs.attn=47 vs.mamba=483 vs.moe=160'),
+    "granite_hybrid/xla_legs/decode": ('648f82e75f10971f', 'vs.attn=106 vs.mamba=309 vs.moe=160'),
+    "granite_hybrid/kernels_interpreted/prefill_rung_1": ('7d7dd0c97eda3976', 'vs.attn=554 vs.mamba=483 vs.moe=160'),
+    "granite_hybrid/kernels_interpreted/prefill_rung_2": ('12ee271ddde61bc7', 'vs.attn=554 vs.mamba=483 vs.moe=160'),
+    "granite_hybrid/kernels_interpreted/decode": ('f67535cadf866b5f', 'vs.attn=69 vs.mamba=255 vs.moe=160'),
+    "deepseek_v2/xla_legs/prefill_rung_1": ('4164d8487fe1a9fb', 'vs.attn=408 vs.mlp=9 vs.moe=108'),
+    "deepseek_v2/xla_legs/prefill_rung_2": ('c1458b6c8dcc5f5d', 'vs.attn=408 vs.mlp=9 vs.moe=108'),
+    "deepseek_v2/xla_legs/decode": ('ad9c244ea9a2ebe5', 'vs.attn=513 vs.mlp=9 vs.moe=130'),
+    "deepseek_v2/kernels_interpreted/prefill_rung_1": ('980500568f06f1ec', 'vs.attn=1788 vs.mlp=9 vs.moe=108'),
+    "deepseek_v2/kernels_interpreted/prefill_rung_2": ('f0486d77c3d6ccfc', 'vs.attn=1788 vs.mlp=9 vs.moe=108'),
+    "deepseek_v2/kernels_interpreted/decode": ('2f1889f41534b464', 'vs.attn=402 vs.mlp=9 vs.moe=130'),
+    "sdar_moe/xla_legs/prefill_rung_1": ('8e3b299e7630eaef', 'vs.attn=314 vs.moe=62'),
+    "sdar_moe/xla_legs/prefill_rung_2": ('c81097f2d31e289d', 'vs.attn=314 vs.moe=62'),
+    "sdar_moe/xla_legs/decode": ('b85e2370cf46c46e', 'vs.attn=414 vs.moe=62 vs.unmask=78'),
+    "sdar_moe/kernels_interpreted/prefill_rung_1": ('e56a516954aba883', 'vs.attn=1332 vs.moe=62'),
+    "sdar_moe/kernels_interpreted/prefill_rung_2": ('bc73527e136986e5', 'vs.attn=1332 vs.moe=62'),
+    "sdar_moe/kernels_interpreted/decode": ('56e940485455a866', 'vs.attn=340 vs.moe=62 vs.unmask=67'),
+    "falcon_h1/xla_legs/prefill_rung_1": ('90cf402ea227c42d', 'vs.attn=216 vs.mamba=384 vs.mlp=56'),
+    "falcon_h1/xla_legs/prefill_rung_2": ('b743fe3d0586fe9e', 'vs.attn=216 vs.mamba=384 vs.mlp=56'),
+    "falcon_h1/xla_legs/decode": ('c1aa2e3ced5cfdfc', 'vs.attn=302 vs.mamba=200 vs.mlp=56'),
+    "falcon_h1/kernels_interpreted/prefill_rung_1": ('217404c2ee985216', 'vs.attn=1230 vs.mamba=384 vs.mlp=56'),
+    "falcon_h1/kernels_interpreted/prefill_rung_2": ('db13332e9b901285', 'vs.attn=1230 vs.mamba=384 vs.mlp=56'),
+    "falcon_h1/kernels_interpreted/decode": ('c6b57d1d7536a78a', 'vs.attn=228 vs.mamba=160 vs.mlp=56'),
+    "laguna/xla_legs/prefill_rung_1": ('c4e9235e1f7934a7', 'vs.attn=651 vs.mlp=9 vs.moe=108'),
+    "laguna/xla_legs/prefill_rung_2": ('80a62b15d7e50d61', 'vs.attn=651 vs.mlp=9 vs.moe=108'),
+    "laguna/xla_legs/decode": ('370af31f6198adea', 'vs.attn=810 vs.mlp=9 vs.moe=108'),
+    "laguna/kernels_interpreted/prefill_rung_1": ('542d10a1a7587958', 'vs.attn=3174 vs.mlp=9 vs.moe=108'),
+    "laguna/kernels_interpreted/prefill_rung_2": ('386a8d3468798b00', 'vs.attn=3174 vs.mlp=9 vs.moe=108'),
+    "laguna/kernels_interpreted/decode": ('6b12595f0b880b74', 'vs.attn=625 vs.mlp=9 vs.moe=108'),
+}
+
+
+def _engine(family: str, leg: str, patch):
+    """The family's toy engine as its test file builds it, in bfloat16, over abstract parameters; not warmed."""
+    toy = FAMILIES[family]
+    cfg = dataclasses.replace(toy.toy_config(), dtype=jnp.bfloat16)
+    if LEGS[leg] is None:
+        patch.delenv("VESCALE_KERNELS", raising=False)
+    else:
+        # ... and, as the families' own fixtures do, both of the expert layer's limits at 0 while the programs are traced:
+        # every program then holds the sorted form on the leg a TPU takes, the grouped SwiGLU kernel
+        patch.setenv("VESCALE_KERNELS", LEGS[leg])
+        patch.setattr(dropless, "DENSE_MAX_TOKENS", 0)
+        patch.setattr(dropless, "PADDED_MAX_MEAN_ROWS", 0)
+    mesh = DeviceMesh(("tp",), (1,), devices=jax.devices()[:1])
+    cache = PagedKVCache(hybrid_cache_config(cfg, num_slots=toy.SLOTS, page_size=toy.PAGE, pages_per_slot=toy.PAGES,
+                                             num_pages=getattr(toy, "POOL", None)), mesh)
+    engine = HybridServeEngine(cfg, mesh, None, cache)
+    params = jax.eval_shape(lambda key: engine.model.init_params(cfg, key), jax.random.key(7))
+    return engine, params
+
+
+def _program(engine, params, which: str):
+    """One of the engine's jitted programs and the shapes it is called with."""
+    cache = engine.cache
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.int32)
+    held = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in engine._held())
+    if which == "decode":
+        S = cache.num_slots
+        return engine._decode_fn, (params, *held, shape(S, cache.config.pages_per_slot), shape(S), shape(S))
+    bucket = engine.buckets[WHICH.index(which)]
+    return engine._prefill_fn, (params, *held, shape(bucket), shape(), shape(bucket // cache.config.page_size), shape())
+
+
+def _jaxpr_digest(fn, args) -> str:
+    text = re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(fn)(*args)))      # (a closure prints its address)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def _scopes(fn, args) -> str:
+    """``"vs.attn=<operations under it> ..."`` of the lowered module: each operation's location resolved to its
+    name stack (the locations' table follows the module), the ``vs.*`` scopes on it counted."""
+    text = fn.lower(*args).as_text(debug_info=True)
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, flags=re.M))
+    counts = collections.Counter(scope for ref in re.findall(r"loc\((#loc\d+)\)$", text, flags=re.M)
+                                 for scope in set(re.findall(r"vs\.[a-z_]+", names.get(ref, ""))))
+    return " ".join(f"{scope}={n}" for scope, n in sorted(counts.items()))
+
+
+def _taken(family: str, leg: str, which: str):
+    with pytest.MonkeyPatch.context() as patch:
+        engine, params = _engine(family, leg, patch)
+        fn, args = _program(engine, params, which)
+        return _jaxpr_digest(fn, args), _scopes(fn, args)
+
+
+@pytest.mark.parametrize("which", WHICH)
+@pytest.mark.parametrize("leg", list(LEGS))
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_a_serve_program_traces_to_the_text_and_the_scopes_it_had(family, leg, which):
+    digest, scopes = _taken(family, leg, which)
+    assert (digest, scopes) == PROGRAMS[f"{family}/{leg}/{which}"]
+
+
+if __name__ == "__main__":
+    for family in FAMILIES:
+        for leg in LEGS:
+            for which in WHICH:
+                print(f'    "{family}/{leg}/{which}": {_taken(family, leg, which)!r},', flush=True)

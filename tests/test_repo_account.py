@@ -106,3 +106,39 @@ def test_every_registered_knob_is_read_by_some_code():
     assert names, "the registry lost its names?"
     unread = [n for n in names if n not in blob]
     assert not unread, f"registered in envreg.py and read by no code: {unread}"
+
+
+def _imports(path):
+    """Every module a file imports, wherever in it (``import`` nodes inside functions too), as dotted names
+    from the repo's root: a relative import is resolved against the file's package."""
+    import ast
+
+    package = os.path.dirname(path).replace("/", ".").split(".")
+    with open(os.path.join(REPO, path)) as f:
+        tree = ast.parse(f.read())
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            yield from (alias.name for alias in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            base = package[: len(package) - node.level + 1] if node.level else []
+            module = ".".join(base + ([node.module] if node.module else []))
+            yield module
+            yield from (f"{module}.{alias.name}" for alias in node.names)      # ``from . import sibling``
+
+
+def test_a_model_family_imports_no_other_family_and_no_kernel_imports_a_model():
+    """The arrows point one way: ``serve/hybrid_engine`` -> a family's module (one that defines the seam's
+    ``serve_decode``) -> ``models/blocks.py``, ``models/mamba2.py``, ``kernels/``, ``moe/``."""
+    models = {p[len("vescale_tpu/models/"):-3]: p for p in FILES if fnmatch.fnmatch(p, "vescale_tpu/models/*.py")}
+    families = {}
+    for name, path in models.items():
+        with open(os.path.join(REPO, path)) as f:
+            if re.search(r"^def serve_decode\(", f.read(), flags=re.M):
+                families[name] = path
+    assert len(families) >= 5, f"the families behind HybridServeEngine's seam: {sorted(families)}"
+    crossed = sorted({(name, other) for name, path in families.items() for module in _imports(path)
+                      for other in families if other != name and module.startswith(f"vescale_tpu.models.{other}")})
+    assert not crossed, f"a family's module imports another family's: {crossed}"
+    upward = sorted({(p, module) for p in FILES if fnmatch.fnmatch(p, "vescale_tpu/kernels/*.py")
+                     for module in _imports(p) if module.startswith("vescale_tpu.models")})
+    assert not upward, f"a kernel imports a model: {upward}"

@@ -154,13 +154,19 @@ def test_paged_decode_compiles(chip, S, Pmax, page, H, KV, hd, dtype, L):
 # --------------------------------------------------------------- ssm step
 # (layers, slots, state, heads x head width): granite-4.0-h-small's nine Mamba-2 layers at 64 slots (the
 # benchmark's cell), and a narrower state whose lanes are one block
+def _ssm_step_in_place(ssm_step):
+    """The op's kernel leg in a program that donates the state, as a decode program does."""
+    return jax.jit(lambda state, decay, dtx, B, C, layer: ssm_step(state, decay, dtx, B, C, layer=layer, interpret=False),
+                   donate_argnums=0)
+
+
 @pytest.mark.parametrize("L,S,N,J", [(9, 64, 128, 8192), (2, 8, 64, 1024)], ids=["granite-64-slots", "narrow"])
 def test_ssm_step_compiles_in_place(chip, L, S, N, J):
     from vescale_tpu.kernels.ssm_step import ssm_step
 
     sds = lambda shape, dt=f32: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
-    compiled = ssm_step.lower(sds((L, S, N, J)), sds((S, J)), sds((S, J)), sds((S, N)), sds((S, N)),
-                              layer=sds((1,), jnp.int32), interpret=False).compile()
+    compiled = _ssm_step_in_place(ssm_step).lower(sds((L, S, N, J)), sds((S, J)), sds((S, J)), sds((S, N)), sds((S, N)),
+                                                  sds((1,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == L * S * N * J * 4 and memory.temp_size_in_bytes < 1 << 20   # no copy of the state
@@ -173,8 +179,8 @@ def test_ssm_step_compiles_in_place_with_two_groups_and_a_state_of_256(chip):
     L, S, N, J, G = 6, 128, 256, 4096, 2
     assert supports(f32, N, J, interpret=False, groups=G)
     sds = lambda shape, dt=f32: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
-    compiled = ssm_step.lower(sds((L, S, N, J)), sds((S, J)), sds((S, J)), sds((S, G, N)), sds((S, G, N)),
-                              layer=sds((1,), jnp.int32), interpret=False).compile()
+    compiled = _ssm_step_in_place(ssm_step).lower(sds((L, S, N, J)), sds((S, J)), sds((S, J)), sds((S, G, N)),
+                                                  sds((S, G, N)), sds((1,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == L * S * N * J * 4 and memory.temp_size_in_bytes < 1 << 20   # no copy of the state
@@ -213,8 +219,9 @@ def test_head_select_compiles_at_sdars_cell_size_and_leaves_three_vectors(chip):
 
     sds = lambda shape, dt=bf16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
     assert supports(f32, 256, 2048, interpret=False) and supports(bf16, 512, 2048, interpret=False)
-    head_select.lower(sds((256, 2048), f32), sds((2048, 151936), f32), interpret=False).compile()
-    compiled = head_select.lower(sds((512, 2048)), sds((2048, 151936)), interpret=False).compile()
+    kernel_leg = jax.jit(lambda x, w: head_select(x, w, interpret=False))
+    kernel_leg.lower(sds((256, 2048), f32), sds((2048, 151936), f32)).compile()
+    compiled = kernel_leg.lower(sds((512, 2048)), sds((2048, 151936))).compile()
     assert '"scoped_memory_configs":[{' not in compiled.as_text()
     assert "tpu_custom_call" in compiled.as_text() and "151936]" in compiled.as_text()
     memory = compiled.memory_analysis()

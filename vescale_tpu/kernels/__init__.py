@@ -30,17 +30,18 @@ Kernels in this package:
     fetches only the pages each slot holds (per-page DMAs through the
     scalar-prefetched page table, double-buffered blocks of pages) and
     runs an online fp32 softmax over them — instead of the slice →
-    gather → masked-softmax → matmul chain over all ``Tmax`` positions;
-    dispatched by ``serve/engine.py``, the default decode path on TPU.
+    gather → masked-softmax → matmul chain over all ``Tmax`` positions
+    (its XLA leg); for ``serve/engine.py`` and every model of pages under
+    ``serve/hybrid_engine.py``, the default decode path on TPU.
   * ``paged_decode_latent`` — its sibling over a LATENT pool (multi-head
     latent attention's absorbed decode): one row a position serves every
     head's score and, by its leading columns, the values, so a page is
-    fetched once for both; dispatched by ``models/deepseek_v2.py`` under
+    fetched once for both; for ``models/deepseek_v2.py`` under
     ``serve/hybrid_engine.py``, the default on TPU.
   * ``ssm_step``         — a state-space (Mamba-2) layer's decode step
     over every slot's recurrent state, in place: one read and one write of
-    the state where XLA reads it twice; dispatched by
-    ``serve/hybrid_engine.py``, the default on TPU.
+    the state where XLA reads it twice (its XLA leg); for
+    ``models/mamba2.py`` under ``serve/hybrid_engine.py``, the default on TPU.
   * ``grouped_experts``  — the sorted form of a dropless expert layer
     (``kernels/grouped_swiglu.py``): one grid over row tiles of the (token,
     expert) pairs in expert order; a tile's expert, a scalar-prefetch operand,
@@ -52,7 +53,7 @@ Kernels in this package:
     diffusion pass selects by (``kernels/head_select.py``): a row's largest
     logit, its id and the softmax denominator, reduced tile by tile of the
     vocabulary as the weights stream past once, so that no logits are
-    written; dispatched by ``models/sdar_moe.py`` under
+    written (its XLA leg writes them); for ``models/sdar_moe.py`` under
     ``serve/hybrid_engine.py``, the default on TPU.
   * ``fused_adamw``      — the adamw_lowmem moment/update elementwise
     chain as one kernel over (g, m, v); dispatched by
@@ -64,7 +65,8 @@ Kernels in this package:
 Contract points:
 
   * Dispatch decisions are HOST-side and live-read: each call site asks
-    :func:`resolve` (or :func:`mode` + the counters) at trace/build time.
+    :func:`resolve` (or :func:`mode` + the counters) at trace/build time
+    (a decode kernel's own ``leg(...)``; its op runs its XLA leg on None).
     A jitted program therefore latches the mode at compile time — flip the
     knob, rebuild/retrace, and the other path compiles.  The serve engine
     documents the same latch (mode read at ``ServeEngine`` build).
@@ -93,6 +95,7 @@ __all__ = [
     "resolve",
     "record_dispatch",
     "record_fallback",
+    "with_xla_leg",
     "on_tpu",
     "ulps_at_scale",
 ]
@@ -149,6 +152,27 @@ def resolve(name: str, *, supported: Callable[[bool], bool] = lambda interpret: 
         return None
     record_dispatch(name)
     return interpret
+
+
+def with_xla_leg(xla: Callable, **jit_kwargs):
+    """A decorator for an op's kernel leg ``fn(*args, interpret=<bool>, **kw)``:
+    the op it makes also takes ``interpret=None`` and then runs ``xla(*args,
+    **kw)``, its XLA leg, chosen in plain Python ABOVE the kernel's
+    ``jax.jit(fn, **jit_kwargs)`` (a program's calls, one a layer, are traced
+    and lowered once, under the op's name)."""
+    import functools
+    import jax
+
+    def op(fn):
+        kernel = jax.jit(fn, **jit_kwargs)
+
+        @functools.wraps(fn)
+        def either_leg(*args, interpret: Optional[bool], **kw):
+            return xla(*args, **kw) if interpret is None else kernel(*args, interpret=interpret, **kw)
+
+        return either_leg
+
+    return op
 
 
 def record_dispatch(name: str) -> None:

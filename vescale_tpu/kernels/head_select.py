@@ -43,7 +43,10 @@ or a ``+inf``'s before it, the denominator NaN, and ``top`` reads ``+inf`` where
 ``jnp.max`` reads NaN); the online denominator differs from the two-pass one in
 its last bits (20 steps of float32 at the sum's scale over 151,936 columns on
 the chip, as two orders of summation differ).  Interpreted parity with the XLA
-leg is asserted in tests/test_head_select.py.
+leg is asserted in tests/test_head_select.py.  :func:`head_select` takes the
+kernel's ``interpret`` flag or None
+for the XLA leg (the logits ``(R, V)`` are made, and :func:`logit_stats` reads
+them three times), and :func:`leg` resolves that for the rows' shape.
 """
 
 from __future__ import annotations
@@ -56,7 +59,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["head_select", "supports"]
+from .. import kernels
+
+__all__ = ["head_select", "logit_stats", "supports", "leg"]
 
 _WEIGHT_TILE_BYTES = 4 << 20        # of the head a grid step, held twice (the next tile arrives under this one's products)
 _CHUNK = 512                        # columns a product inside a step: a (512, 512) float32 block of scores is 1 MB; whole lanes
@@ -84,6 +89,20 @@ def supports(dtype, rows: int, d: int, *, interpret: bool) -> bool:
     whose rows, as ONE block, fit the VMEM a kernel has by default."""
     return interpret or (jnp.dtype(dtype) in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)) and d % 128 == 0
                          and _vmem_bytes(rows, d, dtype) <= _VMEM_BYTES)
+
+
+def leg(dtype, rows: int, d: int) -> Optional[bool]:
+    """The leg :func:`head_select` takes for such rows: the kernel's ``interpret`` flag, or None for the XLA leg."""
+    return kernels.resolve("head_select", supported=lambda interpret: supports(dtype, rows, d, interpret=interpret))
+
+
+def logit_stats(logits):
+    """Of rows of logits ``(N, vocab)``, what a selection asks of each: ``top``
+    (the largest logit), ``best`` (its id, int32: a tie goes to the lowest id)
+    and ``denominator = sum(exp(logit - top))`` over the whole vocabulary, the
+    softmax's: the probability of ``best`` is its inverse."""
+    top = jnp.max(logits, axis=-1)
+    return top, jnp.argmax(logits, axis=-1).astype(jnp.int32), jnp.sum(jnp.exp(logits - top[:, None]), axis=-1)
 
 
 def _kernel(x_ref, w_ref, top_ref, best_ref, den_ref, peak_ref, sum_ref, at_ref, *, vocab: int, tile: int, chunk: int):
@@ -133,12 +152,15 @@ def _kernel(x_ref, w_ref, top_ref, best_ref, den_ref, peak_ref, sum_ref, at_ref,
         best_ref[...] = jnp.min(jnp.where(peak == top, at, jnp.iinfo(jnp.int32).max), axis=-1, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "tile", "chunk"))
-def head_select(x, w, *, interpret: bool, tile: Optional[int] = None, chunk: int = _CHUNK):
-    """``(top, best, denominator)`` of the rows of ``x @ w``, each ``(R,)``:
-    float32, int32, float32.  ``x`` (R, d) and ``w`` (d, V) of one type (the
-    product's operands); ``tile`` columns of ``w`` a grid step (by default what
-    ``_WEIGHT_TILE_BYTES`` hold), ``chunk`` (a divisor of it) a product."""
+@kernels.with_xla_leg(lambda x, w, **_tiles: logit_stats(jnp.dot(x, w, preferred_element_type=jnp.float32)),
+                      static_argnames=("interpret", "tile", "chunk"))
+def head_select(x, w, *, interpret, tile: Optional[int] = None, chunk: int = _CHUNK):
+    """``(top, best, denominator)`` of the rows of ``x @ w`` (:func:`logit_stats`
+    of them), each ``(R,)``: float32, int32, float32.  ``x`` (R, d) and ``w``
+    (d, V) of one type (the product's operands, float32 accumulation);
+    ``interpret`` the kernel's flag, or None for the XLA leg (what :func:`leg`
+    resolved); of the kernel, ``tile`` columns of ``w`` a grid step (by default
+    what ``_WEIGHT_TILE_BYTES`` hold), ``chunk`` (a divisor of it) a product."""
     R, d = x.shape
     V = w.shape[1]
     tile = min(tile or _tile(d, x.dtype), -(-V // _LANES) * _LANES)

@@ -1,10 +1,10 @@
 """Paged-attention decode kernel — a slot's live pages, straight from the pool.
 
-The XLA decode leg (``serve/engine.py``) moves the WHOLE page pool every
-step, whatever it holds: per layer it slices the layer out of the 5-D
+The XLA decode leg (:func:`paged_attention_xla`) moves the WHOLE page pool
+every step, whatever it holds: per layer it slices the layer out of the 5-D
 pool, gathers every slot's ``Pmax`` pages into a dense ``(S, Tmax, KV,
 hd)`` view, upcasts it and runs a masked softmax over all ``Tmax``
-positions.  This kernel does work in proportion to the tokens the cache
+positions.  The kernel does work in proportion to the tokens the cache
 holds:
 
   * **operands** — the whole ``(L, N, page, KV, hd)`` K and V pools stay
@@ -42,18 +42,25 @@ docs/kernels.md.  V rows past a slot's length are zeroed and their scores
 masked, so stale bytes (a NaN in a page's tail, or VMEM a skipped DMA
 never wrote) reach nothing.  A slot of length 0 fetches nothing and its
 output is zeros.
+
+Each op takes the kernel's ``interpret`` flag or None for its XLA leg, and :func:`leg` /
+:func:`leg_latent` resolve that for a pool: a caller names its pool and chooses nothing.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["paged_decode", "supports", "paged_decode_latent", "supports_latent"]
+from .. import kernels
+
+__all__ = ["paged_decode", "paged_attention_xla", "supports", "leg", "paged_decode_latent", "latent_attention_xla",
+           "supports_latent", "leg_latent"]
 
 _NEG_INF = -1e30
 _BLOCK_BYTES = 1 << 20       # of K in one block (V the same; two buffers each)
@@ -78,6 +85,25 @@ def supports(pool_dtype, kv_heads: int, head_dim: int, *, interpret: bool) -> bo
     if not (dt.itemsize == 4 or (dt == jnp.bfloat16 and kv_heads % 2 == 0)):
         return False
     return interpret or head_dim % 128 == 0
+
+
+def leg(pool_dtype, kv_heads: int, head_dim: int) -> Optional[bool]:
+    """The leg :func:`paged_decode` takes over such a pool: the kernel's ``interpret`` flag, or None for the XLA leg."""
+    return kernels.resolve("paged_decode",
+                           supported=lambda interpret: supports(pool_dtype, kv_heads, head_dim, interpret=interpret))
+
+
+def paged_attention_xla(q, k_pool, v_pool, table, valid_len, *, layer: int, scale: float):
+    """:func:`paged_decode` without the kernel: gather every slot's pages, mask by length, float32 softmax."""
+    S, H, hd = q.shape
+    KV = k_pool.shape[3]
+    ks = jnp.take(k_pool[layer], table, axis=0).reshape(S, -1, KV, hd)
+    vs = jnp.take(v_pool[layer], table, axis=0).reshape(S, -1, KV, hd)
+    qg = (q.astype(jnp.float32) * scale).reshape(S, KV, H // KV, hd)
+    s = jnp.einsum("skgd,stkd->skgt", qg, ks.astype(jnp.float32))
+    mask = jnp.arange(ks.shape[1], dtype=jnp.int32)[None, :] < valid_len[:, None]
+    p = jax.nn.softmax(jnp.where(mask[:, None, None, :], s, -1e30), axis=-1)
+    return jnp.einsum("skgt,stkd->skgd", p, vs.astype(jnp.float32)).reshape(S, H, hd)
 
 
 def _decode_kernel(layer_ref, len_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
@@ -183,22 +209,21 @@ def _decode_kernel(layer_ref, len_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
-# jitted so that the calls of a decode program, one a layer with the same
-# shapes, are traced and lowered once (set-up pays for each lowering)
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@kernels.with_xla_leg(paged_attention_xla, static_argnames=("scale", "interpret"))
 def paged_decode(q, k_pool, v_pool, table, lengths, *, layer, scale, interpret):
     """One decode-step attention of one layer over the paged KV pool.
 
     ``q``: (S, H, hd) new-token queries; ``k_pool``/``v_pool``:
-    (L, N, page, KV, hd), the WHOLE pool, every layer (left in HBM; only
-    ``layer``'s live pages are read); ``layer``: int or int32 scalar;
-    ``table``: (S, Pmax) int32 physical page ids per slot (0 = the reserved
-    null page); ``lengths``: (S,) int32 valid positions per slot (the new
-    token included).  Returns fp32 (S, H, hd) attention output — callers
-    reshape and cast (the XLA reference's ``.astype(dtype)`` boundary).
+    (L, N, page, KV, hd), the WHOLE pool, every layer (the kernel leaves it
+    in HBM and reads only ``layer``'s live pages); ``layer``: int or int32
+    scalar; ``table``: (S, Pmax) int32 physical page ids per slot (0 = the
+    reserved null page); ``lengths``: (S,) int32 valid positions per slot (the
+    new token included); ``interpret``: the kernel's flag, or None for the XLA
+    leg (what :func:`leg` resolved, latched by the caller's program).  Returns
+    fp32 (S, H, hd) attention output — callers reshape and cast.
 
-    Implementation-only: the caller (serve/engine.py) owns the dispatch
-    decision and any shard_map wrapping for a kv-head-sharded pool.
+    The caller (serve/engine.py) owns any shard_map wrapping of the kernel
+    for a kv-head-sharded pool.
     """
     S, H, hd = q.shape
     L, N, page, KV, hd2 = k_pool.shape
@@ -259,6 +284,27 @@ def supports_latent(pool_dtype, row: int, latent: int, page: int, *, interpret: 
     if dt not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)) or not 0 < latent <= row:
         return False
     return interpret or (row % 128 == 0 and latent % 128 == 0 and page % (32 // dt.itemsize) == 0)
+
+
+def leg_latent(pool_dtype, row: int, latent: int, page: int) -> Optional[bool]:
+    """The leg :func:`paged_decode_latent` takes over such a pool: the kernel's ``interpret`` flag, or None."""
+    return kernels.resolve("paged_decode_latent",
+                           supported=lambda interpret: supports_latent(pool_dtype, row, latent, page, interpret=interpret))
+
+
+def latent_attention_xla(q, pool, table, valid_len, *, layer: int, scale: float, latent: int):
+    """Decode attention of one layer in the absorbed form without the kernel:
+    gather every slot's pages, mask by length, float32 softmax.  ``q`` (S, H,
+    row) in the pool's type, ``pool`` (L, N, page, 1, row); the values are the
+    rows' first ``latent`` columns.  Returns (S, H, latent) float32."""
+    S, H, row = q.shape
+    rows = jnp.take(pool[layer], table, axis=0).reshape(S, -1, row)                      # (S, Tmax, row)
+    mask = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] < valid_len[:, None]
+    rows = jnp.where(mask[:, :, None], rows, jnp.zeros_like(rows))     # stale bytes past the length reach nothing
+    # operands widened exactly (the kernel multiplies them as they are, with float32 accumulation: the same numbers)
+    s = scale * jnp.einsum("shr,str->sht", q.astype(jnp.float32), rows.astype(jnp.float32))
+    p = jax.nn.softmax(jnp.where(mask[:, None, :], s, -1e30), axis=-1)
+    return jnp.einsum("sht,stc->shc", p.astype(rows.dtype).astype(jnp.float32), rows[..., :latent].astype(jnp.float32))
 
 
 def _latent_kernel(layer_ref, len_ref, table_ref, q_ref, pool_hbm, o_ref,
@@ -328,17 +374,18 @@ def _latent_kernel(layer_ref, len_ref, table_ref, q_ref, pool_hbm, o_ref,
     o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "latent", "interpret"))
+@kernels.with_xla_leg(latent_attention_xla, static_argnames=("scale", "latent", "interpret"))
 def paged_decode_latent(q, pool, table, lengths, *, layer, scale, latent, interpret):
     """One decode-step attention of one layer over a LATENT paged pool.
 
     ``q``: (S, H, row) absorbed queries in the pool's type (the products run
-    on operands of that type with float32 accumulation, as the XLA leg's do);
-    ``pool``: (L, N, page, 1, row), every layer, left in HBM; ``table``,
-    ``lengths``, ``layer`` as :func:`paged_decode` takes them.  Returns
-    float32 (S, H, latent): ``sum_t softmax_t(scale q . row_t) row_t[:latent]``
-    over the slot's first ``lengths`` positions (zeros for a length of 0).
-    Only the slot's live pages are read, once, for scores and values both."""
+    on operands of that type with float32 accumulation, on both legs);
+    ``pool``: (L, N, page, 1, row), every layer; ``table``, ``lengths``,
+    ``layer``, ``interpret`` as :func:`paged_decode` takes them (None: the
+    XLA leg; :func:`leg_latent` resolves it).  Returns float32 (S, H, latent):
+    ``sum_t softmax_t(scale q . row_t) row_t[:latent]`` over the slot's first
+    ``lengths`` positions (zeros for a length of 0).  The kernel reads only the
+    slot's live pages, once, for scores and values both."""
     S, H, row = q.shape
     L, N, page, one, row2 = pool.shape
     if one != 1 or row != row2 or q.dtype != pool.dtype:

@@ -35,21 +35,24 @@ place and reduces it to ``y`` while it is in VMEM: one read, one write.
     128, 1024 at N = 256; in and out double-buffered, 4 MiB of VMEM).
 
 Numerics: float32 throughout, the same operations in the same order as the
-XLA leg (``models/granite_hybrid.py``), except the order of the sum over
-``N``; interpreted parity is asserted in tests/test_granite_hybrid.py (1e-6 of the
-tensor's scale).
+XLA leg (:func:`ssm_advance_xla`), except the order of the sum over ``N``;
+interpreted parity is asserted in tests/test_granite_hybrid.py (1e-6 of the
+tensor's scale).  :func:`ssm_step` takes the kernel's ``interpret`` flag or
+None for the XLA leg, and :func:`leg` resolves that for a state's shape.
 """
 
 from __future__ import annotations
 
-import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["ssm_step", "supports"]
+from .. import kernels
+
+__all__ = ["ssm_step", "ssm_advance_xla", "supports", "leg"]
 
 _BLOCK_BYTES = 1 << 20   # of the state in one block, float32: in and out double-buffered
 
@@ -73,6 +76,29 @@ def supports(state_dtype, state_dim: int, lanes: int, *, interpret: bool, groups
     return interpret or (state_dim % 8 == 0 and _block(state_dim, lanes // groups) % 128 == 0)
 
 
+def leg(state_dtype, state_dim: int, lanes: int, *, groups: int = 1) -> Optional[bool]:
+    """The leg :func:`ssm_step` takes over such a state: the kernel's ``interpret`` flag, or None for the XLA leg."""
+    return kernels.resolve(
+        "ssm_step", supported=lambda interpret: supports(state_dtype, state_dim, lanes, interpret=interpret, groups=groups))
+
+
+def _over_lanes(a, lanes: int):
+    """``B`` or ``C`` laid against the state's lanes: (S, N) -> (S, N, 1), one
+    column for all; (S, G, N) -> (S, N, J), each group's column over the ``J /
+    G`` lanes of its heads."""
+    if a.ndim == 2:
+        return a[:, :, None]
+    return jnp.repeat(a.transpose(0, 2, 1), lanes // a.shape[1], axis=2)
+
+
+def ssm_advance_xla(ssm, decay, dtx, B, C, *, layer: int):
+    """:func:`ssm_step` without the kernel, which reads and writes the state
+    once; this reads it twice."""
+    J = ssm.shape[-1]
+    h = decay[:, None, :] * ssm[layer].astype(jnp.float32) + _over_lanes(B, J) * dtx[:, None, :]
+    return ssm.at[layer].set(h.astype(ssm.dtype)), jnp.sum(h * _over_lanes(C, J), axis=1)
+
+
 def _step_kernel(layer_ref, decay_ref, dtx_ref, b_ref, c_ref, h_ref, h_out_ref, y_ref):
     del layer_ref                                        # it placed the blocks
     new = decay_ref[0] * h_ref[0, 0] + b_ref[0] * dtx_ref[0]          # (1, T) * (N, T) + (N, 1) * (1, T)
@@ -80,13 +106,16 @@ def _step_kernel(layer_ref, decay_ref, dtx_ref, b_ref, c_ref, h_ref, h_out_ref, 
     y_ref[0] = jnp.sum(new * c_ref[0], axis=0, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",), donate_argnames=("state",))
-def ssm_step(state, decay, dtx, B, C, *, layer, interpret: bool):
-    """One step of one layer for every slot.  ``state`` (layers, S, N, J)
-    float32, updated in place at ``layer`` (an int32 scalar or array of one);
-    ``decay`` and ``dtx`` (S, J): each head's ``exp(dt A)`` repeated over its
-    head width, and ``dt x``; ``B`` and ``C`` (S, N), or (S, G, N) where the
-    lanes lie in ``G`` groups.  Returns the state array and ``y`` (S, J) float32."""
+@kernels.with_xla_leg(ssm_advance_xla, static_argnames=("interpret",), donate_argnames=("state",))
+def ssm_step(state, decay, dtx, B, C, *, layer, interpret):
+    """One step of one layer for every slot: ``h' = decay * h + B (x) dtx`` and
+    ``y = sum_n h' C`` on the ``layer``-th state (an int32 scalar or array of
+    one) of ``state`` (layers, S, N, J), updated in place; ``decay`` and
+    ``dtx`` (S, J): each head's ``exp(dt A)`` repeated over its head width, and
+    ``dt x``; ``B`` and ``C`` (S, N), or (S, G, N) where the lanes lie in ``G``
+    groups; ``interpret`` the kernel's flag (a float32 state: :func:`supports`),
+    or None for the XLA leg (what :func:`leg` resolved).  Returns the state
+    array and ``y`` (S, J) float32."""
     _layers, S, N, J = state.shape
     G = 1 if B.ndim == 2 else B.shape[1]
     if C.shape != B.shape or J % G:

@@ -3,8 +3,9 @@ LATENT attention (MLA) and whose feed-forward, after a leading dense layer, is
 a group-limited routed expert layer beside shared experts.
 
 The block is written ONCE, as pure functions over a plain parameter tree
-(``init_params``), and both serve programs call them, as in
-``models/granite_hybrid.py``; the last section of this file is what
+(``init_params``), and both serve programs call them; what other families
+share (the norm, the product, the SwiGLU, YaRN's frequencies) is
+``models/blocks.py``'s.  The last section of this file is what
 ``serve.HybridServeEngine`` asks of a model's module (the cache's geometry,
 the bodies of its two programs, the counters of a decode step).
 
@@ -54,27 +55,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..moe.dropless import dropless_experts, route_group_limited
+from ..moe.dropless import route_group_limited, routed_experts
+from .blocks import F32, ROUTED_DOWN_GAIN, _mm, rmsnorm, swiglu, yarn_inv_freq, yarn_mscale
 
 __all__ = [
-    "DeepseekV2Config", "init_params", "rmsnorm", "embed", "head", "yarn_inv_freq", "yarn_mscale", "rotary",
-    "mla_prefill", "mla_step", "latent_attention_xla", "dense_mlp", "expert_layer", "layer_prefill", "layer_step",
-    "cache_config", "prefill_chunk", "decode_kernels", "serve_prefill", "serve_decode", "STEP_COUNTERS",
-    "step_counters", "prefill_counters",
+    "DeepseekV2Config", "init_params", "embed", "head", "inv_freq", "rotary", "mla_prefill", "mla_step", "dense_mlp",
+    "expert_layer", "layer_prefill", "layer_step", "cache_config", "prefill_chunk", "decode_kernels", "serve_prefill",
+    "serve_decode", "STEP_COUNTERS", "step_counters", "prefill_counters",
 ]
 
-F32 = jnp.float32
 LANES = 128
 FLASH_NAME = "mla_flash_fwd"        # the prefill attention's kernel, as the device trace names it
-# Random weights of variance 1 / fan-in make every expert's output as large as the residual stream, and the
-# gates (a peaked softmax's probabilities times 16) reach 3.5: a token whose sixth and seventh expert a
-# rounding difference swaps then moves by a tenth of its own size, the next layer's router sees that and
-# swaps more, and two computations of the same model in different precisions part by their whole range
-# (PERF.md, section 6, PR 34: 0.47 to 1.3 of the largest logit on the chip).  In a trained model one
-# expert's marginal contribution is small beside the stream.  So ``init_params`` draws the routed experts'
-# down projections this much narrower: the routed part stays a few per cent of the stream, a swapped
-# expert moves a logit row by 5e-3 of the largest, and a wrong gate scale still shows.
-ROUTED_DOWN_GAIN = 1.0 / 64.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,7 +166,7 @@ def init_params(config: DeepseekV2Config, key) -> Dict[str, Any]:
                 "kv_b_v": normal(ks[4], (H, c.kv_lora_rank, c.v_head_dim), c.kv_lora_rank),
                 "o": normal(ks[5], (H * c.v_head_dim, E), H * c.v_head_dim)}
 
-    def swiglu(k, width):
+    def swiglu_params(k, width):
         ks = jax.random.split(k, 3)
         return {"gate": normal(ks[0], (E, width), E), "up": normal(ks[1], (E, width), E),
                 "down": normal(ks[2], (width, E), width)}
@@ -186,7 +177,7 @@ def init_params(config: DeepseekV2Config, key) -> Dict[str, Any]:
         return {"router": normal(ks[0], (E, c.num_experts), E, F32, gain=2.0),
                 "w_gate": normal(ks[1], (held, E, F), E), "w_up": normal(ks[2], (held, E, F), E),
                 "w_down": normal(ks[3], (held, F, E), F, gain=ROUTED_DOWN_GAIN),
-                "shared": swiglu(ks[4], c.n_shared_experts * F)}
+                "shared": swiglu_params(ks[4], c.n_shared_experts * F)}
 
     params: Dict[str, Any] = {
         "embed_tokens": {"embedding": normal(jax.random.fold_in(key, 1 << 20), (c.vocab_size, E), E)},
@@ -199,22 +190,12 @@ def init_params(config: DeepseekV2Config, key) -> Dict[str, Any]:
             "input_layernorm": {"weight": jnp.ones((E,), dt)},
             "post_attention_layernorm": {"weight": jnp.ones((E,), dt)},
             "self_attn": attention(k_attn),
-            "mlp": moe(k_mlp) if l >= c.first_k_dense_replace else swiglu(k_mlp, c.intermediate_size),
+            "mlp": moe(k_mlp) if l >= c.first_k_dense_replace else swiglu_params(k_mlp, c.intermediate_size),
         }
     return params
 
 
-# ------------------------------------------------------------- shared pieces
-def rmsnorm(x, w, eps):
-    x = x.astype(F32)
-    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * w.astype(F32)
-
-
-def _mm(x, w, dtype):
-    """``x @ w`` with operands in ``dtype`` and a float32 result."""
-    return jnp.dot(x.astype(dtype), w.astype(dtype), preferred_element_type=F32)
-
-
+# ------------------------------------------------------------ embedding, head
 def embed(config: DeepseekV2Config, params, tokens):
     return jnp.take(params["embed_tokens"]["embedding"], tokens, axis=0).astype(F32)
 
@@ -224,32 +205,12 @@ def head(config: DeepseekV2Config, params, x):
     return _mm(rmsnorm(x, params["norm"]["weight"], config.rms_norm_eps), params["lm_head"]["kernel"], config.dtype)
 
 
-def _swiglu(p, h, dtype):
-    return _mm(jax.nn.silu(_mm(h, p["gate"], dtype)) * _mm(h, p["up"], dtype), p["down"], dtype)
-
-
 # --------------------------------------------------------------------- rotary
-def yarn_mscale(factor: float, mscale: float) -> float:
-    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
-
-
-def yarn_inv_freq(config: DeepseekV2Config) -> np.ndarray:
-    """The rotary frequencies (dim / 2,) under YaRN: a pair that turns more
-    than ``beta_fast`` times over the original length keeps its frequency, one
-    that turns fewer than ``beta_slow`` times has it divided by ``factor``, a
-    linear ramp between."""
+def inv_freq(config: DeepseekV2Config) -> np.ndarray:
+    """The rotary frequencies (qk_rope_head_dim / 2,): YaRN's, on this config's numbers."""
     c = config
-    dim, base = c.qk_rope_head_dim, c.rope_theta
-    plain = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
-
-    def correction_dim(rotations):
-        return dim * math.log(c.rope_original_max_position_embeddings / (rotations * 2 * math.pi)) / (2 * math.log(base))
-
-    low = max(math.floor(correction_dim(c.rope_beta_fast)), 0)
-    high = min(math.ceil(correction_dim(c.rope_beta_slow)), dim - 1)
-    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / ((high if high != low else high + 0.001) - low), 0, 1)
-    keep = 1.0 - ramp
-    return (plain / c.rope_factor * (1.0 - keep) + plain * keep).astype(np.float32)
+    return yarn_inv_freq(c.qk_rope_head_dim, c.rope_theta, c.rope_factor, c.rope_original_max_position_embeddings,
+                         c.rope_beta_fast, c.rope_beta_slow)
 
 
 def rotary(config: DeepseekV2Config, x, positions):
@@ -266,7 +227,7 @@ def rotary(config: DeepseekV2Config, x, positions):
     mscale_all_dim`` is applied as it stands."""
     c = config
     dim = x.shape[-1]
-    angle = positions.astype(F32)[..., None] * jnp.asarray(np.repeat(yarn_inv_freq(c), 2))      # (..., dim)
+    angle = positions.astype(F32)[..., None] * jnp.asarray(np.repeat(inv_freq(c), 2))      # (..., dim)
     m = yarn_mscale(c.rope_factor, c.rope_mscale) / yarn_mscale(c.rope_factor, c.rope_mscale_all_dim)
     turn = np.zeros((dim, dim), np.float32)
     turn[np.arange(1, dim, 2), np.arange(0, dim, 2)] = -1.0
@@ -323,27 +284,16 @@ def mla_prefill(c: DeepseekV2Config, ap, u, *, interpret: Optional[bool] = None)
                       preferred_element_type=F32), rows
 
 
-def latent_attention_xla(q, pool, table, valid_len, *, layer: int, scale: float, latent: int):
-    """Decode attention of one layer in the absorbed form without the kernel:
-    gather every slot's pages, mask by length, float32 softmax.  ``q`` (S, H,
-    row) in the pool's type, ``pool`` (L, N, page, 1, row); the values are the
-    rows' first ``latent`` columns.  Returns (S, H, latent) float32."""
-    S, H, row = q.shape
-    rows = jnp.take(pool[layer], table, axis=0).reshape(S, -1, row)                      # (S, Tmax, row)
-    mask = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] < valid_len[:, None]
-    rows = jnp.where(mask[:, :, None], rows, jnp.zeros_like(rows))     # stale bytes past the length reach nothing
-    # operands widened exactly (the kernel multiplies them as they are, with float32 accumulation: the same numbers)
-    s = scale * jnp.einsum("shr,str->sht", q.astype(F32), rows.astype(F32))
-    p = jax.nn.softmax(jnp.where(mask[:, None, :], s, -1e30), axis=-1)
-    return jnp.einsum("sht,stc->shc", p.astype(rows.dtype).astype(F32), rows[..., :latent].astype(F32))
-
-
-def mla_step(c: DeepseekV2Config, ap, u, pool, *, layer: int, table, page, offset, positions, valid_len, attend):
+def mla_step(c: DeepseekV2Config, ap, u, pool, *, layer: int, table, page, offset, positions, valid_len,
+             interpret: Optional[bool]):
     """The ABSORBED form, one new position a slot: ``u`` (S, E); the
     position's row goes to ``(page, offset)`` of the pool's ``layer`` (the
-    null page for a slot that may not write), then ``attend(q', pool, table,
-    valid_len, layer=, scale=, latent=)`` reads the slot's pages with the
-    576-wide absorbed queries.  Returns the output (S, E) and the pool."""
+    null page for a slot that may not write), then
+    ``kernels.paged_decode_latent`` reads the slot's pages with the 576-wide
+    absorbed queries (``interpret``: the kernel's flag, or None for its XLA
+    leg).  Returns the output (S, E) and the pool."""
+    from ..kernels.paged_attention import paged_decode_latent
+
     S, H = u.shape[0], c.num_attention_heads
     q_nope, q_pe = _queries(c, ap, u, positions)
     pool = pool.at[layer, page, offset, 0].set(_latent_rows(c, ap, u, positions).astype(pool.dtype))
@@ -352,7 +302,8 @@ def mla_step(c: DeepseekV2Config, ap, u, pool, *, layer: int, table, page, offse
                        preferred_element_type=F32).transpose(1, 0, 2)
     pad = jnp.zeros((S, H, c.cache_row - c.latent_row), pool.dtype)
     q = jnp.concatenate([q_abs.astype(pool.dtype), q_pe.astype(pool.dtype), pad], axis=-1)
-    mixed = attend(q, pool, table, valid_len, layer=layer, scale=c.softmax_scale, latent=c.kv_lora_rank)
+    mixed = paged_decode_latent(q, pool, table, valid_len, layer=layer, scale=c.softmax_scale, latent=c.kv_lora_rank,
+                                interpret=interpret)
     y = jnp.einsum("hsc,hcd->hsd", mixed.astype(c.dtype).transpose(1, 0, 2), ap["kv_b_v"].astype(c.dtype),
                    preferred_element_type=F32).transpose(1, 0, 2)
     return _mm(y.reshape(S, H * c.v_head_dim), ap["o"], c.dtype), pool
@@ -360,22 +311,22 @@ def mla_step(c: DeepseekV2Config, ap, u, pool, *, layer: int, table, page, offse
 
 # ---------------------------------------------------------------- feed-forward
 def dense_mlp(c: DeepseekV2Config, mp, h):
-    return _swiglu(mp, h, c.dtype)
+    """The SwiGLU over a tree of ``gate`` / ``up`` / ``down``: a dense layer's MLP, the shared experts."""
+    return swiglu(h, mp["gate"], mp["up"], mp["down"], c.dtype)
 
 
 def expert_layer(c: DeepseekV2Config, ep, h, token_mask=None):
     """``sum over kept and held e of g_e E_e(h) + S(h)`` for tokens ``h`` (N,
     E).  Returns the sum (N, E) float32, how many tokens each held expert got
     (held,), and how many of the tokens' kept groups lie on this chip (a scalar)."""
-    scores = jnp.dot(h.astype(F32), ep["router"].astype(F32), precision=jax.lax.Precision.HIGHEST)
-    idx, gates, kept = route_group_limited(scores, c.num_experts_per_tok, n_group=c.n_group, topk_group=c.topk_group,
-                                           scale=c.routed_scaling_factor)
-    routed, counts = dropless_experts(h, idx, gates, ep["w_gate"], ep["w_up"], ep["w_down"],
-                                      first_held=c.first_expert_held, token_mask=token_mask, dtype=c.dtype)
+    route = lambda scores: route_group_limited(scores, c.num_experts_per_tok, n_group=c.n_group, topk_group=c.topk_group,
+                                               scale=c.routed_scaling_factor)
+    routed, counts, kept = routed_experts(h, ep["router"], route, ep["w_gate"], ep["w_up"], ep["w_down"],
+                                          first_held=c.first_expert_held, token_mask=token_mask, dtype=c.dtype)
     here = kept[:, np.asarray(c.groups_held, np.int32)]
     if token_mask is not None:
         here = here & token_mask[:, None]
-    return routed + _swiglu(ep["shared"], h, c.dtype), counts, jnp.sum(here.astype(jnp.int32))
+    return routed + dense_mlp(c, ep["shared"], h), counts, jnp.sum(here.astype(jnp.int32))
 
 
 # ------------------------------------------------------------ whole layers
@@ -431,13 +382,10 @@ def prefill_chunk(config: DeepseekV2Config) -> int:
 def decode_kernels(config: DeepseekV2Config, cache) -> Dict[str, Any]:
     """The decode step's kernels, latched at build: ``{"decode": the
     ``interpret`` flag of ``paged_decode_latent``, or None for the XLA leg}``."""
-    from .. import kernels as _kernels
-    from ..kernels import paged_attention as _paged
+    from ..kernels import paged_attention
 
-    return {"decode": _kernels.resolve(
-        "paged_decode_latent",
-        supported=lambda interp: _paged.supports_latent(cache.k.data.dtype, config.cache_row, config.kv_lora_rank,
-                                                        cache.config.page_size, interpret=interp))}
+    return {"decode": paged_attention.leg_latent(cache.k.data.dtype, config.cache_row, config.kv_lora_rank,
+                                                 cache.config.page_size)}
 
 
 def serve_prefill(c: DeepseekV2Config, params, arrays, tokens, length, page_row, slot, *, page: int,
@@ -463,23 +411,13 @@ def serve_decode(c: DeepseekV2Config, params, arrays, table, lengths, tokens, *,
     Returns the logits (S, vocab), the step's counts ``{"experts": (expert
     layers, held) tokens an expert got, "groups": (expert layers,) kept groups
     that lie here}`` and the cache's arrays."""
-    from ..kernels import paged_attention as _paged
-
-    kernel_interpret = kernels["decode"]
-
-    def attend(q, pool, table, valid_len, *, layer, scale, latent):
-        if kernel_interpret is not None:
-            return _paged.paged_decode_latent(q, pool, table, valid_len, layer=layer, scale=scale, latent=latent,
-                                              interpret=kernel_interpret)
-        return latent_attention_xla(q, pool, table, valid_len, layer=layer, scale=scale, latent=latent)
-
     x = embed(c, params, tokens)                    # (S, E)
     pool, experts, groups = arrays["k"], [], []
     for l in range(c.num_hidden_layers):
         lp = params[f"layers_{l}"]
         step = lambda u, lp=lp, l=l: mla_step(c, lp["self_attn"], u, pool, layer=l, table=table, page=write_page,
                                                offset=write_offset, positions=lengths, valid_len=lengths + 1,
-                                               attend=attend)
+                                               interpret=kernels["decode"])
         x, pool, routed = layer_step(c, lp, l, x, active, step)
         if routed is not None:
             experts.append(routed[0])

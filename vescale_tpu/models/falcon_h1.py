@@ -4,11 +4,11 @@ normed input and adds both to the residual stream, over a dense SwiGLU, with
 twelve fixed multipliers (muP) on the way.
 
 The block is written ONCE, as pure functions over a plain parameter tree
-(``init_params``), and both serve programs call them.  What the state-space
-mixer shares with Granite-4.0-H it imports from ``models/granite_hybrid.py``
-(``mamba2_prefill`` over ``ssd_chunked``, ``mamba2_step``, the XLA legs of both
-decode kernels), which is written for ``G`` groups of B and C; the
-rotary term is ``models/sdar_moe.py``'s.  No flax module, no training copy.
+(``init_params``), and both serve programs call them.  The state-space mixer
+is ``models/mamba2.py``'s (``mamba2_prefill`` over ``ssd_chunked``,
+``mamba2_step``), which is written for ``G`` groups of B and C; the norm, the
+product and the rotary term are ``models/blocks.py``'s.  No flax module, no
+training copy.
 
 Equations (HF ``modeling_falcon_h1.py``; ISSUE 43 writes them out):
 
@@ -23,16 +23,13 @@ Equations (HF ``modeling_falcon_h1.py``; ISSUE 43 writes them out):
 ``attn``: q, k, v, o without bias, grouped-query (query head ``h`` reads key
 head ``h // (H / KV)``; 20 on 4 at 34B, five a key head), ``k = key_multiplier *
 W_k u``, rotary over the whole head (``rotate_half`` pairs) on q and k, causal
-softmax of ``q.k / sqrt(head_dim)``.  ``mamba`` (Mamba-2 with ``G`` groups of B
-and C, two at 34B): ``p = W_in u`` times ``ssm_multipliers`` segment by segment
-(z, x, B, C, dt); ``[z | xBC | dt] = p``; ``xBC = silu(causal depthwise
-conv1d(xBC) + b)``; ``dt = softplus(dt + dt_bias)``; per head ``h`` of group ``g
-= h // (H / G)``: ``S_t = exp(dt_t A_h) S_{t-1} + dt_t x_t B_{g,t}^T``, ``y_t =
-S_t C_{g,t} + D_h x_t``; ``y = rmsnorm_per_group(y * silu(z)) * w`` (the gate
-first, then each group of ``d_ssm / G`` channels normed by its own mean
-square); ``W_out y``.
+softmax of ``q.k / sqrt(head_dim)``.  ``mamba``: ``models/mamba2.py``'s mixer
+with ``G`` = 2 groups of B and C at 34B, its in-projection ``p = W_in u`` times
+``ssm_multipliers`` segment by segment (z, x, B, C, dt) before ``[z | xBC | dt]
+= p`` (the gate first, then each group of ``d_ssm / G`` channels normed by its
+own mean square).
 
-Precision, as ``granite_hybrid.py`` states it: weights and matmul operands are
+Precision: weights and matmul operands are
 ``config.dtype`` (bfloat16) with float32 accumulation; the residual stream, the
 norms, the gate, the rotary term and everything of the recurrence are float32,
 the state float32.
@@ -54,8 +51,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .granite_hybrid import F32, _mm, mamba2_prefill, mamba2_step, paged_attention_xla, rmsnorm, ssm_advance_xla
-from .sdar_moe import rotary
+from .blocks import F32, _mm, rmsnorm, rotary, write_position
+from .mamba2 import mamba2_prefill, mamba2_step
 
 __all__ = [
     "FalconH1Config", "init_params", "embed", "head", "in_scale", "attention_prefill", "attention_step", "mlp",
@@ -246,15 +243,17 @@ def attention_prefill(c: FalconH1Config, ap, u, *, interpret: Optional[bool] = N
 
 
 def attention_step(c: FalconH1Config, ap, u, k_pool, v_pool, *, layer: int, table, page, offset, positions,
-                   valid_len, attend):
+                   valid_len, interpret: Optional[bool]):
     """One new position a slot, at ``positions`` (S,): its K and V go to
     ``(page, offset)`` of the pool's ``layer`` (the null page for a slot that
-    may not write), then ``attend(q, k_pool, v_pool, table, valid_len, layer=,
-    scale=)`` reads the slot's pages.  Returns the output (S, E) and both pools."""
+    may not write), then ``kernels.paged_decode`` reads the slot's pages
+    (``interpret``: the kernel's flag, or None for its XLA leg).  Returns the
+    output (S, E) and both pools."""
+    from ..kernels.paged_attention import paged_decode
+
     q, k, v = _qkv(c, ap, u, positions)
-    k_pool = k_pool.at[layer, page, offset].set(k.astype(k_pool.dtype))
-    v_pool = v_pool.at[layer, page, offset].set(v.astype(v_pool.dtype))
-    y = attend(q, k_pool, v_pool, table, valid_len, layer=layer, scale=c.head_dim ** -0.5)
+    k_pool, v_pool = write_position(k_pool, v_pool, k, v, (layer, page, offset))
+    y = paged_decode(q, k_pool, v_pool, table, valid_len, layer=layer, scale=c.head_dim ** -0.5, interpret=interpret)
     return _mm(y.reshape(u.shape[0], -1), ap["o_proj"], c.dtype), k_pool, v_pool
 
 
@@ -306,20 +305,10 @@ def prefill_chunk(config: FalconH1Config) -> int:
 def decode_kernels(config: FalconH1Config, cache) -> Dict[str, Any]:
     """``{"decode":, "ssm_step":}``, each kernel's ``interpret`` flag, or None
     for its XLA leg (the kernels on TPU, the XLA legs elsewhere)."""
-    from .. import kernels as _kernels
-    from ..kernels import paged_attention as _paged
-    from ..kernels import ssm_step as _ssm
+    from ..kernels import paged_attention, ssm_step
 
-    c = config
-    return {
-        "decode": _kernels.resolve(
-            "paged_decode",
-            supported=lambda interp: _paged.supports(cache.k.data.dtype, c.num_key_value_heads, c.head_dim,
-                                                     interpret=interp)),
-        "ssm_step": _kernels.resolve(
-            "ssm_step", supported=lambda interp: _ssm.supports(c.state_dtype, *c.ssm_state_shape, interpret=interp,
-                                                               groups=c.mamba_n_groups)),
-    }
+    return {"decode": paged_attention.leg(cache.k.data.dtype, config.num_key_value_heads, config.head_dim),
+            "ssm_step": ssm_step.leg(config.state_dtype, *config.ssm_state_shape, groups=config.mamba_n_groups)}
 
 
 def serve_prefill(c: FalconH1Config, params, arrays, tokens, length, page_row, slot, *, page: int,
@@ -360,22 +349,8 @@ def serve_decode(c: FalconH1Config, params, arrays, table, lengths, tokens, *, a
     paged attention over that layer's pool (the ``paged_decode`` kernel on TPU),
     then the MLP.  Returns the logits (S, vocab), no counts of its own (a dense
     model: nothing is routed) and the cache's arrays."""
-    from ..kernels import paged_attention as _paged
-    from ..kernels import ssm_step as _ssm
-
     del active          # every slot goes through the dense layers; a slot that holds nothing writes the null page
     kd, vd, ssm, conv = arrays["k"], arrays["v"], arrays["ssm"], arrays["conv"]
-
-    def attend(q, kd, vd, table, valid_len, *, layer, scale):
-        if kernels["decode"] is not None:
-            return _paged.paged_decode(q, kd, vd, table, valid_len, layer=layer, scale=scale,
-                                       interpret=kernels["decode"])
-        return paged_attention_xla(q, kd, vd, table, valid_len, layer=layer, scale=scale)
-
-    def advance(ssm, decay, dtx, B, C, *, layer):
-        if kernels["ssm_step"] is not None:
-            return _ssm.ssm_step(ssm, decay, dtx, B, C, layer=layer, interpret=kernels["ssm_step"])
-        return ssm_advance_xla(ssm, decay, dtx, B, C, layer=layer)
 
     scale = in_scale(c)
     x = embed(c, params, tokens)                    # (S, E)
@@ -383,10 +358,11 @@ def serve_decode(c: FalconH1Config, params, arrays, table, lengths, tokens, *, a
         lp = params[f"layers_{l}"]
         x, (ssm, tail), (kd, vd) = layer(
             c, lp, x,
-            lambda u, lp=lp, l=l: mamba2_step(c, lp["mamba"], u, ssm, conv[l], layer=l, advance=advance, in_scale=scale),
+            lambda u, lp=lp, l=l: mamba2_step(c, lp["mamba"], u, ssm, conv[l], layer=l, interpret=kernels["ssm_step"],
+                                              in_scale=scale),
             lambda u, lp=lp, l=l: attention_step(
                 c, lp["self_attn"], u, kd, vd, layer=l, table=table, page=write_page, offset=write_offset,
-                positions=lengths, valid_len=lengths + 1, attend=attend))
+                positions=lengths, valid_len=lengths + 1, interpret=kernels["decode"]))
         conv = conv.at[l].set(tail)
     return head(c, params, x), {}, {"k": kd, "v": vd, "ssm": ssm, "conv": conv}
 

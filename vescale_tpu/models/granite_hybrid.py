@@ -4,10 +4,12 @@ is a routed expert layer beside one shared expert, in every layer.
 
 The block is written ONCE, as pure functions over a plain parameter tree
 (``init_params``), and both serve programs call them: prefill
-(``mamba2_prefill``, ``attention_prefill``) and decode (``mamba2_step``,
-``attention_step``) share the projections, the convolution, the gate, the
-norms, the expert layer (``moe.dropless``) and the head.  No flax module, no
-third copy for training yet (ROADMAP D3).
+(``mamba2.mamba2_prefill``, ``attention_prefill``) and decode
+(``mamba2.mamba2_step``, ``attention_step``) share the projections, the
+convolution, the gate, the norms, the expert layer (``moe.dropless``) and the
+head (the norm, the product and the SwiGLU are ``models/blocks.py``'s, each
+decode kernel and its XLA leg ``kernels/``'s).  No flax module, no third copy
+for training yet (ROADMAP D3).
 
 Equations (HF ``modeling_granitemoehybrid.py``; ISSUE 29 writes them out):
 
@@ -17,20 +19,8 @@ Equations (HF ``modeling_granitemoehybrid.py``; ISSUE 29 writes them out):
     logits = rmsnorm(x_L) @ E^T / logits_scaling          (tied head)
 
 ``attention``: q, k, v, o without bias, grouped-query, NO positional term,
-causal softmax of ``attention_multiplier * q.k``.  ``mamba`` (Mamba-2, one
-group of B and C shared by all heads): ``[z | xBC | dt] = W_in u``; ``xBC =
-silu(causal depthwise conv1d(xBC) + b)``; ``dt = softplus(dt + dt_bias)``;
-per head ``h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T``, ``y_t = h_t C_t + D
-x_t``; ``y = rmsnorm(y * silu(z)) * w``; ``W_out y``.
-
-The Mamba-2 mixer's functions (``mamba2_prefill``, ``mamba2_step``,
-``ssd_chunked``, ``ssm_advance_xla`` and the helpers under them) are written
-for ``G = mamba_n_groups`` groups of B and C: head ``h`` reads group ``h // (H
-/ G)``, and the gated norm is taken over each group's ``d_inner / G`` channels.
-This model has one; ``models/falcon_h1.py``, which has two, imports them.
-Where there is one group ``B`` and ``C`` carry no group axis, ``(T, N)``, and
-the arithmetic is what it was before groups were written; where there are
-several they are ``(T, G, N)``.
+causal softmax of ``attention_multiplier * q.k``.  ``mamba``:
+``models/mamba2.py``'s mixer, one group of B and C shared by all heads.
 
 Precision: weights and matmul operands are ``config.dtype`` (bfloat16) with
 float32 accumulation; the residual stream, the norms, the gate, the router and
@@ -55,21 +45,15 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..moe.dropless import dropless_experts, route_topk
+from ..moe.dropless import route_topk, routed_experts
+from .blocks import F32, _mm, rmsnorm, swiglu, write_position
+from .mamba2 import mamba2_prefill, mamba2_step
 
 __all__ = [
-    "GraniteHybridConfig", "init_params", "rmsnorm", "embed", "head",
-    "mamba2_prefill", "mamba2_step", "ssd_chunked", "attention_prefill", "attention_step",
-    "paged_attention_xla", "ssm_advance_xla", "expert_layer", "layer_prefill", "layer_step",
-    "cache_config", "prefill_chunk", "decode_kernels", "serve_prefill", "serve_decode", "STEP_COUNTERS",
-    "step_counters", "prefill_counters",
+    "GraniteHybridConfig", "init_params", "embed", "head", "attention_prefill", "attention_step", "expert_layer",
+    "layer_prefill", "layer_step", "cache_config", "prefill_chunk", "decode_kernels", "serve_prefill", "serve_decode",
+    "STEP_COUNTERS", "step_counters", "prefill_counters",
 ]
-
-F32 = jnp.float32
-# the scan's own products (C B^T, the decayed sums, the chunk states) are a few
-# per cent of a prefill's operations; in float32 they leave the state exact to
-# the recurrence's own rounding
-SCAN_PRECISION = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)
@@ -212,17 +196,7 @@ def init_params(config: GraniteHybridConfig, key) -> Dict[str, Any]:
     return params
 
 
-# ------------------------------------------------------------- shared pieces
-def rmsnorm(x, w, eps):
-    x = x.astype(F32)
-    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * w.astype(F32)
-
-
-def _mm(x, w, dtype):
-    """``x @ w`` with operands in ``dtype`` and a float32 result."""
-    return jnp.dot(x.astype(dtype), w.astype(dtype), preferred_element_type=F32)
-
-
+# ------------------------------------------------------------ embedding, head
 def embed(config: GraniteHybridConfig, params, tokens):
     return config.embedding_multiplier * jnp.take(params["embed_tokens"]["embedding"], tokens, axis=0).astype(F32)
 
@@ -231,158 +205,6 @@ def head(config: GraniteHybridConfig, params, x):
     """Logits (float32) over the rows of the embedding held here."""
     xn = rmsnorm(x, params["norm"]["weight"], config.rms_norm_eps)
     return _mm(xn, params["embed_tokens"]["embedding"].T, config.dtype) / config.logits_scaling
-
-
-# ------------------------------------------------------------ Mamba-2 mixer
-def _mamba_in(c, mp, u, scale=None):
-    """``[z | xBC | dt] = W_in u``, times ``scale`` (in_proj_dim,) where a
-    model multiplies the projection's segments; xBC in the weights' type, as the
-    convolution tail is kept (prefill and decode then convolve the same values)."""
-    zxbcdt = _mm(u, mp["in_proj"], c.dtype)
-    if scale is not None:
-        zxbcdt = zxbcdt * scale
-    z = zxbcdt[..., : c.d_inner]
-    xBC = zxbcdt[..., c.d_inner: c.d_inner + c.conv_dim].astype(c.dtype)
-    dt = jax.nn.softplus(zxbcdt[..., c.d_inner + c.conv_dim:] + mp["dt_bias"].astype(F32))
-    return z, xBC, dt
-
-
-def _mamba_split(c, conv_out):
-    """``x`` (..., H, P) and ``B``, ``C``: (..., N) of one group, (..., G, N) of several."""
-    act = jax.nn.silu(conv_out)
-    G, GN = c.mamba_n_groups, c.mamba_n_groups * c.mamba_d_state
-    x = act[..., : c.d_inner].reshape(act.shape[:-1] + (c.mamba_n_heads, c.mamba_d_head))
-    B = act[..., c.d_inner: c.d_inner + GN]
-    C = act[..., c.d_inner + GN:]
-    if G > 1:
-        B, C = (a.reshape(a.shape[:-1] + (G, c.mamba_d_state)) for a in (B, C))
-    return x, B, C
-
-
-def _mamba_out(c, mp, y, z):
-    """Gate first, then the norm over each group's share of ``d_inner`` (all of
-    it where there is one group), then ``W_out``."""
-    y = y.reshape(y.shape[:-2] + (c.d_inner,)) * jax.nn.silu(z)
-    if c.mamba_n_groups == 1:
-        y = rmsnorm(y, mp["norm_weight"], c.rms_norm_eps)
-    else:
-        grouped = y.shape[:-1] + (c.mamba_n_groups, c.d_inner // c.mamba_n_groups)
-        y = rmsnorm(y.reshape(grouped), mp["norm_weight"].reshape(grouped[-2:]), c.rms_norm_eps).reshape(y.shape)
-    return _mm(y, mp["out_proj"], c.dtype)
-
-
-def ssd_chunked(x, dt, A, B, C, chunk: int, initial_state=None):
-    """The recurrence ``h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T``, ``y_t =
-    h_t C_t`` over one sequence by chunks (Mamba-2's state-space duality):
-    inside a chunk a masked, decay-weighted ``(C B^T)`` product, between
-    chunks a scan over the chunk states.  ``x`` (T, H, P), ``dt`` (T, H),
-    ``A`` (H,), ``B`` and ``C`` (T, N), or (T, G, N) where the heads read ``G``
-    groups (head ``h`` group ``h // (H / G)``); T a multiple of ``chunk``.
-    Returns ``y`` (T, H, P) and the state after the last position (H, P, N),
-    float32.  A position whose ``dt`` is 0 decays nothing and adds nothing."""
-    if B.ndim == 2:
-        return _ssd_one_group(x, dt, A, B, C, chunk, initial_state)
-    T, H, P = x.shape
-    G, N = B.shape[1:]
-    if H % G:
-        raise ValueError(f"{H} heads do not divide into {G} groups")
-    # a group is a scan of its own over its heads: the one-group arithmetic, once a group
-    heads = lambda a: a.reshape(a.shape[:1] + (G, H // G) + a.shape[2:])
-    h0 = jnp.zeros((H, P, N), F32) if initial_state is None else initial_state
-    y, last = jax.vmap(lambda *group: _ssd_one_group(*group[:5], chunk, group[5]), in_axes=(1, 1, 0, 1, 1, 0),
-                       out_axes=(1, 0))(heads(x), heads(dt), A.reshape(G, H // G), B, C, h0.reshape(G, H // G, P, N))
-    return y.reshape(T, H, P), last.reshape(H, P, N)
-
-
-def _ssd_one_group(x, dt, A, B, C, chunk: int, initial_state=None):
-    """:func:`ssd_chunked` where every head reads the same ``B`` and ``C`` (T, N)."""
-    T, H, P = x.shape
-    N = B.shape[-1]
-    if T % chunk:
-        raise ValueError(f"{T} positions are not a whole number of chunks of {chunk}")
-    n = T // chunk
-    x, dt, B, C = (a.astype(F32) for a in (x, dt, B, C))
-    xd = (x * dt[..., None]).reshape(n, chunk, H, P)
-    Bc, Cc = B.reshape(n, chunk, N), C.reshape(n, chunk, N)
-    cs = jnp.cumsum((dt * A.astype(F32)).reshape(n, chunk, H), axis=1)          # (n, q, H), <= 0
-    ein = lambda spec, *ops: jnp.einsum(spec, *ops, precision=SCAN_PRECISION)
-    # inside a chunk: y_q += sum_{s<=q} (C_q . B_s) exp(cs_q - cs_s) dt_s x_s
-    causal = jnp.tril(jnp.ones((chunk, chunk), bool))[None, :, :, None]
-    decay = jnp.exp(jnp.where(causal, cs[:, :, None, :] - cs[:, None, :, :], -jnp.inf))   # (n, q, s, H)
-    y = ein("cqsh,cshp->cqhp", ein("cqn,csn->cqs", Cc, Bc)[..., None] * decay, xd)
-    # what each chunk adds to the state by its end, and the scan over chunks
-    added = ein("cqh,cqhp,cqn->chpn", jnp.exp(cs[:, -1:, :] - cs), xd, Bc)
-    h0 = jnp.zeros((H, P, N), F32) if initial_state is None else initial_state.astype(F32)
-
-    def over_chunks(h, inp):
-        add, total = inp
-        return jnp.exp(total)[:, None, None] * h + add, h
-
-    last, before = jax.lax.scan(over_chunks, h0, (added, cs[:, -1, :]))
-    y = y + ein("cqn,chpn,cqh->cqhp", Cc, before, jnp.exp(cs))
-    return y.reshape(T, H, P), last
-
-
-def mamba2_prefill(c, mp, u, length, *, in_scale=None):
-    """One sequence ``u`` (T, E), T a multiple of the chunk, of which the
-    first ``length`` positions are real.  In the pad ``dt`` is forced to 0, so
-    the state stands where the prompt ends, and the convolution tail is taken
-    from the prompt's last ``d_conv - 1`` real inputs (zeros before its
-    start).  ``in_scale`` as :func:`_mamba_in` takes it.  Returns the mixer's
-    output (T, E), the state in the cache's layout (N, H P) and type, and the
-    tail (d_conv - 1, conv_dim)."""
-    T, K = u.shape[0], c.mamba_d_conv
-    z, xBC, dt = _mamba_in(c, mp, u, in_scale)
-    dt = jnp.where((jnp.arange(T) < length)[:, None], dt, 0.0)
-    padded = jnp.concatenate([jnp.zeros((K - 1, c.conv_dim), xBC.dtype), xBC], axis=0)
-    tail = jax.lax.dynamic_slice_in_dim(padded, length, K - 1, axis=0)
-    w = mp["conv_weight"].astype(F32)
-    conv = mp["conv_bias"].astype(F32) + sum(w[k] * padded[k: k + T].astype(F32) for k in range(K))
-    x, B, C = _mamba_split(c, conv)
-    y, state = ssd_chunked(x, dt, -jnp.exp(mp["A_log"].astype(F32)), B, C, c.mamba_chunk_size)
-    y = y + mp["D"].astype(F32)[:, None] * x
-    state = state.transpose(2, 0, 1).reshape(c.ssm_state_shape)          # (H, P, N) -> (N, H P)
-    return _mamba_out(c, mp, y, z), state.astype(c.state_dtype), tail
-
-
-def _over_lanes(a, lanes: int):
-    """``B`` or ``C`` laid against the state's lanes: (S, N) -> (S, N, 1), one
-    column for all; (S, G, N) -> (S, N, J), each group's column over the ``J /
-    G`` lanes of its heads."""
-    if a.ndim == 2:
-        return a[:, :, None]
-    return jnp.repeat(a.transpose(0, 2, 1), lanes // a.shape[1], axis=2)
-
-
-def ssm_advance_xla(ssm, decay, dtx, B, C, *, layer: int):
-    """``h' = decay * h + B (x) dtx`` and ``y = sum_n h' C`` for every slot, on
-    the ``layer``-th state of ``ssm`` (layers, S, N, J); ``decay`` and ``dtx``
-    (S, J), ``B`` and ``C`` (S, N), or (S, G, N) where the lanes lie in ``G``
-    groups.  Returns ``ssm`` with that layer advanced and ``y`` (S, J).  The XLA
-    leg of ``kernels.ssm_step`` (which reads and writes the state once; this
-    reads it twice)."""
-    J = ssm.shape[-1]
-    h = decay[:, None, :] * ssm[layer].astype(F32) + _over_lanes(B, J) * dtx[:, None, :]
-    return ssm.at[layer].set(h.astype(ssm.dtype)), jnp.sum(h * _over_lanes(C, J), axis=1)
-
-
-def mamba2_step(c, mp, u, ssm, tail, *, layer: int, advance=ssm_advance_xla, in_scale=None):
-    """The recurrence's one step for every slot: ``u`` (S, E), ``ssm`` the
-    states of all state-space layers (layers, S, N, H P) of which this mixer's
-    is the ``layer``-th, ``tail`` (S, d_conv - 1, conv_dim).
-    ``advance(ssm, decay, dtx, B, C, layer=)`` moves the state (the kernel on
-    TPU); ``in_scale`` as :func:`_mamba_in` takes it.  Returns the output (S,
-    E), ``ssm`` and the tail, advanced."""
-    z, xBC, dt = _mamba_in(c, mp, u, in_scale)
-    window = jnp.concatenate([tail, xBC[:, None, :].astype(tail.dtype)], axis=1)      # (S, K, conv_dim)
-    conv = mp["conv_bias"].astype(F32) + jnp.sum(mp["conv_weight"].astype(F32)[None] * window.astype(F32), axis=1)
-    x, B, C = _mamba_split(c, conv)
-    decay = jnp.exp(dt * -jnp.exp(mp["A_log"].astype(F32)))                               # (S, H)
-    S = u.shape[0]
-    ssm, y = advance(ssm, jnp.repeat(decay, c.mamba_d_head, axis=1), (dt[..., None] * x).reshape(S, c.d_inner), B, C,
-                     layer=layer)
-    y = y.reshape(x.shape) + mp["D"].astype(F32)[:, None] * x
-    return _mamba_out(c, mp, y, z), ssm, window[:, 1:]
 
 
 # ---------------------------------------------------------- attention mixer
@@ -405,30 +227,18 @@ def attention_prefill(c: GraniteHybridConfig, ap, u, *, interpret: Optional[bool
     return _mm(y.reshape(u.shape[0], -1), ap["o_proj"], c.dtype), k, v
 
 
-def paged_attention_xla(q, k_pool, v_pool, table, valid_len, *, layer: int, scale: float):
-    """Decode attention of one layer without the kernel: gather every slot's
-    pages, mask by length, float32 softmax (the XLA leg of ``ServeEngine``)."""
-    S, H, hd = q.shape
-    KV = k_pool.shape[3]
-    ks = jnp.take(k_pool[layer], table, axis=0).reshape(S, -1, KV, hd)
-    vs = jnp.take(v_pool[layer], table, axis=0).reshape(S, -1, KV, hd)
-    qg = (q.astype(F32) * scale).reshape(S, KV, H // KV, hd)
-    s = jnp.einsum("skgd,stkd->skgt", qg, ks.astype(F32))
-    mask = jnp.arange(ks.shape[1], dtype=jnp.int32)[None, :] < valid_len[:, None]
-    p = jax.nn.softmax(jnp.where(mask[:, None, None, :], s, -1e30), axis=-1)
-    return jnp.einsum("skgt,stkd->skgd", p, vs.astype(F32)).reshape(S, H, hd)
-
-
 def attention_step(c: GraniteHybridConfig, ap, u, k_pool, v_pool, *, layer: int, table, page, offset, valid_len,
-                   attend):
+                   interpret: Optional[bool]):
     """One new position a slot: its K and V go to ``(page, offset)`` of the
     pool's ``layer`` (the null page for a slot that may not write), then
-    ``attend(q, k_pool, v_pool, table, valid_len, layer=, scale=)`` reads the
-    slot's pages.  Returns the output (S, E) and both pools."""
+    ``kernels.paged_decode`` reads the slot's pages (``interpret``: the
+    kernel's flag, or None for its XLA leg).  Returns the output (S, E) and
+    both pools."""
+    from ..kernels.paged_attention import paged_decode
+
     q, k, v = _qkv(c, ap, u)
-    k_pool = k_pool.at[layer, page, offset].set(k.astype(k_pool.dtype))
-    v_pool = v_pool.at[layer, page, offset].set(v.astype(v_pool.dtype))
-    y = attend(q, k_pool, v_pool, table, valid_len, layer=layer, scale=c.attention_multiplier)
+    k_pool, v_pool = write_position(k_pool, v_pool, k, v, (layer, page, offset))
+    y = paged_decode(q, k_pool, v_pool, table, valid_len, layer=layer, scale=c.attention_multiplier, interpret=interpret)
     return _mm(y.reshape(u.shape[0], -1), ap["o_proj"], c.dtype), k_pool, v_pool
 
 
@@ -439,13 +249,10 @@ def expert_layer(c: GraniteHybridConfig, ep, h, token_mask=None):
     over those ten; the held experts' part is computed without capacity
     (``moe.dropless``), the shared expert on every token.  Returns the sum
     (N, E) float32 and how many tokens each held expert got (held,)."""
-    scores = jnp.dot(h.astype(F32), ep["router"].astype(F32), precision=jax.lax.Precision.HIGHEST)
-    idx, gates = route_topk(scores, c.num_experts_per_tok)
-    routed, counts = dropless_experts(h, idx, gates, ep["w_gate"], ep["w_up"], ep["w_down"],
-                                      first_held=c.first_expert_held, token_mask=token_mask, dtype=c.dtype)
-    shared = _mm(jax.nn.silu(_mm(h, ep["shared_gate"], c.dtype)) * _mm(h, ep["shared_up"], c.dtype),
-                 ep["shared_down"], c.dtype)
-    return routed + shared, counts
+    routed, counts = routed_experts(h, ep["router"], lambda scores: route_topk(scores, c.num_experts_per_tok),
+                                    ep["w_gate"], ep["w_up"], ep["w_down"], first_held=c.first_expert_held,
+                                    token_mask=token_mask, dtype=c.dtype)
+    return routed + swiglu(h, ep["shared_gate"], ep["shared_up"], ep["shared_down"], c.dtype), counts
 
 
 # ------------------------------------------------------------ whole layers
@@ -518,19 +325,10 @@ def decode_kernels(config: GraniteHybridConfig, cache) -> Dict[str, Any]:
     """The decode step's kernels, latched at build: ``{"decode":, "ssm_step":}``
     each kernel's ``interpret`` flag, or None for its XLA leg (the kernels on
     TPU, the XLA legs elsewhere, as ``ServeEngine`` decides)."""
-    from .. import kernels as _kernels
-    from ..kernels import paged_attention as _paged
-    from ..kernels import ssm_step as _ssm
+    from ..kernels import paged_attention, ssm_step
 
-    c = config
-    return {
-        "decode": _kernels.resolve(
-            "paged_decode",
-            supported=lambda interp: _paged.supports(cache.k.data.dtype, c.num_key_value_heads, c.head_dim,
-                                                     interpret=interp)),
-        "ssm_step": _kernels.resolve(
-            "ssm_step", supported=lambda interp: _ssm.supports(c.state_dtype, *c.ssm_state_shape, interpret=interp)),
-    }
+    return {"decode": paged_attention.leg(cache.k.data.dtype, config.num_key_value_heads, config.head_dim),
+            "ssm_step": ssm_step.leg(config.state_dtype, *config.ssm_state_shape)}
 
 
 def _cache_rows(c: GraniteHybridConfig) -> Dict[int, int]:
@@ -576,33 +374,19 @@ def serve_decode(c: GraniteHybridConfig, params, arrays, table, lengths, tokens,
     ``paged_decode`` kernel on TPU, the XLA leg elsewhere), the expert layer
     over the active slots.  Returns the logits (S, vocab), ``{"experts":
     (layers, held) tokens an expert got}`` and the cache's arrays."""
-    from ..kernels import paged_attention as _paged
-    from ..kernels import ssm_step as _ssm
-
     kd, vd, ssm, conv = arrays["k"], arrays["v"], arrays["ssm"], arrays["conv"]
     row = _cache_rows(c)
-
-    def attend(q, kd, vd, table, valid_len, *, layer, scale):
-        if kernels["decode"] is not None:
-            return _paged.paged_decode(q, kd, vd, table, valid_len, layer=layer, scale=scale,
-                                       interpret=kernels["decode"])
-        return paged_attention_xla(q, kd, vd, table, valid_len, layer=layer, scale=scale)
-
-    def advance(ssm, decay, dtx, B, C, *, layer):
-        if kernels["ssm_step"] is not None:
-            return _ssm.ssm_step(ssm, decay, dtx, B, C, layer=layer, interpret=kernels["ssm_step"])
-        return ssm_advance_xla(ssm, decay, dtx, B, C, layer=layer)
-
     x = embed(c, params, tokens)                    # (S, E)
     counts = []
     for l, kind in enumerate(c.layer_types):
         lp, i = params[f"layers_{l}"], row[l]
         if kind == "mamba":
-            step = lambda u, lp=lp, i=i: mamba2_step(c, lp["mixer"], u, ssm, conv[i], layer=i, advance=advance)
+            step = lambda u, lp=lp, i=i: mamba2_step(c, lp["mixer"], u, ssm, conv[i], layer=i,
+                                                     interpret=kernels["ssm_step"])
         else:
             step = lambda u, lp=lp, i=i: attention_step(
                 c, lp["mixer"], u, kd, vd, layer=i, table=table, page=write_page, offset=write_offset,
-                valid_len=lengths + 1, attend=attend)
+                valid_len=lengths + 1, interpret=kernels["decode"])
         x, kept, n = layer_step(c, lp, kind, x, active, step)
         if kind == "mamba":
             ssm, conv = kept[0], conv.at[i].set(kept[1])

@@ -63,23 +63,20 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import types
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..moe.dropless import dropless_experts, route_sigmoid_topk
-from .deepseek_v2 import F32, ROUTED_DOWN_GAIN, _mm, _swiglu as swiglu, rmsnorm, yarn_inv_freq
-from .granite_hybrid import paged_attention_xla
+from ..moe.dropless import route_sigmoid_topk, routed_experts
+from .blocks import F32, ROUTED_DOWN_GAIN, _mm, rmsnorm, swiglu, write_position, yarn_inv_freq
 
 __all__ = [
-    "LagunaConfig", "init_params", "rmsnorm", "embed", "head", "inv_freq", "rotary", "attention_gate",
-    "attention_prefill", "attention_step", "ring_row", "ring_source", "swiglu", "expert_layer", "layer_prefill",
-    "layer_step", "cache_config", "prefill_chunk", "decode_kernels", "serve_prefill", "serve_decode", "STEP_COUNTERS",
-    "step_counters", "prefill_counters", "window_pairs", "qk_gain", "FULL", "SLIDING", "DENSE", "SPARSE",
-    "SCORE_DEVIATION",
+    "LagunaConfig", "init_params", "embed", "head", "inv_freq", "rotary", "attention_gate", "attention_prefill",
+    "attention_step", "ring_row", "ring_source", "mlp", "expert_layer", "layer_prefill", "layer_step", "cache_config",
+    "prefill_chunk", "decode_kernels", "serve_prefill", "serve_decode", "STEP_COUNTERS", "step_counters",
+    "prefill_counters", "window_pairs", "qk_gain", "FULL", "SLIDING", "DENSE", "SPARSE", "SCORE_DEVIATION",
 ]
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -174,7 +171,7 @@ def init_params(config: LagunaConfig, key) -> Dict[str, Any]:
     Matrices are normal with variance 1 / fan-in, but for: the embedding (unit
     variance: the stream starts at the size the branches add to it); the router
     (float32) twice as wide and the routed experts' down projections
-    ``ROUTED_DOWN_GAIN`` times as wide (``models/deepseek_v2.py:init_params``'s rule and constant);
+    ``ROUTED_DOWN_GAIN`` times as wide (the constant says why);
     and ``Wq``, ``Wk`` :func:`qk_gain` times as wide each (``SCORE_DEVIATION`` says why)."""
     c, dt = config, config.dtype
     E, KV, hd = c.hidden_size, c.num_key_value_heads, c.head_dim
@@ -218,9 +215,7 @@ def init_params(config: LagunaConfig, key) -> Dict[str, Any]:
     return params
 
 
-# ------------------------------------------------------------- shared pieces
-# (``rmsnorm``, ``_mm`` and ``swiglu`` are ``models/deepseek_v2.py``'s, as is the routed experts' down gain, PR 34's
-# rule: one routed expert's marginal contribution a few per cent of the stream, as in a trained model)
+# ------------------------------------------------------------ embedding, head
 def embed(config: LagunaConfig, params, tokens):
     return jnp.take(params["embed_tokens"]["embedding"], tokens, axis=0).astype(F32)
 
@@ -234,15 +229,12 @@ def head(config: LagunaConfig, params, x):
 def inv_freq(config: LagunaConfig, kind: str) -> Tuple[np.ndarray, float]:
     """The rotary frequencies of a layer of ``kind`` (rotated width / 2,) and
     what multiplies its cos and sin: a full layer's are YaRN's over the rotated
-    part (``models/deepseek_v2.py:yarn_inv_freq``, the same rule on these
-    numbers) under ``attention_factor``; a sliding layer's plain."""
+    part under ``attention_factor``; a sliding layer's plain."""
     c = config
     if kind == FULL:
-        yarn = types.SimpleNamespace(
-            qk_rope_head_dim=int(c.head_dim * c.full_partial_rotary_factor), rope_theta=c.full_rope_theta,
-            rope_factor=c.full_rope_factor, rope_original_max_position_embeddings=c.full_rope_original_max_position_embeddings,
-            rope_beta_fast=c.full_rope_beta_fast, rope_beta_slow=c.full_rope_beta_slow)
-        return yarn_inv_freq(yarn), float(c.full_rope_attention_factor)
+        return yarn_inv_freq(int(c.head_dim * c.full_partial_rotary_factor), c.full_rope_theta, c.full_rope_factor,
+                             c.full_rope_original_max_position_embeddings, c.full_rope_beta_fast,
+                             c.full_rope_beta_slow), float(c.full_rope_attention_factor)
     dim = int(c.head_dim * c.sliding_partial_rotary_factor)
     return (c.sliding_rope_theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)).astype(np.float32), 1.0
 
@@ -315,32 +307,37 @@ def ring_source(length, rung: int, window: int):
 
 
 def attention_step(c: LagunaConfig, ap, u, kind: str, k_store, v_store, *, layer: int, table, write, positions,
-                   valid_len, attend):
+                   valid_len, interpret: Optional[bool]):
     """One new position a slot, at ``positions`` (S,): its K and V go to
     ``write`` of the stores' ``layer`` (a full layer: ``(page, offset)`` of the
     pools, the null page for a slot that may not write; a sliding layer:
-    ``(slot, ring row)`` of the rings), then ``attend(q, k_store, v_store,
-    table, valid_len, layer=, scale=)`` reads through ``table``.  Returns the
-    output (S, E) and both stores."""
+    ``(slot, ring row)`` of the rings), then ``kernels.paged_decode`` reads
+    through ``table``, on the leg ``interpret`` names (the kernel's flag, or
+    None for the XLA leg).  Returns the output (S, E) and both stores."""
+    from ..kernels.paged_attention import paged_decode
+
     q, k, v, gate = _qkvg(c, ap, u, positions, kind)
-    k_store = k_store.at[(layer,) + write].set(k.astype(k_store.dtype))
-    v_store = v_store.at[(layer,) + write].set(v.astype(v_store.dtype))
-    y = attend(q, k_store, v_store, table, valid_len, layer=layer, scale=c.head_dim ** -0.5)
+    k_store, v_store = write_position(k_store, v_store, k, v, (layer,) + write)
+    y = paged_decode(q, k_store, v_store, table, valid_len, layer=layer, scale=c.head_dim ** -0.5, interpret=interpret)
     return _out(c, ap, y, gate), k_store, v_store
 
 
 # -------------------------------------------------------------- feed-forward
+def mlp(c: LagunaConfig, mp, h):
+    """The SwiGLU over a tree of ``gate`` / ``up`` / ``down``: a dense layer's MLP, the shared expert."""
+    return swiglu(h, mp["gate"], mp["up"], mp["down"], c.dtype)
+
+
 def expert_layer(c: LagunaConfig, ep, h, token_mask=None):
     """``sum over kept and held e of w_e E_e(h) + E_shared(h)`` for tokens ``h``
     (N, E): the router's scores in float32, sigmoid routing
     (``route_sigmoid_topk``), the dropless layer over the held experts, and the
     shared expert on every token.  Returns the sum (N, E) float32 and how many
     tokens each held expert got (held,)."""
-    scores = jnp.dot(h.astype(F32), ep["router"].astype(F32), precision=jax.lax.Precision.HIGHEST)
-    idx, gates = route_sigmoid_topk(scores, c.num_experts_per_tok, scale=c.moe_routed_scaling_factor)
-    routed, counts = dropless_experts(h, idx, gates, ep["w_gate"], ep["w_up"], ep["w_down"],
-                                      first_held=c.first_expert_held, token_mask=token_mask, dtype=c.dtype)
-    return routed + swiglu(ep["shared"], h, c.dtype), counts
+    route = lambda scores: route_sigmoid_topk(scores, c.num_experts_per_tok, scale=c.moe_routed_scaling_factor)
+    routed, counts = routed_experts(h, ep["router"], route, ep["w_gate"], ep["w_up"], ep["w_down"],
+                                    first_held=c.first_expert_held, token_mask=token_mask, dtype=c.dtype)
+    return routed + mlp(c, ep["shared"], h), counts
 
 
 def _after_attention(c: LagunaConfig, lp, l: int, x, y, token_mask):
@@ -350,7 +347,7 @@ def _after_attention(c: LagunaConfig, lp, l: int, x, y, token_mask):
     h = rmsnorm(x, lp["post_attention_layernorm"]["weight"], c.rms_norm_eps)
     if c.mlp_layer_types[l] == DENSE:
         with jax.named_scope("vs.mlp"):
-            return x + swiglu(lp["mlp"], h, c.dtype), None
+            return x + mlp(c, lp["mlp"], h), None
     with jax.named_scope("vs.moe"):
         y, counts = expert_layer(c, lp["mlp"], h, token_mask=token_mask)
     return x + y, counts
@@ -406,13 +403,9 @@ def decode_kernels(config: LagunaConfig, cache) -> Dict[str, Any]:
     """``{"decode": the ``interpret`` flag of ``paged_decode``, or None for the
     XLA leg}``: pools and rings have one row (``KV`` heads of ``hd``) and one
     type, so one answer holds for both."""
-    from .. import kernels as _kernels
-    from ..kernels import paged_attention as _paged
+    from ..kernels import paged_attention
 
-    return {"decode": _kernels.resolve(
-        "paged_decode",
-        supported=lambda interp: _paged.supports(cache.k.data.dtype, config.num_key_value_heads, config.head_dim,
-                                                 interpret=interp))}
+    return {"decode": paged_attention.leg(cache.k.data.dtype, config.num_key_value_heads, config.head_dim)}
 
 
 def serve_prefill(c: LagunaConfig, params, arrays, tokens, length, page_row, slot, *, page: int,
@@ -453,13 +446,6 @@ def serve_decode(c: LagunaConfig, params, arrays, table, lengths, tokens, *, act
     through the same ``paged_decode`` (the kernel on TPU), at the layer's own
     count of query heads.  Returns the logits (S, vocab), ``{"experts":
     (expert layers, held) tokens an expert got}`` and the cache's arrays."""
-    from ..kernels import paged_attention as _paged
-
-    def attend(q, kd, vd, table, valid_len, *, layer, scale):
-        if kernels["decode"] is not None:
-            return _paged.paged_decode(q, kd, vd, table, valid_len, layer=layer, scale=scale, interpret=kernels["decode"])
-        return paged_attention_xla(q, kd, vd, table, valid_len, layer=layer, scale=scale)
-
     S, W, page = lengths.shape[0], c.sliding_window, arrays["k"].shape[2]
     stores = {FULL: (arrays["k"], arrays["v"])}
     place = {FULL: dict(table=table, write=(write_page, write_offset), valid_len=lengths + 1)}
@@ -479,7 +465,8 @@ def serve_decode(c: LagunaConfig, params, arrays, table, lengths, tokens, *, act
     for l in range(c.num_hidden_layers):
         lp, kind = params[f"layers_{l}"], c.layer_types[l]
         step = lambda u, lp=lp, kind=kind, l=l: attention_step(
-            c, lp["self_attn"], u, kind, *stores[kind], layer=index[l], positions=lengths, attend=attend, **place[kind])
+            c, lp["self_attn"], u, kind, *stores[kind], layer=index[l], positions=lengths, interpret=kernels["decode"],
+            **place[kind])
         x, k_store, v_store, counts = layer_step(c, lp, l, x, active, step)
         stores[kind] = (k_store, v_store)
         if counts is not None:

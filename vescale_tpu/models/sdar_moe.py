@@ -30,8 +30,9 @@ its static low-confidence schedule, greedy:
 So a whole block costs T calls for B tokens (T + 1 units of B rows through the
 stack, as the published loop has passes), a request of n blocks n T + 1, and
 slots are at different passes of their blocks in one program call.  Both
-programs are written once, as pure functions over a plain parameter tree, as
-``models/deepseek_v2.py`` does; the last section is what ``serve.HybridServeEngine`` asks of a model's
+programs are written once, as pure functions over a plain parameter tree (the
+norm, the product and the rotary term are ``models/blocks.py``'s); the last
+section is what ``serve.HybridServeEngine`` asks of a model's
 module, with ``block_schedule`` for the engine's host-side mirror
 (``serve/hybrid_engine.py``, "A block engine", says what ``serve_decode`` is
 given and gives, and the teacher-forced use of the same program).
@@ -60,24 +61,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..moe.dropless import dropless_experts, route_topk
-from .granite_hybrid import paged_attention_xla
+from ..moe.dropless import route_topk, routed_experts
+from .blocks import F32, ROUTED_DOWN_GAIN, _mm, rmsnorm, rotary
 
 __all__ = [
-    "SdarMoeConfig", "init_params", "rmsnorm", "embed", "head", "rotary", "attention_prefill", "attention_pass",
-    "expert_layer", "layer_prefill", "layer_pass", "logit_stats", "head_stats", "unmask", "cache_config", "prefill_chunk",
-    "decode_kernels",
+    "SdarMoeConfig", "init_params", "embed", "head", "attention_prefill", "attention_pass", "expert_layer",
+    "layer_prefill", "layer_pass", "head_stats", "unmask", "cache_config", "prefill_chunk", "decode_kernels",
     "block_schedule", "serve_prefill", "serve_decode", "STEP_COUNTERS", "step_counters", "prefill_counters",
 ]
 
-F32 = jnp.float32
-# as ``models/deepseek_v2.py:ROUTED_DOWN_GAIN``, for the reason written there: with every weight at variance
-# 1 / fan-in a routed expert's output is as large as the residual stream (here three times it: random attention
-# over some hundred positions averages its values away), so the token in ten whose eighth and ninth expert a
-# rounding difference swaps moves by a tenth of its size and the next layers' routers amplify that.  Drawn this
-# much narrower the routed part is a few per cent of the stream, as one expert's marginal contribution is in a
-# trained model.
-ROUTED_DOWN_GAIN = 1.0 / 64.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,10 +110,11 @@ class SdarMoeConfig:
 # ------------------------------------------------------------------ parameters
 def init_params(config: SdarMoeConfig, key) -> Dict[str, Any]:
     """Seeded random weights in the types they are served in (jit the call).
-    Matrices are normal with variance 1 / fan-in, but for two, as
-    ``models/deepseek_v2.py:init_params`` draws them: the router (float32)
-    twice as wide, so that its softmax is not flat, and the routed experts'
-    down projections ``ROUTED_DOWN_GAIN`` times as wide."""
+    Matrices are normal with variance 1 / fan-in, but for two: the router
+    (float32) twice as wide, so that its softmax is not flat, and the routed
+    experts' down projections ``ROUTED_DOWN_GAIN`` times as wide (the constant
+    says why; here a routed expert's output would be three times the stream:
+    random attention over some hundred positions averages its values away)."""
     c, dt = config, config.dtype
     E, H, KV, hd, F, held = (c.hidden_size, c.num_attention_heads, c.num_key_value_heads, c.head_dim,
                              c.moe_intermediate_size, c.experts_held)
@@ -154,17 +147,7 @@ def init_params(config: SdarMoeConfig, key) -> Dict[str, Any]:
     return params
 
 
-# ------------------------------------------------------------- shared pieces
-def rmsnorm(x, w, eps):
-    x = x.astype(F32)
-    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * w.astype(F32)
-
-
-def _mm(x, w, dtype):
-    """``x @ w`` with operands in ``dtype`` and a float32 result."""
-    return jnp.dot(x.astype(dtype), w.astype(dtype), preferred_element_type=F32)
-
-
+# ------------------------------------------------------------ embedding, head
 def embed(config: SdarMoeConfig, params, tokens):
     return jnp.take(params["embed_tokens"]["embedding"], tokens, axis=0).astype(F32)
 
@@ -172,16 +155,6 @@ def embed(config: SdarMoeConfig, params, tokens):
 def head(config: SdarMoeConfig, params, x):
     """Logits (float32), a row a position, not shifted."""
     return _mm(rmsnorm(x, params["norm"]["weight"], config.rms_norm_eps), params["lm_head"]["kernel"], config.dtype)
-
-
-def rotary(x, positions, theta: float):
-    """Rotate ``x`` (N, heads, dim) by ``positions`` (N,) over the pairs ``(i, i
-    + dim / 2)`` (the source's ``rotate_half``), float32."""
-    half = x.shape[-1] // 2
-    angle = positions.astype(F32)[:, None, None] * (1.0 / theta ** (jnp.arange(half, dtype=F32) / half))
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    a, b = x[..., :half].astype(F32), x[..., half:].astype(F32)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 
 # ------------------------------------------------------------------ attention
@@ -211,17 +184,19 @@ def attention_prefill(c: SdarMoeConfig, ap, u, *, interpret: Optional[bool] = No
 
 
 def attention_pass(c: SdarMoeConfig, ap, u, k_pool, v_pool, *, layer: int, table, page, offset, positions, valid_len,
-                   attend):
+                   interpret: Optional[bool]):
     """One pass over every slot's open block: ``u`` (S x B, E), slot-major.  The
     block's K and V go to ``(page, offset .. offset + B)`` of the pool's
     ``layer`` (a block never straddles a page; the null page for a slot that
     may not write), FIRST: every query of the block then sees exactly
     ``valid_len`` = block start + B positions, which is the block mask, and
-    ``attend(q, k_pool, v_pool, table, valid_len, layer=, scale=)`` is the
-    decode attention any model's step calls.  Its queries are a slot's ``B x
-    H`` rows laid out so that the ``B x H / KV`` rows of one key head lie
-    together, which the ``paged_decode`` kernel takes as a group: no change to
-    the kernel.  Returns the output (S x B, E) and both pools."""
+    ``kernels.paged_decode`` (``interpret``: the kernel's flag, or None for its
+    XLA leg) is the decode attention any model's step calls.  Its queries are a
+    slot's ``B x H`` rows laid out so that the ``B x H / KV`` rows of one key
+    head lie together, which the kernel takes as a group: no change to the
+    kernel.  Returns the output (S x B, E) and both pools."""
+    from ..kernels.paged_attention import paged_decode
+
     S, B = page.shape[0], c.block_length
     H, KV, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
     q, k, v = _qkv(c, ap, u, positions.reshape(S * B))
@@ -229,7 +204,7 @@ def attention_pass(c: SdarMoeConfig, ap, u, k_pool, v_pool, *, layer: int, table
     k_pool = k_pool.at[where].set(k.reshape(S, B, KV, hd).astype(k_pool.dtype))
     v_pool = v_pool.at[where].set(v.reshape(S, B, KV, hd).astype(v_pool.dtype))
     grouped = q.reshape(S, B, KV, H // KV, hd).transpose(0, 2, 1, 3, 4).reshape(S, B * H, hd)
-    y = attend(grouped, k_pool, v_pool, table, valid_len, layer=layer, scale=hd ** -0.5)
+    y = paged_decode(grouped, k_pool, v_pool, table, valid_len, layer=layer, scale=hd ** -0.5, interpret=interpret)
     y = y.reshape(S, KV, B, H // KV, hd).transpose(0, 2, 1, 3, 4).reshape(S * B, H * hd)
     return _mm(y, ap["o_proj"], c.dtype), k_pool, v_pool
 
@@ -241,10 +216,8 @@ def expert_layer(c: SdarMoeConfig, ep, h, token_mask=None):
     and their gates are a softmax over those eight (the source's softmax over
     all, top-k, renormalised: the same numbers); no shared expert.  Returns the
     sum (N, E) float32 and how many tokens each held expert got (held,)."""
-    scores = jnp.dot(h.astype(F32), ep["router"].astype(F32), precision=jax.lax.Precision.HIGHEST)
-    idx, gates = route_topk(scores, c.num_experts_per_tok)
-    return dropless_experts(h, idx, gates, ep["w_gate"], ep["w_up"], ep["w_down"], first_held=c.first_expert_held,
-                            token_mask=token_mask, dtype=c.dtype)
+    return routed_experts(h, ep["router"], lambda scores: route_topk(scores, c.num_experts_per_tok), ep["w_gate"],
+                          ep["w_up"], ep["w_down"], first_held=c.first_expert_held, token_mask=token_mask, dtype=c.dtype)
 
 
 def _after_attention(c: SdarMoeConfig, lp, x, y, token_mask):
@@ -277,22 +250,11 @@ def layer_pass(c: SdarMoeConfig, lp, x, live, attention_step):
 
 
 # ------------------------------------------------------- from logits to a block
-def logit_stats(logits):
-    """Of rows of logits ``(N, vocab)``, what a selection asks of each: ``top``
-    (the largest logit), ``best`` (its id, int32: a tie goes to the lowest id)
-    and ``denominator = sum(exp(logit - top))`` over the whole vocabulary, the
-    softmax's: the probability of ``best`` is its inverse."""
-    top = jnp.max(logits, axis=-1)
-    return top, jnp.argmax(logits, axis=-1).astype(jnp.int32), jnp.sum(jnp.exp(logits - top[:, None]), axis=-1)
-
-
 def head_stats(c: SdarMoeConfig, params, x, interpret: Optional[bool]):
-    """:func:`logit_stats` of the head's rows over ``x`` (N, E), on either leg:
-    ``interpret`` None the XLA leg (the logits ``(N, vocab)`` are made, and read
-    three times), else the ``head_select`` kernel's flag (the same product, tile
-    by tile of the vocabulary, and no logits)."""
-    if interpret is None:
-        return logit_stats(head(c, params, x))
+    """Of the rows of logits :func:`head` makes of ``x`` (N, E), what a
+    selection asks of each (``kernels.head_select``: the largest logit, its id,
+    the softmax's denominator): ``interpret`` None the XLA leg (the logits are
+    made, and read three times), else the kernel's flag (no logits)."""
     from ..kernels.head_select import head_select
 
     return head_select(rmsnorm(x, params["norm"]["weight"], c.rms_norm_eps).astype(c.dtype),
@@ -303,7 +265,7 @@ def unmask(c: SdarMoeConfig, best, denominator, ids, masked, passes, may_reveal)
     """The static low-confidence schedule's one step over blocks ``ids`` (S, B)
     of which ``masked`` (S, B) are still to decide, at their ``passes``
     (S,)-th denoising pass: ``best`` (S, B) every position's greedy token and
-    ``denominator`` (S, B) its softmax's (:func:`logit_stats`: the position's
+    ``denominator`` (S, B) its softmax's (:func:`head_stats`: the position's
     confidence, the softmax probability of that token over the whole
     vocabulary, is its inverse); of a slot's masked positions the ``B / T`` most
     confident (the first ``B mod T`` passes one more; never more than are
@@ -346,15 +308,10 @@ def decode_kernels(config: SdarMoeConfig, cache) -> Dict[str, Any]:
     """The pass's kernels, latched at build: ``{"decode": the ``interpret`` flag
     of ``paged_decode``, "head_select": that of ``head_select`` over the open
     rows of every slot, or None for the XLA leg}``."""
-    from .. import kernels as _kernels
-    from ..kernels import head_select as _head
-    from ..kernels import paged_attention as _paged
+    from ..kernels import head_select, paged_attention
 
-    rows = cache.num_slots * config.block_length
-    return {"decode": _kernels.resolve("paged_decode", supported=lambda interp: _paged.supports(
-                cache.k.data.dtype, config.num_key_value_heads, config.head_dim, interpret=interp)),
-            "head_select": _kernels.resolve("head_select", supported=lambda interp: _head.supports(
-                config.dtype, rows, config.hidden_size, interpret=interp))}
+    return {"decode": paged_attention.leg(cache.k.data.dtype, config.num_key_value_heads, config.head_dim),
+            "head_select": head_select.leg(config.dtype, cache.num_slots * config.block_length, config.hidden_size)}
 
 
 def block_schedule(config: SdarMoeConfig):
@@ -419,7 +376,7 @@ def serve_decode(c: SdarMoeConfig, params, arrays, table, lengths, tokens, *, ac
     block's final K and V among them (a layer writes before it attends), and
     head, confidence and selection run on the open rows alone (:func:`head_stats`
     on the leg ``kernels["head_select"]`` names: on the kernel's NO LOGITS ARE
-    MADE, on the XLA leg's they are the program's temporary).  To ``attend``
+    MADE, on the XLA leg's they are the program's temporary).  To the attention
     the places are C more slots of the same call (the slot's own table row; an
     unused place has length 0), and their rows route to no expert.  A ``FUSED``
     that finds something masked, or every place taken, is an ``OWN_PASS``.
@@ -432,13 +389,7 @@ def serve_decode(c: SdarMoeConfig, params, arrays, table, lengths, tokens, *, ac
     rows, commits that rode in a place) of the slots moved}`` and the cache's
     arrays; a slot whose pass found nothing masked has committed, and its state
     is a fresh block; a slot that fused has the block after it, one pass on."""
-    from ..kernels import paged_attention as _paged
     from ..serve.engine import BlockSchedule
-
-    def attend(q, kd, vd, table, valid_len, *, layer, scale):
-        if kernels["decode"] is not None:
-            return _paged.paged_decode(q, kd, vd, table, valid_len, layer=layer, scale=scale, interpret=kernels["decode"])
-        return paged_attention_xla(q, kd, vd, table, valid_len, layer=layer, scale=scale)
 
     B, S = c.block_length, lengths.shape[0]
     C = block_schedule(c).commit_places(S)
@@ -473,7 +424,7 @@ def serve_decode(c: SdarMoeConfig, params, arrays, table, lengths, tokens, *, ac
         lp = params[f"layers_{l}"]
         step = lambda u, lp=lp, l=l: attention_pass(c, lp["self_attn"], u, kd, vd, layer=l, table=tables, page=page,
                                                     offset=offset, positions=positions, valid_len=valid_len,
-                                                    attend=attend)
+                                                    interpret=kernels["decode"])
         x, kd, vd, n = layer_pass(c, lp, x, live, step)
         experts.append(n)
     hidden = x[: S * B]

@@ -78,13 +78,13 @@ biases) stay as they are for training; ROADMAP D4 moves them here.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["route_topk", "route_group_limited", "route_sigmoid_topk", "dropless_experts", "padded_candidate", "fits_pad", "expert_form",
-           "grouped_leg", "DENSE_MAX_TOKENS", "ROW_PAD", "PADDED_MIN_MEAN_ROWS", "PADDED_MAX_MEAN_ROWS"]
+__all__ = ["route_topk", "route_group_limited", "route_sigmoid_topk", "dropless_experts", "routed_experts", "padded_candidate",
+           "fits_pad", "expert_form", "grouped_leg", "DENSE_MAX_TOKENS", "ROW_PAD", "PADDED_MIN_MEAN_ROWS", "PADDED_MAX_MEAN_ROWS"]
 
 # one row tile of the MXU: up to here each touched expert costs a grouped product a tile, sorted or not
 DENSE_MAX_TOKENS = 128
@@ -185,6 +185,19 @@ def dropless_experts(x, idx, gates, w_gate, w_up, w_down, *, first_held: int = 0
     dtype = w_gate.dtype if dtype is None else dtype
     return _experts(x, idx, gates, w_gate, w_up, w_down, token_mask, first_held=first_held, dtype=dtype, form=form,
                     grouped=grouped_leg(dtype, d, f) if form == SORTED else None)
+
+
+def routed_experts(h, router, route: Callable, w_gate, w_up, w_down, *, first_held: int = 0,
+                   token_mask: Optional[jax.Array] = None, dtype=None):
+    """A routed expert layer from its tokens on: the router's scores of ALL
+    experts, ``h`` (N, d) on ``router`` (d, E) in float32 at the highest
+    precision; ``route(scores)``, one of this module's routing rules with its
+    numbers bound; :func:`dropless_experts`, whose other arguments these are.
+    Returns ``(result, counts, *what route gave beside ids and gates)``."""
+    scores = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+    idx, gates, *own = route(scores)
+    return (*dropless_experts(h, idx, gates, w_gate, w_up, w_down, first_held=first_held, token_mask=token_mask,
+                              dtype=dtype), *own)
 
 
 # jitted inside its caller's program: a model's layers have one shape, so the layer is traced and lowered once a

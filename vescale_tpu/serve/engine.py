@@ -747,23 +747,9 @@ class ServeEngine(DecodeAhead):
 
         self._commit_fn = jax.jit(prefill_commit, donate_argnums=(0, 1))
 
-        def paged_attention(q, kd, vd, layer, table, valid_len):
-            # q (S,H,hd); kd/vd (L,N,page,KV,hd); table (S,Pmax); valid (S,)
-            ks = jnp.take(kd[layer], table, axis=0).reshape(S, Tmax, KV, hd)
-            vs = jnp.take(vd[layer], table, axis=0).reshape(S, Tmax, KV, hd)
-            g = H // KV
-            qg = (q.astype(jnp.float32) * scale).reshape(S, KV, g, hd)
-            s = jnp.einsum("skgd,stkd->skgt", qg, ks.astype(jnp.float32))
-            mask = jnp.arange(Tmax, dtype=jnp.int32)[None, :] < valid_len[:, None]
-            s = jnp.where(mask[:, None, None, :], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("skgt,stkd->skgd", p, vs.astype(jnp.float32))
-            return o.reshape(S, H * hd).astype(dtype)
-
         # ---- kernel dispatch (latched at build: the decode program is
         # compiled once; VESCALE_KERNELS is read here, not per step).  Unset,
         # paged_decode is the compiled kernel on TPU and the XLA leg elsewhere
-        from .. import kernels as _kernels
         from ..kernels import paged_attention as _paged
 
         # mesh axis sharding the pool's kv-head dim (dim 3 of the 5-D cache
@@ -773,22 +759,16 @@ class ServeEngine(DecodeAhead):
             if p.is_shard(3) and self.mesh.shape[i] > 1:
                 kernel_shard_ax, kv_local = self.mesh.mesh_dim_names[i], KV // self.mesh.shape[i]
                 break
-        kernel_interpret = _kernels.resolve(
-            "paged_decode",
-            supported=lambda interpret: _paged.supports(cache.k.data.dtype, kv_local, hd, interpret=interpret))
+        kernel_interpret = _paged.leg(cache.k.data.dtype, kv_local, hd)
         self.kernel_decode = kernel_interpret is not None
+        kernel_shard_ax = kernel_shard_ax if self.kernel_decode else None   # (the compiler partitions the XLA leg itself)
 
-        def paged_attention_kernel(q, kd, vd, layer, table, valid_len):
-            # kd/vd: the WHOLE (L, N, page, KV, hd) pools; the kernel reads
-            # only ``layer``'s live pages out of them
+        def attend(q, kd, vd, layer, table, valid_len):
+            # q (S,H,hd); kd/vd: the WHOLE (L, N, page, KV, hd) pools (the kernel reads only ``layer``'s live
+            # pages out of them, the XLA leg gathers every slot's); table (S,Pmax); valid_len (S,)
             from ..collectives import shard_map
 
-            def body(q_l, kd_l, vd_l, table_l, len_l):
-                return _paged.paged_decode(
-                    q_l, kd_l, vd_l, table_l, len_l,
-                    layer=layer, scale=scale, interpret=kernel_interpret,
-                )
-
+            body = lambda *local: _paged.paged_decode(*local, layer=layer, scale=scale, interpret=kernel_interpret)
             if kernel_shard_ax is None:
                 out = body(q, kd, vd, table, valid_len)
             else:
@@ -803,8 +783,6 @@ class ServeEngine(DecodeAhead):
                     axis_names=frozenset({ax}),
                 )(q, kd, vd, table, valid_len)
             return out.reshape(S, H * hd).astype(dtype)
-
-        attend = paged_attention_kernel if self.kernel_decode else paged_attention
 
         def decode(params, kd, vd, table, lengths, tokens):
             x = embed(params, tokens)  # (S, E)

@@ -44,9 +44,10 @@ gives, as plain functions of the config:
       the ladder's first rung (a scan's chunk, a flash block);
   ``decode_kernels(config, cache)``
       ``{kernel name: its interpret flag, or None for the XLA leg}``, resolved
-      once at build (the program latches it); ``engine.kernel_<name>`` says
-      which leg each took, and ``decode_pages_*`` count with the kernel named
-      ``decode`` (the paged attention's);
+      once at build (the program latches it) by each kernel's own ``leg(...)``
+      (a kernel owns its XLA leg and the choice; ``serve_decode`` hands the flag
+      to its op); ``engine.kernel_<name>`` says which leg each took, and
+      ``decode_pages_*`` count with the kernel named ``decode``;
   ``serve_prefill(config, params, arrays, tokens, length, page_row, slot, *,
   page, interpret)`` -> ``(logits row, arrays)``
   ``serve_decode(config, params, arrays, table, lengths, tokens, *, active,

@@ -202,8 +202,8 @@ def write_pages(pool, rows, page_row, page: int):
     slab of all layers a page, updated in place.  (One scatter over the page
     axis makes the compiler re-lay out the WHOLE pool and back around it where
     a row of the pool is 4 heads wide, 3.7 ms a copy at a pool of 1.2 GB:
-    PERF.md section 6, PR 43.)  The prefill programs of ``models/falcon_h1.py``
-    and ``models/sdar_moe.py`` write through it; a family whose compiled rung
+    PERF.md section 6, PR 43.)  The prefill programs of ``models/falcon_h1.py``,
+    ``models/sdar_moe.py`` and ``models/laguna.py`` write through it; a family whose compiled rung
     holds no such copy (pools with rows of 8 or 32 heads, or the latent form's
     one row) keeps its scatter, which has no loop to run (PERF.md section 6,
     PR 44).  Entries of ``page_row`` past a slot's reserved pages name page 0,
