@@ -1,4 +1,4 @@
-"""The serve programs of the five families behind ``HybridServeEngine`` are WHAT
+"""The serve programs of the six families behind ``HybridServeEngine`` are WHAT
 THEIR FUNCTIONS COMPUTE, not where those are written: each family's prefill at
 its first two rungs and its decode step, at the toy widths of the family's own
 test file in the type they are served in (bfloat16), on both legs
@@ -12,7 +12,8 @@ source location), and how many operations of the lowered module lie under each
 jaxpr's text.  Taken on the parent of the PR that gave the shared blocks one
 home each (e1809cb: this file on that tree, ``PROGRAMS`` printed by ``python
 tests/test_program_identity.py``).  A PR that changes these programs on purpose
-takes them anew."""
+takes them anew.  (``mimo_v2``'s six were taken on the tree of the PR that brought
+the family, PR 50: they pin it from there on.)"""
 
 import collections
 import dataclasses
@@ -27,6 +28,7 @@ import test_deepseek_v2
 import test_falcon_h1
 import test_granite_hybrid
 import test_laguna
+import test_mimo_v2
 import test_sdar_moe
 from vescale_tpu.mesh import DeviceMesh
 from vescale_tpu.moe import dropless
@@ -34,7 +36,7 @@ from vescale_tpu.serve import HybridServeEngine, PagedKVCache
 from vescale_tpu.serve.hybrid_engine import hybrid_cache_config
 
 FAMILIES = {"granite_hybrid": test_granite_hybrid, "deepseek_v2": test_deepseek_v2, "sdar_moe": test_sdar_moe,
-            "falcon_h1": test_falcon_h1, "laguna": test_laguna}
+            "falcon_h1": test_falcon_h1, "laguna": test_laguna, "mimo_v2": test_mimo_v2}
 LEGS = {"xla_legs": None, "kernels_interpreted": "interpret"}
 WHICH = ("prefill_rung_1", "prefill_rung_2", "decode")
 
@@ -69,6 +71,12 @@ PROGRAMS = {
     "laguna/kernels_interpreted/prefill_rung_1": ('542d10a1a7587958', 'vs.attn=3174 vs.mlp=9 vs.moe=108'),
     "laguna/kernels_interpreted/prefill_rung_2": ('386a8d3468798b00', 'vs.attn=3174 vs.mlp=9 vs.moe=108'),
     "laguna/kernels_interpreted/decode": ('6b12595f0b880b74', 'vs.attn=625 vs.mlp=9 vs.moe=108'),
+    "mimo_v2/xla_legs/prefill_rung_1": ('db2e012b49ba659a', 'vs.attn=990 vs.mlp=9 vs.moe=132'),
+    "mimo_v2/xla_legs/prefill_rung_2": ('4b302c23a9b49776', 'vs.attn=990 vs.mlp=9 vs.moe=132'),
+    "mimo_v2/xla_legs/decode": ('46088ea8d8664d65', 'vs.attn=1257 vs.mlp=9 vs.moe=216'),
+    "mimo_v2/kernels_interpreted/prefill_rung_1": ('e9350405c3a75b3e', 'vs.attn=4916 vs.mlp=9 vs.moe=132'),
+    "mimo_v2/kernels_interpreted/prefill_rung_2": ('617bfcc9ff21ef45', 'vs.attn=4916 vs.mlp=9 vs.moe=132'),
+    "mimo_v2/kernels_interpreted/decode": ('e1b4216a59e292bd', 'vs.attn=931 vs.mlp=9 vs.moe=216'),
 }
 
 

@@ -374,6 +374,65 @@ def test_lagunas_decode_program_and_a_rung_compile_at_the_cells_size_and_copy_no
         assert not [line for line in compiled.as_text().splitlines() if " copy(" in line and f"= {ring}" in line]
 
 
+@pytest.mark.parametrize("program,kernels_in_it", [("decode step", 13), ("rung of 512 positions", 13)], ids=["decode", "rung512"])
+def test_mimos_decode_program_and_a_rung_compile_at_the_cells_size_with_no_copy_and_no_padding_of_a_pool(chip, program, kernels_in_it):
+    """``mimov25_serve_reasoning``'s decode step (256 slots: two
+    ``paged_decode_kv4`` over the folded pages, five ``paged_decode_kv8`` with a
+    sink over the folded rings read as pages, six ``grouped_swiglu``) and the 512
+    rung of its prefill ladder (two ``causal_flash_fwd`` at 192 | 128, five
+    ``window_flash_fwd`` with a sink, six ``grouped_swiglu``).  Neither holds a
+    copy of a pool of either kind, and the chip lays the folded rows out WITHOUT
+    padding: the program's arguments are the weights' and the cache's logical
+    bytes (5,120 B a position in the pages, 3.28 MB a slot in the rings), where
+    rows of (4, 192) would be padded to 256 lanes a head or turned round."""
+    family, config, sizes, programs = _cells_programs(chip, "mimov25_serve_reasoning")
+    titles = [title for title, _ in programs]
+    assert sum("prefill, rung of" in t for t in titles) == 12 and "decode step, 256 slots x 8192 positions" in titles[-1]
+    assert sizes["weights_bytes"] == family.weight_bytes(config)
+    assert sizes["kv_pool_bytes"] == 23552 * 32 * 5120 and sizes["slot_state_bytes"] == 256 * 3276800
+    assert sizes["kv_pool_bytes"] + sizes["slot_state_bytes"] == family.cache_bytes(config, config["serve"])
+    (lowered,) = [low for title, low in programs if program in title]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    kernel_calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernel_calls) == kernels_in_it and sum("grouped_swiglu" in line for line in kernel_calls) == 6
+    if "rung" in program:
+        assert sum("window_flash_fwd" in line for line in kernel_calls) == 5 and sum("causal_flash_fwd" in line for line in kernel_calls) == 2
+    else:
+        assert sum("paged_decode_kv8" in line for line in kernel_calls) == 5 and sum("paged_decode_kv4" in line for line in kernel_calls) == 2
+    _assert_in_place_and_fits(compiled, sizes, "bf16[2,23552,32,1,768]")        # 2.3 GB of keys
+    for pool in ("bf16[2,23552,32,1,512]", "bf16[2,23552,32,768]", "bf16[2,23552,32,512]", "bf16[5,256,128,1,1536]",
+                 "bf16[5,256,128,1,1024]", "bf16[5,1024,32,1,1536]", "bf16[5,1024,32,1,1024]", "bf16[5,1024,32,1536]",
+                 "bf16[5,1024,32,1024]"):                                       # as the cache and as the kernel see them
+        assert not [line for line in text.splitlines() if " copy(" in line and f"= {pool}" in line]
+    # no pool padded past 5% of its logical bytes: the arguments are the weights, the cache and a few small arrays
+    assert compiled.memory_analysis().argument_size_in_bytes < 1.005 * sum(sizes.values())
+    for row in ("[2,23552,32,1,768]{4,2,3,1,0:T(8,128)(2,1)}", "[5,256,128,1,1536]{4,2,3,1,0:T(8,128)(2,1)}"):
+        assert f"bf16{row}" in text, "a folded row is the lanes and a page's positions the sublanes: whole tiles"
+
+
+# what a program outside its kernels' bodies lowers to, for a described v5e: a digest of the lowered text with every
+# kernel's serialized body taken out (it holds the checkout's path and the kernel's line numbers; the bodies' own identity
+# is the jaxpr digests of tests/test_program_identity.py).  Taken on the parent of the PR that gave ``paged_decode`` a
+# folded sibling and the flash forward a sink and narrower values (8655180, this function on that tree).
+LOWERED_BEFORE_FOLDED_POOLS = {"decode step": "34098600aed73bdf", "rung of 512 positions": "bf0426431e3c2841"}
+
+
+@pytest.mark.parametrize("program", list(LOWERED_BEFORE_FOLDED_POOLS), ids=["decode", "rung512"])
+def test_an_existing_cells_programs_lower_to_the_text_they_had(chip, program):
+    """``lagunaxs2_serve_mixedlen``'s decode step (``paged_decode`` over pages and
+    rings of ONE width) and its 512 rung (the causal and the windowed forward
+    without a sink): with ``sink=None``, ``Dv == D``, ``bias=None`` and
+    ``v_head_dim=None`` nothing of them changed."""
+    import hashlib
+    import re
+
+    _family, _config, _sizes, programs = _cells_programs(chip, "lagunaxs2_serve_mixedlen")
+    (lowered,) = [low for title, low in programs if program in title]
+    text = re.sub(r'(backend_config = ")[^\n]*', r"\1<kernel>", lowered.as_text())
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == LOWERED_BEFORE_FOLDED_POOLS[program]
+
+
 # ------------------------------------------------------------ fused adamw
 @pytest.mark.parametrize("shape", [(4096, 14336), (4097,)], ids=["ffn-leaf", "ragged-tail"])
 def test_fused_adamw_compiles(chip, shape):

@@ -38,6 +38,12 @@ Kernels in this package:
     head's score and, by its leading columns, the values, so a page is
     fetched once for both; for ``models/deepseek_v2.py`` under
     ``serve/hybrid_engine.py``, the default on TPU.
+  * ``paged_decode_folded`` — its sibling over FOLDED pools (keys of one
+    width beside values of another, neither a whole number of lane tiles a
+    head: a position's row is every key head's entries side by side), with
+    an optional sink logit a head in the softmax; for
+    ``models/mimo_v2.py`` under ``serve/hybrid_engine.py``, dispatched
+    under ``paged_decode``'s name, the default on TPU.
   * ``ssm_step``         — a state-space (Mamba-2) layer's decode step
     over every slot's recurrent state, in place: one read and one write of
     the state where XLA reads it twice (its XLA leg); for

@@ -26,6 +26,7 @@ __all__ = [
     "_NEG_INF",
     "BLOCK_MASK_NAME",
     "WINDOW_NAME",
+    "CAUSAL_NAME",
     "_use_streaming",
     "_flash_fwd_pallas",
     "_flash_bwd_pallas",
@@ -35,6 +36,7 @@ __all__ = [
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/where VPU-safe
 BLOCK_MASK_NAME = "block_flash_fwd"     # the forward under a block mask, as the device trace names it
 WINDOW_NAME = "window_flash_fwd"        # ... and under a sliding window
+CAUSAL_NAME = "causal_flash_fwd"        # ... and the causal forward whose values are narrower than its keys, or that has a sink
 
 
 # ------------------------------------------------------------------ forward
@@ -46,11 +48,20 @@ def _visible_to(q_pos, mask_block: int):
     return q_pos if mask_block == 1 else (q_pos // mask_block + 1) * mask_block - 1
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, block_k, seq_len, mask_block=1,
-                window=None):
+def _with_sink(m, l, acc, sink):
+    """The softmax's last normalisation with a SINK: one more column of logit
+    ``sink`` (a scalar a head) that takes mass and mixes no value, so it joins
+    the running maximum and the denominator, once, after the last key block."""
+    m_all = jnp.maximum(m, sink)
+    shrink = jnp.exp(m - m_all)
+    return m_all, l * shrink + jnp.exp(sink - m_all), acc * shrink[:, None]
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k, seq_len, mask_block=1, window=None, sink=False):
+    sink_ref, (o_ref, lse_ref) = (rest[0], rest[1:]) if sink else (None, rest)
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * scale  # (block_q, D)
-    D = q.shape[-1]
+    D = v_ref.shape[-1]                       # the values' width (the keys', but for a caller that says otherwise)
 
     nk_total = seq_len // block_k
     if causal:
@@ -87,6 +98,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, 
         return m_new, l_new, acc_new
 
     m, l, acc = jax.lax.fori_loop(first, nk, body, (m0, l0, acc0))
+    if sink:
+        m, l, acc = _with_sink(m, l, acc, sink_ref[0, 0, 0])
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
     # (1, block_q, 1) block: trailing singleton satisfies TPU tiling rules
@@ -144,10 +157,11 @@ def _use_streaming(kernel: str, T: int, D: int, dtype, block_q: int, block_k: in
     return _resident_vmem_bytes(kernel, T, D, dtype, block_q, block_k, rep) > _VMEM_LIMIT_BYTES
 
 
-def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                       *, scale, causal, block_q, block_k, seq_len, mask_block=1, window=None):
+def _fwd_kernel_stream(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k, seq_len, mask_block=1, window=None,
+                       sink=False):
     """Streaming forward: grid (BH, nq, nk) — k/v arrive one block per grid
     step; online-softmax state lives in VMEM scratch across the nk steps."""
+    sink_ref, (o_ref, lse_ref, m_scr, l_scr, acc_scr) = (rest[0], rest[1:]) if sink else (None, rest)
     qi = pl.program_id(1)
     j = pl.program_id(2)
     nk = seq_len // block_k
@@ -191,6 +205,12 @@ def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_sc
 
     @pl.when(j == nk - 1)
     def _final():
+        if sink:
+            m, l, acc = _with_sink(m_scr[:, 0], l_scr[:, 0], acc_scr[...], sink_ref[0, 0, 0])
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
+            lse_ref[0] = (m + jnp.log(l_safe))[:, None]
+            return
         l = l_scr[:, 0]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_scr[...] / l_safe[:, None]).astype(o_ref.dtype)
@@ -198,7 +218,7 @@ def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_sc
 
 
 def _flash_fwd_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret, H, KV,
-                      streaming=None, mask_block=1, window=None):
+                      streaming=None, mask_block=1, window=None, sink=None):
     """q3: (B*H, T, D); k3/v3: (B*KV, T, D) — GQA never materializes the
     repeated K/V heads; the BlockSpec index map routes each q head to its
     kv group (rows are consecutive per group, llama repeat convention).
@@ -208,8 +228,15 @@ def _flash_fwd_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret, H,
     window: a row sees the ``window`` newest positions, itself among them, and
     the key loop starts at the block that holds the oldest of them.  Both are
     static parameters: at their defaults the kernels are traced as they were
-    before either existed."""
+    before either existed.  FORWARD ONLY (no backward kernel knows them): ``v3``
+    may be (B*KV, T, Dv) with ``Dv`` another width than ``D`` (the output is
+    then (B*H, T, Dv)), and ``sink`` (H,) float32 is one logit a query head that
+    joins the softmax's maximum and denominator and mixes no value
+    (:func:`_with_sink`; the logsumexp counts it); without a window such a
+    forward is named ``CAUSAL_NAME``.  With ``Dv == D`` and no sink the kernels
+    are traced as they were."""
     BH, T, D = q3.shape
+    Dv = v3.shape[-1]
     rep = H // KV
     if streaming is None:
         streaming = _use_streaming("fwd", T, D, k3.dtype, block_q, block_k)
@@ -224,8 +251,16 @@ def _flash_fwd_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret, H,
         if not causal or mask_block != 1 or window < 1:
             raise ValueError(f"a window of {window} positions is causal, of 1 or more positions, and takes no block mask")
         kw["window"], name = int(window), WINDOW_NAME
+    if (sink is not None or Dv != D) and name is None:
+        name = CAUSAL_NAME
+    sinks, sink_specs = (), []
+    if sink is not None:
+        # a head's logit as a (1, 1, 1) block, by the query row's head
+        kw["sink"] = True
+        sinks = (jnp.tile(sink.astype(jnp.float32), BH // H).reshape(BH, 1, 1),)
+        sink_specs = [pl.BlockSpec((1, 1, 1), lambda b, *_: (b, 0, 0))]
     out_shape = (
-        jax.ShapeDtypeStruct(q3.shape, q3.dtype),
+        jax.ShapeDtypeStruct((BH, T, Dv), q3.dtype),
         jax.ShapeDtypeStruct((BH, T, 1), jnp.float32),
     )
     if streaming:
@@ -237,20 +272,21 @@ def _flash_fwd_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret, H,
             in_specs=[
                 pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
                 pl.BlockSpec((1, block_k, D), kv_row_s),
-                pl.BlockSpec((1, block_k, D), kv_row_s),
+                pl.BlockSpec((1, block_k, Dv), kv_row_s),
+                *sink_specs,
             ],
             out_specs=(
-                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
                 pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
             ),
             scratch_shapes=[
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
-                pltpu.VMEM((block_q, D), jnp.float32),
+                pltpu.VMEM((block_q, Dv), jnp.float32),
             ],
             interpret=interpret,
             name=name,
-        )(q3, k3, v3)
+        )(q3, k3, v3, *sinks)
     kv_row = lambda b, i: ((b // H) * KV + (b % H) // rep, 0, 0)
     grid = (BH, T // block_q)
     return pl.pallas_call(
@@ -260,15 +296,16 @@ def _flash_fwd_pallas(q3, k3, v3, scale, causal, block_q, block_k, interpret, H,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, T, D), kv_row),
-            pl.BlockSpec((1, T, D), kv_row),
+            pl.BlockSpec((1, T, Dv), kv_row),
+            *sink_specs,
         ],
         out_specs=(
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ),
         interpret=interpret,
         name=name,
-    )(q3, k3, v3)
+    )(q3, k3, v3, *sinks)
 
 
 # ------------------------------------------------- forward for a serve prefill

@@ -44,7 +44,7 @@ never wrote) reach nothing.  A slot of length 0 fetches nothing and its
 output is zeros.
 
 Each op takes the kernel's ``interpret`` flag or None for its XLA leg, and :func:`leg` /
-:func:`leg_latent` resolve that for a pool: a caller names its pool and chooses nothing.
+:func:`leg_latent` / :func:`leg_folded` resolve that for a pool: a caller names its pool and chooses nothing.
 """
 
 from __future__ import annotations
@@ -60,7 +60,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import kernels
 
 __all__ = ["paged_decode", "paged_attention_xla", "supports", "leg", "paged_decode_latent", "latent_attention_xla",
-           "supports_latent", "leg_latent"]
+           "supports_latent", "leg_latent", "paged_decode_folded", "folded_attention_xla", "supports_folded", "leg_folded"]
 
 _NEG_INF = -1e30
 _BLOCK_BYTES = 1 << 20       # of K in one block (V the same; two buffers each)
@@ -418,3 +418,212 @@ def paged_decode_latent(q, pool, table, lengths, *, layer, scale, latent, interp
         name="paged_decode_latent",
     )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.clip(lengths.astype(jnp.int32), 0, Pmax * page),
       table.astype(jnp.int32), q, pool.reshape(L, N, page, row))
+
+
+# ------------------------------------------------------------- the folded form
+# Keys of one width beside values of another, neither a whole number of 128-lane
+# tiles a head (192 | 128 on 4 or 8 key heads): a pool row ``(KV, 192)`` would be
+# laid out by the chip with 192 padded to 256 lanes, or transposed so that the
+# PAGES are the lanes (read on a described v5e: ``bf16[L, N, 32, 4, 192]`` gets
+# the layout ``{1,4,3,2,0}``), and either way a page is no longer one contiguous
+# copy.  So the pools are FOLDED: a position's row holds every key head's
+# entries side by side, ``(L, N, page, 1, KV x Dk)`` and ``(L, N, page, 1, KV x
+# Dv)`` (768 | 512 and 1,536 | 1,024 lanes: whole tiles, no padding, a page one
+# DMA).  Nothing is sliced at a half tile: the query rows are SPREAD over the
+# folded width instead (row ``h`` keeps its entries in its key head's lanes and
+# zeros elsewhere; the spreading is one small product with a 0 / 1 matrix, once
+# a slot), so one product of ``(H, KV x Dk)`` against the block's rows gives
+# every head's scores, and the mix of the block's folded values is cut back a
+# key head at a time at whole-tile offsets.  The MXU does ``KV`` times the
+# products a head needs, as :func:`paged_decode` does; the step is the pages'
+# read.  A ``sink`` (one learned logit a query head: a column of the softmax
+# that takes mass and gives no value) joins the running maximum and the
+# denominator once, after the slot's last block.
+def supports_folded(pool_dtype, kv_heads: int, dk: int, dv: int, page: int, *, interpret: bool) -> bool:
+    """Whether :func:`paged_decode_folded` takes pools of this dtype whose rows
+    fold ``kv_heads`` heads of ``dk`` (keys) and ``dv`` (values): compiled, both
+    folded widths and ``dv`` are whole 128-lane tiles and a page a whole sublane
+    tile of the dtype."""
+    dt = jnp.dtype(pool_dtype)
+    if dt not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)) or min(kv_heads, dk, dv) < 1:
+        return False
+    return interpret or ((kv_heads * dk) % 128 == 0 and dv % 128 == 0 and page % (32 // dt.itemsize) == 0)
+
+
+def leg_folded(pool_dtype, kv_heads: int, dk: int, dv: int, page: int) -> Optional[bool]:
+    """The leg :func:`paged_decode_folded` takes over such pools: the kernel's ``interpret`` flag, or None."""
+    return kernels.resolve("paged_decode", supported=lambda interpret: supports_folded(pool_dtype, kv_heads, dk, dv, page,
+                                                                                      interpret=interpret))
+
+
+def _folded_heads(q, k_pool, v_pool):
+    S, H, dk = q.shape
+    L, N, page, one, wk = k_pool.shape
+    kv = wk // dk
+    if (one != 1 or wk != kv * dk or H % max(kv, 1) or v_pool.shape[:4] != k_pool.shape[:4] or v_pool.shape[4] % kv
+            or v_pool.dtype != k_pool.dtype or q.dtype != k_pool.dtype):
+        raise ValueError(f"paged_decode_folded: q {q.shape} {q.dtype} against folded pools {k_pool.shape} / {v_pool.shape} "
+                         f"{k_pool.dtype}")
+    return kv, v_pool.shape[4] // kv
+
+
+def folded_attention_xla(q, k_pool, v_pool, table, valid_len, *, layer: int, scale: float, sink=None):
+    """:func:`paged_decode_folded` without the kernel: gather every slot's pages,
+    mask by length, float32 softmax with the sink as one more column.  Products
+    on operands of the pools' type with float32 accumulation, as the kernel."""
+    S, H, dk = q.shape
+    kv, dv = _folded_heads(q, k_pool, v_pool)
+    ks = jnp.take(k_pool[layer], table, axis=0).reshape(S, -1, kv, dk)
+    vs = jnp.take(v_pool[layer], table, axis=0).reshape(S, -1, kv, dv)
+    mask = jnp.arange(ks.shape[1], dtype=jnp.int32)[None, :] < valid_len[:, None]
+    vs = jnp.where(mask[:, :, None, None], vs, jnp.zeros_like(vs))     # stale bytes past the length reach nothing
+    s = scale * jnp.einsum("skgd,stkd->skgt", q.reshape(S, kv, H // kv, dk), ks, preferred_element_type=jnp.float32)
+    s = jnp.where(mask[:, None, None, :], s, _NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    column = None if sink is None else sink.astype(jnp.float32).reshape(1, kv, H // kv, 1)
+    if column is not None:
+        m = jnp.maximum(m, column)
+    p = jnp.where(mask[:, None, None, :], jnp.exp(s - m), 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True) + (0.0 if column is None else jnp.exp(column - m))
+    o = jnp.einsum("skgt,stkd->skgd", p.astype(vs.dtype), vs, preferred_element_type=jnp.float32)
+    return (o / jnp.where(l == 0.0, 1.0, l)).reshape(S, H, dv)
+
+
+def _folded_kernel(layer_ref, len_ref, table_ref, q_ref, spread_ref, *rest, scale, page, block_pages, kv_heads, group, dk, dv,
+                   has_sink):
+    """Grid (S,), sequential; the buffers alternate over the whole call as in ``_decode_kernel``."""
+    sink_ref, (k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, cur_ref, m_scr, l_scr, acc_scr) = (
+        (rest[0], rest[1:]) if has_sink else (None, rest))
+    s = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    H = kv_heads * group
+    T = block_pages * page
+    layer = layer_ref[0]
+
+    def block_dma(slot, block, buf, wait):
+        first = block * block_pages
+        live = jnp.clip(pl.cdiv(len_ref[slot], page) - first, 0, block_pages)
+
+        def one_page(i, carry):
+            phys = table_ref[slot, first + i]
+            for hbm, vmem, sem in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                copy = pltpu.make_async_copy(hbm.at[layer, phys], vmem.at[buf, i], sems.at[buf, sem])
+                copy.wait() if wait else copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, live, one_page, 0)
+
+    length = len_ref[s]
+    n_blocks = jnp.maximum(pl.cdiv(length, T), 1)
+
+    @pl.when(s == 0)
+    def _first():
+        cur_ref[0] = 0
+        block_dma(0, 0, 0, wait=False)
+
+    m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+    row_head = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // group          # the key head of each query row
+    # the query rows over the folded width: a copy of the row in every key head's lanes (exact: ones and zeros), kept
+    # in its own head's alone
+    lane_head = jax.lax.broadcasted_iota(jnp.int32, (H, kv_heads * dk), 1) // dk
+    q = jax.lax.dot_general(q_ref[0], spread_ref[...], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    q = jnp.where(lane_head == row_head, q, 0.0).astype(k_buf.dtype)           # (H, KV x Dk)
+
+    def block_body(b, cur):
+        nxt = 1 - cur
+
+        @pl.when(b + 1 < n_blocks)
+        def _():
+            block_dma(s, b + 1, nxt, wait=False)
+
+        @pl.when(jnp.logical_and(b + 1 == n_blocks, s + 1 < n_slots))
+        def _():
+            block_dma(s + 1, 0, nxt, wait=False)
+
+        block_dma(s, b, cur, wait=True)
+        keys = k_buf[cur].reshape(T, kv_heads * dk)
+        sc = scale * jax.lax.dot_general(q, keys, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)   # (H, T)
+        valid = (b * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)) < length
+        sc = jnp.where(valid, sc, _NEG_INF)             # (a stale key's NaN goes with it)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        pexp = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
+        m_scr[...] = m_new
+        # values past the length are stale pool bytes, or VMEM a skipped DMA never wrote: zeroed
+        values = v_buf[cur].reshape(T, kv_heads * dv)
+        keep = (b * T + jax.lax.broadcasted_iota(jnp.int32, (T, kv_heads * dv), 0)) < length
+        mixed = jax.lax.dot_general(pexp.astype(values.dtype), jnp.where(keep, values, jnp.zeros_like(values)),
+                                    (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)   # (H, KV x Dv)
+        pv = jnp.zeros(acc_scr.shape, jnp.float32)
+        for k in range(kv_heads):                       # a row's own key head's lanes: whole tiles
+            pv = jnp.where(row_head == k, mixed[:, k * dv:(k + 1) * dv], pv)
+        acc_scr[...] = acc_scr[...] * alpha + pv
+        return nxt
+
+    cur_ref[0] = jax.lax.fori_loop(0, n_blocks, block_body, cur_ref[0])
+    m, l, acc = m_scr[...], l_scr[...], acc_scr[...]
+    if has_sink:
+        # one more column, of no value: it joins the maximum and the denominator (a slot of length 0: all of the mass)
+        m_all = jnp.maximum(m, sink_ref[...])
+        shrink = jnp.exp(m - m_all)
+        l, acc = l * shrink + jnp.exp(sink_ref[...] - m_all), acc * shrink
+    o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+@kernels.with_xla_leg(folded_attention_xla, static_argnames=("scale", "interpret"))
+def paged_decode_folded(q, k_pool, v_pool, table, lengths, *, layer, scale, sink=None, interpret):
+    """One decode-step attention of one layer over FOLDED pools: keys of one
+    width, values of another, an optional sink.
+
+    ``q``: (S, H, Dk) in the pools' type; ``k_pool``: (L, N, page, 1, KV x Dk)
+    and ``v_pool``: (L, N, page, 1, KV x Dv), every layer, a position's row
+    every key head's entries side by side (query head ``h`` reads key head ``h
+    // (H / KV)``); ``sink``: None or (H,) float32, one logit a query head that
+    enters the softmax's maximum and denominator and mixes no value;
+    ``table``, ``lengths``, ``layer``, ``interpret`` as :func:`paged_decode`
+    takes them (None: the XLA leg; :func:`leg_folded` resolves it).  Returns
+    float32 (S, H, Dv): ``sum_t p_t v_t`` with ``p_t = exp(s_t - m) / (sum_t
+    exp(s_t - m) + exp(sink - m))`` over the slot's first ``lengths`` positions
+    (zeros for a length of 0, with a sink or without).  The kernel is named by
+    its key heads in a device trace (``paged_decode_kv4``)."""
+    S, H, dk = q.shape
+    kv, dv = _folded_heads(q, k_pool, v_pool)
+    L, N, page = k_pool.shape[:3]
+    if not supports_folded(k_pool.dtype, kv, dk, dv, page, interpret=bool(interpret)):
+        raise ValueError(f"paged_decode_folded takes no {k_pool.dtype} pools of {kv} heads x {dk} | {dv} in pages of {page} "
+                         "(see supports_folded())")
+    Pmax = table.shape[1]
+    bp = _block_pages(Pmax, page, kv, dk, jnp.dtype(k_pool.dtype).itemsize)
+    spread = jnp.tile(jnp.eye(dk, dtype=k_pool.dtype), (1, kv))                # (Dk, KV x Dk): a row into every head's lanes
+    whole = lambda *shape: pl.BlockSpec(shape, lambda s, *_: (0,) * len(shape))
+    sinks = () if sink is None else (sink.astype(jnp.float32).reshape(H, 1),)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[pl.BlockSpec((1, H, dk), lambda s, *_: (s, 0, 0)), whole(dk, kv * dk), *(whole(H, 1) for _ in sinks),
+                  pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, H, dv), lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, bp, page, kv * dk), k_pool.dtype),
+            pltpu.VMEM((2, bp, page, kv * dv), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, dv), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_folded_kernel, scale=float(scale), page=page, block_pages=bp, kv_heads=kv, group=H // kv, dk=dk,
+                          dv=dv, has_sink=sink is not None),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, H, dv), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=f"paged_decode_kv{kv}",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.clip(lengths.astype(jnp.int32), 0, Pmax * page),
+      table.astype(jnp.int32), q, spread, *sinks, k_pool.reshape(L, N, page, kv * dk), v_pool.reshape(L, N, page, kv * dv))

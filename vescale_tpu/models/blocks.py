@@ -1,7 +1,8 @@
 """What more than one model family behind ``serve.HybridServeEngine`` is built
 of: the norm, the product, the SwiGLU, the plain rotary term, YaRN's
-frequencies, a new position's write into its pools, and one rule of the random
-weights they are served with.  Plain functions of arrays and numbers: none
+frequencies, a new position's write into its pools, where a ring of the newest
+``window`` positions keeps a position (two families mix window and full
+attention), and one rule of the random weights they are served with.  Plain functions of arrays and numbers: none
 reads a config, so none knows its caller.  A family's file holds what is its
 own (its mixers' projections, its routing rule, its cache, ``embed``, ``head``)
 and imports from here, ``models/mamba2.py``, ``kernels/`` and ``moe/``; no
@@ -11,12 +12,14 @@ family imports another.
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["F32", "ROUTED_DOWN_GAIN", "rmsnorm", "swiglu", "rotary", "yarn_mscale", "yarn_inv_freq", "write_position"]
+__all__ = ["F32", "ROUTED_DOWN_GAIN", "rmsnorm", "swiglu", "rotary", "yarn_mscale", "yarn_inv_freq", "write_position", "ring_row",
+           "ring_source", "window_pairs"]
 
 F32 = jnp.float32
 # Of a family's ``init_params`` with routed experts.  Random weights of variance 1 / fan-in make every expert's
@@ -83,3 +86,26 @@ def write_position(k_store, v_store, k, v, where):
     page, offset)`` of the pools, a slot's row of a ring), rounded to the
     stores' type.  A layer writes before it attends.  Returns both stores."""
     return k_store.at[where].set(k.astype(k_store.dtype)), v_store.at[where].set(v.astype(v_store.dtype))
+
+
+def ring_row(positions, window: int):
+    """The ring row that holds position ``p``: ``p mod window``."""
+    return positions % window
+
+
+def ring_source(length, rung: int, window: int):
+    """For each ring row ``r`` (window,), the position of a prefilled prompt of
+    ``length`` tokens (on a rung of ``rung`` positions) that it holds: the
+    NEWEST real position ``p < length`` with ``ring_row(p) = r``.  A row that no
+    real position falls on (a prompt shorter than the window) names position 0:
+    no decode step reads it before it is written."""
+    r = jnp.arange(window, dtype=jnp.int32)
+    newest = r + window * ((length - 1 - r) // window)
+    return jnp.clip(jnp.where(r < length, newest, 0), 0, rung - 1)
+
+
+def window_pairs(T: int, window: Optional[int] = None) -> int:
+    """The (query, key) pairs of ``T`` positions that the causal mask keeps, under a window of ``window`` or none."""
+    if window is None or T <= window:
+        return T * (T + 1) // 2
+    return window * (window + 1) // 2 + (T - window) * window

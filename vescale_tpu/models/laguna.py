@@ -70,7 +70,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..moe.dropless import route_sigmoid_topk, routed_experts
-from .blocks import F32, ROUTED_DOWN_GAIN, _mm, rmsnorm, swiglu, write_position, yarn_inv_freq
+from .blocks import (F32, ROUTED_DOWN_GAIN, _mm, ring_row, ring_source, rmsnorm, swiglu, window_pairs, write_position,
+                     yarn_inv_freq)
 
 __all__ = [
     "LagunaConfig", "init_params", "embed", "head", "inv_freq", "rotary", "attention_gate", "attention_prefill",
@@ -290,22 +291,6 @@ def attention_prefill(c: LagunaConfig, ap, u, kind: str, *, interpret: Optional[
     return _out(c, ap, y, gate), k, v
 
 
-def ring_row(positions, window: int):
-    """The ring row that holds position ``p``: ``p mod window``."""
-    return positions % window
-
-
-def ring_source(length, rung: int, window: int):
-    """For each ring row ``r`` (window,), the position of a prefilled prompt of
-    ``length`` tokens (on a rung of ``rung`` positions) that it holds: the
-    NEWEST real position ``p < length`` with ``ring_row(p) = r``.  A row that no
-    real position falls on (a prompt shorter than the window) names position 0:
-    no decode step reads it before it is written."""
-    r = jnp.arange(window, dtype=jnp.int32)
-    newest = r + window * ((length - 1 - r) // window)
-    return jnp.clip(jnp.where(r < length, newest, 0), 0, rung - 1)
-
-
 def attention_step(c: LagunaConfig, ap, u, kind: str, k_store, v_store, *, layer: int, table, write, positions,
                    valid_len, interpret: Optional[bool]):
     """One new position a slot, at ``positions`` (S,): its K and V go to
@@ -486,13 +471,6 @@ def serve_decode(c: LagunaConfig, params, arrays, table, lengths, tokens, *, act
 # under the causal mask (scores and values over the (query, key) pairs the mask keeps, from the rung).
 STEP_COUNTERS = ("ring_positions_read", "ring_positions_unwindowed", "ring_bytes_rw", "prefill_window_attn_flops",
                  "prefill_full_attn_flops")
-
-
-def window_pairs(T: int, window: Optional[int] = None) -> int:
-    """The (query, key) pairs of ``T`` positions that the causal mask keeps, under a window of ``window`` or none."""
-    if window is None or T <= window:
-        return T * (T + 1) // 2
-    return window * (window + 1) // 2 + (T - window) * window
 
 
 def step_counters(config: LagunaConfig, cache, lengths: np.ndarray, counts) -> Dict[str, int]:

@@ -130,14 +130,23 @@ def route_group_limited(scores, k: int, *, n_group: int, topk_group: int, scale:
     return idx.astype(jnp.int32), top * scale, kept
 
 
-def route_sigmoid_topk(scores, k: int, *, scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+def route_sigmoid_topk(scores, k: int, *, scale: float = 1.0, bias=None) -> Tuple[jax.Array, jax.Array]:
     """Sigmoid routing: each of a token's router scores (N, E) through a
     float32 sigmoid on its own (no softmax over the experts), the ``k`` largest,
     their gates renormalised to sum 1 over those ``k``, times ``scale`` (the
     convention a ``routed_scaling_factor`` is published under where the scores
-    are sigmoids); no groups, no selection bias.  Returns ids (N, k) int32 and
-    gates (N, k) float32, as :func:`route_topk` does."""
-    top, idx = jax.lax.top_k(jax.nn.sigmoid(scores.astype(jnp.float32)), k)
+    are sigmoids); no groups.  ``bias`` (E,) float32 is a SELECTION bias (the
+    sources' ``e_score_correction_bias``): the ``k`` largest of ``sigmoid(scores)
+    + bias`` are kept, and the gates are the kept experts' ``sigmoid(scores)``
+    as they are, renormalised: the bias chooses, it does not weigh.  None: the
+    program without one.  Returns ids (N, k) int32 and gates (N, k) float32, as
+    :func:`route_topk` does."""
+    probs = jax.nn.sigmoid(scores.astype(jnp.float32))
+    if bias is None:
+        top, idx = jax.lax.top_k(probs, k)
+    else:
+        _, idx = jax.lax.top_k(probs + bias.astype(jnp.float32), k)
+        top = jnp.take_along_axis(probs, idx, axis=-1)
     return idx.astype(jnp.int32), top * (scale / jnp.sum(top, axis=-1, keepdims=True))
 
 

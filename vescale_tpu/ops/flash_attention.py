@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from ..kernels.flash_attention import (  # noqa: F401  (re-exported for tests)
     BLOCK_MASK_NAME,
+    CAUSAL_NAME,
     WINDOW_NAME,
     _NEG_INF,
     _flash_bwd_pallas,
@@ -83,12 +84,14 @@ def _from3(x, B, H):
     return jnp.transpose(x.reshape(B, H, T, D), (0, 2, 1, 3))
 
 
-def _xla_fwd_4d(q, k, v, scale, causal, mask_block=1, window=None):
+def _xla_fwd_4d(q, k, v, scale, causal, mask_block=1, window=None, sink=None):
     """Dense (o, lse) with the kernel's GQA layout and lse convention —
     the fallback leg of the shared partition rule (mode != off only; the
     off-mode fallback is the bare ``_dense_ref``).  ``mask_block`` > 1: causal
     over blocks of that many positions (:func:`_masked_forward`);
     ``window``: a row sees the ``window`` newest positions
+    (:func:`_masked_forward`); ``v`` may be narrower than ``k``, and ``sink``
+    (H,) is one more column of the softmax a head, of no value
     (:func:`_masked_forward`)."""
     B, T, H, D = q.shape
     G = k.shape[2]
@@ -105,13 +108,18 @@ def _xla_fwd_4d(q, k, v, scale, causal, mask_block=1, window=None):
             mask = mask & (position[:, None] - position[None, :] < window)
         s = jnp.where(mask[None, None, None], s, _NEG_INF)
     m = jnp.max(s, axis=-1)
+    if sink is not None:
+        column = sink.astype(jnp.float32).reshape(1, G, rep, 1)
+        m = jnp.maximum(m, column)
     p = jnp.exp(s - m[..., None])
     l = jnp.sum(p, axis=-1)
+    if sink is not None:
+        l = l + jnp.exp(column - m)
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o = jnp.einsum("bgrqk,bkgd->bqgrd", p, v.astype(jnp.float32))
     o = o / jnp.transpose(l_safe, (0, 3, 1, 2))[..., None]
     lse = (m + jnp.log(l_safe)).reshape(B, H, T)
-    return o.reshape(B, T, H, D).astype(q.dtype), lse
+    return o.reshape(B, T, H, v.shape[-1]).astype(q.dtype), lse
 
 
 def _xla_bwd_4d(q, k, v, o, do, lse, scale, causal):
@@ -296,28 +304,39 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, impl, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _masked_forward(q, k, v, scale, block_q, block_k, interpret, mask_block=1, window=None):
+def _masked_forward(q, k, v, scale, block_q, block_k, interpret, mask_block=1, window=None, sink=None):
     """The forward alone under one of the two masks a serve prefill brings:
     causal over BLOCKS of ``mask_block`` positions (position i sees j iff ``j //
     mask_block <= i // mask_block``: what a model that generates by diffusion
     over blocks prefills under), or a causal sliding ``window`` (i sees j iff
     ``0 <= i - j < window``: the window layers of a model that mixes window and
-    full attention; the kernel's key loop starts at the window's first block).
+    full attention; the kernel's key loop starts at the window's first block),
+    or under the plain causal mask with what the differentiated road does not
+    take: values ``v`` narrower than the keys, a ``sink`` (H,), one logit a head
+    in every row's softmax that mixes no value (with a window or without).
     No partition rule (one device, never differentiated); the GQA kernel on TPU
-    or interpreted, the dense product elsewhere."""
+    or interpreted, the dense product elsewhere.  Under a window the tiles are
+    no larger than the window rounded up to whole 128s: a query block then
+    visits two key blocks whatever the rung (a window of 512: the tiles of 512
+    it had)."""
     from .. import kernels as _kernels
 
     B, T, H, D = q.shape
     G = k.shape[2]
     if interpret is None:
         interpret = _kernels.mode() == "interpret"
+    if window is not None:
+        cap = -(-window // 128) * 128
+        block_q, block_k = min(block_q, cap), min(block_k, cap)
     block_q, block_k = _fit_block(block_q, T), _fit_block(block_k, T)
     tiles = not (T % block_q or T % block_k or block_q % mask_block or block_k % mask_block)
     if (_kernels.on_tpu() or interpret) and tiles:
         masks = {"mask_block": mask_block} if window is None else {"window": window}
+        if sink is not None:
+            masks["sink"] = sink
         o3, _lse = _flash_fwd_pallas(_to3(q), _to3(k), _to3(v), scale, True, block_q, block_k, interpret, H, G, **masks)
         return _from3(o3, B, H)
-    return _xla_fwd_4d(q, k, v, scale, True, mask_block, window)[0]
+    return _xla_fwd_4d(q, k, v, scale, True, mask_block, window, sink)[0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -333,6 +352,20 @@ def _windowed_refuses_grad(*_args):
 _windowed.defvjp(_windowed_refuses_grad, _windowed_refuses_grad)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _narrow_or_sunk(q, k, v, sink, scale, window, block_q, block_k, interpret):
+    return _masked_forward(q, k, v, scale, block_q, block_k, interpret, window=window, sink=sink)
+
+
+def _narrow_or_sunk_refuses_grad(*_args):
+    raise NotImplementedError("flash_attention(sink=...) and values narrower than the keys (v.shape[-1] != q.shape[-1]) are "
+                              "forward only: the backward kernels know neither, so a model that trains through them has no "
+                              "path here yet")
+
+
+_narrow_or_sunk.defvjp(_narrow_or_sunk_refuses_grad, _narrow_or_sunk_refuses_grad)
+
+
 def flash_attention(
     q,
     k,
@@ -344,6 +377,7 @@ def flash_attention(
     interpret: Optional[bool] = None,
     mask_block: int = 1,
     window: Optional[int] = None,
+    sink=None,
 ):
     """Fused attention over (B, T, H, D) q with (B, T, G, D) k/v, G | H —
     GQA/MQA run natively: the kernels route each q head to its kv group via
@@ -366,12 +400,29 @@ def flash_attention(
     window: position i sees j iff ``0 <= i - j < window``, FORWARD ONLY
     (:func:`_masked_forward`, the kernel named ``window_flash_fwd`` in a device
     trace; differentiating through it raises ``NotImplementedError``); at its
-    default of None nothing of the path below changes."""
+    default of None nothing of the path below changes.
+
+    ``sink`` ((H,) float32; ``causal`` must hold, no ``mask_block``; with a
+    ``window`` or without) adds one column to every row's softmax: query head
+    ``h``'s logit ``sink[h]`` enters the maximum and the denominator and mixes
+    no value (a row of a head whose sink is large gives most of its mass away).
+    ``v`` may be (B, T, G, Dv) with ``Dv != D``: the output is then (B, T, H,
+    Dv).  Both FORWARD ONLY, on the road ``window`` takes (differentiating
+    raises ``NotImplementedError`` and names them); without a window the kernel
+    is named ``causal_flash_fwd`` in a device trace.  At ``sink=None`` and ``Dv
+    == D`` nothing of the paths above and below changes."""
     B, T, H, D = q.shape
     G = k.shape[2]
     if H % max(G, 1):
         raise ValueError(f"q heads {H} not a multiple of kv heads {G}")
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    if sink is not None or v.shape[-1] != D:
+        if not causal or mask_block != 1 or (window is not None and window < 1):
+            raise ValueError("a sink, and values narrower than the keys, go with the causal mask (under a window of 1 or more "
+                             "positions, or none) and take no block mask")
+        if sink is not None and sink.shape != (H,):
+            raise ValueError(f"sink {sink.shape} is one logit a query head, ({H},)")
+        return _narrow_or_sunk(q, k, v, sink, scale, None if window is None else int(window), block_q, block_k, interpret)
     if window is not None:
         if not causal or mask_block != 1 or window < 1:
             raise ValueError(f"window={window} is a causal window of 1 or more positions, without a block mask")

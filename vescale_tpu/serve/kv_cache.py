@@ -121,7 +121,15 @@ class KVCacheConfig:
 
     ``latent`` says the pool's rows are latents that serve as keys and values
     both: ``kv_heads`` is 1, ``head_dim`` the row's width, and there is no
-    value pool."""
+    value pool.
+
+    ``v_head_dim`` (None: ``head_dim``) is the width of a value where it is not
+    a key's; the two pools then differ in their last axis.  ``folded`` lays a
+    position's row out as every key head's entries side by side, ``(layers,
+    num_pages, page_size, 1, kv_heads x width)``: the form for widths that are
+    no whole number of 128-lane tiles a head (192: the chip would pad each head
+    to 256 lanes, or turn the pool round so that a page is no longer one
+    copy), read by ``kernels.paged_decode_folded``."""
 
     layers: int
     kv_heads: int
@@ -133,10 +141,16 @@ class KVCacheConfig:
     dtype: Any = None  # default jnp.float32
     slot_state: Tuple[Tuple[str, int, Tuple[int, ...], Any], ...] = ()
     latent: bool = False
+    v_head_dim: Optional[int] = None
+    folded: bool = False
 
     def __post_init__(self):
         if self.latent and self.kv_heads != 1:
             raise ValueError("a latent cache keeps one row a position for all heads: kv_heads must be 1")
+        if self.latent and (self.v_head_dim is not None or self.folded):
+            raise ValueError("a latent cache has no value pool and one row a position: neither v_head_dim nor folded")
+        if self.v_head_dim is not None and self.v_head_dim <= 0:
+            raise ValueError("v_head_dim must be positive")
         if min(self.layers, self.kv_heads, self.head_dim) <= 0:
             raise ValueError("layers/kv_heads/head_dim must be positive")
         if min(self.num_slots, self.page_size, self.pages_per_slot) <= 0:
@@ -152,6 +166,12 @@ class KVCacheConfig:
     def pool_pages(self) -> int:
         # +1: page 0 is reserved (never allocated, masked everywhere)
         return self.num_pages if self.num_pages is not None else self.num_slots * self.pages_per_slot + 1
+
+    def pool_row(self, values: bool = False) -> Tuple[int, int]:
+        """The last two axes of the key pool (of the value pool): ``(kv_heads,
+        width)``, or folded ``(1, kv_heads x width)``."""
+        width = self.v_head_dim if values and self.v_head_dim is not None else self.head_dim
+        return (1, self.kv_heads * width) if self.folded else (self.kv_heads, width)
 
     @classmethod
     def from_env(cls, layers: int, kv_heads: int, head_dim: int, dtype=None) -> "KVCacheConfig":
@@ -197,7 +217,8 @@ def _idx_shape(idx, shape) -> Tuple[int, ...]:
 
 
 def write_pages(pool, rows, page_row, page: int):
-    """``rows`` (L, T, KV, hd), every layer's K or V of a rung's positions, into
+    """``rows`` (L, T, KV, hd), every layer's K or V of a rung's positions (a
+    folded pool's: (L, T, 1, KV x hd)), into
     the pages ``page_row`` (T / page,) of ``pool`` (L, pages, page, KV, hd): one
     slab of all layers a page, updated in place.  (One scatter over the page
     axis makes the compiler re-lay out the WHOLE pool and back around it where
@@ -242,13 +263,7 @@ class PagedKVCache:
         self.config = config
         self.mesh = mesh
         dtype = config.dtype if config.dtype is not None else jnp.float32
-        shape = (
-            config.layers,
-            self.num_pages,
-            config.page_size,
-            config.kv_heads,
-            config.head_dim,
-        )
+        shape = (config.layers, self.num_pages, config.page_size) + config.pool_row()
         if placements is None:
             # kv-heads (axis 3) split over the mesh dim NAMED "tp" when it
             # exists; any other axis name stays replicated — the same
@@ -257,19 +272,22 @@ class PagedKVCache:
         tp = next(
             (mesh.shape[i] for i, p in enumerate(placements) if p.is_shard(3)), 1
         )
-        if config.kv_heads % max(tp, 1):
+        if config.kv_heads % max(tp, 1) or (config.folded and tp > 1):
             raise ValueError(
                 f"kv_heads={config.kv_heads} not divisible by the head-sharded "
-                f"mesh extent {tp}"
+                f"mesh extent {tp}" + (" (a folded row holds every head: it is not split over heads)" if config.folded else "")
             )
         self.spec = DArraySpec(
             mesh,
             tuple(placements),
             TensorMeta(shape, jnp.dtype(dtype)),
         )
+        # the value pool's own spec where a value is not as wide as a key
+        self.v_spec = self.spec if config.v_head_dim is None else DArraySpec(
+            mesh, tuple(placements), TensorMeta(shape[:3] + config.pool_row(values=True), jnp.dtype(dtype)))
         with _memtrack.tagged("kv_cache"):
             self.k = _memtrack.tag_array(DArray(_zeros_global(self.spec), self.spec))
-            self.v = None if config.latent else _memtrack.tag_array(DArray(_zeros_global(self.spec), self.spec))
+            self.v = None if config.latent else _memtrack.tag_array(DArray(_zeros_global(self.v_spec), self.v_spec))
             # per-slot state beside the pages, replicated over the mesh (see the module docstring)
             self.state = {
                 name: _memtrack.tag_array(_zeros_replicated((layers, config.num_slots) + tuple(shape), dt, mesh))
@@ -536,7 +554,7 @@ class PagedKVCache:
         if (v_data is None) != self.config.latent:
             raise ValueError("a latent cache takes back its one pool, any other its two")
         self.k = DArray(k_data, self.spec)
-        self.v = None if v_data is None else DArray(v_data, self.spec)
+        self.v = None if v_data is None else DArray(v_data, self.v_spec)
 
     def arrays(self) -> Dict[str, Any]:
         """Everything the cache keeps on the device, by name, as an engine's
