@@ -3,8 +3,10 @@ before it has read step k-1's ids; ``DecodeAhead.decode`` of both engines takes
 the ids from the device): every request's stream stays what
 ``engine.replay_greedy`` gives, on a tiny Llama, Granite and DeepSeek-V2 engine,
 under staggered arrivals, EOS at every position, budgets of 1 and 2, injected
-faults and a cancellation from ``on_step`` with a step in flight; and the pins
-the benchmark's harness holds the loop and the engines to."""
+faults and a cancellation from ``on_step`` with a step in flight; a prefill one
+step deep as well (``prefill`` launches and returns its ``PrefillStep`` unread;
+the loop reads it after the decode step that takes its id from the device is
+enqueued); and the pins the benchmark's harness holds the loop and the engines to."""
 
 import numpy as np
 import pytest
@@ -21,7 +23,7 @@ from vescale_tpu.models.llama import Llama, LlamaConfig
 from vescale_tpu.ndtimeline import api as nd
 from vescale_tpu.resilience import faultsim
 from vescale_tpu.serve import (ContinuousBatchingScheduler, DecodeFeed, DecodeStep, HybridServeEngine, KVCacheConfig,
-                               PagedKVCache, Request, ServeEngine, run_serve_resilient)
+                               PagedKVCache, PrefillStep, Request, ServeEngine, run_serve_resilient)
 from vescale_tpu.serve.hybrid_engine import hybrid_cache_config
 
 SLOTS, PAGE, PAGES = 3, 4, 8          # 32 positions a slot
@@ -166,6 +168,168 @@ def test_a_cancellation_from_on_step_mid_flight_loses_and_doubles_no_token_and_t
         assert 2 <= len(tokens) < 12
     for rid in (2, SLOTS, SLOTS + 1):
         assert res.outcomes[rid]["status"] == "completed" and res.outcomes[rid]["tokens"] == want[rid], rid
+    cache.reset()
+
+
+# ------------------------------------------------------- a prefill, one step deep
+def test_a_prefill_returns_unread_and_gives_its_row_and_its_rows_argmax_when_asked(rig):
+    eng, cache = rig
+    cache.reset()
+    slot = cache.alloc(5, 4)
+    start = eng.trace_counters()
+    step = eng.prefill(_prompt(3, 5), slot)
+    cache.commit_prefill(slot, 5)
+    vocab = eng.config.vocab_size
+    assert isinstance(step, PrefillStep)
+    assert (step.shape, step.dtype) == ((vocab,), np.float32) and not step.read, "neither waits"
+    assert eng.trace_counters()["prefill_launches"] == start["prefill_launches"] + 1, "counted as launched at once"
+    row = np.asarray(step)
+    assert step.read and (row.shape, row.dtype) == ((vocab,), np.float32) and np.isfinite(row).all()
+    assert step.token == int(np.argmax(row)) == eng.greedy(step)
+    assert np.array_equal(np.stack([step, row]), np.stack([row, row])), "stacks with rows, as the reference check does"
+    # the decode step it feeds from the device is the step its token feeds from the host
+    again = eng.prefill(_prompt(3, 5), slot)
+    fed = eng.decode(DecodeFeed(eng.decode(np.zeros((SLOTS,), np.int32)), {slot: again}))
+    assert not again.read and eng.trace_counters()["prefill_reads_ahead"] == start["prefill_reads_ahead"] + 1
+    toks = np.zeros((SLOTS,), np.int32)
+    toks[slot] = again.token
+    assert int(eng.decode(toks).tokens[slot]) == int(fed.tokens[slot])
+    cache.reset()
+
+
+def test_a_tie_goes_to_the_lowest_id_in_the_prefills_program_as_on_the_host(rig):
+    """Parameters of zeros: every logit of the row is the same (or not a number, which counts as the largest)."""
+    eng, cache = rig
+    cache.reset()
+    slot = cache.alloc(4, 2)
+    params, eng.params = eng.params, jax.tree_util.tree_map(jnp.zeros_like, eng.params)
+    try:
+        step = eng.prefill(_prompt(5, 4), slot)
+        row = np.asarray(step)
+    finally:
+        eng.params = params
+    assert len(set(row.tolist())) == 1 or np.isnan(row).all()
+    assert step.token == int(np.argmax(row)) == 0
+    cache.reset()
+
+
+def test_a_prefill_that_is_not_its_slots_newest_is_read_before_the_step_and_fed_from_the_host(rig):
+    eng, cache = rig
+    cache.reset()
+    slot = cache.alloc(5, 4)
+    stale = eng.prefill(_prompt(3, 5), slot)
+    newest = eng.prefill(_prompt(4, 5), slot)       # the slot's place among the firsts now holds this one's id
+    cache.commit_prefill(slot, 5)
+    start = eng.trace_counters()["prefill_reads_ahead"]
+    before = eng.decode(np.zeros((SLOTS,), np.int32))
+    a = eng.decode(DecodeFeed(before, {slot: stale}))
+    assert stale.read and not newest.read and eng.trace_counters()["prefill_reads_ahead"] == start
+    toks = np.zeros((SLOTS,), np.int32)
+    toks[slot] = stale.token
+    cache.commit_prefill(slot, 5)
+    assert int(eng.decode(toks).tokens[slot]) == int(a.tokens[slot])
+    cache.reset()
+
+
+def test_prefills_admitted_behind_a_step_in_flight_are_read_after_the_step_that_takes_their_ids_is_enqueued(rig, tmp_path):
+    """One long request keeps a step in flight; two requests admitted in ONE iteration, one of a budget of
+    one token and one whose first token is its EOS arrive behind it.  Every stream is ``replay_greedy``'s,
+    each of those prefills is read after the launch of the step behind it, the request of one token gets
+    no step, the EOS is learned a step late and nothing is recorded after it, each slot is freed once a
+    request, the counter says how many ids a step took from the device, and nothing compiles."""
+    eng, cache = rig
+    long_one = Request(rid=0, prompt=_prompt(20, 5), max_new_tokens=14)
+    pair = [Request(rid=1, prompt=_prompt(21, 4), max_new_tokens=3), Request(rid=2, prompt=_prompt(22, 6), max_new_tokens=3)]
+    one = Request(rid=3, prompt=_prompt(23, 3), max_new_tokens=1)
+    eos_prompt = _prompt(24, 5)
+    eos_first = Request(rid=4, prompt=eos_prompt, max_new_tokens=4, eos_id=_eos_at(rig, eos_prompt, 4, 0))
+    reqs = [(0, long_one), (2, pair[0]), (2, pair[1]), (7, one), (9, eos_first)]
+    want = {req.rid: _golden(rig, req) for _, req in reqs}
+    assert want[4] == [eos_first.eos_id] and len(want[3]) == 1
+
+    log, freed = [], []
+    prefill, decode, read, free = eng.prefill, eng.decode, eng._read_prefill, cache.free
+
+    def logged_prefill(prompt, slot):
+        out = prefill(prompt, slot)
+        log.append(("prefill", slot, out))
+        return out
+
+    def logged_decode(tokens):
+        fresh = dict(tokens.fresh) if isinstance(tokens, DecodeFeed) else None
+        assert fresh is None or not any(isinstance(f, PrefillStep) and f.read for f in fresh.values())
+        out = decode(tokens)
+        log.append(("launch", fresh))
+        return out
+
+    def logged_read(step):
+        log.append(("read", None, step))
+        return read(step)
+
+    def counted_free(slot):
+        freed.append(slot)
+        return free(slot)
+
+    eng.prefill, eng.decode, eng._read_prefill, cache.free = logged_prefill, logged_decode, logged_read, counted_free
+    start = eng.trace_counters()
+    nd.start_trace_session(str(tmp_path / "session"), profiler=False)
+    try:
+        res, sched = _run(rig, reqs)
+    finally:
+        counters = nd.stop_trace_session().counters
+        del eng.prefill, eng.decode, eng._read_prefill, cache.free
+    assert {rid: o["tokens"] for rid, o in res.outcomes.items()} == want
+    assert all(o["status"] == "completed" for o in res.outcomes.values()) and len(freed) == len(reqs)
+
+    steps = {rid: out for (what, _, out), (_, req) in zip([e for e in log if e[0] == "prefill"], reqs) for rid in [req.rid]}
+    at = {id(e[2]): i for i, e in enumerate(log) if e[0] == "read"}
+    fed = {id(f): i for i, e in enumerate(log) if e[0] == "launch" and e[1] for f in e[1].values() if isinstance(f, PrefillStep)}
+    launched = {id(e[2]): i for i, e in enumerate(log) if e[0] == "prefill"}
+    # the first request finds no step in flight: read at once, and the step after it starts from the host's token
+    first_launch = next(i for i, e in enumerate(log) if e[0] == "launch")
+    assert at[id(steps[0])] < first_launch and log[first_launch][1] is None and id(steps[0]) not in fed
+    # the two of one iteration: both launched, then ONE step that takes both ids from the device, then both read
+    a, b = steps[1], steps[2]
+    assert launched[id(b)] == launched[id(a)] + 1 and fed[id(a)] == fed[id(b)] == launched[id(b)] + 1
+    assert (at[id(a)], at[id(b)]) == (fed[id(a)] + 1, fed[id(a)] + 2)
+    # a budget of one token: known by count, so no step takes its id; it is read in its own iteration all the same
+    assert id(steps[3]) not in fed and id(steps[3]) in at
+    # an EOS as first token: the step behind it was launched before the host knew, and its id for the slot dropped
+    assert fed[id(steps[4])] < at[id(steps[4])] and res.outcomes[4]["tokens"] == [eos_first.eos_id]
+    assert all(step.read for step in steps.values())
+    delta = {k: counters[k] for k in ("prefill_launches", "prefill_reads_ahead", "backend_compiles")}
+    assert delta == {"prefill_launches": 5, "prefill_reads_ahead": 3, "backend_compiles": 0}
+    assert eng.trace_counters()["prefill_reads_ahead"] == start["prefill_reads_ahead"] + 3
+
+
+def test_no_program_compiles_after_warm_in_any_form_of_the_feed(rig, tmp_path):
+    """The host's tokens; the step before's ids as they are; a fresh slot's first token from the host, from
+    its unread prefill on the device, and one of each in one call: one merge program, one executable each."""
+    eng, cache = rig
+    cache.reset()
+    slots = [cache.alloc(4, 8) for _ in range(SLOTS)]
+    programs = lambda: [f._cache_size() for f in (eng._merge_fn, eng._first_fn, eng._decode_fn)]
+    before = programs()
+
+    def prefilled(slot, seed):
+        step = eng.prefill(_prompt(seed, 4), slot)
+        cache.commit_prefill(slot, 4)
+        return step
+
+    nd.start_trace_session(str(tmp_path / "session"), profiler=False)
+    try:
+        toks = np.zeros((SLOTS,), np.int32)
+        toks[slots[0]] = prefilled(slots[0], 1).token
+        step = eng.decode(toks)                                                         # cold: the host's tokens
+        step = eng.decode(DecodeFeed(step, {slots[1]: prefilled(slots[1], 2)}))         # an unread prefill's id
+        step = eng.decode(DecodeFeed(step, {slots[2]: prefilled(slots[2], 3).token}))   # the host's first token
+        step = eng.decode(DecodeFeed(step, {slots[0]: prefilled(slots[0], 4), slots[1]: prefilled(slots[1], 5).token}))
+        step = eng.decode(DecodeFeed(step))                                             # nothing fresh: no merge at all
+        step.tokens
+    finally:
+        counters = nd.stop_trace_session().counters
+    assert programs() == before and counters["backend_compiles"] == 0
+    assert (counters["prefill_launches"], counters["prefill_reads_ahead"], counters["decode_steps_ahead"]) == (5, 2, 4)
     cache.reset()
 
 

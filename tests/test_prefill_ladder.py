@@ -118,8 +118,9 @@ def test_a_bucketed_prefill_is_the_prefill_padded_to_max_seq_len(pair, stages, n
     got, want = eng.prefill(prompt, slot), full.prefill(prompt, full_slot)
     k1, v1 = _pools(cache)
     kf, vf = _pools(full_cache)
-    # the logits row of the last real position
-    assert got.shape == want.shape == (64,) and got.dtype == np.float32
+    # the logits row of the last real position (read where it is asked for: shape and dtype copy nothing)
+    assert got.shape == want.shape == (64,) and got.dtype == np.float32 and not (got.read or want.read)
+    got, want = np.asarray(got), np.asarray(want)
     assert np.abs(got - want).max() <= tol * np.abs(want).max()
     # K/V of the prompt's positions, page by page (the last page up to the prompt's end)
     for i in range(-(-n // PAGE)):

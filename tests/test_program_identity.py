@@ -13,7 +13,8 @@ jaxpr's text.  Taken on the parent of the PR that gave the shared blocks one
 home each (e1809cb: this file on that tree, ``PROGRAMS`` printed by ``python
 tests/test_program_identity.py``).  A PR that changes these programs on purpose
 takes them anew.  (``mimo_v2``'s six were taken on the tree of the PR that brought
-the family, PR 50: they pin it from there on.)"""
+the family, PR 50: they pin it from there on.  The twenty-four prefills were taken
+anew by PR 52, whose prefill also returns its row's argmax: the scopes did not move.)"""
 
 import collections
 import dataclasses
@@ -41,41 +42,41 @@ LEGS = {"xla_legs": None, "kernels_interpreted": "interpret"}
 WHICH = ("prefill_rung_1", "prefill_rung_2", "decode")
 
 PROGRAMS = {
-    "granite_hybrid/xla_legs/prefill_rung_1": ('ddbafc293ce0716a', 'vs.attn=47 vs.mamba=483 vs.moe=160'),
-    "granite_hybrid/xla_legs/prefill_rung_2": ('bedcb3aa605f41d9', 'vs.attn=47 vs.mamba=483 vs.moe=160'),
+    "granite_hybrid/xla_legs/prefill_rung_1": ('98f6b6654290c82b', 'vs.attn=47 vs.mamba=483 vs.moe=160'),
+    "granite_hybrid/xla_legs/prefill_rung_2": ('ec634149a6d21298', 'vs.attn=47 vs.mamba=483 vs.moe=160'),
     "granite_hybrid/xla_legs/decode": ('648f82e75f10971f', 'vs.attn=106 vs.mamba=309 vs.moe=160'),
-    "granite_hybrid/kernels_interpreted/prefill_rung_1": ('7d7dd0c97eda3976', 'vs.attn=554 vs.mamba=483 vs.moe=160'),
-    "granite_hybrid/kernels_interpreted/prefill_rung_2": ('12ee271ddde61bc7', 'vs.attn=554 vs.mamba=483 vs.moe=160'),
+    "granite_hybrid/kernels_interpreted/prefill_rung_1": ('d5a9b27abcbd6ecf', 'vs.attn=554 vs.mamba=483 vs.moe=160'),
+    "granite_hybrid/kernels_interpreted/prefill_rung_2": ('dfa5e9c20591704c', 'vs.attn=554 vs.mamba=483 vs.moe=160'),
     "granite_hybrid/kernels_interpreted/decode": ('f67535cadf866b5f', 'vs.attn=69 vs.mamba=255 vs.moe=160'),
-    "deepseek_v2/xla_legs/prefill_rung_1": ('4164d8487fe1a9fb', 'vs.attn=408 vs.mlp=9 vs.moe=108'),
-    "deepseek_v2/xla_legs/prefill_rung_2": ('c1458b6c8dcc5f5d', 'vs.attn=408 vs.mlp=9 vs.moe=108'),
+    "deepseek_v2/xla_legs/prefill_rung_1": ('00bd63cd35773518', 'vs.attn=408 vs.mlp=9 vs.moe=108'),
+    "deepseek_v2/xla_legs/prefill_rung_2": ('592f6fda2c03d3fa', 'vs.attn=408 vs.mlp=9 vs.moe=108'),
     "deepseek_v2/xla_legs/decode": ('ad9c244ea9a2ebe5', 'vs.attn=513 vs.mlp=9 vs.moe=130'),
-    "deepseek_v2/kernels_interpreted/prefill_rung_1": ('980500568f06f1ec', 'vs.attn=1788 vs.mlp=9 vs.moe=108'),
-    "deepseek_v2/kernels_interpreted/prefill_rung_2": ('f0486d77c3d6ccfc', 'vs.attn=1788 vs.mlp=9 vs.moe=108'),
+    "deepseek_v2/kernels_interpreted/prefill_rung_1": ('fe17fcd91d420093', 'vs.attn=1788 vs.mlp=9 vs.moe=108'),
+    "deepseek_v2/kernels_interpreted/prefill_rung_2": ('21764e8046f1a272', 'vs.attn=1788 vs.mlp=9 vs.moe=108'),
     "deepseek_v2/kernels_interpreted/decode": ('2f1889f41534b464', 'vs.attn=402 vs.mlp=9 vs.moe=130'),
-    "sdar_moe/xla_legs/prefill_rung_1": ('8e3b299e7630eaef', 'vs.attn=314 vs.moe=62'),
-    "sdar_moe/xla_legs/prefill_rung_2": ('c81097f2d31e289d', 'vs.attn=314 vs.moe=62'),
+    "sdar_moe/xla_legs/prefill_rung_1": ('368d83fff82f4894', 'vs.attn=314 vs.moe=62'),
+    "sdar_moe/xla_legs/prefill_rung_2": ('0a77108ffbf534d9', 'vs.attn=314 vs.moe=62'),
     "sdar_moe/xla_legs/decode": ('b85e2370cf46c46e', 'vs.attn=414 vs.moe=62 vs.unmask=78'),
-    "sdar_moe/kernels_interpreted/prefill_rung_1": ('e56a516954aba883', 'vs.attn=1332 vs.moe=62'),
-    "sdar_moe/kernels_interpreted/prefill_rung_2": ('bc73527e136986e5', 'vs.attn=1332 vs.moe=62'),
+    "sdar_moe/kernels_interpreted/prefill_rung_1": ('f933be4443957656', 'vs.attn=1332 vs.moe=62'),
+    "sdar_moe/kernels_interpreted/prefill_rung_2": ('c0e8b8caa1f4e974', 'vs.attn=1332 vs.moe=62'),
     "sdar_moe/kernels_interpreted/decode": ('56e940485455a866', 'vs.attn=340 vs.moe=62 vs.unmask=67'),
-    "falcon_h1/xla_legs/prefill_rung_1": ('90cf402ea227c42d', 'vs.attn=216 vs.mamba=384 vs.mlp=56'),
-    "falcon_h1/xla_legs/prefill_rung_2": ('b743fe3d0586fe9e', 'vs.attn=216 vs.mamba=384 vs.mlp=56'),
+    "falcon_h1/xla_legs/prefill_rung_1": ('651fccfb5f5874be', 'vs.attn=216 vs.mamba=384 vs.mlp=56'),
+    "falcon_h1/xla_legs/prefill_rung_2": ('9f3db013d5d2cdab', 'vs.attn=216 vs.mamba=384 vs.mlp=56'),
     "falcon_h1/xla_legs/decode": ('c1aa2e3ced5cfdfc', 'vs.attn=302 vs.mamba=200 vs.mlp=56'),
-    "falcon_h1/kernels_interpreted/prefill_rung_1": ('217404c2ee985216', 'vs.attn=1230 vs.mamba=384 vs.mlp=56'),
-    "falcon_h1/kernels_interpreted/prefill_rung_2": ('db13332e9b901285', 'vs.attn=1230 vs.mamba=384 vs.mlp=56'),
+    "falcon_h1/kernels_interpreted/prefill_rung_1": ('ce602ea6c8e8ec6e', 'vs.attn=1230 vs.mamba=384 vs.mlp=56'),
+    "falcon_h1/kernels_interpreted/prefill_rung_2": ('f8436a8621fc3650', 'vs.attn=1230 vs.mamba=384 vs.mlp=56'),
     "falcon_h1/kernels_interpreted/decode": ('c6b57d1d7536a78a', 'vs.attn=228 vs.mamba=160 vs.mlp=56'),
-    "laguna/xla_legs/prefill_rung_1": ('c4e9235e1f7934a7', 'vs.attn=651 vs.mlp=9 vs.moe=108'),
-    "laguna/xla_legs/prefill_rung_2": ('80a62b15d7e50d61', 'vs.attn=651 vs.mlp=9 vs.moe=108'),
+    "laguna/xla_legs/prefill_rung_1": ('b146687985cea9d3', 'vs.attn=651 vs.mlp=9 vs.moe=108'),
+    "laguna/xla_legs/prefill_rung_2": ('44ac4f53288c04cd', 'vs.attn=651 vs.mlp=9 vs.moe=108'),
     "laguna/xla_legs/decode": ('370af31f6198adea', 'vs.attn=810 vs.mlp=9 vs.moe=108'),
-    "laguna/kernels_interpreted/prefill_rung_1": ('542d10a1a7587958', 'vs.attn=3174 vs.mlp=9 vs.moe=108'),
-    "laguna/kernels_interpreted/prefill_rung_2": ('386a8d3468798b00', 'vs.attn=3174 vs.mlp=9 vs.moe=108'),
+    "laguna/kernels_interpreted/prefill_rung_1": ('9d93d22a8bea5c98', 'vs.attn=3174 vs.mlp=9 vs.moe=108'),
+    "laguna/kernels_interpreted/prefill_rung_2": ('6aa99d4b99fd8aa9', 'vs.attn=3174 vs.mlp=9 vs.moe=108'),
     "laguna/kernels_interpreted/decode": ('6b12595f0b880b74', 'vs.attn=625 vs.mlp=9 vs.moe=108'),
-    "mimo_v2/xla_legs/prefill_rung_1": ('db2e012b49ba659a', 'vs.attn=990 vs.mlp=9 vs.moe=132'),
-    "mimo_v2/xla_legs/prefill_rung_2": ('4b302c23a9b49776', 'vs.attn=990 vs.mlp=9 vs.moe=132'),
+    "mimo_v2/xla_legs/prefill_rung_1": ('e2a2d458dda57145', 'vs.attn=990 vs.mlp=9 vs.moe=132'),
+    "mimo_v2/xla_legs/prefill_rung_2": ('c605149277dc5904', 'vs.attn=990 vs.mlp=9 vs.moe=132'),
     "mimo_v2/xla_legs/decode": ('46088ea8d8664d65', 'vs.attn=1257 vs.mlp=9 vs.moe=216'),
-    "mimo_v2/kernels_interpreted/prefill_rung_1": ('e9350405c3a75b3e', 'vs.attn=4916 vs.mlp=9 vs.moe=132'),
-    "mimo_v2/kernels_interpreted/prefill_rung_2": ('617bfcc9ff21ef45', 'vs.attn=4916 vs.mlp=9 vs.moe=132'),
+    "mimo_v2/kernels_interpreted/prefill_rung_1": ('2566616dac8eeeeb', 'vs.attn=4916 vs.mlp=9 vs.moe=132'),
+    "mimo_v2/kernels_interpreted/prefill_rung_2": ('44cfbf93fd2828e4', 'vs.attn=4916 vs.mlp=9 vs.moe=132'),
     "mimo_v2/kernels_interpreted/decode": ('e1b4216a59e292bd', 'vs.attn=931 vs.mlp=9 vs.moe=216'),
 }
 

@@ -389,6 +389,45 @@ def test_engine_continuous_batching_interleaved(model_and_params, tp2_mesh):
     assert tb == _reference_tokens(model, params, pb, 4)
 
 
+def test_engine_a_joining_slot_fed_from_its_unread_prefill_decodes_what_the_hosts_token_gives(model_and_params, tp2_mesh):
+    """B joins A's batch with a step in flight, on the tp mesh: B's prefill stays on the device
+    (``PrefillStep``), the step behind it takes B's first id from there, and both streams are the full
+    recompute's; the row and the id read afterwards are the ones a caller that reads at once gets."""
+    from vescale_tpu.serve import DecodeFeed, PrefillStep
+
+    model, params = model_and_params
+    cache = _cache(num_slots=2, mesh=tp2_mesh)
+    eng = ServeEngine(CFG, tp2_mesh, params, cache)
+    pa, pb = (5, 9, 17), (40, 2, 33, 8)
+    sa = cache.alloc(len(pa), 6)
+    first_a = eng.prefill(pa, sa)
+    cache.commit_prefill(sa, len(pa))
+    assert isinstance(first_a, PrefillStep) and not first_a.read
+    ta = [first_a.token]
+    t = [0, 0]
+    t[sa] = ta[-1]
+    step = eng.decode(t)                        # A alone, left in flight
+    cache.advance(sa)
+    sb = cache.alloc(len(pb), 6)
+    first_b = eng.prefill(pb, sb)               # launched behind it, unread
+    cache.commit_prefill(sb, len(pb))
+    nxt = eng.decode(DecodeFeed(step, {sb: first_b}))
+    cache.advance(sa)
+    cache.advance(sb)
+    assert step.read and not first_b.read and not nxt.read and eng.prefill_reads_ahead == 1
+    ta.append(int(step.tokens[sa]))
+    tb = [first_b.token]
+    assert first_b.token == int(np.argmax(np.asarray(first_b))) and np.asarray(first_b).dtype == np.float32
+    for _ in range(2):
+        step, nxt = nxt, eng.decode(DecodeFeed(nxt))
+        cache.advance(sa)
+        cache.advance(sb)
+        ta.append(int(step.tokens[sa]))
+        tb.append(int(step.tokens[sb]))
+    assert ta == _reference_tokens(model, params, pa, 4)
+    assert tb == _reference_tokens(model, params, pb, 3)
+
+
 def test_engine_stage_split_matches_single_stage(model_and_params, tp2_mesh):
     """num_stages=2 splits the layer loop with the pipe engine's cut math;
     the math is unchanged, so logits must be BITWISE identical."""

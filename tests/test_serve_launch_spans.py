@@ -43,8 +43,9 @@ LLAMA = LlamaConfig(vocab_size=96, hidden_size=16, intermediate_size=32, num_hid
                     num_key_value_heads=2, max_position_embeddings=64, dtype=jnp.float32)
 LAUNCHES = (P.SERVE_DECODE_LAUNCH, P.SERVE_PREFILL_LAUNCH)
 LOOP_SPANS = (P.SERVE_BOUNDARY, P.SERVE_ADMIT, P.SERVE_BOOKS, P.SERVE_HOOK, P.SERVE_IDLE)
-# what tiles an iteration at its top level (``.launch``, ``.fetch`` and a settled step's spans nest inside these)
-TOP = LOOP_SPANS + (P.SERVE_SAMPLE, P.SERVE_DECODE_CALL, P.SERVE_PREFILL_CALL)
+# what tiles an iteration at its top level (``.launch``, a decode step's ``.fetch`` and a settled step's spans nest inside
+# these; a prefill's ``.fetch`` stands beside them: the loop reads a prefill after the decode call that went in behind it)
+TOP = LOOP_SPANS + (P.SERVE_SAMPLE, P.SERVE_DECODE_CALL, P.SERVE_PREFILL_CALL, P.SERVE_PREFILL_FETCH)
 NEW_LIVE = LAUNCHES + LOOP_SPANS
 
 
@@ -138,10 +139,14 @@ def test_launches_are_numbered_without_a_hole_and_each_fetch_names_the_launch_it
     # in time order the numbers rise: a launch's number is its place in the order of enqueues
     in_time = [s.tags["launch"] for s in sorted(decodes + prefills, key=lambda s: s.start)]
     assert in_time == sorted(in_time)
-    # a prefill says where and how wide; its fetch reads the launch just made
+    # a prefill says where and how wide; it is read once, in the order of the launches, by a fetch that names
+    # it and comes after its enqueue (a block engine's loop never reads one: its prefill yields no token)
     assert len(prefills) == len(res.outcomes) and all(set(s.tags) == {"launch", "rung", "slot"} for s in prefills)
     assert all(s.tags["rung"] in eng.buckets and 0 <= s.tags["slot"] < SLOTS for s in prefills)
-    assert [s.tags["launch"] for s in _named(session, P.SERVE_PREFILL_FETCH)] == [s.tags["launch"] for s in prefills]
+    read = _named(session, P.SERVE_PREFILL_FETCH)
+    assert [s.tags["launch"] for s in read] == ([s.tags["launch"] for s in prefills] if eng.block is None else [])
+    for fetch, launch in zip(read, prefills):
+        assert set(fetch.tags) == {"launch"} and launch.start + launch.duration <= fetch.start + 1e-6
     # every decode step launched in the session was read in it, once, by a fetch that names it; the pipeline
     # is one step deep, so a fetch reads the launch before the one whose span it sits in
     fetched = [s.tags["launch"] for s in _named(session, P.SERVE_DECODE_FETCH)]
@@ -178,6 +183,28 @@ def test_a_step_launched_in_a_session_and_read_after_it_is_in_one_counter_and_no
     cache.reset()
 
 
+def test_every_stream_is_replay_greedys_with_prefills_left_unread_behind_the_step_in_flight(rig):
+    """Each engine kind under requests that arrive while a step is in flight: the streams are
+    ``replay_greedy``'s, and ``prefill_reads_ahead`` counts the prefills a decode step went in behind
+    unread: all but the first (no step in flight: read at once) where a step takes a token a slot, and
+    every one of a block engine, whose loop never reads a prefill."""
+    eng, cache = rig
+    reqs = [(arrive, req) for arrive, req in zip((0, 2, 2, 5, 6, 9, 11), _requests(budget=9))]
+    cache.reset()
+    want = {req.rid: eng.replay_greedy(req.prompt, req.max_new_tokens) for _, req in reqs}
+    cache.reset()
+    sched = ContinuousBatchingScheduler(cache, max_queue=32)
+    start = eng.trace_counters()
+    res = run_serve_resilient(engine=eng, scheduler=sched, arrivals=reqs, install_signal_handlers=False, coordinate=False)
+    sched.ledger_check()
+    cache.reset()
+    assert {rid: o["tokens"] for rid, o in res.outcomes.items()} == want
+    c = {k: v - start[k] for k, v in eng.trace_counters().items()}
+    assert c["prefill_launches"] == len(reqs)
+    assert c["prefill_reads_ahead"] == len(reqs) - (eng.block is None)
+    assert c["decode_steps_ahead"] == c["decode_steps"] - 1, "one cold start, and no prefill broke the pipeline"
+
+
 # ------------------------------------------------------------ B. the tiling
 def test_the_loops_spans_tile_an_iteration_to_within_its_own_lines(traced):
     """Every span of the top level is disjoint from the next, an iteration
@@ -202,7 +229,8 @@ def test_the_loops_spans_tile_an_iteration_to_within_its_own_lines(traced):
     uncovered = []
     for a, b in zip(starts, starts[1:]):
         spans = outer[a:b]
-        ranks = [order[s.metric] for s in spans]
+        # (a prefill is read at once where no step is in flight, else after the step in flight is recorded)
+        ranks = [order[s.metric] for s in spans if s.metric != P.SERVE_PREFILL_FETCH]
         assert ranks == sorted(ranks) and ranks.count(order[P.SERVE_ADMIT]) <= 1, [s.metric for s in spans]
         wall = outer[b].start - spans[0].start
         uncovered.append(1.0 - sum(s.duration for s in spans) / wall)

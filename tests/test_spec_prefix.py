@@ -502,6 +502,41 @@ def test_loop_speculative_bit_identical(model_and_params, tp2_mesh, k):
     assert 0 <= (spec.accept_rate() or 0.0) <= 1.0
 
 
+@pytest.mark.parametrize("path", ["speculative", "prefix_hit"])
+def test_loop_paths_that_need_the_hosts_token_read_their_prefill_at_once(model_and_params, tp2_mesh, path):
+    """A drafter drafts from tokens on the host, and a prefix hit's suffix comes off ``decode_multi`` as a
+    row: neither leaves a first token on the device for the decode step behind it (a plain prefill under
+    a prefix cache, with a step in flight, does), and the streams stay the plain loop's."""
+    from vescale_tpu.serve import DecodeFeed, PrefillStep
+
+    _, params = model_and_params
+    arrivals = _shared_arrivals(max_new=5)
+    eng, _, sched, _ = _build_rig(params, tp2_mesh)
+    golden = _run(eng, sched, arrivals)
+    eng2, _, sched2, pc = _build_rig(params, tp2_mesh, prefix=path == "prefix_hit")
+    spec = SpeculativeDecoder(eng2, slice_drafter_params(params, 1), drafter_layers=1, k=4) if path == "speculative" else None
+    decode, suffix, fed, hits = eng2.decode, eng2.prefill_suffix, [], []
+
+    def noted_decode(tokens):
+        fed.append(dict(tokens.fresh) if isinstance(tokens, DecodeFeed) else None)
+        return decode(tokens)
+
+    def noted_suffix(prompt, slot, matched):
+        hits.append(slot)
+        return suffix(prompt, slot, matched)
+
+    eng2.decode, eng2.prefill_suffix = noted_decode, noted_suffix
+    res = _run(eng2, sched2, arrivals, speculative=spec)
+    assert {rid: o["tokens"] for rid, o in res.outcomes.items()} == {rid: o["tokens"] for rid, o in golden.outcomes.items()}
+    unread = [f for fresh in fed if fresh for f in fresh.values() if isinstance(f, PrefillStep)]
+    assert eng2.prefill_reads_ahead == len(unread)
+    if path == "speculative":
+        assert not unread and all(fresh is None for fresh in fed), "every step of a drafter's loop starts from the host's tokens"
+    else:
+        assert hits and eng2.prefill_launches + len(hits) == len(arrivals)
+        assert len(unread) <= eng2.prefill_launches - 1 and eng.prefill_reads_ahead == len(arrivals) - 1
+
+
 def test_loop_spec_plus_prefix_under_fault_battery(model_and_params, tp2_mesh):
     """The acceptance criterion: BOTH multipliers on, full fault battery —
     completed token streams bit-identical to the plain golden run, ledger

@@ -414,8 +414,9 @@ def test_mimos_decode_program_and_a_rung_compile_at_the_cells_size_with_no_copy_
 # what a program outside its kernels' bodies lowers to, for a described v5e: a digest of the lowered text with every
 # kernel's serialized body taken out (it holds the checkout's path and the kernel's line numbers; the bodies' own identity
 # is the jaxpr digests of tests/test_program_identity.py).  Taken on the parent of the PR that gave ``paged_decode`` a
-# folded sibling and the flash forward a sink and narrower values (8655180, this function on that tree).
-LOWERED_BEFORE_FOLDED_POOLS = {"decode step": "34098600aed73bdf", "rung of 512 positions": "bf0426431e3c2841"}
+# folded sibling and the flash forward a sink and narrower values (8655180, this function on that tree); the rung's anew
+# by PR 52, whose prefill program also returns its row's argmax (``bf0426431e3c2841`` before it).
+LOWERED_BEFORE_FOLDED_POOLS = {"decode step": "34098600aed73bdf", "rung of 512 positions": "e19c2768c59bc5a3"}
 
 
 @pytest.mark.parametrize("program", list(LOWERED_BEFORE_FOLDED_POOLS), ids=["decode", "rung512"])

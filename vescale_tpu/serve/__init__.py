@@ -21,7 +21,7 @@ from typing import Any, Dict
 
 from . import autoscale, fleet, fleettrace, journal, obs, prefix_cache, reqtrace, router, speculative
 from .autoscale import Autoscaler, RolloutController
-from .engine import DecodeFeed, DecodeStep, ServeEngine
+from .engine import DecodeFeed, DecodeStep, PrefillStep, ServeEngine
 from .fleet import FleetSupervisor, ReplicaSpec, RequestInbox, serve_replica
 from .journal import FencedEpochError, FleetJournal, LeaderLease
 from .fleettrace import (
@@ -57,6 +57,7 @@ __all__ = [
     "ServeEngine",
     "DecodeFeed",
     "DecodeStep",
+    "PrefillStep",
     "HybridServeEngine",
     "SlotStateUnsupported",
     "ServeResult",

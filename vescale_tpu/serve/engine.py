@@ -18,11 +18,13 @@ runs it if nobody has):
   training forward takes off-TPU) and the training ``rotary`` phase math;
   per-layer K/V of the rung's positions land in the slot's first pages via
   one scatter (the mask is causal, so the pad never reaches a real
-  position).  The layer stack is partitioned with the pipe engine's
-  stage-split (``pipe.pipe_stage._cuts_by_weight``) into ``num_stages``
-  separately compiled segments — the cut points a
-  prefill/decode-disaggregated deployment would place its pipeline
-  boundaries on.
+  position).  ``prefill`` only LAUNCHES those programs: the logits row and
+  its greedy id, taken in the program, stay on the device in the
+  ``PrefillStep`` it returns until a caller reads them.  The layer stack is
+  partitioned with the pipe engine's stage-split
+  (``pipe.pipe_stage._cuts_by_weight``) into ``num_stages`` separately
+  compiled segments — the cut points a prefill/decode-disaggregated
+  deployment would place its pipeline boundaries on.
 
   **decode** — one token per active slot: project q/k/v for the new
   position, scatter k/v into the page the slot's table maps that position
@@ -45,7 +47,9 @@ runs it if nobody has):
   on the device unless a caller reads them.  ``decode`` only LAUNCHES its
   step (``DecodeAhead``, shared with ``HybridServeEngine``): the ids are read
   when somebody reads them, and a ``DecodeFeed`` feeds the next step from
-  them as they lie on the device, so the serve loop keeps one step in flight.
+  them as they lie on the device (a slot prefilled since from its unread
+  ``PrefillStep``'s id), so the serve loop keeps one step in flight and a
+  prefill does not break it.
 
 Decode is a deterministic function of (params, prompt, cache geometry):
 an evicted-and-replayed request regenerates bit-identical tokens in any
@@ -65,7 +69,8 @@ from ..ndtimeline import predefined as _p
 from ..ndtimeline.api import ndtimeit, register_counter_source
 from .kv_cache import PagedKVCache
 
-__all__ = ["BlockSchedule", "DecodeAhead", "DecodeFeed", "DecodeStep", "ServeEngine", "prefill_buckets", "stack_params_check"]
+__all__ = ["BlockSchedule", "DecodeAhead", "DecodeFeed", "DecodeStep", "PrefillStep", "ServeEngine", "prefill_buckets",
+           "stack_params_check"]
 
 # A prefill under some hundred positions streams the weights and gets little
 # faster (the DeepSeek serve cut on a v5e: 6.96 ms of the device at 128
@@ -190,20 +195,75 @@ class DecodeStep:
         return out if dtype is None else out.astype(dtype, copy=False)
 
 
+class PrefillStep:
+    """The prefill one ``prefill`` call LAUNCHED, one step deep as a decode step
+    is: the call enqueues the prompt's programs and returns this at once, with
+    the next-token logits row and its greedy id both still on the device.
+    ``token`` is that id on the host: the argmax of the float32 row, taken in
+    the prefill's program (ties break to the lowest id, what ``greedy`` gives of
+    the row); its first read waits for the device under the
+    ``vs.serve-prefill.fetch`` span, tagged with the launch's number.  Until then
+    ``read`` is False, and a :class:`DecodeFeed` may name this step for its slot:
+    the decode step then takes the id as it lies on the device.  The row itself
+    crosses to the host only when a caller asks for it: ``np.asarray(step)`` is
+    the fp32 ``(vocab,)`` row (and reads the id, if nobody has), ``step.shape``
+    and ``step.dtype`` copy nothing, so a caller that stacked, compared or
+    sampled the row ``prefill`` used to return reads this as one.
+    Of a block engine the row is the last prompt position's own, and its serve
+    loop never reads either."""
+
+    __slots__ = ("_row", "_id", "_token", "_owner", "_launch")
+
+    def __init__(self, row, first, owner, launch: int):
+        self._row, self._id = row, first    # on the device, both
+        self._token: Optional[int] = None
+        self._owner = owner
+        self._launch = launch               # the launch's number: what the ``.fetch`` that reads it names
+
+    @property
+    def read(self) -> bool:
+        return self._token is not None
+
+    @property
+    def token(self) -> int:
+        if self._token is None:
+            self._owner._read_prefill(self)
+        return self._token
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        return tuple(self._row.shape)
+
+    @property
+    def dtype(self) -> np.dtype:
+        return np.dtype(self._row.dtype)
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        self.token      # the wait for the device, under the span that names it
+        out = np.asarray(self._row)
+        return out if dtype is None else out.astype(dtype, copy=False)
+
+
 class DecodeFeed:
     """The argument of ``decode`` that feeds a step FROM THE DEVICE: every slot
     takes the id that ``step`` (the step launched before, read or not) made for
-    it, as it lies on the device, but for the slots of ``fresh`` (``{slot:
-    token}``: prefilled since, so their first token is the host's), which a
-    program of a few bytes merges in.  The serve loop passes one whenever a
-    step is in flight; the call that takes it waits for ``step``'s ids after it
-    has enqueued its own program, so the device goes from one into the next.
+    it, as it lies on the device, but for the slots of ``fresh``, prefilled
+    since, which a program of a few bytes merges in.  ``fresh`` is ``{slot:
+    first token}``, and a first token is the host's int, or the slot's
+    :class:`PrefillStep` still unread: that slot then takes its id from the
+    device too (where every prefill leaves it, ``DecodeAhead._firsts``), and
+    the host reads the prefill after this step is enqueued behind it.  The
+    serve loop passes one whenever a step is in flight; the call that takes it
+    waits for ``step``'s ids after it has enqueued its own program, so the
+    device goes from one into the next.
 
     For an engine whose steps move blocks nothing is fed at all: a slot's open
     block lies in the cache's slot state, where the pass before left it.
     ``slots`` (``{slot: tokens the host will take from this call}``) then names
     the slots this call MOVES (every other slot's block is held as it is), and
-    ``step`` may be None (no step in flight): it only says what to wait for.
+    ``step`` may be None (no step in flight): it only says what to wait for,
+    and ``fresh`` only which prefills were launched since and left unread (for
+    the engine's count: a block engine's loop never reads one).
     ``fused`` names those of them whose block has nothing masked and that commit
     it AND run the first pass of the block after it in this one call
     (``BlockSchedule.FUSED``; the others run the one pass their state asks for),
@@ -212,7 +272,7 @@ class DecodeFeed:
 
     __slots__ = ("step", "fresh", "slots", "fused", "deferred")
 
-    def __init__(self, step: Optional[DecodeStep], fresh: Optional[Dict[int, int]] = None,
+    def __init__(self, step: Optional[DecodeStep], fresh: Optional[Dict[int, Any]] = None,
                  slots: Optional[Dict[int, int]] = None, fused: Sequence[int] = (), deferred: int = 0):
         self.step = step
         self.fresh = fresh or {}
@@ -317,14 +377,26 @@ class DecodeAhead:
         # have (the host's through a device_put): one signature, so one executable, whatever feeds a step
         self._ids_sharding = ids_sharding
 
-        def decode_merge(ids, tokens, fresh):   # named for the ``XLA Modules`` line: a step's programs begin ``jit_decode``
-            return jnp.where(fresh, tokens, ids)
+        # a step's programs begin ``jit_decode`` and a prefill's ``jit_prefill``: named for the ``XLA Modules`` line
+        def decode_merge(ids, tokens, fresh, firsts, unread):
+            return jnp.where(unread, firsts, jnp.where(fresh, tokens, ids))
+
+        def prefill_first(firsts, first, slot):
+            return firsts.at[slot].set(first)
 
         self._merge_fn = jax.jit(decode_merge, out_shardings=ids_sharding)
+        self._first_fn = jax.jit(prefill_first, out_shardings=ids_sharding)
+        # every slot's newest prefill's greedy id, on the device (what a decode step fed an unread ``PrefillStep``
+        # takes; made when first asked for: an engine is built without a device to hold it, for its programs'
+        # lowering alone), and the number of the launch that left it: a step that is not its slot's newest is
+        # read instead
+        self._firsts = None
+        self._first_launch: Dict[int, int] = {}
         # launches (``decode`` / ``prefill`` calls that enqueued their programs; ``warm`` counts none): their sum
         # is the NUMBER a launch carries to what it causes, one sequence for both kinds (``launches``)
         self.decode_launches = 0
         self.prefill_launches = 0
+        self.prefill_reads_ahead = 0
         self.decode_steps = 0
         self.decode_steps_ahead = 0
         self.logits_bytes_to_host = 0
@@ -341,11 +413,39 @@ class DecodeAhead:
 
         return jax.device_put(np.asarray(tokens, np.int32).reshape(self.cache.num_slots), self._ids_sharding)
 
-    def _merged_tokens(self, ids, fresh: Dict[int, int]):
-        tokens, mask = np.zeros((self.cache.num_slots,), np.int32), np.zeros((self.cache.num_slots,), bool)
-        for slot, token in fresh.items():
-            tokens[slot], mask[slot] = token, True
-        return self._merge_fn(ids, tokens, mask)
+    def _first_ids(self):
+        if self._firsts is None:
+            self._firsts = self._host_tokens(np.zeros((self.cache.num_slots,), np.int32))
+        return self._firsts
+
+    def _note_first(self, first, slot: int) -> None:
+        self._firsts = self._first_fn(self._first_ids(), first, np.int32(slot))
+
+    def _merged_tokens(self, ids, fresh: Dict[int, Any]):
+        S = self.cache.num_slots
+        tokens, mask, unread = np.zeros((S,), np.int32), np.zeros((S,), bool), np.zeros((S,), bool)
+        for slot, first in fresh.items():
+            if isinstance(first, PrefillStep) and not first.read and self._first_launch.get(slot) == first._launch:
+                unread[slot] = True     # its id lies in ``_firsts``: the prefill stays unread
+            else:
+                tokens[slot], mask[slot] = first.token if isinstance(first, PrefillStep) else first, True
+        return self._merge_fn(ids, tokens, mask, self._first_ids(), unread)
+
+    def _launched_prefill(self, row, first, slot: int) -> "PrefillStep":
+        """A prefill's programs are enqueued: its id goes to the slot's place
+        among the firsts, the launch is counted, and what ``prefill`` returns."""
+        launch = self.launches
+        self._note_first(first, slot)
+        self._first_launch[slot] = launch
+        self.prefill_launches += 1
+        return PrefillStep(row, first, self, launch)
+
+    def _read_prefill(self, step: "PrefillStep") -> None:
+        import jax
+
+        # waits for the device, then copies the id; ``launch`` names the span that caused it
+        with ndtimeit(_p.SERVE_PREFILL_FETCH, launch=step._launch):
+            step._token = int(jax.device_get(step._id))
 
     def _fed(self, tokens):
         """What the decode program takes for ``tokens``: the host's ids, or a
@@ -361,13 +461,15 @@ class DecodeAhead:
         engine's own: what the host will take from the step, and which slots fused)."""
         return None, None
 
-    def _warm_decode(self) -> None:
+    def _warm_decode(self, first) -> None:
         """The decode step (no slot active) in every form the loop feeds it: the
         host's tokens, the last step's ids as they are, and those with a fresh
-        slot's token merged in."""
+        slot's first token merged in (the host's, or ``first``, the id a warmed
+        prefill left on the device: one program takes both)."""
         cache = self.cache
         table = np.zeros((cache.num_slots, cache.config.pages_per_slot), np.int32)
         zeros = np.zeros((cache.num_slots,), np.int32)      # every slot's length, and its token
+        self._note_first(first, 0)
         ids = self._run_decode(table, zeros, self._host_tokens(zeros))[1]
         ids = self._run_decode(table, zeros, ids)[1]
         self._run_decode(table, zeros, self._merged_tokens(ids, {0: 0}))
@@ -378,22 +480,29 @@ class DecodeAhead:
         the cache lands at the slot's current length, and the
         :class:`DecodeStep` returned, unread, is that of the NEXT position.
         ``tokens`` is the host's ``(num_slots,)`` ids, or a :class:`DecodeFeed`
-        naming the step launched before: then the ids come from the device, and
+        naming the step launched before: then the ids come from the device (a
+        slot prefilled since from the host's first token or, its
+        :class:`PrefillStep` unread, from the id the prefill left on the device:
+        ``prefill_reads_ahead`` counts those), and
         once this step is enqueued the call waits for that one's ids (inside
         this call's ``vs.serve-decode`` span, under ``.fetch``), so that on
-        return the step before is read and this one is in flight.  Callers
+        return the step before is read and this one is in flight; an unread
+        prefill it named is the caller's to read, now behind this step.  Callers
         advance lengths via ``cache.advance`` for the slots whose token was
         real, after the call: a launch takes the lengths as they stand.  (An
         engine whose steps move blocks says in its own docstring what a pass
         does with ``tokens``, and what the step returned is of.)"""
         cache = self.cache
         lengths = cache.lengths_array()
-        before = tokens.step if isinstance(tokens, DecodeFeed) else None
+        feed = tokens if isinstance(tokens, DecodeFeed) else None
+        before = feed.step if feed is not None else None
         with ndtimeit(_p.SERVE_DECODE_CALL):
             n = self.launches
             with ndtimeit(_p.SERVE_DECODE_LAUNCH, launch=n):    # the enqueue alone
                 logits, ids, counts = self._run_decode(cache.table_array(), lengths, self._fed(tokens))
             self.decode_launches += 1
+            if feed is not None:    # the prefills this step went in behind, unread
+                self.prefill_reads_ahead += sum(isinstance(f, PrefillStep) and not f.read for f in feed.fresh.values())
             ahead = before is not None and not before.read
             rows, note = self._note(tokens, lengths)
             out = DecodeStep(ids, logits, self, (lengths, counts, ahead, note, n), rows)
@@ -477,7 +586,7 @@ class DecodeAhead:
                         if eos_id is not None and out[-1] == eos_id:
                             break
                 return out
-            tok = _pick(self.greedy(first), lambda: first)
+            tok = _pick(first.token, lambda: np.asarray(first))
             out.append(tok)
             for _ in range(max_new_tokens - 1):
                 if eos_id is not None and tok == eos_id:
@@ -727,8 +836,10 @@ class ServeEngine(DecodeAhead):
 
         def prefill_head(params, x, length):
             last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1, keepdims=False)
-            logits = head(params, last)[0]
-            return jax.lax.with_sharding_constraint(logits, rep_sharding)
+            logits = jax.lax.with_sharding_constraint(head(params, last)[0], rep_sharding)
+            # the row's greedy id, in this program as the decode step takes its own (``PrefillStep.token``)
+            first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return logits, jax.lax.with_sharding_constraint(first, rep_sharding)
 
         self._head_fn = jax.jit(prefill_head)
 
@@ -895,7 +1006,8 @@ class ServeEngine(DecodeAhead):
         """Compile and run every program of the serving path: each rung of the
         prefill ladder (into the null page only: a page row of zeros, so no
         slot's pages or length are touched) and the decode step (no slot
-        active, in each form of its tokens: ``_warm_decode``).  Twice over, as ``HybridServeEngine.warm`` does: the first
+        active, in each form of its tokens: ``_warm_decode``, which the last
+        rung's id feeds).  Twice over, as ``HybridServeEngine.warm`` does: the first
         call of all sees the cache's arrays as they were allocated, every later
         one as a program returned them, and a program that compiles again for
         those does it here.  The first ``prefill`` of an engine's life runs
@@ -905,14 +1017,14 @@ class ServeEngine(DecodeAhead):
         self._warmed = True
         for _ in range(2):
             for rung in self.buckets:
-                self._run_prefill(np.zeros((rung,), np.int32), 1, np.zeros((rung // page,), np.int32))
-            self._warm_decode()
+                _, first = self._run_prefill(np.zeros((rung,), np.int32), 1, np.zeros((rung // page,), np.int32))
+            self._warm_decode(first)
         return self
 
     # ---------------------------------------------------------------- API
     def _run_prefill(self, toks: np.ndarray, n: int, page_row: np.ndarray):
         """The programs of one prefill at ``len(toks)`` positions; the logits
-        row of position ``n - 1``, still on the device."""
+        row of position ``n - 1`` and its greedy id, still on the device."""
         import jax.numpy as jnp
 
         cache = self.cache
@@ -923,12 +1035,12 @@ class ServeEngine(DecodeAhead):
             x, k, v = fn(self.params, x, positions)
             ks.append(k)
             vs.append(v)
-        logits = self._head_fn(self.params, x, np.int32(n))
+        logits, first = self._head_fn(self.params, x, np.int32(n))
         k_stack = ks[0] if len(ks) == 1 else jnp.concatenate(ks, axis=0)
         v_stack = vs[0] if len(vs) == 1 else jnp.concatenate(vs, axis=0)
         kd, vd = self._commit_fn(cache.k.data, cache.v.data, k_stack, v_stack, page_row)
         cache.update(kd, vd)
-        return logits
+        return logits, first
 
     def _run_decode(self, table, lengths, tokens):
         cache = self.cache
@@ -936,9 +1048,13 @@ class ServeEngine(DecodeAhead):
         cache.update(kd, vd)
         return logits, next_ids, None
 
-    def prefill(self, prompt: Sequence[int], slot: int) -> np.ndarray:
-        """Run the prompt through the stack, write its K/V into ``slot``'s
-        reserved pages, and return the next-token logits (fp32, host).
+    def prefill(self, prompt: Sequence[int], slot: int) -> PrefillStep:
+        """LAUNCH the prompt through the stack: its K/V goes into ``slot``'s
+        reserved pages, and the :class:`PrefillStep` returned at once, unread,
+        holds the next-token logits row and its greedy id on the device
+        (``.token`` waits for the id; ``np.asarray(step)`` is the fp32 row, for
+        a caller that wants it).  The serve loop reads ``.token`` after it has
+        enqueued the decode step that takes the id from the device.
         One compiled program per stage and rung: the prompt is padded to the
         smallest of ``self.buckets`` that holds it, every rung is compiled by
         ``warm()``, so repeat calls never retrace."""
@@ -950,15 +1066,11 @@ class ServeEngine(DecodeAhead):
             self.warm()
         rung = next(b for b in self.buckets if b >= n)
         with ndtimeit(_p.SERVE_PREFILL_CALL):
-            launch = self.launches
-            with ndtimeit(_p.SERVE_PREFILL_LAUNCH, launch=launch, rung=rung, slot=slot):    # the enqueue alone
+            with ndtimeit(_p.SERVE_PREFILL_LAUNCH, launch=self.launches, rung=rung, slot=slot):     # the enqueue alone
                 toks = np.zeros((rung,), np.int32)
                 toks[:n] = np.asarray(prompt, np.int32)
                 page_row = cache.page_table[slot, : rung // cache.config.page_size].copy()
-                logits = self._run_prefill(toks, n, page_row)
-            self.prefill_launches += 1
-            with ndtimeit(_p.SERVE_PREFILL_FETCH, launch=launch):   # waits for the device, then copies the row
-                out = np.asarray(logits)
+                out = self._launched_prefill(*self._run_prefill(toks, n, page_row), slot)
         self.prefill_calls += 1
         self.prefill_tokens_real += n
         self.prefill_tokens_padded += rung
@@ -972,16 +1084,20 @@ class ServeEngine(DecodeAhead):
         ``launch=<n>`` tag of ``vs.serve-decode.launch``,
         ``vs.serve-prefill.launch`` and the ``.fetch`` that reads each); a step
         launched inside a session and read after it is in ``decode_launches``
-        and not in ``decode_steps``.  ``decode_steps`` counts the
-        decode steps READ (a ``decode`` call launches its step; whoever reads
+        and not in ``decode_steps``.  ``prefill_reads_ahead`` counts the
+        prefills whose id was still unread when the decode step that takes it
+        was enqueued (a :class:`DecodeFeed` named the ``PrefillStep``: the
+        device went from the prefill into the step; the rest were read first).
+        ``decode_steps`` counts the decode steps READ (a ``decode`` call launches its step; whoever reads
         its ids first counts it), ``decode_steps_ahead`` those of them that
         were launched while the step before was still unread (the pipeline
         engaged; the rest started cold).  ``logits_bytes_to_host`` is of
         ``decode`` calls: the bytes of fp32
         logits that callers copied out of their results (none for a step
         read through ``.tokens`` alone, ``vocab x 4`` a row, ``slots x
-        vocab x 4`` a whole read; prefill copies one row and
-        ``decode_multi`` every row, neither counted).  ``prefill_tokens_padded``
+        vocab x 4`` a whole read; a prefill's row is copied where a
+        caller asks for it and ``decode_multi`` copies every row, neither
+        counted).  ``prefill_tokens_padded``
         adds the rung each of the ``prefill_calls`` ran at, so padded / calls is
         the mean rung and 1 - real / padded the pad share.  ``decode_pages_read``
         of ``decode_pages_capacity`` says how far the ``paged_decode`` kernel
@@ -989,6 +1105,7 @@ class ServeEngine(DecodeAhead):
         over ``decode`` calls, against the ``slots x pages_per_slot`` the XLA
         leg gathers; both stay 0 on an engine built with the XLA leg."""
         return {"decode_launches": self.decode_launches, "prefill_launches": self.prefill_launches,
+                "prefill_reads_ahead": self.prefill_reads_ahead,
                 "decode_steps": self.decode_steps, "decode_steps_ahead": self.decode_steps_ahead,
                 "logits_bytes_to_host": self.logits_bytes_to_host,
                 "prefill_calls": self.prefill_calls,

@@ -160,17 +160,17 @@ import numpy as np
 
 from ..ndtimeline import predefined as _p
 from ..ndtimeline.api import ndtimeit, register_counter_source
-from .engine import BlockSchedule, DecodeAhead, DecodeFeed, prefill_buckets
+from .engine import BlockSchedule, DecodeAhead, DecodeFeed, PrefillStep, prefill_buckets
 from .kv_cache import KVCacheConfig, PagedKVCache
 
 __all__ = ["HybridServeEngine", "RowsByDemand", "hybrid_cache_config", "prefill_buckets"]
 
 # what the engine counts for every model (``trace_counters``); a model's own follow (``STEP_COUNTERS``)
-COUNTERS = ("decode_launches", "prefill_launches", "decode_steps", "decode_steps_ahead", "logits_bytes_to_host",
-            "logits_rows_made", "prefill_tokens_real", "prefill_tokens_padded", "prefill_bucket_tokens", "decode_pages_read",
-            "decode_pages_capacity", "moe_assignments", "moe_assignments_held", "moe_busiest_expert_tokens",
-            "moe_expert_slots", "moe_layer_steps", "moe_experts_touched", "moe_padded_layer_steps",
-            "moe_expert_layer_calls", "moe_grouped_layer_calls")
+COUNTERS = ("decode_launches", "prefill_launches", "prefill_reads_ahead", "decode_steps", "decode_steps_ahead",
+            "logits_bytes_to_host", "logits_rows_made", "prefill_tokens_real", "prefill_tokens_padded",
+            "prefill_bucket_tokens", "decode_pages_read", "decode_pages_capacity", "moe_assignments",
+            "moe_assignments_held", "moe_busiest_expert_tokens", "moe_expert_slots", "moe_layer_steps",
+            "moe_experts_touched", "moe_padded_layer_steps", "moe_expert_layer_calls", "moe_grouped_layer_calls")
 # ... and for a model that generates by blocks, in UNITS of B rows that went through the stack for a request (a
 # denoising pass or a commit; a slot that fuses in a call is two, its commit and the next block's first pass):
 # the units, those of them that were commits, the tokens the host took, the query rows that still had something
@@ -302,7 +302,9 @@ class HybridServeEngine(DecodeAhead):
             tokens, length, page_row, slot = rest[n:]
             logits, arrays = model.serve_prefill(c, params, dict(zip(names, rest[:n])), tokens, length, page_row, slot,
                                                  page=page, interpret=self.interpret)
-            return (logits,) + tuple(arrays[name] for name in names)
+            # the row's greedy id, in this program as the decode step takes its own (``PrefillStep.token``)
+            first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (logits, first) + tuple(arrays[name] for name in names)
 
         block = self.block
 
@@ -351,16 +353,17 @@ class HybridServeEngine(DecodeAhead):
         """Compile and run every program: each prefill bucket (into the null
         page and slot 0's state, which its next prefill rewrites) and the
         decode step (no slot active, in each form of its tokens:
-        ``_warm_decode``).  Twice over: the first call of all sees
+        ``_warm_decode``, which the last bucket's id feeds).  Twice over: the
+        first call of all sees
         the cache's arrays as they were allocated, every later one sees them as
         a program returned them, and a program that compiles again for those
         does it here.  Nothing compiles after this."""
         cache = self.cache
         for _ in range(2):
             for bucket in self.buckets:
-                self._run_prefill(np.zeros((bucket,), np.int32), 1,
-                                  np.zeros((bucket // cache.config.page_size,), np.int32), 0)
-            self._warm_decode()
+                _, first = self._run_prefill(np.zeros((bucket,), np.int32), 1,
+                                             np.zeros((bucket // cache.config.page_size,), np.int32), 0)
+            self._warm_decode(first)
         return self
 
     # ---------------------------------------------------------------- API
@@ -369,9 +372,9 @@ class HybridServeEngine(DecodeAhead):
         return tuple(arrays[name] for name in self._array_names)
 
     def _run_prefill(self, tokens, n, page_row, slot):
-        logits, *arrays = self._prefill_fn(self.params, *self._held(), tokens, np.int32(n), page_row, np.int32(slot))
+        logits, first, *arrays = self._prefill_fn(self.params, *self._held(), tokens, np.int32(n), page_row, np.int32(slot))
         self.cache.update_arrays(dict(zip(self._array_names, arrays)))
-        return logits
+        return logits, first
 
     def _run_decode(self, table, lengths, tokens):
         params = self.params
@@ -399,10 +402,11 @@ class HybridServeEngine(DecodeAhead):
             yields, deferred = sum(tokens.slots.values()), tokens.deferred
         return lengths % self.block.B, (yields, fused, deferred)
 
-    def _warm_decode(self) -> None:
+    def _warm_decode(self, first) -> None:
         if self.block is None:
-            return super()._warm_decode()
+            return super()._warm_decode(first)
         cache = self.cache
+        self._note_first(first, 0)      # (a prefill's program, whatever the engine)
         table = np.zeros((cache.num_slots, cache.config.pages_per_slot), np.int32)
         zeros = np.zeros((cache.num_slots,), np.int32)
         for tokens in (zeros, DecodeFeed(None, slots={})):      # both uses are one executable: warmed twice over
@@ -417,26 +421,26 @@ class HybridServeEngine(DecodeAhead):
         """A launched program of ``rows`` rows: its expert layers, and those that are the grouped kernel."""
         self._add({"moe_expert_layer_calls": self._expert_layers, "moe_grouped_layer_calls": self._grouped_layers.get(rows, 0)})
 
-    def prefill(self, prompt: Sequence[int], slot: int) -> np.ndarray:
-        """Run the prompt through the stack in its bucket, write what its
-        positions leave in the cache into ``slot``'s reserved pages (and its
-        state into ``slot``'s rows), and return the next-token logits (fp32,
-        host); of a block engine the last prompt position's own row."""
+    def prefill(self, prompt: Sequence[int], slot: int) -> PrefillStep:
+        """LAUNCH the prompt through the stack in its bucket: what its
+        positions leave in the cache goes into ``slot``'s reserved pages (and
+        its state into ``slot``'s rows), and the ``PrefillStep`` returned at
+        once, unread, holds the next-token logits row (of a block engine the
+        last prompt position's own row) and its greedy id on the device:
+        ``.token`` waits for the id, ``np.asarray(step)`` is the fp32 row.  The
+        serve loop reads ``.token`` after it has enqueued the decode step that
+        takes the id from the device, and of a block engine reads neither."""
         cache = self.cache
         n = len(prompt)
         if not (0 < n <= cache.max_seq_len):
             raise ValueError(f"prompt length {n} not in (0, {cache.max_seq_len}]")
         bucket = next(b for b in self.buckets if b >= n)
         with ndtimeit(_p.SERVE_PREFILL_CALL):
-            launch = self.launches
-            with ndtimeit(_p.SERVE_PREFILL_LAUNCH, launch=launch, rung=bucket, slot=slot):  # the enqueue alone
+            with ndtimeit(_p.SERVE_PREFILL_LAUNCH, launch=self.launches, rung=bucket, slot=slot):   # the enqueue alone
                 toks = np.zeros((bucket,), np.int32)
                 toks[:n] = np.asarray(prompt, np.int32)
                 page_row = cache.page_table[slot, : bucket // cache.config.page_size].copy()
-                logits = self._run_prefill(toks, n, page_row, slot)
-            self.prefill_launches += 1
-            with ndtimeit(_p.SERVE_PREFILL_FETCH, launch=launch):
-                out = np.asarray(logits)
+                out = self._launched_prefill(*self._run_prefill(toks, n, page_row, slot), slot)
         self.prefill_tokens_real += n
         self.prefill_tokens_padded += bucket
         self.prefill_bucket_tokens += bucket
@@ -479,7 +483,10 @@ class HybridServeEngine(DecodeAhead):
     def trace_counters(self) -> Dict[str, int]:
         """The engine's own counts since it was built.  Those ``ServeEngine``
         has mean the same here (``decode_launches`` / ``prefill_launches`` are
-        of calls that enqueued; ``decode_steps`` / ``decode_steps_ahead`` are
+        of calls that enqueued, ``prefill_reads_ahead`` of prefills still unread
+        when the decode step behind them was enqueued, which of a block engine's
+        loop is every one: it never reads a prefill; ``decode_steps`` /
+        ``decode_steps_ahead`` are
         of steps read; ``logits_bytes_to_host`` is what callers copied
         out of ``decode``'s results, ``logits_rows_made`` the rows of logits a
         block engine's steps computed because a caller read them (another

@@ -63,7 +63,7 @@ from ..resilience import faultsim as _fs
 from ..resilience.preempt import PreemptionHandler
 from ..resilience.watchdog import Watchdog
 from . import reqtrace
-from .engine import DecodeFeed, DecodeStep, ServeEngine
+from .engine import DecodeFeed, DecodeStep, PrefillStep, ServeEngine
 from .obs import ServeObservability
 from .scheduler import ContinuousBatchingScheduler, Request
 
@@ -212,13 +212,26 @@ def run_serve_resilient(
     ``speculative`` every step is read in the iteration that launched it.
     ``on_step`` runs with that one step possibly unread.
 
+    A prefill is one step deep too: ``engine.prefill`` launches and returns
+    its ``PrefillStep`` unread, and with a step in flight the iteration
+    launches every admitted request's prefill, then its decode step with
+    the fresh slots fed from the device (``DecodeFeed.fresh`` names the
+    ``PrefillStep``), and only then reads the first tokens
+    (``_first_tokens``: the TTFT is the instant the host has the token), so
+    the device goes from the prefill into the step.  A request whose
+    budget that unread token fills gets no step; an EOS as first token is
+    learned one step late, as any EOS.  With no step in flight, with
+    ``speculative``, after a prefix hit and for an engine whose ``prefill``
+    returns a row, the first token is read at once.  Every prefill is read
+    in the iteration that launched it: no boundary finds one unread.
+
     A step yields a COUNT of tokens a slot, which the loop learns from the
     engine (``engine.block``, a ``BlockSchedule``, where generation is by
     diffusion over blocks; one token a slot without it).  Such an engine's
-    prefill yields no token: the request's first tokens, and its TTFT, come
-    with its first block's commit, and every block's tokens are recorded
-    together, in order, when that call is read; a pass in between yields
-    none.  A commit rides in the call that runs the first pass of the block
+    prefill yields no token and is never read: the request's first tokens,
+    and its TTFT, come with its first block's commit, and every block's
+    tokens are recorded together, in order, when that call is read; a pass
+    in between yields none.  A commit rides in the call that runs the first pass of the block
     after it (``BlockSchedule.fuses``: the request is owed more), so a block
     of ``B`` tokens is ``T`` calls and a request of ``n`` blocks ``n T + 1``;
     the program has ``commit_places`` places for such commits a call, and a
@@ -577,15 +590,17 @@ def run_serve_resilient(
         )
         return ttft
 
-    def _prefill_admitted(step: int) -> None:
-        """Admit queued requests into free slots and prefill them; the
-        first sampled token is recorded immediately (its latency IS the
-        TTFT).  Where a step moves a block the prefill yields no token: it
-        opens the slot's first block, and the TTFT comes with that block."""
+    def _prefill_admitted(step: int) -> List[Tuple[Any, Any]]:
+        """Admit queued requests into free slots and LAUNCH their prefills,
+        reading none: ``[(request, what its prefill returned)]``, for
+        ``_first_tokens``.  Where a step moves a block the prefill yields no
+        token: it opens the slot's first block, and the TTFT comes with that
+        block."""
         with _nd.ndtimeit(_p.SERVE_ADMIT) as span:      # the scheduler's and the allocator's work
             admitted = scheduler.admit(step)
             if span is not None:
                 span.tag(admitted=len(admitted))
+        launched = []
         for inf in admitted:
             _beat(step, "prefill")
             inf.admit_wall = time.perf_counter()
@@ -599,14 +614,15 @@ def run_serve_resilient(
             if inf.prefix_hit:
                 # prefix-cache hit: the slot's leading table entries map
                 # cached pages (alloc_shared) — commit them and run only
-                # the suffix.  The TTFT decomposition still tiles: this
-                # request's prefill component is just smaller.
+                # the suffix (``decode_multi``'s road: its row comes read).
+                # The TTFT decomposition still tiles: this request's
+                # prefill component is just smaller.
                 cache.commit_prefill(inf.slot, inf.prefix_hit)
-                logits = engine.prefill_suffix(
+                first = engine.prefill_suffix(
                     inf.req.prompt, inf.slot, inf.prefix_hit
                 )
             else:
-                logits = engine.prefill(inf.req.prompt, inf.slot)
+                first = engine.prefill(inf.req.prompt, inf.slot)
                 cache.commit_prefill(inf.slot, len(inf.req.prompt))
             if scheduler.prefix is not None:
                 # adopt the freshly-written full pages into the radix tree
@@ -627,20 +643,29 @@ def run_serve_resilient(
                 speculative.admit(
                     inf.slot, inf.req.prompt, inf.req.max_new_tokens
                 )
-            if block is None:
-                _sample(inf.slot, engine.greedy(logits))
-            else:
+            if block is not None:
                 inf.block = block.open(len(inf.req.prompt))
+            launched.append((inf, first))
+        return launched
+
+    def _first_tokens(step: int, launched: List[Tuple[Any, Any]]) -> None:
+        """Read each launched prefill's first token (the wait for the device;
+        a row that came read, a stub engine's or a prefix hit's, is sampled on
+        the host) and record it: its latency IS the TTFT.  A block engine's
+        prefill yields no token and is never read: its books alone."""
+        for inf, first in launched:
+            if block is None:
+                _sample(inf.slot, first.token if isinstance(first, PrefillStep) else engine.greedy(first))
             now = time.perf_counter()
             prefill_s = now - inf.admit_wall
             reqtrace.prefill(inf.req.rid, inf.slot, prefill_s,
                              tokens=len(inf.req.prompt))
-            # cold-start retry seed: the first prefill wall time is the
-            # first measured bound on a step of this model (conservative —
-            # a decode step is cheaper than a full prefill)
-            scheduler.seed_step_time(prefill_s)
             _tel.observe("serve_ttft_prefill_seconds", prefill_s)
             if block is None:
+                # cold-start retry seed: the first prefill wall time is the
+                # first measured bound on a step of this model (conservative —
+                # a decode step is cheaper than a full prefill)
+                scheduler.seed_step_time(prefill_s)
                 # the prefill's half of the TTFT decomposition closes it
                 _event("admit", rid=inf.req.rid, slot=inf.slot, at_step=step,
                        replays=inf.replays, ttft_s=round(_first_token(inf, now), 6))
@@ -939,11 +964,25 @@ def run_serve_resilient(
                 # free drafter slots whose target terminated since the
                 # last boundary BEFORE admission can reuse the slot ids
                 speculative.sync_slots(scheduler.active)
+            # the prefills launched in this iteration whose first token stays
+            # on the device until the decode step that takes it from there is
+            # enqueued, by slot: ``{slot: (request, its PrefillStep)}``
+            unread: Dict[int, Tuple[Any, PrefillStep]] = {}
+            launched: List[Tuple[Any, Any]] = []
             if not draining and reload_job is None:
-                _prefill_admitted(step)
+                launched = _prefill_admitted(step)
+                # what needs the host's token before it can go on reads at
+                # once: no step in flight to feed from (the next starts cold,
+                # from the host's tokens), a drafter, a row that came read;
+                # a block engine's prefill yields none and only keeps its books
+                if block is None and pending is not None and speculative is None:
+                    unread = {inf.slot: (inf, first) for inf, first in launched if isinstance(first, PrefillStep)}
+                _first_tokens(step, [(inf, first) for inf, first in launched if inf.slot not in unread])
                 # the prefill-sampled token may already satisfy the request
                 # (max_new_tokens=1, or EOS on the first token): complete it
                 # here or the decode below would overrun its token budget
+                # (one still unread ends by its count, below; its EOS is
+                # learned a step late, as a decode step's is)
                 _finish_done(step)
             if scheduler.active:
                 if _fs.fires("slow_decode", ctx=f"serve_step{step}"):
@@ -961,12 +1000,13 @@ def run_serve_resilient(
                 # from a denoising pass), and what feeds each: the id the
                 # step in flight is making for it, as it lies on the device,
                 # or (prefilled since) the host's last token; a block lies
-                # on the device as the pass before left it.  A request whose
-                # budget the ids in flight fill ends by its count: nothing
-                # more is launched for it
+                # on the device as the pass before left it; a first token still
+                # unread is fed as its ``PrefillStep``, from the device too.  A
+                # request whose budget the ids in flight, or its unread first
+                # token, fill ends by its count: nothing more is launched for it
                 flight = pending[1] if pending is not None else {}
                 stepped: Dict[int, Tuple[Any, int, int]] = {}
-                fresh: Dict[int, int] = {}
+                fresh: Dict[int, Any] = {}
                 settles: Dict[int, int] = {}
                 # a block engine's: the slots whose commit rides with their next
                 # block's first pass, and those that found every place for commit
@@ -974,13 +1014,16 @@ def run_serve_resilient(
                 fused: List[int] = []
                 deferred: List[int] = []
                 for slot, inf in scheduler.active.items():
-                    unread = flight.get(slot, (None,))[0] is inf
-                    owed = inf.req.max_new_tokens - len(inf.tokens) - (flight[slot][2] if unread else 0)
+                    in_flight = flight.get(slot, (None,))[0] is inf
+                    owed = (inf.req.max_new_tokens - len(inf.tokens) - (flight[slot][2] if in_flight else 0)
+                            - (slot in unread))
                     if owed <= 0:
                         continue
                     if block is None:
                         stepped[slot], settles[slot] = (inf, 0, 1), 1
-                        if not unread:
+                        if slot in unread:
+                            fresh[slot] = unread[slot][1]
+                        elif not in_flight:
                             fresh[slot] = inf.tokens[-1]
                         continue
                     fuse = block.fuses(inf.block, owed)
@@ -1007,7 +1050,9 @@ def run_serve_resilient(
                     before, pending = pending, None
                     if stepped:
                         if block is not None:
+                            # (the prefills this call goes in behind, for the engine's count: nobody reads them)
                             feed = DecodeFeed(before[0] if before is not None else None,
+                                              {inf.slot: first for inf, first in launched},
                                               slots={slot: stepped[slot][2] for slot in active_slots
                                                      if slot not in deferred},
                                               fused=fused, deferred=len(deferred))
@@ -1086,6 +1131,10 @@ def run_serve_resilient(
                     if rate is not None:
                         _tel.set_gauge("serve_spec_accept_rate", rate)
                 dt = time.perf_counter() - t0
+                # the first tokens left on the device, now that the step that
+                # takes them from there is enqueued behind their prefills: the
+                # wait for a prefill is no part of the step's period
+                _first_tokens(step, list(unread.values()))
                 if _fs.fires("replica_kill", ctx=f"serve_step{step}"):
                     # an abrupt replica crash MID-LOAD (consulted only on
                     # decode steps with in-flight work, so the kill always
