@@ -182,8 +182,11 @@ def test_a_prefill_returns_unread_and_gives_its_row_and_its_rows_argmax_when_ask
     vocab = eng.config.vocab_size
     assert isinstance(step, PrefillStep)
     assert (step.shape, step.dtype) == ((vocab,), np.float32) and not step.read, "neither waits"
-    assert eng.trace_counters()["prefill_launches"] == start["prefill_launches"] + 1, "counted as launched at once"
+    # counted as launched at once; where prompts ride it WAITS for a step to carry it, and this read launches it alone
+    rides = getattr(eng, "rides", False)
+    assert step.launched != rides and eng.trace_counters()["prefill_launches"] == start["prefill_launches"] + (not rides)
     row = np.asarray(step)
+    assert step.launched and eng.trace_counters()["prefill_launches"] == start["prefill_launches"] + 1
     assert step.read and (row.shape, row.dtype) == ((vocab,), np.float32) and np.isfinite(row).all()
     assert step.token == int(np.argmax(row)) == eng.greedy(step)
     assert np.array_equal(np.stack([step, row]), np.stack([row, row])), "stacks with rows, as the reference check does"
@@ -259,7 +262,7 @@ def test_prefills_admitted_behind_a_step_in_flight_are_read_after_the_step_that_
         fresh = dict(tokens.fresh) if isinstance(tokens, DecodeFeed) else None
         assert fresh is None or not any(isinstance(f, PrefillStep) and f.read for f in fresh.values())
         out = decode(tokens)
-        log.append(("launch", fresh))
+        log.append(("launch", fresh, tokens.rider if fresh is not None else None))
         return out
 
     def logged_read(step):
@@ -288,18 +291,34 @@ def test_prefills_admitted_behind_a_step_in_flight_are_read_after_the_step_that_
     # the first request finds no step in flight: read at once, and the step after it starts from the host's token
     first_launch = next(i for i, e in enumerate(log) if e[0] == "launch")
     assert at[id(steps[0])] < first_launch and log[first_launch][1] is None and id(steps[0]) not in fed
-    # the two of one iteration: both launched, then ONE step that takes both ids from the device, then both read
     a, b = steps[1], steps[2]
-    assert launched[id(b)] == launched[id(a)] + 1 and fed[id(a)] == fed[id(b)] == launched[id(b)] + 1
-    assert (at[id(a)], at[id(b)]) == (fed[id(a)] + 1, fed[id(a)] + 2)
-    # a budget of one token: known by count, so no step takes its id; it is read in its own iteration all the same
-    assert id(steps[3]) not in fed and id(steps[3]) in at
+    carried = {id(e[2]): i for i, e in enumerate(log) if e[0] == "launch" and e[2] is not None}
+    if not getattr(eng, "rides", False):
+        # the two of one iteration: both launched, then ONE step that takes both ids from the device, then both read
+        assert launched[id(b)] == launched[id(a)] + 1 and fed[id(a)] == fed[id(b)] == launched[id(b)] + 1
+        assert (at[id(a)], at[id(b)]) == (fed[id(a)] + 1, fed[id(a)] + 2)
+        # a budget of one token: known by count, so no step takes its id; it is read in its own iteration all the same
+        assert id(steps[3]) not in fed and id(steps[3]) in at and not carried
+        ahead = 3
+    else:
+        # where prompts ride, the first of the two RIDES the step about to be launched (which steps neither slot) and
+        # the second the step after it; a rider's first token is its step's to make: the NEXT step takes it from
+        # the device, and the host reads it once that one is enqueued
+        assert launched[id(b)] == launched[id(a)] + 1 and carried[id(a)] == launched[id(b)] + 1
+        assert not {a.slot, b.slot} & set(log[carried[id(a)]][1])
+        assert carried[id(b)] == fed[id(a)] == carried[id(a)] + 1 and at[id(a)] == fed[id(a)] + 1
+        assert fed[id(b)] == at[id(a)] + 1 and at[id(b)] == fed[id(b)] + 1 and log[fed[id(b)]][2] is None
+        # a budget of one token rides too; known by count, no step takes its id, and it is read behind the step after
+        assert id(steps[3]) in carried and id(steps[3]) not in fed and at[id(steps[3])] > carried[id(steps[3])] + 1
+        assert set(carried) == {id(a), id(b), id(steps[3]), id(steps[4])}
+        assert counters["prefill_rides"] == 4
+        ahead = 4       # ... and a rider is unread when the step that carries it is enqueued, whatever its budget
     # an EOS as first token: the step behind it was launched before the host knew, and its id for the slot dropped
     assert fed[id(steps[4])] < at[id(steps[4])] and res.outcomes[4]["tokens"] == [eos_first.eos_id]
     assert all(step.read for step in steps.values())
     delta = {k: counters[k] for k in ("prefill_launches", "prefill_reads_ahead", "backend_compiles")}
-    assert delta == {"prefill_launches": 5, "prefill_reads_ahead": 3, "backend_compiles": 0}
-    assert eng.trace_counters()["prefill_reads_ahead"] == start["prefill_reads_ahead"] + 3
+    assert delta == {"prefill_launches": 5, "prefill_reads_ahead": ahead, "backend_compiles": 0}
+    assert eng.trace_counters()["prefill_reads_ahead"] == start["prefill_reads_ahead"] + ahead
 
 
 def test_no_program_compiles_after_warm_in_any_form_of_the_feed(rig, tmp_path):
@@ -425,7 +444,7 @@ def test_a_step_is_read_once_by_whoever_reads_first_and_counted_then(rig):
     eng, cache = rig
     cache.reset()
     slot = cache.alloc(5, 8)
-    eng.prefill(_prompt(3, 5), slot)
+    eng.prefill(_prompt(3, 5), slot).token      # (read: a prompt that waited would be launched, and counted, by the step)
     cache.commit_prefill(slot, 5)
     start = eng.trace_counters()
     toks = np.zeros((SLOTS,), np.int32)

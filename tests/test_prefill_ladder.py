@@ -95,7 +95,7 @@ def pair(request):
             if ladder is not None:
                 eng.buckets = ladder
             other = cache.alloc(300, 40)
-            eng.prefill(_prompt(300, 99), other)
+            eng.prefill(_prompt(300, 99), other).token      # (read: a prompt that waited would be launched by the next call)
             cache.commit_prefill(other, 300)
             rigs.append((eng, cache, other))
         built[stages] = rigs
@@ -116,12 +116,14 @@ def test_a_bucketed_prefill_is_the_prefill_padded_to_max_seq_len(pair, stages, n
     k0, v0 = _pools(cache)
     before = eng.trace_counters()
     got, want = eng.prefill(prompt, slot), full.prefill(prompt, full_slot)
-    k1, v1 = _pools(cache)
-    kf, vf = _pools(full_cache)
-    # the logits row of the last real position (read where it is asked for: shape and dtype copy nothing)
+    # the logits row of the last real position (read where it is asked for: shape and dtype copy nothing; a
+    # single-stage engine's prompt WAITS until then for a step to carry it, and is launched alone by this read)
     assert got.shape == want.shape == (64,) and got.dtype == np.float32 and not (got.read or want.read)
+    assert got.launched == want.launched == (stages > 1)
     got, want = np.asarray(got), np.asarray(want)
     assert np.abs(got - want).max() <= tol * np.abs(want).max()
+    k1, v1 = _pools(cache)
+    kf, vf = _pools(full_cache)
     # K/V of the prompt's positions, page by page (the last page up to the prompt's end)
     for i in range(-(-n // PAGE)):
         upto = min(PAGE, n - i * PAGE)
@@ -168,6 +170,10 @@ def test_decode_after_a_bucketed_prefill_reads_what_the_full_pad_wrote(pair):
 
 # --------------------------------------------------------------- the warm-up
 def _program_counts(eng):
+    """Executables a program: a prefill's programs (the ONE step that carries a prompt where prompts ride, the
+    stages' four elsewhere: an executable a rung each), then the decode step's."""
+    if eng.rides:
+        return [eng._ride_fn._cache_size(), eng._decode_fn._cache_size()]
     return [f._cache_size() for f in (eng._embed_fn, *eng._stage_fns, eng._head_fn, eng._commit_fn, eng._decode_fn)]
 
 
@@ -178,7 +184,7 @@ def test_after_the_warm_up_no_prompt_of_any_rung_compiles(stages, how, tmp_path)
     mesh = DeviceMesh(("tp",), (1,), devices=jax.devices()[:1])
     params = Llama(cfg).init(jax.random.key(0), jnp.ones((1, 8), jnp.int32))["params"]
     eng, cache = _engine(cfg, params, mesh, stages)
-    assert _program_counts(eng) == [0] * (stages + 4)
+    assert _program_counts(eng) == [0] * (2 if stages == 1 else stages + 4) and eng.rides == (stages == 1)
     if how == "warm":
         assert eng.warm() is eng
     else:                                              # the benchmark's own warm-up: one short prompt, one step

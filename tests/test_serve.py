@@ -430,7 +430,9 @@ def test_engine_a_joining_slot_fed_from_its_unread_prefill_decodes_what_the_host
 
 def test_engine_stage_split_matches_single_stage(model_and_params, tp2_mesh):
     """num_stages=2 splits the layer loop with the pipe engine's cut math;
-    the math is unchanged, so logits must be BITWISE identical."""
+    the math is unchanged.  (Bitwise until a single-stage engine's prompt went
+    through the step that carries it: that program's products run over the
+    decode rows and the prompt's together, so the last bits may differ.)"""
     model, params = model_and_params
     prompt = (11, 4, 9)
     outs = []
@@ -440,7 +442,7 @@ def test_engine_stage_split_matches_single_stage(model_and_params, tp2_mesh):
         assert len(eng.stage_bounds) == stages
         slot = cache.alloc(len(prompt), 1)
         outs.append(np.asarray(eng.prefill(prompt, slot)))
-    assert outs[0].tobytes() == outs[1].tobytes()
+    assert np.abs(outs[0] - outs[1]).max() <= 1e-6 * np.abs(outs[1]).max()
 
 
 def test_engine_rejects_scanned_params(tp2_mesh):

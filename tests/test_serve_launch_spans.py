@@ -135,10 +135,21 @@ def test_launches_are_numbered_without_a_hole_and_each_fetch_names_the_launch_it
     decodes, prefills = (_named(session, m) for m in LAUNCHES)
     numbers = sorted(s.tags["launch"] for s in decodes + prefills)
     assert numbers == list(range(numbers[0], numbers[0] + len(numbers))), "one sequence for both kinds, no hole"
-    assert numbers[-1] + 1 == eng.launches == eng.decode_launches + eng.prefill_launches
+    assert numbers[-1] + 1 == eng.launches == eng.decode_launches + eng.prefill_launches - eng.prefill_rides
     # in time order the numbers rise: a launch's number is its place in the order of enqueues
     in_time = [s.tags["launch"] for s in sorted(decodes + prefills, key=lambda s: s.start)]
     assert in_time == sorted(in_time)
+    if getattr(eng, "rides", False):
+        # where prompts ride, a prompt's program IS a decode step's (the step that carries it; alone, that step with
+        # every decode row idle): ONE launch span of the decode kind that says where and how wide, and no span of the
+        # prefill kind; the steps nobody's ``decode`` call launched (the prompts that went alone) are read by no fetch
+        assert not prefills and session.counters["prefill_rides"] > 0
+        prefills = [s for s in decodes if "rung" in s.tags]
+        alone = session.counters["prefill_launches"] - session.counters["prefill_rides"]
+        read_steps = {s.tags["launch"] for s in _named(session, P.SERVE_DECODE_FETCH)}
+        went_alone = [s for s in decodes if s.tags["launch"] not in read_steps]
+        assert len(went_alone) == alone > 0 and all(s in prefills for s in went_alone)
+        decodes = [s for s in decodes if s not in went_alone]
     # a prefill says where and how wide; it is read once, in the order of the launches, by a fetch that names
     # it and comes after its enqueue (a block engine's loop never reads one: its prefill yields no token)
     assert len(prefills) == len(res.outcomes) and all(set(s.tags) == {"launch", "rung", "slot"} for s in prefills)
@@ -154,17 +165,21 @@ def test_launches_are_numbered_without_a_hole_and_each_fetch_names_the_launch_it
     for fetch in _named(session, P.SERVE_DECODE_FETCH):
         (launch,) = [s for s in decodes if s.tags["launch"] == fetch.tags["launch"]]
         assert launch.start + launch.duration <= fetch.start + 1e-6, "a step is read after it was launched"
-    # each .launch lies inside the call's span that was there before
+    # each .launch lies inside the call's span that was there before (but a prompt's that its READER launched:
+    # nobody carried it, and the read of its first token is where it went, alone)
     calls = _named(session, P.SERVE_DECODE_CALL) + _named(session, P.SERVE_PREFILL_CALL)
-    for s in decodes + prefills:
+    for s in decodes + [p for p in prefills if not getattr(eng, "rides", False)]:
         assert any(c.start - 1e-6 <= s.start and s.start + s.duration <= c.start + c.duration + 1e-6 for c in calls)
 
 
 def test_the_counters_agree_with_the_ring(traced):
     session, _, _, _ = traced
     c = session.counters
-    assert c["decode_launches"] == len(_named(session, P.SERVE_DECODE_LAUNCH)) > 0
-    assert c["prefill_launches"] == len(_named(session, P.SERVE_PREFILL_LAUNCH)) > 0
+    # (a prompt that rode is its step's launch, one that went alone a launch of the decode kind of its own: both say ``rung``)
+    wide = [s for s in _named(session, P.SERVE_DECODE_LAUNCH) if "rung" in s.tags]
+    alone = len(wide) - c.get("prefill_rides", 0)
+    assert c["decode_launches"] == len(_named(session, P.SERVE_DECODE_LAUNCH)) - alone > 0
+    assert c["prefill_launches"] == len(_named(session, P.SERVE_PREFILL_LAUNCH)) + len(wide) > 0
     assert c["decode_steps"] == len(_named(session, P.SERVE_DECODE_FETCH)) == c["decode_launches"]
 
 
@@ -351,7 +366,9 @@ def test_dormant_every_new_site_is_the_one_nullcontext_and_builds_nothing(rig):
         patch.setattr(reqtrace, "time", NoClock())
         reqtrace.inbox_wait(1, 0.0)
     assert res.status == "completed"
-    assert {m for m, _, _ in seen} >= set(NEW_LIVE), "every new site ran"
+    # (where prompts ride no launch is of the prefill kind: a prompt's program is a decode step's)
+    assert {m for m, _, _ in seen} >= set(NEW_LIVE) - ({P.SERVE_PREFILL_LAUNCH} if getattr(rig[0], "rides", False) else set()), \
+        "every new site ran"
     dormant = real("anything")
     assert isinstance(dormant, contextlib.nullcontext)
     assert all(out is dormant and tags is None for _, tags, out in seen)

@@ -411,6 +411,53 @@ def test_mimos_decode_program_and_a_rung_compile_at_the_cells_size_with_no_copy_
         assert f"bf16{row}" in text, "a folded row is the lanes and a page's positions the sublanes: whole tiles"
 
 
+def test_deepseeks_step_that_carries_a_prompt_compiles_at_the_cells_size_with_one_product_a_weight(chip):
+    """``deepseek7b_serve_batch``'s decode step with the 128 rung's prompt in it (PR 53: 32 decode rows and 128
+    prompt rows, one array before every weight's product): eight ``paged_decode`` and eight flash forwards, no
+    copy of a pool, and every product of the stack over all 160 rows, the head's over the 32 steps' rows and the
+    prompt's last: a weight crosses the HBM once for both.  (The engine is the one the cell's family builds for
+    ``benchmark/rehearse.py``, from shapes alone.)"""
+    import re
+    from unittest import mock
+
+    from vescale_tpu.serve import ServeEngine
+
+    built, init = [], ServeEngine.__init__
+
+    def noted(self, *args, **kwargs):
+        init(self, *args, **kwargs)
+        built.append(self)
+
+    with mock.patch.object(ServeEngine, "__init__", noted):
+        _family, _config, _sizes, programs = _cells_programs(chip, "deepseek7b_serve_batch")
+        (engine,) = built
+        cache = engine.cache
+        S, page, rung = cache.num_slots, cache.config.page_size, 128
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+        # (traced here too, under the same answers: the flash forward asks for its platform while it is traced)
+        import importlib
+
+        from vescale_tpu import kernels
+
+        flash_ops = importlib.import_module("vescale_tpu.ops.flash_attention")
+        with mock.patch.object(kernels, "on_tpu", lambda: True), mock.patch.object(flash_ops, "jax", _JaxOnATpu()):
+            lowered = engine._ride_fn.lower(
+                engine.params, cache.k.data, cache.v.data, i32(S, cache.config.pages_per_slot), i32(S), i32(S), i32(S),
+                i32(rung), i32(), i32(rung // page), i32())
+    assert engine.rides and engine.kernel_decode and (S, rung) == (32, 128)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 16
+    assert not [line for line in text.splitlines() if " copy(" in line and "= bf16[8,3073,16,32,128]" in line]
+    products = re.findall(r"= bf16\[(\d+),(\d+)\]\S* convolution\(", text)
+    assert len(products) == 7 * 8 + 1 and sorted(set(products)) == [("160", "11008"), ("160", "4096"), ("33", "102400")]
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * 8 * 3073 * 16 * 32 * 128 * 2 and memory.temp_size_in_bytes < 64 << 20, memory
+    # ... and the step without a prompt is the program it was: its products over the 32 rows
+    (step,) = [low for title, low in programs if "decode step" in title]
+    assert set(re.findall(r"= bf16\[(\d+),\d+\]\S* convolution\(", step.compile().as_text())) == {"32"}
+
+
 # what a program outside its kernels' bodies lowers to, for a described v5e: a digest of the lowered text with every
 # kernel's serialized body taken out (it holds the checkout's path and the kernel's line numbers; the bodies' own identity
 # is the jaxpr digests of tests/test_program_identity.py).  Taken on the parent of the PR that gave ``paged_decode`` a

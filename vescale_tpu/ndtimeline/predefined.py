@@ -58,7 +58,9 @@ SERVE_SAMPLE = "vs.serve-sample"
 # that later waits for that program carries the same ``launch``: the span
 # that caused it.  The tags are the event's stats in the profiler's trace,
 # where a reader joins a launch to the device's program by kind and order
-# (benchmark/layer_metrics/_programs.py).
+# (benchmark/layer_metrics/_programs.py).  A decode step that CARRIES a prompt
+# (serve/engine.py, "a step that carries a prompt") is one launch of the
+# decode kind that says ``rung`` and ``slot`` too, and no prefill launch.
 SERVE_DECODE_LAUNCH = "vs.serve-decode.launch"
 SERVE_PREFILL_LAUNCH = "vs.serve-prefill.launch"
 # the serve loop's iteration, tiled (serve/loop.py; one call site each): what

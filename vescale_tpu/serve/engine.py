@@ -20,7 +20,11 @@ runs it if nobody has):
   one scatter (the mask is causal, so the pad never reaches a real
   position).  ``prefill`` only LAUNCHES those programs: the logits row and
   its greedy id, taken in the program, stay on the device in the
-  ``PrefillStep`` it returns until a caller reads them.  The layer stack is
+  ``PrefillStep`` it returns until a caller reads them.  Where the stack is
+  ONE stage it does not even launch: the prompt waits to RIDE the decode
+  step the serve loop is about to launch (below), and goes alone, through
+  that same program with every decode row idle, when a reader or another
+  call of the engine needs it first.  The layer stack is
   partitioned with the pipe engine's stage-split
   (``pipe.pipe_stage._cuts_by_weight``) into ``num_stages`` separately
   compiled segments — the cut points a prefill/decode-disaggregated
@@ -50,6 +54,18 @@ runs it if nobody has):
   them as they lie on the device (a slot prefilled since from its unread
   ``PrefillStep``'s id), so the serve loop keeps one step in flight and a
   prefill does not break it.
+
+  **a step that carries a prompt** (a single-stage engine, ``rides``) — the
+  decode step AND a prompt padded to its rung, one program a rung: the S
+  decode rows and the prompt's rows are one array before every weight's
+  product, so a weight crosses the HBM once for both (a step of S rows is
+  memory-bound: the prompt's rows ride under the bytes it moves anyway);
+  attention apart (the decode rows over their pages, the prompt causally
+  over itself), the prompt's K/V into its slot's pages a layer at a time,
+  the head over the decode rows and the prompt's last.  The serve loop asks
+  for it with ``DecodeFeed(..., rider=step)``; the rider's slot is idle in
+  that step, which makes its FIRST token and leaves it where a prefill
+  launched alone leaves its own.
 
 Decode is a deterministic function of (params, prompt, cache geometry):
 an evicted-and-replayed request regenerates bit-identical tokens in any
@@ -210,15 +226,37 @@ class PrefillStep:
     and ``step.dtype`` copy nothing, so a caller that stacked, compared or
     sampled the row ``prefill`` used to return reads this as one.
     Of a block engine the row is the last prompt position's own, and its serve
-    loop never reads either."""
+    loop never reads either.
 
-    __slots__ = ("_row", "_id", "_token", "_owner", "_launch")
+    **A prompt that waits to RIDE** (an engine whose ``rides`` is True: a
+    single-stage :class:`ServeEngine`).  Its ``prefill`` launches nothing: the
+    step it returns holds the padded prompt (``launched`` False, ``rung`` and
+    ``slot`` say how wide and where) until a decode step CARRIES it, which is
+    the caller's to ask for (``DecodeFeed(..., rider=step)``: the prompt's rows
+    then go through the stack in that step's program, beside the decode rows
+    and under the same read of every weight), or until something needs it: a
+    read of ``token`` or of the row, a ``decode`` that is fed from it
+    (``fresh``) or from the host's tokens, ``decode_multi`` or ``swap_params``
+    launch it first, alone, behind every prompt that came before it (prompts
+    go in the order they came), so a caller that knows nothing of riding gets
+    what it got before.  While it waits its slot is idle in every step.  The
+    serve loop decides (``run_serve_resilient``): the engine only offers."""
 
-    def __init__(self, row, first, owner, launch: int):
-        self._row, self._id = row, first    # on the device, both
+    __slots__ = ("_row", "_id", "_token", "_owner", "_launch", "_prompt", "rung", "slot", "_ahead")
+
+    def __init__(self, row, first, owner, launch: Optional[int], *, prompt=None, rung: Optional[int] = None,
+                 slot: Optional[int] = None):
+        self._row, self._id = row, first    # on the device, both (None while the prompt waits)
         self._token: Optional[int] = None
         self._owner = owner
         self._launch = launch               # the launch's number: what the ``.fetch`` that reads it names
+        self._prompt = prompt               # (padded tokens, length, page row) of a prompt that waits for its launch
+        self.rung, self.slot = rung, slot
+        self._ahead = False                 # counted among ``prefill_reads_ahead``
+
+    @property
+    def launched(self) -> bool:
+        return self._prompt is None
 
     @property
     def read(self) -> bool:
@@ -232,11 +270,11 @@ class PrefillStep:
 
     @property
     def shape(self) -> Tuple[int, ...]:
-        return tuple(self._row.shape)
+        return tuple(self._row.shape) if self.launched else (self._owner.config.vocab_size,)
 
     @property
     def dtype(self) -> np.dtype:
-        return np.dtype(self._row.dtype)
+        return np.dtype(np.float32 if self._row is None else self._row.dtype)
 
     def __array__(self, dtype=None, copy=None) -> np.ndarray:
         self.token      # the wait for the device, under the span that names it
@@ -257,6 +295,19 @@ class DecodeFeed:
     waits for ``step``'s ids after it has enqueued its own program, so the
     device goes from one into the next.
 
+    ``rider`` is a :class:`PrefillStep` that waits for its launch (an engine
+    whose ``rides`` is True returned it), and asks this step to CARRY it: the
+    prompt's rows go through the stack in the step's own program, each weight
+    read once for them and the decode rows together.  The rider's slot is NOT
+    stepped by the call that carries it (the program sees it empty; the caller
+    does not ``advance`` it): the step leaves the rider's first token where a
+    launched prefill leaves its own, so the NEXT call names the rider in
+    ``fresh`` as it would a prefill launched alone.  One rider a step, and the
+    caller's to choose.  A prompt that waits and that ``fresh`` names is
+    launched first, alone, and with it every prompt that came before it or
+    before the rider; a YOUNGER one goes on waiting, its slot idle in this
+    step too (the caller steps no slot whose prompt waits).
+
     For an engine whose steps move blocks nothing is fed at all: a slot's open
     block lies in the cache's slot state, where the pass before left it.
     ``slots`` (``{slot: tokens the host will take from this call}``) then names
@@ -270,15 +321,17 @@ class DecodeFeed:
     ``deferred`` how many slots the host held back from this call because every
     place for commit rows was taken (a count for the engine's counters)."""
 
-    __slots__ = ("step", "fresh", "slots", "fused", "deferred")
+    __slots__ = ("step", "fresh", "slots", "fused", "deferred", "rider")
 
     def __init__(self, step: Optional[DecodeStep], fresh: Optional[Dict[int, Any]] = None,
-                 slots: Optional[Dict[int, int]] = None, fused: Sequence[int] = (), deferred: int = 0):
+                 slots: Optional[Dict[int, int]] = None, fused: Sequence[int] = (), deferred: int = 0,
+                 rider: Optional[PrefillStep] = None):
         self.step = step
         self.fresh = fresh or {}
         self.slots = slots
         self.fused = fused
         self.deferred = deferred
+        self.rider = rider
 
 
 class BlockSchedule:
@@ -392,10 +445,11 @@ class DecodeAhead:
         # read instead
         self._firsts = None
         self._first_launch: Dict[int, int] = {}
-        # launches (``decode`` / ``prefill`` calls that enqueued their programs; ``warm`` counts none): their sum
-        # is the NUMBER a launch carries to what it causes, one sequence for both kinds (``launches``)
+        # launches (``decode`` calls and prompts whose programs were enqueued; ``warm`` counts none): the enqueues so
+        # far are the NUMBER a launch carries to what it causes, one sequence for both kinds (``launches``)
         self.decode_launches = 0
         self.prefill_launches = 0
+        self.prefill_rides = 0          # ... those of them that a decode step carried: one enqueue for both
         self.prefill_reads_ahead = 0
         self.decode_steps = 0
         self.decode_steps_ahead = 0
@@ -405,8 +459,9 @@ class DecodeAhead:
 
     @property
     def launches(self) -> int:
-        """The number the next launch takes (``launch=<n>`` on its spans)."""
-        return self.decode_launches + self.prefill_launches
+        """The number the next launch takes (``launch=<n>`` on its spans): the
+        enqueues so far, a step and the prompt it carried being one."""
+        return self.decode_launches + self.prefill_launches - self.prefill_rides
 
     def _host_tokens(self, tokens):
         import jax
@@ -443,6 +498,8 @@ class DecodeAhead:
     def _read_prefill(self, step: "PrefillStep") -> None:
         import jax
 
+        if not step.launched:       # nobody carried it: it goes now, alone, behind whatever came before it
+            self._launch_waiting((step,))
         # waits for the device, then copies the id; ``launch`` names the span that caused it
         with ndtimeit(_p.SERVE_PREFILL_FETCH, launch=step._launch):
             step._token = int(jax.device_get(step._id))
@@ -453,6 +510,13 @@ class DecodeAhead:
         if not isinstance(tokens, DecodeFeed):
             return self._host_tokens(tokens)
         return self._merged_tokens(tokens.step._ids, tokens.fresh) if tokens.fresh else tokens.step._ids
+
+    def _launch_waiting(self, needed=None, rider: Optional[PrefillStep] = None) -> Sequence[PrefillStep]:
+        """Launch, alone, the prompts that wait for a step to carry them and
+        are ``needed`` now (all of them, where None); the prompts that still
+        wait afterwards, ``rider`` among them (an engine that carries none has
+        none waiting)."""
+        return ()
 
     def _note(self, tokens, lengths: np.ndarray) -> Tuple[Optional[np.ndarray], Any]:
         """Of a launch: ``(rows, note)``: which row of a block ``step[slot]`` of
@@ -489,20 +553,39 @@ class DecodeAhead:
         return the step before is read and this one is in flight; an unread
         prefill it named is the caller's to read, now behind this step.  Callers
         advance lengths via ``cache.advance`` for the slots whose token was
-        real, after the call: a launch takes the lengths as they stand.  (An
+        real, after the call: a launch takes the lengths as they stand.  A feed
+        that names a ``rider`` (a :class:`PrefillStep` that waits; an engine
+        whose ``rides`` is True) makes this step CARRY that prompt: one program,
+        its span tagged ``rung`` and ``slot`` beside ``launch``; the rider's slot
+        is idle in it and is not to be advanced.  A prompt that waits and that the
+        feed names in ``fresh`` is launched first, alone (with the host's tokens:
+        every prompt that waits); one that goes on waiting has its slot idle.  (An
         engine whose steps move blocks says in its own docstring what a pass
         does with ``tokens``, and what the step returned is of.)"""
         cache = self.cache
         lengths = cache.lengths_array()
         feed = tokens if isinstance(tokens, DecodeFeed) else None
         before = feed.step if feed is not None else None
+        rider = feed.rider if feed is not None else None
         with ndtimeit(_p.SERVE_DECODE_CALL):
+            # a prompt that waits and that this step is fed from goes before it, alone (with the host's tokens: all of
+            # them); the slot of one that still waits, the rider's too, is idle in this step: its first token comes first
+            for waits in self._launch_waiting(feed.fresh.values() if feed is not None else None, rider):
+                lengths[waits.slot] = 0
             n = self.launches
-            with ndtimeit(_p.SERVE_DECODE_LAUNCH, launch=n):    # the enqueue alone
-                logits, ids, counts = self._run_decode(cache.table_array(), lengths, self._fed(tokens))
+            if rider is None:
+                with ndtimeit(_p.SERVE_DECODE_LAUNCH, launch=n):    # the enqueue alone
+                    logits, ids, counts = self._run_decode(cache.table_array(), lengths, self._fed(tokens))
+            else:
+                with ndtimeit(_p.SERVE_DECODE_LAUNCH, launch=n, rung=rider.rung, slot=rider.slot):
+                    logits, ids, counts = self._carry(rider, n, cache.table_array(), lengths, self._fed(tokens))
+                self.prefill_rides += 1
             self.decode_launches += 1
-            if feed is not None:    # the prefills this step went in behind, unread
-                self.prefill_reads_ahead += sum(isinstance(f, PrefillStep) and not f.read for f in feed.fresh.values())
+            if feed is not None:    # the prefills this step went in behind (or carried), unread
+                for f in (*feed.fresh.values(), rider):
+                    if isinstance(f, PrefillStep) and not (f.read or f._ahead):
+                        f._ahead = True
+                        self.prefill_reads_ahead += 1
             ahead = before is not None and not before.read
             rows, note = self._note(tokens, lengths)
             out = DecodeStep(ids, logits, self, (lengths, counts, ahead, note, n), rows)
@@ -671,6 +754,7 @@ class ServeEngine(DecodeAhead):
         # a function of the cache's geometry: whole pages, so a rung's K/V is a whole number of page writes
         self.buckets = prefill_buckets(cache.config.page_size, cache.max_seq_len, smallest=_SMALLEST_RUNG)
         self._warmed = False
+        self._waiting: List[PrefillStep] = []   # the prompts that wait for a step to carry them, in the order they came
         self._positions = np.arange(cache.max_seq_len, dtype=np.int32)[None, :]
         # what this engine has done, in plain integers (a trace session
         # reads them at its two ends: ``trace_counters``)
@@ -699,6 +783,14 @@ class ServeEngine(DecodeAhead):
         sharding = NamedSharding(self.mesh.jax_mesh, P())
         return jax.make_array_from_callback(host.shape, sharding, lambda idx: host[idx])
 
+    @property
+    def rides(self) -> bool:
+        """Does a prompt ride a decode step here?  The OFFER the serve loop
+        asks for (``HybridServeEngine`` has none): where the stack is one stage,
+        ``prefill`` returns a :class:`PrefillStep` that waits, and a ``decode``
+        whose :class:`DecodeFeed` names it as ``rider`` carries it."""
+        return len(self.stage_bounds) == 1
+
     def swap_params(self, params: Dict[str, Any]) -> Dict[str, Any]:
         """Hot-swap the weight tree WITHOUT rebuilding: every compiled
         program takes ``params`` as an argument, so a tree with identical
@@ -709,6 +801,7 @@ class ServeEngine(DecodeAhead):
         (the serving tree is never left half-swapped)."""
         import jax
 
+        self._launch_waiting()      # a prompt that waits was given to the tree that is serving now
         new = _as_tree(params)
         stack_params_check(new, self.config.num_hidden_layers)
         new = jax.tree_util.tree_map(self._replicate, new)
@@ -940,6 +1033,69 @@ class ServeEngine(DecodeAhead):
         self._decode_fn = jax.jit(decode, donate_argnums=(1, 2))
         self._init_decode_ahead(rep_sharding)
 
+        # ---- the step that CARRIES a prompt: the decode step above with ``rung`` rows more.  The S decode rows and
+        # the prompt's rows are ONE array before every weight's product, so a weight crosses the HBM once for both
+        # (a memory-bound step of S rows has room under the bytes it moves anyway); attention apart, each as it is
+        # alone: the decode rows over their pages, the prompt's causally over themselves (the whole prompt rides one
+        # step, so none of its rows attends to a page); the prompt's K/V into its slot's pages a layer at a time; the
+        # head over the decode rows and the prompt's row ``n - 1``.  The same program with every decode row idle
+        # (lengths of 0: each writes the null page) is a prompt launched ALONE, so a rung has the one program
+        from ..ops.flash_attention import flash_attention
+
+        def ride_layer(lp, x, kd, vd, l, table, pos, pg, off, positions, page_row):
+            """One decoder block over the S decode rows and the prompt's R rows, ``x`` (S + R, E): every weight in
+            ONE product over all of them; ``l`` is the layer's number as a value, so that the block is traced
+            once a rung whatever the depth (a set-up that traced it a layer paid seconds a rung for it)."""
+            R = x.shape[0] - S
+            xn = _rmsnorm(x, lp["input_layernorm"]["weight"], eps).astype(dtype)
+            q = dense(xn, lp["self_attn"]["q_proj"]["kernel"]).reshape(1, S + R, H, hd)
+            k = dense(xn, lp["self_attn"]["k_proj"]["kernel"]).reshape(1, S + R, KV, hd)
+            v = dense(xn, lp["self_attn"]["v_proj"]["kernel"]).reshape(1, S + R, KV, hd)
+            q, k = rotary(q, k, positions, theta)
+            kd = kd.at[l, pg, off].set(k[0, :S].astype(kd.dtype))
+            vd = vd.at[l, pg, off].set(v[0, :S].astype(vd.dtype))
+            # (page_row is the slot's first rung // page table entries; one past its reserved pages is the null page)
+            kd = kd.at[l, page_row].set(k[0, S:].reshape(R // page, page, KV, hd).astype(kd.dtype))
+            vd = vd.at[l, page_row].set(v[0, S:].reshape(R // page, page, KV, hd).astype(vd.dtype))
+            y = jnp.concatenate([
+                attend(q[0, :S], kd, vd, l, table, pos + 1),
+                flash_attention(q[:, S:], k[:, S:], v[:, S:], causal=True, interpret=interpret).reshape(R, H * hd)])
+            x = x + dense(y, lp["self_attn"]["o_proj"]["kernel"])
+            xn2 = _rmsnorm(x, lp["post_attention_layernorm"]["weight"], eps).astype(dtype)
+            gt = dense(xn2, lp["mlp"]["gate_proj"]["kernel"])
+            u = dense(xn2, lp["mlp"]["up_proj"]["kernel"])
+            return x + dense(jax.nn.silu(gt) * u, lp["mlp"]["down_proj"]["kernel"]), kd, vd
+
+        ride_layer = jax.jit(ride_layer)    # (inlined where it is called: the pools are the outer program's to donate)
+
+        # (the step's name on the device's ``XLA Modules`` line is its function's: ``jit_decode``, with a prompt or without)
+        def decode(params, kd, vd, table, lengths, tokens, firsts, prompt, n, page_row, slot):      # noqa: F811
+            pos = lengths
+            valid = (pos < Pmax * page) & (lengths > 0)     # the guard of the step without a prompt: an idle row writes the null page
+            safe = jnp.where(valid, pos, 0)
+            pg = jnp.take_along_axis(table, (safe // page)[:, None], axis=1)[:, 0]
+            pg = jnp.where(valid, pg, 0)
+            off = safe % page
+            x = jnp.concatenate([embed(params, tokens), embed(params, prompt)])     # (S + rung, E)
+            positions = jnp.concatenate([pos, jnp.arange(prompt.shape[0], dtype=pos.dtype)])[None]
+            for l in range(c.num_hidden_layers):
+                x, kd, vd = ride_layer(params[f"layers_{l}"], x, kd, vd, np.int32(l), table, pos, pg, off, positions, page_row)
+            last = jax.lax.dynamic_index_in_dim(x, S + n - 1, axis=0, keepdims=True)
+            logits = jax.lax.with_sharding_constraint(head(params, jnp.concatenate([x[:S], last])), rep_sharding)
+            ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)     # S steps' greedy ids, and the prompt's first
+            return (
+                logits[:S],
+                jax.lax.with_sharding_constraint(ids[:S], rep_sharding),
+                logits[S],
+                jax.lax.with_sharding_constraint(ids[S], rep_sharding),
+                # the first id to its slot's place among the firsts, where a prefill launched alone leaves its own
+                jax.lax.with_sharding_constraint(firsts.at[slot].set(ids[S]), rep_sharding),
+                jax.lax.with_sharding_constraint(kd, cache_sharding),
+                jax.lax.with_sharding_constraint(vd, cache_sharding),
+            )
+
+        self._ride_fn = jax.jit(decode, donate_argnums=(1, 2))      # one executable a rung: the prompt's shape is the rung
+
         # ---- multi-token step factory (speculative verify + prefix-cache
         # suffix prefill): the token width W is a COMPILE-TIME constant —
         # each distinct W lowers once into self._multi_fns and never
@@ -1007,18 +1163,34 @@ class ServeEngine(DecodeAhead):
         prefill ladder (into the null page only: a page row of zeros, so no
         slot's pages or length are touched) and the decode step (no slot
         active, in each form of its tokens: ``_warm_decode``, which the last
-        rung's id feeds).  Twice over, as ``HybridServeEngine.warm`` does: the first
+        rung's id feeds).  Where prompts ride (``rides``) a rung is ONE
+        program, the step that carries it, run here with every decode row
+        idle; else the stages' four.  Twice over, as ``HybridServeEngine.warm``
+        does: the first
         call of all sees the cache's arrays as they were allocated, every later
         one as a program returned them, and a program that compiles again for
         those does it here.  The first ``prefill`` of an engine's life runs
         this if nobody has; no ``prefill`` or ``decode`` compiles after it
         (``decode_multi`` lowers a width when it first meets it)."""
-        page = self.cache.config.page_size
+        import jax
+
+        cache = self.cache
+        page = cache.config.page_size
         self._warmed = True
+        zeros = np.zeros((cache.num_slots,), np.int32)
+        table = np.zeros((cache.num_slots, cache.config.pages_per_slot), np.int32)
+        ids = self._host_tokens(zeros)      # then the ids a step made: what a step that carries is fed
         for _ in range(2):
             for rung in self.buckets:
-                _, first = self._run_prefill(np.zeros((rung,), np.int32), 1, np.zeros((rung // page,), np.int32))
+                prompt = (np.zeros((rung,), np.int32), np.int32(1), np.zeros((rung // page,), np.int32))
+                if self.rides:
+                    _, ids, _, first = self._run_ride(table, zeros, ids, prompt, 0)
+                else:
+                    _, first = self._run_prefill(*prompt)
             self._warm_decode(first)
+        # ... and RUN: what the device still owes of a program's first run (an executable read from the compile cache is
+        # loaded when it first runs; on a v5e some ten seconds for a ladder of rungs) is set-up's, not the first request's
+        jax.block_until_ready((cache.k.data, cache.v.data))
         return self
 
     # ---------------------------------------------------------------- API
@@ -1048,16 +1220,72 @@ class ServeEngine(DecodeAhead):
         cache.update(kd, vd)
         return logits, next_ids, None
 
+    def _run_ride(self, table, lengths, tokens, prompt, slot: int):
+        """The step that carries ``prompt`` (tokens padded to the rung, length,
+        page row): the decode rows' logits and ids, the prompt's row and its
+        greedy id (which the program has put in ``slot``'s place among the
+        firsts), all still on the device."""
+        cache = self.cache
+        logits, next_ids, row, first, self._firsts, kd, vd = self._ride_fn(
+            self.params, cache.k.data, cache.v.data, table, lengths, tokens, self._first_ids(), *prompt, np.int32(slot))
+        cache.update(kd, vd)
+        return logits, next_ids, row, first
+
+    def _carry(self, rider: PrefillStep, launch: int, table, lengths, tokens):
+        """Launch the step numbered ``launch`` with ``rider``'s prompt in it;
+        the step's ``(logits, ids, counts)``.  The rider is launched from here on."""
+        logits, ids, rider._row, rider._id = self._run_ride(table, lengths, tokens, rider._prompt, rider.slot)
+        rider._prompt, rider._launch = None, launch
+        self._first_launch[rider.slot] = launch
+        self._waiting.remove(rider)
+        self.prefill_launches += 1
+        return logits, ids, None
+
+    def _launch_waiting(self, needed=None, rider: Optional[PrefillStep] = None) -> Sequence[PrefillStep]:
+        """The prompts that wait and are ``needed`` now (all, where None), and
+        every one that came BEFORE the last of them or before ``rider`` (what
+        two prompts leave in one slot's pages depends on their order), go now,
+        ALONE and in the order they came, but ``rider`` itself: the step that
+        carries a prompt with every decode row idle (lengths of 0: the rows
+        write the null page and their ids are nobody's), under a launch span of
+        that program's kind.  Returns the prompts that still wait."""
+        cache = self.cache
+        due = list(self._waiting)
+        if due and needed is not None:
+            named = {id(step) for step in (*needed, rider)}
+            due = due[: max((i for i, step in enumerate(due, 1) if id(step) in named), default=0)]
+        for step in due:
+            if step is not rider:
+                zeros = np.zeros((cache.num_slots,), np.int32)
+                n = self.launches
+                with ndtimeit(_p.SERVE_DECODE_LAUNCH, launch=n, rung=step.rung, slot=step.slot):
+                    self._carry(step, n, cache.table_array(), zeros, self._host_tokens(zeros))
+        return self._waiting
+
     def prefill(self, prompt: Sequence[int], slot: int) -> PrefillStep:
-        """LAUNCH the prompt through the stack: its K/V goes into ``slot``'s
+        """Send the prompt through the stack: its K/V goes into ``slot``'s
         reserved pages, and the :class:`PrefillStep` returned at once, unread,
         holds the next-token logits row and its greedy id on the device
         (``.token`` waits for the id; ``np.asarray(step)`` is the fp32 row, for
         a caller that wants it).  The serve loop reads ``.token`` after it has
         enqueued the decode step that takes the id from the device.
-        One compiled program per stage and rung: the prompt is padded to the
-        smallest of ``self.buckets`` that holds it, every rung is compiled by
-        ``warm()``, so repeat calls never retrace."""
+        The prompt is padded to the smallest of ``self.buckets`` that holds
+        it, every rung is compiled by ``warm()``, so repeat calls never retrace.
+
+        Where prompts RIDE (``self.rides``: the stack is one stage) this call
+        launches NOTHING: the step returned WAITS (``launched`` False) for a
+        ``decode`` whose :class:`DecodeFeed` names it as ``rider``, which runs
+        the prompt's rows through that step's own program, and the K/V, the row
+        and the id exist from then on.  Whether a prompt rides is the CALLER's
+        decision (``run_serve_resilient`` takes the offer up where a step is
+        about to be launched); a caller that knows nothing of it gets what it
+        got before, because a prompt that waits is launched alone, in the order
+        the prompts came, by whatever needs it first: a read of its token or
+        row, a ``decode`` fed from it or from the host's tokens,
+        ``decode_multi``, ``swap_params``.  What does NOT see it is a direct
+        read of ``cache.k`` / ``cache.v`` between this call and those.
+        With more stages a prefill is a program a stage (and three small ones),
+        launched here, as before."""
         cache = self.cache
         n = len(prompt)
         if not (0 < n <= cache.max_seq_len):
@@ -1066,11 +1294,15 @@ class ServeEngine(DecodeAhead):
             self.warm()
         rung = next(b for b in self.buckets if b >= n)
         with ndtimeit(_p.SERVE_PREFILL_CALL):
-            with ndtimeit(_p.SERVE_PREFILL_LAUNCH, launch=self.launches, rung=rung, slot=slot):     # the enqueue alone
-                toks = np.zeros((rung,), np.int32)
-                toks[:n] = np.asarray(prompt, np.int32)
-                page_row = cache.page_table[slot, : rung // cache.config.page_size].copy()
-                out = self._launched_prefill(*self._run_prefill(toks, n, page_row), slot)
+            toks = np.zeros((rung,), np.int32)
+            toks[:n] = np.asarray(prompt, np.int32)
+            page_row = cache.page_table[slot, : rung // cache.config.page_size].copy()
+            if self.rides:      # nothing is launched: the prompt waits for the step that carries it, or for a reader
+                out = PrefillStep(None, None, self, None, prompt=(toks, np.int32(n), page_row), rung=rung, slot=slot)
+                self._waiting.append(out)
+            else:
+                with ndtimeit(_p.SERVE_PREFILL_LAUNCH, launch=self.launches, rung=rung, slot=slot):     # the enqueue alone
+                    out = self._launched_prefill(*self._run_prefill(toks, n, page_row), slot)
         self.prefill_calls += 1
         self.prefill_tokens_real += n
         self.prefill_tokens_padded += rung
@@ -1084,7 +1316,12 @@ class ServeEngine(DecodeAhead):
         ``launch=<n>`` tag of ``vs.serve-decode.launch``,
         ``vs.serve-prefill.launch`` and the ``.fetch`` that reads each); a step
         launched inside a session and read after it is in ``decode_launches``
-        and not in ``decode_steps``.  ``prefill_reads_ahead`` counts the
+        and not in ``decode_steps``.  Where prompts ride, ``prefill_launches``
+        counts the prompts whose program was enqueued and ``prefill_rides``
+        those of them that a ``decode`` call's step CARRIED (one enqueue, one
+        number, for both; the rest went alone: that step's program with every
+        decode row idle, a ``vs.serve-decode.launch`` of its own that no
+        ``decode_launches`` counts and no fetch reads).  ``prefill_reads_ahead`` counts the
         prefills whose id was still unread when the decode step that takes it
         was enqueued (a :class:`DecodeFeed` named the ``PrefillStep``: the
         device went from the prefill into the step; the rest were read first).
@@ -1105,7 +1342,7 @@ class ServeEngine(DecodeAhead):
         over ``decode`` calls, against the ``slots x pages_per_slot`` the XLA
         leg gathers; both stay 0 on an engine built with the XLA leg."""
         return {"decode_launches": self.decode_launches, "prefill_launches": self.prefill_launches,
-                "prefill_reads_ahead": self.prefill_reads_ahead,
+                "prefill_rides": self.prefill_rides, "prefill_reads_ahead": self.prefill_reads_ahead,
                 "decode_steps": self.decode_steps, "decode_steps_ahead": self.decode_steps_ahead,
                 "logits_bytes_to_host": self.logits_bytes_to_host,
                 "prefill_calls": self.prefill_calls,
@@ -1125,6 +1362,7 @@ class ServeEngine(DecodeAhead):
         garbage the host must ignore.  Returns (num_slots, W, vocab)
         fp32."""
         cache = self.cache
+        self._launch_waiting()      # (a prompt that waits goes before whatever else touches the cache)
         tokens = np.asarray(tokens, np.int32)
         W = int(tokens.shape[-1])
         tokens = tokens.reshape(cache.num_slots, W)

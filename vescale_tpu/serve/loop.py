@@ -223,7 +223,32 @@ def run_serve_resilient(
     learned one step late, as any EOS.  With no step in flight, with
     ``speculative``, after a prefix hit and for an engine whose ``prefill``
     returns a row, the first token is read at once.  Every prefill is read
-    in the iteration that launched it: no boundary finds one unread.
+    in the iteration that launched it, but the one that rides:
+
+    A prompt RIDES the decode step where the engine OFFERS that
+    (``engine.rides``: a single-stage ``ServeEngine``, whose ``prefill``
+    then launches nothing and returns a ``PrefillStep`` that waits;
+    ``HybridServeEngine`` and a block engine have no such attribute, and
+    nothing here names a model).  THIS LOOP decides, on what it observes:
+    with a step in flight, no ``speculative`` and no prefix hit, an
+    admitted request's prompt WAITS (``waiting``), and the oldest prompt
+    that waits goes into the step about to be launched, if that step moves
+    a slot (``DecodeFeed.rider``): one program, each weight read once for the
+    decode rows and the prompt's together.  One rider a step: a second
+    admission of the same iteration rides the step after.  A slot whose
+    prompt waits or rides is not stepped (no ``cache.advance``, no token
+    owed from it; the engine runs its decode row idle): the step that
+    carries the prompt MAKES its first token, the step after it takes that
+    from the device as it takes any unread prefill's, and the host reads it
+    once that one is enqueued, a step late (``pending`` holds the rider
+    beside its step), so no gap opens on the device.  Nothing waits across
+    a boundary that settles: ``_settle`` reads the step in flight, its
+    rider, and every prompt that still waits (whose read launches it, alone),
+    as does an iteration that finds no slot to step.  A request cancelled
+    from ``on_step`` while its prompt waits is dropped here and its prompt
+    left to the engine, which launches it alone before any prompt that came
+    after it.  ``engine.trace_counters()["prefill_rides"]`` of
+    ``["prefill_launches"]`` says how often a prompt rode.
 
     A step yields a COUNT of tokens a slot, which the loop learns from the
     engine (``engine.block``, a ``BlockSchedule``, where generation is by
@@ -337,10 +362,19 @@ def run_serve_resilient(
             "speculative= and a prefix cache need a step of one token a position and a cache without slot "
             f"state; {type(engine).__name__} generates by blocks of {block.B}, whose open block is slot state"
         )
+    # does a prompt ride a decode step?  The engine's OFFER (a single-stage
+    # ``ServeEngine``'s; an engine without the attribute launches every
+    # prompt alone); this loop takes it up where a step is about to be launched
+    rides = getattr(engine, "rides", False)
     # the decode step in flight: launched, its ids not yet read, with what
     # each stepped slot held at the launch and will be given of the step's
-    # ids: ``{slot: (request, skip, count)}``.  At most one.
-    pending: Optional[Tuple[DecodeStep, Dict[int, Tuple[Any, int, int]]]] = None
+    # ids: ``{slot: (request, skip, count)}``, and the prompt it carries:
+    # ``[(request, its PrefillStep)]``, one or none.  At most one.
+    pending: Optional[Tuple[DecodeStep, Dict[int, Tuple[Any, int, int]], List[Tuple[Any, PrefillStep]]]] = None
+    # the requests admitted whose prompt still WAITS for a step to ride, by
+    # slot and in the order they came: ``{slot: (request, its PrefillStep)}``.
+    # A step carries one; a slot that waits is stepped by none
+    waiting: Dict[int, Tuple[Any, PrefillStep]] = {}
 
     # ------------------------------------------- observability wiring
     # goodput/MFU accounting + the /healthz + /router providers; the ops
@@ -654,6 +688,8 @@ def run_serve_resilient(
         the host) and record it: its latency IS the TTFT.  A block engine's
         prefill yields no token and is never read: its books alone."""
         for inf, first in launched:
+            if scheduler.active.get(inf.slot) is not inf:
+                continue    # (a rider read a step late: its request was cancelled or evicted since)
             if block is None:
                 _sample(inf.slot, first.token if isinstance(first, PrefillStep) else engine.greedy(first))
             now = time.perf_counter()
@@ -696,7 +732,7 @@ def run_serve_resilient(
                 _event("complete", rid=inf.req.rid, slot=slot, at_step=step,
                        tokens=len(inf.tokens))
 
-    def _record(flight: Tuple[DecodeStep, Dict[int, Tuple[Any, int, int]]]) -> Dict[int, Tuple[Any, int]]:
+    def _record(flight: Tuple[DecodeStep, Dict[int, Tuple[Any, int, int]], Any]) -> Dict[int, Tuple[Any, int]]:
         """Read a launched step's ids (the wait for the device, unless the
         ``decode`` call that it fed has waited already) and record, in
         order, those the launch meant for each slot (its one; of a block,
@@ -704,7 +740,7 @@ def run_serve_resilient(
         holds the request it was launched for: a slot cancelled, evicted or
         completed since, or taken by another request, drops its ids.
         Returns ``{slot: (request, tokens recorded)}`` of those kept."""
-        dstep, slots = flight
+        dstep, slots, _rider = flight
         # plain ints, a row a slot (of one id, or of a block's): read once
         next_ids = dstep.tokens.reshape(cache.num_slots, -1).tolist()
         kept: Dict[int, Tuple[Any, int]] = {}
@@ -795,6 +831,10 @@ def run_serve_resilient(
         flight, pending = pending, None
         t0 = time.perf_counter()
         kept = _record(flight)
+        # ... and the first token of the prompt that step carried, and of those that still waited
+        # for a step to ride (their read launches them, alone: nothing waits across this)
+        _first_tokens(step, flight[2] + list(waiting.values()))
+        waiting.clear()
         _close_step(step, time.perf_counter() - t0, len(flight[1]), kept)
 
     step = 0
@@ -964,10 +1004,18 @@ def run_serve_resilient(
                 # free drafter slots whose target terminated since the
                 # last boundary BEFORE admission can reuse the slot ids
                 speculative.sync_slots(scheduler.active)
-            # the prefills launched in this iteration whose first token stays
-            # on the device until the decode step that takes it from there is
-            # enqueued, by slot: ``{slot: (request, its PrefillStep)}``
+            # the prefills whose first token stays on the device until the
+            # decode step that takes it from there is enqueued, by slot:
+            # ``{slot: (request, its PrefillStep)}``: the prompt that the step in
+            # flight CARRIES (its first token is that step's to make), and those
+            # launched in this iteration
             unread: Dict[int, Tuple[Any, PrefillStep]] = {}
+            if pending is not None:
+                unread = {inf.slot: (inf, first) for inf, first in pending[2] if scheduler.active.get(inf.slot) is inf}
+            for slot in [slot for slot, (inf, _) in waiting.items() if scheduler.active.get(slot) is not inf]:
+                # cancelled (a hook's ``timeout``) while its prompt waited: the engine still launches it, alone
+                # and before any prompt that came after it, so the pages are written in the order of admission
+                del waiting[slot]
             launched: List[Tuple[Any, Any]] = []
             if not draining and reload_job is None:
                 launched = _prefill_admitted(step)
@@ -976,8 +1024,12 @@ def run_serve_resilient(
                 # from the host's tokens), a drafter, a row that came read;
                 # a block engine's prefill yields none and only keeps its books
                 if block is None and pending is not None and speculative is None:
-                    unread = {inf.slot: (inf, first) for inf, first in launched if isinstance(first, PrefillStep)}
-                _first_tokens(step, [(inf, first) for inf, first in launched if inf.slot not in unread])
+                    for inf, first in launched:
+                        if isinstance(first, PrefillStep):
+                            # (a prompt the engine launched is read behind this iteration's step; one that waits for
+                            # its launch, where prompts ride, behind the step after the one that carries it)
+                            (waiting if rides and not first.launched else unread)[inf.slot] = (inf, first)
+                _first_tokens(step, [(inf, first) for inf, first in launched if inf.slot not in unread and inf.slot not in waiting])
                 # the prefill-sampled token may already satisfy the request
                 # (max_new_tokens=1, or EOS on the first token): complete it
                 # here or the decode below would overrun its token budget
@@ -1014,6 +1066,8 @@ def run_serve_resilient(
                 fused: List[int] = []
                 deferred: List[int] = []
                 for slot, inf in scheduler.active.items():
+                    if slot in waiting:
+                        continue    # its prompt has not been through the stack yet
                     in_flight = flight.get(slot, (None,))[0] is inf
                     owed = (inf.req.max_new_tokens - len(inf.tokens) - (flight[slot][2] if in_flight else 0)
                             - (slot in unread))
@@ -1035,6 +1089,19 @@ def run_serve_resilient(
                     stepped[slot] = (inf, skip, count)
                     if fuse:
                         fused.append(slot)
+                # a prompt RIDES the step about to be launched where the engine
+                # offers that and the step moves a slot: the oldest of the prompts
+                # that wait (one a step: a second admission of one iteration rides the
+                # step after).  Its slot is not stepped by the step that carries it:
+                # that step makes its FIRST token, which the step after it is fed from
+                # the device and the host reads once that one is enqueued.  With no slot
+                # to step there is no step to ride: the prompts that wait go alone, now
+                rider: List[Tuple[Any, PrefillStep]] = []
+                if waiting and stepped:
+                    rider = [waiting.pop(next(iter(waiting)))]
+                elif waiting:
+                    unread.update(waiting)
+                    waiting.clear()
                 active_slots = sorted(stepped)
                 drafted_rows = (speculative.drafted_slots(active_slots)
                                 if speculative is not None else [])
@@ -1061,10 +1128,10 @@ def run_serve_resilient(
                             for slot, tok in fresh.items():
                                 feed[slot] = tok
                         else:
-                            feed = DecodeFeed(before[0], fresh)
+                            feed = DecodeFeed(before[0], fresh, rider=rider[0][1] if rider else None)
                         # launch; with a step in flight the call then waits
                         # for THAT step's ids, the device already in this one
-                        pending = (engine.decode(feed), stepped)
+                        pending = (engine.decode(feed), stepped, rider)
                         for slot in active_slots:
                             # a step appends one position to every slot it
                             # stepped, whatever the token; the call that commits
