@@ -1,4 +1,4 @@
-"""The serve programs of the six families behind ``HybridServeEngine`` are WHAT
+"""The serve programs of the seven families behind ``HybridServeEngine`` are WHAT
 THEIR FUNCTIONS COMPUTE, not where those are written: each family's prefill at
 its first two rungs and its decode step, at the toy widths of the family's own
 test file in the type they are served in (bfloat16), on both legs
@@ -14,7 +14,9 @@ home each (e1809cb: this file on that tree, ``PROGRAMS`` printed by ``python
 tests/test_program_identity.py``).  A PR that changes these programs on purpose
 takes them anew.  (``mimo_v2``'s six were taken on the tree of the PR that brought
 the family, PR 50: they pin it from there on.  The twenty-four prefills were taken
-anew by PR 52, whose prefill also returns its row's argmax: the scopes did not move.)"""
+anew by PR 52, whose prefill also returns its row's argmax: the scopes did not move.  ``longcat_flash``'s six were
+taken on the tree of the PR that brought the family and moved the latent-attention block from ``models/deepseek_v2.py``
+to ``models/mla.py``, PR 54: ``deepseek_v2``'s six held through the move, digest and scopes, as they stand here.)"""
 
 import collections
 import dataclasses
@@ -29,6 +31,7 @@ import test_deepseek_v2
 import test_falcon_h1
 import test_granite_hybrid
 import test_laguna
+import test_longcat_flash
 import test_mimo_v2
 import test_sdar_moe
 from vescale_tpu.mesh import DeviceMesh
@@ -37,7 +40,7 @@ from vescale_tpu.serve import HybridServeEngine, PagedKVCache
 from vescale_tpu.serve.hybrid_engine import hybrid_cache_config
 
 FAMILIES = {"granite_hybrid": test_granite_hybrid, "deepseek_v2": test_deepseek_v2, "sdar_moe": test_sdar_moe,
-            "falcon_h1": test_falcon_h1, "laguna": test_laguna, "mimo_v2": test_mimo_v2}
+            "falcon_h1": test_falcon_h1, "laguna": test_laguna, "mimo_v2": test_mimo_v2, "longcat_flash": test_longcat_flash}
 LEGS = {"xla_legs": None, "kernels_interpreted": "interpret"}
 WHICH = ("prefill_rung_1", "prefill_rung_2", "decode")
 
@@ -78,6 +81,12 @@ PROGRAMS = {
     "mimo_v2/kernels_interpreted/prefill_rung_1": ('2566616dac8eeeeb', 'vs.attn=4916 vs.mlp=9 vs.moe=132'),
     "mimo_v2/kernels_interpreted/prefill_rung_2": ('44cfbf93fd2828e4', 'vs.attn=4916 vs.mlp=9 vs.moe=132'),
     "mimo_v2/kernels_interpreted/decode": ('e1b4216a59e292bd', 'vs.attn=931 vs.mlp=9 vs.moe=216'),
+    "longcat_flash/xla_legs/prefill_rung_1": ('0e64cd6b04187dea', 'vs.attn=564 vs.mlp=36 vs.moe=68'),
+    "longcat_flash/xla_legs/prefill_rung_2": ('37d4316b11599d06', 'vs.attn=564 vs.mlp=36 vs.moe=68'),
+    "longcat_flash/xla_legs/decode": ('02f28e4b8fa49840', 'vs.attn=704 vs.mlp=36 vs.moe=74'),
+    "longcat_flash/kernels_interpreted/prefill_rung_1": ('dc1e4a16dd58625c', 'vs.attn=2404 vs.mlp=36 vs.moe=68'),
+    "longcat_flash/kernels_interpreted/prefill_rung_2": ('3cd144778ac50ded', 'vs.attn=2404 vs.mlp=36 vs.moe=68'),
+    "longcat_flash/kernels_interpreted/decode": ('cfc4ff6f77f0cfba', 'vs.attn=556 vs.mlp=36 vs.moe=74'),
 }
 
 

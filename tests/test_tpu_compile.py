@@ -151,6 +151,21 @@ def test_paged_decode_compiles(chip, S, Pmax, page, H, KV, hd, dtype, L):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20   # no copy of the pool
 
 
+# (slots, pages a slot, heads, pool layers): DeepSeek-V2's cell (128 heads on one row: the ridge) and LongCat-Flash's (64 heads,
+# eight pool layers, 109 operations a byte read: the memory side); rows of 576 padded to 640, the values the first 512, pages of 16
+@pytest.mark.parametrize("S,Pmax,H,L", [(32, 512, 128, 5), (128, 256, 64, 8)], ids=["deepseekv2-128-heads", "longcat-64-heads"])
+def test_paged_decode_latent_compiles_at_both_head_counts(chip, S, Pmax, H, L):
+    from vescale_tpu.kernels.paged_attention import paged_decode_latent, supports_latent
+
+    assert supports_latent(bf16, 640, 512, 16, interpret=False)
+    sds = lambda shape, dt=bf16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    args = (sds((S, H, 640)), sds((L, 20480, 16, 1, 640)), sds((S, Pmax), jnp.int32), sds((S,), jnp.int32), sds((), jnp.int32))
+    compiled = _compile(lambda q, pool, table, lengths, layer: paged_decode_latent(
+        q, pool, table, lengths, layer=layer, scale=192 ** -0.5, latent=512, interpret=False), *args)
+    assert "paged_decode_latent" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20   # no copy of the pool
+
+
 # --------------------------------------------------------------- ssm step
 # (layers, slots, state, heads x head width): granite-4.0-h-small's nine Mamba-2 layers at 64 slots (the
 # benchmark's cell), and a narrower state whose lanes are one block
@@ -191,7 +206,10 @@ def test_ssm_step_compiles_in_place_with_two_groups_and_a_state_of_256(chip):
 # the longest rung that takes the sorted form (Laguna's 256 and 8192, SDAR's 256 and 2048, Granite's 512, DeepSeek-V2's
 # 8192, whose three matrices of 47 MB pass in tiles over the width)
 GROUPED_CASES = {"laguna-256": (256, 8, 256, 2048, 512), "laguna-8192": (8192, 8, 256, 2048, 512), "sdar-256": (256, 8, 128, 2048, 768),
-                 "sdar-2048": (2048, 8, 128, 2048, 768), "granite-512": (512, 10, 36, 4096, 768), "deepseekv2-8192": (8192, 6, 40, 5120, 1536)}
+                 "sdar-2048": (2048, 8, 128, 2048, 768), "granite-512": (512, 10, 36, 4096, 768), "deepseekv2-8192": (8192, 6, 40, 5120, 1536),
+                 # LongCat-Flash's experts of 6144 x 2048, the widest hidden so far (75 MB an expert: tiles of 512 over the width),
+                 # at the shortest sorted rung and at the longest piece a rung goes through in (models/longcat_flash.py)
+                 "longcat-256": (256, 12, 16, 6144, 2048), "longcat-1024": (1024, 12, 16, 6144, 2048)}
 
 
 @pytest.mark.parametrize("case", GROUPED_CASES)
@@ -200,7 +218,7 @@ def test_grouped_swiglu_compiles_at_the_cells_widths_and_rungs(chip, case):
 
     N, k, held, d, f = GROUPED_CASES[case]
     tm, tf = tiles(d, f, bf16, N * k / held)
-    assert (tf == f) == (case != "deepseekv2-8192") and f % tf == 0 and tm % 16 == 0
+    assert (tf == f) == (case not in ("deepseekv2-8192", "longcat-256", "longcat-1024")) and f % tf == 0 and tm % 16 == 0
     sds = lambda shape, dt=bf16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
     rows = row_tiles(N * k, held, tm) * tm
     compiled = grouped_swiglu.lower(sds((rows, d)), sds((held,), jnp.int32), sds((held, d, f)), sds((held, d, f)), sds((held, f, d)),
@@ -372,6 +390,41 @@ def test_lagunas_decode_program_and_a_rung_compile_at_the_cells_size_and_copy_no
     _assert_in_place_and_fits(compiled, sizes, "bf16[2,28672,16,8,128]")        # 1.88 GB a pool
     for ring in ("bf16[3,128,512,8,128]", "bf16[3,4096,16,8,128]"):             # 0.40 GB a ring, as the cache and as the kernel see it
         assert not [line for line in compiled.as_text().splitlines() if " copy(" in line and f"= {ring}" in line]
+
+
+@pytest.mark.parametrize("program", ["decode step", "rung of 512 positions", "rung of 2048 positions", "rung of 4096 positions"],
+                         ids=["decode", "rung512", "rung2048", "rung4096"])
+def test_longcats_decode_program_and_its_rungs_compile_at_the_cells_size_and_fit_beside_the_weights(chip, program):
+    """``longcatflash_serve_reasoning``'s decode step (128 slots: eight
+    ``paged_decode_latent`` at 64 heads, one a SUBLAYER; its sixteen experts a
+    layer go all on all, no kernel) and three rungs of its prefill ladder (eight
+    ``mla_flash_fwd`` and four ``grouped_swiglu`` over experts of 6144 x 2048: a
+    rung over 1,024 rows takes the routed branch in pieces, so the kernel is
+    there once a layer whatever the rung).  The latent pool is written in place,
+    and the 4,096 rung's temporaries are 1.6 GB beside 13.7 GB of weights and
+    cache (3.0 GB with the branch whole: read before the pieces, PERF.md section
+    6, PR 54)."""
+    family, config, sizes, programs = _cells_programs(chip, "longcatflash_serve_reasoning")
+    titles = [title for title, _ in programs]
+    assert sum("prefill, rung of" in t for t in titles) == 8 and "decode step, 128 slots x 4096 positions" in titles[-1]
+    assert sizes["weights_bytes"] == family.weight_bytes(config) and sizes["slot_state_bytes"] == 0
+    assert sizes["kv_pool_bytes"] == family.cache_bytes(config, config["serve"]) == config["serve"]["pool_pages"] * 16 * 10240
+    (lowered,) = [low for title, low in programs if program in title]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    kernel_calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    if "rung" in program:
+        assert len(kernel_calls) == 12 and sum("mla_flash_fwd" in line for line in kernel_calls) == 8
+        assert sum("grouped_swiglu" in line for line in kernel_calls) == 4
+    else:
+        assert len(kernel_calls) == 8 and all("paged_decode_latent" in line for line in kernel_calls)
+    pages = config["serve"]["pool_pages"]
+    assert not [line for line in text.splitlines() if " copy(" in line and f"= bf16[8,{pages},16,1,640]" in line]
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= sum(sizes.values()) and memory.alias_size_in_bytes >= sizes["kv_pool_bytes"], memory
+    assert memory.argument_size_in_bytes < 1.005 * sum(sizes.values()), "no row of the pool padded: 640 is whole lane tiles"
+    assert memory.temp_size_in_bytes < (1.7e9 if "rung" in program else 0.1e9), memory
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.4e9, memory
 
 
 @pytest.mark.parametrize("program,kernels_in_it", [("decode step", 13), ("rung of 512 positions", 13)], ids=["decode", "rung512"])

@@ -5,7 +5,10 @@ a group-limited routed expert layer beside shared experts.
 The block is written ONCE, as pure functions over a plain parameter tree
 (``init_params``), and both serve programs call them; what other families
 share (the norm, the product, the SwiGLU, YaRN's frequencies) is
-``models/blocks.py``'s.  The last section of this file is what
+``models/blocks.py``'s, and the latent-attention block itself, which another
+family has too, is ``models/mla.py``'s: this file gives it the model's numbers
+(``DeepseekV2Config.mla``: YaRN's frequencies, the score scale with its
+``mscale``, 128 heads).  The last section of this file is what
 ``serve.HybridServeEngine`` asks of a model's module (the cache's geometry,
 the bodies of its two programs, the counters of a decode step).
 
@@ -56,16 +59,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..moe.dropless import route_group_limited, routed_experts
+from . import mla
 from .blocks import F32, ROUTED_DOWN_GAIN, _mm, rmsnorm, swiglu, yarn_inv_freq, yarn_mscale
+from .mla import LANES
 
 __all__ = [
     "DeepseekV2Config", "init_params", "embed", "head", "inv_freq", "rotary", "mla_prefill", "mla_step", "dense_mlp",
     "expert_layer", "layer_prefill", "layer_step", "cache_config", "prefill_chunk", "decode_kernels", "serve_prefill",
     "serve_decode", "STEP_COUNTERS", "step_counters", "prefill_counters",
 ]
-
-LANES = 128
-FLASH_NAME = "mla_flash_fwd"        # the prefill attention's kernel, as the device trace names it
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,6 +131,17 @@ class DeepseekV2Config:
         return -(-self.latent_row // LANES) * LANES
 
     @property
+    def mla(self) -> mla.LatentAttention:
+        """The latent-attention block on this model's numbers: YaRN's frequencies with the cos / sin multiplier
+        ``mscale / mscale_all_dim``, the score scale with ``mscale^2``, no multiplier on the low-rank activations."""
+        return mla.LatentAttention(
+            hidden_size=self.hidden_size, num_attention_heads=self.num_attention_heads, q_lora_rank=self.q_lora_rank,
+            kv_lora_rank=self.kv_lora_rank, qk_nope_head_dim=self.qk_nope_head_dim, qk_rope_head_dim=self.qk_rope_head_dim,
+            v_head_dim=self.v_head_dim, inv_freq=inv_freq(self), softmax_scale=self.softmax_scale,
+            rms_norm_eps=self.rms_norm_eps, dtype=self.dtype,
+            cos_scale=yarn_mscale(self.rope_factor, self.rope_mscale) / yarn_mscale(self.rope_factor, self.rope_mscale_all_dim))
+
+    @property
     def softmax_scale(self) -> float:
         return self.qk_head_dim ** -0.5 * yarn_mscale(self.rope_factor, self.rope_mscale_all_dim) ** 2
 
@@ -149,22 +162,10 @@ def init_params(config: DeepseekV2Config, key) -> Dict[str, Any]:
     differ; and the routed experts' down projections ``ROUTED_DOWN_GAIN`` times
     as wide (the constant says why)."""
     c, dt = config, config.dtype
-    E, H = c.hidden_size, c.num_attention_heads
+    E = c.hidden_size
 
     def normal(k, shape, fan_in, dtype=dt, gain=1.0):
         return (jax.random.normal(k, shape, F32) * (gain / math.sqrt(fan_in))).astype(dtype)
-
-    def attention(k):
-        ks = jax.random.split(k, 6)
-        return {"q_a": normal(ks[0], (E, c.q_lora_rank), E),
-                "q_a_norm": jnp.ones((c.q_lora_rank,), dt),
-                "q_b": normal(ks[1], (c.q_lora_rank, H * c.qk_head_dim), c.q_lora_rank),
-                "kv_a": normal(ks[2], (E, c.latent_row), E),
-                "kv_a_norm": jnp.ones((c.kv_lora_rank,), dt),
-                # kv_b's two halves, a head at a time: W_uk (H, nope, C) and W_uv (H, C, v)
-                "kv_b_k": normal(ks[3], (H, c.qk_nope_head_dim, c.kv_lora_rank), c.kv_lora_rank),
-                "kv_b_v": normal(ks[4], (H, c.kv_lora_rank, c.v_head_dim), c.kv_lora_rank),
-                "o": normal(ks[5], (H * c.v_head_dim, E), H * c.v_head_dim)}
 
     def swiglu_params(k, width):
         ks = jax.random.split(k, 3)
@@ -189,7 +190,7 @@ def init_params(config: DeepseekV2Config, key) -> Dict[str, Any]:
         params[f"layers_{l}"] = {
             "input_layernorm": {"weight": jnp.ones((E,), dt)},
             "post_attention_layernorm": {"weight": jnp.ones((E,), dt)},
-            "self_attn": attention(k_attn),
+            "self_attn": mla.attention_params(c.mla, k_attn),
             "mlp": moe(k_mlp) if l >= c.first_k_dense_replace else swiglu_params(k_mlp, c.intermediate_size),
         }
     return params
@@ -214,99 +215,19 @@ def inv_freq(config: DeepseekV2Config) -> np.ndarray:
 
 
 def rotary(config: DeepseekV2Config, x, positions):
-    """Rotate the interleaved pairs ``(2i, 2i+1)`` of ``x`` (..., dim) by
-    ``positions`` times the YaRN frequencies, in place: ``positions``
-    broadcasts against ``x``'s leading axes with one axis of size 1 added for
-    ``dim`` (a (T,) vector for ``x`` (T, dim); ``positions[:, None]`` for (T,
-    H, dim); ``positions[None, :]`` for (H, T, dim)).  The source first
-    brings the pairs to halves and its result stays so; queries and keys get
-    the same order, so scores do not see it, and keeping the pairs where they
-    are needs no strided access: ``x cos + (x R) sin`` with ``R`` the (dim,
-    dim) matrix that takes each pair ``(a, b)`` to ``(-b, a)`` (entries 0 and
-    +-1: the product is exact).  Float32.  The cos/sin multiplier ``mscale /
-    mscale_all_dim`` is applied as it stands."""
-    c = config
-    dim = x.shape[-1]
-    angle = positions.astype(F32)[..., None] * jnp.asarray(np.repeat(inv_freq(c), 2))      # (..., dim)
-    m = yarn_mscale(c.rope_factor, c.rope_mscale) / yarn_mscale(c.rope_factor, c.rope_mscale_all_dim)
-    turn = np.zeros((dim, dim), np.float32)
-    turn[np.arange(1, dim, 2), np.arange(0, dim, 2)] = -1.0
-    turn[np.arange(0, dim, 2), np.arange(1, dim, 2)] = 1.0
-    x = x.astype(F32)
-    turned = jnp.dot(x, jnp.asarray(turn), precision=jax.lax.Precision.HIGHEST)
-    return x * (jnp.cos(angle) * m) + turned * (jnp.sin(angle) * m)
+    """``mla.rotary`` at this model's frequencies and multiplier (``mscale / mscale_all_dim``)."""
+    return mla.rotary(config.mla, x, positions)
 
 
 # ------------------------------------------------------------------ attention
-def _queries(c: DeepseekV2Config, ap, u, positions, *, head_major: bool = False):
-    """``q_nope`` (T, H, nope) and the rotated ``q_pe`` (T, H, rope) in the
-    operands' type; ``head_major``: (H, T, .), as the flash forward reads
-    them, straight from the product."""
-    T, H = u.shape[0], c.num_attention_heads
-    cq = rmsnorm(_mm(u, ap["q_a"], c.dtype), ap["q_a_norm"], c.rms_norm_eps).astype(c.dtype)
-    w = ap["q_b"].astype(c.dtype).reshape(c.q_lora_rank, H, c.qk_head_dim)
-    q = jnp.einsum("tr,rhd->htd" if head_major else "tr,rhd->thd", cq, w, preferred_element_type=F32).astype(c.dtype)
-    where = positions[None, :] if head_major else positions[:, None]
-    return q[..., : c.qk_nope_head_dim], rotary(c, q[..., c.qk_nope_head_dim:], where).astype(c.dtype)
-
-
-def _latent_rows(c: DeepseekV2Config, ap, u, positions):
-    """The cache rows of ``u``'s positions (T, cache_row) in ``c.dtype``:
-    the normed latent, the rotated key, the zero pad."""
-    kv = _mm(u, ap["kv_a"], c.dtype)
-    latent = rmsnorm(kv[:, : c.kv_lora_rank], ap["kv_a_norm"], c.rms_norm_eps)
-    k_pe = rotary(c, kv[:, c.kv_lora_rank:], positions)
-    pad = jnp.zeros((u.shape[0], c.cache_row - c.latent_row), F32)
-    return jnp.concatenate([latent, k_pe, pad], axis=-1).astype(c.dtype)
-
-
 def mla_prefill(c: DeepseekV2Config, ap, u, *, interpret: Optional[bool] = None):
-    """The EXPANDED form over one sequence ``u`` (T, E) from position 0:
-    per-head keys and values from the latent, causal attention with scores
-    ``qk_head_dim`` wide and values ``v_head_dim`` wide through the blocked
-    flash forward (no (T, T) tensor).  Returns the output (T, E) and the
-    positions' cache rows (T, cache_row).  Pad positions follow the real ones,
-    so causality keeps them out."""
-    from ..ops.flash_attention import flash_attention_forward
-
-    T, H = u.shape[0], c.num_attention_heads
-    positions = jnp.arange(T, dtype=jnp.int32)
-    q_nope, q_pe = _queries(c, ap, u, positions, head_major=True)
-    rows = _latent_rows(c, ap, u, positions)
-    latent, k_pe = rows[:, : c.kv_lora_rank], rows[:, c.kv_lora_rank: c.latent_row]
-    # head-major (H, T, .) as the kernel reads them, in the operands' type straight from the products
-    k_nope = jnp.einsum("tc,hdc->htd", latent, ap["kv_b_k"].astype(c.dtype), preferred_element_type=F32).astype(c.dtype)
-    v = jnp.einsum("tc,hcd->htd", latent, ap["kv_b_v"].astype(c.dtype), preferred_element_type=F32).astype(c.dtype)
-    q = jnp.concatenate([q_nope, q_pe], axis=-1)
-    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe[None], (H, T, c.qk_rope_head_dim))], axis=-1)
-    y = flash_attention_forward(q, k, v, scale=c.softmax_scale, interpret=interpret, name=FLASH_NAME)
-    return jnp.einsum("htd,hde->te", y, ap["o"].astype(c.dtype).reshape(H, c.v_head_dim, c.hidden_size),
-                      preferred_element_type=F32), rows
+    """``mla.mla_prefill`` (the EXPANDED form over one sequence) on this model's numbers."""
+    return mla.mla_prefill(c.mla, ap, u, interpret=interpret)
 
 
-def mla_step(c: DeepseekV2Config, ap, u, pool, *, layer: int, table, page, offset, positions, valid_len,
-             interpret: Optional[bool]):
-    """The ABSORBED form, one new position a slot: ``u`` (S, E); the
-    position's row goes to ``(page, offset)`` of the pool's ``layer`` (the
-    null page for a slot that may not write), then
-    ``kernels.paged_decode_latent`` reads the slot's pages with the 576-wide
-    absorbed queries (``interpret``: the kernel's flag, or None for its XLA
-    leg).  Returns the output (S, E) and the pool."""
-    from ..kernels.paged_attention import paged_decode_latent
-
-    S, H = u.shape[0], c.num_attention_heads
-    q_nope, q_pe = _queries(c, ap, u, positions)
-    pool = pool.at[layer, page, offset, 0].set(_latent_rows(c, ap, u, positions).astype(pool.dtype))
-    # the heads lead both operands of the absorbed products (a batch axis elsewhere the CPU's runtime refuses in bfloat16)
-    q_abs = jnp.einsum("hsd,hdc->hsc", q_nope.transpose(1, 0, 2), ap["kv_b_k"].astype(c.dtype),
-                       preferred_element_type=F32).transpose(1, 0, 2)
-    pad = jnp.zeros((S, H, c.cache_row - c.latent_row), pool.dtype)
-    q = jnp.concatenate([q_abs.astype(pool.dtype), q_pe.astype(pool.dtype), pad], axis=-1)
-    mixed = paged_decode_latent(q, pool, table, valid_len, layer=layer, scale=c.softmax_scale, latent=c.kv_lora_rank,
-                                interpret=interpret)
-    y = jnp.einsum("hsc,hcd->hsd", mixed.astype(c.dtype).transpose(1, 0, 2), ap["kv_b_v"].astype(c.dtype),
-                   preferred_element_type=F32).transpose(1, 0, 2)
-    return _mm(y.reshape(S, H * c.v_head_dim), ap["o"], c.dtype), pool
+def mla_step(c: DeepseekV2Config, ap, u, pool, **where):
+    """``mla.mla_step`` (the ABSORBED form, one new position a slot) on this model's numbers."""
+    return mla.mla_step(c.mla, ap, u, pool, **where)
 
 
 # ---------------------------------------------------------------- feed-forward
@@ -367,11 +288,8 @@ def cache_config(config: DeepseekV2Config, *, num_slots: int, page_size: int, pa
                  num_pages: Optional[int] = None):
     """The cache's geometry: a latent pool of every layer's rows, no value
     pool, no slot state."""
-    from ..serve.kv_cache import KVCacheConfig
-
-    return KVCacheConfig(layers=config.num_hidden_layers, kv_heads=1, head_dim=config.cache_row, num_slots=num_slots,
-                         page_size=page_size, pages_per_slot=pages_per_slot, num_pages=num_pages, dtype=config.dtype,
-                         latent=True)
+    return mla.cache_config(config.mla, layers=config.num_hidden_layers, num_slots=num_slots, page_size=page_size,
+                            pages_per_slot=pages_per_slot, num_pages=num_pages)
 
 
 def prefill_chunk(config: DeepseekV2Config) -> int:
@@ -382,10 +300,7 @@ def prefill_chunk(config: DeepseekV2Config) -> int:
 def decode_kernels(config: DeepseekV2Config, cache) -> Dict[str, Any]:
     """The decode step's kernels, latched at build: ``{"decode": the
     ``interpret`` flag of ``paged_decode_latent``, or None for the XLA leg}``."""
-    from ..kernels import paged_attention
-
-    return {"decode": paged_attention.leg_latent(cache.k.data.dtype, config.cache_row, config.kv_lora_rank,
-                                                 cache.config.page_size)}
+    return {"decode": mla.decode_kernel(config.mla, cache)}
 
 
 def serve_prefill(c: DeepseekV2Config, params, arrays, tokens, length, page_row, slot, *, page: int,
@@ -432,16 +347,11 @@ STEP_COUNTERS = ("latent_bytes_read", "prefill_attn_flops", "moe_groups_kept_her
 def step_counters(config: DeepseekV2Config, cache, lengths: np.ndarray, counts: Dict[str, np.ndarray]) -> Dict[str, int]:
     """What one decode step adds: the latent pages its attention had to read
     (live pages x page bytes x layers) and the kept groups that lay here."""
-    kc = cache.config
-    live = int(np.minimum(-(-(lengths + 1) // kc.page_size), kc.pages_per_slot)[lengths > 0].sum())
-    page_bytes = kc.page_size * kc.head_dim * jnp.dtype(config.dtype).itemsize
-    return {"latent_bytes_read": live * page_bytes * config.num_hidden_layers,
+    return {"latent_bytes_read": mla.latent_bytes_read(config.mla, cache, lengths, config.num_hidden_layers),
             "moe_groups_kept_here": int(counts["groups"].sum())}
 
 
 def prefill_counters(config: DeepseekV2Config, bucket: int) -> Dict[str, int]:
     """What one prefill of ``bucket`` positions adds: causal attention's
     useful operations at the real widths (scores and values, half the square)."""
-    c = config
-    per_pair = 2 * (c.qk_head_dim + c.v_head_dim)
-    return {"prefill_attn_flops": c.num_attention_heads * per_pair * bucket * bucket // 2 * c.num_hidden_layers}
+    return {"prefill_attn_flops": mla.prefill_attn_flops(config.mla, bucket, config.num_hidden_layers)}

@@ -12,6 +12,12 @@ lives elsewhere adds nothing here (the exchange that would carry it there is
 not this module's; on one chip the layer runs without it), and tokens masked
 out (slots that are not active, pad positions) route nowhere.
 
+A router may have more outputs than the model has experts with weights
+(:func:`identity_experts`: zero-compute experts, whose part is the token itself
+under its gates): those ids lie past every held range, so the forms below never
+see them as work, and a token's pairs on real experts are then FEWER than ``k``
+and vary from token to token.
+
 Three forms, one result; the choice reads shapes and counts, never a knob or a
 model's name.  What decides is how many ROWS AN EXPERT gets.
 
@@ -83,8 +89,8 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["route_topk", "route_group_limited", "route_sigmoid_topk", "dropless_experts", "routed_experts", "padded_candidate",
-           "fits_pad", "expert_form", "grouped_leg", "DENSE_MAX_TOKENS", "ROW_PAD", "PADDED_MIN_MEAN_ROWS", "PADDED_MAX_MEAN_ROWS"]
+__all__ = ["route_topk", "route_group_limited", "route_sigmoid_topk", "route_softmax_biased", "identity_experts",
+           "dropless_experts", "routed_experts", "padded_candidate", "fits_pad", "expert_form", "grouped_leg", "DENSE_MAX_TOKENS", "ROW_PAD", "PADDED_MIN_MEAN_ROWS", "PADDED_MAX_MEAN_ROWS"]
 
 # one row tile of the MXU: up to here each touched expert costs a grouped product a tile, sorted or not
 DENSE_MAX_TOKENS = 128
@@ -148,6 +154,44 @@ def route_sigmoid_topk(scores, k: int, *, scale: float = 1.0, bias=None) -> Tupl
         _, idx = jax.lax.top_k(probs + bias.astype(jnp.float32), k)
         top = jnp.take_along_axis(probs, idx, axis=-1)
     return idx.astype(jnp.int32), top * (scale / jnp.sum(top, axis=-1, keepdims=True))
+
+
+def route_softmax_biased(scores, k: int, *, scale: float = 1.0, bias=None) -> Tuple[jax.Array, jax.Array]:
+    """A softmax over ALL of a token's router scores (N, X) in float32 (``X``
+    may count more outputs than there are experts with weights:
+    :func:`identity_experts`); the ``k`` largest of ``probabilities + bias``
+    are kept (``bias`` (X,) float32 is a SELECTION bias, the sources'
+    ``e_score_correction_bias``: it chooses, it does not weigh; None: the
+    program without one), and the gates are the kept outputs' probabilities AS
+    THEY ARE (not renormalised over the kept), times ``scale``; no groups.
+    Returns ids (N, k) int32 and gates (N, k) float32, as :func:`route_topk`
+    does."""
+    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+    if bias is None:
+        top, idx = jax.lax.top_k(probs, k)
+    else:
+        _, idx = jax.lax.top_k(probs + bias.astype(jnp.float32), k)
+        top = jnp.take_along_axis(probs, idx, axis=-1)
+    return idx.astype(jnp.int32), top * scale
+
+
+def identity_experts(x, idx, gates, *, first_identity: int, token_mask: Optional[jax.Array] = None):
+    """ZERO-COMPUTE experts: the router's outputs ``first_identity ..`` have no
+    weights, and a kept pair on one of them gives the token back under its
+    gate, so their part of the layer is ``(sum over a token's kept ids >=
+    first_identity of g) x``: no product, no weight, no exchange, and so
+    computed WHOLE on every chip of a share for that chip's own tokens (in the
+    sum over shares it counts once, as a shared expert does), while
+    :func:`dropless_experts` sends the same ids, which lie outside any held
+    range, to its trailing group that no product touches.  ``x`` (N, d),
+    ``idx`` / ``gates`` (N, k) from a routing rule, ``token_mask`` (N,) bool as
+    there.  Returns the part (N, d) float32 and how many kept pairs of the
+    tokens that route fell on identity experts (int32)."""
+    zero = idx >= first_identity
+    if token_mask is not None:
+        zero = zero & token_mask[:, None]
+    weight = jnp.sum(jnp.where(zero, gates, 0.0), axis=-1, keepdims=True)
+    return weight * x.astype(jnp.float32), jnp.sum(zero, dtype=jnp.int32)
 
 
 def padded_candidate(N: int, k: int, held: int) -> bool:

@@ -4,7 +4,7 @@ MODULE, and this class keeps what every such model needs once: the prefill
 ladder, ``warm()``, donation, the ``vs.serve-*`` spans and ``trace_counters()``;
 ``decode`` itself, one step deep (a call launches its step and returns the
 ``DecodeStep`` unread; a ``DecodeFeed`` feeds the next from the device), is
-``engine.DecodeAhead``'s, shared with ``ServeEngine``.  Six models plug in today:
+``engine.DecodeAhead``'s, shared with ``ServeEngine``.  Seven models plug in today:
 
   * ``models/granite_hybrid.py`` (the class's name is from it): state-space
     mixers with a per-slot recurrent state beside the paged K/V of their few
@@ -28,7 +28,11 @@ ladder, ``warm()``, donation, the ``vs.serve-*`` spans and ``trace_counters()``;
     256 small sigmoid-routed experts beside a shared one after a dense first layer;
   * ``models/mimo_v2.py``: window and full attention mixed with KEYS OF 192 BESIDE VALUES OF 128 and a learned sink in
     every window layer's softmax: FOLDED pages on 4 key heads and folded rings of 128 on 8 (``KVCacheConfig.folded`` /
-    ``v_head_dim``), read by ``paged_decode_folded``; a share of 256 sigmoid-routed experts chosen under a selection bias.
+    ``v_head_dim``), read by ``paged_decode_folded``; a share of 256 sigmoid-routed experts chosen under a selection bias;
+  * ``models/longcat_flash.py``: a layer of TWO latent-attention sublayers (``models/mla.py``'s block, which DeepSeek-V2
+    has too, at 64 heads with two LoRA multipliers) and two dense SwiGLUs, so a model layer owns two layers of the latent
+    pool; a routed branch that leaves after the first sublayer and returns at the layer's end, over a softmax router some of
+    whose outputs are zero-compute identity experts (``moe.dropless.route_softmax_biased`` / ``identity_experts``).
 
 A second engine class beside :class:`ServeEngine`, behind the same surface
 (``prefill(prompt, slot)``, ``decode(tokens)``, ``params``,
