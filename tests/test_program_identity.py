@@ -14,7 +14,8 @@ home each (e1809cb: this file on that tree, ``PROGRAMS`` printed by ``python
 tests/test_program_identity.py``).  A PR that changes these programs on purpose
 takes them anew.  (``mimo_v2``'s six were taken on the tree of the PR that brought
 the family, PR 50: they pin it from there on.  The twenty-four prefills were taken
-anew by PR 52, whose prefill also returns its row's argmax: the scopes did not move.  ``longcat_flash``'s six were
+anew by PR 52, whose prefill also returns its row's argmax: the scopes did not move.  ``falcon_h1``'s four were replaced
+by PR 55 with its four RIDING rungs, the step that carries a prompt: its engine launches no prefill.  ``longcat_flash``'s six were
 taken on the tree of the PR that brought the family and moved the latent-attention block from ``models/deepseek_v2.py``
 to ``models/mla.py``, PR 54: ``deepseek_v2``'s six held through the move, digest and scopes, as they stand here.)"""
 
@@ -43,6 +44,8 @@ FAMILIES = {"granite_hybrid": test_granite_hybrid, "deepseek_v2": test_deepseek_
             "falcon_h1": test_falcon_h1, "laguna": test_laguna, "mimo_v2": test_mimo_v2, "longcat_flash": test_longcat_flash}
 LEGS = {"xla_legs": None, "kernels_interpreted": "interpret"}
 WHICH = ("prefill_rung_1", "prefill_rung_2", "decode")
+# a family whose engine offers a ride launches no prefill program: its rungs are the step that carries a prompt
+RIDING = {"prefill_rung_1": "riding_rung_1", "prefill_rung_2": "riding_rung_2"}
 
 PROGRAMS = {
     "granite_hybrid/xla_legs/prefill_rung_1": ('98f6b6654290c82b', 'vs.attn=47 vs.mamba=483 vs.moe=160'),
@@ -63,11 +66,11 @@ PROGRAMS = {
     "sdar_moe/kernels_interpreted/prefill_rung_1": ('f933be4443957656', 'vs.attn=1332 vs.moe=62'),
     "sdar_moe/kernels_interpreted/prefill_rung_2": ('c0e8b8caa1f4e974', 'vs.attn=1332 vs.moe=62'),
     "sdar_moe/kernels_interpreted/decode": ('56e940485455a866', 'vs.attn=340 vs.moe=62 vs.unmask=67'),
-    "falcon_h1/xla_legs/prefill_rung_1": ('651fccfb5f5874be', 'vs.attn=216 vs.mamba=384 vs.mlp=56'),
-    "falcon_h1/xla_legs/prefill_rung_2": ('9f3db013d5d2cdab', 'vs.attn=216 vs.mamba=384 vs.mlp=56'),
+    "falcon_h1/xla_legs/riding_rung_1": ('215586b1844b9746', 'vs.attn=232 vs.mamba=275 vs.mlp=28'),
+    "falcon_h1/xla_legs/riding_rung_2": ('ebbecbf244a84194', 'vs.attn=232 vs.mamba=275 vs.mlp=28'),
     "falcon_h1/xla_legs/decode": ('c1aa2e3ced5cfdfc', 'vs.attn=302 vs.mamba=200 vs.mlp=56'),
-    "falcon_h1/kernels_interpreted/prefill_rung_1": ('ce602ea6c8e8ec6e', 'vs.attn=1230 vs.mamba=384 vs.mlp=56'),
-    "falcon_h1/kernels_interpreted/prefill_rung_2": ('f8436a8621fc3650', 'vs.attn=1230 vs.mamba=384 vs.mlp=56'),
+    "falcon_h1/kernels_interpreted/riding_rung_1": ('0ba0b405beb28be4', 'vs.attn=696 vs.mamba=249 vs.mlp=28'),
+    "falcon_h1/kernels_interpreted/riding_rung_2": ('cec96f38d98f1d72', 'vs.attn=696 vs.mamba=249 vs.mlp=28'),
     "falcon_h1/kernels_interpreted/decode": ('c6b57d1d7536a78a', 'vs.attn=228 vs.mamba=160 vs.mlp=56'),
     "laguna/xla_legs/prefill_rung_1": ('b146687985cea9d3', 'vs.attn=651 vs.mlp=9 vs.moe=108'),
     "laguna/xla_legs/prefill_rung_2": ('44ac4f53288c04cd', 'vs.attn=651 vs.mlp=9 vs.moe=108'),
@@ -119,7 +122,11 @@ def _program(engine, params, which: str):
         S = cache.num_slots
         return engine._decode_fn, (params, *held, shape(S, cache.config.pages_per_slot), shape(S), shape(S))
     bucket = engine.buckets[WHICH.index(which)]
-    return engine._prefill_fn, (params, *held, shape(bucket), shape(), shape(bucket // cache.config.page_size), shape())
+    prompt = (shape(bucket), shape(), shape(bucket // cache.config.page_size), shape())
+    if engine.rides:
+        S = cache.num_slots
+        return engine._ride_fn, (params, *held, shape(S, cache.config.pages_per_slot), shape(S), shape(S), shape(S), *prompt)
+    return engine._prefill_fn, (params, *held, *prompt)
 
 
 def _jaxpr_digest(fn, args) -> str:
@@ -138,22 +145,24 @@ def _scopes(fn, args) -> str:
 
 
 def _taken(family: str, leg: str, which: str):
+    """``(the program's name in PROGRAMS, its digest, its scopes)``."""
     with pytest.MonkeyPatch.context() as patch:
         engine, params = _engine(family, leg, patch)
         fn, args = _program(engine, params, which)
-        return _jaxpr_digest(fn, args), _scopes(fn, args)
+        return RIDING.get(which, which) if engine.rides else which, _jaxpr_digest(fn, args), _scopes(fn, args)
 
 
 @pytest.mark.parametrize("which", WHICH)
 @pytest.mark.parametrize("leg", list(LEGS))
 @pytest.mark.parametrize("family", list(FAMILIES))
 def test_a_serve_program_traces_to_the_text_and_the_scopes_it_had(family, leg, which):
-    digest, scopes = _taken(family, leg, which)
-    assert (digest, scopes) == PROGRAMS[f"{family}/{leg}/{which}"]
+    name, digest, scopes = _taken(family, leg, which)
+    assert (digest, scopes) == PROGRAMS[f"{family}/{leg}/{name}"]
 
 
 if __name__ == "__main__":
     for family in FAMILIES:
         for leg in LEGS:
             for which in WHICH:
-                print(f'    "{family}/{leg}/{which}": {_taken(family, leg, which)!r},', flush=True)
+                name, *taken = _taken(family, leg, which)
+                print(f'    "{family}/{leg}/{name}": {tuple(taken)!r},', flush=True)

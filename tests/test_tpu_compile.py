@@ -511,6 +511,53 @@ def test_deepseeks_step_that_carries_a_prompt_compiles_at_the_cells_size_with_on
     assert set(re.findall(r"= bf16\[(\d+),\d+\]\S* convolution\(", step.compile().as_text())) == {"32"}
 
 
+def test_falcon_h1s_step_that_carries_a_prompt_compiles_at_the_cells_size_with_one_product_a_weight(chip):
+    """``falconh1_34b_serve_batch``'s decode step with the 128 rung's prompt in it (PR 55: 128 decode rows and 128
+    prompt rows, one array before every weight's product): six ``ssm_step``, six ``paged_decode`` and six flash
+    forwards, no copy of a pool or of the state, every product of the stack over all 256 rows and none over 128
+    beside it, the head's over the 128 steps' rows and the prompt's last: a weight crosses the HBM once for both.
+    (The engine is the one the cell's family builds for ``benchmark/rehearse.py``, from shapes alone.)"""
+    import importlib
+    import re
+    from unittest import mock
+
+    from vescale_tpu import kernels
+    from vescale_tpu.serve import HybridServeEngine
+
+    built, init = [], HybridServeEngine.__init__
+
+    def noted(self, *args, **kwargs):
+        init(self, *args, **kwargs)
+        built.append(self)
+
+    flash_ops = importlib.import_module("vescale_tpu.ops.flash_attention")
+    with mock.patch.object(HybridServeEngine, "__init__", noted):
+        _family, _config, sizes, programs = _cells_programs(chip, "falconh1_34b_serve_batch")
+        (engine,) = built
+        cache = engine.cache
+        S, page, rung = cache.num_slots, cache.config.page_size, 128
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+        # (traced here too, under the same answers: the flash forward asks for its platform while it is traced)
+        with mock.patch.object(kernels, "on_tpu", lambda: True), mock.patch.object(flash_ops, "jax", _JaxOnATpu()):
+            lowered = engine._ride_fn.lower(engine.params, *engine._held(), i32(S, cache.config.pages_per_slot), i32(S), i32(S),
+                                            i32(S), i32(rung), i32(), i32(rung // page), i32())
+    assert engine.rides and engine.kernel_decode and engine.kernel_ssm_step and (S, rung) == (128, 128)
+    assert lowered.as_text().lstrip().startswith("module @jit_decode ")
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    kernel_calls = re.findall(r"%([a-z_.]+?)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    assert sorted(kernel_calls) == ["paged_decode"] * 6 + ["ssm_step"] * 6 + ["vs.attn"] * 6      # (the flash forward bears its scope's name)
+    _assert_in_place_and_fits(compiled, sizes, "bf16[6,12289,16,4,128]")
+    assert not [line for line in text.splitlines() if " copy(" in line and "= f32[6,128,256,4096]" in line], "nor of the state"
+    products = set(re.findall(r"= \w+\[(\d+),(\d+)\]\S* convolution\(", text))
+    # in_proj, q and k/v, o and out_proj and down_proj, gate and up: all 256 rows; the head 128 + 1
+    assert products == {("256", "9248"), ("256", "2560"), ("256", "512"), ("256", "5120"), ("256", "21504"), ("129", "130560")}
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
+    # ... and the step without a prompt is the program it was: its products over the 128 rows
+    (step,) = [low for title, low in programs if "decode step" in title]
+    assert set(re.findall(r"= \w+\[(\d+),\d+\]\S* convolution\(", step.compile().as_text())) == {"128"}
+
+
 # what a program outside its kernels' bodies lowers to, for a described v5e: a digest of the lowered text with every
 # kernel's serialized body taken out (it holds the checkout's path and the kernel's line numbers; the bodies' own identity
 # is the jaxpr digests of tests/test_program_identity.py).  Taken on the parent of the PR that gave ``paged_decode`` a

@@ -4,9 +4,10 @@ normed input and adds both to the residual stream, over a dense SwiGLU, with
 twelve fixed multipliers (muP) on the way.
 
 The block is written ONCE, as pure functions over a plain parameter tree
-(``init_params``), and both serve programs call them.  The state-space mixer
+(``init_params``), and every serve program calls them.  The state-space mixer
 is ``models/mamba2.py``'s (``mamba2_prefill`` over ``ssd_chunked``,
-``mamba2_step``), which is written for ``G`` groups of B and C; the norm, the
+``mamba2_step``, and ``mamba2_ride``, which is both over one array of rows),
+which is written for ``G`` groups of B and C; the norm, the
 product and the rotary term are ``models/blocks.py``'s.  No flax module, no
 training copy.
 
@@ -38,6 +39,11 @@ The cache: EVERY layer owns a row of the state arrays (``ssm``, ``conv``) AND a
 layer of the K/V pools; a prefill writes both for every layer and a decode
 step reads and writes both.
 
+A prompt RIDES the decode step (``serve_ride``, which ``HybridServeEngine``
+takes as this model's offer): the step's rows and the prompt's are one array
+before every weight's product, so the weights cross the HBM once for both, and
+a slot that holds no request in that step keeps its state and tail bit for bit.
+
 A chip's share: ``vocab_size`` is the rows of the embedding and of the head
 held here; nothing stands in for the absent chips.
 """
@@ -52,11 +58,12 @@ import jax
 import jax.numpy as jnp
 
 from .blocks import F32, _mm, rmsnorm, rotary, write_position
-from .mamba2 import mamba2_prefill, mamba2_step
+from .mamba2 import mamba2_prefill, mamba2_ride, mamba2_step
 
 __all__ = [
-    "FalconH1Config", "init_params", "embed", "head", "in_scale", "attention_prefill", "attention_step", "mlp",
-    "layer", "cache_config", "prefill_chunk", "decode_kernels", "serve_prefill", "serve_decode",
+    "FalconH1Config", "init_params", "embed", "head", "in_scale", "attention_prefill", "attention_step",
+    "attention_ride", "mlp", "layer", "cache_config", "prefill_chunk", "decode_kernels", "serve_prefill",
+    "serve_decode", "serve_ride",
     "STEP_COUNTERS", "step_counters", "prefill_counters", "BRANCH_GAIN",
 ]
 
@@ -257,6 +264,33 @@ def attention_step(c: FalconH1Config, ap, u, k_pool, v_pool, *, layer: int, tabl
     return _mm(y.reshape(u.shape[0], -1), ap["o_proj"], c.dtype), k_pool, v_pool
 
 
+def attention_ride(c: FalconH1Config, ap, u, k_pool, v_pool, *, layer, table, page, offset, positions, valid_len,
+                   page_row, page_size: int, interpret: Optional[bool], flash_interpret: Optional[bool]):
+    """A decode step's rows AND a prompt's through one attention mixer: ``u``
+    (S + T, E), the ``S`` slots' rows first (at ``positions[:S]``), then a
+    prompt padded to ``T`` positions from 0.  ``W_q``, ``W_k``, ``W_v`` and
+    ``W_o`` over all the rows at once; between them the slots' rows as
+    :func:`attention_step` runs them (their K and V to ``(page, offset)``,
+    ``paged_decode`` over their pages) and the prompt's as
+    :func:`attention_prefill` does (causal over themselves: a whole prompt
+    rides one step, so none of its rows reads a page), its K and V into the
+    pages ``page_row`` (T / page_size,) of this ``layer`` (an int or a traced
+    int32).  Returns the output (S + T, E) and both pools."""
+    from ..kernels.paged_attention import paged_decode
+    from ..ops.flash_attention import flash_attention
+    from ..serve.kv_cache import write_pages
+
+    S, scale = table.shape[0], c.head_dim ** -0.5
+    q, k, v = _qkv(c, ap, u, positions)
+    k_pool, v_pool = write_position(k_pool, v_pool, k[:S], v[:S], (layer, page, offset))
+    k_pool = write_pages(k_pool, k[None, S:], page_row, page_size, layer)
+    v_pool = write_pages(v_pool, v[None, S:], page_row, page_size, layer)
+    y_step = paged_decode(q[:S], k_pool, v_pool, table, valid_len, layer=layer, scale=scale, interpret=interpret)
+    y_prompt = flash_attention(q[None, S:], k[None, S:], v[None, S:], causal=True, scale=scale, interpret=flash_interpret)[0]
+    y = jnp.concatenate([y_step.reshape(S, -1), y_prompt.reshape(u.shape[0] - S, -1)])
+    return _mm(y, ap["o_proj"], c.dtype), k_pool, v_pool
+
+
 # ------------------------------------------------------------ the dense MLP
 def mlp(c: FalconH1Config, fp, h):
     gate = c.mlp_multipliers[0] * _mm(h, fp["gate_proj"], c.dtype)
@@ -316,7 +350,11 @@ def serve_prefill(c: FalconH1Config, params, arrays, tokens, length, page_row, s
     """The prefill program's body: ``tokens`` (bucket,) through the stack; every
     layer's K and V of the bucket's positions go to the slot's pages, its state
     and tail to the slot's rows.  Returns the last real position's logits row
-    and the cache's arrays."""
+    and the cache's arrays.  (Since this module gives :func:`serve_ride` the
+    engine runs no prefill program of its own: a prompt alone is the riding
+    program with every decode row idle.  What still lowers this body is the
+    benchmark's rehearsal, ``benchmark/families/falcon_h1.py:rehearse_serve``,
+    and the tests that compare a riding step with it.)"""
     from ..serve.kv_cache import write_pages
 
     kd, vd, ssm, conv = arrays["k"], arrays["v"], arrays["ssm"], arrays["conv"]
@@ -365,6 +403,56 @@ def serve_decode(c: FalconH1Config, params, arrays, table, lengths, tokens, *, a
                 positions=lengths, valid_len=lengths + 1, interpret=kernels["decode"]))
         conv = conv.at[l].set(tail)
     return head(c, params, x), {}, {"k": kd, "v": vd, "ssm": ssm, "conv": conv}
+
+
+def serve_ride(c: FalconH1Config, params, arrays, table, lengths, tokens, prompt, length, page_row, slot, *, active,
+               write_page, write_offset, kernels: Dict[str, Any], page: int, interpret: Optional[bool] = None):
+    """The body of the decode step that CARRIES a prompt: :func:`serve_decode`
+    with ``prompt`` (rung,) rows more, of which ``length`` are real.  The ``S``
+    decode rows and the prompt's are ONE array before every weight's product
+    (the mixers' projections, the MLP; the head over the ``S`` rows and the
+    prompt's row ``length - 1``), so a weight crosses the HBM once for both.
+    What is no weight's product runs for each kind of row as it does alone
+    (``mamba2_ride``, :func:`attention_ride`); the prompt's K and V go to the
+    pages ``page_row``, its state and tail over ``slot``'s rows after the
+    step's pass over them, a layer at a time.  A slot that ``active`` (S,) does
+    not name (it holds no request, or its prompt waits, or it is ``slot``
+    itself) keeps its state and tail BIT FOR BIT and writes the null page: with
+    no slot active this is a prompt launched alone, beside slots in the middle
+    of their outputs.  Returns the logits (S, vocab), the prompt's logits row,
+    no counts of its own and the cache's arrays."""
+    S, rung = tokens.shape[0], prompt.shape[0]
+    scale = in_scale(c)
+    positions = jnp.concatenate([lengths, jnp.arange(rung, dtype=lengths.dtype)])
+
+    def ride_layer(lp, x, kd, vd, ssm, conv, l, table, lengths, positions, active, write_page, write_offset, length,
+                   page_row, slot):
+        """One layer over all the rows; ``l`` is the layer's number AS A VALUE, so that the layer, its three kernels
+        and all, is traced and lowered once a rung whatever the depth (both decode kernels take their layer as an
+        operand; a rung written out layer by layer cost the dense engine seconds of set-up a rung)."""
+        x, (ssm, tail, state, prompt_tail), (kd, vd) = layer(
+            c, lp, x,
+            lambda u: mamba2_ride(c, lp["mamba"], u, ssm, jax.lax.dynamic_index_in_dim(conv, l, keepdims=False), length,
+                                  active=active, layer=l, interpret=kernels["ssm_step"], in_scale=scale),
+            lambda u: attention_ride(
+                c, lp["self_attn"], u, kd, vd, layer=l, table=table, page=write_page, offset=write_offset,
+                positions=positions, valid_len=lengths + 1, page_row=page_row, page_size=page,
+                interpret=kernels["decode"], flash_interpret=interpret))
+        # every slot's tail as the step leaves it, then the prompt's state and tail over its slot's rows
+        conv = jax.lax.dynamic_update_slice(conv, tail[None].astype(conv.dtype), (l, 0, 0, 0))
+        conv = jax.lax.dynamic_update_slice(conv, prompt_tail[None, None].astype(conv.dtype), (l, slot, 0, 0))
+        ssm = jax.lax.dynamic_update_slice(ssm, state[None, None].astype(ssm.dtype), (l, slot, 0, 0))
+        return x, kd, vd, ssm, conv
+
+    ride_layer = jax.jit(ride_layer)    # (inlined where it is called: the cache's arrays are the outer program's to donate)
+    kd, vd, ssm, conv = arrays["k"], arrays["v"], arrays["ssm"], arrays["conv"]
+    x = embed(c, params, jnp.concatenate([tokens, prompt]))         # (S + rung, E)
+    for l in range(c.num_hidden_layers):
+        x, kd, vd, ssm, conv = ride_layer(params[f"layers_{l}"], x, kd, vd, ssm, conv, jnp.int32(l), table, lengths,
+                                          positions, active, write_page, write_offset, length, page_row, slot)
+    last = jax.lax.dynamic_index_in_dim(x, S + length - 1, axis=0, keepdims=True)
+    logits = head(c, params, jnp.concatenate([x[:S], last]))
+    return logits[:S], logits[S], {}, {"k": kd, "v": vd, "ssm": ssm, "conv": conv}
 
 
 # this model's own counters beside those every model's engine keeps: the slot state read and written (every

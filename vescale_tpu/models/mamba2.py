@@ -4,9 +4,14 @@ conv1d(xBC) + b)``; ``dt = softplus(dt + dt_bias)``; per head ``h`` of group
 ``g = h // (H / G)``: ``S_t = exp(dt_t A_h) S_{t-1} + dt_t x_t B_{g,t}^T``,
 ``y_t = S_t C_{g,t} + D_h x_t``; ``y = rmsnorm_per_group(y * silu(z)) * w``;
 ``W_out y``.  Written once, as pure functions over a mixer's parameters, and
-both serve programs call them: a prefill runs the recurrence by chunks (:func:`mamba2_prefill` over
+every serve program calls them: a prefill runs the recurrence by chunks (:func:`mamba2_prefill` over
 :func:`ssd_chunked`), a decode step one step of it for every slot
-(:func:`mamba2_step` over ``kernels.ssm_step``, the kernel or its XLA leg).
+(:func:`mamba2_step` over ``kernels.ssm_step``, the kernel or its XLA leg), and
+a decode step that CARRIES a prompt both (:func:`mamba2_ride`: the slots' rows
+and the prompt's are one array through ``W_in`` and through the gate, the norm
+and ``W_out``, so each weight is read once for both; between them the slots'
+rows take the one step and the prompt's the chunked scan, each as it runs
+alone, and a slot that holds no request keeps its state and its tail bit for bit).
 
 ``c`` is the family's config (its ``mamba_*`` fields, ``d_inner``, ``conv_dim``,
 ``ssm_state_shape``, ``rms_norm_eps``, ``dtype``, ``state_dtype``).  The
@@ -25,7 +30,7 @@ import jax.numpy as jnp
 
 from .blocks import F32, _mm, rmsnorm
 
-__all__ = ["mamba2_prefill", "mamba2_step", "ssd_chunked", "SCAN_PRECISION"]
+__all__ = ["mamba2_prefill", "mamba2_step", "mamba2_ride", "ssd_chunked", "SCAN_PRECISION"]
 
 # the scan's own products (C B^T, the decayed sums, the chunk states) are a few
 # per cent of a prefill's operations; in float32 they leave the state exact to
@@ -122,16 +127,13 @@ def _ssd_one_group(x, dt, A, B, C, chunk: int, initial_state=None):
     return y.reshape(T, H, P), last
 
 
-def mamba2_prefill(c, mp, u, length, *, in_scale=None):
-    """One sequence ``u`` (T, E), T a multiple of the chunk, of which the
-    first ``length`` positions are real.  In the pad ``dt`` is forced to 0, so
-    the state stands where the prompt ends, and the convolution tail is taken
-    from the prompt's last ``d_conv - 1`` real inputs (zeros before its
-    start).  ``in_scale`` as :func:`_mamba_in` takes it.  Returns the mixer's
-    output (T, E), the state in the cache's layout (N, H P) and type, and the
-    tail (d_conv - 1, conv_dim)."""
-    T, K = u.shape[0], c.mamba_d_conv
-    z, xBC, dt = _mamba_in(c, mp, u, in_scale)
+def _scan_rows(c, mp, xBC, dt, length):
+    """One sequence's rows between the in-projection and the gate: the
+    convolution from zeros before the start, the chunked scan from a zero state
+    with ``dt`` forced to 0 past ``length``.  Returns ``y`` (T, H, P), the state
+    in the cache's layout (N, H P) and type, and the tail (d_conv - 1, conv_dim)
+    of the last real inputs."""
+    T, K = xBC.shape[0], c.mamba_d_conv
     dt = jnp.where((jnp.arange(T) < length)[:, None], dt, 0.0)
     padded = jnp.concatenate([jnp.zeros((K - 1, c.conv_dim), xBC.dtype), xBC], axis=0)
     tail = jax.lax.dynamic_slice_in_dim(padded, length, K - 1, axis=0)
@@ -141,7 +143,47 @@ def mamba2_prefill(c, mp, u, length, *, in_scale=None):
     y, state = ssd_chunked(x, dt, -jnp.exp(mp["A_log"].astype(F32)), B, C, c.mamba_chunk_size)
     y = y + mp["D"].astype(F32)[:, None] * x
     state = state.transpose(2, 0, 1).reshape(c.ssm_state_shape)          # (H, P, N) -> (N, H P)
-    return _mamba_out(c, mp, y, z), state.astype(c.state_dtype), tail
+    return y, state.astype(c.state_dtype), tail
+
+
+def _step_rows(c, mp, xBC, dt, ssm, tail, *, layer, interpret, active=None):
+    """Every slot's one row between the in-projection and the gate: the
+    convolution over the slot's tail and the new input, one step of the
+    recurrence on the ``layer``-th state (``kernels.ssm_step``).  ``active``
+    (S,) bool, where given, names the slots that hold a request: every other
+    slot takes ``decay = 1`` and ``dt x = 0`` (selected, not multiplied: ``h' =
+    1 h + B (x) 0`` is ``h`` exactly in float32), so its state stands bit for
+    bit though the kernel reads and writes it.  Returns ``y`` (S, H, P), ``ssm``
+    advanced, and the convolution's window (S, d_conv, conv_dim), whose last
+    ``d_conv - 1`` rows are the new tail."""
+    from ..kernels.ssm_step import ssm_step     # (Pallas comes with it: imported late)
+
+    window = jnp.concatenate([tail, xBC[:, None, :].astype(tail.dtype)], axis=1)      # (S, K, conv_dim)
+    conv = mp["conv_bias"].astype(F32) + jnp.sum(mp["conv_weight"].astype(F32)[None] * window.astype(F32), axis=1)
+    x, B, C = _mamba_split(c, conv)
+    decay = jnp.exp(dt * -jnp.exp(mp["A_log"].astype(F32)))                               # (S, H)
+    if active is not None:
+        decay = jnp.where(active[:, None], decay, 1.0)
+    decay = jnp.repeat(decay, c.mamba_d_head, axis=1)
+    dtx = dt[..., None] * x
+    if active is not None:
+        dtx = jnp.where(active[:, None, None], dtx, 0.0)
+    ssm, y = ssm_step(ssm, decay, dtx.reshape(xBC.shape[0], c.d_inner), B, C, layer=layer, interpret=interpret)
+    y = y.reshape(x.shape) + mp["D"].astype(F32)[:, None] * x
+    return y, ssm, window
+
+
+def mamba2_prefill(c, mp, u, length, *, in_scale=None):
+    """One sequence ``u`` (T, E), T a multiple of the chunk, of which the
+    first ``length`` positions are real.  In the pad ``dt`` is forced to 0, so
+    the state stands where the prompt ends, and the convolution tail is taken
+    from the prompt's last ``d_conv - 1`` real inputs (zeros before its
+    start).  ``in_scale`` as :func:`_mamba_in` takes it.  Returns the mixer's
+    output (T, E), the state in the cache's layout (N, H P) and type, and the
+    tail (d_conv - 1, conv_dim)."""
+    z, xBC, dt = _mamba_in(c, mp, u, in_scale)
+    y, state, tail = _scan_rows(c, mp, xBC, dt, length)
+    return _mamba_out(c, mp, y, z), state, tail
 
 
 def mamba2_step(c, mp, u, ssm, tail, *, layer: int, interpret=None, in_scale=None):
@@ -151,15 +193,28 @@ def mamba2_step(c, mp, u, ssm, tail, *, layer: int, interpret=None, in_scale=Non
     ``kernels.ssm_step`` moves the state, on the leg ``interpret`` names (the
     kernel's flag, or None for the XLA leg); ``in_scale`` as :func:`_mamba_in`
     takes it.  Returns the output (S, E), ``ssm`` and the tail, advanced."""
-    from ..kernels.ssm_step import ssm_step     # (Pallas comes with it: imported late)
-
     z, xBC, dt = _mamba_in(c, mp, u, in_scale)
-    window = jnp.concatenate([tail, xBC[:, None, :].astype(tail.dtype)], axis=1)      # (S, K, conv_dim)
-    conv = mp["conv_bias"].astype(F32) + jnp.sum(mp["conv_weight"].astype(F32)[None] * window.astype(F32), axis=1)
-    x, B, C = _mamba_split(c, conv)
-    decay = jnp.exp(dt * -jnp.exp(mp["A_log"].astype(F32)))                               # (S, H)
-    S = u.shape[0]
-    ssm, y = ssm_step(ssm, jnp.repeat(decay, c.mamba_d_head, axis=1), (dt[..., None] * x).reshape(S, c.d_inner), B, C,
-                      layer=layer, interpret=interpret)
-    y = y.reshape(x.shape) + mp["D"].astype(F32)[:, None] * x
+    y, ssm, window = _step_rows(c, mp, xBC, dt, ssm, tail, layer=layer, interpret=interpret)
     return _mamba_out(c, mp, y, z), ssm, window[:, 1:]
+
+
+def mamba2_ride(c, mp, u, ssm, tail, length, *, active, layer, interpret=None, in_scale=None):
+    """A decode step's rows AND a prompt's through one mixer: ``u`` (S + T, E),
+    the ``S`` slots' rows first, then a prompt padded to ``T`` positions (a
+    multiple of the chunk) of which ``length`` are real.  ``W_in`` over all the
+    rows at once, and the gate, the norm and ``W_out``; between them the slots'
+    rows as :func:`mamba2_step` runs them (but that a slot ``active`` (S,) does
+    not name keeps its state, :func:`_step_rows`, and its tail), the prompt's
+    as :func:`mamba2_prefill` does, from a zero state.  ``layer`` may be a traced int32.  Returns the
+    output (S + T, E), ``ssm`` and the tails (S, ...) advanced, and the
+    prompt's own state (N, H P) and tail, which the caller writes over its
+    slot's rows AFTER this step has touched them."""
+    S = tail.shape[0]
+    # (the barrier makes ``W_in``'s product ONE: its three parts are read far apart, on both sides of the state's
+    # kernel and of the scan, and the v5e's compiler, rather than keep 10 MB of it, ran the product anew for each
+    # reader, four times a layer, the weights read each time: 3 ms of a 128 rung's step, 7 of a 512's; PERF.md §6, PR 55)
+    z, xBC, dt = jax.lax.optimization_barrier(_mamba_in(c, mp, u, in_scale))
+    y_step, ssm, window = _step_rows(c, mp, xBC[:S], dt[:S], ssm, tail, layer=layer, interpret=interpret, active=active)
+    y_scan, state, scan_tail = _scan_rows(c, mp, xBC[S:], dt[S:], length)
+    tail = jnp.where(active[:, None, None], window[:, 1:], tail)
+    return _mamba_out(c, mp, jnp.concatenate([y_step, y_scan]), z), ssm, tail, state, scan_tail

@@ -229,7 +229,8 @@ class PrefillStep:
     loop never reads either.
 
     **A prompt that waits to RIDE** (an engine whose ``rides`` is True: a
-    single-stage :class:`ServeEngine`).  Its ``prefill`` launches nothing: the
+    single-stage :class:`ServeEngine`, and a ``HybridServeEngine`` whose model's
+    module gives a ``serve_ride`` body).  Its ``prefill`` launches nothing: the
     step it returns holds the padded prompt (``launched`` False, ``rung`` and
     ``slot`` say how wide and where) until a decode step CARRIES it, which is
     the caller's to ask for (``DecodeFeed(..., rider=step)``: the prompt's rows
@@ -418,7 +419,20 @@ class DecodeAhead:
     step adds to more than the counters kept here.  ``block`` is None where a
     step moves one position a slot and yields its one token, and the engine's
     :class:`BlockSchedule` where it moves a block (the serve loop asks it what
-    a pass yields; ``_fed`` and ``_note`` are then the engine's own)."""
+    a pass yields; ``_fed`` and ``_note`` are then the engine's own).
+
+    **A prompt that rides** is kept here too, once for both engines: the
+    prompts that wait for a step to carry them (``_waiting``, which
+    ``_prompt_waits`` fills from the engine's ``prefill``), the step that
+    carries one (``_carry``) and the launch, alone, of those that something
+    needs first (``_launch_waiting``).  An engine that OFFERS a ride (its
+    ``rides`` is true: each engine says when) gives ``_run_ride(table, lengths,
+    tokens, prompt, slot) -> (logits, ids, counts or None, the prompt's row,
+    its greedy id)``, the step's program with the prompt's rows in it, which
+    leaves that id in ``slot``'s place of ``_firsts``; with lengths of 0 it is
+    a prompt launched alone.  An engine that offers none never has one waiting.
+    ``_warm_ladder`` is what both ``warm()`` run: every rung, riding or not, and
+    the decode step."""
 
     block: Optional[BlockSchedule] = None
 
@@ -445,6 +459,7 @@ class DecodeAhead:
         # read instead
         self._firsts = None
         self._first_launch: Dict[int, int] = {}
+        self._waiting: List[PrefillStep] = []   # the prompts that wait for a step to carry them, in the order they came
         # launches (``decode`` calls and prompts whose programs were enqueued; ``warm`` counts none): the enqueues so
         # far are the NUMBER a launch carries to what it causes, one sequence for both kinds (``launches``)
         self.decode_launches = 0
@@ -511,12 +526,44 @@ class DecodeAhead:
             return self._host_tokens(tokens)
         return self._merged_tokens(tokens.step._ids, tokens.fresh) if tokens.fresh else tokens.step._ids
 
-    def _launch_waiting(self, needed=None, rider: Optional[PrefillStep] = None) -> Sequence[PrefillStep]:
-        """Launch, alone, the prompts that wait for a step to carry them and
-        are ``needed`` now (all of them, where None); the prompts that still
-        wait afterwards, ``rider`` among them (an engine that carries none has
-        none waiting)."""
-        return ()
+    def _prompt_waits(self, toks: np.ndarray, n: int, page_row: np.ndarray, slot: int) -> "PrefillStep":
+        """What ``prefill`` returns where prompts ride: nothing is launched, the
+        prompt (padded to its rung) waits for the step that carries it, or for a reader."""
+        out = PrefillStep(None, None, self, None, prompt=(toks, np.int32(n), page_row), rung=len(toks), slot=slot)
+        self._waiting.append(out)
+        return out
+
+    def _carry(self, rider: "PrefillStep", launch: int, table, lengths, tokens):
+        """Launch the step numbered ``launch`` with ``rider``'s prompt in it;
+        the step's ``(logits, ids, counts)``.  The rider is launched from here on."""
+        logits, ids, counts, rider._row, rider._id = self._run_ride(table, lengths, tokens, rider._prompt, rider.slot)
+        rider._prompt, rider._launch = None, launch
+        self._first_launch[rider.slot] = launch
+        self._waiting.remove(rider)
+        self.prefill_launches += 1
+        return logits, ids, counts
+
+    def _launch_waiting(self, needed=None, rider: Optional["PrefillStep"] = None) -> Sequence["PrefillStep"]:
+        """The prompts that wait and are ``needed`` now (all, where None), and
+        every one that came BEFORE the last of them or before ``rider`` (what
+        two prompts leave in one slot's pages depends on their order), go now,
+        ALONE and in the order they came, but ``rider`` itself: the step that
+        carries a prompt with every decode row idle (lengths of 0: the rows
+        write the null page, leave their slots' state as it was, and their ids
+        are nobody's), under a launch span of
+        that program's kind.  Returns the prompts that still wait."""
+        cache = self.cache
+        due = list(self._waiting)
+        if due and needed is not None:
+            named = {id(step) for step in (*needed, rider)}
+            due = due[: max((i for i, step in enumerate(due, 1) if id(step) in named), default=0)]
+        for step in due:
+            if step is not rider:
+                zeros = np.zeros((cache.num_slots,), np.int32)
+                n = self.launches
+                with ndtimeit(_p.SERVE_DECODE_LAUNCH, launch=n, rung=step.rung, slot=step.slot):
+                    self._carry(step, n, cache.table_array(), zeros, self._host_tokens(zeros))
+        return self._waiting
 
     def _note(self, tokens, lengths: np.ndarray) -> Tuple[Optional[np.ndarray], Any]:
         """Of a launch: ``(rows, note)``: which row of a block ``step[slot]`` of
@@ -524,6 +571,29 @@ class DecodeAhead:
         ``_count_step`` is told of the launch when the step is read (a block
         engine's own: what the host will take from the step, and which slots fused)."""
         return None, None
+
+    def _warm_ladder(self, run_prefill) -> None:
+        """Every rung of ``self.buckets`` (a prompt of one token into the null
+        page and slot 0: where prompts ride, the step that carries it with every
+        decode row idle, fed the ids the step before made; else
+        ``run_prefill(tokens, n, page_row)``) and then the decode step
+        (``_warm_decode``, which the last rung's id feeds), twice over: the
+        first call of all sees the cache's arrays as they were allocated, every
+        later one as a program returned them, and a program that compiles again
+        for those does it here."""
+        cache = self.cache
+        page = cache.config.page_size
+        zeros = np.zeros((cache.num_slots,), np.int32)
+        table = np.zeros((cache.num_slots, cache.config.pages_per_slot), np.int32)
+        ids = self._host_tokens(zeros) if self.rides else None
+        for _ in range(2):
+            for rung in self.buckets:
+                prompt = (np.zeros((rung,), np.int32), np.int32(1), np.zeros((rung // page,), np.int32))
+                if self.rides:
+                    _, ids, _, _, first = self._run_ride(table, zeros, ids, prompt, 0)
+                else:
+                    _, first = run_prefill(*prompt)
+            self._warm_decode(first)
 
     def _warm_decode(self, first) -> None:
         """The decode step (no slot active) in every form the loop feeds it: the
@@ -754,7 +824,6 @@ class ServeEngine(DecodeAhead):
         # a function of the cache's geometry: whole pages, so a rung's K/V is a whole number of page writes
         self.buckets = prefill_buckets(cache.config.page_size, cache.max_seq_len, smallest=_SMALLEST_RUNG)
         self._warmed = False
-        self._waiting: List[PrefillStep] = []   # the prompts that wait for a step to carry them, in the order they came
         self._positions = np.arange(cache.max_seq_len, dtype=np.int32)[None, :]
         # what this engine has done, in plain integers (a trace session
         # reads them at its two ends: ``trace_counters``)
@@ -786,7 +855,7 @@ class ServeEngine(DecodeAhead):
     @property
     def rides(self) -> bool:
         """Does a prompt ride a decode step here?  The OFFER the serve loop
-        asks for (``HybridServeEngine`` has none): where the stack is one stage,
+        asks for (``HybridServeEngine`` sets its own, by its model): where the stack is one stage,
         ``prefill`` returns a :class:`PrefillStep` that waits, and a ``decode``
         whose :class:`DecodeFeed` names it as ``rider`` carries it."""
         return len(self.stage_bounds) == 1
@@ -1165,29 +1234,15 @@ class ServeEngine(DecodeAhead):
         active, in each form of its tokens: ``_warm_decode``, which the last
         rung's id feeds).  Where prompts ride (``rides``) a rung is ONE
         program, the step that carries it, run here with every decode row
-        idle; else the stages' four.  Twice over, as ``HybridServeEngine.warm``
-        does: the first
-        call of all sees the cache's arrays as they were allocated, every later
-        one as a program returned them, and a program that compiles again for
-        those does it here.  The first ``prefill`` of an engine's life runs
+        idle; else the stages' four.  Twice over (``_warm_ladder``, which
+        ``HybridServeEngine.warm`` runs too, says why).  The first ``prefill`` of an engine's life runs
         this if nobody has; no ``prefill`` or ``decode`` compiles after it
         (``decode_multi`` lowers a width when it first meets it)."""
         import jax
 
         cache = self.cache
-        page = cache.config.page_size
         self._warmed = True
-        zeros = np.zeros((cache.num_slots,), np.int32)
-        table = np.zeros((cache.num_slots, cache.config.pages_per_slot), np.int32)
-        ids = self._host_tokens(zeros)      # then the ids a step made: what a step that carries is fed
-        for _ in range(2):
-            for rung in self.buckets:
-                prompt = (np.zeros((rung,), np.int32), np.int32(1), np.zeros((rung // page,), np.int32))
-                if self.rides:
-                    _, ids, _, first = self._run_ride(table, zeros, ids, prompt, 0)
-                else:
-                    _, first = self._run_prefill(*prompt)
-            self._warm_decode(first)
+        self._warm_ladder(self._run_prefill)
         # ... and RUN: what the device still owes of a program's first run (an executable read from the compile cache is
         # loaded when it first runs; on a v5e some ten seconds for a ladder of rungs) is set-up's, not the first request's
         jax.block_until_ready((cache.k.data, cache.v.data))
@@ -1222,45 +1277,14 @@ class ServeEngine(DecodeAhead):
 
     def _run_ride(self, table, lengths, tokens, prompt, slot: int):
         """The step that carries ``prompt`` (tokens padded to the rung, length,
-        page row): the decode rows' logits and ids, the prompt's row and its
-        greedy id (which the program has put in ``slot``'s place among the
-        firsts), all still on the device."""
+        page row): the decode rows' logits and ids, no counts, the prompt's row
+        and its greedy id (which the program has put in ``slot``'s place among
+        the firsts), all still on the device."""
         cache = self.cache
         logits, next_ids, row, first, self._firsts, kd, vd = self._ride_fn(
             self.params, cache.k.data, cache.v.data, table, lengths, tokens, self._first_ids(), *prompt, np.int32(slot))
         cache.update(kd, vd)
-        return logits, next_ids, row, first
-
-    def _carry(self, rider: PrefillStep, launch: int, table, lengths, tokens):
-        """Launch the step numbered ``launch`` with ``rider``'s prompt in it;
-        the step's ``(logits, ids, counts)``.  The rider is launched from here on."""
-        logits, ids, rider._row, rider._id = self._run_ride(table, lengths, tokens, rider._prompt, rider.slot)
-        rider._prompt, rider._launch = None, launch
-        self._first_launch[rider.slot] = launch
-        self._waiting.remove(rider)
-        self.prefill_launches += 1
-        return logits, ids, None
-
-    def _launch_waiting(self, needed=None, rider: Optional[PrefillStep] = None) -> Sequence[PrefillStep]:
-        """The prompts that wait and are ``needed`` now (all, where None), and
-        every one that came BEFORE the last of them or before ``rider`` (what
-        two prompts leave in one slot's pages depends on their order), go now,
-        ALONE and in the order they came, but ``rider`` itself: the step that
-        carries a prompt with every decode row idle (lengths of 0: the rows
-        write the null page and their ids are nobody's), under a launch span of
-        that program's kind.  Returns the prompts that still wait."""
-        cache = self.cache
-        due = list(self._waiting)
-        if due and needed is not None:
-            named = {id(step) for step in (*needed, rider)}
-            due = due[: max((i for i, step in enumerate(due, 1) if id(step) in named), default=0)]
-        for step in due:
-            if step is not rider:
-                zeros = np.zeros((cache.num_slots,), np.int32)
-                n = self.launches
-                with ndtimeit(_p.SERVE_DECODE_LAUNCH, launch=n, rung=step.rung, slot=step.slot):
-                    self._carry(step, n, cache.table_array(), zeros, self._host_tokens(zeros))
-        return self._waiting
+        return logits, next_ids, None, row, first
 
     def prefill(self, prompt: Sequence[int], slot: int) -> PrefillStep:
         """Send the prompt through the stack: its K/V goes into ``slot``'s
@@ -1297,9 +1321,8 @@ class ServeEngine(DecodeAhead):
             toks = np.zeros((rung,), np.int32)
             toks[:n] = np.asarray(prompt, np.int32)
             page_row = cache.page_table[slot, : rung // cache.config.page_size].copy()
-            if self.rides:      # nothing is launched: the prompt waits for the step that carries it, or for a reader
-                out = PrefillStep(None, None, self, None, prompt=(toks, np.int32(n), page_row), rung=rung, slot=slot)
-                self._waiting.append(out)
+            if self.rides:
+                out = self._prompt_waits(toks, n, page_row, slot)
             else:
                 with ndtimeit(_p.SERVE_PREFILL_LAUNCH, launch=self.launches, rung=rung, slot=slot):     # the enqueue alone
                     out = self._launched_prefill(*self._run_prefill(toks, n, page_row), slot)

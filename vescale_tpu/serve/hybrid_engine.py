@@ -3,8 +3,9 @@ mathematics, its share of the cache and its counters come from the MODEL'S
 MODULE, and this class keeps what every such model needs once: the prefill
 ladder, ``warm()``, donation, the ``vs.serve-*`` spans and ``trace_counters()``;
 ``decode`` itself, one step deep (a call launches its step and returns the
-``DecodeStep`` unread; a ``DecodeFeed`` feeds the next from the device), is
-``engine.DecodeAhead``'s, shared with ``ServeEngine``.  Seven models plug in today:
+``DecodeStep`` unread; a ``DecodeFeed`` feeds the next from the device), and
+the prompts that wait to RIDE a step, are ``engine.DecodeAhead``'s, shared with
+``ServeEngine``.  Seven models plug in today:
 
   * ``models/granite_hybrid.py`` (the class's name is from it): state-space
     mixers with a per-slot recurrent state beside the paged K/V of their few
@@ -20,7 +21,8 @@ ladder, ``warm()``, donation, the ``vs.serve-*`` spans and ``trace_counters()``;
   * ``models/falcon_h1.py``: a state-space mixer AND a rotary grouped-query
     attention mixer side by side in EVERY layer, so every layer owns a row of
     the state arrays and a layer of the K/V pools; two groups of B and C; a
-    dense MLP (no experts: the engine's ``moe_*`` counters stay 0);
+    dense MLP (no experts: the engine's ``moe_*`` counters stay 0); the one
+    module that gives a ``serve_ride`` body today, so its prompts ride;
   * ``models/laguna.py``: window and full attention MIXED, with more query
     heads and another rotary term on the window layers: the full layers keep
     pages, a window layer a RING a slot of the newest ``window`` positions (slot
@@ -67,10 +69,27 @@ gives, as plain functions of the config:
       ``counts["experts"]`` (expert layers, held) is the tokens each held
       expert got (a dense model leaves it out), whatever else ``counts`` holds
       is the model's own;
+  ``serve_ride(config, params, arrays, table, lengths, tokens, prompt, length,
+  page_row, slot, *, active, write_page, write_offset, kernels, page,
+  interpret)`` -> ``(logits (S, vocab), the prompt's logits row, counts, arrays)``
+      OPTIONAL: the body of a decode step that CARRIES a prompt, ``serve_decode``
+      with ``rung`` rows more.  The ``S`` decode rows and the prompt's rows are
+      ONE array before every weight's product, so a weight crosses the HBM
+      once for both; what is no weight's product runs for each kind of row as
+      it does alone; the prompt's share of the cache goes to ``page_row`` and
+      to ``slot``'s rows AFTER the step has touched them.  **A row that
+      ``active`` does not name leaves its slot's state as it was, bit for
+      bit** (and writes the null page): with no row active the program is a
+      prompt launched alone, beside slots in the middle of their outputs,
+      which ``serve_decode``, whose idle rows hold no request, need not mind.
+      The engine OFFERS a ride (``engine.rides``, on the instance) iff the
+      module has this function and a step moves one position a slot
+      (``block`` is None): nothing tests a model's name, and there is no knob;
   ``STEP_COUNTERS``, ``step_counters(config, cache, lengths, counts)``,
   ``prefill_counters(config, bucket)``
       the names of the model's own counters and what one decode step, and one
-      prefill, adds to them;
+      prefill, adds to them (a prompt is counted at the ``prefill`` call,
+      whether a step carries it later or it goes alone);
   ``block_schedule(config)`` (only a model that generates by blocks)
       its ``engine.BlockSchedule``; ``serve_decode`` then returns ``(hidden (S x
       B, E), ids (S, B), counts, arrays)``, the open rows' final hidden state
@@ -81,8 +100,9 @@ gives, as plain functions of the config:
       ``lengths // B * B``, and ``next_page`` / ``next_offset`` of the block
       after it (a call that commits a block and opens the next writes both).
 
-Two kinds of compiled program, all static-shaped and all compiled by
-``warm()`` before the engine is handed over:
+Two kinds of compiled program (where prompts ride, the second alone: below),
+all static-shaped and all compiled by ``warm()`` before the engine is handed
+over:
 
   **prefill**, one program a BUCKET: the prompt is padded to the next of
   ``prefill_buckets(chunk, max_seq_len)`` (the rule's home is ``serve/engine.py``,
@@ -100,6 +120,22 @@ Two kinds of compiled program, all static-shaped and all compiled by
   cache's arrays are donated: a second copy would not fit.  The step's counts
   (``counts["experts"]`` and the model's own) come to the host with its ids,
   when the step is read.
+
+  **a step that carries a prompt** (``engine.rides``: the model gives
+  ``serve_ride``), one program a BUCKET, in place of that bucket's prefill: its
+  function is named ``decode`` (the device's module is ``jit_decode``, as the
+  step's without a prompt: the benchmark joins a decode launch by that name).
+  ``prefill`` then launches nothing and returns a ``PrefillStep`` that waits
+  for the ``decode`` whose ``DecodeFeed`` names it as ``rider``; a prompt
+  nobody carries goes ALONE through the same program with every decode row
+  idle, so no program is a prefill's own (``_prefill_fn`` stays a jitted
+  function that is never run: the benchmark's rehearsal lowers it).
+  ``warm()`` runs each bucket's program that way, and ends by WAITING for the
+  device: an executable read from the compile cache is loaded when it first
+  runs, and that is set-up's, not the first request's (an engine that does
+  not ride keeps the ``warm()`` it had).  ``prefill_rides`` of
+  ``prefill_launches`` says how many prompts a step carried, and only an
+  engine that rides reports it.
 
 **A block engine** (``engine.block`` is the model's ``BlockSchedule``; the
 serve loop learns from it that a step yields a COUNT a slot, no flag is
@@ -153,6 +189,9 @@ latent form) nothing stands in their way but the programs, which are not
 written (``NotImplementedError`` names them); a block engine has neither
 program, and a verify step of one token a position is not what its passes are.
 ``num_stages`` > 1 and a mesh of more than one device have no program here yet.
+A block engine offers no ride whatever its module gives (a prompt is more rows
+of a pass, which is another program), and neither does a model whose module
+gives no ``serve_ride``: six of the seven today.
 """
 
 from __future__ import annotations
@@ -281,8 +320,12 @@ class HybridServeEngine(DecodeAhead):
         self._decode_rows = decode_rows
         self._grouped_layers = {rows: len(experts) for rows in (*self.buckets, decode_rows) if grouped and dropless.expert_form(
             rows, c.num_experts_per_tok, c.experts_held) == dropless.SORTED}
-        # what this engine has done, in plain integers (``trace_counters``)
-        self.counter_names = COUNTERS + (BLOCK_COUNTERS if self.block is not None else ()) + tuple(self.model.STEP_COUNTERS)
+        # does a prompt ride a decode step here?  The OFFER the serve loop asks for (``getattr(engine, "rides", False)``),
+        # made where the model's module gives the body of such a step and a step moves one position a slot
+        self.rides = hasattr(self.model, "serve_ride") and self.block is None
+        # what this engine has done, in plain integers (``trace_counters``); ``prefill_rides`` only where prompts ride
+        self.counter_names = (COUNTERS + (("prefill_rides",) if self.rides else ())
+                              + (BLOCK_COUNTERS if self.block is not None else ()) + tuple(self.model.STEP_COUNTERS))
         for name in self.counter_names:
             setattr(self, name, 0)
         register_counter_source(self)
@@ -312,11 +355,10 @@ class HybridServeEngine(DecodeAhead):
 
         block = self.block
 
-        def decode(params, *rest):
-            table, lengths, tokens = rest[n:]
-            # where each slot's new position lands (the first of its open block, where a step moves a block),
-            # as in ServeEngine.decode: a position past the reserved pages, or a slot that holds nothing yet
-            # (before its prefill, or free), writes the null page
+        def landings(table, lengths):
+            """``active`` (S,) and where each slot's new position lands (the first of its open block, where a step
+            moves a block), as in ServeEngine.decode: a position past the reserved pages, or a slot that holds
+            nothing yet (before its prefill, or free), writes the null page."""
             active = lengths > 0
 
             def landing(first):
@@ -325,14 +367,16 @@ class HybridServeEngine(DecodeAhead):
                 return jnp.where(valid, jnp.take_along_axis(table, (safe // page)[:, None], axis=1)[:, 0], 0), safe % page
 
             first = lengths if block is None else lengths // block.B * block.B
-            write_page, write_offset = landing(first)
-            # (a call that commits a block and opens the next writes that one too)
-            after = {}
-            if block is not None:
-                after["next_page"], after["next_offset"] = landing(first + block.B)
+            where = dict(zip(("write_page", "write_offset"), landing(first)))
+            if block is not None:       # (a call that commits a block and opens the next writes that one too)
+                where["next_page"], where["next_offset"] = landing(first + block.B)
+            return active, where
+
+        def decode(params, *rest):
+            table, lengths, tokens = rest[n:]
+            active, where = landings(table, lengths)
             out = model.serve_decode(
-                c, params, dict(zip(names, rest[:n])), table, lengths, tokens, active=active, write_page=write_page,
-                write_offset=write_offset, kernels=kernels, **after)
+                c, params, dict(zip(names, rest[:n])), table, lengths, tokens, active=active, kernels=kernels, **where)
             if block is None:
                 logits, counts, arrays = out
                 # every slot's greedy token, in this program (``DecodeStep.tokens``)
@@ -343,31 +387,51 @@ class HybridServeEngine(DecodeAhead):
                 logits, next_ids, counts, arrays = out
             return (logits, next_ids, counts) + tuple(arrays[name] for name in names)
 
+        # the step that CARRIES a prompt, where the model gives its body: the decode step with ``rung`` rows more, one
+        # program a rung; with every decode row idle (lengths of 0) it is a prompt launched alone, so such a model has
+        # no prefill program of its own.  (Its name on the device's ``XLA Modules`` line is its function's, ``jit_decode``)
+        def decode_carrying(params, *rest):
+            table, lengths, tokens, firsts, prompt, length, page_row, slot = rest[n:]
+            active, where = landings(table, lengths)
+            logits, row, counts, arrays = model.serve_ride(
+                c, params, dict(zip(names, rest[:n])), table, lengths, tokens, prompt, length, page_row, slot, active=active,
+                kernels=kernels, page=page, interpret=self.interpret, **where)
+            next_ids, first = (jnp.argmax(a, axis=-1).astype(jnp.int32) for a in (logits, row))
+            # (the first id to its slot's place among the firsts, where a prefill launched alone leaves its own)
+            return (logits, next_ids, counts, row, first, firsts.at[slot].set(first)) + tuple(arrays[name] for name in names)
+
+        decode_carrying.__name__ = "decode"
+
         def head_rows(params, hidden, which):       # the rows of logits a caller reads, when it reads them
             return model.head(c, params, hidden[which])
 
         donated = tuple(range(1, 1 + n))
         self._array_names = names
-        self._prefill_fn = jax.jit(prefill, donate_argnums=donated)
+        self._prefill_fn = jax.jit(prefill, donate_argnums=donated)     # (never run where prompts ride)
         self._decode_fn = jax.jit(decode, donate_argnums=donated)
+        self._ride_fn = jax.jit(decode_carrying, donate_argnums=donated) if self.rides else None
         self._head_fn = jax.jit(head_rows) if block is not None else None
-        self._init_decode_ahead(jax.sharding.SingleDeviceSharding(self.mesh.jax_mesh.devices.flat[0]))
+        # (where prompts ride, the sharding the programs' own ids come out with, the cache's mesh's: a rung's program is
+        # fed the host's tokens, a step's ids and the firsts in turn, and is ONE executable only if they are all alike)
+        self._init_decode_ahead(jax.sharding.NamedSharding(self.mesh.jax_mesh, jax.sharding.PartitionSpec()) if self.rides
+                                else jax.sharding.SingleDeviceSharding(self.mesh.jax_mesh.devices.flat[0]))
 
     def warm(self) -> "HybridServeEngine":
         """Compile and run every program: each prefill bucket (into the null
         page and slot 0's state, which its next prefill rewrites) and the
         decode step (no slot active, in each form of its tokens:
-        ``_warm_decode``, which the last bucket's id feeds).  Twice over: the
-        first call of all sees
-        the cache's arrays as they were allocated, every later one sees them as
-        a program returned them, and a program that compiles again for those
-        does it here.  Nothing compiles after this."""
-        cache = self.cache
-        for _ in range(2):
-            for bucket in self.buckets:
-                _, first = self._run_prefill(np.zeros((bucket,), np.int32), 1,
-                                             np.zeros((bucket // cache.config.page_size,), np.int32), 0)
-            self._warm_decode(first)
+        ``_warm_decode``, which the last bucket's id feeds).  Where prompts
+        ride a bucket's program is the step that carries it, run here with
+        every decode row idle.  Twice over (``DecodeAhead._warm_ladder`` says
+        why).  Nothing compiles after this."""
+        self._warm_ladder(lambda toks, n, page_row: self._run_prefill(toks, n, page_row, 0))
+        if self.rides:
+            # ... and RUN: an executable read from the compile cache is loaded onto the chip when it first runs, seconds
+            # for a ladder of programs each as large as the step, and that is set-up's, not the first request's (as
+            # ``ServeEngine.warm`` waits; an engine whose prompts do not ride keeps the set-up it had)
+            import jax
+
+            jax.block_until_ready(self._held())
         return self
 
     # ---------------------------------------------------------------- API
@@ -379,6 +443,16 @@ class HybridServeEngine(DecodeAhead):
         logits, first, *arrays = self._prefill_fn(self.params, *self._held(), tokens, np.int32(n), page_row, np.int32(slot))
         self.cache.update_arrays(dict(zip(self._array_names, arrays)))
         return logits, first
+
+    def _run_ride(self, table, lengths, tokens, prompt, slot: int):
+        """The step that carries ``prompt`` (tokens padded to the bucket, length,
+        page row): the decode rows' logits and ids, the step's counts, the
+        prompt's row and its greedy id (which the program has put in ``slot``'s
+        place among the firsts), all still on the device."""
+        logits, next_ids, counts, row, first, self._firsts, *arrays = self._ride_fn(
+            self.params, *self._held(), table, lengths, tokens, self._first_ids(), *prompt, np.int32(slot))
+        self.cache.update_arrays(dict(zip(self._array_names, arrays)))
+        return logits, next_ids, counts, row, first
 
     def _run_decode(self, table, lengths, tokens):
         params = self.params
@@ -433,18 +507,29 @@ class HybridServeEngine(DecodeAhead):
         last prompt position's own row) and its greedy id on the device:
         ``.token`` waits for the id, ``np.asarray(step)`` is the fp32 row.  The
         serve loop reads ``.token`` after it has enqueued the decode step that
-        takes the id from the device, and of a block engine reads neither."""
+        takes the id from the device, and of a block engine reads neither.
+        Where prompts RIDE (``self.rides``) this call launches nothing: the step
+        returned WAITS (``launched`` False) for the ``decode`` whose
+        :class:`DecodeFeed` names it as ``rider``, or for whatever needs it
+        first (a read of it, a ``decode`` fed from it or from the host's
+        tokens), which launches it alone: ``ServeEngine.prefill`` says the
+        same of its own.  The prompt is counted here either way."""
         cache = self.cache
         n = len(prompt)
         if not (0 < n <= cache.max_seq_len):
             raise ValueError(f"prompt length {n} not in (0, {cache.max_seq_len}]")
         bucket = next(b for b in self.buckets if b >= n)
+        def padded():
+            toks = np.zeros((bucket,), np.int32)
+            toks[:n] = np.asarray(prompt, np.int32)
+            return toks, n, cache.page_table[slot, : bucket // cache.config.page_size].copy()
+
         with ndtimeit(_p.SERVE_PREFILL_CALL):
-            with ndtimeit(_p.SERVE_PREFILL_LAUNCH, launch=self.launches, rung=bucket, slot=slot):   # the enqueue alone
-                toks = np.zeros((bucket,), np.int32)
-                toks[:n] = np.asarray(prompt, np.int32)
-                page_row = cache.page_table[slot, : bucket // cache.config.page_size].copy()
-                out = self._launched_prefill(*self._run_prefill(toks, n, page_row, slot), slot)
+            if self.rides:
+                out = self._prompt_waits(*padded(), slot)
+            else:
+                with ndtimeit(_p.SERVE_PREFILL_LAUNCH, launch=self.launches, rung=bucket, slot=slot):   # the enqueue alone
+                    out = self._launched_prefill(*self._run_prefill(*padded(), slot), slot)
         self.prefill_tokens_real += n
         self.prefill_tokens_padded += bucket
         self.prefill_bucket_tokens += bucket
@@ -489,7 +574,9 @@ class HybridServeEngine(DecodeAhead):
         has mean the same here (``decode_launches`` / ``prefill_launches`` are
         of calls that enqueued, ``prefill_reads_ahead`` of prefills still unread
         when the decode step behind them was enqueued, which of a block engine's
-        loop is every one: it never reads a prefill; ``decode_steps`` /
+        loop is every one: it never reads a prefill; ``prefill_rides``, which only
+        an engine that rides reports, of the prompts among them that a decode
+        step carried, the rest having gone alone; ``decode_steps`` /
         ``decode_steps_ahead`` are
         of steps read; ``logits_bytes_to_host`` is what callers copied
         out of ``decode``'s results, ``logits_rows_made`` the rows of logits a

@@ -216,11 +216,13 @@ def _idx_shape(idx, shape) -> Tuple[int, ...]:
     return tuple(len(range(*s.indices(n))) for s, n in zip(idx, shape))
 
 
-def write_pages(pool, rows, page_row, page: int):
+def write_pages(pool, rows, page_row, page: int, layer=0):
     """``rows`` (L, T, KV, hd), every layer's K or V of a rung's positions (a
     folded pool's: (L, T, 1, KV x hd)), into
     the pages ``page_row`` (T / page,) of ``pool`` (L, pages, page, KV, hd): one
-    slab of all layers a page, updated in place.  (One scatter over the page
+    slab of all layers a page, updated in place.  Rows of fewer layers (one:
+    a step that carries a prompt writes a layer at a time) go to the layers
+    from ``layer`` on, an int or a traced int32.  (One scatter over the page
     axis makes the compiler re-lay out the WHOLE pool and back around it where
     a row of the pool is 4 heads wide, 3.7 ms a copy at a pool of 1.2 GB:
     PERF.md section 6, PR 43.)  The prefill programs of ``models/falcon_h1.py``,
@@ -236,7 +238,7 @@ def write_pages(pool, rows, page_row, page: int):
 
     def one_page(p, pool):
         slab = jax.lax.dynamic_slice_in_dim(slabs, p, 1, axis=1)
-        return jax.lax.dynamic_update_slice(pool, slab, (0, page_row[p], 0, 0, 0))
+        return jax.lax.dynamic_update_slice(pool, slab, (layer, page_row[p], 0, 0, 0))
 
     return jax.lax.fori_loop(0, T // page, one_page, pool)
 

@@ -226,9 +226,10 @@ def run_serve_resilient(
     in the iteration that launched it, but the one that rides:
 
     A prompt RIDES the decode step where the engine OFFERS that
-    (``engine.rides``: a single-stage ``ServeEngine``, whose ``prefill``
-    then launches nothing and returns a ``PrefillStep`` that waits;
-    ``HybridServeEngine`` and a block engine have no such attribute, and
+    (``engine.rides``: a single-stage ``ServeEngine``, and a
+    ``HybridServeEngine`` whose model's module gives the body of such a step,
+    whose ``prefill`` then launches nothing and returns a ``PrefillStep`` that
+    waits; a block engine offers none, and
     nothing here names a model).  THIS LOOP decides, on what it observes:
     with a step in flight, no ``speculative`` and no prefix hit, an
     admitted request's prompt WAITS (``waiting``), and the oldest prompt
@@ -362,8 +363,8 @@ def run_serve_resilient(
             "speculative= and a prefix cache need a step of one token a position and a cache without slot "
             f"state; {type(engine).__name__} generates by blocks of {block.B}, whose open block is slot state"
         )
-    # does a prompt ride a decode step?  The engine's OFFER (a single-stage
-    # ``ServeEngine``'s; an engine without the attribute launches every
+    # does a prompt ride a decode step?  The engine's OFFER (either engine's; one
+    # without the attribute, or with it false, launches every
     # prompt alone); this loop takes it up where a step is about to be launched
     rides = getattr(engine, "rides", False)
     # the decode step in flight: launched, its ids not yet read, with what
