@@ -2,9 +2,12 @@
 ``stop_trace_session``): it arms and disarms in a running process, twice; its
 ``vs.*`` spans appear once a call while it runs and never while it does not;
 the engine's counters say what a tiny serve run implies; the clock offset lays
-a span recorded after the fact inside the live span that contains it; and the
-host-stall reads give ``None`` where ``/proc`` lacks a file."""
+a span recorded after the fact inside the live span that contains it; the
+host-stall reads give ``None`` where ``/proc`` lacks a file; and the
+collector's witness counts every collection, names the long ones of a window
+and is a ``vs.host-gc`` span only while a session is armed."""
 
+import gc
 import os
 import time
 
@@ -59,6 +62,12 @@ def _host_events(profile, prefix="vs."):
             for line in plane.lines for e in line.events if e.name.startswith(prefix)]
 
 
+def _metrics(out):
+    """The ring's spans by name, in order, but for the collector's: a
+    collection may fall in any session and is not what these tests place."""
+    return [s.metric for s in out.spans if s.metric != P.HOST_GC]
+
+
 def _profiler_is_running() -> bool:
     """A second start raises while one runs; a start that works is stopped again."""
     try:
@@ -87,7 +96,7 @@ def test_session_starts_and_stops_twice_in_one_process(tmp_path):
         assert not nd.is_active() and not nd.session_active()
         assert nd.get_manager().tail(10) == []
         assert not _profiler_is_running()
-        assert [s.metric for s in out.spans] == ["vs.work"]
+        assert _metrics(out) == ["vs.work"]
         assert out.xplane_path and os.path.exists(out.xplane_path) and out.profile is not None
         assert out.counters["backend_compiles"] == 0 and out.stopped > out.started
         names = [n for n, _, _ in _host_events(out.profile)]
@@ -101,7 +110,7 @@ def test_session_without_the_profiler_arms_spans_and_counters_only(tmp_path):
     with nd.ndtimeit("vs.work"):
         pass
     out = nd.stop_trace_session()
-    assert [s.metric for s in out.spans] == ["vs.work"]
+    assert _metrics(out) == ["vs.work"]
     assert out.xplane_path is None and out.profile is None and out.clock_offset_ns is None
     assert out.to_trace_ns(out.started) is None
     assert not os.path.exists(tmp_path / "none")
@@ -122,7 +131,7 @@ def test_session_leaves_an_operators_own_timers_as_they_are(tmp_path):
     with nd.ndtimeit("vs.during"):
         pass
     out = nd.stop_trace_session()
-    assert [s.metric for s in out.spans] == ["vs.during"]
+    assert _metrics(out) == ["vs.during"]
     assert nd.is_active() and nd.get_manager() is mgr           # still the operator's
     assert [s.metric for s in nd.flush()] == ["before", "vs.during"] and len(seen) == 2
 
@@ -179,7 +188,7 @@ def test_train_and_loader_spans_and_the_step_reads_no_loss_on_the_host(tmp_path,
         out = nd.stop_trace_session()
     finally:
         loader.close()
-    assert [s.metric for s in out.spans] == [P.DATA_LOAD, P.TRAIN_STEP] * 3
+    assert _metrics(out) == [P.DATA_LOAD, P.TRAIN_STEP] * 3
     assert [s.step for s in out.spans if s.metric == P.TRAIN_STEP] == [0, 1, 2]
     assert recorded == []                             # the record_step feed stays with telemetry.init()
 
@@ -281,6 +290,9 @@ def test_host_sched_stats_gives_none_where_proc_lacks_a_file(tmp_path, present):
     assert {k: got[k] for k in want} == want and got["at"] > 0
     delta = hoststat.host_sched_delta(got, hoststat.host_sched_stats(str(tmp_path)))
     assert all(delta[k] == (None if v is None else 0.0) for k, v in want.items())
+    # the interpreter's fields are no file's: a number wherever the kernel counts nothing
+    assert all(isinstance(got[k], int) and isinstance(delta[k], int) and delta[k] >= 0 for k in hoststat._GC_FIELDS)
+    assert isinstance(delta["gc_longest_pauses_ms_at_s"], list)
 
 
 def test_host_sched_delta_subtracts_field_by_field_and_keeps_none():
@@ -296,7 +308,123 @@ def test_host_sched_delta_subtracts_field_by_field_and_keeps_none():
     delta = hoststat.host_sched_delta(opened, closed)
     assert delta == {"thread_run_ns": 4e9, "thread_runq_wait_ns": 2.49e8, "thread_timeslices": None,
                      "voluntary_ctxt_switches": None, "nonvoluntary_ctxt_switches": 4.0, "cpu_steal_s": None,
-                     "psi_cpu_some_us": None, "seconds": 45.0}
+                     "psi_cpu_some_us": None, "seconds": 45.0,
+                     # two reads that lack the interpreter's fields, as a record of before PR 56 does
+                     "gc_collections": None, "gc_gen2_collections": None, "gc_pause_ns": None,
+                     "gc_gen2_pause_ns": None, "gc_longest_pauses_ms_at_s": []}
     before = threading.active_count()
     hoststat.host_sched_delta(hoststat.host_sched_stats(), hoststat.host_sched_stats())
-    assert threading.active_count() == before and set(hoststat.__all__) == {"host_sched_stats", "host_sched_delta"}
+    assert threading.active_count() == before and set(hoststat.__all__) == {
+        "host_sched_stats", "host_sched_delta", "gc_witness_counts", "arm_gc_spans"}
+
+
+# ------------------------------------------------------------ the collector's witness
+# ``quiet_collector`` (tests/conftest.py): no collection but the test's own; the witness itself never
+# touches the collector's switch, which a test below holds
+def test_witness_counts_a_full_collection_between_two_reads(quiet_collector):
+    gc.collect(2)                                   # before the first read: in no delta
+    opened = hoststat.host_sched_stats()
+    gc.collect(2)
+    closed = hoststat.host_sched_stats()
+    delta = hoststat.host_sched_delta(opened, closed)
+    assert delta["gc_collections"] == 1 and delta["gc_gen2_collections"] == 1
+    assert delta["gc_pause_ns"] == delta["gc_gen2_pause_ns"] > 0
+    (ms, at_s, gen), = delta["gc_longest_pauses_ms_at_s"]
+    assert gen == 2 and 0 <= at_s <= delta["seconds"] and ms == pytest.approx(delta["gc_gen2_pause_ns"] / 1e6, abs=1e-3)
+    # the next window holds none of it
+    quiet = hoststat.host_sched_delta(closed, hoststat.host_sched_stats())
+    assert quiet["gc_collections"] == 0 and quiet["gc_pause_ns"] == 0 and quiet["gc_longest_pauses_ms_at_s"] == []
+
+
+def test_witness_tells_generations_apart_and_shows_the_longest_three(quiet_collector):
+    opened = hoststat.host_sched_stats()
+    for g in (0, 1, 2, 0, 2):
+        gc.collect(g)
+    delta = hoststat.host_sched_delta(opened, hoststat.host_sched_stats())
+    assert delta["gc_collections"] == 5 and delta["gc_gen2_collections"] == 2
+    assert 0 < delta["gc_gen2_pause_ns"] < delta["gc_pause_ns"]
+    shown = delta["gc_longest_pauses_ms_at_s"]
+    assert len(shown) == hoststat.GC_LONGEST_SHOWN and [p[0] for p in shown] == sorted((p[0] for p in shown), reverse=True)
+    assert all(0 <= p[1] <= delta["seconds"] and p[2] in (0, 1, 2) for p in shown)
+
+
+def test_witness_keeps_a_bounded_list_of_the_longest(quiet_collector):
+    hoststat.host_sched_stats()
+    for _ in range(3 * hoststat.GC_LONGEST_KEPT):
+        gc.collect(0)
+    gc.collect(2)                                    # the long one comes last, with the list full
+    kept = hoststat.host_sched_stats()["gc_longest_pauses"]
+    assert len(kept) == hoststat.GC_LONGEST_KEPT and max(kept)[2] == 2
+    assert hoststat.host_sched_stats()["gc_longest_pauses"] == []      # a read leaves an empty list behind
+
+
+def test_witness_is_installed_once_and_sets_nothing_of_the_collector(tmp_path):
+    thresholds, enabled = gc.get_threshold(), gc.isenabled()
+    frozen = gc.get_freeze_count()
+    for _ in range(2):
+        hoststat.host_sched_stats()
+        nd.start_trace_session(str(tmp_path / "w"), profiler=False)
+        nd.stop_trace_session()
+    assert gc.callbacks.count(hoststat._on_gc) == 1
+    assert gc.get_threshold() == thresholds and gc.isenabled() == enabled and gc.get_freeze_count() == frozen
+
+
+def test_a_full_collection_under_a_session_is_one_host_gc_span_inside_the_span_it_fell_in(tmp_path, quiet_collector):
+    nd.start_trace_session(str(tmp_path / "g"), profiler=False)
+    with nd.ndtimeit(P.SERVE_BOOKS):
+        gc.collect(0)                               # tens of microseconds: counted, no span
+        gc.collect(2)
+    out = nd.stop_trace_session()
+    (books,), (pause,) = ([s for s in out.spans if s.metric == m] for m in (P.SERVE_BOOKS, P.HOST_GC))
+    assert books.start <= pause.start and pause.start + pause.duration <= books.start + books.duration
+    assert pause.tags["gen"] == 2 and pause.tags["collected"] >= 0 and pause.duration > 0
+    assert out.counters["gc_pauses"] == 2 and out.counters["gc_gen2_pauses"] == 1
+    assert out.counters["gc_pause_us"] >= int(pause.duration * 1e6 * 0.5)
+    # disarmed with the session: a further collection is counted and is no span
+    counted = hoststat.gc_witness_counts()
+    gc.collect(2)
+    assert hoststat.gc_witness_counts()[1] == counted[1] + 1
+    assert hoststat._gc_spans is None and nd.get_manager().tail(10) == []
+    nd.start_trace_session(str(tmp_path / "g2"), profiler=False)
+    again = nd.stop_trace_session()
+    assert again.spans == [] and again.counters["gc_pauses"] == 0
+
+
+def test_generation_zero_is_a_span_only_when_it_is_long(tmp_path, quiet_collector, monkeypatch):
+    nd.start_trace_session(str(tmp_path / "z"), profiler=False)
+    for _ in range(5):
+        gc.collect(0)
+    monkeypatch.setattr(nd, "GC_SPAN_MIN_S", 0.0)   # every one is "long" now
+    gc.collect(0)
+    gc.collect(1)
+    out = nd.stop_trace_session()
+    assert [s.tags["gen"] for s in out.spans if s.metric == P.HOST_GC] == [0, 1]
+    assert out.counters["gc_pauses"] == 7 and out.counters["gc_gen2_pauses"] == 0
+
+
+def test_host_gc_is_an_annotation_on_the_trace_nested_in_the_span_it_fell_in(tmp_path, quiet_collector):
+    """Under the profiler the collection is on the host plane, on the
+    trace's clock, inside the ``vs.*`` span the host was in, and the ring's
+    record of it maps onto the annotation through the session's offset."""
+    nd.start_trace_session(str(tmp_path / "t"))
+    with nd.ndtimeit(P.SERVE_BOOKS):
+        gc.collect(0)
+        gc.collect(2)
+    out = nd.stop_trace_session()
+    events = _host_events(out.profile)
+    (_, a, b), = [e for e in events if e[0] == P.SERVE_BOOKS]
+    (_, ga, gb), = [e for e in events if e[0] == P.HOST_GC]
+    assert a <= ga < gb <= b
+    ring, = [s for s in out.spans if s.metric == P.HOST_GC]
+    assert abs(out.to_trace_ns(ring.start) - ga) < 2e5
+
+
+def test_a_collection_inside_the_rings_own_lock_does_not_wait_for_it(tmp_path, quiet_collector):
+    """``NDTimerManager.record`` allocates a span under its lock, and the
+    collector runs wherever something allocates: the witness keeps the
+    collection aside and the session records it once it stops."""
+    nd.start_trace_session(str(tmp_path / "l"), profiler=False)
+    with nd.get_manager()._lock:
+        gc.collect(1)
+    out = nd.stop_trace_session()
+    assert [s.tags["gen"] for s in out.spans if s.metric == P.HOST_GC] == [1]

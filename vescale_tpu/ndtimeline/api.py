@@ -12,7 +12,7 @@ import time
 import weakref
 from typing import Any, Dict, List, Optional
 
-from .predefined import SESSION_MARK
+from .predefined import HOST_GC, SESSION_MARK
 from .timer import NDTimerManager, Span
 from .world_info import WorldInfo
 
@@ -228,6 +228,43 @@ class _LiveSession:
     compiles: List[float]
     on_compile: Any
     mark_epoch_s: float
+    gc_spans: "_GcSpans"
+    gc_at_start: tuple                              # ``hoststat.gc_witness_counts()`` as the session armed
+
+
+GC_SPAN_MIN_S = 1e-3    # a generation-0 collection is a span only if it took longer
+
+
+class _GcSpans:
+    """What the collector's witness (``telemetry/hoststat.py``) hands its
+    collections to while a session is armed.  A collection of generation 1
+    or 2 is a ``vs.host-gc`` annotation on the trace's clock, opened before the
+    pause and closed after it on the collecting thread; with any generation-0
+    one over a millisecond (tens of microseconds is its rule, hundreds of
+    times a second: an annotation each would be the armed cost) it is kept
+    here and poured into the ring as the session stops.  Not before: the
+    collector can run inside the ring's own lock, so nothing here takes it."""
+
+    def __init__(self, annotation):
+        self._annotation = annotation       # ``jax.profiler.TraceAnnotation``, bound once: no import at a collection
+        self._ann = None
+        self._t0 = 0.0
+        self.kept: List[tuple] = []         # (epoch start, seconds, generation, collected)
+
+    def open(self, gen: int) -> None:
+        if gen:
+            self._ann = self._annotation(HOST_GC, gen=gen)
+            self._ann.__enter__()
+        self._t0 = time.time()
+
+    def close(self, gen: int, collected: int) -> None:
+        duration = time.time() - self._t0
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.set_metadata(collected=collected)
+            ann.__exit__(None, None, None)
+        if gen or duration > GC_SPAN_MIN_S:
+            self.kept.append((self._t0, duration, gen, collected))
 
 
 _SESSION: Optional[_LiveSession] = None
@@ -288,9 +325,13 @@ def start_trace_session(log_dir: str, *, profiler: bool = True, rank: int = 0) -
     own_timers = not is_active()   # an operator's own init_ndtimers, and its handlers, stay as they are
     if own_timers:
         init_ndtimers(rank=rank)
+    from ..telemetry import hoststat
+
+    gc_spans = _GcSpans(jax.profiler.TraceAnnotation)
+    hoststat.arm_gc_spans(gc_spans)
     _SESSION = _LiveSession(log_dir=log_dir, profiler=profiler, own_timers=own_timers, started=time.time(),
                             counters_at_start=_counters_by_source(), compiles=compiles, on_compile=on_compile,
-                            mark_epoch_s=_mark())
+                            mark_epoch_s=_mark(), gc_spans=gc_spans, gc_at_start=hoststat.gc_witness_counts())
 
 
 def stop_trace_session() -> TraceSession:
@@ -305,7 +346,10 @@ def stop_trace_session() -> TraceSession:
     import jax
     from jax._src import monitoring as _monitoring
 
+    from ..telemetry import hoststat
+
     _SESSION = None
+    hoststat.arm_gc_spans(None)
     stopped = time.time()
     counters: Dict[str, int] = {}
     for source, values in _counters_by_source().items():     # a source born in the session counts from zero
@@ -313,6 +357,10 @@ def stop_trace_session() -> TraceSession:
         for name, value in values.items():
             counters[name] = counters.get(name, 0) + value - before.get(name, 0)
     counters["backend_compiles"] = len(live.compiles)
+    collections, full, pause_ns, _ = (b - a for a, b in zip(live.gc_at_start, hoststat.gc_witness_counts()))
+    counters.update(gc_pauses=collections, gc_gen2_pauses=full, gc_pause_us=pause_ns // 1000)
+    for start, duration, gen, collected in live.gc_spans.kept:
+        get_manager().record(HOST_GC, start, duration, {"gen": gen, "collected": collected})
     if live.own_timers:
         spans = get_manager().flush()
         deinit_ndtimers()

@@ -84,6 +84,13 @@ SERVE_INBOX_WAIT = "serve-inbox-wait"
 # the one annotation a trace session (ndtimeline/api.py) emits at its start:
 # its instant is known on the spans' clock and on the trace's
 SESSION_MARK = "vs.session-mark"
+# the interpreter's collector, timed where it runs (telemetry/hoststat.py's
+# witness on ``gc.callbacks``; ndtimeline/api.py makes the span of it while a
+# session is armed): a collection of generation 1 or 2, tagged ``gen`` and
+# ``collected``, nested in whatever span the host was in.  A full collection
+# holds the interpreter's lock for its length, so a device left idle above
+# one names its cause.
+HOST_GC = "vs.host-gc"
 # speculative decoding (serve/speculative.py; ISSUE 15): with a drafter
 # armed each decode iteration forks into a serve-draft span (the drafter's
 # k sequential proposal steps) and a serve-verify span (the target's ONE
