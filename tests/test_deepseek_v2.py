@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from benchmark.spec import SpecError, load_family
-from vescale_tpu.kernels.paged_attention import paged_decode_latent, supports_latent
+from vescale_tpu.kernels.paged_attention import _latent_blocks, paged_decode_latent, supports_latent
 from vescale_tpu.mesh import DeviceMesh
 from vescale_tpu.models import blocks
 from vescale_tpu.models import deepseek_v2 as ds
@@ -331,28 +331,49 @@ def test_every_rung_is_compiled_before_the_engine_is_handed_over_and_the_counter
 
 
 # -------------------------------------------------------------------- kernel
-def ragged_pool(rng, dtype, lengths, *, layers=2, page=16, row=256, pages_per_slot=9):
-    pool = jnp.asarray(rng.normal(size=(layers, 1 + sum(-(-n // page) for n in lengths), page, 1, row)), dtype)
+def ragged_pool(rng, dtype, lengths, *, layers=2, page=16, row=256, pages_per_slot=9, scattered=False):
+    """A latent pool whose null page holds NaN and a table of each slot's live pages, in order or ``scattered`` over
+    the pool; every unused entry of the table points at the null page."""
+    live = sum(-(-n // page) for n in lengths)
+    pool = jnp.asarray(rng.normal(size=(layers, 1 + live, page, 1, row)), dtype)
     pool = pool.at[:, 0].set(jnp.nan)               # the null page holds garbage that must reach nothing
-    table, nxt = np.zeros((len(lengths), pages_per_slot), np.int32), 1
+    ids = 1 + (rng.permutation(live) if scattered else np.arange(live))
+    table, nxt = np.zeros((len(lengths), pages_per_slot), np.int32), 0
     for s, n in enumerate(lengths):
         for i in range(-(-n // page)):
-            table[s, i], nxt = nxt, nxt + 1
+            table[s, i], nxt = ids[nxt], nxt + 1
     return pool, jnp.asarray(table)
 
 
+# a block of the kernel is 512 positions, fetched in groups of 128 (the cases' pages of 16 and float32 rows of 256)
+_G = 16 * _latent_blocks(64, 16, 256, 4)[0]
+_T = _G * _latent_blocks(64, 16, 256, 4)[1]
+# name: (lengths, heads, pages a slot, pool layers, the table scattered)
+LATENT_CASES = {
+    # nothing, part of a page, a full slot, ragged, one whole page: one block a slot
+    "one-block": ([0, 5, 16 * 9, 37, 16], 8, 9, 2, False),
+    # a block's edges: the double buffer across blocks and the next slot's first block started from a slot's last
+    "block-edges-64-heads": ([_T - 1, _T, _T + 1, 2 * _T], 64, 2 * _T // 16, 1, True),
+    # a group's edges, a slot of nothing and one of a page BETWEEN long ones, a table no whole number of blocks wide
+    "group-edges-128-heads": ([2 * _T + _G + 1, 0, _T + _G, 16, 3 * _T - 5, _G - 1, 1, 2 * _T + 2 * _G], 128, 100, 1, True),
+    # three blocks and a tail a slot, each slot's last block another count of live groups, the pages in order
+    "long-slots-64-heads": ([3 * _T + 7, 3 * _T + _G + 16, 4 * _T - 1, 3 * _T + 3 * _G], 64, 4 * _T // 16, 2, False),
+}
+
+
+@pytest.mark.parametrize("case", list(LATENT_CASES))
 @pytest.mark.parametrize("dtype,bound", [(jnp.float32, 2e-6), (jnp.bfloat16, 2e-2)], ids=["float32", "bfloat16"])
-def test_the_latent_decode_kernel_is_the_xla_leg_on_ragged_lengths_a_null_page_and_a_full_slot(dtype, bound):
+def test_the_latent_decode_kernel_is_the_xla_leg_on_ragged_lengths_a_null_page_and_a_full_slot(dtype, bound, case):
+    lengths, heads, pages_per_slot, layers, scattered = LATENT_CASES[case]
     rng = np.random.default_rng(0)
-    lengths = [0, 5, 16 * 9, 37, 16]                # nothing, part of a page, a full slot, ragged, one whole page
-    pool, table = ragged_pool(rng, dtype, lengths)
-    q = jnp.asarray(rng.normal(size=(len(lengths), 8, 256)), dtype)
+    pool, table = ragged_pool(rng, dtype, lengths, layers=layers, pages_per_slot=pages_per_slot, scattered=scattered)
+    q = jnp.asarray(rng.normal(size=(len(lengths), heads, 256)), dtype)
     valid = jnp.asarray(lengths, jnp.int32)
-    for layer in (0, 1):
+    for layer in range(layers):
         got = paged_decode_latent(q, pool, table, valid, layer=layer, scale=0.3, latent=128, interpret=True)
         want = paged_decode_latent(q, pool, table, valid, layer=layer, scale=0.3, latent=128, interpret=None)
-        assert got.shape == (len(lengths), 8, 128) and got.dtype == jnp.float32
-        assert np.isfinite(np.asarray(got)).all() and not np.asarray(got[0]).any()
+        assert got.shape == (len(lengths), heads, 128) and got.dtype == jnp.float32
+        assert np.isfinite(np.asarray(got)).all() and not np.asarray(got)[np.asarray(lengths) == 0].any()
         assert float(jnp.max(jnp.abs(got - want))) < bound * float(jnp.max(jnp.abs(want)))
 
 

@@ -59,7 +59,7 @@ PROGRAMS = {
     "deepseek_v2/xla_legs/decode": ('ad9c244ea9a2ebe5', 'vs.attn=513 vs.mlp=9 vs.moe=130'),
     "deepseek_v2/kernels_interpreted/prefill_rung_1": ('fe17fcd91d420093', 'vs.attn=1788 vs.mlp=9 vs.moe=108'),
     "deepseek_v2/kernels_interpreted/prefill_rung_2": ('21764e8046f1a272', 'vs.attn=1788 vs.mlp=9 vs.moe=108'),
-    "deepseek_v2/kernels_interpreted/decode": ('2f1889f41534b464', 'vs.attn=402 vs.mlp=9 vs.moe=130'),
+    "deepseek_v2/kernels_interpreted/decode": ('d4549fe0907634cd', 'vs.attn=402 vs.mlp=9 vs.moe=130'),
     "sdar_moe/xla_legs/prefill_rung_1": ('368d83fff82f4894', 'vs.attn=314 vs.moe=62'),
     "sdar_moe/xla_legs/prefill_rung_2": ('0a77108ffbf534d9', 'vs.attn=314 vs.moe=62'),
     "sdar_moe/xla_legs/decode": ('b85e2370cf46c46e', 'vs.attn=414 vs.moe=62 vs.unmask=78'),
@@ -89,7 +89,7 @@ PROGRAMS = {
     "longcat_flash/xla_legs/decode": ('02f28e4b8fa49840', 'vs.attn=704 vs.mlp=36 vs.moe=74'),
     "longcat_flash/kernels_interpreted/prefill_rung_1": ('dc1e4a16dd58625c', 'vs.attn=2404 vs.mlp=36 vs.moe=68'),
     "longcat_flash/kernels_interpreted/prefill_rung_2": ('3cd144778ac50ded', 'vs.attn=2404 vs.mlp=36 vs.moe=68'),
-    "longcat_flash/kernels_interpreted/decode": ('cfc4ff6f77f0cfba', 'vs.attn=556 vs.mlp=36 vs.moe=74'),
+    "longcat_flash/kernels_interpreted/decode": ('1b33297552cdd061', 'vs.attn=556 vs.mlp=36 vs.moe=74'),
 }
 
 

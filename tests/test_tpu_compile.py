@@ -155,15 +155,23 @@ def test_paged_decode_compiles(chip, S, Pmax, page, H, KV, hd, dtype, L):
 # eight pool layers, 109 operations a byte read: the memory side); rows of 576 padded to 640, the values the first 512, pages of 16
 @pytest.mark.parametrize("S,Pmax,H,L", [(32, 512, 128, 5), (128, 256, 64, 8)], ids=["deepseekv2-128-heads", "longcat-64-heads"])
 def test_paged_decode_latent_compiles_at_both_head_counts(chip, S, Pmax, H, L):
-    from vescale_tpu.kernels.paged_attention import paged_decode_latent, supports_latent
+    from vescale_tpu.kernels.paged_attention import _latent_blocks, paged_decode_latent, supports_latent
 
     assert supports_latent(bf16, 640, 512, 16, interpret=False)
     sds = lambda shape, dt=bf16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
     args = (sds((S, H, 640)), sds((L, 20480, 16, 1, 640)), sds((S, Pmax), jnp.int32), sds((S,), jnp.int32), sds((), jnp.int32))
     compiled = _compile(lambda q, pool, table, lengths, layer: paged_decode_latent(
         q, pool, table, lengths, layer=layer, scale=192 ** -0.5, latent=512, interpret=False), *args)
-    assert "paged_decode_latent" in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20   # no copy of the pool
+    text = compiled.as_text()
+    # what the benchmark finds the kernel's events by (benchmark/layer_metrics/mla_serve_batch.py, families/longcat_flash.py)
+    assert "paged_decode_latent" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20   # no copy of the pool, and the table is not padded
+    # blocks of four groups of 8 pages whatever the head count, in two buffers: 1.3 MB beside the softmax's state, well
+    # under the 16 MiB a kernel has without asking, and it does not ask (a kernel that sets `vmem_limit_bytes` changes
+    # how the compiler builds every other fusion of its program)
+    group, groups = _latent_blocks(Pmax, 16, 640, 2)
+    assert (group, groups) == (8, 4)
+    assert 2 * group * groups * 16 * 640 * 2 + H * (512 + 2 * 128) * 4 < 2 << 20 and '"scoped_memory_configs":[{' not in text
 
 
 # --------------------------------------------------------------- ssm step

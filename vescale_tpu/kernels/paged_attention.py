@@ -269,12 +269,47 @@ def paged_decode(q, k_pool, v_pool, table, lengths, *, layer, scale, interpret):
 # ------------------------------------------------------------- the latent form
 # Multi-head latent attention's decode (the absorbed form): the pool holds ONE
 # row a position, shared by every head, and the values are the row's first
-# ``latent`` columns.  So a block of pages is fetched once (one DMA a page, as
-# above) and serves both products: all ``H`` absorbed queries against the
-# block's rows through the MXU for the scores, the probabilities against the
-# same rows' leading columns for the mix.  With 128 heads on one row that is
-# about 240 operations a byte read: the v5e's ridge, where the kernel above
-# (one or four heads a row) sits far on the memory side.
+# ``latent`` columns.  So a block of pages is fetched once and serves both
+# products: all ``H`` absorbed queries against the block's rows through the MXU
+# for the scores, the probabilities against the same rows' leading columns for
+# the mix.  With 128 heads on one row that is about 240 operations a byte read:
+# the v5e's ridge, where the kernel above (one or four heads a row) sits far on
+# the memory side.
+#
+# How a block is fetched (PR 57; PERF.md section 6 has the split it rests on).  A
+# page of 16 rows is one copy of 20 KB, and what such a page costs is not its
+# bytes (32 copies in flight move at 766 GB/s) but the scalar unit's work on its
+# descriptor, which runs under nothing: a DMA start holds the instruction
+# stream whether it stands before the products or among them.  So the kernel
+# makes a descriptor as cheap as it gets and issues as few branches as it can:
+#   * a block's pages are started in GROUPS (128 positions: 8 pages of 16), the
+#     copies of a group unrolled under one branch (is the group live?), and a
+#     block is waited for ONCE, by a descriptor over its live groups (the
+#     semaphore counts bytes).  A group is fetched whole: its pages past the
+#     slot's length are whatever the table names (the null page), masked like
+#     every row past the length;
+#   * the buffer's index is STATIC in every descriptor: the buffers alternate
+#     over the whole call (a slot's last block starts the next slot's first), so
+#     the slot's loop walks that ring two positions a trip, from the position
+#     its first block stands in.
+# One dynamic loop over the pages with one wait a page cost 1.19 us a block of
+# 512 positions beside 0.84 (64 heads) or 1.33 (128) of compute, and they ADD;
+# this form costs 0.42.  The masks are free (they hide under the products), and
+# products bounded by the live groups of a slot's last block were SLOWER than
+# whole ones (four bodies and their branches), so every block's products are whole.
+_GROUP_POSITIONS = 128       # of a group: its pages' copies are started under one branch
+
+
+def _latent_blocks(pages_per_slot: int, page: int, row: int, itemsize: int):
+    """``(pages a group, groups a block)`` of the latent kernel, from the shapes
+    alone: groups of ``_GROUP_POSITIONS`` positions (at least a page), and as
+    many of them a block as ``_BLOCK_BYTES`` and ``_BLOCK_POSITIONS`` allow
+    (rows of 640 bfloat16 in pages of 16: 4 groups of 8 pages, 512 positions),
+    never more than a slot has."""
+    group = max(1, _GROUP_POSITIONS // page)
+    return group, max(1, _block_pages(pages_per_slot + group - 1, page, 1, row, itemsize) // group)
+
+
 def supports_latent(pool_dtype, row: int, latent: int, page: int, *, interpret: bool) -> bool:
     """Whether :func:`paged_decode_latent` takes a pool of this dtype with
     rows ``row`` wide of which the first ``latent`` are the values: compiled,
@@ -308,52 +343,53 @@ def latent_attention_xla(q, pool, table, valid_len, *, layer: int, scale: float,
 
 
 def _latent_kernel(layer_ref, len_ref, table_ref, q_ref, pool_hbm, o_ref,
-                   buf, sems, cur_ref, m_scr, l_scr, acc_scr, *, scale, page, block_pages, latent):
-    """Grid (S,), sequential; the buffers alternate over the whole call as in ``_decode_kernel``."""
+                   buf, sems, cur_ref, m_scr, l_scr, acc_scr, *, scale, page, group, groups, latent):
+    """Grid (S,), sequential; the two buffers alternate over the whole call as in ``_decode_kernel``
+    (``cur_ref``: the buffer the slot's first block is in).  Its trace is a serve cell's set-up time, twice a cell:
+    two bodies and a group's copies traced once keep it at 0.07 s (a Python loop over the copies: 0.26 s, and 5 s of
+    ``setup_s`` on the chip's host)."""
     s = pl.program_id(0)
     n_slots = pl.num_programs(0)
-    T = block_pages * page
+    block_pages = group * groups
+    GT = group * page                  # positions a group
+    T = groups * GT                    # positions a block
     row = buf.shape[-1]
     layer = layer_ref[0]
 
-    def block_dma(slot, block, b, wait):
-        first = block * block_pages
-        live = jnp.clip(pl.cdiv(len_ref[slot], page) - first, 0, block_pages)
+    def live_groups(slot, block):
+        """The groups of ``block`` of ``slot`` that hold a live position."""
+        return jnp.clip(pl.cdiv(len_ref[slot] - block * T, GT), 0, groups)
 
+    def start(slot, block, into, n):
+        """Start the copies of the first ``n`` groups of ``block`` of ``slot`` into buffer ``into`` (static)."""
         def one_page(i, carry):
-            copy = pltpu.make_async_copy(pool_hbm.at[layer, table_ref[slot, first + i]], buf.at[b, i], sems.at[b])
-            copy.wait() if wait else copy.start()
+            pltpu.make_async_copy(pool_hbm.at[layer, table_ref[slot, block * block_pages + i]],
+                                  buf.at[into, i], sems.at[into]).start()
             return carry
 
-        jax.lax.fori_loop(0, live, one_page, 0)
+        for g in range(groups):
+            @pl.when(g < n)
+            def _(g=g):       # unrolled where it is lowered (a page's index is a constant there), traced once
+                jax.lax.fori_loop(g * group, (g + 1) * group, one_page, 0, unroll=True)
 
     length = len_ref[s]
+    # at least one block a slot (a length of 0 fetches nothing and computes nothing):
+    # the last block of a slot is where the next slot's first is started
     n_blocks = jnp.maximum(pl.cdiv(length, T), 1)
 
     @pl.when(s == 0)
     def _first():
         cur_ref[0] = 0
-        block_dma(0, 0, 0, wait=False)
+        start(0, 0, 0, live_groups(0, 0))
 
     m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
     l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
     acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
     q = q_ref[0]                                                       # (H, row), the pool's type
 
-    def block_body(b, cur):
-        nxt = 1 - cur
-
-        @pl.when(b + 1 < n_blocks)
-        def _():
-            block_dma(s, b + 1, nxt, wait=False)
-
-        @pl.when(jnp.logical_and(b + 1 == n_blocks, s + 1 < n_slots))
-        def _():
-            block_dma(s + 1, 0, nxt, wait=False)
-
-        block_dma(s, b, cur, wait=True)
+    def attend(b, cur):
         rows = buf[cur].reshape(T, row)
-        # rows past the length are stale pool bytes, or VMEM a skipped DMA never wrote: zeroed, and their scores masked
+        # rows past the length are stale pool bytes, or VMEM no copy ever wrote: zeroed, and their scores masked
         keep = (b * T + jax.lax.broadcasted_iota(jnp.int32, (T, row), 0)) < length
         rows = jnp.where(keep, rows, jnp.zeros_like(rows))
         sc = scale * jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)  # (H, T)
@@ -367,9 +403,39 @@ def _latent_kernel(layer_ref, len_ref, table_ref, q_ref, pool_hbm, o_ref,
         m_scr[...] = m_new
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             pexp.astype(rows.dtype), rows[:, :latent], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        return nxt
 
-    cur_ref[0] = jax.lax.fori_loop(0, n_blocks, block_body, cur_ref[0])
+    def one_block(b, cur):
+        """Block ``b`` of this slot, in buffer ``cur`` (static): start what follows it, wait for it, attend to it."""
+        more = b + 1 < n_blocks
+        nslot = jnp.minimum(jnp.where(more, s, s + 1), n_slots - 1)
+        nblock = jnp.where(more, b + 1, 0)
+        start(nslot, nblock, 1 - cur, jnp.where(jnp.logical_or(more, s + 1 < n_slots), live_groups(nslot, nblock), 0))
+        live = live_groups(s, b)
+        for k in range(1, groups + 1):
+            @pl.when(live == k)
+            def _(k=k):       # one wait for what was started: k groups' bytes
+                pltpu.make_async_copy(pool_hbm.at[layer, pl.ds(0, k * group)], buf.at[cur, pl.ds(0, k * group)],
+                                      sems.at[cur]).wait()
+
+        @pl.when(live > 0)
+        def _():
+            attend(b, cur)
+
+    # the call's blocks stand in a ring of two positions: this slot's block b at position first + b, in buffer
+    # (first + b) % 2; the loop walks the positions in pairs, so that a buffer's index is the place in the pair
+    first = cur_ref[0]
+
+    def two_positions(j, carry):
+        for k in range(2):
+            b = 2 * j + k - first
+
+            @pl.when(jnp.logical_and(b >= 0, b < n_blocks))
+            def _(b=b, k=k):
+                one_block(b, k)
+        return carry
+
+    jax.lax.fori_loop(0, pl.cdiv(first + n_blocks, 2), two_positions, 0)
+    cur_ref[0] = (first + n_blocks) % 2
     l = l_scr[...]
     o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
@@ -394,7 +460,11 @@ def paged_decode_latent(q, pool, table, lengths, *, layer, scale, latent, interp
         raise ValueError(f"paged_decode_latent takes no {pool.dtype} pool of rows {row} / {latent} in pages of {page} "
                          "(see supports_latent())")
     Pmax = table.shape[1]
-    bp = _block_pages(Pmax, page, 1, row, jnp.dtype(pool.dtype).itemsize)
+    group, groups = _latent_blocks(Pmax, page, row, jnp.dtype(pool.dtype).itemsize)
+    bp = group * groups
+    table = table.astype(jnp.int32)
+    if Pmax % bp:       # a group is started whole: the table ends on a block's edge, its new entries the null page
+        table = jnp.pad(table, ((0, 0), (0, bp - Pmax % bp)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S,),
@@ -410,14 +480,14 @@ def paged_decode_latent(q, pool, table, lengths, *, layer, scale, latent, interp
         ],
     )
     return pl.pallas_call(
-        functools.partial(_latent_kernel, scale=float(scale), page=page, block_pages=bp, latent=latent),
+        functools.partial(_latent_kernel, scale=float(scale), page=page, group=group, groups=groups, latent=latent),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, latent), jnp.float32),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_latent",
     )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.clip(lengths.astype(jnp.int32), 0, Pmax * page),
-      table.astype(jnp.int32), q, pool.reshape(L, N, page, row))
+      table, q, pool.reshape(L, N, page, row))
 
 
 # ------------------------------------------------------------- the folded form
