@@ -29,6 +29,25 @@ from vescale_tpu.mesh import DeviceMesh  # noqa: E402
 
 NUM_DEVICES = 8
 
+# ``tests/benchmark``'s older files hold two tables of what the benchmark had when they were written: the toy cell that
+# stands for each real one (``test_bm_session.TINY_OF``: a cell it lacks is a ``KeyError`` in a fixture) and "every serve
+# mix that WAS THERE" (``test_bm_order_seed.FILES``, a glob held to the files that carried ``order_seed`` then).
+# ``tests/benchmark/conftest.py`` names the later cells for the first, and ``BENCHMARK.json``'s ``paths`` cover that
+# directory: a PR that adds a cell and is no ``benchmark`` PR edits no file there, names its cell and traffic file HERE,
+# and the next ``benchmark`` PR moves both into that directory's own tables (ROADMAP D14 a).  At collection, so that
+# each of those files still runs alone.
+LATER_CELLS = {"phi4miniflash_serve_reasoning": "tiny_batch"}
+LATER_TRAFFIC_FILES = {"reasoning2k_closed120"}
+
+
+def pytest_collection_modifyitems(session, config, items):
+    for module in {item.module for item in items if hasattr(item, "module")}:
+        if isinstance(getattr(module, "TINY_OF", None), dict):
+            for cell, toy in LATER_CELLS.items():
+                module.TINY_OF.setdefault(cell, toy)
+        if hasattr(module, "PLANNED_BEFORE") and isinstance(getattr(module, "FILES", None), list):
+            module.FILES = [name for name in module.FILES if name not in LATER_TRAFFIC_FILES]
+
 
 @pytest.fixture
 def mesh1d():

@@ -7,7 +7,7 @@ one described device, and compiles it.  Nothing executes, so this says
 nothing about results or times; it catches what interpret mode cannot — a
 kernel the compiler refuses (scoped VMEM, tiling, HBM).  The cases call the
 kernel entry points below the platform dispatch (``_flash``,
-``paged_decode``, ``ssm_step``, ``grouped_swiglu``, ``head_select``, ``_fused_local``, ``fused_xent_parts``): code that asks
+``paged_decode``, ``ssm_step``, ``selective_scan``, ``grouped_swiglu``, ``head_select``, ``_fused_local``, ``fused_xent_parts``): code that asks
 ``jax.devices()`` still sees the CPU here.
 """
 
@@ -122,6 +122,9 @@ PAGED_CASES = [
     (16, 128, 16, 16, 16, 128, bf16, 1),
     (16, 64, 16, 12, 12, 64, f32, 1),
     (8, 16, 8, 32, 32, 128, f32, 2),
+    # ten key heads (no whole number of a bfloat16 page's sublane tiles): declined in bfloat16, taken in float32
+    (8, 16, 16, 20, 10, 128, bf16, 1),
+    (8, 16, 16, 20, 10, 128, f32, 1),
     # SDAR's pass: a block's 4 x 32 query rows a slot ride as 4 groups (key heads) of 32, 128 slots, six layers
     (128, 128, 16, 128, 4, 128, bf16, 6),
     # Falcon-H1-34B: 20 query rows on 4 key heads, five a key head (not a whole number of 8-row tiles, no power of two)
@@ -163,7 +166,7 @@ def test_paged_decode_latent_compiles_at_both_head_counts(chip, S, Pmax, H, L):
     compiled = _compile(lambda q, pool, table, lengths, layer: paged_decode_latent(
         q, pool, table, lengths, layer=layer, scale=192 ** -0.5, latent=512, interpret=False), *args)
     text = compiled.as_text()
-    # what the benchmark finds the kernel's events by (benchmark/layer_metrics/mla_serve_batch.py, families/longcat_flash.py)
+    # what the benchmark finds the kernel's events by (benchmark/families/deepseek_v2.py and families/longcat_flash.py: ``DECODE_KERNEL``)
     assert "paged_decode_latent" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20   # no copy of the pool, and the table is not padded
     # blocks of four groups of 8 pages whatever the head count, in two buffers: 1.3 MB beside the softmax's state, well
@@ -207,6 +210,55 @@ def test_ssm_step_compiles_in_place_with_two_groups_and_a_state_of_256(chip):
     assert "tpu_custom_call" in compiled.as_text()
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == L * S * N * J * 4 and memory.temp_size_in_bytes < 1 << 20   # no copy of the state
+
+
+# Phi-4-mini-flash's nine Mamba-1 layers at 96 slots (the benchmark's cell): the decay formed in VMEM from ``dt`` and ``A``
+def test_ssm_step_selective_compiles_in_place(chip):
+    from vescale_tpu.kernels.ssm_step import ssm_step_selective
+
+    L, S, N, J = 9, 96, 16, 5120
+    sds = lambda shape, dt=f32: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    step = jax.jit(lambda state, dt, A, dtx, B, C, layer: ssm_step_selective(state, dt, A, dtx, B, C, layer=layer, interpret=False),
+                   donate_argnums=0)
+    compiled = step.lower(sds((L, S, N, J)), sds((S, J)), sds((N, J)), sds((S, J)), sds((S, N)), sds((S, N)),
+                          sds((1,), jnp.int32)).compile()
+    assert "ssm_step_selective" in compiled.as_text()       # what benchmark/families/phi4flash.py finds its events by
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == L * S * N * J * 4 and memory.temp_size_in_bytes < 1 << 20   # no copy of the state, no decay written out
+
+
+# ------------------------------------------------------------ selective scan
+# (positions, channels): the cell's first rung, its most common one and its last, at d_inner 5120 and a state of 16
+@pytest.mark.parametrize("T", [128, 2048, 4096])
+def test_selective_scan_compiles_at_the_cells_rungs(chip, T):
+    from vescale_tpu.kernels.selective_scan import selective_scan, supports
+
+    N, J = 16, 5120
+    assert supports(N, J, T, interpret=False)
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, f32, sharding=chip)
+    compiled = _compile(lambda u, dt, A, B, C: selective_scan(u, dt, A, B, C, interpret=False),
+                        sds(T, J), sds(T, J), sds(N, J), sds(T, N), sds(T, N))
+    assert "selective_scan" in compiled.as_text()           # what benchmark/families/phi4flash.py finds its events by
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20     # B and C by eights; no state in HBM but the last
+
+
+# Phi-4-mini-flash's folded rows (ten key heads of 128: no whole number of sublane tiles, so not ``paged_decode``'s pool): the
+# one pool layer at 96 slots x 256 pages and the eight rings as 32 pages a slot, 40 query rows of 128
+@pytest.mark.parametrize("L,pages,Pmax", [(1, 96 * 256 + 1, 256), (8, 96 * 32, 32)], ids=["the-pool", "the-rings-as-pages"])
+def test_paged_decode_folded_compiles_at_ten_key_heads(chip, L, pages, Pmax):
+    from vescale_tpu.kernels.paged_attention import paged_decode_folded, supports, supports_folded
+
+    S, H, KV, hd = 96, 40, 10, 128
+    assert supports_folded(bf16, KV, hd, hd, 16, interpret=False)
+    sds = lambda shape, dt=bf16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    pool = sds((L, pages, 16, 1, KV * hd))
+    compiled = _compile(lambda q, k, v, table, lengths, layer: paged_decode_folded(
+        q, k, v, table, lengths, layer=layer, scale=0.125, interpret=False),
+        sds((S, H, hd)), pool, pool, sds((S, Pmax), jnp.int32), sds((S,), jnp.int32), sds((), jnp.int32))
+    assert "paged_decode_kv10" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20   # no copy of the pool
+    assert not supports(bf16, KV, hd, interpret=False), "ten bfloat16 heads are no whole sublane tiles: paged_decode declines them"
+    assert all(supports(bf16, kv, hd, interpret=False) for kv in (2, 4, 8, 16, 32)) and supports(f32, KV, hd, interpret=False)
 
 
 # ---------------------------------------------------------- grouped SwiGLU

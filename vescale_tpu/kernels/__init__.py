@@ -3,10 +3,10 @@
 Every hand-written TPU kernel in the framework lives in this package and is
 reached through the same knob (``VESCALE_KERNELS``, registered in
 ``analysis.envreg``).  Unset, each kernel takes its own default:
-``paged_decode``, ``paged_decode_latent``, ``ssm_step``, ``grouped_experts`` and ``head_select`` are the compiled
+``paged_decode``, ``paged_decode_latent``, ``ssm_step``, ``selective_scan``, ``grouped_experts`` and ``head_select`` are the compiled
 kernels on TPU and the XLA leg on every other backend (what the platform is,
-the code can see; PERF.md, PR 27, PR 29, PR 46 and PR 47); the other three stay
-``off``.  Set, it means the same for all eight:
+the code can see; PERF.md, PR 27, PR 29, PR 46, PR 47 and PR 61); the other three stay
+``off``.  Set, it means the same for all nine:
 
   ``off``        the kernels are never consulted — every caller takes
                  exactly the XLA path it took before this package
@@ -47,7 +47,14 @@ Kernels in this package:
   * ``ssm_step``         — a state-space (Mamba-2) layer's decode step
     over every slot's recurrent state, in place: one read and one write of
     the state where XLA reads it twice (its XLA leg); for
-    ``models/mamba2.py`` under ``serve/hybrid_engine.py``, the default on TPU.
+    ``models/mamba2.py`` under ``serve/hybrid_engine.py``, the default on TPU;
+    ``ssm_step_selective`` beside it is the Mamba-1 form (a decay that is one
+    value a state row and lane, formed in VMEM from ``dt`` and ``A``), for
+    ``models/phi4flash.py``, under the same name in the dispatch.
+  * ``selective_scan``   — a Mamba-1 layer's recurrence over a prompt
+    (``kernels/selective_scan.py``): the state stays in VMEM while the kernel
+    walks the positions, where XLA's loop carries it through HBM (its XLA
+    leg); for ``models/phi4flash.py``'s prefill, the default on TPU.
   * ``grouped_experts``  — the sorted form of a dropless expert layer
     (``kernels/grouped_swiglu.py``): one grid over row tiles of the (token,
     expert) pairs in expert order; a tile's expert, a scalar-prefetch operand,
@@ -109,7 +116,8 @@ __all__ = [
 MODES = ("off", "interpret", "on")
 # what an unset VESCALE_KERNELS means for these: compiled on TPU, the XLA leg
 # elsewhere (every other kernel: off)
-DEFAULT_ON_TPU = frozenset({"paged_decode", "paged_decode_latent", "ssm_step", "grouped_experts", "head_select"})
+DEFAULT_ON_TPU = frozenset({"paged_decode", "paged_decode_latent", "ssm_step", "selective_scan", "grouped_experts",
+                            "head_select"})
 
 
 def mode() -> str:

@@ -80,11 +80,14 @@ def supports(pool_dtype, kv_heads: int, head_dim: int, *, interpret: bool) -> bo
     """Whether the kernel takes a pool of this dtype with ``kv_heads`` heads
     (per shard) of ``head_dim``: 32-bit pools, or bfloat16 with an even
     number of heads (a 32-bit row holds two); compiled, the strided loads
-    want whole 128-lane rows.  The engine takes its XLA leg otherwise."""
+    want whole 128-lane rows, and a bfloat16 page's copy whole sublane tiles
+    of its heads: 2, 4 or a multiple of 8 of them (6, 10, 12 or 20 Mosaic
+    refuses: "slice shape must be aligned to tiling (8)"; such rows go FOLDED,
+    :func:`paged_decode_folded`).  The engine takes its XLA leg otherwise."""
     dt = jnp.dtype(pool_dtype)
     if not (dt.itemsize == 4 or (dt == jnp.bfloat16 and kv_heads % 2 == 0)):
         return False
-    return interpret or head_dim % 128 == 0
+    return interpret or (head_dim % 128 == 0 and (dt.itemsize == 4 or kv_heads in (2, 4) or kv_heads % 8 == 0))
 
 
 def leg(pool_dtype, kv_heads: int, head_dim: int) -> Optional[bool]:

@@ -34,8 +34,16 @@ place and reduces it to ``y`` while it is in VMEM: one read, one write.
     block)`` float32 of at most ``_BLOCK_BYTES`` (1 MiB: 2048 lanes at N =
     128, 1024 at N = 256; in and out double-buffered, 4 MiB of VMEM).
 
+**The selective form** (:func:`ssm_step_selective`, Mamba-1): there the decay
+is ``exp(dt[j] * A[n, j])``, one value a (state row, lane) and not one a
+lane.  Materialised in XLA it is an array as large as the state, a third pass
+over the largest thing the step moves a slot; so that form takes ``dt`` (a
+row) and ``A`` (an ``(N, J)`` block, the same for every slot: the lane blocks
+are the grid's outer axis, so a block of it is fetched once for all slots)
+and forms the decay in VMEM.  Same layout, same aliasing, same layer operand.
+
 Numerics: float32 throughout, the same operations in the same order as the
-XLA leg (:func:`ssm_advance_xla`), except the order of the sum over ``N``;
+XLA leg (:func:`ssm_advance_xla`, :func:`ssm_selective_xla`), except the order of the sum over ``N``;
 interpreted parity is asserted in tests/test_granite_hybrid.py (1e-6 of the
 tensor's scale).  :func:`ssm_step` takes the kernel's ``interpret`` flag or
 None for the XLA leg, and :func:`leg` resolves that for a state's shape.
@@ -52,7 +60,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .. import kernels
 
-__all__ = ["ssm_step", "ssm_advance_xla", "supports", "leg"]
+__all__ = ["ssm_step", "ssm_advance_xla", "ssm_step_selective", "ssm_selective_xla", "supports", "leg"]
 
 _BLOCK_BYTES = 1 << 20   # of the state in one block, float32: in and out double-buffered
 
@@ -77,7 +85,8 @@ def supports(state_dtype, state_dim: int, lanes: int, *, interpret: bool, groups
 
 
 def leg(state_dtype, state_dim: int, lanes: int, *, groups: int = 1) -> Optional[bool]:
-    """The leg :func:`ssm_step` takes over such a state: the kernel's ``interpret`` flag, or None for the XLA leg."""
+    """The leg :func:`ssm_step` (and :func:`ssm_step_selective`: one group) takes over such a state: the kernel's
+    ``interpret`` flag, or None for the XLA leg."""
     return kernels.resolve(
         "ssm_step", supported=lambda interpret: supports(state_dtype, state_dim, lanes, interpret=interpret, groups=groups))
 
@@ -142,4 +151,55 @@ def ssm_step(state, decay, dtx, B, C, *, layer, interpret):
         interpret=interpret,
         name="ssm_step",
     )(jnp.asarray(layer, jnp.int32).reshape(1), row(decay), row(dtx), col(B), col(C), state)
+    return new_state, y.reshape(S, J)
+
+
+# ---------------------------------------------------------- the selective form
+def ssm_selective_xla(ssm, dt, A, dtx, B, C, *, layer):
+    """:func:`ssm_step_selective` without the kernel: the decay, as large as
+    the state, is written out, and the state read twice."""
+    h = jnp.exp(dt[:, None, :] * A[None]) * ssm[layer].astype(jnp.float32) + B[:, :, None] * dtx[:, None, :]
+    return ssm.at[layer].set(h.astype(ssm.dtype)), jnp.sum(h * C[:, :, None], axis=1)
+
+
+def _selective_kernel(layer_ref, dt_ref, a_ref, dtx_ref, b_ref, c_ref, h_ref, h_out_ref, y_ref):
+    del layer_ref                                        # it placed the blocks
+    new = jnp.exp(dt_ref[0] * a_ref[...]) * h_ref[0, 0] + b_ref[0] * dtx_ref[0]    # exp((1, T) * (N, T)) * (N, T) + (N, 1) * (1, T)
+    h_out_ref[0, 0] = new
+    y_ref[0] = jnp.sum(new * c_ref[0], axis=0, keepdims=True)
+
+
+@kernels.with_xla_leg(ssm_selective_xla, static_argnames=("interpret",), donate_argnames=("state",))
+def ssm_step_selective(state, dt, A, dtx, B, C, *, layer, interpret):
+    """One step of one Mamba-1 layer for every slot: ``h' = exp(dt (x) A) * h +
+    B (x) dtx`` and ``y = sum_n h' C`` on the ``layer``-th state (an int32
+    scalar or array of one) of ``state`` (layers, S, N, J), updated in place;
+    ``dt`` and ``dtx`` (S, J): each lane's step size and ``dt x``; ``A`` (N, J),
+    negative, every slot's; ``B`` and ``C`` (S, N).  A slot whose ``dt`` and
+    ``dtx`` are 0 keeps its state bit for bit (``exp(0) h + 0``).  ``interpret``
+    the kernel's flag, or None for the XLA leg (what :func:`leg` resolved).
+    Returns the state array and ``y`` (S, J) float32."""
+    _layers, S, N, J = state.shape
+    if A.shape != (N, J) or B.shape != (S, N) or C.shape != B.shape:
+        raise ValueError(f"ssm_step_selective: A {A.shape}, B {B.shape} and C {C.shape} against a state of {(N, J)} a slot")
+    T = _block(N, J)
+    f32 = jnp.float32
+    row = lambda a: a.astype(f32).reshape(S, 1, J)
+    col = lambda a: a.astype(f32).reshape(S, N, 1)
+    # (lane blocks outermost: a block of ``A`` stays in VMEM while the slots go by)
+    rows = pl.BlockSpec((1, 1, T), lambda j, s, layer: (s, 0, j))
+    cols = pl.BlockSpec((1, N, 1), lambda j, s, layer: (s, 0, 0))
+    block = pl.BlockSpec((1, 1, N, T), lambda j, s, layer: (layer[0], s, 0, j))
+    new_state, y = pl.pallas_call(
+        _selective_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(J // T, S),
+            in_specs=[rows, pl.BlockSpec((N, T), lambda j, s, layer: (0, j)), rows, cols, cols, block],
+            out_specs=[block, rows]),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype), jax.ShapeDtypeStruct((S, 1, J), f32)],
+        input_output_aliases={6: 0},          # the state (operand 6, the scalar first) is the first output
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="ssm_step_selective",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), row(dt), A.astype(f32), row(dtx), col(B), col(C), state)
     return new_state, y.reshape(S, J)

@@ -1,5 +1,5 @@
 """What more than one model family behind ``serve.HybridServeEngine`` is built
-of: the norm, the product, the SwiGLU, the plain rotary term, YaRN's
+of: the norms, the product, the SwiGLU, the plain rotary term, YaRN's
 frequencies, a new position's write into its pools, where a ring of the newest
 ``window`` positions keeps a position (two families mix window and full
 attention), and one rule of the random weights they are served with.  Plain functions of arrays and numbers: none
@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["F32", "ROUTED_DOWN_GAIN", "rmsnorm", "swiglu", "rotary", "yarn_mscale", "yarn_inv_freq", "write_position", "ring_row",
+__all__ = ["F32", "ROUTED_DOWN_GAIN", "rmsnorm", "layernorm", "swiglu", "rotary", "yarn_mscale", "yarn_inv_freq", "write_position", "ring_row",
            "ring_source", "window_pairs"]
 
 F32 = jnp.float32
@@ -36,6 +36,14 @@ ROUTED_DOWN_GAIN = 1.0 / 64.0
 def rmsnorm(x, w, eps):
     x = x.astype(F32)
     return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * w.astype(F32)
+
+
+def layernorm(x, weight, bias, eps):
+    """LayerNorm over the last axis (mean and variance of it, then ``weight`` and ``bias``), float32."""
+    x = x.astype(F32)
+    centred = x - jnp.mean(x, axis=-1, keepdims=True)
+    return (centred * jax.lax.rsqrt(jnp.mean(jnp.square(centred), axis=-1, keepdims=True) + eps) * weight.astype(F32)
+            + bias.astype(F32))
 
 
 def _mm(x, w, dtype):

@@ -5,7 +5,7 @@ ladder, ``warm()``, donation, the ``vs.serve-*`` spans and ``trace_counters()``;
 ``decode`` itself, one step deep (a call launches its step and returns the
 ``DecodeStep`` unread; a ``DecodeFeed`` feeds the next from the device), and
 the prompts that wait to RIDE a step, are ``engine.DecodeAhead``'s, shared with
-``ServeEngine``.  Seven models plug in today:
+``ServeEngine``.  Eight models plug in today:
 
   * ``models/granite_hybrid.py`` (the class's name is from it): state-space
     mixers with a per-slot recurrent state beside the paged K/V of their few
@@ -34,7 +34,12 @@ the prompts that wait to RIDE a step, are ``engine.DecodeAhead``'s, shared with
   * ``models/longcat_flash.py``: a layer of TWO latent-attention sublayers (``models/mla.py``'s block, which DeepSeek-V2
     has too, at 64 heads with two LoRA multipliers) and two dense SwiGLUs, so a model layer owns two layers of the latent
     pool; a routed branch that leaves after the first sublayer and returns at the layer's end, over a softmax router some of
-    whose outputs are zero-compute identity experts (``moe.dropless.route_softmax_biased`` / ``identity_experts``).
+    whose outputs are zero-compute identity experts (``moe.dropless.route_softmax_biased`` / ``identity_experts``);
+  * ``models/phi4flash.py``: a stack in TWO halves whose second half keeps no cache of its own: Mamba-1 mixers (``ssm_step_selective``,
+    ``kernels/selective_scan.py``) beside window layers' rings under differential attention, then ONE pool layer (``cache_config``'s
+    ``layers`` = 1) that the layer which writes it and seven cross-attention layers read, and gated memory units that read one layer's
+    scan output; its ``serve_prefill`` runs the second half on the last real row alone; both halves are ``lax.scan``s over stacked
+    periods, the cache's arrays in the carry; dense (no experts).
 
 A second engine class beside :class:`ServeEngine`, behind the same surface
 (``prefill(prompt, slot)``, ``decode(tokens)``, ``params``,
@@ -191,7 +196,7 @@ program, and a verify step of one token a position is not what its passes are.
 ``num_stages`` > 1 and a mesh of more than one device have no program here yet.
 A block engine offers no ride whatever its module gives (a prompt is more rows
 of a pass, which is another program), and neither does a model whose module
-gives no ``serve_ride``: six of the seven today.
+gives no ``serve_ride``: seven of the eight today.
 """
 
 from __future__ import annotations

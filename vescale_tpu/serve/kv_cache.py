@@ -117,7 +117,10 @@ class KVCacheConfig:
     ``slot_state`` adds per-slot arrays beside the pages: entries ``(name,
     layers, shape, dtype)`` give ``PagedKVCache.state[name]`` of shape
     ``(layers, num_slots) + shape``; ``layers`` here then counts only the
-    layers that keep K and V.
+    layers that keep K and V.  SEVERAL layers of a model may read one pool
+    layer (``models/phi4flash.py``: ``layers`` = 1, written by one layer and
+    read by eight, each with its own queries): the cache knows a pool layer by
+    who writes it, and takes no notice of who reads.
 
     ``latent`` says the pool's rows are latents that serve as keys and values
     both: ``kv_heads`` is 1, ``head_dim`` the row's width, and there is no
