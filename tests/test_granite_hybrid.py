@@ -198,15 +198,17 @@ def test_every_bucket_is_compiled_before_the_engine_is_handed_over(system):
     with pytest.raises(ValueError):
         prefill_buckets(256, 1500)
     cache.reset()
-    before = (engine._prefill_fn._cache_size(), engine._decode_fn._cache_size())
+    # (a rung's program is the step that carries the prompt, ``serve_ride``'s: no program is a prefill's own)
+    before = (engine._ride_fn._cache_size(), engine._decode_fn._cache_size())
     for n in (3, 8, 9, 16, 17, 32):
         s = cache.alloc(n, 0)
         engine.prefill(tokens(n, n), s)
         cache.commit_prefill(s, n)
-        engine.decode(np.zeros((SLOTS,), np.int32))
+        engine.decode(np.zeros((SLOTS,), np.int32))         # (the host's tokens: the prompt that waits goes first, alone)
         cache.free(s)
     # (a program may hold one more entry than buckets: the first call of all saw the arrays as allocated)
-    assert (engine._prefill_fn._cache_size(), engine._decode_fn._cache_size()) == before and before[0] >= 3
+    assert (engine._ride_fn._cache_size(), engine._decode_fn._cache_size()) == before and before[0] >= 3
+    assert engine.rides and engine._prefill_fn._cache_size() == 0 and not engine._waiting
 
 
 def test_the_counters_count_what_the_decode_steps_routed(system):

@@ -3,12 +3,17 @@ engine OFFERS a ride (``engine.rides``, set on the instance) where the model's
 module gives a ``serve_ride`` body and a step moves one position a slot, its
 ``prefill`` then launches nothing, and the step whose ``DecodeFeed`` names the
 ``PrefillStep`` as ``rider`` carries the prompt's rows through its own program,
-beside the decode rows under one product a weight.  Falcon-H1 gives the first
-body (``models/falcon_h1.py``, over ``models/mamba2.py:mamba2_ride``).  Held
-here at the toy size of ``tests/test_falcon_h1.py`` in float32, on both legs
-(``VESCALE_KERNELS`` unset: the XLA legs a CPU takes; ``interpret``: the
-``ssm_step``, ``paged_decode`` and flash kernels a TPU compiles, through the
-interpreter):
+beside the decode rows under one product a weight.  Falcon-H1 gave the first
+body (``models/falcon_h1.py``, over ``models/mamba2.py:mamba2_ride``), Granite-4.0-H
+the second (PR 62: ``models/granite_hybrid.py``, whose layer ends in an expert
+layer that runs once over both kinds of row).  Every case below is held for
+BOTH families, each at the toy size of its own test file
+(``tests/test_falcon_h1.py``, ``tests/test_granite_hybrid.py``) in float32, on
+both legs (``VESCALE_KERNELS`` unset: the XLA legs a CPU takes; ``interpret``:
+the ``ssm_step``, ``paged_decode`` and flash kernels a TPU compiles, through the
+interpreter, and with them, both of the expert layer's limits at 0 while
+Granite's programs are traced, the grouped SwiGLU kernel that a riding 512 rung
+takes on the chip):
 
 (a) a riding step IS a prefill and a decode step: against the parent's two
     programs (``serve_prefill``'s, which the engine still builds for the
@@ -24,25 +29,33 @@ interpreter):
 (c) through ``run_serve_resilient`` every stream is the same with the offer
     and with it hidden, cancellations while a prompt rides and while it waits
     included;
-(d) an engine of a module without ``serve_ride`` (the other six families), and
+(d) an engine of a module without ``serve_ride`` (the other five families), and
     a block engine whatever its module gives, offer nothing and report no
     ``prefill_rides``;
 (e) a riding step is ONE launch of the decode kind, tagged ``rung`` and
     ``slot``, and its module is ``jit_decode``; nothing compiles after ``warm()``;
 (f) ``prefill_rides``, ``prefill_launches``, ``prefill_scan_chunks`` and
-    ``prefill_tokens_padded`` count a prompt once, rode or alone."""
+    ``prefill_tokens_padded`` count a prompt once, rode or alone, and
+    ``moe_expert_layer_calls`` a launched program once;
+(g) the engine's ``moe_*`` counters are of DECODE positions with riders as
+    without: a riding step returns the counts of its decode rows alone, so
+    ``moe_assignments_held`` and ``moe_assignments`` of a run with riders are
+    those of the same requests with the offer hidden, to the integer."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import test_falcon_h1 as toy
+import test_falcon_h1
+import test_granite_hybrid
 import test_program_identity as identity
 from test_serve_ride import NoOffer
 from vescale_tpu.mesh import DeviceMesh
 from vescale_tpu.models import falcon_h1 as fh
+from vescale_tpu.models import granite_hybrid as gh
 from vescale_tpu.models import sdar_moe
+from vescale_tpu.moe import dropless
 from vescale_tpu.ndtimeline import api as nd
 from vescale_tpu.ndtimeline import predefined as P
 from vescale_tpu.serve import (ContinuousBatchingScheduler, DecodeFeed, HybridServeEngine, PagedKVCache, PrefillStep, Request,
@@ -51,8 +64,9 @@ from vescale_tpu.serve import hybrid_engine
 from vescale_tpu.serve.hybrid_engine import hybrid_cache_config
 
 SLOTS, PAGE, PAGES = 4, 4, 8        # 32 positions a slot: rungs 8 / 16 / 32 (the chunk is 8)
-TIGHT = toy.TIGHT                   # float32 against float32, the sums in another order: the family's own tolerance
-tokens, rel = toy.tokens, toy.rel
+# the families whose modules give the body: each one's toy (its test file: ``toy_config``, ``tokens``, ``rel``, and
+# ``TIGHT``, float32 against float32 with the sums in another order, the family's own tolerance) and its module
+FAMILIES = {"falcon_h1": (test_falcon_h1, fh), "granite_hybrid": (test_granite_hybrid, gh)}
 
 
 def _engine(cfg, params):
@@ -61,21 +75,30 @@ def _engine(cfg, params):
     return HybridServeEngine(cfg, mesh, params, cache).warm(), cache
 
 
-@pytest.fixture(scope="module", params=[None, "interpret"], ids=["xla_legs", "kernels_interpreted"])
+@pytest.fixture(scope="module", params=[(family, leg) for family in FAMILIES for leg in (None, "interpret")],
+                ids=lambda param: f"{param[0]}-{'kernels_interpreted' if param[1] else 'xla_legs'}")
 def pair(request):
-    """Two engines over the same weights, built (and so latched) under one leg:
-    the one that rides, and one that is only ever driven through the parent's
-    two programs (:func:`prefill_apart`, then ``decode``)."""
+    """Two engines of one family over the same weights, built (and so latched)
+    under one leg: the one that rides, and one that is only ever driven through
+    the parent's two programs (:func:`prefill_apart`, then ``decode``).  Each
+    engine bears its family's toy (``engine.toy``)."""
+    family, leg = request.param
+    toy, model = FAMILIES[family]
     with pytest.MonkeyPatch.context() as patch:
-        if request.param is None:
+        if leg is None:
             patch.delenv("VESCALE_KERNELS", raising=False)
         else:
-            patch.setenv("VESCALE_KERNELS", request.param)
+            # ... and, as ``tests/test_granite_hybrid.py``'s own fixture does, both of the expert layer's limits at 0
+            # while the programs are traced: every one then holds the sorted form on the leg a TPU takes, the grouped kernel
+            patch.setenv("VESCALE_KERNELS", leg)
+            patch.setattr(dropless, "DENSE_MAX_TOKENS", 0)
+            patch.setattr(dropless, "PADDED_MAX_MEAN_ROWS", 0)
         cfg = toy.toy_config()
-        params = jax.jit(lambda k: fh.init_params(cfg, k))(jax.random.key(7))
+        params = jax.jit(lambda k: model.init_params(cfg, k))(jax.random.key(7))
         rides, apart = _engine(cfg, params), _engine(cfg, params)
-    assert rides[0].rides and rides[0].kernel_ssm_step == rides[0].kernel_decode == (request.param == "interpret")
+    assert rides[0].rides and rides[0].kernel_ssm_step == rides[0].kernel_decode == (leg == "interpret")
     rides[0].warmed = _programs(rides[0])
+    rides[0].toy = apart[0].toy = toy
     return rides, apart
 
 
@@ -118,10 +141,17 @@ def _delta(eng, start):
     return {k: v - start[k] for k, v in eng.trace_counters().items()}
 
 
+def _routed(d):
+    """What a run's decode positions gave the experts (a dense family: nothing)."""
+    return d["moe_assignments"], d["moe_assignments_held"]
+
+
 # ------------------------------------------------------------ (a) the program
 @pytest.mark.parametrize("n", [8, 13, 16, 27], ids=["rung8_full", "rung16_short", "rung16_full", "rung32_short"])
 def test_a_riding_step_is_a_prefill_and_a_decode_step(pair, n):
     rung = next(b for b in (8, 16, 32) if b >= n)
+    toy = pair[0][0].toy
+    tokens, rel = toy.tokens, toy.rel
     got = {}
     for ride, (eng, cache) in zip((True, False), pair):
         cache.reset()
@@ -160,13 +190,19 @@ def test_a_riding_step_is_a_prefill_and_a_decode_step(pair, n):
             after_ids = np.concatenate([s2.tokens[[a, b]], s1.tokens[[c]]])
             assert "prefill_rides" in eng.trace_counters() and _delta(eng, start)["prefill_rides"] == 0
         got[ride] = dict(step=np.asarray(s1)[[a, b]], ids=s1.tokens[[a, b]], row=np.asarray(w), first=w.token, after=after,
-                         after_ids=after_ids, **{f"{name}[{slot}]": value for slot in (a, b, c)
+                         after_ids=after_ids, routed=_routed(_delta(eng, start)), **{f"{name}[{slot}]": value for slot in (a, b, c)
                                                  for name, value in (held_c if slot == c else _held(cache, slot)).items()})
         assert not eng._waiting
     rode, apart = got[True], got[False]
     for key in rode:
-        if key not in ("ids", "first", "after_ids"):
-            assert rel(rode[key], apart[key]) < TIGHT, key
+        if key not in ("ids", "first", "after_ids", "routed"):
+            assert rel(rode[key], apart[key]) < toy.TIGHT, key
+    # the expert counts are of decode positions: seven where the prompt rode (2 + 2 + 3: the riding step's counts are of
+    # its two decode rows alone, the rung's rows are none), eight apart (2 + 3 + 3: the step after steps slot c once more)
+    eng = pair[0][0]
+    pairs_a_position = getattr(eng.config, "num_experts_per_tok", 0) * eng._expert_layers
+    assert (rode["routed"][0], apart["routed"][0]) == (7 * pairs_a_position, 8 * pairs_a_position)
+    assert all(0 <= held <= assignments and (held > 0) == bool(eng._expert_layers) for assignments, held in (rode["routed"], apart["routed"]))
     assert rode["first"] == apart["first"] == int(np.argmax(rode["row"]))
     assert np.array_equal(rode["ids"], apart["ids"]) and np.array_equal(rode["after_ids"], apart["after_ids"])
 
@@ -174,6 +210,7 @@ def test_a_riding_step_is_a_prefill_and_a_decode_step(pair, n):
 # ------------------------------------------------------ (b) an idle row's state
 def test_an_idle_rows_state_and_tail_are_the_same_bits_after_a_riding_step_and_a_prompt_launched_alone(pair):
     (eng, cache), (ref, ref_cache) = pair
+    tokens, rel, TIGHT = eng.toy.tokens, eng.toy.rel, eng.toy.TIGHT
     olds, news = ((11, 13), (12, 6), (13, 9), (16, 11)), ((14, 5), (15, 20))       # (seed, length) of each prompt
     cache.reset()
     # slots a and b in the middle of their outputs; y and z held tenants, which left their state behind
@@ -244,6 +281,7 @@ def test_every_stream_is_the_same_with_the_offer_and_with_it_hidden(pair, tmp_pa
     """One long request keeps a step in flight; behind it arrive two requests in ONE iteration, a request of one
     token, and one whose first token is its EOS.  Riding, behind ``NoOffer`` and ``replay_greedy`` agree."""
     (eng, cache), _ = pair
+    tokens = eng.toy.tokens
     eos_prompt = tuple(tokens(24, 14))
     eos = _golden(eng, cache, Request(rid=0, prompt=eos_prompt, max_new_tokens=1))[0]
     reqs = [(0, Request(rid=0, prompt=tuple(tokens(20, 7)), max_new_tokens=16)),
@@ -280,6 +318,7 @@ def test_a_request_cancelled_under_its_prompt_leaves_every_other_stream_as_it_wa
     waits: its prompt is still the engine's to launch, alone, BEFORE the prompt of the request that takes its
     slot), with the offer and with it hidden every other stream is ``replay_greedy``'s."""
     (eng, cache), _ = pair
+    tokens = eng.toy.tokens
     reqs = [(0, Request(rid=0, prompt=tuple(tokens(32, 7)), max_new_tokens=14)),
             (2, Request(rid=1, prompt=tuple(tokens(33, 12)), max_new_tokens=5)), (2, Request(rid=2, prompt=tuple(tokens(34, 20)), max_new_tokens=5)),
             (3, Request(rid=3, prompt=tuple(tokens(35, 10)), max_new_tokens=5))]
@@ -309,7 +348,7 @@ def test_a_request_cancelled_under_its_prompt_leaves_every_other_stream_as_it_wa
 
 
 # ----------------------------------------------------------------- (d) the offer
-@pytest.mark.parametrize("family", [f for f in identity.FAMILIES if f != "falcon_h1"])
+@pytest.mark.parametrize("family", [f for f in identity.FAMILIES if f not in FAMILIES])
 def test_an_engine_of_a_module_without_the_body_offers_nothing(family):
     with pytest.MonkeyPatch.context() as patch:
         engine, _params = identity._engine(family, "xla_legs", patch)
@@ -318,14 +357,15 @@ def test_an_engine_of_a_module_without_the_body_offers_nothing(family):
     assert not engine._waiting
 
 
-def test_a_block_engine_offers_nothing_whatever_its_module_gives_and_falcon_h1_offers():
+def test_a_block_engine_offers_nothing_whatever_its_module_gives_and_the_two_that_give_it_offer():
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(sdar_moe, "serve_ride", fh.serve_ride, raising=False)
         engine, _params = identity._engine("sdar_moe", "xla_legs", patch)
         assert engine.block is not None and hasattr(engine.model, "serve_ride") and engine.rides is False
         assert "prefill_rides" not in engine.trace_counters()
-        engine, _params = identity._engine("falcon_h1", "xla_legs", patch)
-    assert engine.rides is True and "prefill_rides" in engine.trace_counters() and engine._ride_fn is not None
+        for family in FAMILIES:
+            engine, _params = identity._engine(family, "xla_legs", patch)
+            assert engine.rides is True and "prefill_rides" in engine.trace_counters() and engine._ride_fn is not None
     # the offer is the instance's, by its model: the class has none, and what every model's engine counts does not name it
     assert not hasattr(HybridServeEngine, "rides") and "prefill_rides" not in hybrid_engine.COUNTERS
 
@@ -333,6 +373,7 @@ def test_a_block_engine_offers_nothing_whatever_its_module_gives_and_falcon_h1_o
 # ------------------------------------------------------------------ (e) spans
 def test_a_riding_step_is_one_launch_of_the_decode_kind_and_its_module_is_jit_decode(pair, tmp_path):
     (eng, cache), _ = pair
+    tokens = eng.toy.tokens
     cache.reset()
     a = cache.alloc(6, 8)
     first = eng.prefill(tokens(70, 6), a).token
@@ -377,14 +418,19 @@ def test_a_riding_step_is_one_launch_of_the_decode_kind_and_its_module_is_jit_de
 # --------------------------------------------------------------- (f) counters
 def test_a_prompt_is_counted_once_whether_it_rode_or_went_alone(pair):
     (eng, cache), _ = pair
+    tokens = eng.toy.tokens
     cache.reset()
-    chunk = toy.TOY["mamba_chunk_size"]
+    chunk = eng.toy.TOY["mamba_chunk_size"]
     start = eng.trace_counters()
     a = cache.alloc(6, 8)
     first = eng.prefill(tokens(80, 6), a)                   # rung 8
     at_the_call = _delta(eng, start)
     assert (at_the_call["prefill_tokens_real"], at_the_call["prefill_tokens_padded"], at_the_call["prefill_bucket_tokens"],
-            at_the_call["prefill_scan_chunks"], at_the_call["prefill_launches"]) == (6, 8, 8, 8 // chunk, 0)
+            at_the_call["prefill_launches"]) == (6, 8, 8, 0)
+    # (a family's own counter of the scan's chunks, where it keeps one, and the program the prompt will go in, its
+    # expert layers counted now: the rung's rows beside every slot's)
+    assert at_the_call.get("prefill_scan_chunks", 8 // chunk) == 8 // chunk
+    assert at_the_call["moe_expert_layer_calls"] == eng._expert_layers and eng._prompt_rows[8] == 8 + SLOTS
     cache.commit_prefill(a, 6)
     s0 = eng.decode(_host(cache, {a: first.token}))         # read: it went alone
     cache.advance(a)
@@ -398,7 +444,43 @@ def test_a_prompt_is_counted_once_whether_it_rode_or_went_alone(pair):
     cache.reset()
     assert (d["prefill_launches"], d["prefill_rides"], d["prefill_reads_ahead"]) == (2, 1, 1)
     assert (d["prefill_tokens_real"], d["prefill_tokens_padded"], d["prefill_bucket_tokens"]) == (19, 24, 24)
-    assert d["prefill_scan_chunks"] * chunk == d["prefill_bucket_tokens"], "the benchmark's own identity, at any edge of a session"
+    if "prefill_scan_chunks" in d:
+        assert d["prefill_scan_chunks"] * chunk == d["prefill_bucket_tokens"], "the benchmark's own identity, at any edge of a session"
+    # four programs were launched (a prompt alone, a step, the step that carried the other prompt, a step): each one's
+    # expert layers once, and under the interpreted kernels (the sorted form whatever the rows) all the grouped kernel
+    assert d["moe_expert_layer_calls"] == 4 * eng._expert_layers
+    assert d["moe_grouped_layer_calls"] == (4 * eng._expert_layers if eng.kernel_decode else 0)
     assert (d["decode_launches"], d["decode_steps"]) == (3, 3) and eng.launches - (start["decode_launches"] + start["prefill_launches"]
                                                                                   - start["prefill_rides"]) == 4
     assert d["ssm_state_bytes_rw"] == 3 * 2 * SLOTS * cache.state_bytes_per_slot(), "a step's state traffic, carrying or not"
+
+
+# ------------------------------------------------- (g) the expert counters' meaning
+def test_the_expert_counters_are_of_decode_positions_with_riders_as_with_the_offer_hidden(pair):
+    """One long request keeps a step in flight and three more arrive behind it, two of them in one iteration, so
+    prompts ride steps of one, two and three decode rows.  A riding step's expert layer runs over its decode rows and
+    the prompt's together, but what it returns under ``counts["experts"]`` is the decode rows' alone: the engine's
+    ``moe_assignments`` (decode positions x experts a token x layers) and ``moe_assignments_held`` (those that fell on
+    an expert held here: Granite's toy holds 4 of 8) are, to the integer, what the same requests give with the offer
+    hidden, when every prompt goes alone and no step ever holds a prompt's rows."""
+    (eng, cache), _ = pair
+    tokens = eng.toy.tokens
+    reqs = [(0, Request(rid=0, prompt=tuple(tokens(90, 9)), max_new_tokens=14)),
+            (2, Request(rid=1, prompt=tuple(tokens(91, 12)), max_new_tokens=6)), (2, Request(rid=2, prompt=tuple(tokens(92, 21)), max_new_tokens=5)),
+            (5, Request(rid=3, prompt=tuple(tokens(93, 5)), max_new_tokens=4))]
+    seen = {}
+    for face in (eng, NoOffer(eng)):
+        start = eng.trace_counters()
+        _, res = _serve(face, cache, reqs)
+        d = _delta(eng, start)
+        seen[face is eng] = (_routed(d), d["moe_layer_steps"], {rid: o["tokens"] for rid, o in res.outcomes.items()})
+        assert d.get("ride_candidate_layer_steps", 0) == 0, "no toy rung is a candidate for the pad under these limits"
+        assert d["prefill_rides"] == (3 if face is eng else 0) and d["prefill_launches"] == 4
+        assert d["moe_expert_layer_calls"] == (d["decode_launches"] + d["prefill_launches"] - d["prefill_rides"]) * eng._expert_layers
+    assert seen[True] == seen[False]
+    (assignments, held), _steps, _streams = seen[True]
+    if eng._expert_layers:      # (Falcon-H1 is dense: nothing is routed, and the counters stay 0 on both sides)
+        c = eng.config
+        assert 0 < held < assignments and assignments % (c.num_experts_per_tok * c.num_hidden_layers) == 0
+    else:
+        assert (assignments, held) == (0, 0)

@@ -15,7 +15,8 @@ tests/test_program_identity.py``).  A PR that changes these programs on purpose
 takes them anew.  (``mimo_v2``'s six were taken on the tree of the PR that brought
 the family, PR 50: they pin it from there on.  The twenty-four prefills were taken
 anew by PR 52, whose prefill also returns its row's argmax: the scopes did not move.  ``falcon_h1``'s four were replaced
-by PR 55 with its four RIDING rungs, the step that carries a prompt: its engine launches no prefill.  ``longcat_flash``'s six were
+by PR 55 with its four RIDING rungs, the step that carries a prompt: its engine launches no prefill; ``granite_hybrid``'s four
+likewise by PR 62 (its two decode steps held: the expert layer hands its callers the kept ids now, and the step drops them).  ``longcat_flash``'s six were
 taken on the tree of the PR that brought the family and moved the latent-attention block from ``models/deepseek_v2.py``
 to ``models/mla.py``, PR 54: ``deepseek_v2``'s six held through the move, digest and scopes, as they stand here.)"""
 
@@ -48,11 +49,11 @@ WHICH = ("prefill_rung_1", "prefill_rung_2", "decode")
 RIDING = {"prefill_rung_1": "riding_rung_1", "prefill_rung_2": "riding_rung_2"}
 
 PROGRAMS = {
-    "granite_hybrid/xla_legs/prefill_rung_1": ('98f6b6654290c82b', 'vs.attn=47 vs.mamba=483 vs.moe=160'),
-    "granite_hybrid/xla_legs/prefill_rung_2": ('ec634149a6d21298', 'vs.attn=47 vs.mamba=483 vs.moe=160'),
+    "granite_hybrid/xla_legs/riding_rung_1": ('2eed81bdc04d6856', 'vs.attn=172 vs.mamba=240 vs.moe=80'),
+    "granite_hybrid/xla_legs/riding_rung_2": ('4ce44932afd9c4ab', 'vs.attn=172 vs.mamba=240 vs.moe=80'),
     "granite_hybrid/xla_legs/decode": ('648f82e75f10971f', 'vs.attn=106 vs.mamba=309 vs.moe=160'),
-    "granite_hybrid/kernels_interpreted/prefill_rung_1": ('d5a9b27abcbd6ecf', 'vs.attn=554 vs.mamba=483 vs.moe=160'),
-    "granite_hybrid/kernels_interpreted/prefill_rung_2": ('dfa5e9c20591704c', 'vs.attn=554 vs.mamba=483 vs.moe=160'),
+    "granite_hybrid/kernels_interpreted/riding_rung_1": ('5a75ab3126606170', 'vs.attn=636 vs.mamba=216 vs.moe=80'),
+    "granite_hybrid/kernels_interpreted/riding_rung_2": ('e15405ca3830dc48', 'vs.attn=636 vs.mamba=216 vs.moe=80'),
     "granite_hybrid/kernels_interpreted/decode": ('f67535cadf866b5f', 'vs.attn=69 vs.mamba=255 vs.moe=160'),
     "deepseek_v2/xla_legs/prefill_rung_1": ('00bd63cd35773518', 'vs.attn=408 vs.mlp=9 vs.moe=108'),
     "deepseek_v2/xla_legs/prefill_rung_2": ('592f6fda2c03d3fa', 'vs.attn=408 vs.mlp=9 vs.moe=108'),

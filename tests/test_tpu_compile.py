@@ -618,6 +618,69 @@ def test_falcon_h1s_step_that_carries_a_prompt_compiles_at_the_cells_size_with_o
     assert set(re.findall(r"= \w+\[(\d+),\d+\]\S* convolution\(", step.compile().as_text())) == {"128"}
 
 
+def test_granites_step_that_carries_a_prompt_compiles_at_the_cells_size_with_one_product_a_weight(chip):
+    """``granite4hsmall_serve_batch``'s decode step with the 256 rung's prompt in it (PR 62: 64 decode rows and 256
+    prompt rows, one array before every weight's product): nine ``ssm_step``, one ``paged_decode`` and one flash
+    forward, no copy of a pool or of the state, every product of the stack over all 320 rows and none over 64 or 256
+    beside it: ``W_in`` nine times, ``W_out`` / ``W_o`` / ``W_q`` / the shared expert's down 21, its gate and up 20,
+    the router ten, and the 36 held experts' three matrices ONCE a layer, in the padded form that 320 rows x 10 over
+    36 experts make a candidate for; the head's over the 64 steps' rows and the prompt's last.  A weight crosses the
+    HBM once for both, the expert layer's 72% of them too.  (The engine is the one the cell's family builds for
+    ``benchmark/rehearse.py``, from shapes alone.)"""
+    import collections
+    import importlib
+    import re
+    from unittest import mock
+
+    from vescale_tpu import kernels
+    from vescale_tpu.moe import dropless
+    from vescale_tpu.serve import HybridServeEngine
+
+    built, init = [], HybridServeEngine.__init__
+
+    def noted(self, *args, **kwargs):
+        init(self, *args, **kwargs)
+        built.append(self)
+
+    flash_ops = importlib.import_module("vescale_tpu.ops.flash_attention")
+    with mock.patch.object(HybridServeEngine, "__init__", noted):
+        _family, _config, sizes, programs = _cells_programs(chip, "granite4hsmall_serve_batch")
+        (engine,) = built
+        cache, c = engine.cache, engine.config
+        S, page, rung = cache.num_slots, cache.config.page_size, 256
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+        # (traced here too, under the same answers: the flash forward asks for its platform while it is traced)
+        with mock.patch.object(kernels, "on_tpu", lambda: True), mock.patch.object(flash_ops, "jax", _JaxOnATpu()):
+            lowered = engine._ride_fn.lower(engine.params, *engine._held(), i32(S, cache.config.pages_per_slot), i32(S), i32(S),
+                                            i32(S), i32(rung), i32(), i32(rung // page), i32())
+    assert engine.rides and engine.kernel_decode and engine.kernel_ssm_step and (S, rung) == (64, 256)
+    # which form the expert layer holds at each rung's rows, the step's beside them (and the step's alone): by the shapes
+    forms = [dropless.expert_form(rows, c.num_experts_per_tok, c.experts_held) for rows in (S, *engine._prompt_rows.values())]
+    assert forms == [dropless.ALL_ON_ALL, dropless.PADDED_OR_SORTED] + [dropless.SORTED] * 3
+    assert engine._grouped_layers == {rows: 10 for rows in (S + 512, S + 1024, S + 1536)}
+    assert lowered.as_text().lstrip().startswith("module @jit_decode ")
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    kernel_calls = re.findall(r"%([a-z_.]+?)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    assert sorted(kernel_calls) == ["paged_decode"] + ["ssm_step"] * 9 + ["vs.attn"]      # (the flash forward bears its scope's name)
+    _assert_in_place_and_fits(compiled, sizes, "bf16[1,6145,16,8,128]")        # 0.2 GB a pool
+    assert not [line for line in text.splitlines() if " copy(" in line and "= f32[9,64,128,8192]" in line], "nor of the state"
+    products = collections.Counter(re.findall(r"= \w+\[([\d,]+)\]\S* convolution\(", text))
+    weights = {shape: n for shape, n in products.items() if shape.split(",")[0] in ("320", "36", "65")}
+    # in_proj; out_proj x9, o, q and the shared down x10; k and v; the shared gate and up; the router; the held experts'
+    # down, and their gate and up, over 36 x 128 padded places; the head 64 + 1
+    assert weights == {"320,16768": 9, "320,4096": 21, "320,1024": 2, "320,1536": 20, "320,72": 10, "36,128,4096": 10,
+                       "36,128,768": 20, "65,50176": 1}
+    # ... and nothing over the step's 64 rows or the rung's 256 alone but the chunked scan's own products (C B^T of a
+    # chunk and the chunk states: the prompt's rows with each other, no weight in them)
+    assert {shape for shape in products if shape not in weights} == {"256,256", "128,64,256", "128,128,64"}
+    assert ".remat" not in text, "no product is run anew for a second reader (PERF.md section 6, PR 55)"
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
+    # ... and the step without a prompt is the program it was: its products over the 64 rows
+    (step,) = [low for title, low in programs if "decode step" in title]
+    assert {shape.split(",")[0] for shape in re.findall(r"= \w+\[([\d,]+)\]\S* convolution\(", step.compile().as_text())} <= {"64", "36"}
+
+
 # what a program outside its kernels' bodies lowers to, for a described v5e: a digest of the lowered text with every
 # kernel's serialized body taken out (it holds the checkout's path and the kernel's line numbers; the bodies' own identity
 # is the jaxpr digests of tests/test_program_identity.py).  Taken on the parent of the PR that gave ``paged_decode`` a

@@ -4,12 +4,14 @@ is a routed expert layer beside one shared expert, in every layer.
 
 The block is written ONCE, as pure functions over a plain parameter tree
 (``init_params``), and both serve programs call them: prefill
-(``mamba2.mamba2_prefill``, ``attention_prefill``) and decode
-(``mamba2.mamba2_step``, ``attention_step``) share the projections, the
-convolution, the gate, the norms, the expert layer (``moe.dropless``) and the
-head (the norm, the product and the SwiGLU are ``models/blocks.py``'s, each
-decode kernel and its XLA leg ``kernels/``'s).  No flax module, no third copy
-for training yet (ROADMAP D3).
+(``mamba2.mamba2_prefill``, ``attention_prefill``), decode
+(``mamba2.mamba2_step``, ``attention_step``) and the decode step that CARRIES a
+prompt (``mamba2.mamba2_ride``, ``attention_ride``: :func:`serve_ride`, what the
+engine launches for every prompt) share the projections, the convolution, the
+gate, the norms, the expert layer (``moe.dropless``) and the head (the norm, the
+product and the SwiGLU are ``models/blocks.py``'s, each decode kernel and its
+XLA leg ``kernels/``'s).  No flax module, no third copy for training yet
+(ROADMAP D3).
 
 Equations (HF ``modeling_granitemoehybrid.py``; ISSUE 29 writes them out):
 
@@ -45,14 +47,14 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..moe.dropless import route_topk, routed_experts
+from ..moe.dropless import fits_pad, padded_candidate, route_topk, routed_experts
 from .blocks import F32, _mm, rmsnorm, swiglu, write_position
-from .mamba2 import mamba2_prefill, mamba2_step
+from .mamba2 import mamba2_prefill, mamba2_ride, mamba2_step
 
 __all__ = [
-    "GraniteHybridConfig", "init_params", "embed", "head", "attention_prefill", "attention_step", "expert_layer",
-    "layer_prefill", "layer_step", "cache_config", "prefill_chunk", "decode_kernels", "serve_prefill", "serve_decode",
-    "STEP_COUNTERS", "step_counters", "prefill_counters",
+    "GraniteHybridConfig", "init_params", "embed", "head", "attention_prefill", "attention_step", "attention_ride",
+    "expert_layer", "layer_prefill", "layer_step", "cache_config", "prefill_chunk", "decode_kernels", "serve_prefill",
+    "serve_decode", "serve_ride", "STEP_COUNTERS", "step_counters", "prefill_counters",
 ]
 
 
@@ -242,17 +244,58 @@ def attention_step(c: GraniteHybridConfig, ap, u, k_pool, v_pool, *, layer: int,
     return _mm(y.reshape(u.shape[0], -1), ap["o_proj"], c.dtype), k_pool, v_pool
 
 
+def attention_ride(c: GraniteHybridConfig, ap, u, k_pool, v_pool, *, layer, table, page, offset, valid_len, page_row,
+                   page_size: int, interpret: Optional[bool], flash_interpret: Optional[bool]):
+    """A decode step's rows AND a prompt's through one attention mixer: ``u``
+    (S + T, E), the ``S`` slots' rows first, then a prompt padded to ``T``
+    positions.  ``W_q``, ``W_k``, ``W_v`` and ``W_o`` over all the rows at once;
+    between them the slots' rows as :func:`attention_step` runs them (their K
+    and V to ``(page, offset)``, ``paged_decode`` over their pages) and the
+    prompt's as :func:`attention_prefill` does (causal over themselves: a whole
+    prompt rides one step, so none of its rows reads a page), its K and V into
+    the pages ``page_row`` (T / page_size,) of this ``layer`` (an int or a
+    traced int32).  Returns the output (S + T, E) and both pools."""
+    from ..kernels.paged_attention import paged_decode
+    from ..ops.flash_attention import flash_attention
+    from ..serve.kv_cache import write_pages
+
+    S, scale = table.shape[0], c.attention_multiplier
+    q, k, v = _qkv(c, ap, u)
+    k_pool, v_pool = write_position(k_pool, v_pool, k[:S], v[:S], (layer, page, offset))
+    k_pool = write_pages(k_pool, k[None, S:], page_row, page_size, layer)
+    v_pool = write_pages(v_pool, v[None, S:], page_row, page_size, layer)
+    y_step = paged_decode(q[:S], k_pool, v_pool, table, valid_len, layer=layer, scale=scale, interpret=interpret)
+    y_prompt = flash_attention(q[None, S:], k[None, S:], v[None, S:], causal=True, scale=scale, interpret=flash_interpret)[0]
+    y = jnp.concatenate([y_step.reshape(S, -1), y_prompt.reshape(u.shape[0] - S, -1)])
+    return _mm(y, ap["o_proj"], c.dtype), k_pool, v_pool
+
+
 # -------------------------------------------------------------- expert layer
+def _expert_layer(c: GraniteHybridConfig, ep, h, token_mask):
+    """:func:`expert_layer`, and beside it each token's ten kept ids (N, k)."""
+    def route(scores):
+        idx, gates = route_topk(scores, c.num_experts_per_tok)
+        return idx, gates, idx
+
+    routed, counts, idx = routed_experts(h, ep["router"], route, ep["w_gate"], ep["w_up"], ep["w_down"],
+                                         first_held=c.first_expert_held, token_mask=token_mask, dtype=c.dtype)
+    return routed + swiglu(h, ep["shared_gate"], ep["shared_up"], ep["shared_down"], c.dtype), counts, idx
+
+
 def expert_layer(c: GraniteHybridConfig, ep, h, token_mask=None):
     """``moe(h) + shared(h)`` for tokens ``h`` (N, E): the router scores all
     ``num_experts``, the ten largest are kept and their gates are a softmax
     over those ten; the held experts' part is computed without capacity
     (``moe.dropless``), the shared expert on every token.  Returns the sum
     (N, E) float32 and how many tokens each held expert got (held,)."""
-    routed, counts = routed_experts(h, ep["router"], lambda scores: route_topk(scores, c.num_experts_per_tok),
-                                    ep["w_gate"], ep["w_up"], ep["w_down"], first_held=c.first_expert_held,
-                                    token_mask=token_mask, dtype=c.dtype)
-    return routed + swiglu(h, ep["shared_gate"], ep["shared_up"], ep["shared_down"], c.dtype), counts
+    return _expert_layer(c, ep, h, token_mask)[:2]
+
+
+def _held_counts(c: GraniteHybridConfig, idx, token_mask):
+    """How many of the tokens ``token_mask`` (N,) names each held expert got, from their kept ids ``idx`` (N, k): what
+    the expert layer counts (``moe.dropless``), of some of a call's rows alone."""
+    mine = (idx[..., None] - c.first_expert_held == jnp.arange(c.experts_held)) & token_mask[:, None, None]
+    return jnp.sum(mine, axis=(0, 1), dtype=jnp.int32)      # (a compare and a sum of N k held booleans: no scatter)
 
 
 # ------------------------------------------------------------ whole layers
@@ -261,12 +304,13 @@ def _mixer_input(c: GraniteHybridConfig, lp, x):
 
 
 def _after_mixer(c: GraniteHybridConfig, lp, x, y, token_mask):
-    """The mixer's output into the residual stream, then the expert layer's."""
+    """The mixer's output into the residual stream, then the expert layer's.  Returns the stream, the held
+    experts' counts and every token's kept ids."""
     x = x + c.residual_multiplier * y
     with jax.named_scope("vs.moe"):
         h = rmsnorm(x, lp["post_attention_layernorm"]["weight"], c.rms_norm_eps)
-        y, counts = expert_layer(c, lp["moe"], h, token_mask=token_mask)
-    return x + c.residual_multiplier * y, counts
+        y, counts, idx = _expert_layer(c, lp["moe"], h, token_mask)
+    return x + c.residual_multiplier * y, counts, idx
 
 
 def layer_prefill(c: GraniteHybridConfig, lp, kind: str, x, length, *, interpret: Optional[bool] = None):
@@ -281,7 +325,7 @@ def layer_prefill(c: GraniteHybridConfig, lp, kind: str, x, length, *, interpret
     else:
         with jax.named_scope("vs.attn"):
             y, *kept = attention_prefill(c, lp["mixer"], u, interpret=interpret)
-    x, _ = _after_mixer(c, lp, x, y, jnp.arange(x.shape[0]) < length)
+    x, _, _ = _after_mixer(c, lp, x, y, jnp.arange(x.shape[0]) < length)
     return x, tuple(kept)
 
 
@@ -292,7 +336,7 @@ def layer_step(c: GraniteHybridConfig, lp, kind: str, x, active, mixer_step):
     Returns the residual stream, the cache's parts and the held experts' counts."""
     with jax.named_scope("vs.mamba" if kind == "mamba" else "vs.attn"):
         y, *kept = mixer_step(_mixer_input(c, lp, x))
-    x, counts = _after_mixer(c, lp, x, y, active)
+    x, counts, _ = _after_mixer(c, lp, x, y, active)
     return x, tuple(kept), counts
 
 
@@ -343,7 +387,11 @@ def serve_prefill(c: GraniteHybridConfig, params, arrays, tokens, length, page_r
     """The prefill program's body: ``tokens`` (bucket,) through the stack; K
     and V of the bucket's positions go to the slot's pages, the state and the
     tail to the slot's rows.  Returns the last real position's logits row and
-    the cache's arrays."""
+    the cache's arrays.  (Since this module gives :func:`serve_ride` the engine
+    runs no prefill program of its own: a prompt alone is the riding program
+    with every decode row idle.  What still lowers this body is the benchmark's
+    rehearsal, ``benchmark/families/granite_hybrid.py:rehearse_serve``, and the
+    tests that compare a riding step with it.)"""
     kd, vd, ssm, conv = arrays["k"], arrays["v"], arrays["ssm"], arrays["conv"]
     x = embed(c, params, tokens)
     states, tails, ks, vs = [], [], [], []
@@ -396,13 +444,91 @@ def serve_decode(c: GraniteHybridConfig, params, arrays, table, lengths, tokens,
     return head(c, params, x), {"experts": jnp.stack(counts)}, {"k": kd, "v": vd, "ssm": ssm, "conv": conv}
 
 
-# this model's own counter beside those every model's engine keeps: the slot state read and written (every
-# slot's, every step)
-STEP_COUNTERS = ("ssm_state_bytes_rw",)
+def serve_ride(c: GraniteHybridConfig, params, arrays, table, lengths, tokens, prompt, length, page_row, slot, *, active,
+               write_page, write_offset, kernels: Dict[str, Any], page: int, interpret: Optional[bool] = None):
+    """The body of the decode step that CARRIES a prompt: :func:`serve_decode`
+    with ``prompt`` (rung,) rows more, of which ``length`` are real.  The ``S``
+    decode rows and the prompt's are ONE array before every weight's product
+    (the mixers' projections; the router, the routed experts and the shared
+    expert, :func:`expert_layer` once over ``S + rung`` rows with the idle slots
+    and the pad masked out; the head over the ``S`` rows and the prompt's row
+    ``length - 1``), so a weight crosses the HBM once for both, the held
+    experts' 72% of them too.  What is no weight's product runs for each kind of
+    row as it does alone (``mamba2_ride``, :func:`attention_ride`); the prompt's
+    K and V go to the pages ``page_row``, its state and tail over ``slot``'s rows
+    after the step's pass over them, a layer at a time.  A slot that ``active``
+    (S,) does not name (it holds no request, or its prompt waits, or it is
+    ``slot`` itself) keeps its state and tail BIT FOR BIT and writes the null
+    page: with no slot active this is a prompt launched alone, beside slots in
+    the middle of their outputs.  Which form the expert layer takes follows
+    from ``S + rung`` (``moe.dropless.expert_form``), as at every other call.
+    Returns the logits (S, vocab), the prompt's logits row, the counts and the
+    cache's arrays.  ``counts["experts"]`` (layers, held) is of the DECODE rows
+    alone, what :func:`serve_decode` counts of the same step (the engine's
+    ``moe_*`` counters are of decode positions, and a prompt's rows are none);
+    where the call may take the padded form, ``counts["ride_fits_pad"]``
+    (layers,) says of each layer whether ALL its rows' counts did."""
+    S, rung = tokens.shape[0], prompt.shape[0]
+    mask = jnp.concatenate([active, jnp.arange(rung) < length])
+    row = _cache_rows(c)
+
+    def ride_layer(kind):
+        def one(lp, x, kd, vd, ssm, conv, i, table, lengths, active, mask, write_page, write_offset, length, page_row, slot):
+            """One layer of ``kind`` over all the rows; ``i`` is its row of the state arrays, or its layer of the pools,
+            AS A VALUE, so that a kind of layer, its kernels and its expert layer and all, is traced and lowered once a
+            rung whatever the depth (as ``falcon_h1.serve_ride``'s)."""
+            u = _mixer_input(c, lp, x)
+            if kind == "mamba":
+                with jax.named_scope("vs.mamba"):
+                    y, ssm, tail, state, prompt_tail = mamba2_ride(
+                        c, lp["mixer"], u, ssm, jax.lax.dynamic_index_in_dim(conv, i, keepdims=False), length,
+                        active=active, layer=i, interpret=kernels["ssm_step"])
+                # every slot's tail as the step leaves it, then the prompt's state and tail over its slot's rows
+                conv = jax.lax.dynamic_update_slice(conv, tail[None].astype(conv.dtype), (i, 0, 0, 0))
+                conv = jax.lax.dynamic_update_slice(conv, prompt_tail[None, None].astype(conv.dtype), (i, slot, 0, 0))
+                ssm = jax.lax.dynamic_update_slice(ssm, state[None, None].astype(ssm.dtype), (i, slot, 0, 0))
+            else:
+                with jax.named_scope("vs.attn"):
+                    y, kd, vd = attention_ride(
+                        c, lp["mixer"], u, kd, vd, layer=i, table=table, page=write_page, offset=write_offset,
+                        valid_len=lengths + 1, page_row=page_row, page_size=page, interpret=kernels["decode"],
+                        flash_interpret=interpret)
+            x, counts, idx = _after_mixer(c, lp, x, y, mask)
+            return x, kd, vd, ssm, conv, _held_counts(c, idx[:S], active), fits_pad(counts)
+
+        return jax.jit(one)     # (inlined where it is called: the cache's arrays are the outer program's to donate)
+
+    ride = {kind: ride_layer(kind) for kind in set(c.layer_types)}
+    kd, vd, ssm, conv = arrays["k"], arrays["v"], arrays["ssm"], arrays["conv"]
+    x = embed(c, params, jnp.concatenate([tokens, prompt]))         # (S + rung, E)
+    of_the_step, fits = [], []
+    for l, kind in enumerate(c.layer_types):
+        x, kd, vd, ssm, conv, n, fit = ride[kind](
+            params[f"layers_{l}"], x, kd, vd, ssm, conv, jnp.int32(row[l]), table, lengths, active, mask, write_page,
+            write_offset, length, page_row, slot)
+        of_the_step.append(n)
+        fits.append(fit)
+    last = jax.lax.dynamic_index_in_dim(x, S + length - 1, axis=0, keepdims=True)
+    logits = head(c, params, jnp.concatenate([x[:S], last]))
+    counts = {"experts": jnp.stack(of_the_step)}
+    if padded_candidate(S + rung, c.num_experts_per_tok, c.experts_held):
+        counts["ride_fits_pad"] = jnp.stack(fits)
+    return logits[:S], logits[S], counts, {"k": kd, "v": vd, "ssm": ssm, "conv": conv}
+
+
+# this model's own counters beside those every model's engine keeps: the slot state read and written (every
+# slot's, every step), and of the steps that carried a prompt at a rung whose expert layer may take its padded form
+# (``moe.dropless.padded_candidate`` of the step's rows and the rung's together), the expert layers they ran and
+# those of them that did take it (the busiest held expert fit the pad; the rest fell to the sorted form's XLA leg)
+STEP_COUNTERS = ("ssm_state_bytes_rw", "ride_candidate_layer_steps", "ride_padded_layer_steps")
 
 
 def step_counters(config: GraniteHybridConfig, cache, lengths, counts) -> Dict[str, int]:
-    return {"ssm_state_bytes_rw": 2 * cache.state_bytes_per_slot() * cache.num_slots}
+    out = {"ssm_state_bytes_rw": 2 * cache.state_bytes_per_slot() * cache.num_slots}
+    fits = counts.get("ride_fits_pad")
+    if fits is not None:
+        out.update(ride_candidate_layer_steps=int(fits.size), ride_padded_layer_steps=int(fits.sum()))
+    return out
 
 
 def prefill_counters(config: GraniteHybridConfig, bucket: int) -> Dict[str, int]:

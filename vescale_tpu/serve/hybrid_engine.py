@@ -9,7 +9,9 @@ the prompts that wait to RIDE a step, are ``engine.DecodeAhead``'s, shared with
 
   * ``models/granite_hybrid.py`` (the class's name is from it): state-space
     mixers with a per-slot recurrent state beside the paged K/V of their few
-    attention layers, a routed expert layer in every layer;
+    attention layers, a routed expert layer in every layer; it gives a
+    ``serve_ride`` body, so its prompts ride, and the expert layer runs once
+    over the step's rows and the prompt's;
   * ``models/deepseek_v2.py``: latent attention over a LATENT paged cache
     (one pool, no values, no slot state), prefill in the expanded form and
     decode in the absorbed one, group-limited routed experts after a dense
@@ -21,8 +23,8 @@ the prompts that wait to RIDE a step, are ``engine.DecodeAhead``'s, shared with
   * ``models/falcon_h1.py``: a state-space mixer AND a rotary grouped-query
     attention mixer side by side in EVERY layer, so every layer owns a row of
     the state arrays and a layer of the K/V pools; two groups of B and C; a
-    dense MLP (no experts: the engine's ``moe_*`` counters stay 0); the one
-    module that gives a ``serve_ride`` body today, so its prompts ride;
+    dense MLP (no experts: the engine's ``moe_*`` counters stay 0); it gives a
+    ``serve_ride`` body too (the first that did), so its prompts ride;
   * ``models/laguna.py``: window and full attention MIXED, with more query
     heads and another rotary term on the window layers: the full layers keep
     pages, a window layer a RING a slot of the newest ``window`` positions (slot
@@ -73,7 +75,9 @@ gives, as plain functions of the config:
       page for a slot that may not write: the engine reckons it, once);
       ``counts["experts"]`` (expert layers, held) is the tokens each held
       expert got (a dense model leaves it out), whatever else ``counts`` holds
-      is the model's own;
+      is the model's own.  They are of the DECODE rows: a step that carries a
+      prompt returns under ``"experts"`` what its ``S`` rows alone gave the
+      experts, so that ``moe_*`` mean what they mean of a step without one;
   ``serve_ride(config, params, arrays, table, lengths, tokens, prompt, length,
   page_row, slot, *, active, write_page, write_offset, kernels, page,
   interpret)`` -> ``(logits (S, vocab), the prompt's logits row, counts, arrays)``
@@ -196,7 +200,7 @@ program, and a verify step of one token a position is not what its passes are.
 ``num_stages`` > 1 and a mesh of more than one device have no program here yet.
 A block engine offers no ride whatever its module gives (a prompt is more rows
 of a pass, which is another program), and neither does a model whose module
-gives no ``serve_ride``: seven of the eight today.
+gives no ``serve_ride``: six of the eight today (Falcon-H1's and Granite's do).
 """
 
 from __future__ import annotations
@@ -323,11 +327,13 @@ class HybridServeEngine(DecodeAhead):
         grouped = bool(experts) and dropless.grouped_leg(c.dtype, *experts[0].shape[1:]) is not None
         self._expert_layers = len(experts)
         self._decode_rows = decode_rows
-        self._grouped_layers = {rows: len(experts) for rows in (*self.buckets, decode_rows) if grouped and dropless.expert_form(
-            rows, c.num_experts_per_tok, c.experts_held) == dropless.SORTED}
         # does a prompt ride a decode step here?  The OFFER the serve loop asks for (``getattr(engine, "rides", False)``),
         # made where the model's module gives the body of such a step and a step moves one position a slot
         self.rides = hasattr(self.model, "serve_ride") and self.block is None
+        # (the rows of the program a prompt is launched in: its bucket's, beside every decode row where it rides)
+        self._prompt_rows = {bucket: bucket + (decode_rows if self.rides else 0) for bucket in self.buckets}
+        self._grouped_layers = {rows: len(experts) for rows in (*self._prompt_rows.values(), decode_rows)
+                                if grouped and dropless.expert_form(rows, c.num_experts_per_tok, c.experts_held) == dropless.SORTED}
         # what this engine has done, in plain integers (``trace_counters``); ``prefill_rides`` only where prompts ride
         self.counter_names = (COUNTERS + (("prefill_rides",) if self.rides else ())
                               + (BLOCK_COUNTERS if self.block is not None else ()) + tuple(self.model.STEP_COUNTERS))
@@ -497,7 +503,8 @@ class HybridServeEngine(DecodeAhead):
 
     def decode(self, tokens):
         step = super().decode(tokens)
-        self._count_expert_layers(self._decode_rows)
+        if getattr(tokens, "rider", None) is None:      # (a step that carries a prompt is that prompt's program, counted at its ``prefill``)
+            self._count_expert_layers(self._decode_rows)
         return step
 
     def _count_expert_layers(self, rows: int) -> None:
@@ -538,7 +545,7 @@ class HybridServeEngine(DecodeAhead):
         self.prefill_tokens_real += n
         self.prefill_tokens_padded += bucket
         self.prefill_bucket_tokens += bucket
-        self._count_expert_layers(bucket)
+        self._count_expert_layers(self._prompt_rows[bucket])
         self._add(self.model.prefill_counters(self.config, bucket))
         return out
 
@@ -607,7 +614,10 @@ class HybridServeEngine(DecodeAhead):
         that are the grouped kernel outside any choice on the device (by the
         program's rows ``moe.dropless.expert_form`` says the sorted form
         alone, and its leg is the kernel's: ``grouped_leg``; what a
-        candidate's ``cond`` took at a prefill is in no counter).
+        candidate's ``cond`` took at a prefill is in no counter).  Where
+        prompts ride a prompt's program is the step that carries it, the
+        bucket's rows beside every slot's, rode or alone, counted at the
+        ``prefill`` call and not again at the ``decode`` that carries it.
         ``prefill_bucket_tokens`` the bucket lengths.  A block engine's six
         (``BLOCK_COUNTERS``, above), then the model's own (its module's
         ``STEP_COUNTERS`` says what each counts)."""
