@@ -199,3 +199,38 @@ def test_the_static_half_of_the_choice_reads_tokens_choices_and_held_experts(N, 
 def test_the_predicate_is_the_same_on_the_hosts_copy_of_the_counts_layer_by_layer():
     counts = np.array([[0, ROW_PAD, 3], [ROW_PAD + 1, 0, 0], [5, 5, 5]])
     assert list(fits_pad(counts)) == [True, False, True] == [bool(fits_pad(jnp.asarray(row))) for row in counts]
+
+
+# ----------------------------------------------------------- a long call in row pieces
+@pytest.mark.parametrize("k, d, ladder", [
+    # 12 x 6144 a row: pieces of at most 1,024 rows; 8 x 2560: of at most 4,096, and the rung no piece count up to
+    # ceil(N / most) divides (10,240 in 3) takes the next that does (4 of 2,560), never the whole rung
+    (12, 6144, {128: 1, 1024: 1, 1536: 2, 2048: 2, 3072: 3, 4096: 4}),
+    (8, 2560, {128: 1, 4096: 1, 5120: 2, 6144: 2, 7168: 2, 8192: 2, 10240: 4, 12288: 3, 14336: 4, 16384: 4}),
+], ids=["12_of_6144", "8_of_2560"])
+def test_a_rungs_pieces_are_the_fewest_equal_ones_whose_sorted_form_fits(k, d, ladder):
+    assert {N: dropless.row_pieces(N, k, d) for N in ladder} == ladder
+    for N, pieces in ladder.items():
+        assert N % pieces == 0 and 6 * k * d * (N // pieces) <= dropless.SORTED_FORM_BYTES
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["every_row", "masked"])
+def test_a_call_in_row_pieces_gives_what_the_whole_call_gives(masked, monkeypatch):
+    N, E, k, held = 48, 8, 2, 4
+    x, _idx, _gates, weights, _mask = _problem(N, E, k, held, seed=21)
+    x, w_gate, w_up, w_down = (jnp.asarray(a, jnp.float32) for a in (x, *weights))
+    router = jnp.asarray(np.random.default_rng(22).normal(size=(D, E)), jnp.float32)
+    mask = jnp.arange(N) < 41 if masked else None
+
+    def rows(h, token_mask):
+        out, counts = dropless.routed_experts(h, router, lambda s: route_topk(s, k), w_gate, w_up, w_down, first_held=2,
+                                              token_mask=token_mask)
+        return out, counts, jnp.sum(counts)
+
+    whole = rows(x, mask)
+    monkeypatch.setattr(dropless, "SORTED_FORM_BYTES", 6 * k * D * 20)         # at most 20 rows: 48 are three pieces of 16
+    assert dropless.row_pieces(N, k, D) == 3
+    out, counts, pairs = jax.jit(lambda h: dropless.in_row_pieces(rows, h, mask, k=k))(x)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(whole[0]), rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(whole[1]))
+    assert int(pairs) == int(whole[2]) and (not masked or not np.asarray(out[41:]).any())

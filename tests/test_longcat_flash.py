@@ -316,15 +316,18 @@ def test_a_long_rung_goes_through_the_routed_branch_in_pieces_and_gives_the_same
     h = jax.random.normal(jax.random.key(10), (48, cfg.hidden_size), jnp.float32)
     live = jnp.arange(48) < 41
     whole = lc.routed_branch(cfg, ep, h, live)
-    monkeypatch.setattr(lc, "ROUTED_CHUNK_ROWS", 16)          # three pieces of 16 rows
+    form_bytes = lambda rows: 6 * cfg.num_experts_per_tok * cfg.hidden_size * rows      # (dropless.row_pieces' arithmetic)
+    monkeypatch.setattr(dropless, "SORTED_FORM_BYTES", form_bytes(16))                  # three pieces of 16 rows
+    assert dropless.row_pieces(48, cfg.num_experts_per_tok, cfg.hidden_size) == 3
     pieces = jax.jit(lambda h: lc.routed_branch(cfg, ep, h, live))(h)
     assert rel(pieces[0], whole[0]) < 1e-6 and not np.asarray(pieces[0][41:]).any()
     np.testing.assert_array_equal(np.asarray(pieces[1]), np.asarray(whole[1]))
     assert int(pieces[2]) == int(whole[2])
-    monkeypatch.setattr(lc, "ROUTED_CHUNK_ROWS", 20)          # 48 rows are three pieces of 16 again
+    monkeypatch.setattr(dropless, "SORTED_FORM_BYTES", form_bytes(20))                  # 48 rows are three pieces of 16 again
     assert rel(lc.routed_branch(cfg, ep, h)[0], lc._routed_rows(cfg, ep, h, None)[0]) < 1e-6
+    monkeypatch.undo()
     # the real ladder: every rung over 1,024 rows divides into equal pieces of at most 1,024
-    assert [(-(-n // 1024), n % -(-n // 1024)) for n in (1536, 2048, 3072, 4096)] == [(2, 0), (2, 0), (3, 0), (4, 0)]
+    assert [dropless.row_pieces(n, 12, 6144) for n in (128, 1024, 1536, 2048, 3072, 4096)] == [1, 1, 2, 2, 3, 4]
 
 
 # --------------------------------------------------------------------- cache

@@ -7,7 +7,7 @@ one described device, and compiles it.  Nothing executes, so this says
 nothing about results or times; it catches what interpret mode cannot — a
 kernel the compiler refuses (scoped VMEM, tiling, HBM).  The cases call the
 kernel entry points below the platform dispatch (``_flash``,
-``paged_decode``, ``ssm_step``, ``selective_scan``, ``grouped_swiglu``, ``head_select``, ``_fused_local``, ``fused_xent_parts``): code that asks
+``paged_decode``, ``ssm_step``, ``selective_scan``, ``kda_step``, ``kda_chunk``, ``grouped_swiglu``, ``head_select``, ``_fused_local``, ``fused_xent_parts``): code that asks
 ``jax.devices()`` still sees the CPU here.
 """
 
@@ -240,6 +240,57 @@ def test_selective_scan_compiles_at_the_cells_rungs(chip, T):
                         sds(T, J), sds(T, J), sds(N, J), sds(T, N), sds(T, N))
     assert "selective_scan" in compiled.as_text()           # what benchmark/families/phi4flash.py finds its events by
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20     # B and C by eights; no state in HBM but the last
+
+
+# ------------------------------------------------------------ the delta rule
+def test_kda_step_compiles_at_the_cells_state_in_place(chip):
+    """Six layers' states of 256 slots x 32 heads of 128 x 128 float32 (3.2 GB), one layer's step: the whole array aliased,
+    no copy of it, and beside it only the step's small operands (a tile of columns a slot and block, the rows)."""
+    from vescale_tpu.kernels.kda import kda_step, supports_step
+
+    L, S, H, D = 6, 256, 32, 128
+    assert supports_step(f32, H, D, D, interpret=False)
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, f32, sharding=chip)
+    compiled = jax.jit(lambda state, q, k, v, g, beta: kda_step(state, q, k, v, g, beta, layer=jnp.int32(2), interpret=False),
+                       donate_argnums=(0,)).lower(sds(L, S, H, D, D), sds(S, H, D), sds(S, H, D), sds(S, H, D), sds(S, H, D), sds(S, H)).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text and "kda_step" in text      # what benchmark/families/ling_hybrid.py finds its events by
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == L * S * H * D * D * 4 and memory.temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("T", [128, 2048, 8192])
+def test_kda_chunk_compiles_at_the_cells_rungs(chip, T):
+    from vescale_tpu.kernels.kda import kda_chunk, supports_chunk
+
+    H, D = 32, 128
+    assert supports_chunk(H, D, D, T, interpret=False)
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, f32, sharding=chip)
+    compiled = _compile(lambda q, k, v, g, beta: kda_chunk(q, k, v, g, beta, interpret=False),
+                        sds(T, H, D), sds(T, H, D), sds(T, H, D), sds(T, H, D), sds(T, H))
+    assert "kda_chunk" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * T * H * D * 4      # k beta, v beta and the running sums: no state in HBM but the last
+
+
+def test_lings_decode_program_compiles_at_the_cells_size_with_no_copy_of_pool_or_state(chip):
+    """``ling3flash_serve_longgen``'s decode step (256 slots x 16,384 positions): six ``kda_step`` over the states in place
+    and ONE ``paged_decode_latent`` at 32 heads over pages of 32 (a page table of 512 KB: pages of 16 would need 1 MiB of
+    scalar memory, which the compiler refuses); the expert layers are XLA's (a padded candidate)."""
+    import re
+
+    family, config, sizes, programs = _cells_programs(chip, "ling3flash_serve_longgen")
+    titles = [title for title, _ in programs]
+    assert sum("prefill, rung of" in t for t in titles) == 16 and "decode step, 256 slots x 16384 positions" in titles[-1]
+    assert sizes["weights_bytes"] == family.weight_bytes(config)
+    assert sizes["kv_pool_bytes"] == 61440 * 32 * 1280 and sizes["slot_state_bytes"] == 256 * family.state_bytes_per_slot(config, config["serve"])
+    assert sizes["kv_pool_bytes"] + sizes["slot_state_bytes"] == family.cache_bytes(config, config["serve"])
+    compiled = programs[-1][1].compile()
+    text = compiled.as_text()
+    kernel_calls = re.findall(r"%([a-z_.]+?)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    assert sorted(kernel_calls) == ["kda_step"] * 6 + ["paged_decode_latent"]
+    _assert_in_place_and_fits(compiled, sizes, "bf16[1,61440,32,1,640]")
+    for held in ("bf16[1,61440,32,640]", "f32[6,256,32,128,128]", "bf16[6,256,3,12288]"):
+        assert not [line for line in text.splitlines() if " copy(" in line and f"= {held}" in line], held
 
 
 # Phi-4-mini-flash's folded rows (ten key heads of 128: no whole number of sublane tiles, so not ``paged_decode``'s pool): the

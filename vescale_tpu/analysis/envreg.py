@@ -213,7 +213,7 @@ register("VESCALE_SHARDCHECK", "str", "warn",
 
 # --- Pallas kernel layer ---------------------------------------------
 register("VESCALE_KERNELS", "str", None,
-         "Pallas kernel dispatch (docs/kernels.md). Unset: `paged_decode` (serve decode attention), `ssm_step` (a state-space layer's decode step), `selective_scan` (a Mamba-1 layer's recurrence over a prompt), `grouped_experts` (a dropless expert layer's sorted form) and `head_select` (a block diffusion pass's head to selection) are the compiled kernels on TPU and the XLA leg elsewhere, the other kernels are off. Set, for all kernels: `off` = the pre-kernel XLA paths byte-identical, `interpret` = run the kernels through the pallas interpreter on any backend (bit-parity testing), `on` = compiled kernels on TPU (falls back to XLA off-TPU, counted in kernel_fallback_total).")
+         "Pallas kernel dispatch (docs/kernels.md). Unset: `paged_decode` (serve decode attention), `ssm_step` (a state-space layer's decode step), `selective_scan` (a Mamba-1 layer's recurrence over a prompt), `grouped_experts` (a dropless expert layer's sorted form), `head_select` (a block diffusion pass's head to selection) and `kda_step` / `kda_chunk` (delta-rule linear attention's decode step and its chunked prefill) are the compiled kernels on TPU and the XLA leg elsewhere, the other kernels are off. Set, for all kernels: `off` = the pre-kernel XLA paths byte-identical, `interpret` = run the kernels through the pallas interpreter on any backend (bit-parity testing), `on` = compiled kernels on TPU (falls back to XLA off-TPU, counted in kernel_fallback_total).")
 
 # --- gradient compression / quantized collectives --------------------
 register("VESCALE_GRAD_COMPRESS", "str", "",

@@ -3,10 +3,11 @@
 Every hand-written TPU kernel in the framework lives in this package and is
 reached through the same knob (``VESCALE_KERNELS``, registered in
 ``analysis.envreg``).  Unset, each kernel takes its own default:
-``paged_decode``, ``paged_decode_latent``, ``ssm_step``, ``selective_scan``, ``grouped_experts`` and ``head_select`` are the compiled
+``paged_decode``, ``paged_decode_latent``, ``ssm_step``, ``selective_scan``, ``grouped_experts``, ``head_select``, ``kda_step`` and
+``kda_chunk`` are the compiled
 kernels on TPU and the XLA leg on every other backend (what the platform is,
-the code can see; PERF.md, PR 27, PR 29, PR 46, PR 47 and PR 61); the other three stay
-``off``.  Set, it means the same for all nine:
+the code can see; PERF.md, PR 27, PR 29, PR 46, PR 47, PR 61 and PR 63); the other three stay
+``off``.  Set, it means the same for all eleven:
 
   ``off``        the kernels are never consulted — every caller takes
                  exactly the XLA path it took before this package
@@ -55,6 +56,14 @@ Kernels in this package:
     (``kernels/selective_scan.py``): the state stays in VMEM while the kernel
     walks the positions, where XLA's loop carries it through HBM (its XLA
     leg); for ``models/phi4flash.py``'s prefill, the default on TPU.
+  * ``kda_step`` / ``kda_chunk`` — delta-rule linear attention
+    (``kernels/kda.py``), whose state a head is a float32 MATRIX that a
+    position decays a row at a time and corrects by a rank-1 term: the decode
+    step over every slot's state in place (one read, one write), and a
+    prompt's positions in chunks of 128 with the state in VMEM (the chunk's
+    triangular system inverted by products the MXU runs, every decay taken
+    forward in time or inside 16 positions: the gate's lower bound); for
+    ``models/kda.py`` under ``serve/hybrid_engine.py``, the defaults on TPU.
   * ``grouped_experts``  — the sorted form of a dropless expert layer
     (``kernels/grouped_swiglu.py``): one grid over row tiles of the (token,
     expert) pairs in expert order; a tile's expert, a scalar-prefetch operand,
@@ -117,7 +126,7 @@ MODES = ("off", "interpret", "on")
 # what an unset VESCALE_KERNELS means for these: compiled on TPU, the XLA leg
 # elsewhere (every other kernel: off)
 DEFAULT_ON_TPU = frozenset({"paged_decode", "paged_decode_latent", "ssm_step", "selective_scan", "grouped_experts",
-                            "head_select"})
+                            "head_select", "kda_step", "kda_chunk"})
 
 
 def mode() -> str:

@@ -64,14 +64,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..moe.dropless import identity_experts, route_softmax_biased, routed_experts
+from ..moe.dropless import identity_experts, in_row_pieces, route_softmax_biased, routed_experts
 from . import mla
 from .blocks import F32, ROUTED_DOWN_GAIN, _mm, rmsnorm, swiglu
 
 __all__ = [
     "LongcatFlashConfig", "init_params", "selection_bias", "embed", "head", "routed_branch", "layer", "cache_config",
     "prefill_chunk", "decode_kernels", "serve_prefill", "serve_decode", "STEP_COUNTERS", "step_counters",
-    "prefill_counters", "SUBLAYERS", "BIAS_OVER_UNIFORM", "SCORE_DEVIATION", "ROUTED_CHUNK_ROWS",
+    "prefill_counters", "SUBLAYERS", "BIAS_OVER_UNIFORM", "SCORE_DEVIATION",
 ]
 
 SUBLAYERS = 2       # attention sublayers (and dense SwiGLUs, and pool layers) a model layer
@@ -91,13 +91,6 @@ BIAS_OVER_UNIFORM = 2.0
 # thousand positions is flat and no fault of the attention shows), and ``W_uv`` ``1 / s_kv`` times as wide (values of the
 # stream's size).  The multipliers stay where the source has them: dropping either still halves, or thirds, the scores.
 SCORE_DEVIATION = 2.0
-# The rows the routed branch takes at once.  The dropless layer sizes its sorted form for EVERY kept pair landing here
-# (``num_experts_per_tok`` a row: the router could send them all), where a share's mean is a few in a hundred: at the
-# published widths that is 12 x 6144 numbers a row in two types, 3.0 GB of temporaries at a rung of 4,096 rows beside
-# 13.7 GB of weights and cache (read on a described v5e, PERF.md section 6, PR 54).  A longer rung goes through in equal
-# pieces of at most this many rows, one after another: 1.1 GB at most, for one more read of the touched experts' weights
-# a piece.
-ROUTED_CHUNK_ROWS = 1024
 
 
 @dataclasses.dataclass(frozen=True)
@@ -244,19 +237,13 @@ def head(config: LongcatFlashConfig, params, x):
 # ------------------------------------------------------------ the routed branch
 def routed_branch(c: LongcatFlashConfig, ep, h, token_mask=None):
     """``Routed(h)`` for tokens ``h`` (N, E): the held real experts' part through
-    the dropless layer, and the identity experts' part, whole; more than
-    ``ROUTED_CHUNK_ROWS`` rows in equal pieces, one after another.  Returns the
-    sum (N, E) float32, how many tokens each held expert got (held,), and how
-    many kept pairs of the tokens that route (``token_mask``) fell on identity
-    experts."""
-    N = h.shape[0]
-    pieces = -(-N // ROUTED_CHUNK_ROWS)
-    if pieces == 1 or N % pieces:
-        return _routed_rows(c, ep, h, token_mask)
-    mask = jnp.ones((N,), bool) if token_mask is None else token_mask
-    out, counts, zero_pairs = jax.lax.map(lambda piece: _routed_rows(c, ep, *piece),
-                                          (h.reshape(pieces, N // pieces, -1), mask.reshape(pieces, -1)))
-    return out.reshape(N, -1), counts.sum(axis=0), zero_pairs.sum()
+    the dropless layer, and the identity experts' part, whole; a long rung in
+    ``dropless.in_row_pieces`` (at most 1,024 rows at the published widths: the
+    sorted form is sized for every kept pair landing here, 12 a row, where a
+    share's mean is a few in a hundred).  Returns the sum (N, E) float32, how
+    many tokens each held expert got (held,), and how many kept pairs of the
+    tokens that route (``token_mask``) fell on identity experts."""
+    return in_row_pieces(lambda rows, mask: _routed_rows(c, ep, rows, mask), h, token_mask, k=c.num_experts_per_tok)
 
 
 def _routed_rows(c: LongcatFlashConfig, ep, h, token_mask):

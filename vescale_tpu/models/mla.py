@@ -11,7 +11,7 @@ named here and none is imported; a family's file builds its
 
 Equations (HF ``modeling_deepseek.py``; ISSUE 34 and ISSUE 54 write them out)::
 
-    c_q = s_q norm(W_qa u);  q_h = W_qb,h c_q = q_nope,h | q_pe,h
+    c_q = s_q norm(W_qa u);  q_h = W_qb,h c_q = q_nope,h | q_pe,h       (``q_lora_rank`` None: q_h = W_q,h u, no norm)
     W_kva u = c_kv | k_pe (one for all heads);  c = s_kv norm(c_kv)
     rotary on q_pe,h and k_pe, over interleaved pairs, at ``inv_freq``
 
@@ -30,7 +30,9 @@ row occupies anyway), in a paged cache of the latent form
 (``serve/kv_cache.py``): one pool, no value pool.  ``kv_b`` is kept as its two
 halves in the layouts the absorbed products read (``kv_b_k`` (H, nope, C),
 ``kv_b_v`` (H, C, v)); the expanded form multiplies by the same two arrays, so
-there is no second copy.
+there is no second copy.  ``head_gate`` (rows, H), where a family gives one, weighs
+each head's value output before ``W_o`` (a source's head-wise output gate: the
+family computes it, from whatever it is a function of).
 
 Precision: weights and matmul operands ``dtype`` (bfloat16) with float32
 accumulation; norms, multipliers, rotary and softmax float32; a cached row is
@@ -62,7 +64,7 @@ class LatentAttention:
 
     hidden_size: int
     num_attention_heads: int
-    q_lora_rank: int
+    q_lora_rank: Optional[int]          # None: no query LoRA, ``q = W_q u`` (the tree has ``q`` where it had ``q_a``, ``q_a_norm``, ``q_b``)
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
@@ -99,9 +101,13 @@ def attention_params(a: LatentAttention, key, gains: Optional[Mapping[str, float
         return (jax.random.normal(k, shape, F32) * (gains.get(name, 1.0) / math.sqrt(fan_in))).astype(dt)
 
     ks = jax.random.split(key, 6)
-    return {"q_a": normal(ks[0], (E, a.q_lora_rank), E),
-            "q_a_norm": jnp.ones((a.q_lora_rank,), dt),
-            "q_b": normal(ks[1], (a.q_lora_rank, H * a.qk_head_dim), a.q_lora_rank, "q_b"),
+    if a.q_lora_rank is None:
+        queries = {"q": normal(ks[1], (E, H * a.qk_head_dim), E, "q")}
+    else:
+        queries = {"q_a": normal(ks[0], (E, a.q_lora_rank), E),
+                   "q_a_norm": jnp.ones((a.q_lora_rank,), dt),
+                   "q_b": normal(ks[1], (a.q_lora_rank, H * a.qk_head_dim), a.q_lora_rank, "q_b")}
+    return {**queries,
             "kv_a": normal(ks[2], (E, a.latent_row), E),
             "kv_a_norm": jnp.ones((a.kv_lora_rank,), dt),
             # kv_b's two halves, a head at a time: W_uk (H, nope, C) and W_uv (H, C, v)
@@ -143,8 +149,11 @@ def _queries(a: LatentAttention, ap, u, positions, *, head_major: bool = False):
     operands' type; ``head_major``: (H, T, .), as the flash forward reads
     them, straight from the product."""
     H = a.num_attention_heads
-    cq = _scaled(rmsnorm(_mm(u, ap["q_a"], a.dtype), ap["q_a_norm"], a.rms_norm_eps), a.q_scale).astype(a.dtype)
-    w = ap["q_b"].astype(a.dtype).reshape(a.q_lora_rank, H, a.qk_head_dim)
+    if a.q_lora_rank is None:
+        cq, w = u.astype(a.dtype), ap["q"].astype(a.dtype).reshape(a.hidden_size, H, a.qk_head_dim)
+    else:
+        cq = _scaled(rmsnorm(_mm(u, ap["q_a"], a.dtype), ap["q_a_norm"], a.rms_norm_eps), a.q_scale).astype(a.dtype)
+        w = ap["q_b"].astype(a.dtype).reshape(a.q_lora_rank, H, a.qk_head_dim)
     q = jnp.einsum("tr,rhd->htd" if head_major else "tr,rhd->thd", cq, w, preferred_element_type=F32).astype(a.dtype)
     where = positions[None, :] if head_major else positions[:, None]
     return q[..., : a.qk_nope_head_dim], rotary(a, q[..., a.qk_nope_head_dim:], where).astype(a.dtype)
@@ -160,11 +169,21 @@ def _latent_rows(a: LatentAttention, ap, u, positions):
     return jnp.concatenate([latent, k_pe, pad], axis=-1).astype(a.dtype)
 
 
-def mla_prefill(a: LatentAttention, ap, u, *, interpret: Optional[bool] = None):
+def _gated(y, head_gate, heads_axis: int):
+    """``y`` with its heads on ``heads_axis`` and rows on the other leading axis,
+    each head's output times its gate (rows, H) in float32; None: ``y`` as it is."""
+    if head_gate is None:
+        return y
+    gate = head_gate.astype(F32) if heads_axis == 1 else head_gate.astype(F32).T
+    return y.astype(F32) * gate[..., None]
+
+
+def mla_prefill(a: LatentAttention, ap, u, *, interpret: Optional[bool] = None, head_gate=None):
     """The EXPANDED form over one sequence ``u`` (T, E) from position 0:
     per-head keys and values from the latent, causal attention with scores
     ``qk_head_dim`` wide and values ``v_head_dim`` wide through the blocked
-    flash forward (no (T, T) tensor).  Returns the output (T, E) and the
+    flash forward (no (T, T) tensor).  ``head_gate`` (T, H), where given, weighs
+    each head's output before ``W_o``.  Returns the output (T, E) and the
     positions' cache rows (T, cache_row).  Pad positions follow the real ones,
     so causality keeps them out."""
     from ..ops.flash_attention import flash_attention_forward
@@ -180,18 +199,20 @@ def mla_prefill(a: LatentAttention, ap, u, *, interpret: Optional[bool] = None):
     q = jnp.concatenate([q_nope, q_pe], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe[None], (H, T, a.qk_rope_head_dim))], axis=-1)
     y = flash_attention_forward(q, k, v, scale=a.softmax_scale, interpret=interpret, name=FLASH_NAME)
+    y = _gated(y, head_gate, 0).astype(y.dtype)
     return jnp.einsum("htd,hde->te", y, ap["o"].astype(a.dtype).reshape(H, a.v_head_dim, a.hidden_size),
                       preferred_element_type=F32), rows
 
 
 def mla_step(a: LatentAttention, ap, u, pool, *, layer: int, table, page, offset, positions, valid_len,
-             interpret: Optional[bool]):
+             interpret: Optional[bool], head_gate=None):
     """The ABSORBED form, one new position a slot: ``u`` (S, E); the
     position's row goes to ``(page, offset)`` of the pool's ``layer`` (the
     null page for a slot that may not write), then
     ``kernels.paged_decode_latent`` reads the slot's pages with the
     ``latent_row``-wide absorbed queries (``interpret``: the kernel's flag, or
-    None for its XLA leg).  Returns the output (S, E) and the pool."""
+    None for its XLA leg); ``head_gate`` (S, H) as :func:`mla_prefill`'s.
+    Returns the output (S, E) and the pool."""
     from ..kernels.paged_attention import paged_decode_latent
 
     S, H = u.shape[0], a.num_attention_heads
@@ -206,7 +227,7 @@ def mla_step(a: LatentAttention, ap, u, pool, *, layer: int, table, page, offset
                                 interpret=interpret)
     y = jnp.einsum("hsc,hcd->hsd", mixed.astype(a.dtype).transpose(1, 0, 2), ap["kv_b_v"].astype(a.dtype),
                    preferred_element_type=F32).transpose(1, 0, 2)
-    return _mm(y.reshape(S, H * a.v_head_dim), ap["o"], a.dtype), pool
+    return _mm(_gated(y, head_gate, 1).reshape(S, H * a.v_head_dim), ap["o"], a.dtype), pool
 
 
 # ------------------------------------- the block's side of the serve engine's seam

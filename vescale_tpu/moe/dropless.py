@@ -89,8 +89,8 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["route_topk", "route_group_limited", "route_sigmoid_topk", "route_softmax_biased", "identity_experts",
-           "dropless_experts", "routed_experts", "padded_candidate", "fits_pad", "expert_form", "grouped_leg", "DENSE_MAX_TOKENS", "ROW_PAD", "PADDED_MIN_MEAN_ROWS", "PADDED_MAX_MEAN_ROWS"]
+__all__ = ["route_topk", "route_group_limited", "route_sigmoid_topk", "route_sigmoid_group_limited", "route_softmax_biased", "identity_experts",
+           "dropless_experts", "routed_experts", "in_row_pieces", "row_pieces", "SORTED_FORM_BYTES", "padded_candidate", "fits_pad", "expert_form", "grouped_leg", "DENSE_MAX_TOKENS", "ROW_PAD", "PADDED_MIN_MEAN_ROWS", "PADDED_MAX_MEAN_ROWS"]
 
 # one row tile of the MXU: up to here each touched expert costs a grouped product a tile, sorted or not
 DENSE_MAX_TOKENS = 128
@@ -106,6 +106,12 @@ PADDED_MAX_MEAN_ROWS = 96
 PADDED_MIN_MEAN_ROWS = 32
 # what a call holds: one form, or the padded and the sorted one under a choice on the device
 ALL_ON_ALL, SORTED, PADDED_OR_SORTED = "all_on_all", "sorted", "padded_or_sorted"
+# What the sorted form may lay out at once (:func:`row_pieces`).  It is sized for EVERY kept pair landing here, ``k`` a row
+# (the router could send them all), whatever share of the model's experts is held: ``k x d`` numbers a row in two types (the
+# gathered rows in the operands' and the products' results in float32, 6 bytes a number).  At 12 x 6144 a row that is 3.0 GB
+# at a rung of 4,096 rows beside 13.7 GB of weights and cache (read on a described v5e, PERF.md section 6, PR 54); under this
+# bound 1,024 rows there and 4,096 rows of 8 x 2560 (PR 63) are a piece, 0.45 and 0.5 GB.
+SORTED_FORM_BYTES = 512 << 20
 
 
 def route_topk(scores, k: int) -> Tuple[jax.Array, jax.Array]:
@@ -154,6 +160,34 @@ def route_sigmoid_topk(scores, k: int, *, scale: float = 1.0, bias=None) -> Tupl
         _, idx = jax.lax.top_k(probs + bias.astype(jnp.float32), k)
         top = jnp.take_along_axis(probs, idx, axis=-1)
     return idx.astype(jnp.int32), top * (scale / jnp.sum(top, axis=-1, keepdims=True))
+
+
+def route_sigmoid_group_limited(scores, k: int, *, n_group: int, topk_group: int, scale: float = 1.0, bias=None
+                                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Sigmoid routing under a group limit (the sources' ``noaux_tc``): each of
+    a token's router scores (N, E) through a float32 sigmoid on its own, ``s``;
+    the CHOICE is made on ``c = s + bias`` (``bias`` (E,) float32, the sources'
+    ``e_score_correction_bias``; None: ``c = s``): the experts lie in ``n_group``
+    contiguous groups of ``E / n_group``, a group scores as the sum of its TWO
+    largest ``c`` (the one source's rule), the ``topk_group`` best groups are kept, then the
+    ``k`` largest ``c`` among the kept groups' experts (ties to the lower id, as
+    ``jax.lax.top_k`` breaks them).  The gates are the kept experts' ``s`` (the
+    bias chooses, it does not weigh), renormalised to sum 1 over those ``k``,
+    times ``scale``.  Returns ids (N, k) int32 and gates (N, k) float32, as
+    :func:`route_topk` does, and which groups each token kept (N, n_group)
+    bool, as :func:`route_group_limited` does."""
+    N, E = scores.shape
+    per = E // n_group
+    if E % n_group or not 0 < topk_group <= n_group or k > topk_group * per or per < 2:
+        raise ValueError(f"{E} experts in {n_group} groups, {topk_group} kept, cannot give {k} a token")
+    probs = jax.nn.sigmoid(scores.astype(jnp.float32))
+    choice = probs if bias is None else probs + bias.astype(jnp.float32)
+    best, _ = jax.lax.top_k(choice.reshape(N, n_group, per), 2)
+    _, groups = jax.lax.top_k(jnp.sum(best, axis=-1), topk_group)
+    kept = jnp.zeros((N, n_group), bool).at[jnp.arange(N)[:, None], groups].set(True)
+    _, idx = jax.lax.top_k(jnp.where(jnp.repeat(kept, per, axis=1), choice, -jnp.inf), k)
+    top = jnp.take_along_axis(probs, idx, axis=-1)
+    return idx.astype(jnp.int32), top * (scale / jnp.sum(top, axis=-1, keepdims=True)), kept
 
 
 def route_softmax_biased(scores, k: int, *, scale: float = 1.0, bias=None) -> Tuple[jax.Array, jax.Array]:
@@ -251,6 +285,29 @@ def routed_experts(h, router, route: Callable, w_gate, w_up, w_down, *, first_he
     idx, gates, *own = route(scores)
     return (*dropless_experts(h, idx, gates, w_gate, w_up, w_down, first_held=first_held, token_mask=token_mask,
                               dtype=dtype), *own)
+
+
+def row_pieces(N: int, k: int, d: int) -> int:
+    """In how many equal pieces ``N`` rows of width ``d`` with ``k`` experts each go through the layer so that a piece's
+    sorted form stays within ``SORTED_FORM_BYTES``: the fewest that divide ``N``."""
+    pieces = -(-N // max(1, SORTED_FORM_BYTES // (6 * k * d)))
+    while N % pieces:
+        pieces += 1
+    return pieces
+
+
+def in_row_pieces(rows: Callable, h, token_mask: Optional[jax.Array], *, k: int):
+    """``rows(h, token_mask)``, a model's routed part of tokens ``h`` (N, d) (a call of :func:`routed_experts` and what
+    the model adds to it), over the :func:`row_pieces` of ``h`` one after another (``lax.map``), for one more read of the
+    touched experts' weights a piece.  ``rows`` returns the tokens' result (n, .) first and then counts that add up
+    over rows; so does this."""
+    N, d = h.shape
+    pieces = row_pieces(N, k, d)
+    if pieces == 1:
+        return rows(h, token_mask)
+    mask = jnp.ones((N,), bool) if token_mask is None else token_mask
+    out, *counts = jax.lax.map(lambda piece: rows(*piece), (h.reshape(pieces, N // pieces, d), mask.reshape(pieces, -1)))
+    return (out.reshape(N, -1), *(c.sum(axis=0) for c in counts))
 
 
 # jitted inside its caller's program: a model's layers have one shape, so the layer is traced and lowered once a

@@ -5,7 +5,7 @@ ladder, ``warm()``, donation, the ``vs.serve-*`` spans and ``trace_counters()``;
 ``decode`` itself, one step deep (a call launches its step and returns the
 ``DecodeStep`` unread; a ``DecodeFeed`` feeds the next from the device), and
 the prompts that wait to RIDE a step, are ``engine.DecodeAhead``'s, shared with
-``ServeEngine``.  Eight models plug in today:
+``ServeEngine``.  Nine models plug in today:
 
   * ``models/granite_hybrid.py`` (the class's name is from it): state-space
     mixers with a per-slot recurrent state beside the paged K/V of their few
@@ -41,7 +41,13 @@ the prompts that wait to RIDE a step, are ``engine.DecodeAhead``'s, shared with
     ``kernels/selective_scan.py``) beside window layers' rings under differential attention, then ONE pool layer (``cache_config``'s
     ``layers`` = 1) that the layer which writes it and seven cross-attention layers read, and gated memory units that read one layer's
     scan output; its ``serve_prefill`` runs the second half on the last real row alone; both halves are ``lax.scan``s over stacked
-    periods, the cache's arrays in the carry; dense (no experts).
+    periods, the cache's arrays in the carry; dense (no experts);
+  * ``models/ling_hybrid.py``: DELTA-RULE linear attention (``models/kda.py``, ``kernels/kda.py``: a float32 MATRIX state a head
+    and slot, decayed a row at a time and corrected by a rank-1 term; ``kda_step`` in a decode step, ``kda_chunk`` over a prompt)
+    in five layers of six and latent attention (``models/mla.py`` without a query LoRA, a head-wise gate on its output) in the
+    sixth: the first cache that is a LATENT pool (one layer of the cut's seven) AND slot state (six layers' states and
+    convolution tails); one routing group of 512 sigmoid-routed experts under a group limit
+    (``moe.dropless.route_sigmoid_group_limited``) beside a shared expert.
 
 A second engine class beside :class:`ServeEngine`, behind the same surface
 (``prefill(prompt, slot)``, ``decode(tokens)``, ``params``,
@@ -200,7 +206,7 @@ program, and a verify step of one token a position is not what its passes are.
 ``num_stages`` > 1 and a mesh of more than one device have no program here yet.
 A block engine offers no ride whatever its module gives (a prompt is more rows
 of a pass, which is another program), and neither does a model whose module
-gives no ``serve_ride``: six of the eight today (Falcon-H1's and Granite's do).
+gives no ``serve_ride``: seven of the nine today (Falcon-H1's and Granite's do).
 """
 
 from __future__ import annotations

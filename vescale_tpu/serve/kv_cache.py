@@ -42,7 +42,10 @@ this): a position leaves ONE row a layer, shared by every head, from which
 keys and values are both read, so the cache is the ``k`` pool alone,
 ``(layers, num_pages, page_size, 1, row)``, and ``v`` is None.  Nothing of the
 host side changes: a latent page is allocated, shared (:meth:`alloc_shared`),
-rolled back and fingerprinted like any other.
+rolled back and fingerprinted like any other.  A latent pool may have slot
+state beside it (``models/ling_hybrid.py``: one latent layer in six, the others'
+delta-rule states a slot): ``arrays()`` is then ``k`` and the state's arrays, and
+what slot state refuses (below) is refused.
 
 Slot state (a hybrid of state-space and attention layers rides this): beside
 the paged K and V, which then cover only the attention layers, the cache may
