@@ -265,9 +265,10 @@ def test_the_dropless_layer_is_a_per_token_loop_under_routing_so_uneven_that_cap
     # one case for each of the layer's three forms: few tokens; a candidate whose busiest expert, kept by every one of the
     # 123 tokens that route, fits the pad; and 137 on one expert, which a call that is no candidate sorts and one that is
     # sends to the sorted form on the device
-    assert 40 <= DENSE_MAX_TOKENS < 144 and padded_candidate(144, 2, held)
-    assert 144 - len(range(0, 144, 7)) <= ROW_PAD < 160 - len(range(0, 160, 7))
     d, f, E = 16, 12, 8
+    # (told the router's eight outputs too: 36 pairs an expert land, and a share of 3 or 4 could get 96 or 72)
+    assert 40 <= DENSE_MAX_TOKENS < 144 and padded_candidate(144, 2, held, E) and padded_candidate(144, 2, held)
+    assert 144 - len(range(0, 144, 7)) <= ROW_PAD < 160 - len(range(0, 160, 7))
     rng = np.random.default_rng(3)
     x = rng.normal(size=(N, d))
     scores = rng.normal(size=(N, E))
@@ -280,7 +281,7 @@ def test_the_dropless_layer_is_a_per_token_loop_under_routing_so_uneven_that_cap
     assert float(dispatched.sum()) < N * k, "TokenDispatcher drops here"
     mask = np.ones((N,), bool)
     mask[::7] = False              # tokens that route nowhere
-    got, counts = jax.jit(lambda *a: dropless_experts(*a, first_held=first, token_mask=jnp.asarray(mask)))(
+    got, counts = jax.jit(lambda *a: dropless_experts(*a, first_held=first, scored=E, token_mask=jnp.asarray(mask)))(
         jnp.asarray(x, jnp.float32), idx, gates, *(jnp.asarray(w, jnp.float32) for w in (w_gate, w_up, w_down)))
     want = _per_token_loop(x, scores, k, w_gate, w_up, w_down, first, held) * mask[:, None]
     assert rel(got, want) < 1e-5

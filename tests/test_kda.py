@@ -337,6 +337,37 @@ def test_a_decode_step_leaves_an_idle_slots_state_and_tail_bit_for_bit_and_count
     cache.reset()
 
 
+def test_a_share_whose_decode_rows_are_few_an_expert_leaves_the_pad_for_the_grouped_kernel(monkeypatch):
+    """The cell's decode step in small: 3 slots x 4 experts a token over 8 held of 32 are 12 pairs, 1.5 a HELD expert and
+    0.4 a SCORED one.  With all-on-all out of the way and a pad worth taking from 1 row an expert on, the rule that read
+    ``N k / held`` made the step a padded candidate; told the router's 32 outputs it is the sorted form alone, the grouped
+    kernel's, and the engine's latches and counters, which repeat the rule on the host, say so."""
+    monkeypatch.setattr(dropless, "DENSE_MAX_TOKENS", 0)
+    monkeypatch.setattr(dropless, "PADDED_MIN_MEAN_ROWS", 1)
+    monkeypatch.setenv("VESCALE_KERNELS", "interpret")
+    cfg = toy_config()
+    k, held, scored = cfg.num_experts_per_tok, cfg.experts_held, cfg.num_experts
+    assert (k, held, scored) == (4, 8, 32)
+    assert dropless.padded_candidate(SLOTS, k, held) and not dropless.padded_candidate(SLOTS, k, held, scored)
+    mesh = DeviceMesh(("tp",), (1,), devices=jax.devices()[:1])
+    params = jax.jit(lambda key: lh.init_params(cfg, key))(jax.random.key(7))
+    cache = PagedKVCache(hybrid_cache_config(cfg, num_slots=SLOTS, page_size=PAGE, pages_per_slot=PAGES), mesh)
+    engine = HybridServeEngine(cfg, mesh, params, cache)
+    assert not engine._decode_padded_candidate and engine._expert_layers == 6
+    assert engine._grouped_layers == {SLOTS: 6}, "a rung's rows are 2 to 8 a scored expert: candidates still, here"
+    slot = cache.alloc(9, 4)
+    np.asarray(engine.prefill(tokens(3, 9), slot))
+    cache.commit_prefill(slot, 9)
+    for tok in (5, 6, 7):
+        toks = np.zeros((cache.num_slots,), np.int32)
+        toks[slot] = tok
+        np.asarray(engine.decode(toks)[slot])
+        cache.advance(slot)
+    c = engine.trace_counters()
+    assert c["decode_steps"] == 3 and c["moe_layer_steps"] == 3 * 6 and c["moe_padded_layer_steps"] == 0
+    assert c["moe_expert_layer_calls"] == (1 + 3) * 6 and c["moe_grouped_layer_calls"] == 3 * 6, "every decode launch's"
+
+
 def test_the_modules_side_of_the_seam(system):
     cfg, _mesh, _params, cache, engine = system
     assert cfg.latent_layers == (4,) and cfg.delta_layers == (0, 1, 2, 3, 5, 6) and cfg.groups_held == (0,)

@@ -1,4 +1,4 @@
-"""The serve programs of the seven families behind ``HybridServeEngine`` are WHAT
+"""The serve programs of the eight families behind ``HybridServeEngine`` are WHAT
 THEIR FUNCTIONS COMPUTE, not where those are written: each family's prefill at
 its first two rungs and its decode step, at the toy widths of the family's own
 test file in the type they are served in (bfloat16), on both legs
@@ -8,7 +8,7 @@ kernels a TPU compiles, through the interpreter), traced and lowered, never run.
 Pinned per program: a digest of the jaxpr, kernel bodies and all (it holds no
 source location), and how many operations of the lowered module lie under each
 ``jax.named_scope`` the benchmark's per-layer readers match (``vs.attn``,
-``vs.moe``, ``vs.mamba``, ``vs.mlp``, ``vs.unmask``): a scope is not in a
+``vs.moe``, ``vs.mamba``, ``vs.mlp``, ``vs.unmask``, ``vs.kda``, ``vs.mla``, ``vs.routed``): a scope is not in a
 jaxpr's text.  Taken on the parent of the PR that gave the shared blocks one
 home each (e1809cb: this file on that tree, ``PROGRAMS`` printed by ``python
 tests/test_program_identity.py``).  A PR that changes these programs on purpose
@@ -18,7 +18,16 @@ anew by PR 52, whose prefill also returns its row's argmax: the scopes did not m
 by PR 55 with its four RIDING rungs, the step that carries a prompt: its engine launches no prefill; ``granite_hybrid``'s four
 likewise by PR 62 (its two decode steps held: the expert layer hands its callers the kept ids now, and the step drops them).  ``longcat_flash``'s six were
 taken on the tree of the PR that brought the family and moved the latent-attention block from ``models/deepseek_v2.py``
-to ``models/mla.py``, PR 54: ``deepseek_v2``'s six held through the move, digest and scopes, as they stand here.)"""
+to ``models/mla.py``, PR 54: ``deepseek_v2``'s six held through the move, digest and scopes, as they stand here.
+PR 64 told the expert layer how many outputs the router scores (``moe/dropless.py``): ``sdar_moe``'s, ``laguna``'s and
+``falcon_h1``'s eighteen HELD untouched, the proof that a tree that holds every expert, and a dense one, runs the programs
+it ran; so did every XLA-leg program and every decode step (the toys' rows are all-on-all there, which no count of
+experts moves).  THREE were taken anew, each a second rung on the kernels' leg of a toy that holds a SHARE, where the
+grouped kernel's row tile now follows ``N k / E``, a tile of 32 -> 16 each: ``granite_hybrid``'s second riding rung (19
+rows x 3 over 4 held of 8; at the cell's size no rung of Granite's moves), ``mimo_v2``'s (16 x 4 over 4 of 16) and
+``longcat_flash``'s (16 x 4 over 4 of 16 + 8 outputs); ``deepseek_v2``'s and the first rungs sit at the smallest tile
+either way.  ``ling_hybrid``'s six are new here (``tests/test_kda.py``'s toy): the family whose decode
+step PR 64 moved from the padded candidate to the grouped kernel at the cell's size is pinned from here on.)"""
 
 import collections
 import dataclasses
@@ -32,6 +41,7 @@ import pytest
 import test_deepseek_v2
 import test_falcon_h1
 import test_granite_hybrid
+import test_kda
 import test_laguna
 import test_longcat_flash
 import test_mimo_v2
@@ -42,7 +52,8 @@ from vescale_tpu.serve import HybridServeEngine, PagedKVCache
 from vescale_tpu.serve.hybrid_engine import hybrid_cache_config
 
 FAMILIES = {"granite_hybrid": test_granite_hybrid, "deepseek_v2": test_deepseek_v2, "sdar_moe": test_sdar_moe,
-            "falcon_h1": test_falcon_h1, "laguna": test_laguna, "mimo_v2": test_mimo_v2, "longcat_flash": test_longcat_flash}
+            "falcon_h1": test_falcon_h1, "laguna": test_laguna, "mimo_v2": test_mimo_v2, "longcat_flash": test_longcat_flash,
+            "ling_hybrid": test_kda}
 LEGS = {"xla_legs": None, "kernels_interpreted": "interpret"}
 WHICH = ("prefill_rung_1", "prefill_rung_2", "decode")
 # a family whose engine offers a ride launches no prefill program: its rungs are the step that carries a prompt
@@ -53,7 +64,7 @@ PROGRAMS = {
     "granite_hybrid/xla_legs/riding_rung_2": ('4ce44932afd9c4ab', 'vs.attn=172 vs.mamba=240 vs.moe=80'),
     "granite_hybrid/xla_legs/decode": ('648f82e75f10971f', 'vs.attn=106 vs.mamba=309 vs.moe=160'),
     "granite_hybrid/kernels_interpreted/riding_rung_1": ('5a75ab3126606170', 'vs.attn=636 vs.mamba=216 vs.moe=80'),
-    "granite_hybrid/kernels_interpreted/riding_rung_2": ('e15405ca3830dc48', 'vs.attn=636 vs.mamba=216 vs.moe=80'),
+    "granite_hybrid/kernels_interpreted/riding_rung_2": ('fc4d83b3efb4bdd5', 'vs.attn=636 vs.mamba=216 vs.moe=80'),
     "granite_hybrid/kernels_interpreted/decode": ('f67535cadf866b5f', 'vs.attn=69 vs.mamba=255 vs.moe=160'),
     "deepseek_v2/xla_legs/prefill_rung_1": ('00bd63cd35773518', 'vs.attn=408 vs.mlp=9 vs.moe=108'),
     "deepseek_v2/xla_legs/prefill_rung_2": ('592f6fda2c03d3fa', 'vs.attn=408 vs.mlp=9 vs.moe=108'),
@@ -83,14 +94,20 @@ PROGRAMS = {
     "mimo_v2/xla_legs/prefill_rung_2": ('c605149277dc5904', 'vs.attn=990 vs.mlp=9 vs.moe=132'),
     "mimo_v2/xla_legs/decode": ('46088ea8d8664d65', 'vs.attn=1257 vs.mlp=9 vs.moe=216'),
     "mimo_v2/kernels_interpreted/prefill_rung_1": ('2566616dac8eeeeb', 'vs.attn=4916 vs.mlp=9 vs.moe=132'),
-    "mimo_v2/kernels_interpreted/prefill_rung_2": ('44cfbf93fd2828e4', 'vs.attn=4916 vs.mlp=9 vs.moe=132'),
+    "mimo_v2/kernels_interpreted/prefill_rung_2": ('a444b9b80f8a4d09', 'vs.attn=4916 vs.mlp=9 vs.moe=132'),
     "mimo_v2/kernels_interpreted/decode": ('e1b4216a59e292bd', 'vs.attn=931 vs.mlp=9 vs.moe=216'),
     "longcat_flash/xla_legs/prefill_rung_1": ('0e64cd6b04187dea', 'vs.attn=564 vs.mlp=36 vs.moe=68'),
     "longcat_flash/xla_legs/prefill_rung_2": ('37d4316b11599d06', 'vs.attn=564 vs.mlp=36 vs.moe=68'),
     "longcat_flash/xla_legs/decode": ('02f28e4b8fa49840', 'vs.attn=704 vs.mlp=36 vs.moe=74'),
     "longcat_flash/kernels_interpreted/prefill_rung_1": ('dc1e4a16dd58625c', 'vs.attn=2404 vs.mlp=36 vs.moe=68'),
-    "longcat_flash/kernels_interpreted/prefill_rung_2": ('3cd144778ac50ded', 'vs.attn=2404 vs.mlp=36 vs.moe=68'),
+    "longcat_flash/kernels_interpreted/prefill_rung_2": ('023cc3800daad893', 'vs.attn=2404 vs.mlp=36 vs.moe=68'),
     "longcat_flash/kernels_interpreted/decode": ('1b33297552cdd061', 'vs.attn=556 vs.mlp=36 vs.moe=74'),
+    "ling_hybrid/xla_legs/prefill_rung_1": ('a8861740d47c520a', 'vs.kda=1068 vs.mla=120 vs.mlp=9 vs.moe=354 vs.routed=300'),
+    "ling_hybrid/xla_legs/prefill_rung_2": ('43d293b14ab99c53', 'vs.kda=1068 vs.mla=120 vs.mlp=9 vs.moe=354 vs.routed=300'),
+    "ling_hybrid/xla_legs/decode": ('cc7ddadf941eb3a4', 'vs.kda=1110 vs.mla=152 vs.mlp=9 vs.moe=420 vs.routed=366'),
+    "ling_hybrid/kernels_interpreted/prefill_rung_1": ('cb9e4fae6a5c4dc6', 'vs.kda=864 vs.mla=580 vs.mlp=9 vs.moe=354 vs.routed=300'),
+    "ling_hybrid/kernels_interpreted/prefill_rung_2": ('7f3b56534711a180', 'vs.kda=864 vs.mla=580 vs.mlp=9 vs.moe=354 vs.routed=300'),
+    "ling_hybrid/kernels_interpreted/decode": ('04ec5d6b02ca5287', 'vs.kda=942 vs.mla=115 vs.mlp=9 vs.moe=420 vs.routed=366'),
 }
 
 

@@ -275,10 +275,30 @@ def test_kda_chunk_compiles_at_the_cells_rungs(chip, T):
 def test_lings_decode_program_compiles_at_the_cells_size_with_no_copy_of_pool_or_state(chip):
     """``ling3flash_serve_longgen``'s decode step (256 slots x 16,384 positions): six ``kda_step`` over the states in place
     and ONE ``paged_decode_latent`` at 32 heads over pages of 32 (a page table of 512 KB: pages of 16 would need 1 MiB of
-    scalar memory, which the compiler refuses); the expert layers are XLA's (a padded candidate)."""
+    scalar memory, which the compiler refuses); the six expert layers are the grouped kernel (PR 64: 256 rows x 8 over 64
+    of the 512 experts the router scores are 4 rows an expert, under the pad's lower bound, where ``N k / held`` read 32
+    and made the step a padded candidate), and the engine's latches, which repeat the rule on the host, say the same."""
     import re
+    from unittest import mock
 
-    family, config, sizes, programs = _cells_programs(chip, "ling3flash_serve_longgen")
+    from vescale_tpu.moe import dropless
+    from vescale_tpu.serve import HybridServeEngine
+
+    built, init = [], HybridServeEngine.__init__
+
+    def noted(self, *args, **kwargs):
+        init(self, *args, **kwargs)
+        built.append(self)
+
+    with mock.patch.object(HybridServeEngine, "__init__", noted):
+        family, config, sizes, programs = _cells_programs(chip, "ling3flash_serve_longgen")
+    (engine,) = built
+    c, S = engine.config, engine.cache.num_slots
+    assert (S, c.num_experts_per_tok, c.experts_held, c.num_experts, engine._expert_layers) == (256, 8, 64, 512, 6)
+    assert dropless.padded_candidate(S, c.num_experts_per_tok, c.experts_held), "by N k / held it was one"
+    assert not engine._decode_padded_candidate and engine._grouped_layers[S] == 6
+    # ... and every rung past all-on-all is the kernel's alone: no program of the cell holds the pad
+    assert engine._grouped_layers == {rows: 6 for rows in (S, *engine.buckets) if rows > dropless.DENSE_MAX_TOKENS}
     titles = [title for title, _ in programs]
     assert sum("prefill, rung of" in t for t in titles) == 16 and "decode step, 256 slots x 16384 positions" in titles[-1]
     assert sizes["weights_bytes"] == family.weight_bytes(config)
@@ -287,7 +307,7 @@ def test_lings_decode_program_compiles_at_the_cells_size_with_no_copy_of_pool_or
     compiled = programs[-1][1].compile()
     text = compiled.as_text()
     kernel_calls = re.findall(r"%([a-z_.]+?)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
-    assert sorted(kernel_calls) == ["kda_step"] * 6 + ["paged_decode_latent"]
+    assert sorted(kernel_calls) == ["grouped_swiglu"] * 6 + ["kda_step"] * 6 + ["paged_decode_latent"]
     _assert_in_place_and_fits(compiled, sizes, "bf16[1,61440,32,1,640]")
     for held in ("bf16[1,61440,32,640]", "f32[6,256,32,128,128]", "bf16[6,256,3,12288]"):
         assert not [line for line in text.splitlines() if " copy(" in line and f"= {held}" in line], held
@@ -313,23 +333,34 @@ def test_paged_decode_folded_compiles_at_ten_key_heads(chip, L, pages, Pmax):
 
 
 # ---------------------------------------------------------- grouped SwiGLU
-# (rung, choices a token, held experts, hidden, an expert's width): the four MoE cells' expert layers at the shortest and
-# the longest rung that takes the sorted form (Laguna's 256 and 8192, SDAR's 256 and 2048, Granite's 512, DeepSeek-V2's
-# 8192, whose three matrices of 47 MB pass in tiles over the width)
-GROUPED_CASES = {"laguna-256": (256, 8, 256, 2048, 512), "laguna-8192": (8192, 8, 256, 2048, 512), "sdar-256": (256, 8, 128, 2048, 768),
-                 "sdar-2048": (2048, 8, 128, 2048, 768), "granite-512": (512, 10, 36, 4096, 768), "deepseekv2-8192": (8192, 6, 40, 5120, 1536),
+# (rung, choices a token, held experts, outputs the router scores, hidden, an expert's width): the MoE cells' expert layers at
+# the shortest and the longest rung that takes the sorted form (Laguna's 256 and 8192, SDAR's 256 and 2048, Granite's 512,
+# DeepSeek-V2's 8192, whose three matrices of 47 MB pass in tiles over the width)
+GROUPED_CASES = {"laguna-256": (256, 8, 256, 256, 2048, 512), "laguna-8192": (8192, 8, 256, 256, 2048, 512),
+                 "sdar-256": (256, 8, 128, 128, 2048, 768), "sdar-2048": (2048, 8, 128, 128, 2048, 768),
+                 "granite-512": (512, 10, 36, 72, 4096, 768), "deepseekv2-8192": (8192, 6, 40, 160, 5120, 1536),
                  # LongCat-Flash's experts of 6144 x 2048, the widest hidden so far (75 MB an expert: tiles of 512 over the width),
                  # at the shortest sorted rung and at the longest piece a rung goes through in (models/longcat_flash.py)
-                 "longcat-256": (256, 12, 16, 6144, 2048), "longcat-1024": (1024, 12, 16, 6144, 2048)}
+                 "longcat-256": (256, 12, 16, 768, 6144, 2048), "longcat-1024": (1024, 12, 16, 768, 6144, 2048),
+                 # Ling's decode step and its 1,024 rung, 64 of 512 experts held (PR 64): 4 and 16 rows an expert, row tiles of
+                 # 16 and 32 where ``N k / held`` read 32 and 128 rows (the pad, and a tile of 128)
+                 "ling-decode-256": (256, 8, 64, 512, 2560, 768), "ling-1024": (1024, 8, 64, 512, 2560, 768),
+                 # ... and the small row tiles a share now gives experts that stream: MiMo's decode step (16 of 256: a tile of 16
+                 # where it was 256) and DeepSeek-V2's 1,024 rung (40 of 160: 64)
+                 "mimo-decode-256": (256, 8, 16, 256, 4096, 2048), "deepseekv2-1024": (1024, 6, 40, 160, 5120, 1536)}
+STREAMED = ("deepseekv2-8192", "longcat-256", "longcat-1024", "mimo-decode-256", "deepseekv2-1024")     # an expert's matrices pass in tiles over ``f``, once a row tile
 
 
 @pytest.mark.parametrize("case", GROUPED_CASES)
 def test_grouped_swiglu_compiles_at_the_cells_widths_and_rungs(chip, case):
     from vescale_tpu.kernels.grouped_swiglu import grouped_swiglu, row_tiles, tiles
+    from vescale_tpu.moe import dropless
 
-    N, k, held, d, f = GROUPED_CASES[case]
-    tm, tf = tiles(d, f, bf16, N * k / held)
-    assert (tf == f) == (case not in ("deepseekv2-8192", "longcat-256", "longcat-1024")) and f % tf == 0 and tm % 16 == 0
+    N, k, held, E, d, f = GROUPED_CASES[case]
+    assert dropless.expert_form(N, k, held, E) == dropless.SORTED
+    tm, tf = tiles(d, f, bf16, N * k / E)       # the row tile follows the rows that land, as ``moe/dropless.py`` asks for it
+    assert (tf == f) == (case not in STREAMED) and f % tf == 0 and tm % 16 == 0
+    assert {"ling-decode-256": 16, "ling-1024": 32, "longcat-256": 16, "longcat-1024": 32, "deepseekv2-8192": 256}.get(case, tm) == tm
     sds = lambda shape, dt=bf16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
     rows = row_tiles(N * k, held, tm) * tm
     compiled = grouped_swiglu.lower(sds((rows, d)), sds((held,), jnp.int32), sds((held, d, f)), sds((held, d, f)), sds((held, f, d)),
@@ -706,7 +737,7 @@ def test_granites_step_that_carries_a_prompt_compiles_at_the_cells_size_with_one
                                             i32(S), i32(rung), i32(), i32(rung // page), i32())
     assert engine.rides and engine.kernel_decode and engine.kernel_ssm_step and (S, rung) == (64, 256)
     # which form the expert layer holds at each rung's rows, the step's beside them (and the step's alone): by the shapes
-    forms = [dropless.expert_form(rows, c.num_experts_per_tok, c.experts_held) for rows in (S, *engine._prompt_rows.values())]
+    forms = [dropless.expert_form(rows, c.num_experts_per_tok, c.experts_held, c.num_experts) for rows in (S, *engine._prompt_rows.values())]
     assert forms == [dropless.ALL_ON_ALL, dropless.PADDED_OR_SORTED] + [dropless.SORTED] * 3
     assert engine._grouped_layers == {rows: 10 for rows in (S + 512, S + 1024, S + 1536)}
     assert lowered.as_text().lstrip().startswith("module @jit_decode ")

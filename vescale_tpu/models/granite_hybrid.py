@@ -511,7 +511,7 @@ def serve_ride(c: GraniteHybridConfig, params, arrays, table, lengths, tokens, p
     last = jax.lax.dynamic_index_in_dim(x, S + length - 1, axis=0, keepdims=True)
     logits = head(c, params, jnp.concatenate([x[:S], last]))
     counts = {"experts": jnp.stack(of_the_step)}
-    if padded_candidate(S + rung, c.num_experts_per_tok, c.experts_held):
+    if padded_candidate(S + rung, c.num_experts_per_tok, c.experts_held, c.num_experts):
         counts["ride_fits_pad"] = jnp.stack(fits)
     return logits[:S], logits[S], counts, {"k": kd, "v": vd, "ssm": ssm, "conv": conv}
 

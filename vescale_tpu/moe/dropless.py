@@ -19,7 +19,10 @@ see them as work, and a token's pairs on real experts are then FEWER than ``k``
 and vary from token to token.
 
 Three forms, one result; the choice reads shapes and counts, never a knob or a
-model's name.  What decides is how many ROWS AN EXPERT gets.
+model's name.  What decides is how many ROWS AN EXPERT gets: of ``N`` tokens
+with ``k`` experts each, ``N k / E`` on the mean where the router scores ``E``
+outputs, whatever share of them is held here, and at most all ``N k`` over the
+``held`` (a router may send every pair here).
 
 * *All on all.*  At most ``DENSE_MAX_TOKENS`` tokens (a decode step of one
   position a slot): every held expert runs on every token as one batched
@@ -68,14 +71,24 @@ model's name.  What decides is how many ROWS AN EXPERT gets.
 
 The rule.  ``N <= DENSE_MAX_TOKENS``: all on all.  Otherwise, if the pairs
 would fill a quarter of the pad on average and fit it with room for a router's
-unevenness (:func:`padded_candidate`: ``held x PADDED_MIN_MEAN_ROWS <= N k <=
+unevenness (:func:`padded_candidate`: ``E x PADDED_MIN_MEAN_ROWS <= N k <=
 held x PADDED_MAX_MEAN_ROWS``), the call
 holds both other forms and chooses ON THE DEVICE, from the counts it has
 (:func:`fits_pad`: the busiest held expert got at most ``ROW_PAD`` rows: the
 padded form; else the sorted one on its XLA leg, same result: with the kernel
 in the branch that is rarely taken, the compiler built the padded branch
 slower at one model's widths).  Otherwise the sorted form alone, the grouped
-kernel on TPU.
+kernel on TPU.  The two bounds count different rows.  The LOWER one is of
+speed, "is the pad mostly zeros?", and so of the rows that really land: the
+mean over ALL the ``E`` outputs the router scores, ``N k / E``, which a tree
+that holds a share (``held < E``) gets on each held expert as any other tree
+does.  The UPPER one is of fit, "has every pair a place?", and so of the most
+that may land: all ``N k`` on the ``held``; a candidate that does not fit falls
+to the ``ragged_dot`` leg, the slowest there is, so this bound does not move with
+the share.  Told ``E``, a call can therefore only LEAVE the padded form for the
+sorted one (256 rows x 8 over 64 of 512 experts: 4 rows an expert, not 32), and
+none enters it.  The same mean sizes the grouped kernel's row tile; the layout's
+SIZE stays the one that has a place for every pair.
 
 ``moe.layer.MoEMLP`` / ``TokenDispatcher`` (capacity, one-hot masks, expert
 biases) stay as they are for training; ROADMAP D4 moves them here.
@@ -96,13 +109,14 @@ __all__ = ["route_topk", "route_group_limited", "route_sigmoid_topk", "route_sig
 DENSE_MAX_TOKENS = 128
 # the padded form's places an expert: the same row tile, so a touched expert costs no more rows than the grouped product spends
 ROW_PAD = 128
-# pairs a held expert (N k / held) up to which a call holds the padded form.  PERF.md section 6, PR 37, "crossover": at 16 / 32 /
-# 64 / 96 / 128 rows an expert the padded form streamed the weights 2.1-3.4 times as fast as the sorted one wherever it fit, so
-# the bound is one of FIT, not of speed: a uniform router's busiest of 128 experts gets mean + 2.6 sqrt(mean), past the pad from
-# a mean of about 100 on, and such a call would compile a branch it never takes
+# pairs a HELD expert (N k / held: every pair may land here) up to which a call holds the padded form.  PERF.md section 6, PR 37,
+# "crossover": at 16 / 32 / 64 / 96 / 128 rows an expert the padded form streamed the weights 2.1-3.4 times as fast as the sorted
+# one wherever it fit, so the bound is one of FIT, not of speed: a uniform router's busiest of 128 experts gets mean + 2.6
+# sqrt(mean), past the pad from a mean of about 100 on, and such a call would compile a branch it never takes
 PADDED_MAX_MEAN_ROWS = 96
-# ... and from which: below a quarter of the pad three quarters of every padded array are zeros, and the grouped kernel, whose
-# row tile follows the rows, streams the weights faster (PERF.md section 6, PR 46, the table of three forms)
+# ... and the pairs a SCORED expert (N k / E, the rows that really land on an expert, held or not) from which: below a quarter of
+# the pad three quarters of every padded array are zeros, and the grouped kernel, whose row tile follows the rows, streams the
+# weights faster (PERF.md section 6, PR 46, the table of three forms; PR 64: 4 rows an expert over 64 of 512)
 PADDED_MIN_MEAN_ROWS = 32
 # what a call holds: one form, or the padded and the sorted one under a choice on the device
 ALL_ON_ALL, SORTED, PADDED_OR_SORTED = "all_on_all", "sorted", "padded_or_sorted"
@@ -228,16 +242,18 @@ def identity_experts(x, idx, gates, *, first_identity: int, token_mask: Optional
     return weight * x.astype(jnp.float32), jnp.sum(zero, dtype=jnp.int32)
 
 
-def padded_candidate(N: int, k: int, held: int) -> bool:
-    """The static half of the choice: may a call of ``N`` tokens with ``k`` experts each over ``held`` held experts
-    take the padded form?  (The other half is :func:`fits_pad`, of the counts.)"""
-    return N > DENSE_MAX_TOKENS and held * PADDED_MIN_MEAN_ROWS <= N * k <= held * PADDED_MAX_MEAN_ROWS
+def padded_candidate(N: int, k: int, held: int, scored: Optional[int] = None) -> bool:
+    """The static half of the choice: may a call of ``N`` tokens with ``k`` experts each over ``held`` held experts of
+    the ``scored`` the router has outputs for (None: all of them are held) take the padded form?  (The other half is
+    :func:`fits_pad`, of the counts.)"""
+    scored = held if scored is None else scored
+    return N > DENSE_MAX_TOKENS and scored * PADDED_MIN_MEAN_ROWS <= N * k <= held * PADDED_MAX_MEAN_ROWS
 
 
-def expert_form(N: int, k: int, held: int) -> str:
+def expert_form(N: int, k: int, held: int, scored: Optional[int] = None) -> str:
     """What a call of these shapes holds: ``ALL_ON_ALL``, ``SORTED``, or ``PADDED_OR_SORTED`` (both, under the
     choice on the device)."""
-    return ALL_ON_ALL if N <= DENSE_MAX_TOKENS else PADDED_OR_SORTED if padded_candidate(N, k, held) else SORTED
+    return ALL_ON_ALL if N <= DENSE_MAX_TOKENS else PADDED_OR_SORTED if padded_candidate(N, k, held, scored) else SORTED
 
 
 def grouped_leg(dtype, d: int, f: int) -> Optional[bool]:
@@ -255,23 +271,25 @@ def fits_pad(counts):
     return counts.max(axis=-1) <= ROW_PAD
 
 
-def dropless_experts(x, idx, gates, w_gate, w_up, w_down, *, first_held: int = 0,
+def dropless_experts(x, idx, gates, w_gate, w_up, w_down, *, first_held: int = 0, scored: Optional[int] = None,
                      token_mask: Optional[jax.Array] = None, dtype=None):
     """``sum over kept and held e of g_e * W_down,e (silu(W_gate,e x) * W_up,e x)``.
 
     ``x`` (N, d) tokens; ``idx`` / ``gates`` (N, k) from :func:`route_topk`,
     ids over ALL experts; ``w_gate`` / ``w_up`` (held, d, f) and ``w_down``
-    (held, f, d) the held experts' SwiGLU weights, no biases; ``token_mask``
-    (N,) bool, False for tokens that route nowhere; ``dtype`` the products'
-    operand type (default: the weights').  Returns the result (N, d) float32
-    and the tokens each held expert got (held,) int32.
+    (held, f, d) the held experts' SwiGLU weights, no biases; ``scored`` how
+    many outputs the router that gave ``idx`` scores (None: the held ones are
+    all); ``token_mask`` (N,) bool, False for tokens that route nowhere;
+    ``dtype`` the products' operand type (default: the weights').  Returns the
+    result (N, d) float32 and the tokens each held expert got (held,) int32.
     """
     held, d, f = w_gate.shape
     N, k = idx.shape
-    form = expert_form(N, k, held)
+    scored = held if scored is None else scored
+    form = expert_form(N, k, held, scored)
     dtype = w_gate.dtype if dtype is None else dtype
-    return _experts(x, idx, gates, w_gate, w_up, w_down, token_mask, first_held=first_held, dtype=dtype, form=form,
-                    grouped=grouped_leg(dtype, d, f) if form == SORTED else None)
+    return _experts(x, idx, gates, w_gate, w_up, w_down, token_mask, first_held=first_held, scored=scored, dtype=dtype,
+                    form=form, grouped=grouped_leg(dtype, d, f) if form == SORTED else None)
 
 
 def routed_experts(h, router, route: Callable, w_gate, w_up, w_down, *, first_held: int = 0,
@@ -283,8 +301,8 @@ def routed_experts(h, router, route: Callable, w_gate, w_up, w_down, *, first_he
     Returns ``(result, counts, *what route gave beside ids and gates)``."""
     scores = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
     idx, gates, *own = route(scores)
-    return (*dropless_experts(h, idx, gates, w_gate, w_up, w_down, first_held=first_held, token_mask=token_mask,
-                              dtype=dtype), *own)
+    return (*dropless_experts(h, idx, gates, w_gate, w_up, w_down, first_held=first_held, scored=router.shape[-1],
+                              token_mask=token_mask, dtype=dtype), *own)
 
 
 def row_pieces(N: int, k: int, d: int) -> int:
@@ -312,8 +330,8 @@ def in_row_pieces(rows: Callable, h, token_mask: Optional[jax.Array], *, k: int)
 
 # jitted inside its caller's program: a model's layers have one shape, so the layer is traced and lowered once a
 # program and not once a layer (a call that holds two forms is twice the text; warm set-up is tracing and lowering)
-@functools.partial(jax.jit, static_argnames=("first_held", "dtype", "form", "grouped"))
-def _experts(x, idx, gates, w_gate, w_up, w_down, token_mask, *, first_held, dtype, form, grouped):
+@functools.partial(jax.jit, static_argnames=("first_held", "scored", "dtype", "form", "grouped"))
+def _experts(x, idx, gates, w_gate, w_up, w_down, token_mask, *, first_held, scored, dtype, form, grouped):
     held, d, f = w_gate.shape
     N, k = idx.shape
     local = idx - first_held
@@ -358,7 +376,11 @@ def _experts(x, idx, gates, w_gate, w_up, w_down, token_mask, *, first_held, dty
         # twentieth of a millisecond where a gather of as many from a table is one (PERF.md section 6, PR 46)
         from ..kernels import grouped_swiglu as _grouped
 
-        tm, tf = _grouped.tiles(d, f, dtype, N * k / held)
+        # the row tile follows the rows an expert really gets, the mean over all the router scores (at four cells' widths no
+        # rung was slower for it, with an expert's matrices whole in VMEM or streamed once a row tile: PERF.md section 6, PR
+        # 64); the layout's SIZE is the one with a place for every pair, whatever the router does (the kernel spends
+        # nothing behind the last real tile)
+        tm, tf = _grouped.tiles(d, f, dtype, N * k / scored)
         rows = _grouped.row_tiles(N * k, held, tm) * tm
         fill = jnp.arange(rows - N * k, dtype=jnp.int32)                    # the dummies: tm an expert, then what rounds N k up
         expert, nth = fill // tm, fill % tm

@@ -320,16 +320,18 @@ class HybridServeEngine(DecodeAhead):
         decode_rows = cache.num_slots           # ... a block engine's: every slot's open rows and the commit places'
         if self.block is not None:
             decode_rows = (cache.num_slots + self.block.commit_places(cache.num_slots)) * self.block.B
+        # The expert layers of a program (the params' ``w_gate`` leaves, (held, d, f) each) and the outputs their routers
+        # score (the ``router`` leaves, (d, E): what ``dropless.routed_experts`` reads the same fact from).
         # (a dense model's config names no experts, its steps return no ``counts["experts"]``, and ``moe_*`` stay 0)
-        self._decode_padded_candidate = hasattr(c, "experts_held") and dropless.padded_candidate(
-            decode_rows, c.num_experts_per_tok, c.experts_held)
-        self._fits_pad = dropless.fits_pad
-        # the expert layers of a program (the params' ``w_gate`` leaves, (held, d, f) each) and, by its rows, how many of
-        # them are the grouped kernel outside any choice on the device (the sorted form alone, on the kernel's leg):
-        # latched here, as the programs latch it
         import jax
 
-        experts = [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(params) if getattr(path[-1], "key", None) == "w_gate"]
+        leaves = lambda key: [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(params) if getattr(path[-1], "key", None) == key]
+        experts, routers = leaves("w_gate"), leaves("router")
+        form = lambda rows: dropless.expert_form(rows, c.num_experts_per_tok, c.experts_held, routers[0].shape[-1])
+        self._decode_padded_candidate = bool(experts) and form(decode_rows) == dropless.PADDED_OR_SORTED
+        self._fits_pad = dropless.fits_pad
+        # ... and, by a program's rows, how many of them are the grouped kernel outside any choice on the device (the
+        # sorted form alone, on the kernel's leg): latched here, as the programs latch it
         grouped = bool(experts) and dropless.grouped_leg(c.dtype, *experts[0].shape[1:]) is not None
         self._expert_layers = len(experts)
         self._decode_rows = decode_rows
@@ -339,7 +341,7 @@ class HybridServeEngine(DecodeAhead):
         # (the rows of the program a prompt is launched in: its bucket's, beside every decode row where it rides)
         self._prompt_rows = {bucket: bucket + (decode_rows if self.rides else 0) for bucket in self.buckets}
         self._grouped_layers = {rows: len(experts) for rows in (*self._prompt_rows.values(), decode_rows)
-                                if grouped and dropless.expert_form(rows, c.num_experts_per_tok, c.experts_held) == dropless.SORTED}
+                                if grouped and form(rows) == dropless.SORTED}
         # what this engine has done, in plain integers (``trace_counters``); ``prefill_rides`` only where prompts ride
         self.counter_names = (COUNTERS + (("prefill_rides",) if self.rides else ())
                               + (BLOCK_COUNTERS if self.block is not None else ()) + tuple(self.model.STEP_COUNTERS))
