@@ -93,6 +93,33 @@ def _dormant_tracing():
 
 
 @pytest.fixture
+def traces_and_compiles():
+    """``with traces_and_compiles() as seen:`` counts, by jax's own monitoring events, the jaxprs traced and the
+    programs compiled inside the block: ``seen == {"traced": 0, "compiled": 0}`` is "nothing was built anew"."""
+    import contextlib
+
+    from jax._src import monitoring
+
+    events = {"/jax/core/compile/jaxpr_trace_duration": "traced", "/jax/core/compile/backend_compile_duration": "compiled"}
+
+    @contextlib.contextmanager
+    def counting():
+        seen = {"traced": 0, "compiled": 0}
+
+        def on_event(name, _seconds, **_kw):
+            if name in events:
+                seen[events[name]] += 1
+
+        monitoring.register_event_duration_secs_listener(on_event)
+        try:
+            yield seen
+        finally:
+            monitoring.unregister_event_duration_listener(on_event)
+
+    return counting
+
+
+@pytest.fixture
 def quiet_collector():
     """No collection of the interpreter but the test's own: the automatic
     ones are off while the test runs."""
@@ -115,6 +142,111 @@ def _no_collection_in_the_toy_cells_traced_window(request):
     (``tests/test_trace_session.py`` is where the span itself is tested)."""
     if request.node.path.name == "test_bm_session.py":
         request.getfixturevalue("quiet_collector")
+
+
+# ------------------------------------------------- compiling for the chip, without one (tests/test_tpu_compile*.py)
+@pytest.fixture(scope="module")
+def chip():
+    """Sharding on one described v5e device, with the persistent compile
+    cache off around the module (an entry written for a described chip
+    cannot be read back without one, and warns)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or another process holds it
+        pytest.skip(f"cannot describe a v5e topology: {str(e)[:200]}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+class _JaxOnATpu:
+    """``jax`` as ``ops/flash_attention.py`` sees it, but for the platform of ``jax.devices()[0]``."""
+
+    def __getattr__(self, name):
+        return getattr(jax, name)
+
+    def devices(self, *_args):
+        import types
+
+        return [types.SimpleNamespace(platform="tpu")]
+
+
+class _CellsPrograms:
+    """``cells_programs(cell)``: a serve cell's programs from shapes alone, as ``benchmark/rehearse.py`` lowers them:
+    ``(family, config, sizes, [(title, lowered)], engine)``, the engine being the one the cell's family built for them.
+    Built ONCE a module for a cell and a value of ``VESCALE_KERNELS``: a build lowers EVERY rung of the cell's ladder
+    (4-13 s the first time in a process, less once jax has traced the kernels), and a family's cases each compile one
+    program of the same build."""
+
+    def __init__(self, chip):
+        self.chip, self.built = chip, {}
+
+    def on_a_tpu(self):
+        """The program asks ``jax.devices()`` for its platform and would take its CPU legs here, so this answers for
+        it while a program is traced."""
+        import contextlib
+        import importlib
+        from unittest import mock
+
+        from vescale_tpu import kernels
+
+        flash_ops = importlib.import_module("vescale_tpu.ops.flash_attention")    # (``ops`` exports the function under this name)
+        stack = contextlib.ExitStack()
+        stack.enter_context(mock.patch.object(kernels, "on_tpu", lambda: True))
+        stack.enter_context(mock.patch.object(flash_ops, "jax", _JaxOnATpu()))
+        return stack
+
+    def __call__(self, cell):
+        from vescale_tpu.analysis import envreg
+
+        key = (cell, envreg.get_raw("VESCALE_KERNELS"))
+        if key not in self.built:
+            from unittest import mock
+
+            from benchmark.spec import load_cell
+            from vescale_tpu.serve import HybridServeEngine, ServeEngine
+
+            engines, stack = [], self.on_a_tpu()
+            for cls in (ServeEngine, HybridServeEngine):
+                def noted(self, *args, _init=cls.__init__, **kwargs):
+                    _init(self, *args, **kwargs)
+                    engines.append(self)
+
+                stack.enter_context(mock.patch.object(cls, "__init__", noted))
+            spec = load_cell(cell)
+            family, config = spec.family(), spec.config
+            (device,) = self.chip.device_set
+            with stack:
+                sizes, programs = family.rehearse_serve(spec.name, config, config["serve"], [device])
+            (engine,) = engines
+            self.built[key] = (family, config, sizes, programs, engine)
+        return self.built[key]
+
+    @staticmethod
+    def assert_in_place_and_fits(compiled, sizes, pool):
+        """The pools are written in place: no copy of one (``pool``, its shape as
+        the compiled text writes it) to another layout and back around a scatter
+        over the page axis, the cache's bytes aliased, and 16 GB of HBM hold the
+        arguments (weights, pools, state) and the program's temporaries, with room
+        for the logits."""
+        assert not [line for line in compiled.as_text().splitlines() if " copy(" in line and f"= {pool}" in line]
+        memory = compiled.memory_analysis()
+        cache_bytes = sizes["kv_pool_bytes"] + sizes["slot_state_bytes"]
+        assert memory.argument_size_in_bytes >= sum(sizes.values()) and memory.alias_size_in_bytes >= cache_bytes, memory
+        assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9 and memory.temp_size_in_bytes < 0.5e9, memory
+
+
+@pytest.fixture(scope="module")
+def cells_programs(chip):
+    return _CellsPrograms(chip)
 
 
 def _without_cells(bench, later):

@@ -395,6 +395,50 @@ def test_emulator_vs_xla(mesh2d):
     assert len(out) == 8
 
 
+def _eager_collectives():
+    """(id, call over (x, mesh), the same in numpy over the stacked operands) for every eager wrapper of
+    ``vescale_tpu.collectives`` that runs a ``shard_map`` body; ``x`` is ``(8, 8, 6)`` on a mesh of 8."""
+    from vescale_tpu import collectives as C
+
+    return [
+        ("all_reduce-sum", lambda x, m: C.mesh_all_reduce(x, m), lambda a: a.sum(0)),
+        ("all_reduce-max", lambda x, m: C.mesh_all_reduce(x, m, reduce_op="max"), lambda a: a.max(0)),
+        ("all_reduce-avg-unstacked", lambda x, m: C.mesh_all_reduce(x, m, reduce_op="avg", stacked=False), lambda a: a),
+        ("all_gather", lambda x, m: C.mesh_all_gather(x, m, gather_dim=1), lambda a: np.concatenate(list(a), axis=1)),
+        ("reduce_scatter-sum", lambda x, m: C.mesh_reduce_scatter(x, m), lambda a: a.sum(0).reshape(8, 1, 6)),
+        ("reduce_scatter-avg", lambda x, m: C.mesh_reduce_scatter(x, m, reduce_op="avg"), lambda a: a.mean(0).reshape(8, 1, 6)),
+        ("reduce_scatter-max", lambda x, m: C.mesh_reduce_scatter(x, m, reduce_op="max"), lambda a: a.max(0).reshape(8, 1, 6)),
+        ("all_to_all", lambda x, m: C.mesh_all_to_all(x, m, split_dim=0, concat_dim=1),
+         lambda a: np.stack([np.concatenate([a[src, dst:dst + 1] for src in range(8)], axis=1) for dst in range(8)])),
+        ("broadcast", lambda x, m: C.mesh_broadcast(x, m, src_rank=3), lambda a: a[3]),
+        ("ppermute", lambda x, m: C.mesh_ppermute(x, m, shift=3), lambda a: np.roll(a, 3, axis=0)),
+    ]
+
+
+@pytest.mark.parametrize("case", _eager_collectives(), ids=lambda case: case[0])
+def test_an_eager_collective_is_one_compiled_program_and_says_what_numpy_says(mesh1d, traces_and_compiles, case):
+    """Each eager wrapper of ``collectives.py`` gives what the same operation gives in numpy over the stacked
+    per-rank operands, and runs ONE jitted program kept by its definition: a second call with the same definition
+    traces and compiles nothing (a bare ``shard_map`` ran its body a primitive at a time on every call)."""
+    _, call, expected = case
+    stacked = np.random.default_rng(12).normal(size=(8, 8, 6)).astype(np.float32)
+    x = jnp.asarray(stacked)
+    first = np.asarray(call(x, mesh1d))
+    np.testing.assert_allclose(first, expected(stacked), rtol=1e-6, atol=1e-6)
+    with traces_and_compiles() as seen:
+        again = np.asarray(call(x, mesh1d))
+    assert seen == {"traced": 0, "compiled": 0} and np.array_equal(first, again)
+
+
+def test_mesh_scatter_lays_the_chunks_over_the_axis(mesh1d):
+    from vescale_tpu.collectives import mesh_scatter
+
+    full = np.arange(16 * 3, dtype=np.float32).reshape(16, 3)
+    out = mesh_scatter(jnp.asarray(full), mesh1d, scatter_dim=0)
+    np.testing.assert_array_equal(np.asarray(out), full.reshape(8, 2, 3))
+    assert out.sharding.spec[0] == "tp"
+
+
 def test_comm_counts_async_not_double(mesh2d):
     """regression: all-reduce-start/-done pairs count once."""
     from vescale_tpu.debug.comm_mode import _OPCODE_RE, _COLLECTIVE_OPCODES

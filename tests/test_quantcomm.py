@@ -271,6 +271,47 @@ class TestQuantizedCollectives:
             _WARNED_COUNTERPRODUCTIVE.clear()
 
 
+    @pytest.mark.parametrize("wrapper", ["all_reduce_q", "reduce_scatter_q"])
+    def test_a_second_call_with_the_same_definition_builds_nothing(self, mesh1d, traces_and_compiles, wrapper):
+        """An eager quantized collective is ONE compiled program, kept by what defines it (mesh, axis, op, ``block``,
+        ``rounding``, ``acc_dtype``; shape and dtype are ``jit``'s own): the second call traces and compiles nothing
+        (before, a bare ``shard_map`` ran its body a primitive at a time, each a program of its own, on every call),
+        and a call with another ``block`` or ``rounding`` compiles exactly once more.  The stochastic-rounding key is
+        an ARGUMENT of that program: another key is another result and no other program."""
+        call = {"all_reduce_q": all_reduce_q, "reduce_scatter_q": lambda *a, **k: reduce_scatter_q(*a, scatter_dim=0, **k)}[wrapper]
+        x = jnp.asarray(np.random.default_rng(8).normal(size=(8, 64, 16)).astype(np.float32))
+        keys = [jax.random.key(21), jax.random.key(22)]
+        first = np.asarray(call(x, mesh1d, block=64))
+        with traces_and_compiles() as seen:
+            again = np.asarray(call(x, mesh1d, block=64))
+        assert seen == {"traced": 0, "compiled": 0} and np.array_equal(first, again)
+        for other in (dict(block=32), dict(block=64, rounding="stochastic", key=keys[0])):
+            with traces_and_compiles() as seen:
+                once = np.asarray(call(x, mesh1d, **other))
+            assert seen["traced"] >= 1 and seen["compiled"] == 1, (other, seen)
+            with traces_and_compiles() as seen:
+                twice = np.asarray(call(x, mesh1d, **other))
+            assert seen == {"traced": 0, "compiled": 0} and np.array_equal(once, twice), other
+        with traces_and_compiles() as seen:
+            rekeyed = np.asarray(call(x, mesh1d, block=64, rounding="stochastic", key=keys[1]))
+        assert seen == {"traced": 0, "compiled": 0} and not np.array_equal(rekeyed, twice)
+
+    def test_the_knobs_are_read_on_the_host_each_call_and_key_the_program(self, mesh1d, traces_and_compiles, monkeypatch):
+        """``VESCALE_GRAD_COMPRESS_BLOCK`` set between two calls takes effect in the second (one more program) and,
+        unset again, the first program is found, not rebuilt."""
+        x = jnp.asarray(np.random.default_rng(9).normal(size=(8, 512)).astype(np.float32))
+        default = np.asarray(all_reduce_q(x, mesh1d))
+        monkeypatch.setenv("VESCALE_GRAD_COMPRESS_BLOCK", "16")
+        with traces_and_compiles() as seen:
+            finer = np.asarray(all_reduce_q(x, mesh1d))
+        assert seen["compiled"] == 1 and np.array_equal(finer, np.asarray(all_reduce_q(x, mesh1d, block=16)))
+        assert not np.array_equal(finer, default)
+        monkeypatch.delenv("VESCALE_GRAD_COMPRESS_BLOCK")
+        with traces_and_compiles() as seen:
+            assert np.array_equal(np.asarray(all_reduce_q(x, mesh1d)), default)
+        assert seen == {"traced": 0, "compiled": 0}
+
+
 # ============================================================ emulator mode
 class TestEmulatorQuantized:
     def test_bit_for_bit_vs_shard_map(self, mesh1d):

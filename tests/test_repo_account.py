@@ -93,6 +93,26 @@ def test_document_names_only_files_that_exist(doc):
     assert not bad, f"{doc} names files the repo does not hold: {sorted(set(bad))}"
 
 
+# The budgets of the two records every session reads whole (ROADMAP D18): (file's bytes, a line's bytes; a table's
+# row may run longer).  PERF.md had grown to 512 KB in lines of up to 26 KB that no tool shows whole.
+RECORD_BUDGETS = {"PERF.md": (256 * 1024, 6 * 1024), "CHANGES.md": (None, 1536)}
+
+
+@pytest.mark.parametrize("doc", sorted(RECORD_BUDGETS))
+def test_a_record_can_be_opened_whole(doc):
+    whole, a_line = RECORD_BUDGETS[doc]
+    with open(os.path.join(REPO, doc), "rb") as f:
+        text = f.read()
+    fold = ("the budget is written in ROADMAP.md, D18; fold PERF.md's section 6 (a paragraph older than the newest ten "
+            "PRs becomes one line) and strike from section 7 what ROADMAP carries; a CHANGES.md line says what changed, "
+            "where, and the ledger's verdict")
+    size = len(text)
+    assert whole is None or size <= whole, f"{doc} is {size} bytes, over its {whole}: {fold}"
+    long = [(n, len(line)) for n, line in enumerate(text.split(b"\n"), 1)
+            if len(line) > a_line and not line.lstrip().startswith(b"|")]
+    assert not long, f"{doc}: (line, bytes) over {a_line} outside a table: {long}: {fold}"
+
+
 def test_every_registered_knob_is_read_by_some_code():
     from vescale_tpu.analysis import envreg
 

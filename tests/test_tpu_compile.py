@@ -11,36 +11,12 @@ kernel entry points below the platform dispatch (``_flash``,
 ``jax.devices()`` still sees the CPU here.
 """
 
-import os
-
 import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import SingleDeviceSharding
 
 bf16, f32 = jnp.bfloat16, jnp.float32
-
-
-@pytest.fixture(scope="module")
-def chip():
-    """Sharding on one described v5e device, with the persistent compile
-    cache off around the module (an entry written for a described chip
-    cannot be read back without one, and warns)."""
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
-    try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # no TPU compiler here, or another process holds it
-        pytest.skip(f"cannot describe a v5e topology: {str(e)[:200]}")
-    was_on = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", was_on)
-    compilation_cache.reset_cache()
 
 
 def _compile(fn, *shapes):
@@ -272,47 +248,6 @@ def test_kda_chunk_compiles_at_the_cells_rungs(chip, T):
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * T * H * D * 4      # k beta, v beta and the running sums: no state in HBM but the last
 
 
-def test_lings_decode_program_compiles_at_the_cells_size_with_no_copy_of_pool_or_state(chip):
-    """``ling3flash_serve_longgen``'s decode step (256 slots x 16,384 positions): six ``kda_step`` over the states in place
-    and ONE ``paged_decode_latent`` at 32 heads over pages of 32 (a page table of 512 KB: pages of 16 would need 1 MiB of
-    scalar memory, which the compiler refuses); the six expert layers are the grouped kernel (PR 64: 256 rows x 8 over 64
-    of the 512 experts the router scores are 4 rows an expert, under the pad's lower bound, where ``N k / held`` read 32
-    and made the step a padded candidate), and the engine's latches, which repeat the rule on the host, say the same."""
-    import re
-    from unittest import mock
-
-    from vescale_tpu.moe import dropless
-    from vescale_tpu.serve import HybridServeEngine
-
-    built, init = [], HybridServeEngine.__init__
-
-    def noted(self, *args, **kwargs):
-        init(self, *args, **kwargs)
-        built.append(self)
-
-    with mock.patch.object(HybridServeEngine, "__init__", noted):
-        family, config, sizes, programs = _cells_programs(chip, "ling3flash_serve_longgen")
-    (engine,) = built
-    c, S = engine.config, engine.cache.num_slots
-    assert (S, c.num_experts_per_tok, c.experts_held, c.num_experts, engine._expert_layers) == (256, 8, 64, 512, 6)
-    assert dropless.padded_candidate(S, c.num_experts_per_tok, c.experts_held), "by N k / held it was one"
-    assert not engine._decode_padded_candidate and engine._grouped_layers[S] == 6
-    # ... and every rung past all-on-all is the kernel's alone: no program of the cell holds the pad
-    assert engine._grouped_layers == {rows: 6 for rows in (S, *engine.buckets) if rows > dropless.DENSE_MAX_TOKENS}
-    titles = [title for title, _ in programs]
-    assert sum("prefill, rung of" in t for t in titles) == 16 and "decode step, 256 slots x 16384 positions" in titles[-1]
-    assert sizes["weights_bytes"] == family.weight_bytes(config)
-    assert sizes["kv_pool_bytes"] == 61440 * 32 * 1280 and sizes["slot_state_bytes"] == 256 * family.state_bytes_per_slot(config, config["serve"])
-    assert sizes["kv_pool_bytes"] + sizes["slot_state_bytes"] == family.cache_bytes(config, config["serve"])
-    compiled = programs[-1][1].compile()
-    text = compiled.as_text()
-    kernel_calls = re.findall(r"%([a-z_.]+?)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
-    assert sorted(kernel_calls) == ["grouped_swiglu"] * 6 + ["kda_step"] * 6 + ["paged_decode_latent"]
-    _assert_in_place_and_fits(compiled, sizes, "bf16[1,61440,32,1,640]")
-    for held in ("bf16[1,61440,32,640]", "f32[6,256,32,128,128]", "bf16[6,256,3,12288]"):
-        assert not [line for line in text.splitlines() if " copy(" in line and f"= {held}" in line], held
-
-
 # Phi-4-mini-flash's folded rows (ten key heads of 128: no whole number of sublane tiles, so not ``paged_decode``'s pool): the
 # one pool layer at 96 slots x 256 pages and the eight rings as 32 pages a slot, 40 query rows of 128
 @pytest.mark.parametrize("L,pages,Pmax", [(1, 96 * 256 + 1, 256), (8, 96 * 32, 32)], ids=["the-pool", "the-rings-as-pages"])
@@ -386,404 +321,6 @@ def test_head_select_compiles_at_sdars_cell_size_and_leaves_three_vectors(chip):
     assert "tpu_custom_call" in compiled.as_text() and "151936]" in compiled.as_text()
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 1 << 20 and memory.output_size_in_bytes < 1 << 16
-
-
-# ------------------------------------------------- whole programs of a cell
-class _JaxOnATpu:
-    """``jax`` as ``ops/flash_attention.py`` sees it, but for the platform of ``jax.devices()[0]``."""
-
-    def __getattr__(self, name):
-        return getattr(jax, name)
-
-    def devices(self, *_args):
-        import types
-
-        return [types.SimpleNamespace(platform="tpu")]
-
-
-def _cells_programs(chip, cell):
-    """A serve cell's programs from shapes alone, as ``benchmark/rehearse.py``
-    lowers them: ``(family, config, sizes, [(title, lowered)])``.  The program
-    asks ``jax.devices()`` for its platform and would take its CPU legs here, so
-    this answers for it while the programs are traced."""
-    import importlib
-    from unittest import mock
-
-    from benchmark.spec import load_cell
-    from vescale_tpu import kernels
-
-    flash_ops = importlib.import_module("vescale_tpu.ops.flash_attention")    # (``ops`` exports the function under this name)
-    spec = load_cell(cell)
-    family, config = spec.family(), spec.config
-    (device,) = chip.device_set
-    with mock.patch.object(kernels, "on_tpu", lambda: True), mock.patch.object(flash_ops, "jax", _JaxOnATpu()):
-        sizes, programs = family.rehearse_serve(spec.name, config, config["serve"], [device])
-    return family, config, sizes, programs
-
-
-def _assert_in_place_and_fits(compiled, sizes, pool):
-    """The pools are written in place: no copy of one (``pool``, its shape as
-    the compiled text writes it) to another layout and back around a scatter
-    over the page axis, the cache's bytes aliased, and 16 GB of HBM hold the
-    arguments (weights, pools, state) and the program's temporaries, with room
-    for the logits."""
-    assert not [line for line in compiled.as_text().splitlines() if " copy(" in line and f"= {pool}" in line]
-    memory = compiled.memory_analysis()
-    cache_bytes = sizes["kv_pool_bytes"] + sizes["slot_state_bytes"]
-    assert memory.argument_size_in_bytes >= sum(sizes.values()) and memory.alias_size_in_bytes >= cache_bytes, memory
-    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9 and memory.temp_size_in_bytes < 0.5e9, memory
-
-
-@pytest.mark.parametrize("program,kernels_in_it", [("decode step", 12), ("bucket of 512", 6)], ids=["decode", "rung512"])
-def test_falcon_h1s_decode_program_and_a_rung_compile_at_the_cells_size(chip, program, kernels_in_it):
-    """``falconh1_34b_serve_batch``'s decode step (128 slots x 1536 positions:
-    six ``ssm_step`` and six ``paged_decode`` kernels) and the 512 rung of its
-    prefill ladder (six grouped-query flash forwards at 20 / 4 heads)."""
-    family, config, sizes, programs = _cells_programs(chip, "falconh1_34b_serve_batch")
-    titles = [title for title, _ in programs]
-    assert sum("prefill, bucket of" in t for t in titles) == 5 and "decode step, 128 slots x 1536 positions" in titles[-1]
-    assert sizes["weights_bytes"] == family.weight_bytes(config)
-    assert sizes["slot_state_bytes"] == 128 * family.state_bytes_per_slot(config, config["serve"])
-    (lowered,) = [low for title, low in programs if program in title]
-    compiled = lowered.compile()
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == kernels_in_it
-    _assert_in_place_and_fits(compiled, sizes, "bf16[6,12289,16,4,128]")        # 1.2 GB a pool
-
-
-def test_sdars_rung_of_512_compiles_at_the_cells_size_and_writes_its_pools_in_place(chip):
-    """``sdar30b_serve_blockgen``'s 512 rung (six flash forwards under the
-    block mask; 32 rows an expert, so each of the six expert layers is a choice
-    on the device whose fall-back branch is the sorted form's XLA leg, the
-    compiler's own ``ragged-dot``, as before the grouped kernel).  Its pools have Falcon-H1's row, 4 key heads of 128, and
-    go through the same page writer: a scatter cost FOUR copies of a 1.6 GB
-    pool a prefill here (PERF.md section 6, PR 44)."""
-    family, config, sizes, programs = _cells_programs(chip, "sdar30b_serve_blockgen")
-    titles = [title for title, _ in programs]
-    assert sum("prefill, rung of" in t for t in titles) == 6 and "one pass, 128 slots x 4 positions" in titles[-1]
-    assert sizes["weights_bytes"] == family.weight_bytes(config)
-    (lowered,) = [low for title, low in programs if "rung of 512" in title]
-    compiled = lowered.compile()
-    kernel_calls = [line for line in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    assert sum("block_flash_fwd" in line for line in kernel_calls) == 6 and not any("grouped_swiglu" in line for line in kernel_calls)
-    _assert_in_place_and_fits(compiled, sizes, "bf16[6,16385,16,4,128]")        # 1.6 GB a pool
-
-
-@pytest.mark.parametrize("leg", ["kernel", "xla"])
-def test_sdars_pass_compiles_at_the_cells_size_and_holds_no_logits(chip, leg, monkeypatch):
-    """``sdar30b_serve_blockgen``'s decode call (128 slots x 4 open rows and 40
-    places of 4 commit rows: six ``paged_decode``, six expert layers as choices
-    on the device, and ``head_select``).  A pass keeps no logits (PR 47): on the
-    kernel's leg no instruction or output of the program has the logits' shape
-    in either layout, ``f32[128,4,151936]`` (what the parent's program
-    returned, 311 MB, through a 1.5 ms layout copy) or ``f32[512,151936]`` (the
-    head's product); on the XLA leg (``VESCALE_KERNELS=off``) the product is a
-    temporary, and the three-dimensional array is still never formed.  What the
-    program returns in their place is the open rows' hidden state."""
-    if leg == "xla":
-        monkeypatch.setenv("VESCALE_KERNELS", "off")
-    _family, _config, sizes, programs = _cells_programs(chip, "sdar30b_serve_blockgen")
-    (lowered,) = [low for title, low in programs if "one pass, 128 slots x 4 positions" in title]
-    hidden, ids = lowered.out_info[:2]
-    assert (hidden.shape, hidden.dtype, ids.shape) == ((512, 2048), jnp.float32, (128, 4))
-    compiled = lowered.compile()
-    text = compiled.as_text()
-    kernel_calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    assert "f32[128,4,151936]" not in text
-    if leg == "kernel":
-        assert "f32[512,151936]" not in text
-        assert sum("paged_decode" in line for line in kernel_calls) == 6 and sum("head_select" in line for line in kernel_calls) == 1
-        (call,) = [line for line in kernel_calls if "head_select" in line]
-        assert "[512,128]" not in call.split("custom_call_target")[0]           # (an expert layer's signature in the cell's op table)
-        # the kernel asks for no more VMEM than a kernel has: a call that does makes the compiler build EVERY fusion of the
-        # program under another scoped limit (the six expert layers read 0.15 ms slower each: PERF.md section 6, PR 47)
-        assert '"scoped_memory_configs":[{' not in text
-        _assert_in_place_and_fits(compiled, sizes, "bf16[6,16385,16,4,128]")
-    else:                                                                       # (every kernel's XLA leg: the gathered pages are 1.1 GB of temporaries)
-        assert "f32[512,151936]" in text and not any("head_select" in line or "paged_decode" in line for line in kernel_calls)
-        memory = compiled.memory_analysis()
-        assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9, memory
-
-
-@pytest.mark.parametrize("program,kernels_in_it", [("decode step", 5), ("rung of 512 positions", 9)], ids=["decode", "rung512"])
-def test_lagunas_decode_program_and_a_rung_compile_at_the_cells_size_and_copy_no_pool_of_either_kind(chip, program, kernels_in_it):
-    """``lagunaxs2_serve_mixedlen``'s decode step (128 slots: two ``paged_decode``
-    at 48 query heads over the pages, three at 64 over the rings read as pages)
-    and the 512 rung of its prefill ladder (two causal flash forwards, three
-    ``window_flash_fwd``, and the four expert layers' ``grouped_swiglu``: 16
-    rows an expert, the sorted form alone).  Neither holds a copy of a pool of EITHER kind: the
-    full layers' pages (rows of 8 key heads, through ``write_pages``) or the
-    sliding layers' rings (a slot's rows rewritten by one ``dynamic_update_slice``
-    a prefill, one row a slot by a scatter a step, read through a reshape)."""
-    family, config, sizes, programs = _cells_programs(chip, "lagunaxs2_serve_mixedlen")
-    titles = [title for title, _ in programs]
-    assert sum("prefill, rung of" in t for t in titles) == 12 and "decode step, 128 slots x 8192 positions" in titles[-1]
-    assert sizes["weights_bytes"] == family.weight_bytes(config)
-    assert sizes["kv_pool_bytes"] + sizes["slot_state_bytes"] == family.cache_bytes(config, config["serve"])
-    (lowered,) = [low for title, low in programs if program in title]
-    compiled = lowered.compile()
-    kernel_calls = [line for line in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    ours = [line for line in kernel_calls if "ragged-dot" not in line.split(" = ")[0]]      # (a sorted form on its XLA leg is the compiler's own)
-    assert len(ours) == kernels_in_it
-    if "rung" in program:
-        assert sum("window_flash_fwd" in line for line in ours) == 3 and sum("grouped_swiglu" in line for line in ours) == 4
-        assert ours == kernel_calls                             # ... and on the kernel's leg there is none of those
-    else:
-        assert sum("f32[128,64,128]" in line for line in ours) == 3 and sum("f32[128,48,128]" in line for line in ours) == 2
-    _assert_in_place_and_fits(compiled, sizes, "bf16[2,28672,16,8,128]")        # 1.88 GB a pool
-    for ring in ("bf16[3,128,512,8,128]", "bf16[3,4096,16,8,128]"):             # 0.40 GB a ring, as the cache and as the kernel see it
-        assert not [line for line in compiled.as_text().splitlines() if " copy(" in line and f"= {ring}" in line]
-
-
-@pytest.mark.parametrize("program", ["decode step", "rung of 512 positions", "rung of 2048 positions", "rung of 4096 positions"],
-                         ids=["decode", "rung512", "rung2048", "rung4096"])
-def test_longcats_decode_program_and_its_rungs_compile_at_the_cells_size_and_fit_beside_the_weights(chip, program):
-    """``longcatflash_serve_reasoning``'s decode step (128 slots: eight
-    ``paged_decode_latent`` at 64 heads, one a SUBLAYER; its sixteen experts a
-    layer go all on all, no kernel) and three rungs of its prefill ladder (eight
-    ``mla_flash_fwd`` and four ``grouped_swiglu`` over experts of 6144 x 2048: a
-    rung over 1,024 rows takes the routed branch in pieces, so the kernel is
-    there once a layer whatever the rung).  The latent pool is written in place,
-    and the 4,096 rung's temporaries are 1.6 GB beside 13.7 GB of weights and
-    cache (3.0 GB with the branch whole: read before the pieces, PERF.md section
-    6, PR 54)."""
-    family, config, sizes, programs = _cells_programs(chip, "longcatflash_serve_reasoning")
-    titles = [title for title, _ in programs]
-    assert sum("prefill, rung of" in t for t in titles) == 8 and "decode step, 128 slots x 4096 positions" in titles[-1]
-    assert sizes["weights_bytes"] == family.weight_bytes(config) and sizes["slot_state_bytes"] == 0
-    assert sizes["kv_pool_bytes"] == family.cache_bytes(config, config["serve"]) == config["serve"]["pool_pages"] * 16 * 10240
-    (lowered,) = [low for title, low in programs if program in title]
-    compiled = lowered.compile()
-    text = compiled.as_text()
-    kernel_calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    if "rung" in program:
-        assert len(kernel_calls) == 12 and sum("mla_flash_fwd" in line for line in kernel_calls) == 8
-        assert sum("grouped_swiglu" in line for line in kernel_calls) == 4
-    else:
-        assert len(kernel_calls) == 8 and all("paged_decode_latent" in line for line in kernel_calls)
-    pages = config["serve"]["pool_pages"]
-    assert not [line for line in text.splitlines() if " copy(" in line and f"= bf16[8,{pages},16,1,640]" in line]
-    memory = compiled.memory_analysis()
-    assert memory.argument_size_in_bytes >= sum(sizes.values()) and memory.alias_size_in_bytes >= sizes["kv_pool_bytes"], memory
-    assert memory.argument_size_in_bytes < 1.005 * sum(sizes.values()), "no row of the pool padded: 640 is whole lane tiles"
-    assert memory.temp_size_in_bytes < (1.7e9 if "rung" in program else 0.1e9), memory
-    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.4e9, memory
-
-
-@pytest.mark.parametrize("program,kernels_in_it", [("decode step", 13), ("rung of 512 positions", 13)], ids=["decode", "rung512"])
-def test_mimos_decode_program_and_a_rung_compile_at_the_cells_size_with_no_copy_and_no_padding_of_a_pool(chip, program, kernels_in_it):
-    """``mimov25_serve_reasoning``'s decode step (256 slots: two
-    ``paged_decode_kv4`` over the folded pages, five ``paged_decode_kv8`` with a
-    sink over the folded rings read as pages, six ``grouped_swiglu``) and the 512
-    rung of its prefill ladder (two ``causal_flash_fwd`` at 192 | 128, five
-    ``window_flash_fwd`` with a sink, six ``grouped_swiglu``).  Neither holds a
-    copy of a pool of either kind, and the chip lays the folded rows out WITHOUT
-    padding: the program's arguments are the weights' and the cache's logical
-    bytes (5,120 B a position in the pages, 3.28 MB a slot in the rings), where
-    rows of (4, 192) would be padded to 256 lanes a head or turned round."""
-    family, config, sizes, programs = _cells_programs(chip, "mimov25_serve_reasoning")
-    titles = [title for title, _ in programs]
-    assert sum("prefill, rung of" in t for t in titles) == 12 and "decode step, 256 slots x 8192 positions" in titles[-1]
-    assert sizes["weights_bytes"] == family.weight_bytes(config)
-    assert sizes["kv_pool_bytes"] == 23552 * 32 * 5120 and sizes["slot_state_bytes"] == 256 * 3276800
-    assert sizes["kv_pool_bytes"] + sizes["slot_state_bytes"] == family.cache_bytes(config, config["serve"])
-    (lowered,) = [low for title, low in programs if program in title]
-    compiled = lowered.compile()
-    text = compiled.as_text()
-    kernel_calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(kernel_calls) == kernels_in_it and sum("grouped_swiglu" in line for line in kernel_calls) == 6
-    if "rung" in program:
-        assert sum("window_flash_fwd" in line for line in kernel_calls) == 5 and sum("causal_flash_fwd" in line for line in kernel_calls) == 2
-    else:
-        assert sum("paged_decode_kv8" in line for line in kernel_calls) == 5 and sum("paged_decode_kv4" in line for line in kernel_calls) == 2
-    _assert_in_place_and_fits(compiled, sizes, "bf16[2,23552,32,1,768]")        # 2.3 GB of keys
-    for pool in ("bf16[2,23552,32,1,512]", "bf16[2,23552,32,768]", "bf16[2,23552,32,512]", "bf16[5,256,128,1,1536]",
-                 "bf16[5,256,128,1,1024]", "bf16[5,1024,32,1,1536]", "bf16[5,1024,32,1,1024]", "bf16[5,1024,32,1536]",
-                 "bf16[5,1024,32,1024]"):                                       # as the cache and as the kernel see them
-        assert not [line for line in text.splitlines() if " copy(" in line and f"= {pool}" in line]
-    # no pool padded past 5% of its logical bytes: the arguments are the weights, the cache and a few small arrays
-    assert compiled.memory_analysis().argument_size_in_bytes < 1.005 * sum(sizes.values())
-    for row in ("[2,23552,32,1,768]{4,2,3,1,0:T(8,128)(2,1)}", "[5,256,128,1,1536]{4,2,3,1,0:T(8,128)(2,1)}"):
-        assert f"bf16{row}" in text, "a folded row is the lanes and a page's positions the sublanes: whole tiles"
-
-
-def test_deepseeks_step_that_carries_a_prompt_compiles_at_the_cells_size_with_one_product_a_weight(chip):
-    """``deepseek7b_serve_batch``'s decode step with the 128 rung's prompt in it (PR 53: 32 decode rows and 128
-    prompt rows, one array before every weight's product): eight ``paged_decode`` and eight flash forwards, no
-    copy of a pool, and every product of the stack over all 160 rows, the head's over the 32 steps' rows and the
-    prompt's last: a weight crosses the HBM once for both.  (The engine is the one the cell's family builds for
-    ``benchmark/rehearse.py``, from shapes alone.)"""
-    import re
-    from unittest import mock
-
-    from vescale_tpu.serve import ServeEngine
-
-    built, init = [], ServeEngine.__init__
-
-    def noted(self, *args, **kwargs):
-        init(self, *args, **kwargs)
-        built.append(self)
-
-    with mock.patch.object(ServeEngine, "__init__", noted):
-        _family, _config, _sizes, programs = _cells_programs(chip, "deepseek7b_serve_batch")
-        (engine,) = built
-        cache = engine.cache
-        S, page, rung = cache.num_slots, cache.config.page_size, 128
-        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
-        # (traced here too, under the same answers: the flash forward asks for its platform while it is traced)
-        import importlib
-
-        from vescale_tpu import kernels
-
-        flash_ops = importlib.import_module("vescale_tpu.ops.flash_attention")
-        with mock.patch.object(kernels, "on_tpu", lambda: True), mock.patch.object(flash_ops, "jax", _JaxOnATpu()):
-            lowered = engine._ride_fn.lower(
-                engine.params, cache.k.data, cache.v.data, i32(S, cache.config.pages_per_slot), i32(S), i32(S), i32(S),
-                i32(rung), i32(), i32(rung // page), i32())
-    assert engine.rides and engine.kernel_decode and (S, rung) == (32, 128)
-    compiled = lowered.compile()
-    text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 16
-    assert not [line for line in text.splitlines() if " copy(" in line and "= bf16[8,3073,16,32,128]" in line]
-    products = re.findall(r"= bf16\[(\d+),(\d+)\]\S* convolution\(", text)
-    assert len(products) == 7 * 8 + 1 and sorted(set(products)) == [("160", "11008"), ("160", "4096"), ("33", "102400")]
-    memory = compiled.memory_analysis()
-    assert memory.alias_size_in_bytes >= 2 * 8 * 3073 * 16 * 32 * 128 * 2 and memory.temp_size_in_bytes < 64 << 20, memory
-    # ... and the step without a prompt is the program it was: its products over the 32 rows
-    (step,) = [low for title, low in programs if "decode step" in title]
-    assert set(re.findall(r"= bf16\[(\d+),\d+\]\S* convolution\(", step.compile().as_text())) == {"32"}
-
-
-def test_falcon_h1s_step_that_carries_a_prompt_compiles_at_the_cells_size_with_one_product_a_weight(chip):
-    """``falconh1_34b_serve_batch``'s decode step with the 128 rung's prompt in it (PR 55: 128 decode rows and 128
-    prompt rows, one array before every weight's product): six ``ssm_step``, six ``paged_decode`` and six flash
-    forwards, no copy of a pool or of the state, every product of the stack over all 256 rows and none over 128
-    beside it, the head's over the 128 steps' rows and the prompt's last: a weight crosses the HBM once for both.
-    (The engine is the one the cell's family builds for ``benchmark/rehearse.py``, from shapes alone.)"""
-    import importlib
-    import re
-    from unittest import mock
-
-    from vescale_tpu import kernels
-    from vescale_tpu.serve import HybridServeEngine
-
-    built, init = [], HybridServeEngine.__init__
-
-    def noted(self, *args, **kwargs):
-        init(self, *args, **kwargs)
-        built.append(self)
-
-    flash_ops = importlib.import_module("vescale_tpu.ops.flash_attention")
-    with mock.patch.object(HybridServeEngine, "__init__", noted):
-        _family, _config, sizes, programs = _cells_programs(chip, "falconh1_34b_serve_batch")
-        (engine,) = built
-        cache = engine.cache
-        S, page, rung = cache.num_slots, cache.config.page_size, 128
-        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
-        # (traced here too, under the same answers: the flash forward asks for its platform while it is traced)
-        with mock.patch.object(kernels, "on_tpu", lambda: True), mock.patch.object(flash_ops, "jax", _JaxOnATpu()):
-            lowered = engine._ride_fn.lower(engine.params, *engine._held(), i32(S, cache.config.pages_per_slot), i32(S), i32(S),
-                                            i32(S), i32(rung), i32(), i32(rung // page), i32())
-    assert engine.rides and engine.kernel_decode and engine.kernel_ssm_step and (S, rung) == (128, 128)
-    assert lowered.as_text().lstrip().startswith("module @jit_decode ")
-    compiled = lowered.compile()
-    text = compiled.as_text()
-    kernel_calls = re.findall(r"%([a-z_.]+?)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
-    assert sorted(kernel_calls) == ["paged_decode"] * 6 + ["ssm_step"] * 6 + ["vs.attn"] * 6      # (the flash forward bears its scope's name)
-    _assert_in_place_and_fits(compiled, sizes, "bf16[6,12289,16,4,128]")
-    assert not [line for line in text.splitlines() if " copy(" in line and "= f32[6,128,256,4096]" in line], "nor of the state"
-    products = set(re.findall(r"= \w+\[(\d+),(\d+)\]\S* convolution\(", text))
-    # in_proj, q and k/v, o and out_proj and down_proj, gate and up: all 256 rows; the head 128 + 1
-    assert products == {("256", "9248"), ("256", "2560"), ("256", "512"), ("256", "5120"), ("256", "21504"), ("129", "130560")}
-    assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
-    # ... and the step without a prompt is the program it was: its products over the 128 rows
-    (step,) = [low for title, low in programs if "decode step" in title]
-    assert set(re.findall(r"= \w+\[(\d+),\d+\]\S* convolution\(", step.compile().as_text())) == {"128"}
-
-
-def test_granites_step_that_carries_a_prompt_compiles_at_the_cells_size_with_one_product_a_weight(chip):
-    """``granite4hsmall_serve_batch``'s decode step with the 256 rung's prompt in it (PR 62: 64 decode rows and 256
-    prompt rows, one array before every weight's product): nine ``ssm_step``, one ``paged_decode`` and one flash
-    forward, no copy of a pool or of the state, every product of the stack over all 320 rows and none over 64 or 256
-    beside it: ``W_in`` nine times, ``W_out`` / ``W_o`` / ``W_q`` / the shared expert's down 21, its gate and up 20,
-    the router ten, and the 36 held experts' three matrices ONCE a layer, in the padded form that 320 rows x 10 over
-    36 experts make a candidate for; the head's over the 64 steps' rows and the prompt's last.  A weight crosses the
-    HBM once for both, the expert layer's 72% of them too.  (The engine is the one the cell's family builds for
-    ``benchmark/rehearse.py``, from shapes alone.)"""
-    import collections
-    import importlib
-    import re
-    from unittest import mock
-
-    from vescale_tpu import kernels
-    from vescale_tpu.moe import dropless
-    from vescale_tpu.serve import HybridServeEngine
-
-    built, init = [], HybridServeEngine.__init__
-
-    def noted(self, *args, **kwargs):
-        init(self, *args, **kwargs)
-        built.append(self)
-
-    flash_ops = importlib.import_module("vescale_tpu.ops.flash_attention")
-    with mock.patch.object(HybridServeEngine, "__init__", noted):
-        _family, _config, sizes, programs = _cells_programs(chip, "granite4hsmall_serve_batch")
-        (engine,) = built
-        cache, c = engine.cache, engine.config
-        S, page, rung = cache.num_slots, cache.config.page_size, 256
-        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
-        # (traced here too, under the same answers: the flash forward asks for its platform while it is traced)
-        with mock.patch.object(kernels, "on_tpu", lambda: True), mock.patch.object(flash_ops, "jax", _JaxOnATpu()):
-            lowered = engine._ride_fn.lower(engine.params, *engine._held(), i32(S, cache.config.pages_per_slot), i32(S), i32(S),
-                                            i32(S), i32(rung), i32(), i32(rung // page), i32())
-    assert engine.rides and engine.kernel_decode and engine.kernel_ssm_step and (S, rung) == (64, 256)
-    # which form the expert layer holds at each rung's rows, the step's beside them (and the step's alone): by the shapes
-    forms = [dropless.expert_form(rows, c.num_experts_per_tok, c.experts_held, c.num_experts) for rows in (S, *engine._prompt_rows.values())]
-    assert forms == [dropless.ALL_ON_ALL, dropless.PADDED_OR_SORTED] + [dropless.SORTED] * 3
-    assert engine._grouped_layers == {rows: 10 for rows in (S + 512, S + 1024, S + 1536)}
-    assert lowered.as_text().lstrip().startswith("module @jit_decode ")
-    compiled = lowered.compile()
-    text = compiled.as_text()
-    kernel_calls = re.findall(r"%([a-z_.]+?)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
-    assert sorted(kernel_calls) == ["paged_decode"] + ["ssm_step"] * 9 + ["vs.attn"]      # (the flash forward bears its scope's name)
-    _assert_in_place_and_fits(compiled, sizes, "bf16[1,6145,16,8,128]")        # 0.2 GB a pool
-    assert not [line for line in text.splitlines() if " copy(" in line and "= f32[9,64,128,8192]" in line], "nor of the state"
-    products = collections.Counter(re.findall(r"= \w+\[([\d,]+)\]\S* convolution\(", text))
-    weights = {shape: n for shape, n in products.items() if shape.split(",")[0] in ("320", "36", "65")}
-    # in_proj; out_proj x9, o, q and the shared down x10; k and v; the shared gate and up; the router; the held experts'
-    # down, and their gate and up, over 36 x 128 padded places; the head 64 + 1
-    assert weights == {"320,16768": 9, "320,4096": 21, "320,1024": 2, "320,1536": 20, "320,72": 10, "36,128,4096": 10,
-                       "36,128,768": 20, "65,50176": 1}
-    # ... and nothing over the step's 64 rows or the rung's 256 alone but the chunked scan's own products (C B^T of a
-    # chunk and the chunk states: the prompt's rows with each other, no weight in them)
-    assert {shape for shape in products if shape not in weights} == {"256,256", "128,64,256", "128,128,64"}
-    assert ".remat" not in text, "no product is run anew for a second reader (PERF.md section 6, PR 55)"
-    assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
-    # ... and the step without a prompt is the program it was: its products over the 64 rows
-    (step,) = [low for title, low in programs if "decode step" in title]
-    assert {shape.split(",")[0] for shape in re.findall(r"= \w+\[([\d,]+)\]\S* convolution\(", step.compile().as_text())} <= {"64", "36"}
-
-
-# what a program outside its kernels' bodies lowers to, for a described v5e: a digest of the lowered text with every
-# kernel's serialized body taken out (it holds the checkout's path and the kernel's line numbers; the bodies' own identity
-# is the jaxpr digests of tests/test_program_identity.py).  Taken on the parent of the PR that gave ``paged_decode`` a
-# folded sibling and the flash forward a sink and narrower values (8655180, this function on that tree); the rung's anew
-# by PR 52, whose prefill program also returns its row's argmax (``bf0426431e3c2841`` before it).
-LOWERED_BEFORE_FOLDED_POOLS = {"decode step": "34098600aed73bdf", "rung of 512 positions": "e19c2768c59bc5a3"}
-
-
-@pytest.mark.parametrize("program", list(LOWERED_BEFORE_FOLDED_POOLS), ids=["decode", "rung512"])
-def test_an_existing_cells_programs_lower_to_the_text_they_had(chip, program):
-    """``lagunaxs2_serve_mixedlen``'s decode step (``paged_decode`` over pages and
-    rings of ONE width) and its 512 rung (the causal and the windowed forward
-    without a sink): with ``sink=None``, ``Dv == D``, ``bias=None`` and
-    ``v_head_dim=None`` nothing of them changed."""
-    import hashlib
-    import re
-
-    _family, _config, _sizes, programs = _cells_programs(chip, "lagunaxs2_serve_mixedlen")
-    (lowered,) = [low for title, low in programs if program in title]
-    text = re.sub(r'(backend_config = ")[^\n]*', r"\1<kernel>", lowered.as_text())
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == LOWERED_BEFORE_FOLDED_POOLS[program]
 
 
 # ------------------------------------------------------------ fused adamw

@@ -65,8 +65,27 @@ def _axis(mesh: DeviceMesh, mesh_dim) -> str:
     return mesh.dim_name(mesh_dim)
 
 
-def _smap(mesh: DeviceMesh, fn, in_spec, out_spec):
-    return shard_map(fn, mesh=mesh.jax_mesh, in_specs=in_spec, out_specs=out_spec, check_vma=False)
+@functools.lru_cache(maxsize=None)
+def _program(jax_mesh, body, in_specs, out_specs, static):
+    return jax.jit(shard_map(functools.partial(body, **dict(static)), mesh=jax_mesh,
+                             in_specs=in_specs, out_specs=out_specs, check_vma=False))
+
+
+def _smap(mesh: DeviceMesh, body, in_specs, out_specs, **static):
+    """The ONE compiled program of an eager collective: ``body`` (a function
+    of this module, never a closure) with its ``static`` parameters bound,
+    under ``shard_map`` and ``jit``, kept by what defines it (the jax mesh,
+    the body, the specs, the static parameters; shape and dtype are ``jit``'s
+    own key).  A bare ``shard_map`` called eagerly runs its body a primitive
+    at a time, each a program of its own, on EVERY call; this traces and
+    compiles once a definition.  What changes from call to call (the operand,
+    a stochastic-rounding key) is an argument of the program.  Under an outer
+    trace the inner ``jit`` is inlined."""
+    return _program(mesh.jax_mesh, body, in_specs, out_specs, tuple(sorted(static.items())))
+
+
+def _all_reduce_body(x, *, ax, reduce_op, stacked):
+    return _REDUCE[reduce_op](jnp.squeeze(x, 0) if stacked else x, ax)
 
 
 def mesh_all_reduce(tensor, mesh: DeviceMesh, reduce_op: str = "sum", mesh_dim=0, stacked: bool = True):
@@ -74,12 +93,12 @@ def mesh_all_reduce(tensor, mesh: DeviceMesh, reduce_op: str = "sum", mesh_dim=0
     output is the reduced value (dim0 removed).  Mirrors
     _collective_utils.py:344."""
     ax = _axis(mesh, mesh_dim)
-    op = _REDUCE[reduce_op]
-    if stacked:
-        f = _smap(mesh, lambda x: op(jnp.squeeze(x, 0), ax), P(ax), P())
-        return f(tensor)
-    f = _smap(mesh, lambda x: op(x, ax), P(), P())
+    f = _smap(mesh, _all_reduce_body, P(ax) if stacked else P(), P(), ax=ax, reduce_op=reduce_op, stacked=stacked)
     return f(tensor)
+
+
+def _all_gather_body(x, *, ax, gather_dim, stacked):  # stacked x: (1, *local)
+    return jax.lax.all_gather(jnp.squeeze(x, 0) if stacked else x, ax, axis=gather_dim, tiled=True)
 
 
 def mesh_all_gather(tensor, mesh: DeviceMesh, mesh_dim=0, gather_dim: int = 0, stacked: bool = True):
@@ -87,39 +106,36 @@ def mesh_all_gather(tensor, mesh: DeviceMesh, mesh_dim=0, gather_dim: int = 0, s
     (_collective_utils.py:315).  With ``stacked`` the input dim0 carries the
     per-rank shards."""
     ax = _axis(mesh, mesh_dim)
-    if stacked:
+    f = _smap(mesh, _all_gather_body, P(ax) if stacked else P(), P(), ax=ax, gather_dim=gather_dim, stacked=stacked)
+    return f(tensor)
 
-        def body(x):  # x: (1, *local)
-            return jax.lax.all_gather(jnp.squeeze(x, 0), ax, axis=gather_dim, tiled=True)
 
-        return _smap(mesh, body, P(ax), P())(tensor)
-
-    def body(x):
-        return jax.lax.all_gather(x, ax, axis=gather_dim, tiled=True)
-
-    return _smap(mesh, body, P(), P())(tensor)
+def _reduce_scatter_body(x, *, ax, n, reduce_op, scatter_dim):  # (1, *full)
+    x = jnp.squeeze(x, 0)
+    if reduce_op == "avg":
+        out = jax.lax.psum_scatter(x, ax, scatter_dimension=scatter_dim, tiled=True) / n
+    elif reduce_op == "sum":
+        out = jax.lax.psum_scatter(x, ax, scatter_dimension=scatter_dim, tiled=True)
+    else:
+        full = _REDUCE[reduce_op](x, ax)
+        idx = jax.lax.axis_index(ax)
+        chunk = full.shape[scatter_dim] // n
+        out = jax.lax.dynamic_slice_in_dim(full, idx * chunk, chunk, axis=scatter_dim)
+    return out[None]
 
 
 def mesh_reduce_scatter(tensor, mesh: DeviceMesh, reduce_op: str = "sum", scatter_dim: int = 0, mesh_dim=0):
     """Each rank contributes a full tensor (stacked on dim0); output stacks
     each rank's reduced scatter chunk on dim0 (_collective_utils.py:288)."""
     ax = _axis(mesh, mesh_dim)
+    f = _smap(mesh, _reduce_scatter_body, P(ax), P(ax), ax=ax, n=mesh.size(mesh_dim), reduce_op=reduce_op,
+              scatter_dim=scatter_dim)
+    return f(tensor)
 
-    def body(x):  # (1, *full)
-        x = jnp.squeeze(x, 0)
-        if reduce_op == "avg":
-            out = jax.lax.psum_scatter(x, ax, scatter_dimension=scatter_dim, tiled=True) / mesh.size(mesh_dim)
-        elif reduce_op == "sum":
-            out = jax.lax.psum_scatter(x, ax, scatter_dimension=scatter_dim, tiled=True)
-        else:
-            full = _REDUCE[reduce_op](x, ax)
-            n = mesh.size(mesh_dim)
-            idx = jax.lax.axis_index(ax)
-            chunk = full.shape[scatter_dim] // n
-            out = jax.lax.dynamic_slice_in_dim(full, idx * chunk, chunk, axis=scatter_dim)
-        return out[None]
 
-    return _smap(mesh, body, P(ax), P(ax))(tensor)
+def _all_to_all_body(x, *, ax, split_dim, concat_dim):
+    out = jax.lax.all_to_all(jnp.squeeze(x, 0), ax, split_axis=split_dim, concat_axis=concat_dim, tiled=True)
+    return out[None]
 
 
 def mesh_all_to_all(tensor, mesh: DeviceMesh, mesh_dim=0, split_dim: int = 0, concat_dim: int = 0):
@@ -128,26 +144,20 @@ def mesh_all_to_all(tensor, mesh: DeviceMesh, mesh_dim=0, split_dim: int = 0, co
     chunk j with rank j, concatenating received chunks along ``concat_dim``.
     Dims are in the *operand* (post-squeeze) coordinate system."""
     ax = _axis(mesh, mesh_dim)
+    return _smap(mesh, _all_to_all_body, P(ax), P(ax), ax=ax, split_dim=split_dim, concat_dim=concat_dim)(tensor)
 
-    def body(x):
-        x = jnp.squeeze(x, 0)
-        out = jax.lax.all_to_all(x, ax, split_axis=split_dim, concat_axis=concat_dim, tiled=True)
-        return out[None]
 
-    return _smap(mesh, body, P(ax), P(ax))(tensor)
+def _broadcast_body(x, *, ax, src_rank):
+    x = jnp.squeeze(x, 0)
+    masked = jnp.where(jax.lax.axis_index(ax) == src_rank, x, jnp.zeros_like(x))
+    return jax.lax.psum(masked, ax)
 
 
 def mesh_broadcast(tensor, mesh: DeviceMesh, mesh_dim=0, src_rank: int = 0):
     """Broadcast rank ``src_rank``'s operand (from the stacked dim0) to all
     (_collective_utils.py:237): output has no stack dim."""
     ax = _axis(mesh, mesh_dim)
-
-    def body(x):
-        x = jnp.squeeze(x, 0)
-        masked = jnp.where(jax.lax.axis_index(ax) == src_rank, x, jnp.zeros_like(x))
-        return jax.lax.psum(masked, ax)
-
-    return _smap(mesh, body, P(ax), P())(tensor)
+    return _smap(mesh, _broadcast_body, P(ax), P(), ax=ax, src_rank=src_rank)(tensor)
 
 
 def mesh_scatter(tensor, mesh: DeviceMesh, mesh_dim=0, scatter_dim: int = 0, src_rank: int = 0):
@@ -160,18 +170,17 @@ def mesh_scatter(tensor, mesh: DeviceMesh, mesh_dim=0, scatter_dim: int = 0, src
     return jax.device_put(chunks, NamedSharding(mesh.jax_mesh, P(ax)))
 
 
+def _ppermute_body(x, *, ax, perm):
+    return jax.lax.ppermute(jnp.squeeze(x, 0), ax, perm)[None]
+
+
 def mesh_ppermute(tensor, mesh: DeviceMesh, mesh_dim=0, shift: int = 1):
     """Ring permute along a mesh dim (the PP p2p primitive; reference uses
     dist.send/recv — pipe/p2p_communication.py)."""
     ax = _axis(mesh, mesh_dim)
     n = mesh.size(mesh_dim)
-    perm = [(i, (i + shift) % n) for i in range(n)]
-
-    def body(x):
-        x = jnp.squeeze(x, 0)
-        return jax.lax.ppermute(x, ax, perm)[None]
-
-    return _smap(mesh, body, P(ax), P(ax))(tensor)
+    perm = tuple((i, (i + shift) % n) for i in range(n))
+    return _smap(mesh, _ppermute_body, P(ax), P(ax), ax=ax, perm=perm)(tensor)
 
 
 # ------------------------------------------------- quantized collectives
@@ -441,26 +450,30 @@ def _compress_telemetry(n_elements: int, itemsize: int, block: int, op: str, n: 
     _tel.count(f"grad_compress_{op}_total")
 
 
+def _all_reduce_q_body(x, key, *, ax, n, stacked, **kw):
+    return q_psum(jnp.squeeze(x, 0) if stacked else x, ax, n, key=key, **kw)
+
+
 def all_reduce_q(tensor, mesh: DeviceMesh, reduce_op: str = "sum", mesh_dim=0,
                  stacked: bool = True, *, block=None, rounding=None, key=None,
                  acc_dtype=jnp.float32):
     """Block-scaled int8 all-reduce — the quantized ``mesh_all_reduce``.
     Same stacked calling convention; knobs default from the registered
-    ``VESCALE_GRAD_COMPRESS_*`` env vars."""
+    ``VESCALE_GRAD_COMPRESS_*`` env vars, read on the host each call.  The
+    stochastic-rounding key is an ARGUMENT of the compiled program."""
     block, rounding, key = _compress_defaults(block, rounding, key)
     ax = _axis(mesh, mesh_dim)
     n = mesh.size(mesh_dim)
-    kw = dict(block=block, rounding=rounding, key=key, acc_dtype=acc_dtype,
-              reduce_op=reduce_op)
-    if stacked:
-        f = _smap(mesh, lambda x: q_psum(jnp.squeeze(x, 0), ax, n, **kw), P(ax), P())
-        elems = int(np.prod(tensor.shape[1:]))
-    else:
-        f = _smap(mesh, lambda x: q_psum(x, ax, n, **kw), P(), P())
-        elems = int(np.prod(tensor.shape))
-    out = f(tensor)
+    f = _smap(mesh, _all_reduce_q_body, (P(ax) if stacked else P(), P()), P(), ax=ax, n=n, stacked=stacked,
+              block=block, rounding=rounding, acc_dtype=acc_dtype, reduce_op=reduce_op)
+    out = f(tensor, key)
+    elems = int(np.prod(tensor.shape[1:] if stacked else tensor.shape))
     _compress_telemetry(elems, jnp.dtype(tensor.dtype).itemsize, block, "all_reduce", n)
     return out
+
+
+def _reduce_scatter_q_body(x, key, *, ax, n, **kw):  # (1, *full)
+    return q_psum_scatter(jnp.squeeze(x, 0), ax, n, key=key, **kw)[None]
 
 
 def reduce_scatter_q(tensor, mesh: DeviceMesh, reduce_op: str = "sum",
@@ -472,16 +485,9 @@ def reduce_scatter_q(tensor, mesh: DeviceMesh, reduce_op: str = "sum",
     block, rounding, key = _compress_defaults(block, rounding, key)
     ax = _axis(mesh, mesh_dim)
     n = mesh.size(mesh_dim)
-
-    def body(x):  # (1, *full)
-        x = jnp.squeeze(x, 0)
-        out = q_psum_scatter(
-            x, ax, n, scatter_dim=scatter_dim, block=block, rounding=rounding,
-            key=key, acc_dtype=acc_dtype, reduce_op=reduce_op,
-        )
-        return out[None]
-
-    out = _smap(mesh, body, P(ax), P(ax))(tensor)
+    f = _smap(mesh, _reduce_scatter_q_body, (P(ax), P()), P(ax), ax=ax, n=n, scatter_dim=scatter_dim,
+              block=block, rounding=rounding, acc_dtype=acc_dtype, reduce_op=reduce_op)
+    out = f(tensor, key)
     elems = int(np.prod(tensor.shape[1:]))
     _compress_telemetry(elems, jnp.dtype(tensor.dtype).itemsize, block, "reduce_scatter", n)
     return out
