@@ -172,6 +172,82 @@ def test_the_sigmoid_router_under_a_group_limit_is_the_plain_one_ties_and_bias_a
         route_sigmoid_group_limited(jnp.zeros((2, 32)), 17, n_group=4, topk_group=2)
 
 
+def top_k_route(scores, k, *, n_group, topk_group, scale=1.0, bias=None):
+    """The rule as it read before PR 67: every selection a ``jax.lax.top_k``, the kept groups marked by a scatter."""
+    N, E = scores.shape
+    per = E // n_group
+    probs = jax.nn.sigmoid(scores.astype(jnp.float32))
+    choice = probs if bias is None else probs + bias.astype(jnp.float32)
+    best, _ = jax.lax.top_k(choice.reshape(N, n_group, per), 2)
+    _, groups = jax.lax.top_k(jnp.sum(best, axis=-1), topk_group)
+    kept = jnp.zeros((N, n_group), bool).at[jnp.arange(N)[:, None], groups].set(True)
+    _, idx = jax.lax.top_k(jnp.where(jnp.repeat(kept, per, axis=1), choice, -jnp.inf), k)
+    top = jnp.take_along_axis(probs, idx, axis=-1)
+    return idx.astype(jnp.int32), top * (scale / jnp.sum(top, axis=-1, keepdims=True)), kept
+
+
+def top_k_operands(jaxpr):
+    """The operand shapes of every ``top_k`` of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "top_k":
+            yield eqn.invars[0].aval.shape
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from top_k_operands(inner)
+
+
+def router_case(name):
+    """Scores (N, E) and a bias or None at Ling's shapes: (256, 512), 8 groups of 64 (of 2 in one case)."""
+    rng = np.random.default_rng(67)
+    scores, bias = rng.normal(size=(256, 512)).astype(np.float32), None
+    if name == "bias":
+        bias = (0.3 * rng.normal(size=512)).astype(np.float32)
+        assert (bias < 0).any() and (bias > 0).any()
+    elif name == "two_largest_of_a_group_equal":
+        scores[:, 64 * 3 + 5] = scores[:, 64 * 3 + 40] = 2.0     # group 3's maximum twice: it scores 2 x sigmoid(2.0)
+        scores[::2, 64 * 6 + 63] = scores[::2, 64 * 6] = 2.5     # the first and the last place of a group
+        scores[5, 64:128] = 0.25                                 # a whole group alike
+        scores[7] = -3.0                                         # group 3 holds 2.0 twice and nothing else, four groups 2.0 and 1.5:
+        scores[7, [64 * 3 + 9, 64 * 3 + 10]] = 2.0               # twice its maximum puts group 3 first, its maximum and the next
+        for g in (0, 1, 2, 4):                                   # below it would put it behind all four
+            scores[7, [64 * g + 1, 64 * g + 33]] = 2.0, 1.5
+    elif name == "two_groups_equal":
+        scores[:, 64 * 5:64 * 6] = scores[:, 64 * 2:64 * 3]      # the lower group wins where the cut falls between them
+        scores[0] = np.tile(scores[0, :64], 8)                   # every group alike: groups 0 to 3
+    elif name == "two_experts_a_group":
+        scores = scores[:, :16]
+        scores[3] = 0.5
+    elif name == "bfloat16":
+        scores = jnp.asarray(scores, jnp.bfloat16)               # 8 bits of mantissa: equal scores abound
+    elif name != "random":
+        raise ValueError(name)
+    return jnp.asarray(scores), None if bias is None else jnp.asarray(bias)
+
+
+@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("name", ["random", "bias", "two_largest_of_a_group_equal", "two_groups_equal", "two_experts_a_group", "bfloat16"])
+def test_the_rule_without_a_sort_routes_bit_for_bit_as_its_top_k_form_does(name, jitted):
+    """8 of 4 of 8 groups a token, as Ling routes."""
+    scores, bias = router_case(name)
+    new, old = (lambda s, b, rule=rule: rule(s, 8, n_group=8, topk_group=4, scale=2.5, bias=b) for rule in (route_sigmoid_group_limited, top_k_route))
+    if jitted:
+        N, n_group, per = scores.shape[0], 8, scores.shape[1] // 8
+        operands = list(top_k_operands(jax.make_jaxpr(jax.jit(new))(scores, bias).jaxpr))
+        assert (N, n_group, per) in top_k_operands(jax.make_jaxpr(old)(scores, bias).jaxpr), "the walk finds the sort where there is one"
+        assert operands == [(N, n_group)], f"one top_k is left, of the groups' scores: none over {(N, n_group, per)}, none over a row's experts"
+        new, old = jax.jit(new), jax.jit(old)
+    (idx, gates, kept), (want_idx, want_gates, want_kept) = new(scores, bias), old(scores, bias)
+    assert idx.dtype == jnp.int32 and gates.dtype == jnp.float32 and kept.dtype == jnp.bool_
+    kept = np.asarray(kept)
+    assert np.array_equal(np.asarray(idx), np.asarray(want_idx)) and np.array_equal(kept, np.asarray(want_kept))
+    assert np.array_equal(np.asarray(gates).view(np.uint32), np.asarray(want_gates).view(np.uint32)), "the gates, bit for bit"
+    assert (kept.sum(axis=1) == 4).all()
+    if name == "two_largest_of_a_group_equal":
+        assert list(kept[7]) == [True] * 4 + [False] * 4, "twice the maximum, not the maximum and the next below it"
+    if name == "two_groups_equal":
+        assert not (kept[:, 5] & ~kept[:, 2]).any() and (kept[:, 2] & ~kept[:, 5]).any()
+        assert list(kept[0]) == [True] * 4 + [False] * 4
+
+
 # ------------------------------------------------- the latent block without a query LoRA
 def test_the_latent_block_without_a_query_lora_has_one_query_matrix_and_takes_a_head_gate():
     cfg = toy_config()

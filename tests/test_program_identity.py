@@ -27,7 +27,9 @@ grouped kernel's row tile now follows ``N k / E``, a tile of 32 -> 16 each: ``gr
 rows x 3 over 4 held of 8; at the cell's size no rung of Granite's moves), ``mimo_v2``'s (16 x 4 over 4 of 16) and
 ``longcat_flash``'s (16 x 4 over 4 of 16 + 8 outputs); ``deepseek_v2``'s and the first rungs sit at the smallest tile
 either way.  ``ling_hybrid``'s six are new here (``tests/test_kda.py``'s toy): the family whose decode
-step PR 64 moved from the padded candidate to the grouped kernel at the cell's size is pinned from here on.)"""
+step PR 64 moved from the padded candidate to the grouped kernel at the cell's size is pinned from here on.
+PR 67 took ``ling_hybrid``'s six anew: ``route_sigmoid_group_limited`` selects by maxima where it sorted (``vs.routed``
+and ``vs.moe`` 166 operations more, the unrolled rounds); the forty-two others held, no other family calls the rule.)"""
 
 import collections
 import dataclasses
@@ -102,12 +104,12 @@ PROGRAMS = {
     "longcat_flash/kernels_interpreted/prefill_rung_1": ('dc1e4a16dd58625c', 'vs.attn=2404 vs.mlp=36 vs.moe=68'),
     "longcat_flash/kernels_interpreted/prefill_rung_2": ('023cc3800daad893', 'vs.attn=2404 vs.mlp=36 vs.moe=68'),
     "longcat_flash/kernels_interpreted/decode": ('1b33297552cdd061', 'vs.attn=556 vs.mlp=36 vs.moe=74'),
-    "ling_hybrid/xla_legs/prefill_rung_1": ('a8861740d47c520a', 'vs.kda=1068 vs.mla=120 vs.mlp=9 vs.moe=354 vs.routed=300'),
-    "ling_hybrid/xla_legs/prefill_rung_2": ('43d293b14ab99c53', 'vs.kda=1068 vs.mla=120 vs.mlp=9 vs.moe=354 vs.routed=300'),
-    "ling_hybrid/xla_legs/decode": ('cc7ddadf941eb3a4', 'vs.kda=1110 vs.mla=152 vs.mlp=9 vs.moe=420 vs.routed=366'),
-    "ling_hybrid/kernels_interpreted/prefill_rung_1": ('cb9e4fae6a5c4dc6', 'vs.kda=864 vs.mla=580 vs.mlp=9 vs.moe=354 vs.routed=300'),
-    "ling_hybrid/kernels_interpreted/prefill_rung_2": ('7f3b56534711a180', 'vs.kda=864 vs.mla=580 vs.mlp=9 vs.moe=354 vs.routed=300'),
-    "ling_hybrid/kernels_interpreted/decode": ('04ec5d6b02ca5287', 'vs.kda=942 vs.mla=115 vs.mlp=9 vs.moe=420 vs.routed=366'),
+    "ling_hybrid/xla_legs/prefill_rung_1": ('42aee405651da71e', 'vs.kda=1068 vs.mla=120 vs.mlp=9 vs.moe=520 vs.routed=466'),
+    "ling_hybrid/xla_legs/prefill_rung_2": ('eb00bee5e49c470f', 'vs.kda=1068 vs.mla=120 vs.mlp=9 vs.moe=520 vs.routed=466'),
+    "ling_hybrid/xla_legs/decode": ('70ca317312eca05e', 'vs.kda=1110 vs.mla=152 vs.mlp=9 vs.moe=586 vs.routed=532'),
+    "ling_hybrid/kernels_interpreted/prefill_rung_1": ('0df0cbe6fedf37e5', 'vs.kda=864 vs.mla=580 vs.mlp=9 vs.moe=520 vs.routed=466'),
+    "ling_hybrid/kernels_interpreted/prefill_rung_2": ('1acaacaa2fb23d21', 'vs.kda=864 vs.mla=580 vs.mlp=9 vs.moe=520 vs.routed=466'),
+    "ling_hybrid/kernels_interpreted/decode": ('e9bbc0fd9db6388c', 'vs.kda=942 vs.mla=115 vs.mlp=9 vs.moe=586 vs.routed=532'),
 }
 
 

@@ -77,6 +77,8 @@ def test_lings_decode_program_compiles_at_the_cells_size_with_no_copy_of_pool_or
     text = compiled.as_text()
     kernel_calls = re.findall(r"%([a-z_.]+?)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
     assert sorted(kernel_calls) == ["grouped_swiglu"] * 6 + ["kda_step"] * 6 + ["paged_decode_latent"]
+    # the router sorts the eight group scores of a row and nothing wider (PR 67: a group's score and the final eight are maxima)
+    assert set(re.findall(r"= \((f32\[[\d,]+\])[^\n]* sort\(", text)) == {"f32[256,8]"}
     cells_programs.assert_in_place_and_fits(compiled, sizes, "bf16[1,61440,32,1,640]")
     for held in ("bf16[1,61440,32,640]", "f32[6,256,32,128,128]", "bf16[6,256,3,12288]"):
         assert not [line for line in text.splitlines() if " copy(" in line and f"= {held}" in line], held

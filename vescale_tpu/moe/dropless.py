@@ -196,10 +196,20 @@ def route_sigmoid_group_limited(scores, k: int, *, n_group: int, topk_group: int
         raise ValueError(f"{E} experts in {n_group} groups, {topk_group} kept, cannot give {k} a token")
     probs = jax.nn.sigmoid(scores.astype(jnp.float32))
     choice = probs if bias is None else probs + bias.astype(jnp.float32)
-    best, _ = jax.lax.top_k(choice.reshape(N, n_group, per), 2)
-    _, groups = jax.lax.top_k(jnp.sum(best, axis=-1), topk_group)
-    kept = jnp.zeros((N, n_group), bool).at[jnp.arange(N)[:, None], groups].set(True)
-    _, idx = jax.lax.top_k(jnp.where(jnp.repeat(kept, per, axis=1), choice, -jnp.inf), k)
+    # No sort anywhere a maximum does: on the chip ``top_k(grouped, 2)`` is a full sort of every group (171 us a call at
+    # (256, 8, 64)), ``top_k(..., k)`` one of every row, and the scatter that marked the kept groups sorts its indices first;
+    # the rule alone took 249 us at 256 rows and takes 34 (PERF.md section 6, PR 67).  ``argmax`` gives the FIRST of equal
+    # maxima, so a group whose two largest are equal scores twice that value and the ids come in ``lax.top_k``'s order.
+    without = lambda values, at: jnp.where(jnp.arange(values.shape[-1]) == at[..., None], -jnp.inf, values)
+    grouped = choice.reshape(N, n_group, per)
+    second = jnp.max(without(grouped, jnp.argmax(grouped, axis=-1)), axis=-1)
+    _, groups = jax.lax.top_k(jnp.max(grouped, axis=-1) + second, topk_group)
+    kept = jnp.any(groups[:, :, None] == jnp.arange(n_group), axis=1)
+    left, ids = jnp.where(jnp.repeat(kept, per, axis=1), choice, -jnp.inf), []
+    for _ in range(k):
+        ids.append(jnp.argmax(left, axis=-1))
+        left = without(left, ids[-1])
+    idx = jnp.stack(ids, axis=-1)
     top = jnp.take_along_axis(probs, idx, axis=-1)
     return idx.astype(jnp.int32), top * (scale / jnp.sum(top, axis=-1, keepdims=True)), kept
 
